@@ -27,9 +27,12 @@ def svc():
 
 
 @pytest.fixture(autouse=True)
-def _isolate_compile_cache(monkeypatch):
-    """Each test gets a disconnected remote tier and zeroed counters."""
+def _isolate_compile_cache(monkeypatch, tmp_path):
+    """Each test gets a disconnected remote tier, zeroed counters and —
+    placed the only way the cache can be placed, through the environment
+    — a local tier under its own tmp_path."""
     monkeypatch.delenv("TPUJOB_COMPILE_CACHE", raising=False)
+    monkeypatch.setenv(cc.ENV_DIR, str(tmp_path))
     cc.configure_remote(None)
     for k in cc._stats:
         cc._stats[k] = 0
@@ -99,7 +102,7 @@ def test_corrupted_entry_purged_and_workload_falls_back(svc, tmp_path):
         return modeled_payload(key_material)
 
     data, source = cc.cached_compile(
-        key_material, compile_fn, cache_dir=str(tmp_path), wait_s=0.0
+        key_material, compile_fn, wait_s=0.0
     )
     assert source == "compiled" and calls == [1]
     assert data == modeled_payload(key_material)
@@ -146,7 +149,7 @@ def test_dead_cachesvc_degrades_to_local_with_span_attr(tmp_path, monkeypatch):
     cc.configure_remote("http://127.0.0.1:9")  # nothing listens there
     data, source = cc.cached_compile(
         "some/config", lambda: b"compiled-bytes",
-        cache_dir=str(tmp_path), wait_s=0.0,
+        wait_s=0.0,
     )
     assert (data, source) == (b"compiled-bytes", "compiled")
     stats = cc.stats()
@@ -206,11 +209,11 @@ def test_remote_fill_lands_locally(svc, tmp_path):
     assert client.publish(key, b"remote-built")
     cc.configure_remote(svc.url)
     data, source = cc.cached_compile(
-        key_material, lambda: b"never", cache_dir=str(tmp_path), wait_s=0.0
+        key_material, lambda: b"never", wait_s=0.0
     )
     assert (data, source) == (b"remote-built", "remote")
     data2, source2 = cc.cached_compile(
-        key_material, lambda: b"never", cache_dir=str(tmp_path), wait_s=0.0
+        key_material, lambda: b"never", wait_s=0.0
     )
     assert (data2, source2) == (b"remote-built", "local")
 
@@ -223,7 +226,7 @@ def test_cached_compile_configures_remote_from_env(svc, tmp_path, monkeypatch):
     CacheClient(svc.url).publish(key, b"fleet-built")
     monkeypatch.setenv("TPUJOB_COMPILE_CACHE", svc.url)
     data, source = cc.cached_compile(
-        key_material, lambda: b"never", cache_dir=str(tmp_path), wait_s=0.0
+        key_material, lambda: b"never", wait_s=0.0
     )
     assert (data, source) == (b"fleet-built", "remote")
 

@@ -1,0 +1,169 @@
+"""The main path's kernels, compiled at real widths for a TPU v5e that is
+DESCRIBED, not attached (`jax.experimental.topologies`): what the chip's
+compiler refuses — a block that does not tile, more VMEM than a kernel may
+scope — it refuses here, at no chip time. Interpret-mode tests cannot see
+either; both had passed every one of them.
+
+The only file that describes a topology. The describing call loads the
+TPU's library, which one process at a time may hold, so it lives in a
+module-scoped fixture (never at import, in a skipif, in parametrize or in
+conftest.py) and every compile runs in the test's own process, with the
+persistent compilation cache off around it (an entry written for a
+described chip cannot be read back without one). A compile that passes is
+not a chip run: no time, no result comes from here.
+"""
+
+import importlib
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+fa = importlib.import_module("tf_operator_tpu.ops.flash_attention")
+gm = importlib.import_module("tf_operator_tpu.ops.grouped_matmul")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no compiler here: skip, named
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _kernels(fn, *args) -> int:
+    """Compile for the described chip; count the Mosaic kernels in it."""
+    return jax.jit(fn).lower(*args).compile().as_text().count("tpu_custom_call")
+
+
+# ---- flash attention: fwd and fwd+bwd -------------------------------------
+
+FLASH_SHAPES = {
+    # b, t, h, h_kv, d — bf16 as in training
+    "gqa-2048": (6, 2048, 16, 4, 128),
+    "hd64-t2048": (4, 2048, 12, 12, 64),
+}
+
+
+@pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
+def test_flash_attention_compiles_for_v5e(one_chip, no_cache, shape, bwd):
+    b, t, h, h_kv, d = FLASH_SHAPES[shape]
+
+    def spec(heads):
+        return jax.ShapeDtypeStruct((b, t, heads, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def fwd(q, k, v):
+        # This process's own backend is the CPU, so flash_attention()'s
+        # dispatch would hand the compiler the reference: take the block
+        # sizes the dispatch picks, then enter the kernel path itself.
+        use, bq, bk = fa._dispatch(q, k, v, None, None, True, None)
+        assert use
+        return fa._flash_lse(q, k, v, True, bq, bk, False)[0]
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if bwd else fwd
+    n = _kernels(fn, spec(h), spec(h_kv), spec(h_kv))
+    assert n >= (3 if bwd else 1)  # fwd; + dq and dk/dv kernels
+
+
+# ---- paged decode: the kernel the serve engine cannot run without ---------
+
+
+@pytest.mark.parametrize("page", [16, 128])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_flash_attention_decode_compiles_for_v5e(one_chip, no_cache, dtype,
+                                                 page):
+    s_n, h, h_kv, d, n_pages, p = 8, 16, 4, 128, 81, 16  # gqa-2048 widths
+
+    def a(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = a((n_pages, h_kv, page, d), dtype)
+    n = _kernels(
+        lambda q, k, v, pt, sl: fa._decode_call(q, k, v, pt, sl, False),
+        a((s_n, h, d), dtype), pool, pool, a((s_n, p), jnp.int32),
+        a((s_n,), jnp.int32),
+    )
+    assert n == 1
+
+
+# ---- grouped matmul: fwd, dx, dw, with and without the fused row scale ----
+
+GMM_WIDTHS = {
+    # E, k, n (the up/gate projection; "down" swaps k and n)
+    "moe-small-up": (8, 768, 3072),
+    "moe-small-down": (8, 3072, 768),
+    # the widths whose dx (21 MB) and dw (28 MB) tiles overran the 16 MB
+    # scoped-VMEM default before _plan_cols counted every resident tile
+    "mixtral-8x7b-up": (8, 4096, 14336),
+    "mixtral-8x7b-down": (8, 14336, 4096),
+}
+
+
+@pytest.mark.parametrize("scaled", [False, True], ids=["plain", "row_scale"])
+@pytest.mark.parametrize("part", ["fwd", "dx", "dw"])
+@pytest.mark.parametrize("widths", sorted(GMM_WIDTHS))
+def test_gmm_compiles_for_v5e(one_chip, no_cache, widths, part, scaled):
+    E, k, n = GMM_WIDTHS[widths]
+    rows, br = 4096, 256
+
+    def a(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    x, w = a((rows, k), jnp.bfloat16), a((E, k, n), jnp.bfloat16)
+    be, sc = a((rows // br,), jnp.int32), a((rows,), jnp.float32)
+
+    def y(x, w, be, sc):
+        return gm.gmm(x, w, be, row_scale=sc if scaled else None,
+                      block_rows=br)
+
+    def total(x, w, be, sc):
+        return jnp.sum(y(x, w, be, sc).astype(jnp.float32))
+
+    fn = {
+        "fwd": y,
+        "dx": jax.grad(total, argnums=0),
+        "dw": jax.grad(total, argnums=1),
+    }[part]
+    assert _kernels(fn, x, w, be, sc) >= 1
+
+
+def test_gmm_refuses_what_it_cannot_tile_by_name():
+    """No contraction tiling: a [block_rows, k] row tile that cannot fit
+    even the raised VMEM limit is refused by gmm's own ValueError, not by
+    a Mosaic allocation failure at compile time."""
+    x = jax.ShapeDtypeStruct((512, 131072), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((2, 131072, 256), jnp.bfloat16)
+    be = jax.ShapeDtypeStruct((2,), jnp.int32)
+    with pytest.raises(ValueError, match="resident in VMEM"):
+        jax.eval_shape(lambda x, w, be: gm.gmm(x, w, be, interpret=True),
+                       x, w, be)

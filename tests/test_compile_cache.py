@@ -7,37 +7,79 @@ import time
 import tf_operator_tpu.train.compile_cache as cc
 
 
+def _place(monkeypatch, path) -> str:
+    """The only way to place the cache: the environment."""
+    monkeypatch.setenv(cc.ENV_DIR, str(path))
+    return str(path)
+
+
 def test_enable_creates_and_configures_dir(tmp_path, monkeypatch):
-    target = str(tmp_path / "xla-cache")
-    got = cc.enable(target, force=True)
+    target = _place(monkeypatch, tmp_path / "xla-cache")
+    got = cc.enable(force=True)
     assert got == target and os.path.isdir(target)
     import jax
 
     assert jax.config.jax_compilation_cache_dir == target
 
 
-def test_env_dir_override(tmp_path, monkeypatch):
-    target = str(tmp_path / "from-env")
-    monkeypatch.setenv(cc.ENV_DIR, target)
+def test_env_set_means_that_directory_and_no_other(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set ⇒ both tiers (jax's executables via
+    enable(), caller artifacts via cached_compile()) write there and
+    nowhere else — in particular not the in-checkout default."""
+    target = _place(monkeypatch, tmp_path / "from-env")
+    default_before = (
+        set(os.listdir(cc.DEFAULT_CACHE_DIR))
+        if os.path.isdir(cc.DEFAULT_CACHE_DIR) else None
+    )
+    assert cc.cache_dir() == target
     assert cc.enable(force=True) == target
+    data, source = cc.cached_compile("env-set-case", lambda: b"x", wait_s=0.0)
+    assert (data, source) == (b"x", "compiled")
+    assert any(n.endswith("-cache") for n in os.listdir(target))
+    default_after = (
+        set(os.listdir(cc.DEFAULT_CACHE_DIR))
+        if os.path.isdir(cc.DEFAULT_CACHE_DIR) else None
+    )
+    assert default_after == default_before
+
+
+def test_env_unset_means_the_fixed_in_checkout_path(monkeypatch):
+    """Unset ⇒ one fixed path inside the checkout: not under home, not a
+    temp name, nothing that changes from one process to the next (the
+    path is part of what makes a second run hit)."""
+    import inspect
+    import tempfile
+
+    monkeypatch.delenv(cc.ENV_DIR, raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.cache_dir() == cc.DEFAULT_CACHE_DIR == os.path.join(
+        repo, ".cache", "xla"
+    )
+    assert not cc.DEFAULT_CACHE_DIR.startswith(tempfile.gettempdir() + os.sep)
+    assert os.path.expanduser("~") + os.sep + ".cache" not in cc.DEFAULT_CACHE_DIR
+    # and no entry point takes a directory of its own
+    assert "cache_dir" not in inspect.signature(cc.enable).parameters
+    assert "cache_dir" not in inspect.signature(cc.cached_compile).parameters
 
 
 def test_disable_env(monkeypatch, tmp_path):
+    _place(monkeypatch, tmp_path / "x")
     monkeypatch.setenv(cc.ENV_DISABLE, "1")
-    assert cc.enable(str(tmp_path / "x"), force=True) is None
+    assert cc.enable(force=True) is None
     assert not (tmp_path / "x").exists()
 
 
 def test_unwritable_dir_degrades_to_none(monkeypatch, tmp_path):
     blocker = tmp_path / "file"
     blocker.write_text("not a dir")
-    assert cc.enable(str(blocker / "sub"), force=True) is None
+    _place(monkeypatch, blocker / "sub")
+    assert cc.enable(force=True) is None
 
 
-def test_cache_populates_on_compile(tmp_path):
+def test_cache_populates_on_compile(tmp_path, monkeypatch):
     """A jitted computation lands executables in the cache directory."""
-    target = str(tmp_path / "xla-cache")
-    assert cc.enable(target, force=True) == target
+    target = _place(monkeypatch, tmp_path / "xla-cache")
+    assert cc.enable(force=True) == target
     import jax
     import jax.numpy as jnp
 
@@ -57,26 +99,27 @@ def test_cache_populates_on_compile(tmp_path):
 # restart in native deserialization code.
 
 
-def _lru(tmp_path):
-    cc.enable(str(tmp_path / "xc"), force=True)  # installs hardened put/get
+def _lru(tmp_path, monkeypatch):
+    _place(monkeypatch, tmp_path / "xc")
+    cc.enable(force=True)  # installs hardened put/get
     from jax._src.lru_cache import LRUCache
 
     return LRUCache(str(tmp_path / "lru"), max_size=-1)
 
 
-def test_put_writes_payload_digest_and_atime(tmp_path):
-    cache = _lru(tmp_path)
+def test_put_writes_payload_digest_and_atime(tmp_path, monkeypatch):
+    cache = _lru(tmp_path, monkeypatch)
     cache.put("k1", b"executable-bytes")
     names = sorted(os.listdir(tmp_path / "lru"))
     assert names == ["k1-atime", "k1-cache", "k1-cache-sha256"]
     assert cache.get("k1") == b"executable-bytes"
 
 
-def test_torn_write_is_a_miss_and_self_heals(tmp_path):
+def test_torn_write_is_a_miss_and_self_heals(tmp_path, monkeypatch):
     """A truncated payload under the final name (pre-fix poison, or a
     legacy jax write killed mid-flight) must read as a miss and be
     deleted — never handed to XLA."""
-    cache = _lru(tmp_path)
+    cache = _lru(tmp_path, monkeypatch)
     cache.put("k2", b"full-payload")
     (tmp_path / "lru" / "k2-cache").write_bytes(b"full-pay")  # torn
     assert cache.get("k2") is None
@@ -86,22 +129,24 @@ def test_torn_write_is_a_miss_and_self_heals(tmp_path):
     assert cache.get("k2") == b"recompiled"
 
 
-def test_legacy_entry_without_digest_is_purged(tmp_path):
+def test_legacy_entry_without_digest_is_purged(tmp_path, monkeypatch):
     """Entries from before the hardening have no sidecar; they are
     unverifiable, so get() drops them once and recompilation repopulates
     with a digest."""
-    cache = _lru(tmp_path)
+    cache = _lru(tmp_path, monkeypatch)
     (tmp_path / "lru" / "k3-cache").write_bytes(b"who knows")
     assert cache.get("k3") is None
     assert not (tmp_path / "lru" / "k3-cache").exists()
 
 
-def test_harden_is_idempotent(tmp_path):
+def test_harden_is_idempotent(tmp_path, monkeypatch):
     from jax._src.lru_cache import LRUCache
 
-    cc.enable(str(tmp_path / "a"), force=True)
+    _place(monkeypatch, tmp_path / "a")
+    cc.enable(force=True)
     put1, get1 = LRUCache.put, LRUCache.get
-    cc.enable(str(tmp_path / "b"), force=True)
+    _place(monkeypatch, tmp_path / "b")
+    cc.enable(force=True)
     assert LRUCache.put is put1 and LRUCache.get is get1
 
 
@@ -175,12 +220,13 @@ def test_publish_pair_breaks_stale_lock(tmp_path, monkeypatch):
 
 
 def test_cpu_only_platform_skips_cache(monkeypatch, tmp_path):
-    """jaxlib CPU executable deserialization is not cross-process-safe
-    (r10: a warm-restarted trainer loading another process's cached
-    executable died in native code) — enable() must refuse on a
-    cpu-pinned process unless explicitly forced."""
+    """An XLA:CPU executable is specific to the CPU that compiled it (its
+    AOT loader says so on every reload) and a CPU compile is cheap —
+    enable() must refuse on a cpu-pinned process unless explicitly
+    forced."""
+    _place(monkeypatch, tmp_path / "x")
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     monkeypatch.delenv(cc.ENV_FORCE, raising=False)
-    assert cc.enable(str(tmp_path / "x")) is None
+    assert cc.enable() is None
     monkeypatch.setenv(cc.ENV_FORCE, "1")
-    assert cc.enable(str(tmp_path / "x")) is not None
+    assert cc.enable() is not None

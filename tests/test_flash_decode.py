@@ -34,8 +34,8 @@ def _paged_prefix(lengths, page_size, h_kv, d, seed=0, scramble=False):
         # genuinely exercised (sequential ids would also pass a broken
         # identity mapping).
         pool._free = list(rng.permutation(num_pages))
-    k_pages = np.zeros((num_pages + 1, page_size, h_kv, d), np.float32)
-    v_pages = np.zeros((num_pages + 1, page_size, h_kv, d), np.float32)
+    k_pages = np.zeros((num_pages + 1, h_kv, page_size, d), np.float32)
+    v_pages = np.zeros((num_pages + 1, h_kv, page_size, d), np.float32)
     max_p = max(pages_needed(L, page_size) for L in lengths)
     table = np.full((len(lengths), max_p), pool.trash_page - 1, np.int32)
     k_seqs, v_seqs = [], []
@@ -46,8 +46,8 @@ def _paged_prefix(lengths, page_size, h_kv, d, seed=0, scramble=False):
         k_seq = rng.randn(L, h_kv, d).astype(np.float32)
         v_seq = rng.randn(L, h_kv, d).astype(np.float32)
         for t in range(L):
-            k_pages[sp.pages[t // page_size], t % page_size] = k_seq[t]
-            v_pages[sp.pages[t // page_size], t % page_size] = v_seq[t]
+            k_pages[sp.pages[t // page_size], :, t % page_size] = k_seq[t]
+            v_pages[sp.pages[t // page_size], :, t % page_size] = v_seq[t]
         k_seqs.append(k_seq)
         v_seqs.append(v_seq)
     return (
@@ -134,12 +134,12 @@ def test_decode_incremental_accumulation():
     )[0]
     pool = PagePool(pages_needed(L, page) + 1)
     sp = SequencePages(page)
-    kp = np.zeros((pool.num_pages + 1, page, h_kv, d), np.float32)
+    kp = np.zeros((pool.num_pages + 1, h_kv, page, d), np.float32)
     vp = np.zeros_like(kp)
     for t in range(L):
         sp.ensure(t + 1, pool)
-        kp[sp.pages[t // page], t % page] = k_all[t]
-        vp[sp.pages[t // page], t % page] = v_all[t]
+        kp[sp.pages[t // page], :, t % page] = k_all[t]
+        vp[sp.pages[t // page], :, t % page] = v_all[t]
         table = np.full((1, pages_needed(L, page)), 0, np.int32)
         table[0, : len(sp.pages)] = sp.pages
         out = np.asarray(

@@ -113,3 +113,41 @@ def test_row_count_must_divide_block_rows():
     x, w, _ = _mk(R=60)  # 60 % 8 != 0
     with pytest.raises(ValueError, match="divisible"):
         gmm(x, w, jnp.zeros((8,), jnp.int32), block_rows=B, interpret=True)
+
+
+# -- VMEM tile plan (PR 21) --------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "name,args,want",
+    [
+        # (n, k, block_rows, x_bytes, w_bytes, out_bytes, scaled, acc_rows)
+        # moe-small (E=8, 768<->3072, bf16): the measured tile choices,
+        # unchanged by counting every resident tile, inside the default
+        ("moe-small up fwd", (3072, 768, 256, 2, 2, 2, False, 256), (1536, False)),
+        ("moe-small down fwd", (768, 3072, 256, 2, 2, 2, True, 256), (384, False)),
+        ("moe-small up dw", (3072, 768, 256, 2, 4, 2, False, 768), (1024, False)),
+        ("moe-small down dw", (768, 3072, 256, 2, 4, 2, True, 3072), (256, False)),
+        # mixtral-8x7b (4096<->14336): the double-buffered [256, 14336] row
+        # tile alone is 14.7 MB — narrowest tile AND a raised limit
+        ("mixtral up fwd", (14336, 4096, 256, 2, 2, 2, False, 256), (512, False)),
+        ("mixtral up dx", (4096, 14336, 256, 2, 2, 2, False, 256), (128, True)),
+        ("mixtral down dw", (4096, 14336, 256, 2, 4, 2, False, 14336), (128, True)),
+    ],
+)
+def test_plan_cols_counts_every_resident_tile(name, args, want):
+    from tf_operator_tpu.ops.grouped_matmul import (
+        _VMEM_ASK_MAX,
+        _VMEM_SCOPED_DEFAULT,
+        _plan_cols,
+        _resident_bytes,
+    )
+
+    bn, limit = _plan_cols(*args)
+    assert (bn, limit is not None) == want, name
+    n, k, br, xb, wb, ob, scaled, acc = args
+    need = _resident_bytes(br, k, bn, xb, wb, ob, scaled, acc)
+    if limit is None:
+        assert need <= _VMEM_SCOPED_DEFAULT
+    else:
+        assert _VMEM_SCOPED_DEFAULT < need < limit <= _VMEM_ASK_MAX

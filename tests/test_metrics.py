@@ -51,3 +51,45 @@ def test_long_context_correction_magnitude():
         cfg.n_active_params(), toks, cfg.n_layers, cfg.d_model, t
     )
     assert exact / six_nd < 1.10
+
+
+# -- no invented peak (PR 21) -----------------------------------------------
+
+
+class _Dev:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+def test_peak_is_keyed_by_device_kind():
+    from tf_operator_tpu.train.metrics import peak_flops_per_chip
+
+    assert peak_flops_per_chip(_Dev("tpu", "TPU v5 lite")) == 197e12
+    assert peak_flops_per_chip(_Dev("tpu", "TPU v4")) == 275e12
+
+
+def test_unknown_tpu_kind_is_an_error_not_a_default():
+    import pytest
+
+    from tf_operator_tpu.train.metrics import mfu, peak_flops_per_chip
+
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        peak_flops_per_chip(_Dev("tpu", "TPU v9 hyper"))
+    with pytest.raises(ValueError):
+        mfu(1e12, 1.0, 1, device=_Dev("tpu", "TPU v9 hyper"))
+    with pytest.raises(ValueError):  # a CPU has no peak to divide by
+        peak_flops_per_chip(_Dev("cpu", "cpu"))
+
+
+def test_off_tpu_there_is_no_mfu():
+    """The workloads log "n/a", not a number against an invented 1e12."""
+    from tf_operator_tpu.train.metrics import StepTimer, fmt_mfu, mfu
+
+    assert mfu(1e12, 1.0, 1, device=_Dev("cpu", "cpu")) is None
+    assert fmt_mfu(None) == "n/a" and fmt_mfu(0.5731) == "0.573"
+    assert mfu(197e12, 2.0, 1, device=_Dev("tpu", "TPU v5 lite")) == 0.5
+    timer = StepTimer(warmup=0)
+    timer.start()
+    timer.stop()
+    summary = timer.summary(flops_per_step=1e9)  # the tests' CPU backend
+    assert "mfu" not in summary and summary["tflops_per_chip"] > 0

@@ -137,6 +137,28 @@ def test_engine_is_deterministic(tiny_engine):
 
 
 @pytest.mark.serve
+@pytest.mark.parametrize("compiled", [False, True], ids=["jit", "aot"])
+def test_engine_agrees_with_unpaged_greedy_decoding(tiny_engine, compiled):
+    """The engine against the MODEL (not only attention against
+    attention): paged, cached, chunk-prefilled decoding picks the tokens
+    plain greedy decoding through transformer_forward picks — through
+    the lazy jit path and through the AOT executables ``compile()``
+    installs (what a warmed-up server runs)."""
+    from tf_operator_tpu.serve.engine import greedy_reference_gaps
+
+    if compiled:
+        report = tiny_engine.compile()
+        assert report["decode_tpu_custom_calls"] == 0  # CPU: no Mosaic
+        assert report["decode_compile_s"] >= 0
+    res = tiny_engine.run(_requests(), clock=_fake_clock())
+    for req in res.requests[:3]:
+        n_exact, max_gap = greedy_reference_gaps(
+            tiny_engine.cfg, tiny_engine.params, req.prompt, req.tokens
+        )
+        assert n_exact == len(req.tokens) and max_gap == 0.0
+
+
+@pytest.mark.serve
 def test_engine_rejects_impossible_requests(tiny_engine):
     from tf_operator_tpu.serve.engine import Request
 
@@ -250,6 +272,15 @@ def test_submit_workload_serve_builds_valid_job():
     assert worker.template.entrypoint.startswith(
         "tf_operator_tpu.workloads.serve"
     )
+    # the canned job runs on the machine's own backend (on a TPU host,
+    # the TPU) and asks for the chip it uses: no platform pin unless the
+    # caller passes one
+    assert "JAX_PLATFORMS" not in worker.template.env
+    assert worker.template.chips_per_process == 1
+    pinned = build_serve_job("cpu", env={"JAX_PLATFORMS": "cpu"})
+    assert pinned.spec.replica_specs[ReplicaType.WORKER].template.env == {
+        "JAX_PLATFORMS": "cpu"
+    }
     validate_job(job)
 
 

@@ -42,7 +42,6 @@ import logging
 import os
 import subprocess
 import sys
-import tempfile
 import threading
 import time
 from typing import Callable, Dict, Optional
@@ -198,38 +197,41 @@ class AOTCompiler:
     def _compile_topology(self, aot: Dict) -> bool:
         """Real AOT against a virtual TPU topology (no chips needed, but
         the TPU *compiler* — libtpu — must be importable). Runs hloprobe
-        in a subprocess with the persistent compilation cache pointed at
-        a scratch dir, then publishes every executable that landed under
-        jax's own cache keys."""
+        in a subprocess that writes the one persistent compilation cache
+        every process of this checkout uses (``compile_cache.cache_dir()``
+        — the environment's directory when it names one), then publishes
+        every executable the run ADDED under jax's own cache keys."""
+        from tf_operator_tpu.train import compile_cache
+
         topology = str(aot.get("topology", ""))
         self.client.announce(self._cache_key(aot))
-        scratch = tempfile.mkdtemp(prefix="tpujob-aot-")
-        try:
-            env = dict(os.environ)
-            env["JAX_COMPILATION_CACHE_DIR"] = scratch
-            env.setdefault("JAX_PLATFORMS", "cpu")
-            proc = subprocess.run(
-                [sys.executable, "-m", "tools.hloprobe",
-                 "--topology", topology],
-                env=env, capture_output=True, timeout=float(
-                    aot.get("timeout_s", 600)),
-                check=False,
+        cache = compile_cache.cache_dir()
+        os.makedirs(cache, exist_ok=True)
+        before = set(os.listdir(cache))
+        env = dict(os.environ)
+        env[compile_cache.ENV_DIR] = cache
+        env.setdefault("JAX_PLATFORMS", "cpu")
+        proc = subprocess.run(
+            [sys.executable, "-m", "tools.hloprobe",
+             "--topology", topology],
+            env=env, capture_output=True, timeout=float(
+                aot.get("timeout_s", 600)),
+            check=False,
+        )
+        if proc.returncode != 0:
+            log.warning(
+                "aot topology compile for %s failed (hloprobe rc=%d): %s",
+                topology, proc.returncode,
+                proc.stderr.decode(errors="replace")[-400:],
             )
-            if proc.returncode != 0:
-                log.info("aot topology compile for %s skipped (hloprobe rc=%d)",
-                         topology, proc.returncode)
-                self.stats["skipped"] += 1
-                return False
-            published = 0
-            for fname in os.listdir(scratch):
-                if not fname.endswith("-cache"):
-                    continue
-                with open(os.path.join(scratch, fname), "rb") as f:
-                    data = f.read()
-                if self.client.publish(fname[: -len("-cache")], data):
-                    published += 1
-            return published > 0
-        finally:
-            import shutil
-
-            shutil.rmtree(scratch, ignore_errors=True)
+            self.stats["skipped"] += 1
+            return False
+        published = 0
+        for fname in sorted(set(os.listdir(cache)) - before):
+            if not fname.endswith("-cache"):
+                continue
+            with open(os.path.join(cache, fname), "rb") as f:
+                data = f.read()
+            if self.client.publish(fname[: -len("-cache")], data):
+                published += 1
+        return published > 0

@@ -388,9 +388,7 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh):
         # shards over dp/fsdp and heads over tp with no collectives. A
         # sequence-sharded (cp) mesh needs ring attention instead.
         if mesh is not None and mesh.devices.size > 1:
-            from tf_operator_tpu.parallel.collectives import (
-                shard_map_compat as shard_map,
-            )
+            from tf_operator_tpu.parallel.collectives import shard_map
             from jax.sharding import PartitionSpec as P
 
             batch = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names) or None

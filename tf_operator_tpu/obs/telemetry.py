@@ -601,12 +601,11 @@ class StepTelemetry:
     def _mfu(self, mean_step_s: float) -> float:
         if not self.flops_per_step or mean_step_s <= 0:
             return 0.0
-        try:
-            from tf_operator_tpu.train.metrics import mfu
+        from tf_operator_tpu.train.metrics import mfu
 
-            return float(mfu(self.flops_per_step, mean_step_s, self.n_chips))
-        except Exception:  # noqa: BLE001 — no jax / no device: stay finite
-            return float(self.flops_per_step / (mean_step_s * self.n_chips * 1e12))
+        # off-TPU there is no MFU: the gauge stays 0, not a number
+        # against an invented peak
+        return float(mfu(self.flops_per_step, mean_step_s, self.n_chips) or 0.0)
 
     # -- on-demand profiling ------------------------------------------------
 

@@ -53,6 +53,23 @@ NEG_INF = -1e30  # large-negative instead of -inf: exp() of a whole masked
 LSE_LANES = 128  # lse/delta carry a full lane dim to satisfy TPU tiling
 
 
+_said: set = set()
+
+
+def _say_reference(op: str, why: str) -> None:
+    """A TPU run that was handed the jnp reference instead of the kernel
+    says so, once per distinct reason — a fallback nobody can see is how
+    a slow program gets measured as if it were the kernel's."""
+    if (op, why) not in _said:
+        _said.add((op, why))
+        import logging
+
+        logging.getLogger(__name__).warning(
+            "%s: running the jnp reference on a TPU, not the Pallas "
+            "kernel (%s)", op, why,
+        )
+
+
 def _use_kernel(t: int, d: int, block_q: int, block_k: int, interpret: bool) -> bool:
     if t % block_q or t % block_k:
         return False  # kernels assume exact tiling; odd lengths fall back
@@ -625,6 +642,12 @@ def flash_attention(
                                       force_kernel)
     q, k, v = _tag_inputs(q, k, v)
     if not use:
+        if force_kernel is None and jax.default_backend() == "tpu":
+            _say_reference(
+                "flash_attention",
+                f"t={q.shape[1]} d={q.shape[3]} blocks=({block_q},{block_k}) "
+                "does not tile, or is under the hd=64 crossover",
+            )
         return reference_attention(q, k, v, causal=causal)
     # One custom-vjp entry serves both public surfaces (the lse output is
     # a residual either way, so dropping it here costs nothing).
@@ -652,23 +675,28 @@ def paged_decode_reference(q, k_pages, v_pages, page_table, seq_lens):
     off-TPU fallback (same contract as the decode kernel).
 
     q [s, h, d] (one query token per sequence), k_pages/v_pages
-    [n_pages, page_size, h_kv, d], page_table [s, p] int32 (page ids in
+    [n_pages, h_kv, page_size, d], page_table [s, p] int32 (page ids in
     sequence order; rows padded with any valid id past the live prefix),
     seq_lens [s] int32 = valid K/V tokens per sequence INCLUDING the
-    current position. Gathers pages to [s, p·page_size, h_kv, d], masks
+    current position. Gathers pages to [s, h_kv, p·page_size, d], masks
     positions >= seq_len with the NEG_INF sentinel, f32 softmax. Rows
     with seq_len == 0 produce the uniform-softmax artifact (see
     reference_attention_lse) — callers mask inactive slots out."""
     s_n, h, d = q.shape
-    n_pages, page_size, h_kv, _ = k_pages.shape
+    n_pages, h_kv, page_size, _ = k_pages.shape
     p = page_table.shape[1]
     g = h // h_kv
     scale = d**-0.5
-    k = k_pages[page_table].reshape(s_n, p * page_size, h_kv, d)
-    v = v_pages[page_table].reshape(s_n, p * page_size, h_kv, d)
+
+    def gather(pages):  # [s, p, h_kv, page, d] -> [s, h_kv, p·page, d]
+        return jnp.swapaxes(pages[page_table], 1, 2).reshape(
+            s_n, h_kv, p * page_size, d
+        )
+
+    k, v = gather(k_pages), gather(v_pages)
     q5 = q.reshape(s_n, h_kv, g, d).astype(jnp.float32) * scale
     s = jnp.einsum(
-        "shgd,sthd->shgt", q5, k.astype(jnp.float32),
+        "shgd,shtd->shgt", q5, k.astype(jnp.float32),
         preferred_element_type=jnp.float32,
     )  # [s, h_kv, g, t]
     kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 3)
@@ -677,7 +705,7 @@ def paged_decode_reference(q, k_pages, v_pages, page_table, seq_lens):
     pr = jnp.exp(s - m)
     l = jnp.sum(pr, axis=-1, keepdims=True)
     out = jnp.einsum(
-        "shgt,sthd->shgd", pr / l, v.astype(jnp.float32),
+        "shgt,shtd->shgd", pr / l, v.astype(jnp.float32),
         preferred_element_type=jnp.float32,
     )
     return out.reshape(s_n, h, d).astype(q.dtype)
@@ -710,8 +738,8 @@ def _decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(live)
     def _step():
         q = q_ref[0, 0].reshape(g, d).astype(jnp.float32) * scale
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [page_size, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)  # [page_size, d]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [g, page_size]
@@ -741,7 +769,7 @@ def _decode_call(q, k_pages, v_pages, page_table, seq_lens, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     s_n, h, d = q.shape
-    _, page_size, h_kv, _ = k_pages.shape
+    _, h_kv, page_size, _ = k_pages.shape
     p = page_table.shape[1]
     g = h // h_kv
     q4 = q.reshape(s_n, h_kv, g, d)
@@ -749,19 +777,22 @@ def _decode_call(q, k_pages, v_pages, page_table, seq_lens, interpret):
     # Scalar-prefetch args (page_table, seq_lens) reach the index_maps as
     # TRAILING refs after the grid indices — the K/V source block for
     # grid step (si, hk, pi) is whatever page the table names, which is
-    # the whole paging trick.
+    # the whole paging trick. One K/V block is one (page, kv-head) slab
+    # [page_size, d]: the tiled minor dims Mosaic requires of a block
+    # (sublane-aligned page, whole head_dim) — the reason the pools are
+    # laid out [n_pages, h_kv, page_size, d].
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s_n, h_kv, p),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda si, hk, pi, pt, sl: (si, hk, 0, 0)),
             pl.BlockSpec(
-                (1, page_size, 1, d),
-                lambda si, hk, pi, pt, sl: (pt[si, pi], 0, hk, 0),
+                (1, 1, page_size, d),
+                lambda si, hk, pi, pt, sl: (pt[si, pi], hk, 0, 0),
             ),
             pl.BlockSpec(
-                (1, page_size, 1, d),
-                lambda si, hk, pi, pt, sl: (pt[si, pi], 0, hk, 0),
+                (1, 1, page_size, d),
+                lambda si, hk, pi, pt, sl: (pt[si, pi], hk, 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -798,8 +829,10 @@ def flash_attention_decode(
     """Paged decode attention: one query token per sequence against a
     paged K/V cache.
 
-    q [s, h, d]; k_pages/v_pages [n_pages, page_size, h_kv, d] (the
-    serve/kvcache.py pool layout); page_table [s, max_pages] int32;
+    q [s, h, d]; k_pages/v_pages [n_pages, h_kv, page_size, d] (the
+    serve/kvcache.py pool layout — head-major so one (page, kv-head)
+    slab is a tile-aligned [page_size, d] block the TPU compiler
+    accepts); page_table [s, max_pages] int32;
     seq_lens [s] int32 (valid K/V length per sequence, INCLUDING the
     just-written current position — decode attends to itself). Returns
     [s, h, d] in q's dtype. GQA-native: h % h_kv folds into the q tile
@@ -807,9 +840,11 @@ def flash_attention_decode(
 
     Dispatch mirrors flash_attention: the Pallas kernel engages on TPU
     (or under ``interpret=True`` — the CPU test path) when the page size
-    is sublane-aligned; otherwise the pure-JAX gather reference (same
-    math, same f32 softmax, same NEG_INF masking) — the documented
-    off-TPU path, so the serve engine runs everywhere. ``force_kernel``
+    is sublane-aligned for the pool dtype (8 rows of f32, 16 of bf16);
+    otherwise the pure-JAX gather reference (same math, same f32
+    softmax, same NEG_INF masking) — the off-TPU path, so the serve
+    engine runs everywhere. A TPU run that takes the reference says so
+    once in the log (_say_reference). ``force_kernel``
     overrides the heuristic both ways (alignment still binds). Rows with
     seq_lens == 0 are inactive slots: both paths return garbage-but-
     finite output there (zeros from the kernel, the uniform artifact
@@ -817,21 +852,27 @@ def flash_attention_decode(
     if q.ndim != 3 or k_pages.ndim != 4:
         raise ValueError(
             f"decode shapes: q [s,h,d] (got {q.shape}), pages "
-            f"[n,page,h_kv,d] (got {k_pages.shape})"
+            f"[n,h_kv,page,d] (got {k_pages.shape})"
         )
     if k_pages.shape != v_pages.shape:
         raise ValueError(f"k/v pool mismatch: {k_pages.shape} vs {v_pages.shape}")
-    h, h_kv = q.shape[1], k_pages.shape[2]
+    h, h_kv = q.shape[1], k_pages.shape[1]
     if h % h_kv:
         raise ValueError(f"q heads {h} not a multiple of kv heads {h_kv}")
-    page_size = k_pages.shape[1]
-    aligned = page_size % 8 == 0
-    use = aligned and (bool(interpret) or jax.default_backend() == "tpu")
+    page_size = k_pages.shape[2]
+    sublanes = 8 * max(1, 4 // jnp.dtype(k_pages.dtype).itemsize)
+    aligned = page_size % sublanes == 0
+    on_tpu = jax.default_backend() == "tpu"
+    use = aligned and (bool(interpret) or on_tpu)
     if force_kernel is not None:
-        use = force_kernel and aligned and (
-            bool(interpret) or jax.default_backend() == "tpu"
-        )
+        use = force_kernel and use
     if not use:
+        if on_tpu and force_kernel is None:
+            _say_reference(
+                "flash_attention_decode",
+                f"page_size={page_size} is not a multiple of {sublanes} "
+                f"({jnp.dtype(k_pages.dtype).name} sublanes)",
+            )
         return paged_decode_reference(q, k_pages, v_pages, page_table, seq_lens)
     return _decode_call(
         q, k_pages, v_pages, page_table, seq_lens, bool(interpret)

@@ -74,13 +74,74 @@ def _pick_cols(n: int, target: int) -> int:
     return n
 
 
-def _auto_cols(n: int, k: int, elem_bytes: int) -> int:
-    """Column tile bounded by a ~4 MB VMEM budget for the [k, bn] weight
-    tile (fwd) or the f32 [k, bn] accumulator (dw). Wider is faster:
-    full-width tiles measured 60.6 TFLOP/s vs 52.0 at bn=512 on the
-    moe-small shapes (98% of XLA's same-FLOPs dense rate) — the
-    per-grid-step dot is what feeds the MXU."""
-    return _pick_cols(n, max(128, (4 * 2**20) // (elem_bytes * k)))
+# Scoped VMEM a kernel gets without asking (v5e/v6e default) and the most
+# it may ask for: the chip's VMEM is 128 MiB; a quarter stays with the
+# compiler for its own temporaries (the f32 dot result, spills).
+_VMEM_SCOPED_DEFAULT = 16 * 2**20
+_VMEM_ASK_MAX = 96 * 2**20
+
+
+def _resident_bytes(block_rows: int, k: int, bn: int, x_bytes: int,
+                    w_bytes: int, out_bytes: int, scaled: bool,
+                    acc_rows: int) -> int:
+    """VMEM one grid step keeps resident: the [block_rows, k] row tile,
+    the [k, bn] weight tile (fwd/dx) or f32 accumulator (dw), the
+    [block_rows, bn] output (fwd/dx) or dy (dw) tile and, when scaled,
+    the [block_rows, 1] scale column padded to a lane tile — each TWICE,
+    because the pipeline double-buffers every blocked operand — plus the
+    f32 [acc_rows, bn] product the dot leaves before the cast (fwd/dx:
+    block_rows) or the accumulate (dw: k)."""
+    tiles = (
+        block_rows * k * x_bytes
+        + k * bn * w_bytes
+        + block_rows * bn * out_bytes
+        + (block_rows * 128 * 4 if scaled else 0)
+    )
+    return 2 * tiles + 4 * acc_rows * bn
+
+
+def _plan_cols(n: int, k: int, block_rows: int, x_bytes: int, w_bytes: int,
+               out_bytes: int, scaled: bool, acc_rows: int, block_cols=None):
+    """(column tile, vmem_limit_bytes or None) for one gmm kernel.
+
+    The tile starts from the measured rule — a ~4 MB [k, bn] weight tile
+    (fwd/dx) or f32 accumulator (dw); wider is faster: full-width tiles
+    measured 60.6 TFLOP/s vs 52.0 at bn=512 on the moe-small shapes —
+    and narrows (128-aligned divisors of n) until EVERYTHING the step
+    keeps resident fits the scoped default. Where even the narrowest
+    tile does not (k=14336 at mixtral-8x7b widths: the double-buffered
+    [256, k] row tile alone is 14.7 MB) the kernel asks the compiler for
+    what it needs, up to _VMEM_ASK_MAX; above that it is refused here,
+    by name, not by a Mosaic allocation error."""
+    budget = _VMEM_SCOPED_DEFAULT * 7 // 8
+    if block_cols is not None:
+        cands = [_pick_cols(n, block_cols)]
+    else:
+        bn = _pick_cols(n, max(128, (4 * 2**20) // (w_bytes * k)))
+        cands = [bn] + [c for c in range(bn - bn % 128, 127, -128)
+                        if c < bn and n % c == 0]
+    for bn in cands:
+        need = _resident_bytes(block_rows, k, bn, x_bytes, w_bytes,
+                               out_bytes, scaled, acc_rows)
+        if need <= budget:
+            return bn, None
+    if need * 5 // 4 > _VMEM_ASK_MAX:
+        raise ValueError(
+            f"gmm: k={k}, n={n}, block_rows={block_rows} keeps "
+            f"{need / 2**20:.1f} MiB resident in VMEM at its narrowest "
+            f"column tile ({bn}); the kernel has no contraction tiling, so "
+            f"it is good up to ~{_VMEM_ASK_MAX // 2**20} MiB — lower "
+            "block_rows or the contraction width"
+        )
+    return bn, need * 5 // 4
+
+
+def _compiler_params(vmem_limit):
+    from jax.experimental.pallas import tpu as pltpu
+
+    if vmem_limit is None:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=int(vmem_limit))
 
 
 def gmm(x, w, block_expert, *, row_scale=None, block_rows: int = 256,
@@ -175,14 +236,11 @@ def _gmm_call(x, w, row_scale, block_expert, block_rows, block_cols,
         raise ValueError(f"contraction mismatch: x k={k} vs w k={k2}")
     if R % block_rows:
         raise ValueError(f"rows {R} not divisible by block_rows {block_rows}")
-    # Budget on the INPUT's element size (not a hardcoded bf16 2): an f32
-    # x/w would otherwise get a [k, bn] weight tile 2x the 4 MB budget
-    # and fail VMEM-exceeded at compile (the dw path already budgets on
-    # its f32 accumulator's 4 bytes).
-    bn = (
-        _auto_cols(n, k, x.dtype.itemsize)
-        if block_cols is None
-        else _pick_cols(n, block_cols)
+    # Budget on the operands' own element sizes (an f32 x/w doubles
+    # every tile) and on every tile the step keeps, not the weight alone.
+    bn, vmem_limit = _plan_cols(
+        n, k, block_rows, x.dtype.itemsize, w.dtype.itemsize,
+        x.dtype.itemsize, row_scale is not None, block_rows, block_cols,
     )
     nb = R // block_rows
 
@@ -215,6 +273,7 @@ def _gmm_call(x, w, row_scale, block_expert, block_rows, block_cols,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, n), x.dtype),
+        compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
     )(*operands)
 
@@ -293,7 +352,10 @@ def _gmm_dw(x, dy, w_shape, block_expert, block_rows, block_cols, interpret,
     E, k2, n = w_shape
     # dw accumulates in an f32 [k, bn] output tile held across the inner
     # block walk — budget on 4 bytes, not the bf16 fwd tile
-    bn = _auto_cols(n, k, 4) if block_cols is None else _pick_cols(n, block_cols)
+    bn, vmem_limit = _plan_cols(
+        n, k, block_rows, x.dtype.itemsize, 4, dy.dtype.itemsize,
+        row_scale is not None, k, block_cols,
+    )
     nb = R // block_rows
     nblocks, blist = _expert_block_lists(block_expert, E, nb)
 
@@ -326,6 +388,7 @@ def _gmm_dw(x, dy, w_shape, block_expert, block_rows, block_cols, interpret,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((E, k, n), jnp.float32),
+        compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
     )(*operands)
 

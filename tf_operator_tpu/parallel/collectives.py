@@ -13,29 +13,14 @@ import functools
 import jax
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across the jax versions this repo actually meets:
-    the public ``jax.shard_map`` (``check_vma=`` kwarg) where it exists,
-    else ``jax.experimental.shard_map.shard_map`` (``check_rep=``).
-    Replication/VMA checking is disabled either way — the shard bodies
-    reduce their own stats with explicit psum/pmean, which the checker
-    cannot see through custom_vjp boundaries. Every shard_map in the
-    parallelism layer routes through here so a jax upgrade (or the CI
-    container's older pin) changes exactly one line, not six call
-    sites."""
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:  # jax versions where the public API still says check_rep
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with VMA checking off — the shard bodies reduce
+    their own stats with explicit psum/pmean, which the checker cannot
+    see through custom_vjp boundaries. Every shard_map in the
+    parallelism layer routes through here so that policy is stated
+    once."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def all_reduce_mean(x, axis_name: str):

@@ -632,9 +632,7 @@ def moe_apply(
     warning). Same queue semantics for sort/einsum, same drop patterns,
     same stats (pinned by the impl-parity tests); the end-to-end win is
     recorded in BASELINE.md."""
-    from tf_operator_tpu.parallel.collectives import (  # noqa: F401
-        shard_map_compat as shard_map,
-    )
+    from tf_operator_tpu.parallel.collectives import shard_map
 
     if dispatch_impl not in ("sort", "einsum", "ragged", "gmm"):
         raise ValueError(f"unknown dispatch_impl {dispatch_impl!r}")

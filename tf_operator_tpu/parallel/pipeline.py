@@ -397,9 +397,7 @@ def pipeline_apply(
     nothing crosses dp), while stage params shard over ``axis_name`` (+ tp
     when param_specs say so) and replicate over the data axes.
     """
-    from tf_operator_tpu.parallel.collectives import (  # noqa: F401
-        shard_map_compat as shard_map,
-    )
+    from tf_operator_tpu.parallel.collectives import shard_map
 
     batch = x.shape[0]
     if n_chunks > 1:
@@ -486,9 +484,7 @@ def _apply_1f1b(stage_params, x_micro, fn, mesh, axis_name, x_spec, param_specs,
     reshape on the way back), specs shift to P(None, axis_name, …), and
     the local tick bodies see chunk-major [v, ...] params. v = 1 keeps
     the [S, ...] layout where the local [1, ...] block IS chunk-major."""
-    from tf_operator_tpu.parallel.collectives import (  # noqa: F401
-        shard_map_compat as shard_map,
-    )
+    from tf_operator_tpu.parallel.collectives import shard_map
 
     fn2 = _with_aux(fn, aux_size)
     k = max(aux_size, 1)

@@ -207,9 +207,7 @@ def ring_attention(
     oracle body, materializes per-hop scores). ``interpret`` forces the
     flash path's kernels through the Pallas interpreter (CPU tests).
     """
-    from tf_operator_tpu.parallel.collectives import (  # noqa: F401
-        shard_map_compat as shard_map,
-    )
+    from tf_operator_tpu.parallel.collectives import shard_map
 
     cp = mesh.shape[axis_name]
     if q.shape[1] != k.shape[1] or k.shape[1] != v.shape[1]:

@@ -120,9 +120,7 @@ def ulysses_attention(
     then h/cp query vs n_kv/cp kv heads — the flash kernel and the
     grouped dense default both do; an MHA-only attn_fn is safe only for
     equal-head models)."""
-    from tf_operator_tpu.parallel.collectives import (  # noqa: F401
-        shard_map_compat as shard_map,
-    )
+    from tf_operator_tpu.parallel.collectives import shard_map
 
     cp = mesh.shape[axis_name]
     b, t, h, d = q.shape

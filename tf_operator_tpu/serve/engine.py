@@ -119,6 +119,42 @@ class RunResult:
         return out
 
 
+def greedy_reference_gaps(cfg, params, prompt: List[int], tokens: List[int]):
+    """Hold one finished request against plain un-paged greedy decoding.
+
+    The reference is ``models.transformer.transformer_forward`` — no
+    pages, no KV cache, dense attention — on the same weights in f32 at
+    the highest matmul precision, run ONCE over prompt + generated tokens
+    (teacher-forced: causal attention makes row i exactly what greedy
+    decoding would have seen when it chose token i). Returns
+    ``(n_exact, max_gap)``: how many of the engine's tokens are the
+    reference's argmax, and the largest amount by which the reference's
+    best logit exceeds the logit of the token the engine chose (0.0 when
+    every token is exact). Random weights leave thin top-2 margins, so a
+    caller states a tolerance on ``max_gap`` rather than demanding token
+    equality at every position."""
+    from dataclasses import replace
+
+    import jax
+    import jax.numpy as jnp
+
+    from tf_operator_tpu.models.transformer import transformer_forward
+
+    ref_cfg = replace(cfg, dtype=jnp.float32, remat=False, attn_impl="dense")
+    f32 = jax.tree_util.tree_map(lambda a: jnp.asarray(a, jnp.float32), params)
+    seq = jnp.asarray([list(prompt) + list(tokens[:-1])], jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        logits = jax.jit(
+            lambda p, t: transformer_forward(p, t, ref_cfg)
+        )(f32, seq)[0]
+    rows = logits[len(prompt) - 1:]  # row i chose tokens[i]
+    chosen = jnp.take_along_axis(
+        rows, jnp.asarray(tokens, jnp.int32)[:, None], axis=1
+    )[:, 0]
+    gaps = jnp.max(rows, axis=-1) - chosen
+    return int(jnp.sum(gaps == 0.0)), float(jnp.max(gaps))
+
+
 class _Slot:
     __slots__ = ("req", "pages", "seq_len", "prefill_pos", "cur_tok", "generated")
 
@@ -187,8 +223,11 @@ class ServeEngine:
                 v = (h @ lp["wv"][l]).reshape(n, -1, hd)
                 q = rope_at_positions(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
                 k = rope_at_positions(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-                kp = kp.at[l, write_pid, write_row].set(k)
-                vp = vp.at[l, write_pid, write_row].set(v)
+                # pools are [L, page, h_kv, row, hd]: the two index
+                # arrays straddle the head slice, so the indexed result
+                # is [n, h_kv, hd] — k/v's own shape
+                kp = kp.at[l, write_pid, :, write_row].set(k)
+                vp = vp.at[l, write_pid, :, write_row].set(v)
                 attn = flash_attention_decode(q, kp[l], vp[l], table, lens)
                 x = x + attn.reshape(n, -1) @ lp["wo"][l]
                 h2 = _rms_norm(x, lp["mlp_norm"][l], cfg.norm_eps)
@@ -234,14 +273,57 @@ class ServeEngine:
         self._decode = jax.jit(decode_step, donate_argnums=(1, 2))
         self._prefill = jax.jit(prefill_chunk, donate_argnums=(1, 2))
 
+    def compile(self) -> Dict[str, Any]:
+        """AOT-compile both step programs at the engine's fixed shapes
+        and serve with the compiled executables from here on: a server
+        warms up before it takes traffic, so no request's TTFT carries a
+        compile, and a program the device's compiler refuses fails here,
+        by name. Returns each program's compile seconds and how many
+        ``tpu_custom_call``s (Pallas kernels) its compiled text holds —
+        0 means the step runs the gather reference, not the kernel."""
+        import jax
+        import jax.numpy as jnp
+
+        scfg = self.scfg
+
+        def arr(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        kp, vp = (arr(self._pool_shape(), jnp.float32),) * 2
+        s_n, p = scfg.max_slots, self.max_pages_per_seq
+        i32 = jnp.int32
+        programs = {
+            "decode": (self._decode, (
+                self.params, kp, vp, arr((s_n, p), i32), arr((s_n,), i32),
+                arr((s_n,), i32), arr((s_n,), jnp.bool_),
+            )),
+            "prefill": (self._prefill, (
+                self.params, kp, vp, arr((p,), i32), arr((), i32),
+                arr((scfg.prefill_chunk,), i32), arr((), i32),
+            )),
+        }
+        out: Dict[str, Any] = {}
+        for name, (fn, args) in programs.items():
+            t0 = time.perf_counter()
+            compiled = fn.lower(*args).compile()
+            out[f"{name}_compile_s"] = round(time.perf_counter() - t0, 3)
+            out[f"{name}_tpu_custom_calls"] = compiled.as_text().count(
+                "tpu_custom_call"
+            )
+            setattr(self, f"_{name}", compiled)
+        return out
+
+    def _pool_shape(self):
+        cfg, scfg = self.cfg, self.scfg
+        return (
+            cfg.n_layers, scfg.pool_pages + 1, cfg.n_kv_heads,
+            scfg.page_size, cfg.head_dim,
+        )
+
     def _fresh_pools(self):
         import jax.numpy as jnp
 
-        cfg, scfg = self.cfg, self.scfg
-        shape = (
-            cfg.n_layers, scfg.pool_pages + 1, scfg.page_size,
-            cfg.n_kv_heads, cfg.head_dim,
-        )
+        shape = self._pool_shape()
         return jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
 
     # -- the scheduler loop ----------------------------------------------

@@ -2,8 +2,10 @@
 
 The vLLM (SOSP '23) memory model in jax_graft form: decode K/V state
 lives in PAGES of ``page_size`` token slots, preallocated as one device
-pool per layer side — shape [n_layers, num_pages + 1, page_size,
-n_kv_heads, head_dim]. A sequence owns an ordered page table (host-side
+pool per layer side — shape [n_layers, num_pages + 1, n_kv_heads,
+page_size, head_dim] (head-major inside a page: the decode kernel's
+block is one (page, kv-head) slab [page_size, head_dim], which is what
+the TPU compiler can tile). A sequence owns an ordered page table (host-side
 int32 row); growing by one token touches exactly one page row, and
 completion returns the pages to a free list with NO copying — the next
 sequence overwrites them in place (pages carry no ownership state on

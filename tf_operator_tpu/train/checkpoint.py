@@ -601,30 +601,14 @@ class CheckpointManager:
                 abstract
             )
             try:
-                try:
-                    restored = mgr.restore(
-                        int(step),
-                        args=self._ocp.args.PyTreeRestore(
-                            item=abstract,
-                            restore_args=restore_args,
-                            partial_restore=True,
-                        ),
-                    )
-                except TypeError:
-                    # orbax < 0.9: PyTreeRestore has no partial_restore
-                    # kwarg; the (deprecated-but-kept) transformations API
-                    # spells the same contract — item defines the subset,
-                    # transforms={} says "no renames, drop the rest"
-                    # (r6: previously this raised and evaluators silently
-                    # scored nothing on such containers)
-                    restored = mgr.restore(
-                        int(step),
-                        args=self._ocp.args.PyTreeRestore(
-                            item=abstract,
-                            restore_args=restore_args,
-                            transforms={},
-                        ),
-                    )
+                restored = mgr.restore(
+                    int(step),
+                    args=self._ocp.args.PyTreeRestore(
+                        item=abstract,
+                        restore_args=restore_args,
+                        partial_restore=True,
+                    ),
+                )
             finally:
                 mgr.close()
             return {k: restored[k] for k in templates}
@@ -747,6 +731,10 @@ class WorkloadCheckpointer:
         self.save_stalls: List[float] = []
         # "peer" | "disk" after a warm restore; "" cold / not restored.
         self.restore_source = ""
+        # Losses of this run's first LOSS_TRACE_STEPS dispatches, kept as
+        # device scalars (no sync in the step loop) — loss_trace() fetches
+        # them after the loop for the worker's run report.
+        self._loss_trace: List[Any] = []
 
     # -- peer warm-restore protocol (rendezvous/statechannel.py) ----------
 
@@ -878,6 +866,17 @@ class WorkloadCheckpointer:
                 )
             if self.manager.save(self._step, state):
                 self._note_save_stall(self._step)
+
+    LOSS_TRACE_STEPS = 32
+
+    def _trace_loss(self, loss) -> None:
+        if len(self._loss_trace) < self.LOSS_TRACE_STEPS:
+            self._loss_trace.append(loss)
+
+    def loss_trace(self) -> List[float]:
+        """The first dispatches' losses as floats (one per step, or per
+        chunk under device_loop) — call after run_loop, it syncs."""
+        return [float(x) for x in self._loss_trace]
 
     def _note_save_stall(self, step: int) -> None:
         """Record how long the step loop was actually blocked by the save
@@ -1031,6 +1030,7 @@ class WorkloadCheckpointer:
             else:
                 chunk, stacked = pull_chunk(k)
                 state, m = trainer.multi_step(state, chunk, k, stacked=stacked)
+            self._trace_loss(m["loss"])
             self.advance(state, loss=m["loss"], n=k)
             self.poll_cadence_directive()  # cadence retune lands at chunk boundary
             if on_step is not None:
@@ -1046,6 +1046,7 @@ class WorkloadCheckpointer:
         # (at least one chunk stays timed); a novel tail size can still
         # compile in-region, but a tail is by construction small.
         state, m = trainer.step(state, pull())
+        self._trace_loss(m["loss"])
         self.advance(state, loss=m["loss"])
         if on_step is not None:
             on_step(self._step)

@@ -22,7 +22,13 @@ is recorded in ``stats()`` and surfaced as a span attribute by
 
 ``enable()`` is called by the rendezvous harness before user ``train_fn``
 runs (every operator-launched process gets it), and by ``bench.py``. Safe
-to call multiple times; honors an explicit ``JAX_COMPILATION_CACHE_DIR``.
+to call multiple times.
+
+Where the cache lives is decided from OUTSIDE the program, in one place
+(``cache_dir()``): ``JAX_COMPILATION_CACHE_DIR`` when set, else one fixed
+path inside the checkout (``<repo>/.cache/xla``, git-ignored). The path is
+part of what makes a second run hit, so nothing in the tree passes its
+own directory, and nothing lands under the user's home or a temp name.
 """
 
 from __future__ import annotations
@@ -36,7 +42,8 @@ from typing import Callable, Dict, Optional
 log = logging.getLogger("tpujob.compile_cache")
 
 DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "tf_operator_tpu", "xla"
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".cache", "xla",
 )
 ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
 ENV_DISABLE = "TPUJOB_NO_COMPILE_CACHE"
@@ -58,6 +65,12 @@ _stats = {
     "local_hits": 0, "remote_hits": 0, "misses": 0,
     "local_puts": 0, "remote_puts": 0,
 }
+
+
+def cache_dir() -> str:
+    """The one directory both cache tiers write: the environment's when
+    it names one, else the fixed in-checkout path."""
+    return os.environ.get(ENV_DIR) or DEFAULT_CACHE_DIR
 
 
 def _digest_path(cache_path):
@@ -163,10 +176,11 @@ def stats() -> Dict[str, object]:
 
 def _remote_jax_tier_active() -> bool:
     """The shared tier for JAX-PRODUCED executables. cpu-pinned processes
-    are excluded UNCONDITIONALLY (not even ENV_FORCE overrides): jaxlib
-    CPU executables embed process-local state, so publishing one to the
-    fleet weaponizes the r10 crash across hosts. force only re-enables
-    the LOCAL cache for machinery tests."""
+    are excluded UNCONDITIONALLY (not even ENV_FORCE overrides): an
+    XLA:CPU executable is built for the compiling host's instruction set
+    (see enable()), so one host's entry must never reach another through
+    the fleet. force only re-enables the LOCAL cache for machinery
+    tests."""
     return _remote is not None and not _cpu_only_platform()
 
 
@@ -215,15 +229,12 @@ def _harden_cache_io() -> None:
       fleet tier (sha256-checked again in transfer) and lands the entry
       locally before returning it.
 
-    Private-API patch, same caveat and best-effort guard as the
-    ``reset_cache()`` call in ``enable()`` below."""
+    Private-API patch (``jax._src.lru_cache.LRUCache``; signatures as
+    of jax 0.9.0, the one installation this tree is written for)."""
     global _hardened
     if _hardened:
         return
-    try:
-        from jax._src.lru_cache import LRUCache
-    except ImportError:
-        return
+    from jax._src.lru_cache import LRUCache
 
     orig_put, orig_get = LRUCache.put, LRUCache.get
 
@@ -302,7 +313,6 @@ def _cpu_only_platform() -> bool:
 def cached_compile(
     key_material: str,
     compile_fn: Callable[[], bytes],
-    cache_dir: Optional[str] = None,
     wait_s: Optional[float] = None,
 ) -> tuple:
     """Generic read-through/write-back compile against both cache tiers,
@@ -322,9 +332,7 @@ def cached_compile(
     import pathlib
 
     key = hashlib.sha256(key_material.encode()).hexdigest()
-    root = pathlib.Path(
-        cache_dir or os.environ.get(ENV_DIR) or DEFAULT_CACHE_DIR
-    )
+    root = pathlib.Path(cache_dir())
     try:
         root.mkdir(parents=True, exist_ok=True)
     except OSError:
@@ -369,10 +377,11 @@ def cached_compile(
     return val, "compiled"
 
 
-def enable(cache_dir: str | None = None, force: bool = False) -> str | None:
-    """Turn on the persistent compilation cache; returns the directory in
-    use, or None when disabled via TPUJOB_NO_COMPILE_CACHE=1 or because
-    the process is pinned to the CPU backend.
+def enable(force: bool = False) -> str | None:
+    """Turn on the persistent compilation cache at ``cache_dir()``;
+    returns the directory in use, or None when disabled via
+    TPUJOB_NO_COMPILE_CACHE=1 or because the process is pinned to the CPU
+    backend.
 
     When the controller stamped a compile-cache service URL
     (TPUJOB_COMPILE_CACHE, cli/operator.py), the hardened cache I/O also
@@ -380,20 +389,20 @@ def enable(cache_dir: str | None = None, force: bool = False) -> str | None:
     cpu-pinned processes, where even force leaves the remote tier off
     (see below).
 
-    CPU is excluded (r10, root-caused by the serve preemption probe):
-    jaxlib 0.4.x serializes CPU executables with process-local state
-    (custom-call pointers), so an entry deserialized by a DIFFERENT
-    process than the one that compiled it can execute as heap
-    corruption — observed as warm-restarted trainers dying with
-    SIGSEGV/SIGABRT ("corrupted double-linked list") or, worse,
-    silently computing garbage that trips the non-finite-loss
-    checkpoint gate. Bit-identical entries reproduce it: the writing
-    process runs fine, a second identical process reading the entry
-    crashes. The cache is a TPU submit-latency lever; on CPU (tests,
-    local benches) compiles are cheap and correctness wins.
-    ``force=True`` / TPUJOB_FORCE_COMPILE_CACHE=1 override for cache
-    machinery tests — the override re-enables only the LOCAL tier;
-    process-local executables must never enter the shared one."""
+    CPU is excluded. The r10 reason — entries read back by another
+    process crashing it — was re-checked on jax/jaxlib 0.9.0 and no
+    longer reproduces (a second process took 5/5 hits on the tiny LM
+    step and computed identical losses). What is true today: XLA:CPU
+    reloads an entry through its AOT loader, which logs an error on
+    every hit that the compile machine's features do not match the
+    host's ("could lead to execution errors such as SIGILL") — the
+    executable is specific to the CPU that compiled it, and a checkout's
+    cache outlives the machine. The cache is a TPU submit-latency lever;
+    on CPU (tests, local benches) compiles are cheap, so there is
+    nothing to buy with that risk. ``force=True`` /
+    TPUJOB_FORCE_COMPILE_CACHE=1 override for cache machinery tests —
+    the override re-enables only the LOCAL tier; host-specific
+    executables must never enter the shared one."""
     if os.environ.get(ENV_DISABLE, "") == "1":
         return None
     from tf_operator_tpu.rendezvous.env import ENV_COMPILE_CACHE
@@ -403,7 +412,7 @@ def enable(cache_dir: str | None = None, force: bool = False) -> str | None:
     if not force and os.environ.get(ENV_FORCE, "") != "1" and _cpu_only_platform():
         log.debug("persistent compilation cache disabled on cpu-only backend")
         return None
-    path = cache_dir or os.environ.get(ENV_DIR) or DEFAULT_CACHE_DIR
+    path = cache_dir()
     try:
         os.makedirs(path, exist_ok=True)
     except OSError as exc:
@@ -422,11 +431,8 @@ def enable(cache_dir: str | None = None, force: bool = False) -> str | None:
     # pinned to that moment's (usually disabled) state and this call
     # would silently do nothing (r6: observed as checkpoint-restore →
     # compile-cache test-order pollution, present since the seed).
-    try:
-        from jax._src import compilation_cache as _jcc
+    from jax._src import compilation_cache as _jcc  # private; jax 0.9.0
 
-        _jcc.reset_cache()
-    except (ImportError, AttributeError):  # private API; best-effort
-        pass
+    _jcc.reset_cache()
     _harden_cache_io()
     return path

@@ -27,9 +27,9 @@ mis-sourced row changes the digest.
 ``jax`` arrays are built with ``jax.make_array_from_callback`` against a
 local 1-device ``dp`` mesh (the CI data plane — one process, one CPU
 device), and the re-layout goes through ``jax.jit`` with
-``out_shardings``; ``parallel/collectives.shard_map_compat`` papers over
-the shard_map API gap for the row-update body so the same code shape
-lifts to a real multi-device mesh.
+``out_shardings``; the row-update body runs under
+``parallel/collectives.shard_map`` so the same code shape lifts to a
+real multi-device mesh.
 """
 
 from __future__ import annotations
@@ -106,14 +106,14 @@ def init_row(seed: int, p: int, dim: int = PARAM_DIM) -> np.ndarray:
 
 def make_row_update() -> Callable:
     """The jitted one-touch row update. Runs the body through
-    shard_map_compat over the local mesh so the identical code shape
-    lifts to a real dp mesh; on the 1-device mesh the spec is fully
-    replicated and the compat wrapper is an identity layout."""
+    shard_map over the local mesh so the identical code shape lifts to
+    a real dp mesh; on the 1-device mesh the spec is fully replicated
+    and the wrapper is an identity layout."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from tf_operator_tpu.parallel.collectives import shard_map_compat
+    from tf_operator_tpu.parallel.collectives import shard_map
 
     mesh = local_mesh()
 
@@ -122,7 +122,7 @@ def make_row_update() -> Callable:
         new_mom = ROW_LR * w * jnp.ones_like(mom)
         return new_row, new_mom
 
-    shard = shard_map_compat(
+    shard = shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), P()),
         out_specs=(P(), P()),

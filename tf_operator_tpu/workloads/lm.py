@@ -24,7 +24,9 @@ mesh axis).
 
 from __future__ import annotations
 
+import json
 import logging
+import time
 
 from tf_operator_tpu.rendezvous.context import JobContext, RetryableFailure
 from tf_operator_tpu.train.profile import profile_ctx
@@ -44,7 +46,9 @@ def main(ctx: JobContext) -> None:
         transformer_logical_axes,
     )
     from tf_operator_tpu.train.metrics import (
+        fmt_mfu,
         mfu,
+        run_report,
         transformer_train_flops,
         transformer_train_flops_exact,
     )
@@ -149,6 +153,16 @@ def main(ctx: JobContext) -> None:
                 # routed by the harness to the user-retryable exit code
                 raise RetryableFailure(f"fault injection at step {step}")
 
+    # The step program is compiled ahead of the loop: its compile time is
+    # read apart from the first step's run time, a compile the device's
+    # compiler refuses fails HERE with its own error, and the compiled
+    # text says which kernels the device will really run.
+    t_compile = time.perf_counter()
+    step_kernels = trainer.compile_step(
+        jax.ShapeDtypeStruct((batch, seq), "int32")
+    ).as_text().count("tpu_custom_call")
+    compile_s = time.perf_counter() - t_compile
+
     try:
         with profile_ctx(wl.get("profile_dir")):
             state, loss, timed, step_s = ckpt.run_loop(
@@ -191,6 +205,13 @@ def main(ctx: JobContext) -> None:
                 "z_loss=%.4f",
                 float(m["moe_lb_loss"]), float(m["moe_z_loss"]),
             )
+    log.info("run report: %s", json.dumps(run_report(
+        workload="lm", preset=wl.get("preset", "tiny"), batch_size=batch,
+        seq_len=seq, n_layers=cfg.n_layers, attn=cfg.attn_impl,
+        step_compile_s=round(compile_s, 3),
+        step_tpu_custom_calls=step_kernels,
+        step_s=step_s, losses=ckpt.loss_trace(),
+    )))
     if step_s is not None:
         n_chips = mesh.devices.size
         # active params: for top-1 MoE only one expert's FLOPs count per
@@ -201,11 +222,11 @@ def main(ctx: JobContext) -> None:
             cfg.n_active_params(), batch * seq, cfg.n_layers, cfg.d_model, seq
         )
         log.info(
-            "lm done: preset=%s loss=%.4f step=%.2fms tok/s=%.0f mfu_attn=%.3f "
-            "mfu_6nd=%.3f (%d chips)",
+            "lm done: preset=%s loss=%.4f step=%.2fms tok/s=%.0f mfu_attn=%s "
+            "mfu_6nd=%s (%d chips)",
             wl.get("preset", "tiny"), loss, step_s * 1e3, batch * seq / step_s,
-            mfu(flops_exact, step_s, n_chips), mfu(flops_6nd, step_s, n_chips),
-            n_chips,
+            fmt_mfu(mfu(flops_exact, step_s, n_chips)),
+            fmt_mfu(mfu(flops_6nd, step_s, n_chips)), n_chips,
         )
     else:
         log.info("lm done: preset=%s loss=%.4f (no timed steps remained)",

@@ -111,7 +111,7 @@ def main(ctx: JobContext) -> None:
     import jax.numpy as jnp
 
     from tf_operator_tpu.models.resnet import ResNetConfig, init_resnet, resnet_forward
-    from tf_operator_tpu.train.metrics import mfu, resnet_train_flops
+    from tf_operator_tpu.train.metrics import fmt_mfu, mfu, resnet_train_flops
     from tf_operator_tpu.train.trainer import Trainer, TrainerConfig
 
     wl = ctx.workload
@@ -185,8 +185,9 @@ def main(ctx: JobContext) -> None:
         n_chips = mesh.devices.size
         flops = resnet_train_flops(cfg.flops_per_image(image_size), batch)
         log.info(
-            "resnet done: loss=%.4f step=%.2fms imgs/s=%.0f mfu=%.3f (%d chips)",
-            loss, step_s * 1e3, batch / step_s, mfu(flops, step_s, n_chips), n_chips,
+            "resnet done: loss=%.4f step=%.2fms imgs/s=%.0f mfu=%s (%d chips)",
+            loss, step_s * 1e3, batch / step_s,
+            fmt_mfu(mfu(flops, step_s, n_chips)), n_chips,
         )
     else:
         log.info("resnet done: loss=%.4f (no timed steps remained)", loss)

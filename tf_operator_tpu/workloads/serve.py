@@ -21,17 +21,27 @@ workload config keys: preset (+ any TransformerConfig override),
 requests, prompt_len, max_new_tokens, arrival_rate (req/s Poisson; 0 ⇒
 all at t=0), seed, kv_page_size, kv_pool_pages, max_slots,
 prefill_chunk, reserve_full, max_admit_per_step, mode
-("continuous"|"static"), report_every.
+("continuous"|"static"), report_every, check_greedy (hold the first N
+finished requests against un-paged greedy decoding through
+transformer_forward; the job fails when a token the engine chose is
+further than GREEDY_TOL below the reference's best logit).
 """
 
 from __future__ import annotations
 
+import json
 import logging
 import time
 
 from tf_operator_tpu.rendezvous.context import JobContext
 
 log = logging.getLogger("tpujob.serve")
+
+# check_greedy's tolerance, in logit units (logits of these models have a
+# spread of ~1): random weights leave thin top-2 margins, so the check is
+# "the engine's token is within this of the reference's best", not token
+# equality. Measured gap on the v5e at gqa-2048 widths: 0.0 (PR 21).
+GREEDY_TOL = 0.05
 
 
 def synthesize_requests(wl: dict, vocab: int):
@@ -87,7 +97,12 @@ def main(ctx: JobContext) -> None:
         preset_from_workload,
     )
     from tf_operator_tpu.obs.spans import trace8
-    from tf_operator_tpu.serve.engine import ServeConfig, ServeEngine
+    from tf_operator_tpu.serve.engine import (
+        ServeConfig,
+        ServeEngine,
+        greedy_reference_gaps,
+    )
+    from tf_operator_tpu.train.metrics import run_report
 
     wl = ctx.workload
     cfg = preset_from_workload(wl)
@@ -102,6 +117,7 @@ def main(ctx: JobContext) -> None:
     )
     params = init_transformer(jax.random.PRNGKey(int(wl.get("seed", 0))), cfg)
     engine = ServeEngine(cfg, params, scfg)
+    compiled = engine.compile()  # warm up before taking traffic
     requests = synthesize_requests(wl, cfg.vocab)
     total = len(requests)
     report_every = max(1, int(wl.get("report_every", 4)))
@@ -159,6 +175,28 @@ def main(ctx: JobContext) -> None:
         "tokens_generated": float(res.generated_tokens),
         "tokens_per_s": float(res.tokens_per_s),
     })
+    # Engine vs model: paged, cached, chunk-prefilled decoding against
+    # the plain forward pass on the same weights.
+    parity = []
+    for req in requests[: int(wl.get("check_greedy", 0))]:
+        n_exact, max_gap = greedy_reference_gaps(
+            cfg, params, req.prompt, req.tokens
+        )
+        parity.append({"request": req.rid, "tokens": len(req.tokens),
+                       "exact": n_exact, "max_logit_gap": max_gap})
+    log.info("run report: %s", json.dumps(run_report(
+        workload="serve", preset=wl.get("preset", "tiny"),
+        n_layers=cfg.n_layers, requests=total, completed=res.completed,
+        generated_tokens=res.generated_tokens, steps=res.steps,
+        wall_s=round(res.wall_s, 3), page_leaks=leaked,
+        greedy_parity=parity, greedy_tol=GREEDY_TOL, **compiled,
+    )))
+    bad = [p for p in parity if not p["max_logit_gap"] <= GREEDY_TOL]
+    if bad:
+        raise RuntimeError(
+            f"engine disagrees with un-paged greedy decoding beyond "
+            f"tol={GREEDY_TOL}: {bad}"
+        )
     ttfts = res.ttfts()
     log.info(
         "serve done: preset=%s mode=%s requests=%d/%d tokens=%d tok/s=%.1f "

@@ -35,10 +35,16 @@ Usage:
     python -m tools.hloprobe [--probe fsdp,tp,flagship]
         [--topology v5e:2x4] [--json artifacts/hloprobe.json]
 
-Exit 1 when any probed config violates the criterion. If the TPU
+Exit 1 when any probed config violates the criterion — and when the TPU
 compiler/topology cannot initialize at all (no libtpu in the
-environment), prints SKIP and exits 0 — the receipt is only meaningful
-where the real compiler runs; CI containers have it.
+environment): a receipt that was not produced is a failure, not a skip.
+
+The process is CPU-pinned, so the kernels' own dispatch
+(``jax.default_backend()``) would hand the compiler the Pallas
+interpreter or the jnp reference — a program the chip never runs. The
+probe steers that answer to "tpu" around the lowering (here in the tool,
+not through an option of the program), so what is compiled and cached is
+the Mosaic-kernel program.
 """
 
 from __future__ import annotations
@@ -235,9 +241,13 @@ def compile_step(topo_name: str, preset_kwargs: dict, mesh_axes: dict,
     batch_spec = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
                                       sharding=trainer.batch_sharding)
     fn = trainer._build_step()
-    compiled = fn.lower(
-        tmpl.params, tmpl.opt_state, tmpl.step, tmpl.extra, batch_spec
-    ).compile()
+    from unittest import mock
+
+    # the described devices are TPUs; make the kernels' dispatch see one
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        compiled = fn.lower(
+            tmpl.params, tmpl.opt_state, tmpl.step, tmpl.extra, batch_spec
+        ).compile()
     return compiled.as_text()
 
 
@@ -258,10 +268,10 @@ def main(argv=None) -> int:
         topologies.get_topology_desc(platform="tpu",
                                      topology_name=args.topology)
     except Exception as exc:  # noqa: BLE001
-        print(f"hloprobe SKIP: TPU compiler topology unavailable "
-              f"({type(exc).__name__}: {exc}) — the receipt needs libtpu; "
-              "CI images ship it", file=sys.stderr)
-        return 0
+        print(f"hloprobe FAILED: TPU compiler topology unavailable "
+              f"({type(exc).__name__}: {exc}) — the receipt needs libtpu",
+              file=sys.stderr)
+        return 1
 
     configs = _probe_configs()
     results, failed = {}, []

@@ -36,6 +36,11 @@ import sys
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO_ROOT)
 
+# The environment as this sweep was STARTED with: the advisory memplan
+# below pins this parent to the CPU backend (it must not take the chip —
+# each bench child needs it), and that pin must not leak into a child.
+_START_ENV = dict(os.environ)
+
 DEFAULT_POLICIES = (
     "full",
     "save_mid",
@@ -57,7 +62,7 @@ def _memplan_gb(policy: str, batch: int, seq: int) -> float:
 
 def run_cell(policy: str, batch: int, seq: int, steps: int, timeout: int):
     env = dict(
-        os.environ,
+        _START_ENV,
         BENCH_MODEL="gqa-2048",
         BENCH_BATCH=str(batch),
         BENCH_SEQ=str(seq),

@@ -126,7 +126,8 @@ def serve_trace_errors(doc: dict, requests: int) -> list:
 def run_serve_smoke(client: TPUJobClient, timeout: float) -> list:
     """Submit one smoke serve job, return request-span schema errors."""
     name = f"tracesmoke-serve-{int(time.time()) % 100000}"
-    job = build_serve_job(name, workload={
+    # a span-schema smoke, not a device run: it asks for the CPU itself
+    job = build_serve_job(name, env={"JAX_PLATFORMS": "cpu"}, workload={
         "requests": SERVE_SMOKE_REQUESTS, "prompt_len": 6,
         "max_new_tokens": 6, "arrival_rate": 0.0,
     })
