@@ -55,9 +55,23 @@ def no_cache():
     cc.reset_cache()
 
 
+def _kernel_names(fn, *args) -> list:
+    """Compile for the described chip; the HLO instruction name of every
+    Mosaic kernel in it, instance suffix cut (``%flash_fwd.3 = ...
+    custom-call(...)`` gives ``flash_fwd``). The profiler's trace prints an
+    op as its instruction text without metadata, so this name is all a
+    reader of a trace has to tell kernels apart."""
+    import re
+
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return [m.group(1) for m in re.finditer(
+        r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom-call\([^\n]*"
+        r'custom_call_target="tpu_custom_call"', text)]
+
+
 def _kernels(fn, *args) -> int:
     """Compile for the described chip; count the Mosaic kernels in it."""
-    return jax.jit(fn).lower(*args).compile().as_text().count("tpu_custom_call")
+    return len(_kernel_names(fn, *args))
 
 
 # ---- flash attention: fwd and fwd+bwd -------------------------------------
@@ -90,8 +104,41 @@ def test_flash_attention_compiles_for_v5e(one_chip, no_cache, shape, bwd):
         return jnp.sum(fwd(q, k, v).astype(jnp.float32))
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if bwd else fwd
-    n = _kernels(fn, spec(h), spec(h_kv), spec(h_kv))
-    assert n >= (3 if bwd else 1)  # fwd; + dq and dk/dv kernels
+    names = _kernel_names(fn, spec(h), spec(h_kv), spec(h_kv))
+    assert len(names) >= (3 if bwd else 1)  # fwd; + dq and dk/dv kernels
+    # each kernel under its own name, whatever jvp/transpose/remat wrapper
+    # the call sits in (``transpose_jvp_flash_bwd_dq__`` still carries it)
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")[: 3 if bwd else 1]:
+        assert sum(kernel in n for n in names) == 1, (kernel, names)
+
+
+def test_flash_kernels_keep_their_names_under_remat_in_a_scan(one_chip, no_cache):
+    """As the train step holds them: a rematerialised layer inside
+    ``lax.scan``. The forward runs twice (once replayed), both under
+    ``flash_fwd``; before the kernels had names these read ``closed_call``,
+    ``rematted_computation`` and ``checkpoint``."""
+    b, t, h, h_kv, d = 2, 1024, 8, 2, 128
+
+    def spec(heads):
+        return jax.ShapeDtypeStruct((b, t, heads, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def layer(q, k, v):
+        use, bq, bk = fa._dispatch(q, k, v, None, None, True, None)
+        assert use
+        return fa._flash_lse(q, k, v, True, bq, bk, False)[0]
+
+    def loss(q, k, v):
+        f = jax.checkpoint(
+            layer, policy=jax.checkpoint_policies.nothing_saveable)
+        out, _ = jax.lax.scan(lambda c, _: (c + f(c, k, v), None), q, None,
+                              length=2)
+        return jnp.sum(out.astype(jnp.float32))
+
+    names = _kernel_names(jax.grad(loss, argnums=(0, 1, 2)),
+                          spec(h), spec(h_kv), spec(h_kv))
+    assert sorted(names) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd",
+                             "flash_fwd"]
 
 
 # ---- paged decode: the kernel the serve engine cannot run without ---------
@@ -108,12 +155,12 @@ def test_flash_attention_decode_compiles_for_v5e(one_chip, no_cache, dtype,
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     pool = a((n_pages, h_kv, page, d), dtype)
-    n = _kernels(
+    names = _kernel_names(
         lambda q, k, v, pt, sl: fa._decode_call(q, k, v, pt, sl, False),
         a((s_n, h, d), dtype), pool, pool, a((s_n, p), jnp.int32),
         a((s_n,), jnp.int32),
     )
-    assert n == 1
+    assert names == ["paged_attention"]
 
 
 # ---- grouped matmul: fwd, dx, dw, with and without the fused row scale ----

@@ -230,6 +230,7 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret):
             pltpu.VMEM((g * block_q, d), jnp.float32),          # output accumulator
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     # Residuals carry the COMPACT [b, h, t] lse (the kernel's LSE_LANES
     # lane-broadcast is rebuilt in _bwd): saved residuals under a
@@ -381,6 +382,7 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None):
         out_shape=jax.ShapeDtypeStruct((b, h, t, d), qt.dtype),
         scratch_shapes=[pltpu.VMEM((grp * block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, kt, vt, do, lse, delta)
 
     # ---- dk/dv: grid (b, h_kv, nk, nq) ---------------------------------
@@ -408,6 +410,7 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qt, kt, vt, do, lse, delta)
 
     to_model = lambda x: x.transpose(0, 2, 1, 3)
@@ -812,6 +815,7 @@ def _decode_call(q, k_pages, v_pages, page_table, seq_lens, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, h_kv, g, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
       q4, k_pages, v_pages)
     return o.reshape(s_n, h, d)

@@ -32,13 +32,22 @@ Two compiled functions, both fixed-shape:
 Greedy argmax sampling, f32 compute throughout: serving determinism is
 what the correctness oracle (tests/test_serve.py) and the seeded bench
 artifact pin against.
+
+``run`` is instrumented with ``jax.profiler.TraceAnnotation`` spans
+(``serve.admit``, ``serve.step`` and its children ``serve.prefill``,
+``serve.prefill_fetch``, ``serve.decode_prep``, ``serve.decode``,
+``serve.decode_fetch``; ``serve.idle``; the closing ``serve.counters``).
+They cost well under a microsecond each while no profiler session is
+open and land in the xplane of ``profile_ctx`` / ``tpujob profile`` on
+the device trace's own clock; docs/design.md ("On-demand profiling")
+lists what each covers. ``EngineCounters`` are plain ints, always on.
 """
 
 from __future__ import annotations
 
 import time
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import asdict, dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
@@ -89,6 +98,25 @@ class Request:
 
 
 @dataclass
+class EngineCounters:
+    """What one ``run`` did, counted where it happens. The LIVE object
+    rides every ``"step"`` payload (copy it to keep a reading) and ends
+    on ``RunResult``; its final values are also written into an open
+    profiler trace (``serve.counters``), so trace and object agree."""
+
+    admitted: int = 0
+    # step boundaries at which the head of the queue could not be admitted
+    blocked_on_pool: int = 0   # ... for want of KV pages
+    blocked_on_slots: int = 0  # ... for want of a batch slot (static: an undrained batch)
+    prefill_chunks: int = 0    # calls of the prefill program
+    prefill_tokens: int = 0    # prompt tokens they carried
+    prefill_padded: int = 0    # chunk positions they padded
+    decode_steps: int = 0      # calls of the decode program
+    decode_slot_tokens: int = 0  # tokens they produced (active slots, summed)
+    idle_sleeps: int = 0       # sleeps of an empty engine waiting for an arrival
+
+
+@dataclass
 class RunResult:
     requests: List[Request]
     steps: int
@@ -96,6 +124,9 @@ class RunResult:
     generated_tokens: int
     free_pages_start: int
     free_pages_end: int
+    counters: EngineCounters = field(default_factory=EngineCounters)
+    pool_peak_in_use: int = 0
+    pool_alloc_failures: int = 0
 
     @property
     def completed(self) -> int:
@@ -338,9 +369,11 @@ class ServeEngine:
         """Serve ``requests`` (arrival offsets in seconds from run start)
         to completion. ``on_event(kind, payload)`` fires with kinds
         "admitted"/"first_token"/"finished" (payload: the Request) and
-        "step" (payload: dict with step/active/waiting/completed) — the
+        "step" (payload: dict with step/active/waiting/completed/
+        generated/free_pages and the live ``EngineCounters``) — the
         workload's span + live-count seam."""
         import jax.numpy as jnp
+        from jax.profiler import TraceAnnotation as span
 
         mode = mode or self.scfg.mode
         if mode not in ("continuous", "static"):
@@ -372,6 +405,7 @@ class ServeEngine:
         pending = deque(sorted(requests, key=lambda r: (r.arrival, r.rid)))
         waiting: deque = deque()
         emit = on_event or (lambda kind, payload: None)
+        counters = EngineCounters()
         t0 = clock()
         step = 0
         completed = 0
@@ -386,11 +420,15 @@ class ServeEngine:
 
         def _try_admit(now: float) -> int:
             n = 0
-            while waiting and _admit_ok():
+            while waiting:
+                if not _admit_ok():
+                    counters.blocked_on_slots += 1
+                    break
                 if scfg.max_admit_per_step and n >= scfg.max_admit_per_step:
                     break
                 free = [i for i, sl in enumerate(slots) if sl is None]
                 if not free:
+                    counters.blocked_on_slots += 1
                     break
                 req = waiting[0]
                 want = len(req.prompt) + (req.max_new if scfg.reserve_full else 0)
@@ -398,12 +436,14 @@ class ServeEngine:
                 try:
                     sp.ensure(want, pool)
                 except PoolExhausted:
+                    counters.blocked_on_pool += 1
                     break  # head-of-line blocks: FIFO admission, no bypass
                 waiting.popleft()
                 i = free[0]
                 slots[i] = _Slot(req, sp)
                 table[i, : len(sp.pages)] = sp.pages
                 req.admitted = now
+                counters.admitted += 1
                 emit("admitted", req)
                 n += 1
                 if mode == "static" and n >= s_n:
@@ -436,45 +476,40 @@ class ServeEngine:
                         table[j, :] = pool.trash_page - 1
                         slots[j] = None
 
-        while completed < len(requests):
-            now = clock() - t0
-            while pending and pending[0].arrival <= now:
-                waiting.append(pending.popleft())
-            _try_admit(now)
-            busy = [sl for sl in slots if sl is not None]
-            if not busy:
-                if pending:
-                    # idle until the next arrival — a serving engine,
-                    # not a busy loop.
-                    time.sleep(
-                        max(0.0, min(0.01, pending[0].arrival - (clock() - t0)))
-                    )
-                continue
-
-            # ---- prefill: one chunk per still-prefilling slot ----------
+        def _prefill_chunks() -> None:
+            """One chunk per still-prefilling slot."""
+            nonlocal kp, vp, generated
+            c = scfg.prefill_chunk
             for i, sl in enumerate(slots):
                 if sl is None or sl.prefill_pos >= len(sl.req.prompt):
                     continue
                 prompt = sl.req.prompt
-                c = self.scfg.prefill_chunk
                 chunk = prompt[sl.prefill_pos : sl.prefill_pos + c]
                 n_valid = len(chunk)
-                buf = np.zeros(c, np.int32)
-                buf[:n_valid] = chunk
-                if not scfg.reserve_full:
-                    sl.pages.ensure(sl.prefill_pos + n_valid, pool)
-                    table[i, : len(sl.pages.pages)] = sl.pages.pages
-                kp, vp, tok = self._prefill(
-                    self.params, kp, vp, jnp.asarray(table[i]),
-                    jnp.int32(sl.prefill_pos), jnp.asarray(buf),
-                    jnp.int32(n_valid),
-                )
+                last = sl.prefill_pos + n_valid >= len(prompt)
+                with span("serve.prefill", rid=sl.req.rid, slot=i,
+                          start=sl.prefill_pos, n_valid=n_valid, chunk=c,
+                          last=int(last)):
+                    buf = np.zeros(c, np.int32)
+                    buf[:n_valid] = chunk
+                    if not scfg.reserve_full:
+                        sl.pages.ensure(sl.prefill_pos + n_valid, pool)
+                        table[i, : len(sl.pages.pages)] = sl.pages.pages
+                    kp, vp, tok = self._prefill(
+                        self.params, kp, vp, jnp.asarray(table[i]),
+                        jnp.int32(sl.prefill_pos), jnp.asarray(buf),
+                        jnp.int32(n_valid),
+                    )
+                counters.prefill_chunks += 1
+                counters.prefill_tokens += n_valid
+                counters.prefill_padded += c - n_valid
                 sl.prefill_pos += n_valid
                 sl.seq_len = sl.prefill_pos
-                if sl.prefill_pos >= len(prompt):
+                if last:
                     # last chunk's logits ARE the first generated token
                     t_tok = clock() - t0
-                    first = int(tok)
+                    with span("serve.prefill_fetch", rid=sl.req.rid):
+                        first = int(tok)
                     sl.req.tokens.append(first)
                     sl.req.token_times.append(t_tok)
                     sl.req.first_token = t_tok
@@ -485,14 +520,18 @@ class ServeEngine:
                     if sl.generated >= sl.req.max_new:
                         _finish(i, t_tok)
 
-            # ---- decode: one batched step over decoding slots ----------
+        def _decode_step() -> None:
+            """One batched step over the decoding slots."""
+            nonlocal kp, vp, generated
             dec = [
                 (i, sl) for i, sl in enumerate(slots)
                 if sl is not None
                 and sl.prefill_pos >= len(sl.req.prompt)
                 and sl.generated < sl.req.max_new
             ]
-            if dec:
+            if not dec:
+                return
+            with span("serve.decode_prep", active=len(dec)):
                 active = np.zeros(s_n, bool)
                 toks = np.zeros(s_n, np.int32)
                 lens = np.zeros(s_n, np.int32)
@@ -503,35 +542,80 @@ class ServeEngine:
                     active[i] = True
                     toks[i] = sl.cur_tok
                     lens[i] = sl.seq_len
-                kp, vp, nxt = self._decode(
-                    self.params, kp, vp, jnp.asarray(table), jnp.asarray(lens),
-                    jnp.asarray(toks), jnp.asarray(active),
-                )
+                args = (jnp.asarray(table), jnp.asarray(lens),
+                        jnp.asarray(toks), jnp.asarray(active))
+            with span("serve.decode", active=len(dec), slots=s_n):
+                kp, vp, nxt = self._decode(self.params, kp, vp, *args)
+            counters.decode_steps += 1
+            counters.decode_slot_tokens += len(dec)
+            with span("serve.decode_fetch"):
                 nxt = np.asarray(nxt)
-                t_tok = clock() - t0
-                for i, sl in dec:
-                    sl.seq_len += 1
-                    sl.generated += 1
-                    sl.cur_tok = int(nxt[i])
-                    sl.req.tokens.append(sl.cur_tok)
-                    sl.req.token_times.append(t_tok)
-                    generated += 1
-                    if sl.generated >= sl.req.max_new:
-                        _finish(i, t_tok)
-            _drain_static(clock() - t0)
-            step += 1
-            emit("step", {
-                "step": step,
-                "active": sum(1 for sl in slots if sl is not None),
-                "waiting": len(waiting) + len(pending),
-                "completed": completed,
-                "generated": generated,
-                "free_pages": pool.free_count,
-            })
+            t_tok = clock() - t0
+            for i, sl in dec:
+                sl.seq_len += 1
+                sl.generated += 1
+                sl.cur_tok = int(nxt[i])
+                sl.req.tokens.append(sl.cur_tok)
+                sl.req.token_times.append(t_tok)
+                generated += 1
+                if sl.generated >= sl.req.max_new:
+                    _finish(i, t_tok)
+
+        try:
+            while completed < len(requests):
+                with span("serve.admit"):
+                    now = clock() - t0
+                    while pending and pending[0].arrival <= now:
+                        waiting.append(pending.popleft())
+                    _try_admit(now)
+                busy = [sl for sl in slots if sl is not None]
+                if not busy:
+                    if pending:
+                        # idle until the next arrival — a serving engine,
+                        # not a busy loop.
+                        counters.idle_sleeps += 1
+                        with span("serve.idle"):
+                            time.sleep(
+                                max(0.0, min(0.01, pending[0].arrival - (clock() - t0)))
+                            )
+                    continue
+                # attributes as the step STARTS, after this boundary's
+                # admissions; the span's self time (duration less its
+                # children) is the bookkeeping: token appends, _finish,
+                # the on_event callbacks.
+                with span(
+                    "serve.step", step=step + 1, occupied=len(busy),
+                    waiting=len(waiting), free_pages=pool.free_count,
+                    kv_tokens=sum(sl.seq_len for sl in busy),
+                    kv_reserved=scfg.page_size
+                    * sum(len(sl.pages.pages) for sl in busy),
+                ):
+                    _prefill_chunks()
+                    _decode_step()
+                    _drain_static(clock() - t0)
+                    step += 1
+                    emit("step", {
+                        "step": step,
+                        "active": sum(1 for sl in slots if sl is not None),
+                        "waiting": len(waiting) + len(pending),
+                        "completed": completed,
+                        "generated": generated,
+                        "free_pages": pool.free_count,
+                        "counters": counters,
+                    })
+        finally:
+            # also when on_event raised: every span above is closed by
+            # its ``with``, and the trace gets the counters' last values
+            with span("serve.counters", **asdict(counters),
+                      pool_peak_in_use=pool.peak_in_use,
+                      pool_alloc_failures=pool.alloc_failures):
+                pass
 
         wall = clock() - t0
         return RunResult(
             requests=list(requests), steps=step, wall_s=wall,
             generated_tokens=generated, free_pages_start=free_start,
-            free_pages_end=pool.free_count,
+            free_pages_end=pool.free_count, counters=counters,
+            pool_peak_in_use=pool.peak_in_use,
+            pool_alloc_failures=pool.alloc_failures,
         )

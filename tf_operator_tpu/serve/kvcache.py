@@ -62,10 +62,15 @@ class PagePool:
     Pure host-side bookkeeping: the device pool itself is allocated by
     the engine (it owns dtype/layout); this class only decides which
     page ids are live. ``free_count`` is the leak probe — after every
-    sequence is finished and freed it must equal ``num_pages``."""
+    sequence is finished and freed it must equal ``num_pages``.
+    ``peak_in_use`` (most pages ever held at once) and ``alloc_failures``
+    (allocations refused) are counted as they happen; the engine writes
+    both out with its own counters."""
 
     num_pages: int
     _free: List[int] = field(default_factory=list)
+    peak_in_use: int = 0
+    alloc_failures: int = 0
 
     def __post_init__(self) -> None:
         if self.num_pages < 1:
@@ -88,10 +93,12 @@ class PagePool:
         if n < 0:
             raise ValueError(f"alloc({n})")
         if n > len(self._free):
+            self.alloc_failures += 1
             raise PoolExhausted(
                 f"need {n} pages, {len(self._free)}/{self.num_pages} free"
             )
         got = [self._free.pop() for _ in range(n)]
+        self.peak_in_use = max(self.peak_in_use, self.num_pages - len(self._free))
         return got
 
     def free(self, pages: List[int]) -> None:

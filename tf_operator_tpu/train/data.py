@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import Any, Callable, Iterable, Iterator, Optional
 
 import numpy as np
@@ -651,7 +652,14 @@ class DeviceLoader:
     Iteration ends when the source iterator does (pass a bounded iterable
     for epochs; ArrayDataset repeats forever). ``close()`` (or `with`)
     stops the stager; the thread also exits if the consumer drops the
-    loader. Errors in the source re-raise at the consumer's next pull."""
+    loader. Errors in the source re-raise at the consumer's next pull.
+
+    How well the prefetch hides the input pipeline is counted as it
+    happens: ``batches`` handed out, ``wait_s`` the consumer spent
+    blocked in ``next()``, ``empty_pulls`` of them that found nothing
+    staged. Under a profiler session each pull is a ``train.data_wait``
+    span (``queued`` = batches staged at the pull) and each transfer on
+    the stager thread a ``train.data_stage`` span."""
 
     _END = object()
 
@@ -674,6 +682,9 @@ class DeviceLoader:
         self._q: "queue.Queue" = queue.Queue(maxsize=prefetch)
         self._stop = threading.Event()
         self._err: Optional[BaseException] = None
+        self.batches = 0
+        self.wait_s = 0.0
+        self.empty_pulls = 0
         self._thread = threading.Thread(
             target=self._stage, args=(iter(source),), name="device-loader", daemon=True
         )
@@ -697,6 +708,8 @@ class DeviceLoader:
         return jax.device_put(batch, shardings)
 
     def _stage(self, it: Iterator[Any]) -> None:
+        from jax.profiler import TraceAnnotation
+
         try:
             # Restart fast-forward: drop already-consumed batches on the
             # host (no staging cost) so a resumed job continues the stream
@@ -710,7 +723,8 @@ class DeviceLoader:
             for batch in it:
                 if self._stop.is_set():
                     return
-                staged = self._put(batch, self.sharding)
+                with TraceAnnotation("train.data_stage"):
+                    staged = self._put(batch, self.sharding)
                 while not self._stop.is_set():
                     try:
                         self._q.put(staged, timeout=0.2)
@@ -736,20 +750,28 @@ class DeviceLoader:
         return self
 
     def __next__(self) -> Any:
-        while True:
-            try:
-                item = self._q.get(timeout=0.2)
-                break
-            except queue.Empty:
-                if not self._thread.is_alive() and self._q.empty():
-                    item = self._END
+        from jax.profiler import TraceAnnotation
+
+        queued = self._q.qsize()
+        t0 = time.perf_counter()
+        with TraceAnnotation("train.data_wait", queued=queued):
+            while True:
+                try:
+                    item = self._q.get(timeout=0.2)
                     break
+                except queue.Empty:
+                    if not self._thread.is_alive() and self._q.empty():
+                        item = self._END
+                        break
+        self.wait_s += time.perf_counter() - t0
+        self.empty_pulls += queued == 0
         if item is self._END:
             self._stop.set()
             if self._err is not None:
                 err, self._err = self._err, None
                 raise err
             raise StopIteration
+        self.batches += 1
         return item
 
     def close(self) -> None:

@@ -8,9 +8,7 @@ submit→first-step latency).
 
 from __future__ import annotations
 
-import time
-from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, Optional
 
 # Peak dense bf16 FLOP/s per chip, matched on jax's ``device_kind``
 # (lower-cased substring, most specific first). Source: Google Cloud TPU
@@ -97,49 +95,6 @@ def host_fetch(x) -> None:
     import jax
 
     jax.block_until_ready(x)
-
-
-@dataclass
-class StepTimer:
-    """Wall-clock step timing with warmup exclusion (first steps compile).
-
-    ``stop(result)`` waits for ``result`` (host_fetch) before reading the
-    clock — without it, async dispatch makes the measurement meaningless:
-    jax returns before the device finishes."""
-
-    warmup: int = 2
-    _t0: Optional[float] = None
-    durations: List[float] = field(default_factory=list)
-    _seen: int = 0
-
-    def start(self) -> None:
-        self._t0 = time.perf_counter()
-
-    def stop(self, result=None) -> None:
-        if self._t0 is None:
-            return
-        if result is not None:
-            host_fetch(result)
-        dt = time.perf_counter() - self._t0
-        self._t0 = None
-        self._seen += 1
-        if self._seen > self.warmup:
-            self.durations.append(dt)
-
-    def mean(self) -> float:
-        if not self.durations:
-            return float("nan")
-        return sum(self.durations) / len(self.durations)
-
-    def summary(self, flops_per_step: float = 0.0, n_chips: int = 1) -> Dict[str, float]:
-        m = self.mean()
-        out = {"step_time_s": m, "steps_timed": float(len(self.durations))}
-        if flops_per_step and m == m:  # not nan
-            util = mfu(flops_per_step, m, n_chips)
-            if util is not None:  # off-TPU there is no MFU to report
-                out["mfu"] = util
-            out["tflops_per_chip"] = flops_per_step / m / n_chips / 1e12
-        return out
 
 
 def transformer_train_flops(n_params: int, tokens_per_step: int) -> float:

@@ -144,6 +144,7 @@ class Trainer:
         self._precompile_error = None
         self._compiled_hits = 0
         self._compiled_rejections = 0
+        self._step_calls = 0
         self._multi_jit: Dict[Any, Any] = {}
 
     # ---- init -----------------------------------------------------------
@@ -367,6 +368,15 @@ class Trainer:
         return t
 
     def step(self, state: TrainState, batch) -> tuple:
+        """One optimizer step (returns at enqueue). Under a profiler
+        session the dispatch is a ``train.step`` span whose ``call`` is
+        this trainer's host-side count of calls — never ``state.step``,
+        which lives on the device and would synchronise to read."""
+        self._step_calls += 1
+        with jax.profiler.TraceAnnotation("train.step", call=self._step_calls):
+            return self._dispatch_step(state, batch)
+
+    def _dispatch_step(self, state: TrainState, batch) -> tuple:
         if self._precompile_error is not None:
             exc, self._precompile_error = self._precompile_error, None
             raise RuntimeError("train-step precompile failed") from exc

@@ -211,6 +211,10 @@ def main(ctx: JobContext) -> None:
         step_compile_s=round(compile_s, 3),
         step_tpu_custom_calls=step_kernels,
         step_s=step_s, losses=ckpt.loss_trace(),
+        # how well the prefetch hid the input pipeline (None: no loader)
+        loader=None if loader is None else {
+            "batches": loader.batches, "wait_s": round(loader.wait_s, 4),
+            "empty_pulls": loader.empty_pulls},
     )))
     if step_s is not None:
         n_chips = mesh.devices.size

@@ -15,13 +15,17 @@ completion, attrs carry the generated-token count feeding
 
 Live request-count rides the eval_metrics status channel (the same
 optimistic RMW the Evaluator uses) every ``report_every`` steps — the
-dashboard's serve-job "Requests" column reads it.
+dashboard's serve-job "Requests" column reads it. The engine's own
+counters (``serve.engine.EngineCounters``: admissions, blocked
+admissions, prefill chunks and padding, decode steps, idle sleeps) go
+out on the same channel as ``engine_<counter>``.
 
 workload config keys: preset (+ any TransformerConfig override),
 requests, prompt_len, max_new_tokens, arrival_rate (req/s Poisson; 0 ⇒
 all at t=0), seed, kv_page_size, kv_pool_pages, max_slots,
 prefill_chunk, reserve_full, max_admit_per_step, mode
-("continuous"|"static"), report_every, check_greedy (hold the first N
+("continuous"|"static"), report_every, profile_dir (capture a profiler
+trace of the run there), check_greedy (hold the first N
 finished requests against un-paged greedy decoding through
 transformer_forward; the job fails when a token the engine chose is
 further than GREEDY_TOL below the reference's best logit).
@@ -32,6 +36,7 @@ from __future__ import annotations
 import json
 import logging
 import time
+from dataclasses import asdict
 
 from tf_operator_tpu.rendezvous.context import JobContext
 
@@ -75,6 +80,11 @@ def synthesize_requests(wl: dict, vocab: int):
     return reqs
 
 
+def engine_counters(counters) -> dict:
+    """``EngineCounters`` as eval_metrics entries."""
+    return {f"engine_{k}": float(v) for k, v in asdict(counters).items()}
+
+
 def _quantile(xs, q):
     if not xs:
         return 0.0
@@ -103,6 +113,7 @@ def main(ctx: JobContext) -> None:
         greedy_reference_gaps,
     )
     from tf_operator_tpu.train.metrics import run_report
+    from tf_operator_tpu.train.profile import profile_ctx
 
     wl = ctx.workload
     cfg = preset_from_workload(wl)
@@ -140,6 +151,7 @@ def main(ctx: JobContext) -> None:
                     "requests_active": float(payload["active"]),
                     "requests_completed": float(payload["completed"]),
                     "tokens_generated": float(payload["generated"]),
+                    **engine_counters(payload["counters"]),
                 })
             return
         req = payload
@@ -161,7 +173,10 @@ def main(ctx: JobContext) -> None:
                 name=span_name(req.rid, "finished"),
             )
 
-    res = engine.run(requests, on_event=on_event)
+    # profile_dir (the key the lm/resnet workloads honour): an xplane of
+    # the run, the engine's serve.* spans beside the device's ops
+    with profile_ctx(wl.get("profile_dir")):
+        res = engine.run(requests, on_event=on_event)
 
     leaked = res.free_pages_start - res.free_pages_end
     if leaked:
@@ -174,6 +189,9 @@ def main(ctx: JobContext) -> None:
         "requests_completed": float(res.completed),
         "tokens_generated": float(res.generated_tokens),
         "tokens_per_s": float(res.tokens_per_s),
+        **engine_counters(res.counters),
+        "engine_pool_peak_in_use": float(res.pool_peak_in_use),
+        "engine_pool_alloc_failures": float(res.pool_alloc_failures),
     })
     # Engine vs model: paged, cached, chunk-prefilled decoding against
     # the plain forward pass on the same weights.
