@@ -19,8 +19,10 @@ reads from the ``run report: {...}`` line each worker logs. Each phase
 prints one JSON line; the LAST line is the verdict,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": 1}}``,
 and the exit code is 0 only then. A phase that fails, a worker that was
-not on a TPU, or a compiled program without its Pallas kernels
-(``tpu_custom_call``) ends ``"ok": false`` and exit 1 — so on a machine
+not on a TPU, a compiled program without its Pallas kernels
+(``tpu_custom_call``), or an engine program that copies or slices a whole
+KV pool side or layer (``*_pool_copies`` of ``ServeEngine.compile()``)
+ends ``"ok": false`` and exit 1 — so on a machine
 with no TPU this script fails by construction, whatever ``--preset``.
 
     python chip_smoke.py                  # the chip run (what the driver runs)
@@ -183,6 +185,10 @@ def serve_phase(server: str, preset: str) -> dict:
         ),
         "kernel_in_decode": r.get("decode_tpu_custom_calls", 0) > 0,
         "kernel_in_prefill": r.get("prefill_tpu_custom_calls", 0) > 0,
+        # the KV pool is written and read where it lies: neither program
+        # copies, slices or relays a whole pool side or layer of one
+        "pool_in_place": r.get("decode_pool_copies") == 0
+        and r.get("prefill_pool_copies") == 0,
     }
     return r
 

@@ -13,6 +13,7 @@ described chip cannot be read back without one). A compile that passes is
 not a chip run: no time, no result comes from here.
 """
 
+import contextlib
 import importlib
 
 import pytest
@@ -43,30 +44,42 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
-def no_cache():
+@contextlib.contextmanager
+def _cache_off():
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
 
 
-def _kernel_names(fn, *args) -> list:
-    """Compile for the described chip; the HLO instruction name of every
-    Mosaic kernel in it, instance suffix cut (``%flash_fwd.3 = ...
+@pytest.fixture
+def no_cache():
+    with _cache_off():
+        yield
+
+
+def _kernel_names_in(text: str) -> list:
+    """The HLO instruction name of every Mosaic kernel in a compiled
+    program's text, instance suffix cut (``%flash_fwd.3 = ...
     custom-call(...)`` gives ``flash_fwd``). The profiler's trace prints an
     op as its instruction text without metadata, so this name is all a
     reader of a trace has to tell kernels apart."""
     import re
 
-    text = jax.jit(fn).lower(*args).compile().as_text()
     return [m.group(1) for m in re.finditer(
         r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom-call\([^\n]*"
         r'custom_call_target="tpu_custom_call"', text)]
+
+
+def _kernel_names(fn, *args) -> list:
+    """Compile for the described chip; its Mosaic kernels by name."""
+    return _kernel_names_in(jax.jit(fn).lower(*args).compile().as_text())
 
 
 def _kernels(fn, *args) -> int:
@@ -156,11 +169,98 @@ def test_flash_attention_decode_compiles_for_v5e(one_chip, no_cache, dtype,
 
     pool = a((n_pages, h_kv, page, d), dtype)
     names = _kernel_names(
-        lambda q, k, v, pt, sl: fa._decode_call(q, k, v, pt, sl, False),
+        lambda q, k, v, pt, sl: fa._decode_call(
+            q, k[None], v[None], 0, pt, sl, False),
         a((s_n, h, d), dtype), pool, pool, a((s_n, p), jnp.int32),
         a((s_n,), jnp.int32),
     )
     assert names == ["paged_attention"]
+
+
+# ---- the serve engine's two programs: the KV pool stays where it lies ------
+
+SERVE1 = dict(page_size=64, pool_pages=320, max_slots=16, prefill_chunk=128)
+
+
+@pytest.fixture(scope="module")
+def serve1_engine(one_chip):
+    """``ServeEngine`` at the benchmark's -serve1 shapes (Mistral-7B widths,
+    pool ``[L, 321, 8, 64, 128]``, 20 pages a sequence) with 2 layers, from
+    abstract parameters, both programs compiled for the described chip by
+    the engine's own ``compile()``. This process's backend is the CPU, so
+    the kernel dispatch is steered to "tpu" here, in the test."""
+    from unittest import mock
+
+    from tf_operator_tpu.models.transformer import init_transformer, preset
+    from tf_operator_tpu.serve.engine import ServeConfig, ServeEngine
+
+    cfg = preset("llama2-7b", n_kv_heads=8, d_ff=14336, n_layers=2,
+                 max_seq=1280)
+    shapes = jax.eval_shape(
+        lambda: init_transformer(jax.random.PRNGKey(0), cfg))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes)
+    engine = ServeEngine(cfg, params, ServeConfig(**SERVE1))
+    with _cache_off(), mock.patch.object(jax, "default_backend",
+                                         lambda: "tpu"):
+        report = engine.compile()
+    return engine, report
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_engine_programs_keep_the_pool_in_place_on_v5e(serve1_engine, program):
+    """The pool enters, is written, is read and leaves each program in ONE
+    layout, in place. Before PR 25 the write's scatter forced a layout of
+    its own on the pool: four whole-pool ``copy`` in each program (2 GB
+    moved each) and a slice + relayout of a whole layer in front of every
+    kernel call."""
+    import re
+
+    from tf_operator_tpu.serve.engine import pool_copies
+
+    engine, report = serve1_engine
+    text = getattr(engine, f"_{program}").as_text()
+    pool = engine._pool_shape()
+    assert pool == (2, 321, 8, 64, 128)
+    dims = ",".join(map(str, pool))
+    layer = ",".join(map(str, pool[1:]))
+
+    def results(shape):  # opcodes of executed-or-fused results of that shape
+        return re.findall(
+            r"= \w+\[" + shape + r"\]\S* ([\w\-]+)\(", text)
+
+    # 1. nothing pool-shaped but names, views and updates in place
+    moved = [op for op in results(dims) if op in (
+        "copy", "copy-done", "transpose", "slice", "dynamic-slice")]
+    assert not moved, moved
+    # 2. nothing layer-shaped at all: no slice, no relayout feeding the kernel
+    assert not results("1," + layer) and not results(layer)
+    # ...which is what the engine's own counter says, here and in compile()
+    assert pool_copies(text, pool) == 0
+    assert report[f"{program}_pool_copies"] == 0
+    # 3. the pools still alias parameter -> result; one kernel a layer
+    assert "input_output_alias" in text
+    kernels = _kernel_names_in(text)
+    assert kernels.count("paged_attention") == 2, kernels
+    assert report[f"{program}_tpu_custom_calls"] >= 2
+
+
+@pytest.mark.parametrize("page", [8, 16])
+def test_engine_compile_counts_no_pool_copies_at_tiny(page):
+    """The same counter through ``compile()`` on this process's own backend
+    (the CPU: gather reference, no kernel), as every engine reports it."""
+    from tf_operator_tpu.models.transformer import init_transformer, preset
+    from tf_operator_tpu.serve.engine import ServeConfig, ServeEngine
+
+    cfg = preset("tiny")
+    engine = ServeEngine(
+        cfg, init_transformer(jax.random.PRNGKey(0), cfg),
+        ServeConfig(page_size=page, pool_pages=24, max_slots=3,
+                    prefill_chunk=8))
+    report = engine.compile()
+    assert report["decode_pool_copies"] == 0
+    assert report["prefill_pool_copies"] == 0
 
 
 # ---- grouped matmul: fwd, dx, dw, with and without the fused row scale ----

@@ -19,6 +19,7 @@ from tf_operator_tpu.serve.kvcache import (  # noqa: E402
     PagePool,
     SequencePages,
     pages_needed,
+    write_rows,
 )
 
 
@@ -96,10 +97,15 @@ def test_decode_matches_full_prefix_ragged(h, h_kv):
         np.testing.assert_allclose(out[i], want, atol=2e-5, rtol=2e-5)
 
 
-def test_decode_kernel_interpret_matches_reference():
+@pytest.mark.parametrize("pool", ["4d", "5d"])
+def test_decode_kernel_interpret_matches_reference(pool):
     """The Pallas decode kernel (scalar-prefetch page walk, interpret
     mode off-TPU) against the pure-JAX gather reference — same ragged
-    lengths, scrambled page ids so the index_map indirection is real."""
+    lengths, scrambled page ids so the index_map indirection is real.
+    ``5d``: the serve engine's form — the kernel and the reference get
+    the WHOLE [layers, pages, ...] pool and the layer to read (the
+    kernel through its BlockSpec index map), held against the 4-D read
+    of ``pool[layer]``; the other layers hold other values."""
     h, h_kv, d = 4, 2, 128  # lane-width head_dim: the kernel's home turf
     k_seqs, v_seqs, kp, vp, table, lens = _paged_prefix(
         RAGGED, PAGE, h_kv, d, seed=3, scramble=True
@@ -107,9 +113,26 @@ def test_decode_kernel_interpret_matches_reference():
     rng = np.random.RandomState(4)
     q = jnp.asarray(rng.randn(len(RAGGED), h, d).astype(np.float32))
     ref = np.asarray(paged_decode_reference(q, kp, vp, table, lens))
-    krn = np.asarray(
-        flash_attention_decode(q, kp, vp, table, lens, interpret=True)
-    )
+    if pool == "5d":
+        kp5 = jnp.stack([kp + 1.0, kp, kp * 2.0])
+        vp5 = jnp.stack([vp - 1.0, vp, vp * 0.5])
+        krn = np.asarray(flash_attention_decode(
+            q, kp5, vp5, table, lens, interpret=True, layer=1))
+        ref5 = np.asarray(
+            paged_decode_reference(q, kp5, vp5, table, lens, layer=1))
+        np.testing.assert_array_equal(ref5, ref)
+        # bit for bit the 4-D kernel read of that layer, and not another's
+        np.testing.assert_array_equal(krn, np.asarray(flash_attention_decode(
+            q, kp5[1], vp5[1], table, lens, interpret=True)))
+        other = np.asarray(flash_attention_decode(
+            q, kp5, vp5, table, lens, interpret=True, layer=2))
+        assert np.abs(other - ref).max() > 1e-3
+        with pytest.raises(ValueError, match="layer="):
+            flash_attention_decode(q, kp5, vp5, table, lens)
+    else:
+        krn = np.asarray(
+            flash_attention_decode(q, kp, vp, table, lens, interpret=True)
+        )
     np.testing.assert_allclose(krn, ref, atol=2e-5, rtol=2e-5)
     # and both against the full-attention oracle
     for i, L in enumerate(RAGGED):
@@ -149,6 +172,49 @@ def test_decode_incremental_accumulation():
             )
         )[0]
         np.testing.assert_allclose(out, full[t], atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("page", [16, 64])
+def test_write_rows_matches_the_scatter_it_replaces(page, program):
+    """``write_rows`` against ``kp.at[l, pid, :, row].set(k)``, the write
+    it replaced (same values, another scatter: PERF.md §6, PR 25), with
+    the indices the engine's two programs build — a decode step with
+    inactive slots, a prefill chunk that crosses a page boundary and ends
+    in padding, both steering what is masked to the trash page. Bit for
+    bit on every page but the trash page, whose rows nobody reads; the
+    other layers untouched."""
+    L, P, h_kv, d, l = 3, 9, 2, 128, 1
+    trash = P
+    rng = np.random.RandomState(page)
+    kp = jnp.asarray(rng.randn(L, P + 1, h_kv, page, d).astype(np.float32))
+    vp = jnp.asarray(rng.randn(L, P + 1, h_kv, page, d).astype(np.float32))
+    if program == "decode":  # engine.decode_step's pid / row
+        table = jnp.asarray(rng.permutation(P)[:8].reshape(4, 2).astype(np.int32))
+        pos = jnp.asarray([page - 1, 0, page + 3, 5], jnp.int32)
+        active = jnp.asarray([True, False, True, False])
+        pid = jnp.where(active, table[jnp.arange(4), pos // page], trash)
+    else:  # engine.prefill_chunk's: one sequence, positions start..start+c
+        table_row = jnp.asarray([4, 7, 2], jnp.int32)
+        c, n_valid = 24, 19
+        pos = (page - 10) + jnp.arange(c)
+        pid = jnp.where(jnp.arange(c) < n_valid, table_row[pos // page], trash)
+    row = pos % page
+    n = int(pos.shape[0])
+    k = jnp.asarray(rng.randn(n, h_kv, d).astype(np.float32))
+    v = jnp.asarray(rng.randn(n, h_kv, d).astype(np.float32))
+
+    def old(kp, vp, k, v, pid, row):
+        return kp.at[l, pid, :, row].set(k), vp.at[l, pid, :, row].set(v)
+
+    want = jax.jit(old)(kp, vp, k, v, pid, row)
+    got = jax.jit(lambda *a: write_rows(a[0], a[1], l, *a[2:]))(
+        kp, vp, k, v, pid, row)
+    for g, w, before in zip(got, want, (kp, vp)):
+        g, w, before = np.asarray(g), np.asarray(w), np.asarray(before)
+        np.testing.assert_array_equal(g[:, :trash], w[:, :trash])
+        assert (g[l, :trash] != before[l, :trash]).any()  # something landed
+        np.testing.assert_array_equal(g[[0, 2]], before[[0, 2]])
 
 
 def test_pagepool_alloc_free_leak():

@@ -129,6 +129,72 @@ def test_engine_static_mode_also_completes(tiny_engine):
     assert res.steps > cont.steps
 
 
+HLO_POOL = "f32[2,9,2,8,16]{4,3,2,1,0}"
+HLO_LAYER = "f32[9,2,8,16]{3,2,1,0}"
+HLO_CASES = {
+    # what the engine's programs hold today: names, views, updates in place
+    "in_place": (0, f"""
+%fused_scatter (p0: f32[2,9,2,8,16], p1: s32[4,4], p2: f32[4,16]) -> f32[2,9,2,8,16] {{
+  %p0 = {HLO_POOL} parameter(0)
+  ROOT %scatter.0 = {HLO_POOL} scatter(%p0, %p1, %p2), to_apply=%add
+}}
+
+%body (arg: (s32[], f32[2,9,2,8,16])) -> (s32[], f32[2,9,2,8,16]) {{
+  %gte.1 = {HLO_POOL} get-tuple-element(%arg), index=1
+  %dus.1 = {HLO_POOL} dynamic-update-slice(%gte.1, %u, %i, %j, %k, %l, %m)
+}}
+
+ENTRY %main (kp: f32[2,9,2,8,16]) -> f32[2,9,2,8,16] {{
+  %kp = {HLO_POOL} parameter(0)
+  %fusion.2 = {HLO_POOL} fusion(%kp, %idx, %upd), kind=kCustom, calls=%fused_scatter
+  %bitcast.1 = {HLO_POOL} bitcast(%flat)
+  %write.1 = {HLO_POOL} custom-call(%fusion.2, %k), custom_call_target="tpu_custom_call", output_to_operand_aliasing={{{{}}: (0, {{}})}}
+  ROOT %out = {HLO_POOL} get-tuple-element(%while.1), index=1
+}}
+"""),
+    # what they held before PR 25: a relayout of the pool in and out, and
+    # a slice + relayout fusion of a whole layer in front of a kernel
+    "relaid": (5, f"""
+%fused_slice (p0: f32[2,9,2,8,16]) -> f32[9,2,8,16] {{
+  %p0 = f32[2,9,2,8,16]{{4,2,3,1,0}} parameter(0)
+  %slice.9 = f32[1,9,2,8,16]{{4,2,3,1,0}} slice(%p0), slice={{[1:2], [0:9], [0:2], [0:8], [0:16]}}
+  ROOT %bitcast.9 = {HLO_LAYER} bitcast(%slice.9)
+}}
+
+ENTRY %main (kp: f32[2,9,2,8,16]) -> f32[2,9,2,8,16] {{
+  %kp = {HLO_POOL} parameter(0)
+  %copy.1 = f32[2,9,2,8,16]{{4,2,3,1,0}} copy(%kp)
+  %slice.2 = f32[1,9,2,8,16]{{4,2,3,1,0:T(8,128)S(1)}} slice(%copy.1), slice={{[0:1], [0:9], [0:2], [0:8], [0:16]}}
+  %copy_bitcast_fusion = {HLO_LAYER} fusion(%slice.2), kind=kLoop, calls=%fused_slice
+  %slice_bitcast_fusion = {HLO_LAYER} fusion(%copy.1), kind=kLoop, calls=%fused_slice
+  ROOT %copy.2 = {HLO_POOL} copy(%copy.1)
+}}
+"""),
+    # a pool staged through fast memory is a copy too; its start is not a second one
+    "staged": (1, f"""
+ENTRY %main (kp: f32[2,9,2,8,16]) -> f32[2,9,2,8,16] {{
+  %kp = {HLO_POOL} parameter(0)
+  %copy-start.3 = ({HLO_POOL}, {HLO_POOL}, u32[]) copy-start(%kp)
+  ROOT %copy-done.3 = f32[2,9,2,8,16]{{4,3,2,1,0:S(1)}} copy-done(%copy-start.3)
+}}
+"""),
+}
+
+
+@pytest.mark.serve
+@pytest.mark.parametrize("case", sorted(HLO_CASES))
+def test_pool_copies_counts_what_moves_a_pool_or_a_layer(case):
+    """``compile()``'s ``<program>_pool_copies`` on hand-written HLO: the
+    executed instructions whose result is a whole pool side or layer and
+    that are not a name, a view or an update in place. Other shapes (a
+    weight, the same dims in another order) never count."""
+    from tf_operator_tpu.serve.engine import pool_copies
+
+    want, text = HLO_CASES[case]
+    assert pool_copies(text, (2, 9, 2, 8, 16)) == want
+    assert pool_copies(text, (2, 9, 8, 2, 16)) == 0
+
+
 @pytest.mark.serve
 def test_engine_is_deterministic(tiny_engine):
     a = tiny_engine.run(_requests(), clock=_fake_clock())
@@ -150,6 +216,9 @@ def test_engine_agrees_with_unpaged_greedy_decoding(tiny_engine, compiled):
         report = tiny_engine.compile()
         assert report["decode_tpu_custom_calls"] == 0  # CPU: no Mosaic
         assert report["decode_compile_s"] >= 0
+        # neither program copies or slices a whole pool side or layer
+        assert report["decode_pool_copies"] == 0
+        assert report["prefill_pool_copies"] == 0
     res = tiny_engine.run(_requests(), clock=_fake_clock())
     for req in res.requests[:3]:
         n_exact, max_gap = greedy_reference_gaps(

@@ -673,28 +673,31 @@ def flash_attention(
 # GQA group — a decode step has exactly one query position per sequence.
 
 
-def paged_decode_reference(q, k_pages, v_pages, page_table, seq_lens):
+def paged_decode_reference(q, k_pages, v_pages, page_table, seq_lens,
+                           layer: Optional[int] = None):
     """Pure-JAX paged decode attention — the correctness oracle and the
     off-TPU fallback (same contract as the decode kernel).
 
     q [s, h, d] (one query token per sequence), k_pages/v_pages
-    [n_pages, h_kv, page_size, d], page_table [s, p] int32 (page ids in
+    [n_pages, h_kv, page_size, d] — or the whole [n_layers, n_pages,
+    h_kv, page_size, d] pool with ``layer`` naming the one to read —
+    page_table [s, p] int32 (page ids in
     sequence order; rows padded with any valid id past the live prefix),
     seq_lens [s] int32 = valid K/V tokens per sequence INCLUDING the
-    current position. Gathers pages to [s, h_kv, p·page_size, d], masks
+    current position. Gathers pages to [s, h_kv, p·page_size, d] (one
+    gather straight out of the pool: no layer is sliced off first), masks
     positions >= seq_len with the NEG_INF sentinel, f32 softmax. Rows
     with seq_len == 0 produce the uniform-softmax artifact (see
     reference_attention_lse) — callers mask inactive slots out."""
     s_n, h, d = q.shape
-    n_pages, h_kv, page_size, _ = k_pages.shape
+    h_kv, page_size = k_pages.shape[-3:-1]
     p = page_table.shape[1]
     g = h // h_kv
     scale = d**-0.5
 
     def gather(pages):  # [s, p, h_kv, page, d] -> [s, h_kv, p·page, d]
-        return jnp.swapaxes(pages[page_table], 1, 2).reshape(
-            s_n, h_kv, p * page_size, d
-        )
+        got = pages[page_table] if layer is None else pages[layer, page_table]
+        return jnp.swapaxes(got, 1, 2).reshape(s_n, h_kv, p * page_size, d)
 
     k, v = gather(k_pages), gather(v_pages)
     q5 = q.reshape(s_n, h_kv, g, d).astype(jnp.float32) * scale
@@ -741,8 +744,8 @@ def _decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(live)
     def _step():
         q = q_ref[0, 0].reshape(g, d).astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)  # [page_size, d]
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, 0].astype(jnp.float32)  # [page_size, d]
+        v = v_ref[0, 0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [g, page_size]
@@ -767,12 +770,12 @@ def _decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0] = (acc_scr[:, :] / l[:, None]).reshape(g, d).astype(o_ref.dtype)
 
 
-def _decode_call(q, k_pages, v_pages, page_table, seq_lens, interpret):
+def _decode_call(q, k_pool, v_pool, layer, page_table, seq_lens, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     s_n, h, d = q.shape
-    _, h_kv, page_size, _ = k_pages.shape
+    _, _, h_kv, page_size, _ = k_pool.shape
     p = page_table.shape[1]
     g = h // h_kv
     q4 = q.reshape(s_n, h_kv, g, d)
@@ -783,20 +786,21 @@ def _decode_call(q, k_pages, v_pages, page_table, seq_lens, interpret):
     # the whole paging trick. One K/V block is one (page, kv-head) slab
     # [page_size, d]: the tiled minor dims Mosaic requires of a block
     # (sublane-aligned page, whole head_dim) — the reason the pools are
-    # laid out [n_pages, h_kv, page_size, d].
+    # laid out [n_layers, n_pages, h_kv, page_size, d]. The kernel takes
+    # the WHOLE pool and ``layer`` (a Python int) sits in the index map:
+    # handing it ``pool[layer]`` makes XLA materialise that layer (a
+    # slice of the whole layer, 84 MB at the -serve1 shapes) before every call.
+    pool_spec = pl.BlockSpec(
+        (1, 1, 1, page_size, d),
+        lambda si, hk, pi, pt, sl: (layer, pt[si, pi], hk, 0, 0),
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s_n, h_kv, p),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda si, hk, pi, pt, sl: (si, hk, 0, 0)),
-            pl.BlockSpec(
-                (1, 1, page_size, d),
-                lambda si, hk, pi, pt, sl: (pt[si, pi], hk, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, page_size, d),
-                lambda si, hk, pi, pt, sl: (pt[si, pi], hk, 0, 0),
-            ),
+            pool_spec,
+            pool_spec,
         ],
         out_specs=pl.BlockSpec(
             (1, 1, g, d), lambda si, hk, pi, pt, sl: (si, hk, 0, 0)
@@ -817,7 +821,7 @@ def _decode_call(q, k_pages, v_pages, page_table, seq_lens, interpret):
         interpret=interpret,
         name="paged_attention",
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q4, k_pages, v_pages)
+      q4, k_pool, v_pool)
     return o.reshape(s_n, h, d)
 
 
@@ -829,6 +833,7 @@ def flash_attention_decode(
     seq_lens,
     interpret: Optional[bool] = None,
     force_kernel: Optional[bool] = None,
+    layer: Optional[int] = None,
 ):
     """Paged decode attention: one query token per sequence against a
     paged K/V cache.
@@ -836,7 +841,12 @@ def flash_attention_decode(
     q [s, h, d]; k_pages/v_pages [n_pages, h_kv, page_size, d] (the
     serve/kvcache.py pool layout — head-major so one (page, kv-head)
     slab is a tile-aligned [page_size, d] block the TPU compiler
-    accepts); page_table [s, max_pages] int32;
+    accepts), or the whole pool [n_layers, n_pages, h_kv, page_size, d]
+    with ``layer`` (a Python int) naming the layer to read: the serve
+    engine's form. The kernel always takes a 5-D pool and puts the layer
+    in its BlockSpec index map, so no ``pool[layer]`` is ever cut out of
+    the pool in front of it (a 4-D pool is the one-layer case, a free
+    reshape); page_table [s, max_pages] int32;
     seq_lens [s] int32 (valid K/V length per sequence, INCLUDING the
     just-written current position — decode attends to itself). Returns
     [s, h, d] in q's dtype. GQA-native: h % h_kv folds into the q tile
@@ -853,17 +863,17 @@ def flash_attention_decode(
     seq_lens == 0 are inactive slots: both paths return garbage-but-
     finite output there (zeros from the kernel, the uniform artifact
     from the reference) — callers mask, never read."""
-    if q.ndim != 3 or k_pages.ndim != 4:
+    if q.ndim != 3 or k_pages.ndim != (4 if layer is None else 5):
         raise ValueError(
             f"decode shapes: q [s,h,d] (got {q.shape}), pages "
-            f"[n,h_kv,page,d] (got {k_pages.shape})"
+            f"[n,h_kv,page,d], or [layers,n,h_kv,page,d] with layer= "
+            f"(got {k_pages.shape}, layer={layer})"
         )
     if k_pages.shape != v_pages.shape:
         raise ValueError(f"k/v pool mismatch: {k_pages.shape} vs {v_pages.shape}")
-    h, h_kv = q.shape[1], k_pages.shape[1]
+    h, h_kv, page_size = q.shape[1], *k_pages.shape[-3:-1]
     if h % h_kv:
         raise ValueError(f"q heads {h} not a multiple of kv heads {h_kv}")
-    page_size = k_pages.shape[2]
     sublanes = 8 * max(1, 4 // jnp.dtype(k_pages.dtype).itemsize)
     aligned = page_size % sublanes == 0
     on_tpu = jax.default_backend() == "tpu"
@@ -877,7 +887,11 @@ def flash_attention_decode(
                 f"page_size={page_size} is not a multiple of {sublanes} "
                 f"({jnp.dtype(k_pages.dtype).name} sublanes)",
             )
-        return paged_decode_reference(q, k_pages, v_pages, page_table, seq_lens)
+        return paged_decode_reference(
+            q, k_pages, v_pages, page_table, seq_lens, layer
+        )
+    if layer is None:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     return _decode_call(
-        q, k_pages, v_pages, page_table, seq_lens, bool(interpret)
+        q, k_pages, v_pages, layer, page_table, seq_lens, bool(interpret)
     )

@@ -15,10 +15,11 @@ Two compiled functions, both fixed-shape:
 
 - the DECODE step: every slot advances one token. Each layer computes
   single-position q/k/v, rotates at the token's absolute position
-  (rope_at_positions), scatters k/v into the slot's current page row,
-  and attends through the page table (ops.flash_attention_decode —
-  kernel on TPU, gather reference off-TPU). Inactive slots steer their
-  writes to the pool's trash page and mask attention with seq_len 0.
+  (rope_at_positions), stores k/v into the slot's current page row
+  (kvcache.write_rows), and attends through the page table
+  (ops.flash_attention_decode — kernel on TPU, gather reference
+  off-TPU). Inactive slots steer their writes to the pool's trash page
+  and mask attention with seq_len 0.
 
 - the PREFILL chunk: ``prefill_chunk`` prompt tokens of ONE sequence.
   The chunk's C positions are treated as C pseudo-sequences sharing the
@@ -28,6 +29,17 @@ Two compiled functions, both fixed-shape:
   path instead of a second attention implementation. The last chunk's
   final logits yield the request's first generated token (the TTFT
   boundary).
+
+Both take the two KV pools donated and hand them back, and between
+parameter and result the pool is never copied, sliced or relaid: the
+write is a scatter whose only window dimension is head_dim (the pool's
+minor-most, so XLA updates the head-major pool where it lies) and the
+kernel takes the whole 5-D pool with the layer in its BlockSpec index
+map. The earlier ``kp.at[l, pid, :, row].set(k)`` + ``kp[l]`` forced a
+row-major-pages layout on the pool for the scatter's (head, head_dim)
+window: four whole-pool relayout copies a call and a slice of a whole
+layer in front of every kernel. ``compile()`` counts what is left of
+that (``<program>_pool_copies``, 0).
 
 Greedy argmax sampling, f32 compute throughout: serving determinism is
 what the correctness oracle (tests/test_serve.py) and the seeded bench
@@ -45,6 +57,7 @@ lists what each covers. ``EngineCounters`` are plain ints, always on.
 
 from __future__ import annotations
 
+import re
 import time
 from collections import deque
 from dataclasses import asdict, dataclass, field
@@ -57,6 +70,7 @@ from tf_operator_tpu.serve.kvcache import (
     PoolExhausted,
     SequencePages,
     pages_needed,
+    write_rows,
 )
 
 
@@ -186,6 +200,64 @@ def greedy_reference_gaps(cfg, params, prompt: List[int], tokens: List[int]):
     return int(jnp.sum(gaps == 0.0)), float(jnp.max(gaps))
 
 
+# one HLO instruction: ``%name = f32[2,321,8,64,128]{layout} opcode(...``
+_HLO_ARRAY_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\((.*)$"
+)
+_HLO_FREE = frozenset({
+    # name or view a buffer, or update it where it lies
+    "parameter", "get-tuple-element", "bitcast", "while", "conditional",
+    "call", "scatter", "dynamic-update-slice",
+})
+
+
+def pool_copies(hlo_text: str, pool_shape) -> int:
+    """How many instructions of a compiled program MATERIALISE a whole KV
+    pool side or one whole layer of it: executed instructions (fusion
+    bodies are read only to classify their fusion) whose result has the
+    pool's shape ``[L, P+1, h_kv, page, hd]`` or a layer's (``[1, P+1,
+    …]`` / ``[P+1, …]``) and that are neither a name or view of a buffer
+    nor an update in place (a scatter or dynamic-update-slice, bare or as
+    a fusion's root; a custom call that aliases its operand). What is
+    left is a ``copy``, ``slice``, ``copy-done`` or relayout fusion: data
+    movement of 84 MB to 1 GB at the -serve1 shapes that changes no
+    value. 0 when the pool is written and read in one layout, in place."""
+    dims = [str(int(d)) for d in pool_shape]
+    shapes = {",".join(dims), ",".join(["1"] + dims[1:]), ",".join(dims[1:])}
+    bodies: Dict[str, List[str]] = {}
+    name = None
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split()[1 if line.startswith("ENTRY") else 0].lstrip("%")
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", hlo_text))
+
+    def in_place(rest: str) -> bool:
+        called = re.search(r"calls=%([\w.\-]+)", rest)
+        return bool(called) and any(
+            " scatter(" in ln or " dynamic-update-slice(" in ln
+            for ln in bodies.get(called.group(1), ())
+        )
+
+    count = 0
+    for comp, lines in bodies.items():
+        if comp in fused:
+            continue
+        for line in lines:
+            m = _HLO_ARRAY_INSTR.match(line)
+            if not m or m.group(1) not in shapes or m.group(2) in _HLO_FREE:
+                continue
+            op, rest = m.group(2), m.group(3)
+            if op == "fusion" and in_place(rest):
+                continue
+            if op == "custom-call" and "output_to_operand_aliasing" in rest:
+                continue
+            count += 1
+    return count
+
+
 class _Slot:
     __slots__ = ("req", "pages", "seq_len", "prefill_pos", "cur_tok", "generated")
 
@@ -213,12 +285,18 @@ class ServeEngine:
         self.cfg = cfg
         self.scfg = scfg
         # f32 master weights: serving determinism + the logits-parity
-        # oracle; pools match.
+        # oracle; pools match. Abstract parameters (ShapeDtypeStructs,
+        # with the sharding of a described device if any) pass through:
+        # such an engine can ``compile()`` and nothing else.
         import jax.numpy as jnp
 
-        self.params = jax.tree_util.tree_map(
-            lambda a: jnp.asarray(a, jnp.float32), params
-        )
+        def f32(a):
+            if isinstance(a, jax.ShapeDtypeStruct):
+                return jax.ShapeDtypeStruct(a.shape, jnp.float32,
+                                            sharding=a.sharding)
+            return jnp.asarray(a, jnp.float32)
+
+        self.params = jax.tree_util.tree_map(f32, params)
         self.max_pages_per_seq = pages_needed(cfg.max_seq, scfg.page_size)
         self._jit_build()
 
@@ -244,7 +322,10 @@ class ServeEngine:
             """Shared per-layer body: x [n, d] at absolute positions pos
             [n]; writes each row's k/v to (write_pid[i], write_row[i])
             then attends through ``table`` with per-row lengths ``lens``.
-            Returns (kp, vp, final hidden [n, d])."""
+            The pools [L, page, h_kv, row, hd] are carried WHOLE through
+            every layer — written by ``write_rows``, read by the kernel
+            at ``layer=l`` — and never indexed by layer here: ``kp[l]``
+            is a copy of a layer. Returns (kp, vp, final hidden [n, d])."""
             n = x.shape[0]
             lp = params["layers"]
             for l in range(L):
@@ -254,12 +335,8 @@ class ServeEngine:
                 v = (h @ lp["wv"][l]).reshape(n, -1, hd)
                 q = rope_at_positions(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
                 k = rope_at_positions(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-                # pools are [L, page, h_kv, row, hd]: the two index
-                # arrays straddle the head slice, so the indexed result
-                # is [n, h_kv, hd] — k/v's own shape
-                kp = kp.at[l, write_pid, :, write_row].set(k)
-                vp = vp.at[l, write_pid, :, write_row].set(v)
-                attn = flash_attention_decode(q, kp[l], vp[l], table, lens)
+                kp, vp = write_rows(kp, vp, l, k, v, write_pid, write_row)
+                attn = flash_attention_decode(q, kp, vp, table, lens, layer=l)
                 x = x + attn.reshape(n, -1) @ lp["wo"][l]
                 h2 = _rms_norm(x, lp["mlp_norm"][l], cfg.norm_eps)
                 x = x + (
@@ -309,9 +386,13 @@ class ServeEngine:
         and serve with the compiled executables from here on: a server
         warms up before it takes traffic, so no request's TTFT carries a
         compile, and a program the device's compiler refuses fails here,
-        by name. Returns each program's compile seconds and how many
+        by name. Returns each program's compile seconds, how many
         ``tpu_custom_call``s (Pallas kernels) its compiled text holds —
-        0 means the step runs the gather reference, not the kernel."""
+        0 means the step runs the gather reference, not the kernel — and
+        ``<program>_pool_copies``: how many of its instructions
+        materialise a whole pool side or a whole layer of one
+        (``pool_copies``; 0 while the pool is written and read in place,
+        in one layout)."""
         import jax
         import jax.numpy as jnp
 
@@ -338,9 +419,9 @@ class ServeEngine:
             t0 = time.perf_counter()
             compiled = fn.lower(*args).compile()
             out[f"{name}_compile_s"] = round(time.perf_counter() - t0, 3)
-            out[f"{name}_tpu_custom_calls"] = compiled.as_text().count(
-                "tpu_custom_call"
-            )
+            text = compiled.as_text()
+            out[f"{name}_tpu_custom_calls"] = text.count("tpu_custom_call")
+            out[f"{name}_pool_copies"] = pool_copies(text, self._pool_shape())
             setattr(self, f"_{name}", compiled)
         return out
 

@@ -5,8 +5,18 @@ lives in PAGES of ``page_size`` token slots, preallocated as one device
 pool per layer side — shape [n_layers, num_pages + 1, n_kv_heads,
 page_size, head_dim] (head-major inside a page: the decode kernel's
 block is one (page, kv-head) slab [page_size, head_dim], which is what
-the TPU compiler can tile). A sequence owns an ordered page table (host-side
-int32 row); growing by one token touches exactly one page row, and
+the TPU compiler can tile). Inside a compiled program the pool keeps
+that one layout from parameter to result: ``write_rows`` stores a token's
+K/V rows with a scatter whose only window is ``head_dim`` (in place, no
+relayout), and the kernel takes the WHOLE pool with the layer in its
+BlockSpec index map (``ops.flash_attention_decode(..., layer=l)``), so no
+layer is sliced out for it. The scatter this replaced,
+``pool.at[l, pid, :, row].set(k)``, has a (head, head_dim) window, which
+XLA serves only from a row-major-pages layout: it relaid the whole pool
+at every program's entry and exit.
+
+A sequence owns an ordered page table (host-side int32 row); growing by
+one token touches exactly one page row, and
 completion returns the pages to a free list with NO copying — the next
 sequence overwrites them in place (pages carry no ownership state on
 device; the page table is the only source of truth).
@@ -53,6 +63,30 @@ def pool_bytes(
         n_layers * (num_pages + 1) * page_size * n_kv_heads * head_dim
     )
     return 2 * per_side * dtype_bytes
+
+
+def write_rows(kp, vp, layer: int, k, v, pid, row):
+    """Store ``k[i]`` / ``v[i]`` ([n, n_kv_heads, head_dim]) at
+    ``pool[layer, pid[i], :, row[i], :]`` and return the two pools —
+    updated IN PLACE inside a compiled program whose caller donates them.
+
+    One XLA scatter a side whose only window dimension is ``head_dim``,
+    the pool's minor-most: the index arrays name (page, head, row), so
+    the scatter stores ``n * n_kv_heads`` rows of ``head_dim`` values and
+    is content with the pool's own head-major layout. Not
+    ``kp.at[layer, pid, :, row].set(k)``: there the two index arrays
+    straddle the head dimension, the scatter's window is (head,
+    head_dim), XLA wants those two adjacent, puts the pool in a
+    row-major-pages layout for the whole program and relays the WHOLE
+    pool at the program's entry and back at its exit — four copies of a
+    pool side to store ``n`` rows, and a slice of a whole layer in front
+    of every kernel call (PERF.md §6, PR 25). Rows that share a target
+    (inactive slots and padding, all steered to the trash page) land in
+    no stated order; nobody reads them."""
+    import jax.numpy as jnp
+
+    at = (layer, pid[:, None], jnp.arange(k.shape[1])[None, :], row[:, None])
+    return kp.at[at].set(k), vp.at[at].set(v)
 
 
 @dataclass
