@@ -90,16 +90,20 @@ def _kernels(fn, *args) -> int:
 # ---- flash attention: fwd and fwd+bwd -------------------------------------
 
 FLASH_SHAPES = {
-    # b, t, h, h_kv, d — bf16 as in training
+    # b, t, h, h_kv, d (, window) — bf16 as in training
     "gqa-2048": (6, 2048, 16, 4, 128),
     "hd64-t2048": (4, 2048, 12, 12, 64),
+    # smallthinker-21ba3b: 7 query heads a KV head (448-row q tiles of
+    # 64-row blocks), 8,192-token rows, a window layer and a global one
+    "group7-8k-window4096": (1, 8192, 28, 4, 128, 4096),
+    "group7-8k-global": (1, 8192, 28, 4, 128),
 }
 
 
 @pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "fwd+bwd"])
 @pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
 def test_flash_attention_compiles_for_v5e(one_chip, no_cache, shape, bwd):
-    b, t, h, h_kv, d = FLASH_SHAPES[shape]
+    b, t, h, h_kv, d, *window = FLASH_SHAPES[shape]
 
     def spec(heads):
         return jax.ShapeDtypeStruct((b, t, heads, d), jnp.bfloat16,
@@ -111,7 +115,7 @@ def test_flash_attention_compiles_for_v5e(one_chip, no_cache, shape, bwd):
         # sizes the dispatch picks, then enter the kernel path itself.
         use, bq, bk = fa._dispatch(q, k, v, None, None, True, None)
         assert use
-        return fa._flash_lse(q, k, v, True, bq, bk, False)[0]
+        return fa._flash_lse(q, k, v, True, bq, bk, False, *window)[0]
 
     def loss(q, k, v):
         return jnp.sum(fwd(q, k, v).astype(jnp.float32))
@@ -273,7 +277,11 @@ GMM_WIDTHS = {
     # scoped-VMEM default before _plan_cols counted every resident tile
     "mixtral-8x7b-up": (8, 4096, 14336),
     "mixtral-8x7b-down": (8, 14336, 4096),
+    # one chip's 16 held experts of 64, narrow (768) ReGLU experts
+    "smallthinker-share-up": (16, 2560, 768),
+    "smallthinker-share-down": (16, 768, 2560),
 }
+GMM_NAMES = {"fwd": "gmm_fwd", "dx": "gmm_dx", "dw": "gmm_dw"}
 
 
 @pytest.mark.parametrize("scaled", [False, True], ids=["plain", "row_scale"])
@@ -301,7 +309,11 @@ def test_gmm_compiles_for_v5e(one_chip, no_cache, widths, part, scaled):
         "dx": jax.grad(total, argnums=0),
         "dw": jax.grad(total, argnums=1),
     }[part]
-    assert _kernels(fn, x, w, be, sc) >= 1
+    names = _kernel_names(fn, x, w, be, sc)
+    # every product under its own name in a trace (dx of a scaled forward
+    # is the unscaled transposed product: no suffix)
+    want = GMM_NAMES[part] + ("_scaled" if scaled and part != "dx" else "")
+    assert any(want in n for n in names), names
 
 
 def test_gmm_refuses_what_it_cannot_tile_by_name():

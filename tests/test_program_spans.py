@@ -350,3 +350,52 @@ def test_serve_workload_reports_the_counters_and_honours_profile_dir(tmp_path):
     assert sum(n == "serve.step" for n, *_ in spans) == ctx.reports[-1][0]
     (written,) = [a for n, _, _, a in spans if n == "serve.counters"]
     assert written["admitted"] == 5 and written["pool_peak_in_use"] == last["engine_pool_peak_in_use"]
+
+
+# ---- the LM workload: a step's routing counters beside its loss ---------------
+
+
+class _LmStubContext(_StubContext):
+    job_name = "lm-moe-counters"
+
+    def build_mesh(self):
+        import jax
+
+        from tf_operator_tpu.parallel.mesh import build_mesh
+
+        return build_mesh({"dp": 1}, devices=jax.devices()[:1])
+
+
+def test_lm_workload_reports_the_routing_counters(caplog):
+    """gmm-dispatched experts: the four ``moe_*`` counters of the last step
+    are in the ``run report`` and on ``eval_metrics``; they left the step
+    as ``TrainState.extra``, not through a sync of their own."""
+    import json
+    import logging
+
+    from tf_operator_tpu.workloads import lm as workload
+
+    ctx = _LmStubContext({
+        "preset": "tiny-moe", "moe_dispatch": "gmm", "moe_top_k": 2,
+        "steps": 3, "batch_size": 2, "seq_len": 32})
+    with caplog.at_level(logging.INFO, logger="tpujob.lm"):
+        workload.main(ctx)
+    (line,) = [r.getMessage() for r in caplog.records
+               if r.getMessage().startswith("run report: ")]
+    moe = json.loads(line[len("run report: "):])["moe"]
+    assert set(moe) == {"moe_routed_here", "moe_rows_computed",
+                        "moe_held_load_max", "moe_held_load_mean"}
+    # 2 layers x 64 tokens x top-2, every expert held: nothing routed away
+    assert moe["moe_routed_here"] == 2 * 64 * 2
+    assert moe["moe_rows_computed"] % 256 == 0 and moe["moe_rows_computed"] >= 256
+    assert moe["moe_held_load_mean"] == 2 * 64 * 2 / 4
+    assert ctx.reports[-1] == (3, moe)
+    # a dense run has no such entry and reports nothing
+    dense = _LmStubContext({"preset": "tiny", "steps": 2, "batch_size": 2,
+                            "seq_len": 16})
+    caplog.clear()
+    with caplog.at_level(logging.INFO, logger="tpujob.lm"):
+        workload.main(dense)
+    (line,) = [r.getMessage() for r in caplog.records
+               if r.getMessage().startswith("run report: ")]
+    assert json.loads(line[len("run report: "):])["moe"] is None and not dense.reports

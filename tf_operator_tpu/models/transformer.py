@@ -102,6 +102,32 @@ class TransformerConfig:
     pp_axis: str = "pp"
     pp_schedule: str = "1f1b"
     pp_chunks: int = 1
+    # Head width when it is its own number (0: d_model // n_heads). With
+    # d_head set, wq/wo are [d_model, n_heads·d_head] — not square.
+    d_head: int = 0
+    # The layer pattern: one (window, rotary) entry a position of the
+    # PERIOD, repeated n_layers / len(pattern) times. window 0 = every
+    # earlier key (global), W > 0 = the last W keys; rotary False = no
+    # positional encoding at all in that layer (NoPE). () is one entry
+    # (0, True): today's stack. The stack scans over periods with the
+    # period's layers unrolled in the body, so both are static per layer.
+    layer_pattern: tuple = ()
+    # MoE variants. expert_act: the gate activation of the expert MLP
+    # ("silu" SwiGLU | "relu" ReGLU). router_input: the tensor the router
+    # scores — "mlp_norm" (the MLP-side norm, as Switch/Mixtral) or
+    # "attn_norm" (the layer's normalised INPUT, i.e. the router sits
+    # before attention). router_f32: the [T, E] router product in float32
+    # at HIGHEST precision (near-ties of the top-k flip least).
+    expert_act: str = "silu"
+    router_input: str = "mlp_norm"
+    router_f32: bool = False
+    # ONE CHIP'S SHARE of an expert-parallel group: this program holds
+    # experts expert_first .. expert_first + experts_held - 1 of every
+    # layer (0 = all n_experts). The router keeps its n_experts outputs
+    # and its top-k; the layer adds the held experts' part of the sum
+    # (parallel.moe._moe_single_gmm) and nothing for the absent ones.
+    experts_held: int = 0
+    expert_first: int = 0
 
     def __post_init__(self):
         if self.n_experts and not (1 <= self.moe_top_k <= self.n_experts):
@@ -110,29 +136,61 @@ class TransformerConfig:
                 f"{self.n_experts}] (it silently corrupts FLOP accounting "
                 "and fails inside lax.top_k otherwise)"
             )
+        if self.n_layers % len(self.pattern):
+            raise ValueError(
+                f"n_layers={self.n_layers} is not a whole number of periods "
+                f"of {len(self.pattern)} layers"
+            )
+        if any(w and not self.causal for w, _ in self.pattern):
+            raise ValueError("a window layer needs causal=True")
+        if self.expert_act not in ("silu", "relu"):
+            raise ValueError(f"unknown expert_act {self.expert_act!r}")
+        if self.router_input not in ("mlp_norm", "attn_norm"):
+            raise ValueError(f"unknown router_input {self.router_input!r}")
+        if self.experts_held:
+            if not 0 <= self.expert_first <= self.n_experts - self.experts_held:
+                raise ValueError(
+                    f"experts {self.expert_first}..+{self.experts_held} are "
+                    f"not among {self.n_experts}"
+                )
+            if self.n_held < self.n_experts and self.moe_dispatch != "gmm":
+                raise ValueError(
+                    "a share of the experts runs on moe_dispatch='gmm' only"
+                )
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def pattern(self) -> tuple:
+        """((window, rotary), ...): one entry a layer of the period."""
+        return tuple(self.layer_pattern) or ((0, True),)
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights this program holds."""
+        return self.experts_held or self.n_experts
 
     def n_params(self) -> int:
-        """Parameter count (for MFU accounting)."""
+        """Parameter count (for MFU accounting): what this program holds."""
         d, f, v, L = self.d_model, self.d_ff, self.vocab, self.n_layers
-        kv = self.n_kv_heads * self.head_dim
+        q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
         mlp = 3 * d * f
         if self.n_experts:
-            mlp = self.n_experts * mlp + d * self.n_experts  # experts + router
-        per_layer = d * d + 2 * d * kv + d * d + mlp + 2 * d  # qkv+o+mlp+norms
+            mlp = self.n_held * mlp + d * self.n_experts  # experts + router
+        per_layer = d * q + 2 * d * kv + q * d + mlp + 2 * d  # qkv+o+mlp+norms
         return v * d + L * per_layer + d  # embed + layers + final norm
 
     def n_active_params(self) -> int:
         """Params touched per token (= n_params for dense; top-k MoE
-        activates k experts) — the right N for 6ND FLOP accounting."""
+        activates k experts, of which the share held / n_experts are here
+        on average) — the right N for 6ND FLOP accounting."""
         if not self.n_experts:
             return self.n_params()
         d, f, L = self.d_model, self.d_ff, self.n_layers
-        inactive = (self.n_experts - self.moe_top_k) * 3 * d * f
-        return self.n_params() - L * inactive
+        active = self.moe_top_k * self.n_held / self.n_experts
+        return self.n_params() - int(L * (self.n_held - active) * 3 * d * f)
 
 
 PRESETS: Dict[str, TransformerConfig] = {
@@ -209,6 +267,19 @@ PRESETS: Dict[str, TransformerConfig] = {
         d_ff=14336, max_seq=4096, n_experts=8, moe_top_k=2,
         moe_dispatch="gmm",
     ),
+    # SmallThinker-21BA3B-Instruct (PowerInfer; config.json on the hub):
+    # period [global + NoPE, window 4096 + rotary x3] x 13, 28 query / 4 KV
+    # heads of width 128 (28·128 = 3584 != d_model), a top-6 router over 64
+    # ReGLU experts of width 768 placed BEFORE attention, no shared expert.
+    # One chip trains a SHARE of it (experts_held / n_layers / vocab
+    # overrides: benchmarks/configs/smallthinker-21ba3b-ep4share-train1.json).
+    "smallthinker-21ba3b": TransformerConfig(
+        vocab=151936, d_model=2560, n_layers=52, n_heads=28, n_kv_heads=4,
+        d_head=128, d_ff=768, max_seq=16384, rope_theta=1.5e6, norm_eps=1e-6,
+        n_experts=64, moe_top_k=6, moe_dispatch="gmm",
+        layer_pattern=((0, False), (4096, True), (4096, True), (4096, True)),
+        expert_act="relu", router_input="attn_norm", router_f32=True,
+    ),
 }
 
 
@@ -242,10 +313,10 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict[str, Any]:
         "mlp_norm": jnp.ones((L, d), jnp.float32),
     }
     if cfg.n_experts:
-        E = cfg.n_experts
+        E = cfg.n_held  # the router scores all n_experts, the weights are the held
         layers.update(
             {
-                "w_router": dense_init(ks[7], d, L, d, E),
+                "w_router": dense_init(ks[7], d, L, d, cfg.n_experts),
                 "w_gate": dense_init(ks[4], d, L, E, d, f),
                 "w_up": dense_init(ks[5], d, L, E, d, f),
                 "w_down": dense_init(ks[6], f, L, E, f, d),
@@ -342,8 +413,9 @@ def rope_at_positions(x, positions, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _attention(q, k, v, cfg: TransformerConfig, mesh):
-    """q: [b,t,nh,hd]; k/v: [b,t,nkv,hd].
+def _attention(q, k, v, cfg: TransformerConfig, mesh, window: int = 0):
+    """q: [b,t,nh,hd]; k/v: [b,t,nkv,hd]. ``window`` > 0: a sliding-window
+    layer (flash and dense paths; ring / ulysses have no window).
 
     GQA (nkv < nh) runs NATIVE on the dense, flash AND ring paths: no
     [b,t,nh,hd] K/V tensor ever exists — the flash kernel grids over K/V
@@ -358,6 +430,11 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh):
     indivisible kv counts it all-gathers the small K/V over cp and
     head-maps per shard (r4 — no repeated [t, h, hd] tensor either way),
     both handled inside parallel/ulysses.py."""
+    if window and cfg.attn_impl in ("ring", "ulysses"):
+        raise NotImplementedError(
+            f"attn_impl={cfg.attn_impl!r} has no sliding window; window "
+            "layers run on 'flash' or 'dense'"
+        )
     if cfg.attn_impl == "ring" and mesh is not None and cfg.cp_axis in mesh.axis_names:
         from tf_operator_tpu.parallel.ring_attention import ring_attention
 
@@ -405,19 +482,20 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh):
                 v = jnp.repeat(v, grp, axis=2)
             spec = P(batch, None, heads, None)
             fn = shard_map(
-                lambda q, k, v: flash_attention(q, k, v, causal=cfg.causal),
+                lambda q, k, v: flash_attention(q, k, v, causal=cfg.causal,
+                                                window=window),
                 mesh=mesh,
                 in_specs=(spec, spec, spec),
                 out_specs=spec,
             )
             return fn(q, k, v)
-        return flash_attention(q, k, v, causal=cfg.causal)
+        return flash_attention(q, k, v, causal=cfg.causal, window=window)
     # dense path: the GQA-native grouped einsum with f32 MXU accumulation
     # (ops/flash_attention.reference_attention — also the flash oracle, so
     # dense and flash configs are pinned to the same math by its tests)
     from tf_operator_tpu.ops.flash_attention import reference_attention
 
-    return reference_attention(q, k, v, causal=cfg.causal)
+    return reference_attention(q, k, v, causal=cfg.causal, window=window)
 
 
 def _anchored_gamma(gamma, cfg: TransformerConfig, mesh):
@@ -444,8 +522,10 @@ def _anchored_gamma(gamma, cfg: TransformerConfig, mesh):
 
 
 def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
-           tp_manual_vjp=True, local_ep_axis: Optional[str] = None):
-    """One decoder layer. ``tp_axis`` (pipeline tp-within-stage, r3):
+           tp_manual_vjp=True, local_ep_axis: Optional[str] = None,
+           kind: tuple = (0, True)):
+    """One decoder layer. ``kind`` = (window, rotary): this layer's entry
+    of cfg.pattern, static. ``tp_axis`` (pipeline tp-within-stage, r3):
     weights arrive as tp-LOCAL shards (wq/wk/wv/w_gate/w_up
     column-parallel, wo/w_down row-parallel — the Megatron split).
 
@@ -507,8 +587,15 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
     q = (h @ wq).reshape(b, t, wq.shape[-1] // hd, hd)
     k = (h @ wk).reshape(b, t, wk.shape[-1] // hd, hd)
     v = (h @ wv).reshape(b, t, wv.shape[-1] // hd, hd)
-    q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
-    attn = _attention(q, k, v, cfg, mesh).reshape(b, t, wq.shape[-1])
+    window, rotary = kind
+    if rotary:
+        q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+    gate_logits = None
+    if cfg.n_experts and cfg.router_input == "attn_norm":
+        # the router sits BEFORE attention: it scores the same normalised
+        # tensor attention reads
+        gate_logits = _router_logits(h, layer_params, cfg)
+    attn = _attention(q, k, v, cfg, mesh, window).reshape(b, t, wq.shape[-1])
     proj = attn @ layer_params["wo"].astype(x.dtype)
     if tp_axis is not None:
         proj = leave(proj)
@@ -520,7 +607,8 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
     h = anchor_tokens(_rms_norm(x, gamma_mlp, cfg.norm_eps))
     if cfg.n_experts:
         moe_out, aux = _moe_mlp(h, layer_params, cfg, mesh,
-                                local_ep_axis=local_ep_axis)
+                                local_ep_axis=local_ep_axis,
+                                gate_logits=gate_logits)
         return x + moe_out, aux
     if tp_axis is not None:
         h = enter(h)
@@ -536,8 +624,22 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
     return x + down, None
 
 
+# the routing counters of one step (parallel.moe._moe_single_gmm's stats),
+# summed over layers on their way out of the model as ``moe_<name>``
+MOE_COUNTERS = ("routed_here", "rows_computed", "held_load_max", "held_load_mean")
+
+
+def _router_logits(h, layer_params, cfg: TransformerConfig):
+    """[b, t, d] -> [b·t, E] router scores of every expert."""
+    flat = h.reshape(-1, h.shape[-1])
+    if cfg.router_f32:
+        return jnp.dot(flat.astype(jnp.float32), layer_params["w_router"],
+                       precision=jax.lax.Precision.HIGHEST)
+    return flat @ layer_params["w_router"].astype(h.dtype)
+
+
 def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
-             local_ep_axis: Optional[str] = None):
+             local_ep_axis: Optional[str] = None, gate_logits=None):
     """Top-k expert MLP (k = cfg.moe_top_k: 1 Switch / 2 Mixtral-style):
     router -> all-to-all dispatch over the ep axis (parallel.moe) ->
     per-expert SwiGLU -> gate-weighted combine.
@@ -555,16 +657,19 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
     "drop_frac"}."""
     from tf_operator_tpu.parallel.moe import (
         _moe_local,
+        expert_activation,
         expert_capacity,
         moe_apply,
     )
 
     b, t, d = h.shape
     flat = h.reshape(b * t, d)
-    gate_logits = flat @ layer_params["w_router"].astype(h.dtype)
+    if gate_logits is None:  # cfg.router_input == "mlp_norm"
+        gate_logits = _router_logits(h, layer_params, cfg)
+    act = expert_activation(cfg.expert_act)
 
     def expert_fn(wp, toks):
-        gate = jax.nn.silu(toks @ wp["w_gate"].astype(toks.dtype))
+        gate = act(toks @ wp["w_gate"].astype(toks.dtype))
         up = toks @ wp["w_up"].astype(toks.dtype)
         return (gate * up) @ wp["w_down"].astype(toks.dtype)
 
@@ -591,6 +696,7 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
             k_top=cfg.moe_top_k, stat_axes=(local_ep_axis,),
             dispatch_impl=local_impl,
             block_rows=int(os.environ.get("TPUJOB_GMM_BLOCK_ROWS", "256")),
+            expert_act=cfg.expert_act,
         )
     else:
         from tf_operator_tpu.parallel.moe import ragged_swiglu
@@ -609,7 +715,9 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
             k_top=cfg.moe_top_k,
             return_stats=True,
             dispatch_impl=cfg.moe_dispatch,
-            ragged_expert_fn=ragged_swiglu,
+            ragged_expert_fn=partial(ragged_swiglu, act=act),
+            expert_act=cfg.expert_act,
+            expert_first=cfg.expert_first,
         )
     # Switch load-balance loss: E * Σ_e f_e·P_e. f_e (expert_load) comes
     # out of the discrete top-k assignment, so it carries no gradient and
@@ -628,6 +736,7 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
         "expert_load": stats["expert_load"],
         "drop_frac": stats["drop_frac"],
     }
+    aux.update({k: stats[k] for k in MOE_COUNTERS if k in stats})
     out = out.reshape(b, t, d)
     if local_ep_axis is None and mesh is not None and getattr(
         mesh, "devices", None
@@ -819,6 +928,12 @@ def transformer_hidden_pp(params, tokens, cfg: TransformerConfig, mesh):
     split)."""
     from tf_operator_tpu.parallel.pipeline import pipeline_apply
 
+    if len(cfg.pattern) > 1 or cfg.n_held < cfg.n_experts:
+        raise NotImplementedError(
+            "the pipelined stack runs one layer kind and whole expert "
+            "layers; a layer pattern or a share of the experts is not "
+            "stage-partitioned yet"
+        )
     if cfg.n_experts and "tp" in mesh.axis_names and mesh.shape["tp"] > 1:
         raise NotImplementedError(
             "MoE + tp-within-stage is not supported (the expert MLP has "
@@ -996,9 +1111,16 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
         _bwd_replicate.defvjp(_br_fwd, _br_bwd)
         x = _bwd_replicate(x)
 
-    layer_fn = _remat_wrap(partial(_layer, cfg=cfg, mesh=mesh), cfg)
+    # One scan step is one PERIOD of the layer pattern, its layers
+    # unrolled, each with its own static (window, rotary) and its own
+    # remat boundary; a pattern of one entry is the plain per-layer scan.
+    pattern = cfg.pattern
+    layer_fns = [
+        _remat_wrap(partial(_layer, cfg=cfg, mesh=mesh, kind=kind), cfg)
+        for kind in pattern
+    ]
 
-    def scan_body(x, layer_params):
+    def one_layer(layer_fn, x, layer_params):
         if carry_anchor is not None:
             # input-side: without this, the moe shard_map's 8-way token
             # spec back-propagates through rms_norm/reshape onto the
@@ -1009,7 +1131,35 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
             new_x = jax.lax.with_sharding_constraint(new_x, carry_anchor)
         return new_x, aux
 
-    x, aux_stack = jax.lax.scan(scan_body, x, params["layers"])
+    if len(pattern) == 1:
+        # kept apart on purpose: the period body at a period of one lowers
+        # to ANOTHER program for the described v5e (4,086 HLO lines and 45
+        # custom calls against 3,919 and 39 at the dense 7B step), and the
+        # dense cells are held to the program they had
+        x, aux_stack = jax.lax.scan(
+            partial(one_layer, layer_fns[0]), x, params["layers"])
+    else:
+        P = len(pattern)
+
+        def period_body(x, period_params):
+            auxes = []
+            for j, layer_fn in enumerate(layer_fns):
+                x, aux = one_layer(
+                    layer_fn, x,
+                    jax.tree_util.tree_map(lambda a: a[j], period_params))
+                auxes.append(aux)
+            if auxes[0] is None:
+                return x, None
+            return x, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *auxes)
+
+        x, aux_stack = jax.lax.scan(
+            period_body, x,
+            jax.tree_util.tree_map(
+                lambda a: a.reshape((cfg.n_layers // P, P) + a.shape[1:]),
+                params["layers"]))
+        if aux_stack is not None:  # [periods, P, ...] -> [L, ...]
+            aux_stack = jax.tree_util.tree_map(
+                lambda a: a.reshape((cfg.n_layers,) + a.shape[2:]), aux_stack)
     if carry_anchor is not None:
         # exit anchor: pins the BACKWARD scan's carry init too — the
         # transpose of this constraint re-anchors the loss head's
@@ -1029,6 +1179,8 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
         "expert_load": aux_stack["expert_load"],  # [L, E]
         "drop_frac": aux_stack["drop_frac"],  # [L]
     }
+    # routing counters (gmm dispatch): one scalar a step, summed over layers
+    aux.update({k: jnp.sum(aux_stack[k]) for k in MOE_COUNTERS if k in aux_stack})
     return h, aux
 
 
@@ -1146,6 +1298,7 @@ def lm_loss_and_metrics(params, tokens, cfg: TransformerConfig, mesh=None, key=N
                 moe_expert_entropy=jnp.mean(entropy),
                 moe_drop_frac=jnp.mean(aux["drop_frac"]),
             )
+        metrics.update({f"moe_{k}": aux[k] for k in MOE_COUNTERS if k in aux})
     return total, metrics
 
 
@@ -1154,6 +1307,32 @@ def lm_loss(params, tokens, cfg: TransformerConfig, mesh=None, key=None, mask_ra
     includes the weighted MoE router losses for MoE configs."""
     total, _ = lm_loss_and_metrics(params, tokens, cfg, mesh, key, mask_rate)
     return total
+
+
+def moe_counter_names(cfg: TransformerConfig, mesh=None) -> tuple:
+    """The ``moe_*`` routing counters a step of this config returns beside
+    its loss: gmm dispatch on the one-device expert path (no exchange
+    over an ep axis, no pipeline); () otherwise."""
+    sharded = mesh is not None and any(
+        mesh.shape.get(a, 1) > 1 for a in (cfg.ep_axis, cfg.pp_axis))
+    if not cfg.n_experts or cfg.moe_dispatch != "gmm" or sharded:
+        return ()
+    return tuple(f"moe_{k}" for k in MOE_COUNTERS)
+
+
+def lm_loss_with_counters(params, tokens, cfg: TransformerConfig, mesh=None):
+    """(loss, counters) in the shape ``Trainer`` takes as (loss,
+    new_extra): the routing counters of this step — device scalars, one
+    per name of moe_counter_names(cfg) — leave the step beside the loss
+    as ``TrainState.extra``, with no host sync beyond the step's own.
+    Start the state from ``zero_moe_counters(cfg)``."""
+    total, metrics = lm_loss_and_metrics(params, tokens, cfg, mesh)
+    return total, {k: metrics[k].astype(jnp.float32)
+                   for k in moe_counter_names(cfg, mesh)}
+
+
+def zero_moe_counters(cfg: TransformerConfig, mesh=None) -> Dict[str, Any]:
+    return {k: jnp.zeros((), jnp.float32) for k in moe_counter_names(cfg, mesh)}
 
 
 def preset(name: str, **overrides) -> TransformerConfig:
@@ -1170,6 +1349,8 @@ CONFIG_OVERRIDE_FIELDS = frozenset(
         "max_seq", "causal", "remat", "fused_xent", "n_experts",
         "moe_top_k", "capacity_factor", "moe_aux_weight", "moe_zloss_weight",
         "moe_dispatch", "pp_microbatches", "pp_schedule",
+        "d_head", "layer_pattern", "expert_act", "router_input", "router_f32",
+        "experts_held", "expert_first",
     }
 )
 
@@ -1178,6 +1359,9 @@ def preset_from_workload(workload: Dict[str, Any]) -> TransformerConfig:
     """TransformerConfig from a TPUJob workload dict: ``preset`` plus any
     CONFIG_OVERRIDE_FIELDS, with ``attn`` mapping to ``attn_impl``."""
     overrides = {k: workload[k] for k in CONFIG_OVERRIDE_FIELDS if k in workload}
+    if "layer_pattern" in overrides:  # JSON lists -> the hashable tuple form
+        overrides["layer_pattern"] = tuple(
+            (int(w), bool(r)) for w, r in overrides["layer_pattern"])
     if workload.get("attn") in ("ring", "ulysses", "flash", "dense"):
         overrides["attn_impl"] = workload["attn"]
     return preset(workload.get("preset", "tiny"), **overrides)

@@ -90,25 +90,49 @@ def _use_kernel(t: int, d: int, block_q: int, block_k: int, interpret: bool) -> 
     return d % 64 == 0 and t >= 2048
 
 
-def reference_attention(q, k, v, causal: bool = False):
+def reference_attention(q, k, v, causal: bool = False, window: int = 0):
     """Dense attention, f32 softmax — the correctness oracle and the
     off-TPU fallback (same contract as the kernel path). GQA-native: k/v
     may carry fewer heads than q (h % h_kv == 0); the grouped einsum
     keeps the group dim in the contraction instead of materializing
     repeated K/V heads. One implementation: softmax(s) == exp(s − lse),
-    so this is the lse variant with the lse dropped."""
-    return reference_attention_lse(q, k, v, causal=causal)[0]
+    so this is the lse variant with the lse dropped. ``window`` > 0
+    (causal only): query i sees key j iff j <= i and i - j < window."""
+    return reference_attention_lse(q, k, v, causal=causal, window=window)[0]
 
 
-def _causal_mask(s, qi, kb, block_q, block_k):
+def _check_window(causal: bool, window: int) -> None:
+    if window and not causal:
+        raise ValueError("a sliding window needs causal=True")
+
+
+def _causal_mask(s, qi, kb, block_q, block_k, window=0):
     """Causal mask for an s tile whose rows may stack g group members:
     row r is sequence position qi*block_q + (r % block_q) — members share
     the same q sequence block, so position repeats per member (for g=1,
-    r % block_q == r and this is the classic tile mask)."""
+    r % block_q == r and this is the classic tile mask). ``window`` > 0
+    also hides keys at or beyond ``window`` positions behind the query."""
     rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % block_q
     qpos = qi * block_q + rows
     kpos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(kpos <= qpos, s, NEG_INF)
+    visible = kpos <= qpos
+    if window:
+        visible = visible & (qpos - kpos < window)
+    return jnp.where(visible, s, NEG_INF)
+
+
+def _block_live(qb, kb, block_q, block_k, causal, window):
+    """Whether the (q block, k block) pair holds any visible score: under
+    causal masking blocks right of the diagonal hold none, and with a
+    window neither do blocks whose LAST key lies ``window`` or more
+    behind the q block's FIRST row. Dead blocks are still grid steps;
+    they run no dot."""
+    if not causal:
+        return True
+    live = kb * block_k <= qb * block_q + block_q - 1
+    if window:
+        live = live & (kb * block_k + block_k - 1 > qb * block_q - window)
+    return live
 
 
 def _gqa_specs(g, block_q, block_k, d, q_grid_dim):
@@ -147,7 +171,7 @@ def _gqa_specs(g, block_q, block_k, d, q_grid_dim):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, causal, block_q, block_k, scale, g):
+                *, causal, block_q, block_k, scale, g, window=0):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(2)
@@ -163,8 +187,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         acc_scr[:, :] = jnp.zeros_like(acc_scr)
 
     # Above-diagonal blocks contribute nothing under causal masking (every
-    # group member in the tile shares the same q sequence block).
-    live = (kb * block_k <= qi * block_q + block_q - 1) if causal else True
+    # group member in the tile shares the same q sequence block), nor do
+    # blocks wholly left of the window. A row whose keys in a live block
+    # are ALL outside its window adds exp(0) terms to l and acc; the
+    # first block that holds one of its keys (its diagonal at the latest)
+    # rescales both by alpha = exp(NEG_INF - m) = 0, so nothing survives.
+    live = _block_live(qi, kb, block_q, block_k, causal, window)
 
     @pl.when(live)
     def _step():
@@ -175,7 +203,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [g·bq, bk]
         if causal:
-            s = _causal_mask(s, qi, kb, block_q, block_k)
+            s = _causal_mask(s, qi, kb, block_q, block_k, window)
         m_prev = m_scr[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
         p = jnp.exp(s - m_new[:, None])
@@ -195,7 +223,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         ).reshape(g, block_q, LSE_LANES)
 
 
-def _fwd(q, k, v, causal, block_q, block_k, interpret):
+def _fwd(q, k, v, causal, block_q, block_k, interpret, window=0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -213,7 +241,7 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret):
 
     kernel = functools.partial(
         _fwd_kernel, causal=causal, block_q=block_q, block_k=block_k,
-        scale=scale, g=g,
+        scale=scale, g=g, window=window,
     )
     o, lse = pl.pallas_call(
         kernel,
@@ -244,7 +272,7 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret):
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
-                   *, causal, block_q, block_k, scale, g):
+                   *, causal, block_q, block_k, scale, g, window=0):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(2)
@@ -257,7 +285,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_s
     def _init():
         dq_scr[:, :] = jnp.zeros_like(dq_scr)
 
-    live = (kb * block_k <= qi * block_q + block_q - 1) if causal else True
+    live = _block_live(qi, kb, block_q, block_k, causal, window)
 
     @pl.when(live)
     def _step():
@@ -271,7 +299,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_s
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         if causal:
-            s = _causal_mask(s, qi, kb, block_q, block_k)
+            s = _causal_mask(s, qi, kb, block_q, block_k, window)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -287,7 +315,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_s
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                    dk_scr, dv_scr, *, causal, block_q, block_k, scale, g):
+                    dk_scr, dv_scr, *, causal, block_q, block_k, scale, g,
+                    window=0):
     """dk/dv for one k/v head. The q tile stacks the g query heads of the
     group ([g·block_q, d]), so the row contraction in p·ᵀdo and ds·ᵀq sums
     over every group member in one matmul — the [block_k, d] scratch
@@ -308,8 +337,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         dk_scr[:, :] = jnp.zeros_like(dk_scr)
         dv_scr[:, :] = jnp.zeros_like(dv_scr)
 
-    # Causal: q-blocks strictly before this k-block see none of it.
-    live = (qb * block_q + block_q - 1 >= ki * block_k) if causal else True
+    # Causal: q-blocks strictly before this k-block see none of it; with
+    # a window, neither do q-blocks wholly past it.
+    live = _block_live(qb, ki, block_q, block_k, causal, window)
 
     @pl.when(live)
     def _step():
@@ -323,7 +353,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [g·bq, bk]
         if causal:
-            s = _causal_mask(s, qb, ki, block_q, block_k)
+            s = _causal_mask(s, qb, ki, block_q, block_k, window)
         p = jnp.exp(s - lse)  # [g·bq, bk]
         dv_scr[:, :] = dv_scr[:, :] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -342,7 +372,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         dv_ref[0, 0, :, :] = dv_scr[:, :].astype(dv_ref.dtype)
 
 
-def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None):
+def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None,
+         window=0):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -371,7 +402,7 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None):
 
     dq_kernel = functools.partial(
         _bwd_dq_kernel, causal=causal, block_q=block_q, block_k=block_k,
-        scale=scale, g=grp,
+        scale=scale, g=grp, window=window,
     )
     dq = pl.pallas_call(
         dq_kernel,
@@ -393,7 +424,7 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None):
 
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, causal=causal, block_q=block_q, block_k=block_k,
-        scale=scale, g=grp,
+        scale=scale, g=grp, window=window,
     )
     dk, dv = pl.pallas_call(
         dkv_kernel,
@@ -462,14 +493,14 @@ def _lse_public(lse_c):
     return lse_c.transpose(0, 2, 1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_lse(q, k, v, causal, block_q, block_k, interpret):
-    out, res = _fwd(q, k, v, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_lse(q, k, v, causal, block_q, block_k, interpret, window=0):
+    out, res = _fwd(q, k, v, causal, block_q, block_k, interpret, window)
     return out, _lse_public(res[4])
 
 
-def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret):
-    out, res = _fwd(q, k, v, causal, block_q, block_k, interpret)
+def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret, window=0):
+    out, res = _fwd(q, k, v, causal, block_q, block_k, interpret, window)
     lse_pub = _lse_public(res[4])
     # model-layout residuals: q/k/v are the (possibly checkpoint_name-
     # tagged) INPUTS — under a names policy they are saved values, so the
@@ -477,16 +508,17 @@ def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret):
     return (out, lse_pub), (q, k, v, out, lse_pub)
 
 
-def _flash_lse_bwd(causal, block_q, block_k, interpret, residuals, cts):
+def _flash_lse_bwd(causal, block_q, block_k, interpret, window, residuals, cts):
     do, dlse = cts
     res = _to_kernel_res(*residuals)
-    return _bwd(causal, block_q, block_k, interpret, res, do, dlse=dlse)
+    return _bwd(causal, block_q, block_k, interpret, res, do, dlse=dlse,
+                window=window)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd, optimize_remat=True)
 
 
-def reference_attention_lse(q, k, v, causal: bool = False):
+def reference_attention_lse(q, k, v, causal: bool = False, window: int = 0):
     """Dense (o, lse) — the fallback for flash_attention_lse. lse is the
     row logsumexp of the scaled (masked) scores, [b, t, h] f32; rows with
     every key masked get lse = NEG_INF — the finite -1e30 sentinel, NOT
@@ -494,6 +526,7 @@ def reference_attention_lse(q, k, v, causal: bool = False):
     Downstream merges must treat lse <= NEG_INF/2 as masked/weight-0 the
     way ring_attention's _merge_partials does; an isinf check will NOT
     catch it."""
+    _check_window(causal, window)
     b, tq, hq, d = q.shape
     h_kv = k.shape[2]
     scale = d**-0.5
@@ -510,6 +543,8 @@ def reference_attention_lse(q, k, v, causal: bool = False):
     if causal:
         tk = k.shape[1]
         mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        if window:
+            mask = mask & ~jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq - window)
         s = jnp.where(mask.reshape((1,) * (s.ndim - 2) + mask.shape), s, NEG_INF)
     lse = jax.scipy.special.logsumexp(s, axis=-1)  # [b,h,q] or [b,h_kv,g,q]
     p = jnp.exp(s - lse[..., None]).astype(q.dtype)
@@ -530,6 +565,7 @@ def flash_attention_lse(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     force_kernel: Optional[bool] = None,
+    window: int = 0,
 ):
     """flash_attention returning ``(o, lse)`` with lse [b, t, h] f32 —
     the row logsumexp of scaled scores. This is the composition surface
@@ -543,10 +579,12 @@ def flash_attention_lse(
     sentinel, not -inf (see reference_attention_lse)."""
     use, block_q, block_k = _dispatch(q, k, v, block_q, block_k, interpret,
                                       force_kernel)
+    _check_window(causal, window)
     q, k, v = _tag_inputs(q, k, v)
     if not use:
-        return reference_attention_lse(q, k, v, causal=causal)
-    return _flash_lse(q, k, v, causal, block_q, block_k, bool(interpret))
+        return reference_attention_lse(q, k, v, causal=causal, window=window)
+    return _flash_lse(q, k, v, causal, block_q, block_k, bool(interpret),
+                      int(window))
 
 
 def _pick_block(t: int, target: int) -> int:
@@ -611,8 +649,14 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     force_kernel: Optional[bool] = None,
+    window: int = 0,
 ):
     """Self-attention over [b, t, h, d] with softmax(q·kᵀ/√d)·v semantics.
+
+    ``window`` > 0 (causal only) is a sliding window: query i sees key j
+    iff j <= i and i - j < window. The kernels mask inside the diagonal
+    and trailing blocks and run no dot in blocks wholly outside the
+    window; 0 is today's causal / full attention, unchanged.
 
     GQA-native (r3): k/v may carry h_kv < h heads (h % h_kv == 0, the
     llama2-70b 64q/8kv shape). Neither path materializes repeated K/V —
@@ -641,6 +685,7 @@ def flash_attention(
     differ from what was passed. Callers probing an exact configuration
     should treat a changed block as "that config cannot run", not as a
     measurement of it."""
+    _check_window(causal, window)
     use, block_q, block_k = _dispatch(q, k, v, block_q, block_k, interpret,
                                       force_kernel)
     q, k, v = _tag_inputs(q, k, v)
@@ -651,10 +696,11 @@ def flash_attention(
                 f"t={q.shape[1]} d={q.shape[3]} blocks=({block_q},{block_k}) "
                 "does not tile, or is under the hd=64 crossover",
             )
-        return reference_attention(q, k, v, causal=causal)
+        return reference_attention(q, k, v, causal=causal, window=window)
     # One custom-vjp entry serves both public surfaces (the lse output is
     # a residual either way, so dropping it here costs nothing).
-    return _flash_lse(q, k, v, causal, block_q, block_k, bool(interpret))[0]
+    return _flash_lse(q, k, v, causal, block_q, block_k, bool(interpret),
+                      int(window))[0]
 
 
 # ---------------------------------------------------------------------------

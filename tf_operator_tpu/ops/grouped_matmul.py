@@ -226,7 +226,12 @@ def _gmm_fwd_scaled_kernel(be_ref, x_ref, w_ref, s_ref, o_ref):
 
 
 def _gmm_call(x, w, row_scale, block_expert, block_rows, block_cols,
-              interpret):
+              interpret, name="gmm_fwd"):
+    """One grouped product. ``name`` is the kernel's name in a profiler
+    trace and in the compiled text: ``gmm_fwd`` / ``gmm_fwd_scaled`` for
+    the forward products, ``gmm_dx`` for the same kernel against
+    transposed weights in the backward pass (``gmm_dw`` /
+    ``gmm_dw_scaled``: _gmm_dw)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -262,6 +267,7 @@ def _gmm_call(x, w, row_scale, block_expert, block_rows, block_cols,
         )
         operands.append(row_scale.astype(jnp.float32).reshape(R, 1))
         kernel = _gmm_fwd_scaled_kernel
+        name += "_scaled"
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nb, n // bn),
@@ -275,6 +281,7 @@ def _gmm_call(x, w, row_scale, block_expert, block_rows, block_cols,
         out_shape=jax.ShapeDtypeStruct((R, n), x.dtype),
         compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
+        name=name,
     )(*operands)
 
 
@@ -390,6 +397,7 @@ def _gmm_dw(x, dy, w_shape, block_expert, block_rows, block_cols, interpret,
         out_shape=jax.ShapeDtypeStruct((E, k, n), jnp.float32),
         compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
+        name="gmm_dw" if row_scale is None else "gmm_dw_scaled",
     )(*operands)
 
 
@@ -406,7 +414,7 @@ def _gmm_bwd_rule(block_rows, block_cols, interpret, res, dy):
     # padded-FLOP term this kernel retires).
     dx = _gmm_call(
         dy, jnp.swapaxes(w, 1, 2), None, block_expert, block_rows,
-        block_cols, interpret,
+        block_cols, interpret, name="gmm_dx",
     )
     dw = _gmm_dw(
         x, dy, w.shape, block_expert, block_rows, block_cols, interpret
@@ -432,7 +440,7 @@ def _gmm_scaled_bwd_rule(block_rows, block_cols, interpret, res, dy):
     # the forward or saving an unscaled copy of y).
     t = _gmm_call(
         dy, jnp.swapaxes(w, 1, 2), None, block_expert, block_rows,
-        block_cols, interpret,
+        block_cols, interpret, name="gmm_dx",
     ).astype(jnp.float32)
     dx = row_scale.astype(jnp.float32)[:, None] * t
     ds = jnp.sum(x.astype(jnp.float32) * t, axis=-1)

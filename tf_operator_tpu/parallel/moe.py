@@ -162,7 +162,17 @@ def _combine_sparse(outbox, slot, w):
     return jnp.einsum("tk,tkd->td", w, gathered)
 
 
-def ragged_swiglu(expert_params, x_sorted, group_sizes):
+def expert_activation(name: str):
+    """The gate activation of a gated expert MLP: ``silu`` (SwiGLU) or
+    ``relu`` (ReGLU)."""
+    if name == "silu":
+        return jax.nn.silu
+    if name == "relu":
+        return jax.nn.relu
+    raise ValueError(f"unknown expert activation {name!r} (silu | relu)")
+
+
+def ragged_swiglu(expert_params, x_sorted, group_sizes, act=jax.nn.silu):
     """SwiGLU over expert-sorted rows via ``jax.lax.ragged_dot`` — the
     grouped (Megablocks-style) expert matmul. expert_params leaves are
     stacked [E, ...]; x_sorted rows are grouped by expert with
@@ -174,7 +184,7 @@ def ragged_swiglu(expert_params, x_sorted, group_sizes):
     zg = jax.lax.ragged_dot(x_sorted, expert_params["w_gate"], group_sizes)
     zu = jax.lax.ragged_dot(x_sorted, expert_params["w_up"], group_sizes)
     return jax.lax.ragged_dot(
-        jax.nn.silu(zg) * zu, expert_params["w_down"], group_sizes
+        act(zg) * zu, expert_params["w_down"], group_sizes
     )
 
 
@@ -216,19 +226,39 @@ def _moe_single_ragged(x, gate_logits, expert_params, ragged_expert_fn,
 
 
 def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
-                    block_rows: int = 256):
+                    block_rows: int = 256, act=jax.nn.silu, first: int = 0):
     """Padding-free single-device MoE over the Pallas grouped-matmul
-    kernel (ops/grouped_matmul.gmm — the Megablocks-style path, r5):
-    sort the T·k token-choices by expert, pad each expert's rows only to
-    the ROW-BLOCK granularity (worst case E·B extra rows ≈ 12.5% at
-    bench shapes, vs 100% for the cf=2 capacity queues), and steer each
-    block's weight-tile load by a scalar-prefetched block→expert map.
-    Dispatch is a row GATHER (no scatter-add inbox) and no token ever
-    drops. ragged_dot was measured at ~19 TFLOP/s on the same shapes
-    (full-height masked-matmul lowering) — the kernel exists because the
-    XLA-level formulations all lose; see grouped_matmul.py."""
+    kernel (ops/grouped_matmul.gmm — the Megablocks-style path, r5), for
+    ALL the experts or for ONE CHIP'S SHARE of them.
+
+    The router always scores every expert (``gate_logits`` [T, E]) and
+    the gate weights are the softmax over the k chosen, whichever chip
+    holds them. ``expert_params`` carries the ``held`` experts
+    ``first .. first + held - 1`` (held == E, first == 0: the whole
+    layer). Choices whose expert is not held are left out BEFORE the
+    sort; the output is the partial sum over the held experts' choices,
+    which is what the residual stream gets — nothing stands in for the
+    absent chips (the destination-0 segment of _moe_local_gmm without
+    its exchanges).
+
+    Held choices are sorted by expert and each expert's rows padded only
+    to the ROW-BLOCK quantum; a scalar-prefetched block→expert map steers
+    every block's weight-tile load. The buffer has the lossless bound
+    ceil(T·k/B) + held blocks — every choice could land here — and what
+    the routing leaves unoccupied is SENTINEL blocks (-1: zeros written,
+    no MXU work), so no choice of a held expert ever drops, at any load.
+    Dispatch is a row GATHER (no scatter-add inbox). ragged_dot was
+    measured at ~19 TFLOP/s on the same shapes (full-height
+    masked-matmul lowering) — the kernel exists because the XLA-level
+    formulations all lose; see grouped_matmul.py.
+
+    Stats carry the routing counters of this call beside the router
+    observability: ``routed_here`` (choices routed to held experts),
+    ``rows_computed`` (occupied blocks × B: what the kernels multiply),
+    ``held_load_max`` / ``held_load_mean`` (choices per held expert)."""
     tokens, d = x.shape
     n_experts = gate_logits.shape[-1]
+    held = expert_params["w_gate"].shape[0]
     gate_probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
     top_p, top_i = jax.lax.top_k(gate_probs, k_top)  # [T, k]
     if k_top > 1:
@@ -236,33 +266,37 @@ def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
 
     tk = tokens * k_top
     B = block_rows
-    nb = -(-tk // B) + n_experts  # static upper bound incl. per-expert pad
-    flat_e = top_i.reshape(-1).astype(jnp.int32)  # [T*k], t-major
+    nb = -(-tk // B) + held  # static lossless bound incl. per-expert pad
+    chosen = top_i.reshape(-1).astype(jnp.int32)  # [T*k], t-major
+    local = chosen - first
+    here = (local >= 0) & (local < held)
+    flat_e = jnp.where(here, local, held)  # not held: a spare bucket, sorted last
     order = jnp.argsort(flat_e, stable=True)
-    counts = jnp.bincount(flat_e, length=n_experts).astype(jnp.int32)
-    offsets = jnp.cumsum(counts) - counts  # unpadded sorted offsets
-    rank_sorted = jnp.arange(tk, dtype=jnp.int32) - offsets[flat_e[order]]
+    bucket = jnp.bincount(flat_e, length=held + 1).astype(jnp.int32)
+    counts = bucket[:held]
+    bucket_start = jnp.cumsum(bucket) - bucket
+    offsets = bucket_start[:held]  # unpadded sorted offsets
+    rank_sorted = jnp.arange(tk, dtype=jnp.int32) - bucket_start[flat_e[order]]
     ranks = jnp.zeros((tk,), jnp.int32).at[order].set(rank_sorted)
 
-    # every expert owns >= 1 block even with zero routed tokens: the dw
-    # kernel writes an output tile only when a grid step visits it, so a
-    # block-less expert would return UNINITIALIZED gradient memory. Its
-    # one all-garbage block costs B rows of compute, and its dw is
-    # exactly zero — the garbage rows' outputs are never gathered, so
-    # their cotangents arrive as zeros (pinned by
-    # test_gmm_zero_token_expert_gets_zero_grad).
-    blocks_per_e = jnp.maximum((counts + B - 1) // B, 1)
-    pad_start = (jnp.cumsum(blocks_per_e) - blocks_per_e) * B  # [E]
-    bstart = jnp.arange(nb, dtype=jnp.int32) * B
-    block_expert = (
-        jnp.searchsorted(pad_start, bstart, side="right").astype(jnp.int32) - 1
-    )
-    # padded slot s -> source token (garbage slots read row 0; their
-    # outputs are never gathered back and their cotangents are zero)
+    # an expert with no routed row owns no block: the dw kernel zeroes
+    # every (expert, col-tile) at its walk's first step, so its gradient
+    # is an exact zero all the same (test_gmm_zero_token_expert_gets_zero_grad)
+    blocks_per_e = -(-counts // B)
+    bounds = jnp.cumsum(blocks_per_e)  # [held]
+    pad_start = (bounds - blocks_per_e) * B
+    owner = jnp.searchsorted(
+        bounds, jnp.arange(nb, dtype=jnp.int32), side="right"
+    ).astype(jnp.int32)
+    block_expert = jnp.where(owner < held, owner, -1)
+    # padded slot s -> source token (sentinel and round-up slots read row
+    # 0 with gate weight 0; their outputs are never gathered back and
+    # their cotangents are zero)
     s = jnp.arange(nb * B, dtype=jnp.int32)
-    e_s = block_expert[s // B]
+    owner_s = block_expert[s // B]
+    e_s = jnp.maximum(owner_s, 0)
     rank_s = s - pad_start[e_s]
-    valid = rank_s < counts[e_s]
+    valid = (owner_s >= 0) & (rank_s < counts[e_s])
     src_choice = order[jnp.clip(offsets[e_s] + rank_s, 0, tk - 1)]
     x_pad = x[jnp.where(valid, src_choice // k_top, 0)]  # [nb*B, d]
 
@@ -276,24 +310,33 @@ def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
     # the down-projection kernel as a row scale, so the combine below is
     # a pure gather+sum — the separate f32 [T,k,d] weighted-reduction
     # einsum (and its HBM pass) is gone. Garbage slots scale by 0.
-    dst = pad_start[flat_e] + ranks  # [T*k] — every choice's padded slot
-    s_pad = jnp.zeros((nb * B,), jnp.float32).at[dst].set(top_p.reshape(-1))
-    h = run(jax.nn.silu(zg) * zu,
+    s_pad = jnp.where(valid, top_p.reshape(-1)[src_choice], 0.0)
+    h = run(act(zg) * zu,
             expert_params["w_down"].astype(x.dtype), block_expert,
             row_scale=s_pad)
 
+    # every held choice's padded slot; a choice held elsewhere adds nothing
+    dst = jnp.where(here, pad_start[jnp.clip(local, 0, held - 1)] + ranks, 0)
     gathered = h[dst.reshape(tokens, k_top)]  # [T, k, d] — pre-weighted
-    out = jnp.sum(gathered.astype(jnp.float32), axis=1)
+    out = jnp.sum(
+        jnp.where(here.reshape(tokens, k_top, 1), gathered, 0).astype(jnp.float32),
+        axis=1,
+    )
+    held_counts = counts.astype(jnp.float32)
     stats = {
-        "expert_load": counts.astype(jnp.float32) / tk,
+        "expert_load": jnp.bincount(chosen, length=n_experts).astype(jnp.float32) / tk,
         "mean_gate": jnp.mean(gate_probs, axis=0),
         "drop_frac": jnp.float32(0.0),
+        "routed_here": jnp.sum(held_counts),
+        "rows_computed": (bounds[-1] * B).astype(jnp.float32),
+        "held_load_max": jnp.max(held_counts),
+        "held_load_mean": jnp.mean(held_counts),
     }
     return out.astype(x.dtype), stats
 
 
 def _moe_local_gmm(x, gate_logits, expert_params, axis_name: str,
-                   k_top: int = 1, block_rows: int = 256):
+                   k_top: int = 1, block_rows: int = 256, act=jax.nn.silu):
     """Padding-free EP-SHARDED MoE over the Pallas grouped-matmul kernel
     (r6 — the tentpole that brings the gmm path to the flagship ep
     layouts; before this, dispatch_impl="gmm" silently degraded to
@@ -406,7 +449,7 @@ def _moe_local_gmm(x, gate_logits, expert_params, axis_name: str,
     x_flat = x_rcv.reshape(n_shards * s_cap, d)
     zg = run(x_flat, expert_params["w_gate"].astype(x.dtype), block_expert)
     zu = run(x_flat, expert_params["w_up"].astype(x.dtype), block_expert)
-    h = run(jax.nn.silu(zg) * zu,
+    h = run(act(zg) * zu,
             expert_params["w_down"].astype(x.dtype), block_expert,
             row_scale=s_rcv.reshape(-1))
 
@@ -437,7 +480,8 @@ def _dropped_value(x, dropped: str):
 
 def _moe_single(x, gate_logits, expert_params, expert_fn, capacity: int, dropped: str,
                 k_top: int = 1, dispatch_impl: str = "sort",
-                ragged_expert_fn=None):
+                ragged_expert_fn=None, expert_act: str = "silu",
+                expert_first: int = 0):
     """All experts on one device: same routing math, no collectives — the
     fallback when the mesh has no ep axis (or no mesh at all).
 
@@ -466,6 +510,12 @@ def _moe_single(x, gate_logits, expert_params, expert_fn, capacity: int, dropped
         return _moe_single_gmm(
             x, gate_logits, expert_params, k_top,
             block_rows=int(os.environ.get("TPUJOB_GMM_BLOCK_ROWS", "256")),
+            act=expert_activation(expert_act), first=expert_first,
+        )
+    if jax.tree_util.tree_leaves(expert_params)[0].shape[0] != n_experts:
+        raise ValueError(
+            "a share of the experts (fewer expert weights than router "
+            "outputs) runs on dispatch_impl='gmm' only"
         )
     if dispatch_impl == "ragged":
         if ragged_expert_fn is None:
@@ -503,7 +553,8 @@ def _moe_single(x, gate_logits, expert_params, expert_fn, capacity: int, dropped
 
 def _moe_local(x, gate_logits, expert_params, expert_fn, axis_name: str, capacity: int,
                dropped: str, k_top: int = 1, stat_axes: tuple = (),
-               dispatch_impl: str = "sort", block_rows: int = 256):
+               dispatch_impl: str = "sort", block_rows: int = 256,
+               expert_act: str = "silu"):
     """Per-device body. x: [tokens_local, d]; gate_logits: [tokens_local, E];
     expert_params: this device's experts (leading dim E_local).
     ``stat_axes``: every mesh axis the token dim shards over (data axes +
@@ -527,7 +578,8 @@ def _moe_local(x, gate_logits, expert_params, expert_fn, axis_name: str, capacit
                 "dispatch_impl='sort' for custom expert bodies"
             )
         out, stats = _moe_local_gmm(
-            x, gate_logits, expert_params, axis_name, k_top, block_rows
+            x, gate_logits, expert_params, axis_name, k_top, block_rows,
+            act=expert_activation(expert_act),
         )
         for ax in stat_axes or (axis_name,):
             stats = jax.tree_util.tree_map(
@@ -591,6 +643,8 @@ def moe_apply(
     return_stats: bool = False,
     dispatch_impl: str = "sort",
     ragged_expert_fn=None,
+    expert_act: str = "silu",
+    expert_first: int = 0,
 ):
     """Top-k MoE layer with experts sharded over ``axis_name``
     (``k_top=1`` — Switch; ``k_top=2`` — Mixtral-style with renormalized
@@ -631,7 +685,16 @@ def moe_apply(
     blocks, so the sharded path falls back to "sort" with a runtime
     warning). Same queue semantics for sort/einsum, same drop patterns,
     same stats (pinned by the impl-parity tests); the end-to-end win is
-    recorded in BASELINE.md."""
+    recorded in BASELINE.md.
+
+    ``expert_act`` ("silu" | "relu") is the gate activation the gmm
+    dispatch applies (the other dispatches call ``expert_fn`` /
+    ``ragged_expert_fn``, which carry their own). ONE CHIP'S SHARE:
+    ``expert_params`` may hold fewer experts than ``gate_logits`` has
+    outputs — experts ``expert_first ..`` of a layer whose other experts
+    live on absent chips. The router still scores all of them and the
+    result is the held experts' partial sum (_moe_single_gmm); gmm
+    dispatch, no ep axis."""
     from tf_operator_tpu.parallel.collectives import shard_map
 
     if dispatch_impl not in ("sort", "einsum", "ragged", "gmm"):
@@ -644,9 +707,14 @@ def moe_apply(
         capacity = expert_capacity(capacity_factor, k_top, tokens, n_experts)
         out, stats = _moe_single(
             x, gate_logits, expert_params, expert_fn, capacity, dropped, k_top,
-            dispatch_impl, ragged_expert_fn,
+            dispatch_impl, ragged_expert_fn, expert_act, expert_first,
         )
         return (out, stats) if return_stats else out
+    if jax.tree_util.tree_leaves(expert_params)[0].shape[0] != n_experts:
+        raise ValueError(
+            "a share of the experts runs on one chip with no exchange; "
+            f"the mesh has {axis_name}={mesh.shape[axis_name]}"
+        )
     if dispatch_impl == "ragged":
         # ragged_dot has no block steering to skip unoccupied regions of
         # a statically-sized a2a buffer, so under ep it would pay the
@@ -695,7 +763,8 @@ def moe_apply(
         partial(_moe_local, expert_fn=expert_fn, axis_name=axis_name, capacity=capacity,
                 dropped=dropped, k_top=k_top, stat_axes=(*data_axes, axis_name),
                 dispatch_impl=dispatch_impl,
-                block_rows=int(os.environ.get("TPUJOB_GMM_BLOCK_ROWS", "256"))),
+                block_rows=int(os.environ.get("TPUJOB_GMM_BLOCK_ROWS", "256")),
+                expert_act=expert_act),
         mesh=mesh,
         in_specs=(token_spec, token_spec, param_specs),
         out_specs=(token_spec, stat_specs),

@@ -42,8 +42,11 @@ def main(ctx: JobContext) -> None:
     from tf_operator_tpu.models.transformer import (
         init_transformer,
         lm_loss,
+        lm_loss_with_counters,
+        moe_counter_names,
         preset_from_workload,
         transformer_logical_axes,
+        zero_moe_counters,
     )
     from tf_operator_tpu.train.metrics import (
         fmt_mfu,
@@ -61,14 +64,24 @@ def main(ctx: JobContext) -> None:
     cfg = preset_from_workload(wl)
     mesh = ctx.build_mesh()
 
+    # gmm-dispatched experts: the step's routing counters leave it beside
+    # the loss as the state's ``extra`` — device scalars, read once below
+    counted = bool(moe_counter_names(cfg, mesh))
+
     def loss_fn(params, tokens, extra):
         del extra
+        if counted:
+            return lm_loss_with_counters(params, tokens, cfg, mesh=mesh)
         return lm_loss(params, tokens, cfg, mesh=mesh)
+
+    def init_fn(k):
+        params = init_transformer(k, cfg)
+        return (params, zero_moe_counters(cfg, mesh)) if counted else params
 
     trainer = Trainer(
         mesh,
         loss_fn=loss_fn,
-        init_fn=lambda k: init_transformer(k, cfg),
+        init_fn=init_fn,
         logical_axes=transformer_logical_axes(cfg),
         config=TrainerConfig(
             optimizer="adamw", learning_rate=float(wl.get("lr", 3e-4)),
@@ -205,6 +218,11 @@ def main(ctx: JobContext) -> None:
                 "z_loss=%.4f",
                 float(m["moe_lb_loss"]), float(m["moe_z_loss"]),
             )
+    moe_counters = (
+        {k: float(v) for k, v in state.extra.items()} if counted else None)
+    if moe_counters:
+        # the last step's routing, where dashboards read live job numbers
+        ctx.report_eval_metrics(steps, moe_counters)
     log.info("run report: %s", json.dumps(run_report(
         workload="lm", preset=wl.get("preset", "tiny"), batch_size=batch,
         seq_len=seq, n_layers=cfg.n_layers, attn=cfg.attn_impl,
@@ -215,6 +233,9 @@ def main(ctx: JobContext) -> None:
         loader=None if loader is None else {
             "batches": loader.batches, "wait_s": round(loader.wait_s, 4),
             "empty_pulls": loader.empty_pulls},
+        # the last step's routing counters, summed over layers (None: no
+        # gmm-dispatched experts)
+        moe=moe_counters,
     )))
     if step_s is not None:
         n_chips = mesh.devices.size
