@@ -161,22 +161,26 @@ def test_flash_kernels_keep_their_names_under_remat_in_a_scan(one_chip, no_cache
 # ---- paged decode: the kernel the serve engine cannot run without ---------
 
 
+@pytest.mark.parametrize("rows", [1, 128], ids=["decode", "chunk128"])
 @pytest.mark.parametrize("page", [16, 128])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 def test_flash_attention_decode_compiles_for_v5e(one_chip, no_cache, dtype,
-                                                 page):
-    s_n, h, h_kv, d, n_pages, p = 8, 16, 4, 128, 81, 16  # gqa-2048 widths
+                                                 page, rows):
+    """One row a sequence over a batch of slots (a decode step), and one
+    sequence's chunk of 128 positions as ONE q tile of 128 x 4 rows."""
+    h, h_kv, d, n_pages, p = 16, 4, 128, 81, 16  # gqa-2048 widths
+    s_n = 8 if rows == 1 else 1
 
     def a(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     pool = a((n_pages, h_kv, page, d), dtype)
     names = _kernel_names(
-        lambda q, k, v, pt, sl: fa._decode_call(
-            q, k[None], v[None], 0, pt, sl, False),
-        a((s_n, h, d), dtype), pool, pool, a((s_n, p), jnp.int32),
-        a((s_n,), jnp.int32),
+        lambda q, k, v, pt, sl, qs: fa._paged_call(
+            q, k[None], v[None], 0, pt, sl, qs, False),
+        a((s_n, rows, h, d), dtype), pool, pool, a((s_n, p), jnp.int32),
+        a((s_n,), jnp.int32), a((s_n,), jnp.int32),
     )
     assert names == ["paged_attention"]
 
@@ -248,6 +252,12 @@ def test_engine_programs_keep_the_pool_in_place_on_v5e(serve1_engine, program):
     kernels = _kernel_names_in(text)
     assert kernels.count("paged_attention") == 2, kernels
     assert report[f"{program}_tpu_custom_calls"] >= 2
+    # 4. a layer's kernel walks (slots x) 8 KV heads x 20 page slots: the
+    # chunk's 128 positions are ONE tile over their sequence's pages (at
+    # the cell's 12 layers: 1,920 steps a chunk, where 128 pseudo-sequences
+    # through the decode kernel made 245,760)
+    per_layer = {"decode": 16 * 8 * 20, "prefill": 8 * 20}[program]
+    assert 0 < report[f"{program}_attn_grid_steps"] <= 2 * per_layer
 
 
 @pytest.mark.parametrize("page", [8, 16])
@@ -265,6 +275,9 @@ def test_engine_compile_counts_no_pool_copies_at_tiny(page):
     report = engine.compile()
     assert report["decode_pool_copies"] == 0
     assert report["prefill_pool_copies"] == 0
+    # no kernel in the program, none counted
+    assert report["decode_attn_grid_steps"] == 0
+    assert report["prefill_attn_grid_steps"] == 0
 
 
 # ---- grouped matmul: fwd, dx, dw, with and without the fused row scale ----

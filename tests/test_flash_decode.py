@@ -14,6 +14,7 @@ from tf_operator_tpu.ops.flash_attention import (  # noqa: E402
     flash_attention,
     flash_attention_decode,
     paged_decode_reference,
+    reference_attention,
 )
 from tf_operator_tpu.serve.kvcache import (  # noqa: E402
     PagePool,
@@ -172,6 +173,92 @@ def test_decode_incremental_accumulation():
             )
         )[0]
         np.testing.assert_allclose(out, full[t], atol=2e-5, rtol=2e-5)
+
+
+# A prefill chunk: ``rows`` consecutive positions of ONE sequence as one
+# query tile. (rows, start, n_valid, g, page) — the tile path engages when
+# rows·g is a multiple of 8.
+TILES = {
+    "from-0-over-two-pages": (16, 0, 16, 4, 8),
+    "mid-page-start": (16, 5, 16, 4, 8),           # pages 0..2, both ends ragged
+    "page-boundary-start": (16, 16, 16, 4, 8),
+    "padded-rows": (16, 8, 11, 4, 8),              # n_valid < rows
+    "padded-to-one-row": (8, 13, 1, 4, 8),
+    "deep-mid-page-page16": (16, 37, 9, 2, 16),
+    "mha-g1": (16, 5, 12, 1, 8),
+    "one-row-is-decode": (1, 22, 1, 4, 8),
+    "misaligned-takes-the-reference": (3, 6, 3, 2, 8),  # rows·g = 6
+    "oversize-takes-the-reference": (16, 5, 16, 4, 8),  # VMEM budget cut to 1 KB
+}
+SAID = {"misaligned": "not a multiple of 8 rows", "oversize": "does not fit"}
+
+
+@pytest.mark.parametrize("case", sorted(TILES))
+def test_prefill_tile_matches_causal_prefix(case, caplog, monkeypatch):
+    """The tiled path (interpret mode) against ``reference_attention(
+    causal=True)`` on the contiguous prefix: every valid row of the chunk
+    is row ``start + i`` of the full causal attention, whatever page the
+    chunk starts or ends in; rows past ``n_valid`` come back zero from the
+    kernel. Pages are scrambled and read out of a 3-layer pool at
+    ``layer=1``. A tile of one row IS a decode step, bit for bit; a tile
+    the kernel cannot take (rows·g off the sublane grid, or more rows than
+    VMEM holds with their carry) goes to the gather reference, which a TPU
+    run says once in the log."""
+    import importlib
+    import logging
+    from unittest import mock
+
+    rows, start, n_valid, g, page = TILES[case]
+    h_kv, d = 2, 128
+    h, L = h_kv * g, start + n_valid
+    k_seqs, v_seqs, kp, vp, table, lens = _paged_prefix(
+        [L, 3], page, h_kv, d, seed=rows + start, scramble=True)
+    kp5 = jnp.stack([kp + 1.0, kp, kp * 2.0])
+    vp5 = jnp.stack([vp - 1.0, vp, vp * 0.5])
+    table, lens = table[:1], lens[:1]
+    # the engine's table rows are max_pages wide: slots past the live
+    # prefix (here two more) name a valid page nobody may read
+    table = jnp.concatenate([table, jnp.full((1, 2), int(table[0, 0]))], axis=1)
+    rng = np.random.RandomState(7)
+    q = rng.randn(1, rows, h, d).astype(np.float32)
+    q_start = jnp.asarray([start], jnp.int32)
+
+    def run(**kw):
+        return np.asarray(flash_attention_decode(
+            jnp.asarray(q), kp5, vp5, table, lens, layer=1, q_start=q_start,
+            **kw))[0]
+
+    q_full = np.zeros((1, L, h, d), np.float32)
+    q_full[0, start:] = q[0, :n_valid]
+    want = np.asarray(reference_attention(
+        jnp.asarray(q_full), jnp.asarray(k_seqs[0][None]),
+        jnp.asarray(v_seqs[0][None]), causal=True))[0, start:]
+    ref = np.asarray(paged_decode_reference(
+        jnp.asarray(q), kp5, vp5, table, lens, 1, q_start))[0]
+    np.testing.assert_allclose(ref[:n_valid], want, atol=2e-5, rtol=2e-5)
+    assert np.isfinite(ref).all()
+
+    if case.split("-")[0] in SAID:
+        fa = importlib.import_module("tf_operator_tpu.ops.flash_attention")
+        fa._said.clear()
+        if case.startswith("oversize"):
+            monkeypatch.setattr(fa, "_TILE_VMEM_BUDGET", 1 << 10)
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+                caplog.at_level(logging.WARNING):
+            got, again = run(), run()
+        np.testing.assert_array_equal(got, ref)
+        np.testing.assert_array_equal(again, ref)
+        said = [r.getMessage() for r in caplog.records
+                if "flash_attention_decode" in r.getMessage()]
+        assert len(said) == 1 and SAID[case.split("-")[0]] in said[0]
+        return
+    got = run(interpret=True)
+    np.testing.assert_allclose(got[:n_valid], want, atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(got[n_valid:], 0.0)
+    if rows == 1:  # q [s, h, d], no q_start: the decode step's own call
+        np.testing.assert_array_equal(got, np.asarray(flash_attention_decode(
+            jnp.asarray(q[:, 0]), kp5, vp5, table, lens, interpret=True,
+            layer=1)))
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])

@@ -133,6 +133,7 @@ def test_counters_in_the_trace_equal_the_run_results(traced):
     assert sum(a["n_valid"] for a in by["serve.prefill"]) == c.prefill_tokens \
         == sum(len(r.prompt) for r in res.requests)
     assert sum(a["chunk"] - a["n_valid"] for a in by["serve.prefill"]) == c.prefill_padded
+    assert sum(a["kv_pages"] for a in by["serve.prefill"]) == c.prefill_kv_pages > 0
     assert sum(a["last"] for a in by["serve.prefill"]) == len(by["serve.prefill_fetch"]) \
         == len(res.requests) == c.admitted
     assert len(by["serve.decode"]) == c.decode_steps

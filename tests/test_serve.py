@@ -227,6 +227,66 @@ def test_engine_agrees_with_unpaged_greedy_decoding(tiny_engine, compiled):
         assert n_exact == len(req.tokens) and max_gap == 0.0
 
 
+CHUNK_PROMPTS = (5, 19, 37, 40, 64)  # one chunk to eight; ragged and exact ends
+
+
+def _chunk_engine(tiny_engine, chunk, page=8):
+    from tf_operator_tpu.serve.engine import ServeConfig, ServeEngine
+
+    return ServeEngine(
+        tiny_engine.cfg, tiny_engine.params,
+        ServeConfig(page_size=page, pool_pages=48, max_slots=3,
+                    prefill_chunk=chunk))
+
+
+def _chunk_requests():
+    import numpy as np
+
+    from tf_operator_tpu.serve.engine import Request
+
+    rng = np.random.RandomState(11)
+    return [Request(rid=i, prompt=[int(t) for t in rng.randint(1, 256, n)],
+                    max_new=5)
+            for i, n in enumerate(CHUNK_PROMPTS)]
+
+
+@pytest.mark.serve
+@pytest.mark.parametrize("chunk", [8, 32])
+def test_chunked_prefill_picks_the_unchunked_oracles_tokens(tiny_engine, chunk):
+    """A chunk is ONE query tile over its sequence's pages: prompts of one
+    chunk to eight, ending mid-chunk, mid-page and on both boundaries,
+    prefilled 8 or 32 positions at a time, continue with the tokens plain
+    un-paged, un-chunked greedy decoding picks."""
+    from tf_operator_tpu.serve.engine import greedy_reference_gaps
+
+    res = _chunk_engine(tiny_engine, chunk).run(
+        _chunk_requests(), clock=_fake_clock())
+    assert res.completed == len(CHUNK_PROMPTS)
+    assert res.free_pages_start == res.free_pages_end
+    for req in res.requests:
+        n_exact, max_gap = greedy_reference_gaps(
+            tiny_engine.cfg, tiny_engine.params, req.prompt, req.tokens)
+        assert n_exact == len(req.tokens) and max_gap == 0.0, req.rid
+
+
+@pytest.mark.serve
+@pytest.mark.parametrize("chunk,page", [(8, 8), (32, 8), (8, 16)])
+def test_prefill_kv_pages_is_its_arithmetic(tiny_engine, chunk, page):
+    """``prefill_kv_pages``: the pages of K/V each chunk's attention walks
+    — the sequence's prefix up to the chunk's last valid position, rounded
+    up to pages — counted once a chunk."""
+    res = _chunk_engine(tiny_engine, chunk, page).run(
+        _chunk_requests(), clock=_fake_clock())
+    ends = [min(start + chunk, n) for n in CHUNK_PROMPTS
+            for start in range(0, n, chunk)]
+    c = res.counters
+    assert c.prefill_chunks == len(ends)
+    assert c.prefill_tokens == sum(CHUNK_PROMPTS)
+    assert c.prefill_kv_pages == sum(-(-end // page) for end in ends)
+    # a request's last chunk walks its whole prompt
+    assert c.prefill_kv_pages >= sum(-(-n // page) for n in CHUNK_PROMPTS)
+
+
 @pytest.mark.serve
 def test_engine_rejects_impossible_requests(tiny_engine):
     from tf_operator_tpu.serve.engine import Request

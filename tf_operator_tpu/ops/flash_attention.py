@@ -590,7 +590,7 @@ def flash_attention_lse(
 def _pick_block(t: int, target: int) -> int:
     """Largest 8-aligned divisor of t not exceeding target (grid overhead
     falls with block size: 512/1024 blocks measured 2.2x faster than
-    128/128 at t=2048 on v5e). A misaligned target is first rounded down
+    128/128 at t=2048 on v5e). A why_not target is first rounded down
     to a multiple of 8 — the candidate scan steps by 8, so an unaligned
     start would only ever visit unaligned candidates and the gate would
     silently reject the kernel (the g=3/5/12 GQA default targets hit
@@ -704,55 +704,99 @@ def flash_attention(
 
 
 # ---------------------------------------------------------------------------
-# paged decode path (serving, r10)
+# paged attention (serving: decode step and prefill chunk)
 # ---------------------------------------------------------------------------
 #
-# Single-query-per-sequence attention over a PAGED K/V cache
-# (serve/kvcache.py): K/V live in fixed-size pages of a preallocated pool
-# and each sequence owns an ordered page table. The decode step never
-# materializes a contiguous [t, d] K/V tensor on TPU — the kernel walks
-# the page table as its innermost grid dimension and DMAs one page per
-# step, with page ids resolved through scalar-prefetch (the page table is
-# in SMEM before the grid runs, so the K/V BlockSpec index_map can
-# compute each step's HBM source block from it). The online-softmax
-# carry (m, l, acc) is the forward kernel's, shrunk to the g rows of one
-# GQA group — a decode step has exactly one query position per sequence.
+# Attention of a TILE of consecutive query positions of one sequence over
+# that sequence's PAGED K/V cache (serve/kvcache.py): K/V live in
+# fixed-size pages of a preallocated pool and each sequence owns an
+# ordered page table. Nothing materializes a contiguous [t, d] K/V tensor
+# on TPU — the kernel walks the page table as its innermost grid
+# dimension and DMAs one page per step, with page ids resolved through
+# scalar-prefetch (the page table is in SMEM before the grid runs, so the
+# K/V BlockSpec index_map can compute each step's HBM source block from
+# it). The q tile of one (sequence, kv-head) pair is [rows·g, d]: the
+# ``rows`` query positions times the g heads of the GQA group, stacked on
+# the sublane dim as in the forward kernel, with one row of the
+# online-softmax carry (m, l, acc) each and a causal limit per query
+# position. A decode step is the tile at rows = 1 over every slot; a
+# prefill chunk is ONE sequence at rows = chunk, whose pages are walked
+# once for all its positions.
+
+
+def _tile_q(q, h_kv):
+    """q [s, r, h, d] -> [s, h_kv, r·g, d]: row ``i·g + j`` of a tile is
+    query position i, head j of the group (a free reshape at r = 1)."""
+    s_n, r, h, d = q.shape
+    g = h // h_kv
+    return jnp.swapaxes(q.reshape(s_n, r, h_kv, g, d), 1, 2).reshape(
+        s_n, h_kv, r * g, d)
+
+
+def _untile_o(o, r):
+    """[s, h_kv, r·g, d] -> [s, r, h, d], ``_tile_q`` undone."""
+    s_n, h_kv, n, d = o.shape
+    return jnp.swapaxes(o.reshape(s_n, h_kv, r, n // r, d), 1, 2).reshape(
+        s_n, r, h_kv * (n // r), d)
+
+
+def _tile_visible(kpos, row, q_start, length, g):
+    """Which keys a tile's rows see: row ``i·g + j`` is the query at
+    position ``q_start + i``; it sees keys ``<= q_start + i`` if that
+    position lies inside the sequence (``< length``), none otherwise (a
+    padded row, or an inactive slot's). Written without the division
+    ``row // g``: g·(kpos − q_start) is a multiple of g, so it is
+    ``<= g·i`` exactly when it is ``<= row``."""
+    return (g * (kpos - q_start) <= row) & (row < g * (length - q_start))
 
 
 def paged_decode_reference(q, k_pages, v_pages, page_table, seq_lens,
-                           layer: Optional[int] = None):
-    """Pure-JAX paged decode attention — the correctness oracle and the
-    off-TPU fallback (same contract as the decode kernel).
+                           layer: Optional[int] = None, q_start=None):
+    """Pure-JAX paged attention — the correctness oracle and the off-TPU
+    fallback (same contract as the kernel).
 
-    q [s, h, d] (one query token per sequence), k_pages/v_pages
+    q [s, h, d] (one query token per sequence) or [s, r, h, d] (r
+    consecutive positions of each), k_pages/v_pages
     [n_pages, h_kv, page_size, d] — or the whole [n_layers, n_pages,
     h_kv, page_size, d] pool with ``layer`` naming the one to read —
     page_table [s, p] int32 (page ids in
     sequence order; rows padded with any valid id past the live prefix),
     seq_lens [s] int32 = valid K/V tokens per sequence INCLUDING the
-    current position. Gathers pages to [s, h_kv, p·page_size, d] (one
+    last query position, q_start [s] int32 = position of each sequence's
+    first query row (default: the rows are the sequence's last).
+    Gathers pages to [s, h_kv, p·page_size, d] ONCE for all rows (one
     gather straight out of the pool: no layer is sliced off first), masks
-    positions >= seq_len with the NEG_INF sentinel, f32 softmax. Rows
-    with seq_len == 0 produce the uniform-softmax artifact (see
-    reference_attention_lse) — callers mask inactive slots out."""
-    s_n, h, d = q.shape
+    per row (``_tile_visible``) with the NEG_INF sentinel, f32 softmax.
+    Rows that see nothing (seq_len == 0, a padded row) produce the
+    uniform-softmax artifact (see reference_attention_lse) — callers mask
+    them out."""
+    flat = q.ndim == 3
+    if flat:
+        q = q[:, None]
+    s_n, r, h, d = q.shape
     h_kv, page_size = k_pages.shape[-3:-1]
     p = page_table.shape[1]
     g = h // h_kv
     scale = d**-0.5
+    if q_start is None:
+        q_start = seq_lens - r
 
     def gather(pages):  # [s, p, h_kv, page, d] -> [s, h_kv, p·page, d]
         got = pages[page_table] if layer is None else pages[layer, page_table]
         return jnp.swapaxes(got, 1, 2).reshape(s_n, h_kv, p * page_size, d)
 
     k, v = gather(k_pages), gather(v_pages)
-    q5 = q.reshape(s_n, h_kv, g, d).astype(jnp.float32) * scale
+    q5 = _tile_q(q, h_kv).astype(jnp.float32) * scale
     s = jnp.einsum(
         "shgd,shtd->shgt", q5, k.astype(jnp.float32),
         preferred_element_type=jnp.float32,
-    )  # [s, h_kv, g, t]
+    )  # [s, h_kv, r·g, t]
     kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 3)
-    s = jnp.where(kpos < seq_lens[:, None, None, None], s, NEG_INF)
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    per_seq = (slice(None), None, None, None)
+    s = jnp.where(
+        _tile_visible(kpos, row, q_start[per_seq], seq_lens[per_seq], g),
+        s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     pr = jnp.exp(s - m)
     l = jnp.sum(pr, axis=-1, keepdims=True)
@@ -760,23 +804,27 @@ def paged_decode_reference(q, k_pages, v_pages, page_table, seq_lens,
         "shgt,shtd->shgd", pr / l, v.astype(jnp.float32),
         preferred_element_type=jnp.float32,
     )
-    return out.reshape(s_n, h, d).astype(q.dtype)
+    out = _untile_o(out, r).astype(q.dtype)
+    return out[:, 0] if flat else out
 
 
-def _decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, page_size, g, scale):
-    """One (sequence, kv-head) pair streams its pages through VMEM. The
-    innermost grid dim walks page-table SLOTS; pages past the sequence's
-    live prefix are skipped with pl.when (the DMA still lands — a valid
-    pool page, contents ignored). In-page positions past seq_len mask to
-    NEG_INF, so a sequence ending mid-page is exact (the page-boundary-
-    crossing case tests/test_flash_decode.py pins)."""
+def _paged_kernel(pt_ref, sl_ref, qs_ref, q_ref, k_ref, v_ref, o_ref,
+                  m_scr, l_scr, acc_scr, *, page_size, g, scale):
+    """One (sequence, kv-head) pair streams its pages through VMEM past
+    its q tile [rows·g, d]. The innermost grid dim walks page-table
+    SLOTS; slots past the sequence's live prefix are skipped with pl.when
+    (and fetch nothing: their index map repeats the last live page).
+    Inside a live page every row masks what it may not see to NEG_INF
+    (``_tile_visible``), so a sequence ending mid-page and a chunk that
+    starts or ends mid-page are exact (the cases
+    tests/test_flash_decode.py pins). A row that has seen no key yet
+    keeps m at the sentinel, where exp(s - m) of a masked key would be 1:
+    p is zeroed by the same mask, so padded rows end with l == 0."""
     from jax.experimental import pallas as pl
 
     si = pl.program_id(0)
     pi = pl.program_id(2)
     npi = pl.num_programs(2)
-    d = q_ref.shape[-1]
 
     @pl.when(pi == 0)
     def _init():
@@ -785,21 +833,24 @@ def _decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[:, :] = jnp.zeros_like(acc_scr)
 
     length = sl_ref[si]
+    q_start = qs_ref[si]
     live = pi * page_size < length
 
     @pl.when(live)
     def _step():
-        q = q_ref[0, 0].reshape(g, d).astype(jnp.float32) * scale
+        q = q_ref[0, 0].astype(jnp.float32) * scale  # [rows·g, d]
         k = k_ref[0, 0, 0].astype(jnp.float32)  # [page_size, d]
         v = v_ref[0, 0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [g, page_size]
+        )  # [rows·g, page_size]
         kpos = pi * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < length, s, NEG_INF)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        seen = _tile_visible(kpos, row, q_start, length, g)
+        s = jnp.where(seen, s, NEG_INF)
         m_prev = m_scr[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.where(seen, jnp.exp(s - m_new[:, None]), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         m_scr[:, :] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
         l_scr[:, :] = l_scr[:, :] * alpha[:, None] + jnp.sum(p, axis=1)[:, None]
@@ -809,66 +860,86 @@ def _decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(pi == npi - 1)
     def _finish():
-        # seq_len == 0 leaves l at 0 (no live page ever ran) — guard the
-        # divide so inactive slots emit zeros, not nan.
+        # a row that saw no key (seq_len == 0, a padded row) leaves l at
+        # 0 — guard the divide so it emits zeros, not nan.
         l = l_scr[:, 0]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:, :] / l[:, None]).reshape(g, d).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[:, :] / l[:, None]).astype(o_ref.dtype)
 
 
-def _decode_call(q, k_pool, v_pool, layer, page_table, seq_lens, interpret):
+_TILE_VMEM_BUDGET = 12 << 20  # of the 16 MiB a kernel may scope by default
+
+
+def _tile_vmem_bytes(n: int, d: int) -> int:
+    """What ``_paged_call`` keeps resident for a tile of n rows, in f32:
+    q and o double-buffered, the accumulator, m and l a lane row each
+    (the K/V page blocks are small beside them)."""
+    return 4 * n * (5 * d + 2 * LSE_LANES)
+
+
+def _paged_call(q, k_pool, v_pool, layer, page_table, seq_lens, q_start,
+                interpret):
+    """q [s, r, h, d] through the kernel: grid (s, h_kv, page slots)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    s_n, h, d = q.shape
+    s_n, r, h, d = q.shape
     _, _, h_kv, page_size, _ = k_pool.shape
     p = page_table.shape[1]
     g = h // h_kv
-    q4 = q.reshape(s_n, h_kv, g, d)
+    n = r * g
+    qt = _tile_q(q, h_kv)
 
-    # Scalar-prefetch args (page_table, seq_lens) reach the index_maps as
-    # TRAILING refs after the grid indices — the K/V source block for
-    # grid step (si, hk, pi) is whatever page the table names, which is
-    # the whole paging trick. One K/V block is one (page, kv-head) slab
-    # [page_size, d]: the tiled minor dims Mosaic requires of a block
-    # (sublane-aligned page, whole head_dim) — the reason the pools are
-    # laid out [n_layers, n_pages, h_kv, page_size, d]. The kernel takes
-    # the WHOLE pool and ``layer`` (a Python int) sits in the index map:
-    # handing it ``pool[layer]`` makes XLA materialise that layer (a
-    # slice of the whole layer, 84 MB at the -serve1 shapes) before every call.
-    pool_spec = pl.BlockSpec(
-        (1, 1, 1, page_size, d),
-        lambda si, hk, pi, pt, sl: (layer, pt[si, pi], hk, 0, 0),
-    )
+    # Scalar-prefetch args (page_table, seq_lens, q_start) reach the
+    # index_maps as TRAILING refs after the grid indices — the K/V source
+    # block for grid step (si, hk, pi) is whatever page the table names,
+    # which is the whole paging trick. One K/V block is one (page,
+    # kv-head) slab [page_size, d]: the tiled minor dims Mosaic requires
+    # of a block (sublane-aligned page, whole head_dim) — the reason the
+    # pools are laid out [n_layers, n_pages, h_kv, page_size, d]. The
+    # kernel takes the WHOLE pool and ``layer`` (a Python int) sits in
+    # the index map: handing it ``pool[layer]`` makes XLA materialise
+    # that layer (a slice of the whole layer, 84 MB at the -serve1
+    # shapes) before every call. A slot past the last live page names
+    # that page again — a block whose index did not change is not
+    # fetched — and the table is clamped HERE, once a call, not in the
+    # index map, which runs at every grid step on the scalar core.
+    last = jnp.maximum(pl.cdiv(seq_lens, page_size) - 1, 0)
+    page_table = jnp.take_along_axis(
+        page_table,
+        jnp.minimum(jnp.arange(p, dtype=jnp.int32)[None], last[:, None]),
+        axis=1)
+
+    def pool_index(si, hk, pi, pt, sl, qs):
+        return (layer, pt[si, pi], hk, 0, 0)
+
+    def tile_index(si, hk, pi, pt, sl, qs):
+        return (si, hk, 0, 0)
+
+    pool_spec = pl.BlockSpec((1, 1, 1, page_size, d), pool_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(s_n, h_kv, p),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda si, hk, pi, pt, sl: (si, hk, 0, 0)),
-            pool_spec,
-            pool_spec,
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, g, d), lambda si, hk, pi, pt, sl: (si, hk, 0, 0)
-        ),
+        in_specs=[pl.BlockSpec((1, 1, n, d), tile_index), pool_spec, pool_spec],
+        out_specs=pl.BlockSpec((1, 1, n, d), tile_index),
         scratch_shapes=[
-            pltpu.VMEM((g, LSE_LANES), jnp.float32),  # running max m
-            pltpu.VMEM((g, LSE_LANES), jnp.float32),  # running sum l
-            pltpu.VMEM((g, d), jnp.float32),          # output accumulator
+            pltpu.VMEM((n, LSE_LANES), jnp.float32),  # running max m
+            pltpu.VMEM((n, LSE_LANES), jnp.float32),  # running sum l
+            pltpu.VMEM((n, d), jnp.float32),          # output accumulator
         ],
     )
     kernel = functools.partial(
-        _decode_kernel, page_size=page_size, g=g, scale=d**-0.5
+        _paged_kernel, page_size=page_size, g=g, scale=d**-0.5
     )
     o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_n, h_kv, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
         name="paged_attention",
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q4, k_pool, v_pool)
-    return o.reshape(s_n, h, d)
+      q_start.astype(jnp.int32), qt, k_pool, v_pool)
+    return _untile_o(o, r)
 
 
 def flash_attention_decode(
@@ -880,11 +951,15 @@ def flash_attention_decode(
     interpret: Optional[bool] = None,
     force_kernel: Optional[bool] = None,
     layer: Optional[int] = None,
+    q_start=None,
 ):
-    """Paged decode attention: one query token per sequence against a
-    paged K/V cache.
+    """Paged attention: a tile of query positions per sequence against a
+    paged K/V cache — one token per sequence (a decode step) or ``r``
+    consecutive positions of each (a prefill chunk: ONE walk over the
+    sequence's pages serves all its rows).
 
-    q [s, h, d]; k_pages/v_pages [n_pages, h_kv, page_size, d] (the
+    q [s, h, d] or [s, r, h, d]; k_pages/v_pages
+    [n_pages, h_kv, page_size, d] (the
     serve/kvcache.py pool layout — head-major so one (page, kv-head)
     slab is a tile-aligned [page_size, d] block the TPU compiler
     accepts), or the whole pool [n_layers, n_pages, h_kv, page_size, d]
@@ -894,50 +969,65 @@ def flash_attention_decode(
     the pool in front of it (a 4-D pool is the one-layer case, a free
     reshape); page_table [s, max_pages] int32;
     seq_lens [s] int32 (valid K/V length per sequence, INCLUDING the
-    just-written current position — decode attends to itself). Returns
-    [s, h, d] in q's dtype. GQA-native: h % h_kv folds into the q tile
-    exactly as in the full kernel.
+    just-written query positions — a query attends to itself);
+    q_start [s] int32, the position of each sequence's first query row
+    (default ``seq_lens - r``: the rows are the sequence's last). Row i
+    sees keys ``<= q_start + i``; a row at or past ``seq_lens`` (the
+    padding of a short chunk) sees none. Returns q's shape in q's dtype.
+    GQA-native: h % h_kv folds into the q tile exactly as in the full
+    kernel.
 
     Dispatch mirrors flash_attention: the Pallas kernel engages on TPU
     (or under ``interpret=True`` — the CPU test path) when the page size
-    is sublane-aligned for the pool dtype (8 rows of f32, 16 of bf16);
+    is sublane-aligned for the pool dtype (8 rows of f32, 16 of bf16)
+    and a tile of several positions is too (r·g a multiple of 8, and
+    small enough to stay in VMEM with its carry);
     otherwise the pure-JAX gather reference (same math, same f32
     softmax, same NEG_INF masking) — the off-TPU path, so the serve
     engine runs everywhere. A TPU run that takes the reference says so
     once in the log (_say_reference). ``force_kernel``
-    overrides the heuristic both ways (alignment still binds). Rows with
-    seq_lens == 0 are inactive slots: both paths return garbage-but-
-    finite output there (zeros from the kernel, the uniform artifact
-    from the reference) — callers mask, never read."""
-    if q.ndim != 3 or k_pages.ndim != (4 if layer is None else 5):
+    overrides the heuristic both ways (alignment still binds). Rows that
+    see no key (seq_lens == 0: an inactive slot; a padded row) come back
+    garbage-but-finite on both paths (zeros from the kernel, the uniform
+    artifact from the reference) — callers mask, never read."""
+    if q.ndim not in (3, 4) or k_pages.ndim != (4 if layer is None else 5):
         raise ValueError(
-            f"decode shapes: q [s,h,d] (got {q.shape}), pages "
+            f"decode shapes: q [s,h,d] or [s,r,h,d] (got {q.shape}), pages "
             f"[n,h_kv,page,d], or [layers,n,h_kv,page,d] with layer= "
             f"(got {k_pages.shape}, layer={layer})"
         )
     if k_pages.shape != v_pages.shape:
         raise ValueError(f"k/v pool mismatch: {k_pages.shape} vs {v_pages.shape}")
-    h, h_kv, page_size = q.shape[1], *k_pages.shape[-3:-1]
+    h, h_kv, page_size = q.shape[-2], *k_pages.shape[-3:-1]
     if h % h_kv:
         raise ValueError(f"q heads {h} not a multiple of kv heads {h_kv}")
+    r = 1 if q.ndim == 3 else q.shape[1]
     sublanes = 8 * max(1, 4 // jnp.dtype(k_pages.dtype).itemsize)
-    aligned = page_size % sublanes == 0
+    tile = f"a q tile of {r} positions x {h // h_kv} heads a group"
+    why_not = None
+    if page_size % sublanes:
+        why_not = (f"page_size={page_size} is not a multiple of {sublanes} "
+                   f"({jnp.dtype(k_pages.dtype).name} sublanes)")
+    elif r > 1 and (r * h // h_kv) % 8:
+        why_not = f"{tile} is not a multiple of 8 rows"
+    elif _tile_vmem_bytes(r * h // h_kv, q.shape[-1]) > _TILE_VMEM_BUDGET:
+        why_not = f"{tile} does not fit the kernel's VMEM"
     on_tpu = jax.default_backend() == "tpu"
-    use = aligned and (bool(interpret) or on_tpu)
+    use = why_not is None and (bool(interpret) or on_tpu)
     if force_kernel is not None:
         use = force_kernel and use
     if not use:
         if on_tpu and force_kernel is None:
-            _say_reference(
-                "flash_attention_decode",
-                f"page_size={page_size} is not a multiple of {sublanes} "
-                f"({jnp.dtype(k_pages.dtype).name} sublanes)",
-            )
+            _say_reference("flash_attention_decode", why_not)
         return paged_decode_reference(
-            q, k_pages, v_pages, page_table, seq_lens, layer
+            q, k_pages, v_pages, page_table, seq_lens, layer, q_start
         )
     if layer is None:
         k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
-    return _decode_call(
-        q, k_pages, v_pages, layer, page_table, seq_lens, bool(interpret)
+    if q_start is None:
+        q_start = seq_lens - r
+    o = _paged_call(
+        q.reshape(q.shape[0], r, h, q.shape[-1]), k_pages, v_pages, layer,
+        page_table, seq_lens, q_start, bool(interpret),
     )
+    return o.reshape(q.shape)

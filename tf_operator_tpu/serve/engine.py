@@ -22,13 +22,13 @@ Two compiled functions, both fixed-shape:
   and mask attention with seq_len 0.
 
 - the PREFILL chunk: ``prefill_chunk`` prompt tokens of ONE sequence.
-  The chunk's C positions are treated as C pseudo-sequences sharing the
-  sequence's page table row with per-position lengths pos+1 — k/v are
-  written first, then the SAME paged decode attention runs, which makes
-  the chunk causal by construction and keeps prefill on the decode
-  path instead of a second attention implementation. The last chunk's
-  final logits yield the request's first generated token (the TTFT
-  boundary).
+  k/v of the chunk's C positions are written first, then the SAME paged
+  attention runs with the C positions as ONE query tile of that sequence
+  (``q_start`` = the chunk's first position, a causal limit per row):
+  the sequence's pages are walked once for the whole chunk, and prefill
+  has no attention implementation of its own — a decode step is the
+  same kernel with a tile of one row a slot. The last chunk's final
+  logits yield the request's first generated token (the TTFT boundary).
 
 Both take the two KV pools donated and hand them back, and between
 parameter and result the pool is never copied, sliced or relaid: the
@@ -125,6 +125,7 @@ class EngineCounters:
     prefill_chunks: int = 0    # calls of the prefill program
     prefill_tokens: int = 0    # prompt tokens they carried
     prefill_padded: int = 0    # chunk positions they padded
+    prefill_kv_pages: int = 0  # K/V pages their attention walked, once a chunk
     decode_steps: int = 0      # calls of the decode program
     decode_slot_tokens: int = 0  # tokens they produced (active slots, summed)
     idle_sleeps: int = 0       # sleeps of an empty engine waiting for an arrival
@@ -258,6 +259,22 @@ def pool_copies(hlo_text: str, pool_shape) -> int:
     return count
 
 
+def pallas_grid_steps(jaxpr) -> int:
+    """Grid steps of every ``pallas_call`` a traced program holds, summed
+    (calls inside nested jaxprs included; a program that runs the gather
+    reference holds none: 0). What a kernel does in a step is its own
+    affair — this counts how often its body is entered."""
+    from jax.core import jaxprs_in_params
+
+    steps = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            steps += int(np.prod(eqn.params["grid_mapping"].grid))
+        else:
+            steps += sum(map(pallas_grid_steps, jaxprs_in_params(eqn.params)))
+    return steps
+
+
 class _Slot:
     __slots__ = ("req", "pages", "seq_len", "prefill_pos", "cur_tok", "generated")
 
@@ -318,11 +335,11 @@ class ServeEngine:
         hd = cfg.head_dim
         L = cfg.n_layers
 
-        def _body(params, kp, vp, x, pos, table, lens, write_pid, write_row):
+        def _body(params, kp, vp, x, pos, attend, write_pid, write_row):
             """Shared per-layer body: x [n, d] at absolute positions pos
             [n]; writes each row's k/v to (write_pid[i], write_row[i])
-            then attends through ``table`` with per-row lengths ``lens``.
-            The pools [L, page, h_kv, row, hd] are carried WHOLE through
+            then ``attend(q [n, h, hd], kp, vp, l)`` reads them back
+            through the caller's page table(s). The pools [L, page, h_kv, row, hd] are carried WHOLE through
             every layer — written by ``write_rows``, read by the kernel
             at ``layer=l`` — and never indexed by layer here: ``kp[l]``
             is a copy of a layer. Returns (kp, vp, final hidden [n, d])."""
@@ -336,7 +353,7 @@ class ServeEngine:
                 q = rope_at_positions(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
                 k = rope_at_positions(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
                 kp, vp = write_rows(kp, vp, l, k, v, write_pid, write_row)
-                attn = flash_attention_decode(q, kp, vp, table, lens, layer=l)
+                attn = attend(q, kp, vp, l)
                 x = x + attn.reshape(n, -1) @ lp["wo"][l]
                 h2 = _rms_norm(x, lp["mlp_norm"][l], cfg.norm_eps)
                 x = x + (
@@ -352,28 +369,33 @@ class ServeEngine:
             pos = seq_lens
             pid = table[jnp.arange(s), pos // ps]
             pid = jnp.where(active, pid, trash)
-            kp, vp, x = _body(
-                params, kp, vp, x, pos, table,
-                jnp.where(active, pos + 1, 0), pid, pos % ps,
-            )
+            lens = jnp.where(active, pos + 1, 0)
+
+            def attend(q, kp, vp, l):  # one row a slot
+                return flash_attention_decode(q, kp, vp, table, lens, layer=l)
+
+            kp, vp, x = _body(params, kp, vp, x, pos, attend, pid, pos % ps)
             logits = _rms_norm(x, params["final_norm"], cfg.norm_eps) @ params["embed"].T
             return kp, vp, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         def prefill_chunk(params, kp, vp, table_row, start, tokens_c, n_valid):
-            """One chunk of one sequence's prompt: the C positions run as
-            C pseudo-sequences over the shared page-table row (lengths
-            pos+1 ⇒ causal), reusing the paged decode attention."""
+            """One chunk of one sequence's prompt: its C positions are one
+            query tile over the sequence's page-table row, causal per row;
+            rows past ``n_valid`` lie past the sequence's length, see
+            nothing and write to the trash page."""
             c = tokens_c.shape[0]
             idx = jnp.arange(c)
             pos = start + idx
             valid = idx < n_valid
             x = params["embed"][tokens_c]
             pid = jnp.where(valid, table_row[pos // ps], trash)
-            table_c = jnp.broadcast_to(table_row, (c, table_row.shape[0]))
-            kp, vp, x = _body(
-                params, kp, vp, x, pos, table_c,
-                jnp.where(valid, pos + 1, 0), pid, pos % ps,
-            )
+
+            def attend(q, kp, vp, l):  # ONE sequence, c rows
+                return flash_attention_decode(
+                    q[None], kp, vp, table_row[None], (start + n_valid)[None],
+                    layer=l, q_start=start[None])[0]
+
+            kp, vp, x = _body(params, kp, vp, x, pos, attend, pid, pos % ps)
             last = _rms_norm(x[n_valid - 1], params["final_norm"], cfg.norm_eps)
             logits = last @ params["embed"].T
             return kp, vp, jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -388,11 +410,14 @@ class ServeEngine:
         compile, and a program the device's compiler refuses fails here,
         by name. Returns each program's compile seconds, how many
         ``tpu_custom_call``s (Pallas kernels) its compiled text holds —
-        0 means the step runs the gather reference, not the kernel — and
+        0 means the step runs the gather reference, not the kernel —
         ``<program>_pool_copies``: how many of its instructions
         materialise a whole pool side or a whole layer of one
         (``pool_copies``; 0 while the pool is written and read in place,
-        in one layout)."""
+        in one layout), and ``<program>_attn_grid_steps``: the grid steps
+        of its attention kernels, all layers (``pallas_grid_steps``; a
+        chunk walks its sequence's pages once: KV heads x page slots a
+        layer)."""
         import jax
         import jax.numpy as jnp
 
@@ -417,8 +442,10 @@ class ServeEngine:
         out: Dict[str, Any] = {}
         for name, (fn, args) in programs.items():
             t0 = time.perf_counter()
-            compiled = fn.lower(*args).compile()
+            traced = fn.trace(*args)
+            compiled = traced.lower().compile()
             out[f"{name}_compile_s"] = round(time.perf_counter() - t0, 3)
+            out[f"{name}_attn_grid_steps"] = pallas_grid_steps(traced.jaxpr.jaxpr)
             text = compiled.as_text()
             out[f"{name}_tpu_custom_calls"] = text.count("tpu_custom_call")
             out[f"{name}_pool_copies"] = pool_copies(text, self._pool_shape())
@@ -568,9 +595,11 @@ class ServeEngine:
                 chunk = prompt[sl.prefill_pos : sl.prefill_pos + c]
                 n_valid = len(chunk)
                 last = sl.prefill_pos + n_valid >= len(prompt)
+                kv_pages = pages_needed(sl.prefill_pos + n_valid,
+                                        scfg.page_size)
                 with span("serve.prefill", rid=sl.req.rid, slot=i,
                           start=sl.prefill_pos, n_valid=n_valid, chunk=c,
-                          last=int(last)):
+                          last=int(last), kv_pages=kv_pages):
                     buf = np.zeros(c, np.int32)
                     buf[:n_valid] = chunk
                     if not scfg.reserve_full:
@@ -584,6 +613,7 @@ class ServeEngine:
                 counters.prefill_chunks += 1
                 counters.prefill_tokens += n_valid
                 counters.prefill_padded += c - n_valid
+                counters.prefill_kv_pages += kv_pages
                 sl.prefill_pos += n_valid
                 sl.seq_len = sl.prefill_pos
                 if last:
