@@ -4,6 +4,9 @@ Multi-chip hardware is not available in CI; sharding/collective tests run on
 a virtual 8-device CPU mesh exactly as the driver's dryrun does.
 """
 
+import functools
+import importlib.util
+import json
 import os
 import sys
 
@@ -30,3 +33,27 @@ def wait_for(predicate, timeout=30.0, interval=0.05):
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)
+
+
+def load_by_path(name, path):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.cache
+def benchmarks_conftest():
+    """``benchmarks/tests/conftest.py``, loaded by path (here ``conftest``
+    names this file). Its ``make_tiny_root`` knows the tiny form of the mixes
+    of two runners; a mix of a runner that extends one of them
+    (``train_smallthinker``, PR 26) is cut as its family's are — tier-1 loads
+    no cell of such a mix from a tiny root."""
+    home = os.path.join(_ROOT, "benchmarks")
+    conf = load_by_path("benchmarks_tests_conftest",
+                        os.path.join(home, "tests", "conftest.py"))
+    for name in os.listdir(os.path.join(home, "traffic")):
+        with open(os.path.join(home, "traffic", name)) as f:
+            runner = json.load(f)["runner"]
+        conf.TINY_MIXES.setdefault(runner, conf.TINY_MIXES[runner.split("_")[0]])
+    return conf

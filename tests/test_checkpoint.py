@@ -302,37 +302,42 @@ def test_readonly_manager_refuses_save_and_preserves_tmp_dirs(tmp_path, sharded_
     assert not live_tmp.exists()
 
 
-def test_run_loop_device_loop_matches_per_step(tmp_path):
-    """run_loop with device_loop=K: same trajectory, same checkpoints —
-    chunks clip to save boundaries so no periodic save is skipped."""
+def test_run_loop_matches_hand_driven_steps_and_saves_at_boundaries(tmp_path):
+    """run_loop is trainer.step called ``1 + steps`` times: the same
+    trajectory as a hand-driven loop, a save at every boundary plus the
+    final one, and on_step after EVERY step (fault injection keys on it)."""
     from tf_operator_tpu.train.checkpoint import WorkloadCheckpointer
 
     mesh = build_mesh({"dp": 2, "tp": 4})
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 256)
+    trainer, cfg = _tiny_trainer(mesh)
+    tok = jax.device_put(
+        jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 256),
+        trainer.batch_sharding,
+    )
+    wl = {"checkpoint_dir": str(tmp_path / "loop"), "checkpoint_every": 2,
+          "checkpoint_keep": 8}
+    ckpt = WorkloadCheckpointer(wl)
+    seen = []
+    state, loss, timed, step_s = ckpt.run_loop(
+        trainer, jax.random.PRNGKey(0), tok, 7, on_step=seen.append
+    )
+    assert seen == list(range(1, 9))  # warmup + 7, none skipped or late
+    assert timed == 7 and step_s > 0 and int(state.step) == 8
+    assert ckpt.manager.all_steps() == [2, 4, 6, 8]
 
-    def run(device_loop, sub):
-        trainer, cfg = _tiny_trainer(mesh)
-        wl = {"checkpoint_dir": str(tmp_path / sub), "checkpoint_every": 2}
-        ckpt = WorkloadCheckpointer(wl)
-        tok = jax.device_put(tokens, trainer.batch_sharding)
-        state, loss, timed, _ = ckpt.run_loop(
-            trainer, jax.random.PRNGKey(0), tok, 7, device_loop=device_loop
-        )
-        return state, loss, ckpt
-
-    s1, loss1, ckpt1 = run(1, "per-step")
-    s2, loss2, ckpt2 = run(3, "chunked")
-    np.testing.assert_allclose(loss1, loss2, rtol=1e-5)
+    ref, _ = _tiny_trainer(mesh)
+    s_ref = ref.init(jax.random.PRNGKey(0))
+    for _ in range(8):
+        s_ref, m_ref = ref.step(s_ref, tok)
+    np.testing.assert_allclose(loss, float(m_ref["loss"]), rtol=1e-5)
     for a, b in zip(
-        jax.tree_util.tree_leaves(s1.params), jax.tree_util.tree_leaves(s2.params)
+        jax.tree_util.tree_leaves(state.params),
+        jax.tree_util.tree_leaves(s_ref.params),
     ):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5)
-    # identical save points (incl. the boundary-clipped ones and the final)
-    assert ckpt1.manager.all_steps() == ckpt2.manager.all_steps()
 
 
-def test_run_loop_device_loop_stacks_iterator_batches(tmp_path):
-    """device_loop over a loader: K pulls stack into one [K, ...] chunk."""
+def test_run_loop_pulls_one_iterator_batch_per_step(tmp_path):
     from tf_operator_tpu.train.checkpoint import WorkloadCheckpointer
     from tf_operator_tpu.train.data import ArrayDataset, DeviceLoader
 
@@ -343,81 +348,47 @@ def test_run_loop_device_loop_stacks_iterator_batches(tmp_path):
         batch_size=4, shuffle=False,
     )
     ckpt = WorkloadCheckpointer({})
+    pulled = []
     with DeviceLoader(ds, trainer.batch_sharding) as loader:
-        it = (b["t"] for b in loader)
-        state, loss, timed, _ = ckpt.run_loop(
-            trainer, jax.random.PRNGKey(0), it, 6, device_loop=4
+        it = (pulled.append(1) or b["t"] for b in loader)
+        state, loss, timed, step_s = ckpt.run_loop(
+            trainer, jax.random.PRNGKey(0), it, 6
         )
-    # 7 total steps trained: 1 warmup + 4-step warmup chunk + 2 timed
-    assert timed == 2 and int(state.step) == 7
-    assert np.isfinite(loss)
+    # the warmup step trains on a batch of its own and stays untimed
+    assert timed == 6 and int(state.step) == 7 and len(pulled) == 7
+    assert np.isfinite(loss) and step_s > 0
 
 
-def test_run_loop_device_loop_bigger_than_budget_keeps_telemetry(tmp_path):
-    """device_loop >= remaining budget: the warmup must not swallow every
-    step — at least one chunk stays in the timed region so step_s (the
-    workloads' tokens/sec / MFU divisor) is still reported."""
+def test_run_loop_resumes_at_the_saved_step(tmp_path):
+    """A restarted worker re-enters run_loop: it restores the final save,
+    times only the steps that remain, and on_step counts on from there."""
     from tf_operator_tpu.train.checkpoint import WorkloadCheckpointer
 
     mesh = build_mesh({"dp": 2, "tp": 4})
-    trainer, cfg = _tiny_trainer(mesh)
-    tok = jax.device_put(
-        jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 256),
-        trainer.batch_sharding,
-    )
-    ckpt = WorkloadCheckpointer({})
-    state, loss, timed, step_s = ckpt.run_loop(
-        trainer, jax.random.PRNGKey(0), tok, 10, device_loop=10
-    )
-    assert int(state.step) == 11  # warmup + 10
-    assert timed >= 1 and step_s is not None
+    wl = {"checkpoint_dir": str(tmp_path / "resume"), "checkpoint_every": 100}
 
-
-def test_init_and_step_matches_init_then_step():
-    """The submit-latency fast path (one fused program) must be bitwise
-    the same math as init() followed by step()."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from tf_operator_tpu.parallel import build_mesh
-    from tf_operator_tpu.train.trainer import Trainer, TrainerConfig
-
-    mesh = build_mesh({"dp": 1}, devices=jax.devices()[:1])
-
-    def init_fn(key):
-        return {"w": jax.random.normal(key, (8, 8), jnp.float32)}
-
-    def loss_fn(params, batch, extra):
-        del extra
-        return jnp.mean(jnp.square(batch @ params["w"]))
-
-    def mk():
-        return Trainer(
-            mesh, loss_fn=loss_fn, init_fn=init_fn,
-            config=TrainerConfig(optimizer="sgd", learning_rate=0.1),
+    def run(steps):
+        trainer, cfg = _tiny_trainer(mesh)
+        tok = jax.device_put(
+            jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 256),
+            trainer.batch_sharding,
         )
+        ckpt = WorkloadCheckpointer(wl)
+        seen = []
+        if ckpt.is_complete(steps):
+            return ckpt, None, 0, seen
+        state, _, timed, _ = ckpt.run_loop(
+            trainer, jax.random.PRNGKey(0), tok, steps, on_step=seen.append
+        )
+        return ckpt, state, timed, seen
 
-    batch = jax.device_put(
-        jax.random.normal(jax.random.PRNGKey(1), (4, 8)), mk().batch_sharding
-    )
-    key = jax.random.PRNGKey(0)
-
-    t1 = mk()
-    s_ref = t1.init(key)
-    s_ref, m_ref = t1.step(s_ref, batch)
-
-    t2 = mk()
-    s_fused, m_fused = t2.init_and_step(key, batch)
-
-    np.testing.assert_allclose(float(m_fused["loss"]), float(m_ref["loss"]), rtol=1e-6)
-    np.testing.assert_allclose(
-        np.asarray(s_fused.params["w"]), np.asarray(s_ref.params["w"]), rtol=1e-6
-    )
-    assert int(s_fused.step) == 1
-    # and the normal step program continues from the fused state
-    s_next, m_next = t2.step(s_fused, batch)
-    assert float(m_next["loss"]) < float(m_fused["loss"])
+    ckpt, state, timed, seen = run(3)
+    assert int(state.step) == 4 and timed == 3 and seen == [1, 2, 3, 4]
+    assert ckpt.manager.all_steps() == [4]  # no boundary crossed: the final save
+    assert run(3)[1] is None  # already complete: no restore, no step
+    ckpt, state, timed, seen = run(7)
+    assert ckpt.start_step == 4 and timed == 3
+    assert int(state.step) == 8 and seen == [5, 6, 7, 8]
 
 
 # ---------------------------------------------------------------------------

@@ -562,11 +562,7 @@ def test_lm_training_streams_through_device_loader(rig):
     trains the LM with host batches flowing through the prefetching
     DeviceLoader (data="stream") instead of one resident device batch.
     In multi-process mode each process stages only its local slice
-    (make_array_from_process_local_data). device_loop=2 (r4, VERDICT r3
-    #7a): stream chunks are stacked by a JITTED stacker — multi-host
-    global arrays can't stack eagerly — and run through
-    Trainer.multi_step(stacked=True); the r3 behavior silently fell
-    back to per-step dispatch here."""
+    (make_array_from_process_local_data)."""
     store = rig
     job = TPUJob(
         metadata=ObjectMeta(name="lm-stream"),
@@ -588,7 +584,6 @@ def test_lm_training_streams_through_device_loader(rig):
         "batch_size": 4,
         "seq_len": 32,
         "data": "stream",
-        "device_loop": 2,
     }
     store.create(job)
     ok = wait_for(
@@ -742,7 +737,6 @@ def test_moe_expert_parallel_gang(rig):
         "steps": 3,
         "batch_size": 4,
         "seq_len": 32,
-        "device_loop": 2,  # K-steps-per-call through the operator path too
     }
     store.create(job)
     ok = wait_for(

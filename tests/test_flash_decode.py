@@ -318,6 +318,6 @@ def test_pagepool_alloc_free_leak():
     assert sorted(c) == sorted(a)
     pool.free(c)
     pool.free(b)
-    assert pool.free_count == 8  # the serve-bench leak invariant
+    assert pool.free_count == 8  # the leak invariant
     with pytest.raises(ValueError):
         pool.free([0])  # double free

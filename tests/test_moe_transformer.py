@@ -813,13 +813,13 @@ def test_pipeline_ep_in_stage_trains():
     assert all(np.isfinite(losses)) and losses[-1] < losses[0], losses
 
 
-def test_ragged_and_gmm_dispatch_match_sort_at_no_drop_capacity():
-    """dispatch_impl="ragged" and "gmm" (r5 — padding-free grouped
-    expert matmuls, no capacity) must equal the sort path when the sort
-    path's capacity is large enough that nothing drops: with no drops all
-    three compute out[t] = sum_k w_k * expert_k(x[t]). This is the
-    oracle pin BASELINE.md's r5 MoE row cites."""
-    from tf_operator_tpu.parallel.moe import moe_apply, ragged_swiglu
+def test_gmm_dispatch_matches_sort_at_no_drop_capacity():
+    """dispatch_impl="gmm" (r5 — padding-free grouped expert matmuls, no
+    capacity) must equal the sort path when the sort path's capacity is
+    large enough that nothing drops: with no drops both compute
+    out[t] = sum_k w_k * expert_k(x[t]). This is the oracle pin
+    BASELINE.md's r5 MoE row cites."""
+    from tf_operator_tpu.parallel.moe import moe_apply
 
     T, d, f, E = 64, 16, 32, 4
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
@@ -839,24 +839,22 @@ def test_ragged_and_gmm_dispatch_match_sort_at_no_drop_capacity():
             x, gl, ep, efn, None, capacity_factor=float(E), k_top=k_top,
             dropped="zero", dispatch_impl="sort",
         )
-        for impl in ("ragged", "gmm"):
-            out, stats = moe_apply(
-                x, gl, ep, efn, None, k_top=k_top, dispatch_impl=impl,
-                ragged_expert_fn=ragged_swiglu, return_stats=True,
-            )
-            np.testing.assert_allclose(out_sort, out, atol=1e-5,
-                                       err_msg=f"{impl} k={k_top}")
-            assert float(stats["drop_frac"]) == 0.0  # never drops
+        out, stats = moe_apply(
+            x, gl, ep, efn, None, k_top=k_top, dispatch_impl="gmm",
+            return_stats=True,
+        )
+        np.testing.assert_allclose(out_sort, out, atol=1e-5,
+                                   err_msg=f"k={k_top}")
+        assert float(stats["drop_frac"]) == 0.0  # never drops
 
-            g = jax.grad(lambda ew: jnp.sum(moe_apply(
-                x, gl, ew, efn, None, k_top=k_top, dispatch_impl=impl,
-                ragged_expert_fn=ragged_swiglu) ** 2))(ep)
-            g_sort = jax.grad(lambda ew: jnp.sum(moe_apply(
-                x, gl, ew, efn, None, capacity_factor=float(E), k_top=k_top,
-                dropped="zero", dispatch_impl="sort") ** 2))(ep)
-            for name in g:
-                np.testing.assert_allclose(g[name], g_sort[name], atol=1e-4,
-                                           err_msg=f"{impl} {name}")
+        g = jax.grad(lambda ew: jnp.sum(moe_apply(
+            x, gl, ew, efn, None, k_top=k_top, dispatch_impl="gmm") ** 2))(ep)
+        g_sort = jax.grad(lambda ew: jnp.sum(moe_apply(
+            x, gl, ew, efn, None, capacity_factor=float(E), k_top=k_top,
+            dropped="zero", dispatch_impl="sort") ** 2))(ep)
+        for name in g:
+            np.testing.assert_allclose(g[name], g_sort[name], atol=1e-4,
+                                       err_msg=name)
 
 
 def test_gmm_zero_token_expert_gets_zero_grad():
@@ -898,24 +896,21 @@ def test_gmm_rejects_non_swiglu_expert_params():
                   dispatch_impl="gmm")
 
 
-def test_ragged_dispatch_through_model_config():
-    """moe_dispatch="ragged" rides the workload-config surface and trains
-    (loss decreases, stats finite, drop_frac pinned 0)."""
+def test_unknown_moe_dispatch_raises():
+    """A removed or misspelt dispatch is refused, not run as something
+    else: through the layer and through the model config."""
     from tf_operator_tpu.models.transformer import lm_loss_and_metrics
+    from tf_operator_tpu.parallel.moe import moe_apply
 
-    cfg = preset("tiny-moe", moe_dispatch="ragged", moe_top_k=2)
+    with pytest.raises(ValueError, match="unknown dispatch_impl 'ragged'"):
+        moe_apply(jnp.zeros((8, 4)), jnp.zeros((8, 2)),
+                  {"w": jnp.zeros((2, 4, 4))}, lambda w, t: t, None,
+                  dispatch_impl="ragged")
+    cfg = preset("tiny-moe", moe_dispatch="ragged")
     params = init_transformer(jax.random.PRNGKey(0), cfg)
     tok = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab)
-    total, metrics = lm_loss_and_metrics(params, tok, cfg)
-    assert np.isfinite(float(total))
-    assert float(metrics["moe_drop_frac"]) == 0.0
-    # parity with the sort path at no-drop capacity
-    cfg_sort = preset("tiny-moe", capacity_factor=float(cfg.n_experts),
-                      moe_top_k=2)
-    total_sort, _ = lm_loss_and_metrics(params, tok, cfg_sort)
-    # bf16 activations: the two paths feed the experts through different
-    # intermediate layouts, so agreement is to bf16 rounding, not bitwise
-    np.testing.assert_allclose(float(total), float(total_sort), rtol=1e-3)
+    with pytest.raises(ValueError, match="unknown dispatch_impl 'ragged'"):
+        lm_loss_and_metrics(params, tok, cfg)
 
 
 # ---- ep-SHARDED gmm dispatch (r6 tentpole) --------------------------------
@@ -1135,34 +1130,3 @@ def test_ep_gmm_uneven_shard_loads_block_quantum_edge(monkeypatch):
             np.testing.assert_allclose(np.asarray(g1[name]),
                                        np.asarray(g2[name]), rtol=5e-5,
                                        atol=5e-5, err_msg=name)
-
-
-def test_ragged_still_falls_back_under_ep_with_warning(caplog):
-    """ragged keeps the documented capacity fallback (no steering map to
-    skip unoccupied blocks) — and says so at runtime; gmm must NOT warn."""
-    import logging
-
-    from tf_operator_tpu.parallel.moe import moe_apply, ragged_swiglu
-
-    T, d, f, E = 32, 8, 16, 4
-    ks = jax.random.split(jax.random.PRNGKey(0), 5)
-    x = jax.random.normal(ks[0], (T, d), jnp.float32)
-    gl = jax.random.normal(ks[1], (T, E), jnp.float32)
-    wp = {
-        "w_gate": jax.random.normal(ks[2], (E, d, f)) * 0.1,
-        "w_up": jax.random.normal(ks[3], (E, d, f)) * 0.1,
-        "w_down": jax.random.normal(ks[4], (E, f, d)) * 0.1,
-    }
-
-    def efn(w, t):
-        return (jax.nn.silu(t @ w["w_gate"]) * (t @ w["w_up"])) @ w["w_down"]
-
-    mesh = build_mesh({"ep": 4}, devices=jax.devices()[:4])
-    with caplog.at_level(logging.WARNING, logger="tpujob.moe"):
-        moe_apply(x, gl, wp, efn, mesh, dispatch_impl="ragged",
-                  ragged_expert_fn=ragged_swiglu)
-    assert any("falling back" in r.message for r in caplog.records)
-    caplog.clear()
-    with caplog.at_level(logging.WARNING, logger="tpujob.moe"):
-        moe_apply(x, gl, wp, efn, mesh, dispatch_impl="gmm")
-    assert not any("falling back" in r.message for r in caplog.records)

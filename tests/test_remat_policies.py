@@ -4,9 +4,7 @@ The policy names must (a) parse, (b) actually mark the intended values
 saveable (checked through jax.ad_checkpoint.saved_residuals — the same
 introspection print_saved_residuals uses), and (c) be semantically
 IDENTITY: a names policy changes what is stored vs recomputed, never the
-math. The FLOP-retirement receipts live in tools/rematsweep --flops
-(compiled-executable cost analysis on the real chip); these tests pin the
-machinery itself on the CPU backend.
+math. These tests pin the machinery itself on the CPU backend.
 """
 
 import jax

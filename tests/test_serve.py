@@ -118,17 +118,6 @@ def test_engine_completes_all_requests_without_leaks(tiny_engine):
         assert 0 <= r.arrival <= r.admitted <= r.first_token <= r.finished
 
 
-@pytest.mark.serve
-def test_engine_static_mode_also_completes(tiny_engine):
-    res = tiny_engine.run(_requests(), mode="static", clock=_fake_clock())
-    assert res.completed == len(res.requests)
-    assert res.free_pages_start == res.free_pages_end
-    # drain-the-batch takes strictly more steps than requests' max budget:
-    # late arrivals wait out whole generations
-    cont = tiny_engine.run(_requests(), clock=_fake_clock())
-    assert res.steps > cont.steps
-
-
 HLO_POOL = "f32[2,9,2,8,16]{4,3,2,1,0}"
 HLO_LAYER = "f32[9,2,8,16]{3,2,1,0}"
 HLO_CASES = {
@@ -377,8 +366,8 @@ def test_explicit_priority_class_beats_serving_default():
 def test_parse_override_coerces_types():
     assert _parse_override("kv_page_size=8") == ("kv_page_size", 8)
     assert _parse_override("arrival_rate=2.5") == ("arrival_rate", 2.5)
-    assert _parse_override("reserve_full=false") == ("reserve_full", False)
-    assert _parse_override("mode=static") == ("mode", "static")
+    assert _parse_override("router_f32=false") == ("router_f32", False)
+    assert _parse_override("preset=tiny") == ("preset", "tiny")
     with pytest.raises(ValueError):
         _parse_override("no-equals-sign")
 

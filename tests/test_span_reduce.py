@@ -4,7 +4,6 @@ chip before the program had spans, and the new per-layer readers through the
 harness at ``tiny``."""
 
 import gzip
-import importlib.util
 import json
 import os
 import shutil
@@ -13,6 +12,7 @@ from types import SimpleNamespace as NS
 import pytest
 
 from benchmarks import run, span_reduce as sr, trace_reduce as tr
+from conftest import benchmarks_conftest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RECORDED = os.path.join(REPO, "benchmarks", "testdata", "tiny_train.xplane.pb.gz")
@@ -224,15 +224,6 @@ def test_recorded_trace_of_a_program_without_spans(recorded_home):
         (49_326 + 49_552 + 50_058 - 124_254) * 1e-9, rel=1e-3)
 
 
-def _load_benchmarks_conftest():
-    spec = importlib.util.spec_from_file_location(
-        "benchmarks_tests_conftest",
-        os.path.join(REPO, "benchmarks", "tests", "conftest.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def _new_metrics():
     """The per-layer entries whose reader imports span_reduce."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
@@ -245,15 +236,8 @@ def _new_metrics():
 
 @pytest.fixture(scope="module")
 def tiny_root(tmp_path_factory):
-    conf = _load_benchmarks_conftest()
-    # make_tiny_root knows the tiny form of the mixes of two runners; a mix
-    # of a runner that extends one of them (``train_smallthinker``, PR 26)
-    # is cut as its family's are — no cell of such a mix is loaded here
-    for name in os.listdir(os.path.join(REPO, "benchmarks", "traffic")):
-        with open(os.path.join(REPO, "benchmarks", "traffic", name)) as f:
-            runner = json.load(f)["runner"]
-        conf.TINY_MIXES.setdefault(runner, conf.TINY_MIXES[runner.split("_")[0]])
-    return conf.make_tiny_root(tmp_path_factory.mktemp("bench") / "root")
+    return benchmarks_conftest().make_tiny_root(
+        tmp_path_factory.mktemp("bench") / "root")
 
 
 def test_this_pr_added_fifteen_entries():

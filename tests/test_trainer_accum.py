@@ -106,18 +106,6 @@ def test_accum_threads_extra_state():
     assert float(state.extra["count"]) == 4.0
 
 
-def test_accum_composes_with_device_loop():
-    mesh = build_mesh({"dp": 1}, devices=jax.devices()[:1])
-    full = _make_trainer(mesh, 1)
-    acc = _make_trainer(mesh, 2)
-    batch = _batch(jax.random.PRNGKey(0))
-    s_full, m_full = full.multi_step(full.init(jax.random.PRNGKey(0)), batch, 3)
-    s_acc, m_acc = acc.multi_step(acc.init(jax.random.PRNGKey(0)), batch, 3)
-    np.testing.assert_allclose(
-        np.asarray(m_acc["losses"]), np.asarray(m_full["losses"]), rtol=1e-5
-    )
-
-
 def test_indivisible_batch_rejected():
     mesh = build_mesh({"dp": 1}, devices=jax.devices()[:1])
     acc = _make_trainer(mesh, 3)

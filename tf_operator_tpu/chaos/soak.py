@@ -551,9 +551,6 @@ def _soak_job(
             "seq_len": 32,
             "checkpoint_dir": ckpt_dir,
             "checkpoint_every": checkpoint_every,
-            # chaos needs exact-step semantics; the device loop fires
-            # callbacks per chunk (see WorkloadCheckpointer.run_loop)
-            "device_loop": 1,
         }
     else:
         entrypoint = "tf_operator_tpu.workloads.soak:main"

@@ -55,31 +55,8 @@ class ResNetConfig:
     # DEFAULT "var" since r3: accuracy-validated on REAL data through the
     # idx/augmentation pipeline — 3-seed test accuracy 0.9764 vs exact's
     # 0.9787 on real scanned digits, overlapping seed ranges (BASELINE.md
-    # "BN decomposition"); BENCH_BN_STATS_GRAD=exact restores exact BN.
+    # "BN decomposition").
     bn_stats_stop_gradient: Any = "var"
-    # Ghost batch statistics: train-mode normalization uses the PREVIOUS
-    # step's batch stats (carried in state) while this step's stats are
-    # computed only to ship forward — the normalize affine becomes a step
-    # constant that fuses into the conv epilogue and the stats reduction
-    # leaves the critical path (the 10.8 ms barrier, BASELINE.md).
-    # DOCUMENTED NEGATIVE RESULT (r3): stale-stats normalization composed
-    # through depth is a divergent fixed-point iteration — even at FIXED
-    # params and input, layer k's stats describe the previous pass's
-    # (different) input distribution, the scale mismatch multiplies
-    # through layers/residuals, and activations blow up within ~3 steps
-    # (tests/test_models.py::test_bn_ghost_stats_is_divergent_documented;
-    # a variance floor does not save it). Kept for the receipt; do not
-    # enable for training.
-    bn_ghost_stats: bool = False
-    # Run the bottleneck 1x1 convolutions (conv1/conv3/proj — ~83% of the
-    # BN'd activations) through the Pallas fused matmul+stats kernel
-    # (ops/fused_linear_stats): BN batch statistics accumulate in the
-    # matmul epilogue while the output block is in VMEM, and the previous
-    # BN's normalize+ReLU folds into the next kernel's load prologue — the
-    # batch-stats HBM barrier (measured 10.8 ms of a 51.4 ms v5e train
-    # step) never exists for those layers. Train-mode only; eval uses the
-    # folded-affine path either way.
-    fused_1x1: bool = False
 
     @staticmethod
     def resnet50(num_classes: int = 1000) -> "ResNetConfig":
@@ -118,24 +95,17 @@ def _bn_params(c):
     return {"scale": jnp.ones((c,), jnp.float32), "bias": jnp.zeros((c,), jnp.float32)}
 
 
-def _bn_state(c, ghost: bool = False):
-    s = {"mean": jnp.zeros((c,), jnp.float32), "var": jnp.ones((c,), jnp.float32)}
-    if ghost:
-        # last BATCH's stats (not the running average) — what ghost-stats
-        # normalization reads next step; init = identity-ish normalize.
-        s["bmean"] = jnp.zeros((c,), jnp.float32)
-        s["bvar"] = jnp.ones((c,), jnp.float32)
-    return s
+def _bn_state(c):
+    return {"mean": jnp.zeros((c,), jnp.float32), "var": jnp.ones((c,), jnp.float32)}
 
 
 def init_resnet(key, cfg: ResNetConfig) -> Tuple[Dict, Dict]:
     """Returns (params, state) — state carries BN running statistics."""
     keys = iter(jax.random.split(key, 256))
-    ghost = cfg.bn_ghost_stats
     params: Dict[str, Any] = {
         "stem": {"conv": _conv_init(next(keys), 7, 7, 3, 64), "bn": _bn_params(64)}
     }
-    state: Dict[str, Any] = {"stem": _bn_state(64, ghost)}
+    state: Dict[str, Any] = {"stem": _bn_state(64)}
     cin = 64
     for si, (n_blocks, width) in enumerate(zip(cfg.stage_sizes, cfg.widths)):
         stage_p: List[Dict] = []
@@ -152,14 +122,14 @@ def init_resnet(key, cfg: ResNetConfig) -> Tuple[Dict, Dict]:
                 "bn3": _bn_params(cout),
             }
             bs = {
-                "bn1": _bn_state(width, ghost),
-                "bn2": _bn_state(width, ghost),
-                "bn3": _bn_state(cout, ghost),
+                "bn1": _bn_state(width),
+                "bn2": _bn_state(width),
+                "bn3": _bn_state(cout),
             }
             if stride != 1 or cin != cout:
                 bp["proj"] = _conv_init(next(keys), 1, 1, cin, cout)
                 bp["proj_bn"] = _bn_params(cout)
-                bs["proj_bn"] = _bn_state(cout, ghost)
+                bs["proj_bn"] = _bn_state(cout)
             stage_p.append(bp)
             stage_s.append(bs)
             cin = cout
@@ -179,7 +149,7 @@ def resnet_logical_axes(params) -> Dict:
 
 
 def _batch_norm(x, p, s, train: bool, in_act_dtype: bool = True, fused_stats: bool = True,
-                stats_stop_gradient: bool = False, ghost: bool = False):
+                stats_stop_gradient: bool = False):
     """x: [b,h,w,c] activations (any float dtype). Stats in f32.
     Returns (y, new_state).
 
@@ -191,38 +161,7 @@ def _batch_norm(x, p, s, train: bool, in_act_dtype: bool = True, fused_stats: bo
     E[x] and E[x²] computed in one fused read of x (f32 accumulation);
     autodiff of this form also yields the minimal backward (sum(dy),
     sum(dy·x) reductions + one elementwise pass) — the structure a
-    hand-written BN VJP would produce.
-
-    With ``ghost`` (cfg.bn_ghost_stats) train-mode NORMALIZES with the
-    PREVIOUS batch's statistics (s["bmean"]/s["bvar"], carried state) while
-    computing this batch's stats only to ship forward. That breaks the
-    reduce→normalize serialization on the conv output — the affine's
-    (a, b) are step constants, so XLA can fuse the normalize into the conv
-    epilogue, and the stats reduction becomes an independent consumer off
-    the critical path (the 10.8 ms v5e barrier, BASELINE.md). Semantics:
-    one-step-stale statistics, no gradient through them (they're state) —
-    accuracy must be validated per recipe (the real-data e2e path)."""
-    if train and ghost:
-        if fused_stats:
-            bmean = jnp.mean(x, axis=(0, 1, 2), dtype=jnp.float32)
-            m2 = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=(0, 1, 2))
-            bvar = jnp.maximum(m2 - jnp.square(bmean), 0.0)
-        else:
-            xf = x.astype(jnp.float32)
-            bmean = jnp.mean(xf, axis=(0, 1, 2))
-            bvar = jnp.var(xf, axis=(0, 1, 2))
-        new_s = {
-            "mean": BN_MOMENTUM * s["mean"] + (1 - BN_MOMENTUM) * bmean,
-            "var": BN_MOMENTUM * s["var"] + (1 - BN_MOMENTUM) * bvar,
-            "bmean": bmean,
-            "bvar": bvar,
-        }
-        mean, var = s["bmean"], s["bvar"]  # previous step's batch stats
-        a = jax.lax.rsqrt(var + BN_EPS) * p["scale"]
-        b = p["bias"] - mean * a
-        if in_act_dtype:
-            return x * a.astype(x.dtype) + b.astype(x.dtype), new_s
-        return (x.astype(jnp.float32) * a + b).astype(x.dtype), new_s
+    hand-written BN VJP would produce."""
     if train:
         if fused_stats:
             mean = jnp.mean(x, axis=(0, 1, 2), dtype=jnp.float32)
@@ -293,117 +232,23 @@ def _stem_s2d(x, w7):
     )
 
 
-def _bottleneck(x, bp, bs, stride, train, bn_act, bn_fused, bn_sg=False, bn_ghost=False):
-    y, s1 = _batch_norm(_conv(x, bp["conv1"]), bp["bn1"], bs["bn1"], train, bn_act, bn_fused, bn_sg, bn_ghost)
+def _bottleneck(x, bp, bs, stride, train, bn_act, bn_fused, bn_sg=False):
+    y, s1 = _batch_norm(_conv(x, bp["conv1"]), bp["bn1"], bs["bn1"], train, bn_act, bn_fused, bn_sg)
     y = jax.nn.relu(y)
     y, s2 = _batch_norm(
-        _conv(y, bp["conv2"], stride), bp["bn2"], bs["bn2"], train, bn_act, bn_fused, bn_sg, bn_ghost
+        _conv(y, bp["conv2"], stride), bp["bn2"], bs["bn2"], train, bn_act, bn_fused, bn_sg
     )
     y = jax.nn.relu(y)
-    y, s3 = _batch_norm(_conv(y, bp["conv3"]), bp["bn3"], bs["bn3"], train, bn_act, bn_fused, bn_sg, bn_ghost)
+    y, s3 = _batch_norm(_conv(y, bp["conv3"]), bp["bn3"], bs["bn3"], train, bn_act, bn_fused, bn_sg)
     new_bs = {"bn1": s1, "bn2": s2, "bn3": s3}
     if "proj" in bp:
         shortcut, sp = _batch_norm(
-            _conv(x, bp["proj"], stride), bp["proj_bn"], bs["proj_bn"], train, bn_act, bn_fused, bn_sg, bn_ghost
+            _conv(x, bp["proj"], stride), bp["proj_bn"], bs["proj_bn"], train, bn_act, bn_fused, bn_sg
         )
         new_bs["proj_bn"] = sp
     else:
         shortcut = x
     return jax.nn.relu(y + shortcut), new_bs
-
-
-def _bn_affine(p, mean, var):
-    """Folded BN affine from given statistics: y*a + b == normalize."""
-    a = jax.lax.rsqrt(var + BN_EPS) * p["scale"]
-    b = p["bias"] - mean * a
-    return a, b
-
-
-def _bn_update(s, mean, var):
-    return {
-        "mean": BN_MOMENTUM * s["mean"] + (1 - BN_MOMENTUM) * mean,
-        "var": BN_MOMENTUM * s["var"] + (1 - BN_MOMENTUM) * var,
-    }
-
-
-def _bottleneck_fused(x, bp, bs, stride, bn_act, bn_fused=True, bn_sg=False):
-    """Train-mode bottleneck with the 1x1 convs through the Pallas fused
-    matmul+stats kernel (see ResNetConfig.fused_1x1). Same math as
-    _bottleneck with bn_fused_stats (E[x]/E[x²] in f32 — the kernel's
-    epilogue computes exactly that form, so ``bn_fused`` only steers the
-    XLA-path BN2): parity is pinned by tests/test_fused_linear_stats.py.
-    Only the 3x3 conv and its BN stay on the XLA path (17% of the
-    activations). ``bn_sg`` (cfg.bn_stats_stop_gradient) applies to the
-    kernel-derived statistics too."""
-    from tf_operator_tpu.ops.fused_linear_stats import fused_linear_stats
-
-    b, h, w, cin = x.shape
-    flat = x.reshape(b * h * w, cin)
-
-    def stats(s, q, rows):
-        mean = s / rows
-        var = jnp.maximum(q / rows - jnp.square(mean), 0.0)
-        if bn_sg:
-            # same semantics as _batch_norm: "var" keeps the centering
-            # (mean) gradient and stops only the variance path
-            if bn_sg != "var":
-                mean = jax.lax.stop_gradient(mean)
-            var = jax.lax.stop_gradient(var)
-        return mean, var
-
-    # conv1 (1x1): stats in the matmul epilogue
-    y1, s1, q1 = fused_linear_stats(flat, bp["conv1"][0, 0].astype(x.dtype))
-    mean1, var1 = stats(s1, q1, float(flat.shape[0]))
-    a1, b1 = _bn_affine(bp["bn1"], mean1, var1)
-
-    # conv2 (3x3, XLA): the previous normalize+relu is ONE elementwise op
-    # that XLA fuses into the conv input; BN2 takes the existing path.
-    y1n = jax.nn.relu(
-        y1.reshape(b, h, w, -1) * a1.astype(x.dtype) + b1.astype(x.dtype)
-        if bn_act
-        else (y1.reshape(b, h, w, -1).astype(jnp.float32) * a1 + b1).astype(x.dtype)
-    )
-    y2 = _conv(y1n, bp["conv2"], stride)
-    y2n, s2 = _batch_norm(y2, bp["bn2"], bs["bn2"], True, bn_act, bn_fused, bn_sg)
-    y2n = jax.nn.relu(y2n)
-
-    # conv3 (1x1): plain input (y2n already normalized by XLA BN2)
-    oh, ow = y2n.shape[1], y2n.shape[2]
-    y3, s3, q3 = fused_linear_stats(
-        y2n.reshape(b * oh * ow, -1), bp["conv3"][0, 0].astype(x.dtype)
-    )
-    mean3, var3 = stats(s3, q3, float(b * oh * ow))
-    a3, b3 = _bn_affine(bp["bn3"], mean3, var3)
-    y3 = y3.reshape(b, oh, ow, -1)
-
-    new_bs = {
-        "bn1": _bn_update(bs["bn1"], mean1, var1),
-        "bn2": s2,
-        "bn3": _bn_update(bs["bn3"], mean3, var3),
-    }
-
-    if "proj" in bp:
-        xs = x[:, ::stride, ::stride, :] if stride != 1 else x
-        yp, sp, qp = fused_linear_stats(
-            xs.reshape(b * oh * ow, cin), bp["proj"][0, 0].astype(x.dtype)
-        )
-        meanp, varp = stats(sp, qp, float(b * oh * ow))
-        ap, bpb = _bn_affine(bp["proj_bn"], meanp, varp)
-        yp = yp.reshape(b, oh, ow, -1)
-        shortcut = (
-            yp * ap.astype(x.dtype) + bpb.astype(x.dtype)
-            if bn_act
-            else (yp.astype(jnp.float32) * ap + bpb).astype(x.dtype)
-        )
-        new_bs["proj_bn"] = _bn_update(bs["proj_bn"], meanp, varp)
-    else:
-        shortcut = x
-    y3n = (
-        y3 * a3.astype(x.dtype) + b3.astype(x.dtype)
-        if bn_act
-        else (y3.astype(jnp.float32) * a3 + b3).astype(x.dtype)
-    )
-    return jax.nn.relu(y3n + shortcut), new_bs
 
 
 def resnet_forward(params, state, images, cfg: ResNetConfig, train: bool = True):
@@ -418,33 +263,22 @@ def resnet_forward(params, state, images, cfg: ResNetConfig, train: bool = True)
     else:
         x = _conv(x, params["stem"]["conv"], stride=2)
     bn_sg = cfg.bn_stats_stop_gradient
-    bn_ghost = cfg.bn_ghost_stats
-    if bn_ghost and cfg.fused_1x1:
-        raise ValueError("bn_ghost_stats does not compose with fused_1x1")
     x, stem_s = _batch_norm(
-        x, params["stem"]["bn"], state["stem"], train, bn_act, bn_fused, bn_sg,
-        bn_ghost,
+        x, params["stem"]["bn"], state["stem"], train, bn_act, bn_fused, bn_sg
     )
     x = jax.nn.relu(x)
     x = jax.lax.reduce_window(
         x, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1), "SAME"
     )
     new_state: Dict[str, Any] = {"stem": stem_s}
-    fused_1x1 = cfg.fused_1x1 and train  # eval folds running stats anyway
     for si, n_blocks in enumerate(cfg.stage_sizes):
         stage_s = []
         for bi in range(n_blocks):
             stride = 2 if (si > 0 and bi == 0) else 1
-            if fused_1x1:
-                x, bs = _bottleneck_fused(
-                    x, params[f"stage{si}"][bi], state[f"stage{si}"][bi],
-                    stride, bn_act, bn_fused, bn_sg,
-                )
-            else:
-                x, bs = _bottleneck(
-                    x, params[f"stage{si}"][bi], state[f"stage{si}"][bi], stride,
-                    train, bn_act, bn_fused, bn_sg, bn_ghost,
-                )
+            x, bs = _bottleneck(
+                x, params[f"stage{si}"][bi], state[f"stage{si}"][bi], stride,
+                train, bn_act, bn_fused, bn_sg,
+            )
             stage_s.append(bs)
         new_state[f"stage{si}"] = stage_s
     x = jnp.mean(x.astype(jnp.float32), axis=(1, 2))

@@ -64,14 +64,11 @@ class TransformerConfig:
     capacity_factor: float = 2.0
     ep_axis: str = "ep"
     # Expert dispatch: "sort" (capacity queues + scatter/gather, the ep
-    # all_to_all layout), "einsum" (one-hot oracle), "ragged" (r5 —
-    # lax.ragged_dot over actual per-expert counts; measured SLOWER than
-    # the padded vmap on v5e — kept as the negative-result receipt), or
-    # "gmm" (r5/r6 — the Pallas grouped-matmul kernel: block-granular
-    # padding only, no drops; ops/grouped_matmul.py). r6: gmm runs under
-    # ep sharding too (count-exchange + block-quantum all_to_all
-    # buffers, parallel.moe._moe_local_gmm) including ep-inside-pipeline;
-    # only "ragged" still falls back to sort under ep.
+    # all_to_all layout), "einsum" (one-hot oracle), or "gmm" (r5/r6 —
+    # the Pallas grouped-matmul kernel: block-granular padding only, no
+    # drops; ops/grouped_matmul.py). r6: gmm runs under ep sharding too
+    # (count-exchange + block-quantum all_to_all buffers,
+    # parallel.moe._moe_local_gmm) including ep-inside-pipeline.
     moe_dispatch: str = "sort"
     # Router auxiliary losses — without them top-k routing collapses onto a
     # few experts under real training. moe_aux_weight scales the Switch
@@ -210,8 +207,7 @@ PRESETS: Dict[str, TransformerConfig] = {
     # Mixtral-class sparse config (8 experts, top-1 routing): total params
     # ~8x the dense MLP stack, active params per token ~ the dense model.
     # r6: the grouped-matmul dispatch is the default (it beat the r4
-    # capacity path at zero drops in the r5 capture; BENCH_MOE_DISPATCH
-    # still overrides for A/Bs against sort/ragged).
+    # capacity path at zero drops in the r5 capture).
     "moe-small": TransformerConfig(
         vocab=32000, d_model=768, n_layers=12, n_heads=12, n_kv_heads=12, d_ff=3072,
         max_seq=1024, n_experts=8, moe_dispatch="gmm",
@@ -682,8 +678,8 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
         # same capacity rule as moe_apply's sharded branch: flat is
         # already the per-shard token slice. dispatch follows
         # cfg.moe_dispatch with moe_apply's ladder semantics: gmm runs
-        # padding-free in-stage (r6); ragged/einsum degrade to sort (the
-        # einsum inbox layout is identical, sort is the cheap form).
+        # padding-free in-stage (r6); einsum degrades to sort (the inbox
+        # layout is identical, sort is the cheap form).
         import os
 
         local_impl = "gmm" if cfg.moe_dispatch == "gmm" else "sort"
@@ -699,8 +695,6 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
             expert_act=cfg.expert_act,
         )
     else:
-        from tf_operator_tpu.parallel.moe import ragged_swiglu
-
         out, stats = moe_apply(
             flat,
             gate_logits,
@@ -715,7 +709,6 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
             k_top=cfg.moe_top_k,
             return_stats=True,
             dispatch_impl=cfg.moe_dispatch,
-            ragged_expert_fn=partial(ragged_swiglu, act=act),
             expert_act=cfg.expert_act,
             expert_first=cfg.expert_first,
         )

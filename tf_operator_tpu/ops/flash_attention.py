@@ -34,7 +34,7 @@ either d % 128 == 0 (any length) or d % 64 == 0 with t >= 2048 — the
 measured END-TO-END crossover for hd=64 models (gpt-small/bert-base):
 in-model the kernel wins 1.49x at t=2048 but loses to dense at t=512
 under full remat, even though the isolated attention probe favors it at
-every length (`tools/roofline --mode attn --d 64`; BASELINE.md). Off-TPU
+every length (an earlier installation's reading; BASELINE.md). Off-TPU
 the entry falls back to a jnp reference (same math, same f32 softmax) so
 one model config runs everywhere; ``interpret=True`` forces the Pallas
 interpreter — the CPU test path for the kernel logic.
@@ -633,7 +633,7 @@ def _dispatch(q, k, v, block_q, block_k, interpret, force_kernel):
         # The d % 128 lane HEURISTIC is deliberately overridden: the kernel
         # is correct at any d (Mosaic pads the lane dim) — d % 128 is a
         # performance gate, and measuring shapes on the other side of it
-        # is exactly what this hook is for (tools/roofline --mode attn).
+        # is exactly what this hook is for.
         use = force_kernel and not (
             t % block_q or t % block_k or block_q % 8 or block_k % 8
         ) and (bool(interpret) or jax.default_backend() == "tpu")
@@ -675,8 +675,8 @@ def flash_attention(
     1024 (k) — measured optimum on v5e. ``interpret=True`` forces the
     kernel through the Pallas interpreter — the CPU test path for kernel
     logic. ``force_kernel`` overrides the dispatch heuristic both ways
-    (tiling constraints still apply) — the measurement hook behind the
-    tools/roofline --mode attn crossover table.
+    (tiling constraints still apply) — the hook for measuring a shape on
+    the other side of the heuristic.
 
     An EXPLICIT ``block_q``/``block_k`` is a TARGET, not a verbatim
     config: block_q is clamped to 1024//g rows (VMEM bound for folded

@@ -172,59 +172,6 @@ def expert_activation(name: str):
     raise ValueError(f"unknown expert activation {name!r} (silu | relu)")
 
 
-def ragged_swiglu(expert_params, x_sorted, group_sizes, act=jax.nn.silu):
-    """SwiGLU over expert-sorted rows via ``jax.lax.ragged_dot`` — the
-    grouped (Megablocks-style) expert matmul. expert_params leaves are
-    stacked [E, ...]; x_sorted rows are grouped by expert with
-    ``group_sizes`` [E] actual counts (no capacity, no padding rows).
-    Measured on v5e: ragged_dot sustains the chip's chained-matmul rate
-    exactly (55.2 vs 55.2 TFLOP/s at moe-small shapes, r5), so the cf
-    multiplier on expert FLOPs disappears rather than being traded for a
-    slower kernel."""
-    zg = jax.lax.ragged_dot(x_sorted, expert_params["w_gate"], group_sizes)
-    zu = jax.lax.ragged_dot(x_sorted, expert_params["w_up"], group_sizes)
-    return jax.lax.ragged_dot(
-        act(zg) * zu, expert_params["w_down"], group_sizes
-    )
-
-
-def _moe_single_ragged(x, gate_logits, expert_params, ragged_expert_fn,
-                       k_top: int = 1):
-    """Padding-free single-device MoE (r5, VERDICT r4 #2): sort the T·k
-    token-choices by expert (a gather, not the scatter-add inbox), run
-    the experts as ONE grouped matmul over the actual per-expert counts
-    (ragged_swiglu / ragged_dot), and gather-combine. Removes BOTH
-    structural terms the r4 decomposition named: the capacity padding
-    (cf x the active FLOPs — there is no capacity here) and the
-    scatter-add dispatch (the inbox build was ~4x pure-bandwidth; a
-    row gather is the cheap direction on TPU). No tokens drop, ever —
-    drop_frac is identically 0, which also retires the cf-vs-quality
-    trade the capacity path had to make."""
-    tokens, d = x.shape
-    n_experts = gate_logits.shape[-1]
-    gate_probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    top_p, top_i = jax.lax.top_k(gate_probs, k_top)  # [T, k]
-    if k_top > 1:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-
-    flat_e = top_i.reshape(-1).astype(jnp.int32)  # [T*k], t-major
-    order = jnp.argsort(flat_e, stable=True)      # sorted-by-expert choice ids
-    counts = jnp.bincount(flat_e, length=n_experts).astype(jnp.int32)
-    x_sorted = x[(order // k_top)]                # [T*k, d] gather
-    h = ragged_expert_fn(expert_params, x_sorted, counts)  # [T*k, d]
-    inv = jnp.argsort(order)                      # choice j -> its sorted row
-    gathered = h[inv.reshape(tokens, k_top)]      # [T, k, d]
-    out = jnp.einsum(
-        "tk,tkd->td", top_p, gathered.astype(jnp.float32)
-    )
-    stats = {
-        "expert_load": counts.astype(jnp.float32) / (tokens * k_top),
-        "mean_gate": jnp.mean(gate_probs, axis=0),
-        "drop_frac": jnp.float32(0.0),
-    }
-    return out.astype(x.dtype), stats
-
-
 def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
                     block_rows: int = 256, act=jax.nn.silu, first: int = 0):
     """Padding-free single-device MoE over the Pallas grouped-matmul
@@ -480,8 +427,7 @@ def _dropped_value(x, dropped: str):
 
 def _moe_single(x, gate_logits, expert_params, expert_fn, capacity: int, dropped: str,
                 k_top: int = 1, dispatch_impl: str = "sort",
-                ragged_expert_fn=None, expert_act: str = "silu",
-                expert_first: int = 0):
+                expert_act: str = "silu", expert_first: int = 0):
     """All experts on one device: same routing math, no collectives — the
     fallback when the mesh has no ep axis (or no mesh at all).
 
@@ -517,15 +463,6 @@ def _moe_single(x, gate_logits, expert_params, expert_fn, capacity: int, dropped
             "a share of the experts (fewer expert weights than router "
             "outputs) runs on dispatch_impl='gmm' only"
         )
-    if dispatch_impl == "ragged":
-        if ragged_expert_fn is None:
-            raise ValueError(
-                "dispatch_impl='ragged' needs a ragged_expert_fn "
-                "(e.g. parallel.moe.ragged_swiglu)"
-            )
-        return _moe_single_ragged(
-            x, gate_logits, expert_params, ragged_expert_fn, k_top
-        )
     if dispatch_impl == "sort":
         slot, w, keep_any, inbox, stats = _route_sparse(
             x, gate_logits, capacity, k_top, dropped)
@@ -536,10 +473,10 @@ def _moe_single(x, gate_logits, expert_params, expert_fn, capacity: int, dropped
     # vmap over the stacked expert dim — ONE batched-matmul program for
     # all experts. r4: the previous fori_loop ran E sequential [C,d]
     # matmul chains with a dynamic-slice parameter gather and an
-    # acc.at[e].set copy per step; at bench shapes the identical FLOPs
-    # measured 15.1 ms looped vs 8.1 ms batched (tools/roofline --mode
-    # moe), and the batched form runs at 87% of the chip's chained
-    # matmul rate.
+    # acc.at[e].set copy per step; at moe-small shapes the identical
+    # FLOPs measured 15.1 ms looped vs 8.1 ms batched (an earlier
+    # installation's reading), and the batched form runs at 87% of the
+    # chip's chained matmul rate.
     outbox = jax.vmap(
         lambda w_e, t: expert_fn(w_e, t.astype(x.dtype))
     )(expert_params, inbox).astype(jnp.float32)
@@ -642,7 +579,6 @@ def moe_apply(
     k_top: int = 1,
     return_stats: bool = False,
     dispatch_impl: str = "sort",
-    ragged_expert_fn=None,
     expert_act: str = "silu",
     expert_first: int = 0,
 ):
@@ -679,17 +615,13 @@ def moe_apply(
     no drops, padding only to the kernel's row-block quantum; r6 runs it
     under ep sharding too via count-exchange + block-quantum all_to_all
     buffers, _moe_local_gmm — the flagship layouts no longer degrade to
-    capacity queues), or "ragged" (r5 — grouped ragged_dot over actual
-    per-expert counts via ``ragged_expert_fn``; single-device/no-ep path
-    only: its XLA lowering has no steering map to skip unoccupied
-    blocks, so the sharded path falls back to "sort" with a runtime
-    warning). Same queue semantics for sort/einsum, same drop patterns,
-    same stats (pinned by the impl-parity tests); the end-to-end win is
-    recorded in BASELINE.md.
+    capacity queues). Same queue semantics for sort/einsum, same drop
+    patterns, same stats (pinned by the impl-parity tests); the
+    end-to-end win is recorded in BASELINE.md.
 
     ``expert_act`` ("silu" | "relu") is the gate activation the gmm
-    dispatch applies (the other dispatches call ``expert_fn`` /
-    ``ragged_expert_fn``, which carry their own). ONE CHIP'S SHARE:
+    dispatch applies (the other dispatches call ``expert_fn``, which
+    carries its own). ONE CHIP'S SHARE:
     ``expert_params`` may hold fewer experts than ``gate_logits`` has
     outputs — experts ``expert_first ..`` of a layer whose other experts
     live on absent chips. The router still scores all of them and the
@@ -697,7 +629,7 @@ def moe_apply(
     dispatch, no ep axis."""
     from tf_operator_tpu.parallel.collectives import shard_map
 
-    if dispatch_impl not in ("sort", "einsum", "ragged", "gmm"):
+    if dispatch_impl not in ("sort", "einsum", "gmm"):
         raise ValueError(f"unknown dispatch_impl {dispatch_impl!r}")
     n_experts = gate_logits.shape[-1]
     tokens = x.shape[0]
@@ -707,7 +639,7 @@ def moe_apply(
         capacity = expert_capacity(capacity_factor, k_top, tokens, n_experts)
         out, stats = _moe_single(
             x, gate_logits, expert_params, expert_fn, capacity, dropped, k_top,
-            dispatch_impl, ragged_expert_fn, expert_act, expert_first,
+            dispatch_impl, expert_act, expert_first,
         )
         return (out, stats) if return_stats else out
     if jax.tree_util.tree_leaves(expert_params)[0].shape[0] != n_experts:
@@ -715,23 +647,6 @@ def moe_apply(
             "a share of the experts runs on one chip with no exchange; "
             f"the mesh has {axis_name}={mesh.shape[axis_name]}"
         )
-    if dispatch_impl == "ragged":
-        # ragged_dot has no block steering to skip unoccupied regions of
-        # a statically-sized a2a buffer, so under ep it would pay the
-        # worst-case FLOPs — the sharded path keeps the sort dispatch.
-        # Logged, not just documented: the caller opted into the
-        # zero-drop path and is getting capacity drops instead — that
-        # change must be visible at runtime. (The gmm impl no longer
-        # falls back: r6 runs it ep-sharded via _moe_local_gmm.)
-        import logging
-
-        logging.getLogger("tpujob.moe").warning(
-            "dispatch_impl='ragged' needs static per-expert shapes under "
-            "ep sharding; falling back to 'sort' (capacity queues, drops "
-            "possible) — use dispatch_impl='gmm' for the padding-free "
-            "ep path",
-        )
-        dispatch_impl = "sort"
     if dispatch_impl == "gmm" and (
         not isinstance(expert_params, dict)
         or set(expert_params) != {"w_gate", "w_up", "w_down"}

@@ -7,9 +7,7 @@ slots, pushes one prefill chunk for each still-prefilling slot, runs one
 batched decode step for every decoding slot, and evicts finished
 sequences immediately (pages back to the free list the same step — the
 next admission reuses them copy-free). There is no drain-the-batch
-barrier anywhere; ``mode="static"`` deliberately reintroduces one (admit
-only into an EMPTY batch, hold every slot until the whole batch
-finishes) as the baseline tools/servebench.py compares against.
+barrier anywhere.
 
 Two compiled functions, both fixed-shape:
 
@@ -83,17 +81,6 @@ class ServeConfig:
     pool_pages: int = 64
     max_slots: int = 4
     prefill_chunk: int = 16
-    # admission policy: reserve the worst case (prompt + max_new) pages
-    # at admission so a running sequence can never hit PoolExhausted
-    # mid-decode; False allocates prompt-only and grows on demand (a
-    # growth failure is a hard error — the knob exists to measure the
-    # reservation's utilization cost, not for production).
-    reserve_full: bool = True
-    # at most this many admissions per step boundary (0 = unlimited):
-    # bounds per-step prefill work so decode latency stays smooth under
-    # an arrival burst.
-    max_admit_per_step: int = 0
-    mode: str = "continuous"  # "continuous" | "static" (drain baseline)
 
 
 @dataclass
@@ -121,7 +108,7 @@ class EngineCounters:
     admitted: int = 0
     # step boundaries at which the head of the queue could not be admitted
     blocked_on_pool: int = 0   # ... for want of KV pages
-    blocked_on_slots: int = 0  # ... for want of a batch slot (static: an undrained batch)
+    blocked_on_slots: int = 0  # ... for want of a batch slot
     prefill_chunks: int = 0    # calls of the prefill program
     prefill_tokens: int = 0    # prompt tokens they carried
     prefill_padded: int = 0    # chunk positions they padded
@@ -470,7 +457,6 @@ class ServeEngine:
     def run(
         self,
         requests: List[Request],
-        mode: Optional[str] = None,
         clock: Callable[[], float] = time.perf_counter,
         on_event: Optional[Callable[[str, Any], None]] = None,
     ) -> RunResult:
@@ -483,9 +469,6 @@ class ServeEngine:
         import jax.numpy as jnp
         from jax.profiler import TraceAnnotation as span
 
-        mode = mode or self.scfg.mode
-        if mode not in ("continuous", "static"):
-            raise ValueError(f"mode {mode!r}")
         scfg = self.scfg
         for r in requests:
             if not r.prompt:
@@ -519,30 +502,18 @@ class ServeEngine:
         completed = 0
         generated = 0
 
-        def _admit_ok() -> bool:
-            if mode == "static":
-                # drain-the-batch baseline: the batch forms only when
-                # EMPTY — late arrivals wait out the whole generation.
-                return all(sl is None for sl in slots)
-            return True
-
-        def _try_admit(now: float) -> int:
-            n = 0
+        def _try_admit(now: float) -> None:
             while waiting:
-                if not _admit_ok():
-                    counters.blocked_on_slots += 1
-                    break
-                if scfg.max_admit_per_step and n >= scfg.max_admit_per_step:
-                    break
                 free = [i for i, sl in enumerate(slots) if sl is None]
                 if not free:
                     counters.blocked_on_slots += 1
                     break
                 req = waiting[0]
-                want = len(req.prompt) + (req.max_new if scfg.reserve_full else 0)
+                # the worst case (prompt + max_new) is reserved here, so a
+                # running sequence can never hit PoolExhausted mid-decode
                 sp = SequencePages(scfg.page_size)
                 try:
-                    sp.ensure(want, pool)
+                    sp.ensure(len(req.prompt) + req.max_new, pool)
                 except PoolExhausted:
                     counters.blocked_on_pool += 1
                     break  # head-of-line blocks: FIFO admission, no bypass
@@ -553,36 +524,18 @@ class ServeEngine:
                 req.admitted = now
                 counters.admitted += 1
                 emit("admitted", req)
-                n += 1
-                if mode == "static" and n >= s_n:
-                    break
-            return n
 
         def _finish(i: int, now: float) -> None:
-            """Mark slot i's request complete. Continuous mode releases
-            the slot and its pages IMMEDIATELY (reusable this very step);
-            static mode holds everything until the whole batch drains —
-            the barrier being measured."""
+            """Mark slot i's request complete and release the slot and
+            its pages IMMEDIATELY (reusable this very step)."""
             nonlocal completed
             sl = slots[i]
             sl.req.finished = now
             completed += 1
             emit("finished", sl.req)
-            if mode == "continuous":
-                sl.pages.release(pool)
-                table[i, :] = pool.trash_page - 1
-                slots[i] = None
-
-        def _drain_static(now: float) -> None:
-            if mode != "static":
-                return
-            live = [sl for sl in slots if sl is not None]
-            if live and all(sl.generated >= sl.req.max_new for sl in live):
-                for j, sl in enumerate(slots):
-                    if sl is not None:
-                        sl.pages.release(pool)
-                        table[j, :] = pool.trash_page - 1
-                        slots[j] = None
+            sl.pages.release(pool)
+            table[i, :] = pool.trash_page - 1
+            slots[i] = None
 
         def _prefill_chunks() -> None:
             """One chunk per still-prefilling slot."""
@@ -602,9 +555,6 @@ class ServeEngine:
                           last=int(last), kv_pages=kv_pages):
                     buf = np.zeros(c, np.int32)
                     buf[:n_valid] = chunk
-                    if not scfg.reserve_full:
-                        sl.pages.ensure(sl.prefill_pos + n_valid, pool)
-                        table[i, : len(sl.pages.pages)] = sl.pages.pages
                     kp, vp, tok = self._prefill(
                         self.params, kp, vp, jnp.asarray(table[i]),
                         jnp.int32(sl.prefill_pos), jnp.asarray(buf),
@@ -647,9 +597,6 @@ class ServeEngine:
                 toks = np.zeros(s_n, np.int32)
                 lens = np.zeros(s_n, np.int32)
                 for i, sl in dec:
-                    if not scfg.reserve_full:
-                        sl.pages.ensure(sl.seq_len + 1, pool)
-                        table[i, : len(sl.pages.pages)] = sl.pages.pages
                     active[i] = True
                     toks[i] = sl.cur_tok
                     lens[i] = sl.seq_len
@@ -703,7 +650,6 @@ class ServeEngine:
                 ):
                     _prefill_chunks()
                     _decode_step()
-                    _drain_static(clock() - t0)
                     step += 1
                     emit("step", {
                         "step": step,

@@ -30,7 +30,7 @@ The allocator is deliberately host-side and trivial: a LIFO free list.
 LIFO maximizes page reuse locality (a just-freed page is hot in whatever
 cache hierarchy applies) and makes the leak check exact —
 ``free_count`` must return to ``num_pages`` when the engine drains,
-which the serve-bench CI stage asserts.
+which the engine tests and the serve worker assert.
 """
 
 from __future__ import annotations

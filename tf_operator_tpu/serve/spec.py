@@ -1,5 +1,5 @@
 """Serve TPUJob construction — the seam shared by ``tpujob submit
---workload serve``, tools/servebench.py's operator probe, and
+--workload serve``, tools/preempt_probe.py, and
 tools/trace_smoke.py's smoke serve job. One builder so the workload-key
 vocabulary (kv_page_size, kv_pool_pages, requests, ...) has exactly one
 authoritative spelling."""
@@ -54,7 +54,7 @@ def build_serve_job(
     The worker runs on whatever backend its machine gives jax — on a TPU
     host, the TPU — and asks the scheduler for the one chip it uses.
     Nothing here pins a platform: a caller that wants the CPU (tests,
-    soaks, tools/servebench.py --probe) passes that ``env`` itself."""
+    soaks, tools/preempt_probe.py) passes that ``env`` itself."""
     wl = dict(SERVE_WORKLOAD_DEFAULTS)
     wl.update(workload or {})
     spec = TPUJobSpec(

@@ -12,7 +12,6 @@ from tf_operator_tpu.train.checkpoint import (  # noqa: F401
     WorkloadCheckpointer,
 )
 from tf_operator_tpu.train.metrics import (  # noqa: F401
-    host_fetch,
     mfu,
     peak_flops_per_chip,
 )

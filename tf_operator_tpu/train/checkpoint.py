@@ -844,11 +844,8 @@ class WorkloadCheckpointer:
         0 means throughput numbers would be meaningless — don't log them."""
         return max(0, steps - self.start_step)
 
-    def advance(self, state, loss=None, n: int = 1) -> None:
-        """Call once per trainer.step (or once per ``n``-step device-loop
-        chunk); saves when a periodic save is due. Chunked callers must
-        align chunks to save boundaries (run_loop does) — a chunk that
-        jumps OVER a boundary would silently skip that save.
+    def advance(self, state, loss=None) -> None:
+        """Call once per trainer.step; saves when a periodic save is due.
 
         Pass the step's loss so a diverged state is never checkpointed —
         saving NaN params would make them the latest checkpoint and poison
@@ -857,7 +854,7 @@ class WorkloadCheckpointer:
         hot loop stays sync-free."""
         import math
 
-        self._step += n
+        self._step += 1
         if self.manager is not None and self.every and self._step % self.every == 0:
             if loss is not None and not math.isfinite(float(loss)):
                 raise AssertionError(
@@ -874,8 +871,8 @@ class WorkloadCheckpointer:
             self._loss_trace.append(loss)
 
     def loss_trace(self) -> List[float]:
-        """The first dispatches' losses as floats (one per step, or per
-        chunk under device_loop) — call after run_loop, it syncs."""
+        """The first steps' losses as floats — call after run_loop, it
+        syncs."""
         return [float(x) for x in self._loss_trace]
 
     def _note_save_stall(self, step: int) -> None:
@@ -895,9 +892,9 @@ class WorkloadCheckpointer:
         """Apply a pending checkpoint-cadence directive (r16) at a step
         boundary. The autopilot publishes {"epoch", "checkpoint_every"}
         into the job status; the chief calls this between steps, applies
-        each epoch exactly once (updating ``self.every`` — run_loop's
-        chunk clipping reads it per chunk, so the new interval takes
-        effect immediately), and acks ``applied_epoch``/``applied_step``
+        each epoch exactly once (updating ``self.every`` — ``advance``
+        reads it every step, so the new interval takes effect
+        immediately), and acks ``applied_epoch``/``applied_step``
         back. Throttled to one API read per ``cadence_poll_s`` seconds;
         best-effort by contract (an unreachable API changes nothing).
         Returns True when a new epoch was applied this call."""
@@ -939,18 +936,17 @@ class WorkloadCheckpointer:
             if self.manager.save(self._step, state, wait=True):
                 self._note_save_stall(self._step)
 
-    def run_loop(self, trainer, key, batch, steps: int, on_step=None,
-                 device_loop: int = 1):
+    def run_loop(self, trainer, key, batch, steps: int, on_step=None):
         """The one warmup+timed train loop shared by workloads.
 
         restore-or-init → warmup step (compile boundary) → ``steps -
         start_step`` timed steps with periodic NaN-gated saves → finiteness
         guard → final save. Returns ``(state, loss, timed, step_s)`` where
-        ``timed`` counts only the steps inside the timed region (warmup —
-        including the device-loop warmup chunk — trains but is excluded)
-        and ``step_s`` is None when no timed steps remained. Callers must check
-        :meth:`is_complete` first. ``on_step(global_step)`` fires after
-        every advance — the fault-injection / progress-reporting seam.
+        ``timed`` counts only the steps inside the timed region (warmup
+        trains but is excluded) and ``step_s`` is None when no timed steps
+        remained. Callers must check :meth:`is_complete` first.
+        ``on_step(global_step)`` fires after every step — the
+        fault-injection / progress-reporting seam.
 
         ``batch`` is either one fixed batch (re-trained every step: the
         benchmarking shape) or a batch *iterator* — e.g. a
@@ -958,112 +954,34 @@ class WorkloadCheckpointer:
         must share one shape/dtype structure (jit compiles once). On
         restart-based recovery an iterator starts over unless the caller
         fast-forwards it (``DeviceLoader(skip=resume_step())``) — without
-        that, a resumed run re-trains the stream's leading batches.
-
-        ``device_loop=K`` runs up to K steps per compiled call
-        (``Trainer.multi_step``), chunks clipped to checkpoint boundaries
-        so no periodic save is skipped; iterator batches are stacked K at
-        a time through a jitted stacker — multi-host global arrays can't
-        be stacked OUTSIDE jit, but inside jit the stack is an ordinary
-        SPMD program, so multi-host gangs keep the device loop with
-        stream data (r4; the r3 behavior silently fell back to per-step
-        dispatch there, costing the ~7% the loop buys at small steps).
-        NOTE: ``on_step`` fires once per CHUNK with the post-chunk global
-        step, so step-keyed triggers (the lm workload's ``fail_at_step``
-        fault injection) can land up to K-1 steps late and after the
-        chunk's save — chaos scenarios that need exact-step faults should
-        run with device_loop=1 (chunks are deliberately NOT clipped at
-        injection points: the loop cannot know which steps a caller's
-        callback keys on).
-        ``on_step`` then fires once per chunk (with the post-chunk global
-        step), so fault-injection / progress hooks see chunk
-        granularity."""
+        that, a resumed run re-trains the stream's leading batches."""
         import math
         import time
 
-        from tf_operator_tpu.train.metrics import host_fetch
+        import jax
 
         is_iter = hasattr(batch, "__next__")
         pull = (lambda: next(batch)) if is_iter else (lambda: batch)
-        device_loop = max(1, int(device_loop))
-        stackers: dict = {}
 
-        def pull_chunk(k: int):
-            if not is_iter:
-                return batch, False
-            if k == 1:
-                return next(batch), False
-            import jax
-            import jax.numpy as jnp
-
-            slices = [next(batch) for _ in range(k)]
-            # Stack INSIDE jit: on multi-host gangs the slices are
-            # non-fully-addressable global arrays and jnp.stack on them
-            # crashes eagerly, but under jit it is an ordinary SPMD
-            # program (output sharded [None, *batch]). One compiled
-            # stacker per chunk size (chunks vary only at save
-            # boundaries).
-            stacker = stackers.get(k)
-            if stacker is None:
-                stacker = jax.jit(
-                    lambda *xs: jax.tree_util.tree_map(
-                        lambda *ys: jnp.stack(ys), *xs
-                    )
-                )
-                stackers[k] = stacker
-            return stacker(*slices), True
-
-        def chunk_size(remaining: int) -> int:
-            k = min(device_loop, remaining)
-            if self.manager is not None and self.every:
-                # clip to the next save boundary so advance() never jumps
-                # one (without a manager there is nothing to save — don't
-                # forfeit dispatch amortization for a no-op)
-                to_boundary = self.every - (self._step % self.every)
-                k = min(k, to_boundary)
-            return max(1, k)
-
-        def run_chunk(state, remaining: int):
-            k = chunk_size(remaining)
-            if k == 1:
-                state, m = trainer.step(state, pull())
-            else:
-                chunk, stacked = pull_chunk(k)
-                state, m = trainer.multi_step(state, chunk, k, stacked=stacked)
+        def run_step(state):
+            state, m = trainer.step(state, pull())
             self._trace_loss(m["loss"])
-            self.advance(state, loss=m["loss"], n=k)
-            self.poll_cadence_directive()  # cadence retune lands at chunk boundary
-            if on_step is not None:
-                on_step(self._step)
-            return state, m, k
+            self.advance(state, loss=m["loss"])
+            return state, m
 
         state = self.restore_or_init(trainer, key)
         remaining = self.timed_steps(steps)
-        # warmup (compile boundary): the single-step program, then — when
-        # device-looping — one chunk of each distinct upcoming chunk size,
-        # so the boundary-clipped AND steady-state programs both compile
-        # outside the timed region. Stops before exhausting the budget
-        # (at least one chunk stays timed); a novel tail size can still
-        # compile in-region, but a tail is by construction small.
-        state, m = trainer.step(state, pull())
-        self._trace_loss(m["loss"])
-        self.advance(state, loss=m["loss"])
+        state, m = run_step(state)  # warmup: the compile boundary
         if on_step is not None:
             on_step(self._step)
-        warmed: set = set()
-        while device_loop > 1 and remaining > 0:
-            k_next = chunk_size(remaining)
-            if k_next <= 1 or k_next in warmed or remaining <= k_next:
-                break
-            warmed.add(k_next)
-            state, m, k = run_chunk(state, remaining)
-            remaining -= k
-        host_fetch(m["loss"])
+        jax.block_until_ready(m["loss"])
         timed = remaining
         t0 = time.perf_counter()
-        while remaining > 0:
-            state, m, k = run_chunk(state, remaining)
-            remaining -= k
+        for _ in range(remaining):
+            state, m = run_step(state)
+            self.poll_cadence_directive()
+            if on_step is not None:
+                on_step(self._step)
         loss = float(m["loss"])
         step_s = (time.perf_counter() - t0) / timed if timed else None
         if not math.isfinite(loss):

@@ -21,8 +21,8 @@ is recorded in ``stats()`` and surfaced as a span attribute by
 ``JobContext.mark_first_step``, never as a job failure.
 
 ``enable()`` is called by the rendezvous harness before user ``train_fn``
-runs (every operator-launched process gets it), and by ``bench.py``. Safe
-to call multiple times.
+runs (every operator-launched process gets it). Safe to call multiple
+times.
 
 Where the cache lives is decided from OUTSIDE the program, in one place
 (``cache_dir()``): ``JAX_COMPILATION_CACHE_DIR`` when set, else one fixed

@@ -86,17 +86,6 @@ def run_report(**extra) -> Dict[str, object]:
     }
 
 
-def host_fetch(x) -> None:
-    """Wait until ``x`` (an array or a pytree) has been computed — the
-    sync every timing in this framework ends with. With a locally
-    attached chip ``jax.block_until_ready`` is that sync; no host copy
-    of the data is needed (re-tested on the chip, PR 21: see
-    CHANGES.md)."""
-    import jax
-
-    jax.block_until_ready(x)
-
-
 def transformer_train_flops(n_params: int, tokens_per_step: int) -> float:
     """6ND rule: fwd 2ND + bwd 4ND.
 
@@ -104,7 +93,7 @@ def transformer_train_flops(n_params: int, tokens_per_step: int) -> float:
     with sequence length, not parameter count) — at t=8192 on gpt-small the
     attention term is the same order as 6ND, so a 6ND-only MFU under-reports
     long-context utilization by ~2x. Use transformer_train_flops_exact for
-    honest long-context accounting; report both (bench.py does)."""
+    honest long-context accounting; report both."""
     return 6.0 * n_params * tokens_per_step
 
 

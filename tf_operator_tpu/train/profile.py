@@ -1,12 +1,11 @@
 """Profiler capture: one switch around the hot loop.
 
 The reference has no profiling story at all (SURVEY.md §5: per-sync
-latency logs only); here any workload or bench run can capture an XLA
-trace by pointing a directory at it — ``profile_dir`` in the workload
-dict, or ``BENCH_PROFILE=/dir`` for bench.py. The output is a TensorBoard
--loadable xplane (host + device timelines, op breakdown), written per
-process under ``<dir>/<process_index>`` so multi-host gangs don't
-clobber each other.
+latency logs only); here any workload can capture an XLA trace by
+pointing a directory at it — ``profile_dir`` in the workload dict. The
+output is a TensorBoard-loadable xplane (host + device timelines, op
+breakdown), written per process under ``<dir>/<process_index>`` so
+multi-host gangs don't clobber each other.
 """
 
 from __future__ import annotations

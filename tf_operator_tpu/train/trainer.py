@@ -16,7 +16,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,8 +57,8 @@ class TrainerConfig:
     # same-seed init is no longer bit-identical across dp=4 vs dp=8
     # meshes the way threefry was. Default False (r5, ADVICE r4):
     # library callers keep deterministic threefry init for seed-matched
-    # ablations; the submit-latency paths (bench.py, the lm/resnet
-    # workloads) opt in explicitly. Restores/resumes never re-init, so
+    # ablations; the submit-latency paths (the lm/resnet workloads) opt
+    # in explicitly. Restores/resumes never re-init, so
     # recovery semantics are unchanged either way.
     fast_init_rng: bool = False
 
@@ -145,7 +145,6 @@ class Trainer:
         self._compiled_hits = 0
         self._compiled_rejections = 0
         self._step_calls = 0
-        self._multi_jit: Dict[Any, Any] = {}
 
     # ---- init -----------------------------------------------------------
 
@@ -279,37 +278,6 @@ class Trainer:
         if ckpt is not None and ckpt.latest_step() is not None:
             return ckpt.restore(self.state_template())
         return self.init(key)
-
-    def init_and_step(self, key, batch) -> tuple:
-        """Init + FIRST train step as ONE program: one executable to
-        compile and load instead of two. Identical math to init()
-        followed by step(); subsequent steps use the normal step
-        program. Returns (TrainState, {"loss": ...}) like step().
-        (No net win at its last measurement — ROADMAP C1.)"""
-        if self.config.fast_init_rng:
-            key = self._fast_init_key(key)
-        opt_shardings = self._opt_shardings()
-        extra_out = self._repl if self._has_extra else None
-
-        def go(key, batch):
-            out = self.init_fn(key)
-            params, extra = out if self._has_extra else (out, None)
-            return self._step_body(
-                params, self.tx.init(params), jnp.zeros((), jnp.int32), extra, batch
-            )
-
-        fused = jax.jit(
-            go,
-            out_shardings=(
-                self.param_shardings,
-                opt_shardings,
-                self._repl,
-                extra_out,
-                self._repl,
-            ),
-        )
-        params, opt_state, step, extra, loss = fused(key, batch)
-        return TrainState(params, opt_state, step, extra), {"loss": loss}
 
     # ---- step -----------------------------------------------------------
 
@@ -504,54 +472,3 @@ class Trainer:
         # step loop — that copy is the save stall; everything after it
         # (device->host fetch, chunked writes, commit) overlaps training.
         return jax.jit(self._step_body, donate_argnums=(0, 1, 3))
-
-    # ---- multi-step (device loop) ---------------------------------------
-
-    def multi_step(
-        self, state: TrainState, batch, n_steps: int, stacked: bool = False
-    ) -> tuple:
-        """Run ``n_steps`` train steps inside ONE compiled call — a
-        ``lax.scan`` over the step body, so per-step host dispatch
-        disappears from the step time. ``batch`` is one batch trained
-        repeatedly
-        (``stacked=False``, the benchmarking shape) or, with
-        ``stacked=True``, a pytree with a leading [n_steps] dim — one
-        slice per step, e.g. ``n_steps`` loader batches stacked.
-        Returns ``(state, {"loss": last, "losses": [n_steps]})``.
-        Compiles once per (n_steps, stacked) pair."""
-        if stacked:
-            for a in jax.tree_util.tree_leaves(batch):
-                if a.shape[0] != n_steps:
-                    raise ValueError(
-                        f"stacked batch leading dim {a.shape[0]} != n_steps {n_steps}"
-                    )
-        key = (int(n_steps), bool(stacked))
-        if self._multi_jit.get(key) is None:
-            self._multi_jit[key] = self._build_multi_step(n_steps, stacked)
-        params, opt_state, step, extra, losses = self._multi_jit[key](
-            state.params, state.opt_state, state.step, state.extra, batch
-        )
-        return (
-            TrainState(params, opt_state, step, extra),
-            {"loss": losses[-1], "losses": losses},
-        )
-
-    def _build_multi_step(self, n_steps: int, stacked: bool):
-        def go(params, opt_state, step, extra, batch):
-            def body(carry, xs):
-                params, opt_state, step, extra = carry
-                b = xs if stacked else batch
-                params, opt_state, step, extra, loss = self._step_body(
-                    params, opt_state, step, extra, b
-                )
-                return (params, opt_state, step, extra), loss
-
-            (params, opt_state, step, extra), losses = jax.lax.scan(
-                body,
-                (params, opt_state, step, extra),
-                batch if stacked else None,
-                length=None if stacked else n_steps,
-            )
-            return params, opt_state, step, extra, losses
-
-        return jax.jit(go, donate_argnums=(0, 1, 3))

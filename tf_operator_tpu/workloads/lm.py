@@ -8,7 +8,6 @@ workload config keys: preset (any models.transformer.PRESETS name:
 "tiny"|"tiny-moe"|"gpt-small"|"moe-small"|"bert-base"|"llama2-7b"|
 "llama2-13b"|"llama2-70b"), steps, batch_size, seq_len, lr,
 attn ("dense"|"ring"|"flash"), profile_dir (capture an XLA trace),
-device_loop (K steps per compiled call — lax.scan device loop),
 checkpoint_dir, checkpoint_every (steps between saves; restart-based
 recovery resumes from the latest checkpoint), grad_accum (microbatch
 gradient accumulation — same global batch in 1/N-size activation
@@ -143,10 +142,6 @@ def main(ctx: JobContext) -> None:
     # RETRYABLY once at the given global step — the restart-based-recovery
     # e2e: the gang restarts and the next incarnation must resume from the
     # latest checkpoint, not step 0. The marker file makes it once-only.
-    # Granularity: with device_loop=K the on_step callback fires per CHUNK
-    # (post-chunk step), so the fault can trigger up to K-1 steps late and
-    # after that chunk's save — exact-step chaos scenarios should use
-    # device_loop=1 (see WorkloadCheckpointer.run_loop).
     fail_at = int(wl.get("fail_at_step", 0))
     marker = wl.get("fail_marker")
     first_step_marked = []
@@ -180,7 +175,6 @@ def main(ctx: JobContext) -> None:
         with profile_ctx(wl.get("profile_dir")):
             state, loss, timed, step_s = ckpt.run_loop(
                 trainer, jax.random.PRNGKey(0), tokens, steps, on_step=on_step,
-                device_loop=int(wl.get("device_loop", 1)),
             )
     finally:
         if loader is not None:

@@ -24,8 +24,7 @@ image_size, num_classes, lr, variant ("resnet50"|"resnet18"),
 checkpoint_dir, checkpoint_every, data, data_dir, augment (default true),
 crop_padding (default 4), flip (default false — digit-class fixtures are
 orientation-sensitive; set true for natural images), target_accuracy,
-eval_batch_size, profile_dir (XLA trace), device_loop (K steps per
-compiled call — lax.scan device loop).
+eval_batch_size, profile_dir (XLA trace).
 """
 
 from __future__ import annotations
@@ -175,8 +174,7 @@ def main(ctx: JobContext) -> None:
     try:
         with profile_ctx(wl.get("profile_dir")):
             state, loss, timed, step_s = ckpt.run_loop(
-                trainer, jax.random.PRNGKey(0), data, steps,
-                device_loop=int(wl.get("device_loop", 1)),
+                trainer, jax.random.PRNGKey(0), data, steps
             )
     finally:
         if loader is not None:

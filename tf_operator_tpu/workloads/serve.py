@@ -23,8 +23,7 @@ out on the same channel as ``engine_<counter>``.
 workload config keys: preset (+ any TransformerConfig override),
 requests, prompt_len, max_new_tokens, arrival_rate (req/s Poisson; 0 ⇒
 all at t=0), seed, kv_page_size, kv_pool_pages, max_slots,
-prefill_chunk, reserve_full, max_admit_per_step, mode
-("continuous"|"static"), report_every, profile_dir (capture a profiler
+prefill_chunk, report_every, profile_dir (capture a profiler
 trace of the run there), check_greedy (hold the first N
 finished requests against un-paged greedy decoding through
 transformer_forward; the job fails when a token the engine chose is
@@ -50,10 +49,9 @@ GREEDY_TOL = 0.05
 
 
 def synthesize_requests(wl: dict, vocab: int):
-    """The seeded request stream (shared with tools/servebench.py so the
-    bench and the operator workload replay identical traffic): Poisson
-    arrivals, uniform prompt lengths around prompt_len, uniform random
-    prompt tokens, ragged generation budgets in [1, max_new_tokens]."""
+    """The seeded request stream: Poisson arrivals, uniform prompt lengths
+    around prompt_len, uniform random prompt tokens, ragged generation
+    budgets in [1, max_new_tokens]."""
     import numpy as np
 
     from tf_operator_tpu.serve.engine import Request
@@ -122,9 +120,6 @@ def main(ctx: JobContext) -> None:
         pool_pages=int(wl.get("kv_pool_pages", 64)),
         max_slots=int(wl.get("max_slots", 4)),
         prefill_chunk=int(wl.get("prefill_chunk", 16)),
-        reserve_full=bool(wl.get("reserve_full", True)),
-        max_admit_per_step=int(wl.get("max_admit_per_step", 0)),
-        mode=str(wl.get("mode", "continuous")),
     )
     params = init_transformer(jax.random.PRNGKey(int(wl.get("seed", 0))), cfg)
     engine = ServeEngine(cfg, params, scfg)
@@ -217,9 +212,9 @@ def main(ctx: JobContext) -> None:
         )
     ttfts = res.ttfts()
     log.info(
-        "serve done: preset=%s mode=%s requests=%d/%d tokens=%d tok/s=%.1f "
+        "serve done: preset=%s requests=%d/%d tokens=%d tok/s=%.1f "
         "ttft_p50=%.3fs ttft_p99=%.3fs steps=%d (0 page leaks)",
-        wl.get("preset", "tiny"), scfg.mode, res.completed, total,
+        wl.get("preset", "tiny"), res.completed, total,
         res.generated_tokens, res.tokens_per_s,
         _quantile(ttfts, 0.50), _quantile(ttfts, 0.99), res.steps,
     )
