@@ -252,12 +252,15 @@ def test_engine_programs_keep_the_pool_in_place_on_v5e(serve1_engine, program):
     kernels = _kernel_names_in(text)
     assert kernels.count("paged_attention") == 2, kernels
     assert report[f"{program}_tpu_custom_calls"] >= 2
-    # 4. a layer's kernel walks (slots x) 8 KV heads x 20 page slots: the
-    # chunk's 128 positions are ONE tile over their sequence's pages (at
-    # the cell's 12 layers: 1,920 steps a chunk, where 128 pseudo-sequences
-    # through the decode kernel made 245,760)
-    per_layer = {"decode": 16 * 8 * 20, "prefill": 8 * 20}[program]
-    assert 0 < report[f"{program}_attn_grid_steps"] <= 2 * per_layer
+    # 4. a grid step is one page of as many KV heads as VMEM holds beside
+    # their q tiles (``_kv_heads_per_step``): all 8 for a decode step's
+    # 4-row tiles, 4 for a chunk's 512-row tiles (its 128 positions are
+    # ONE tile a head over their sequence's pages). So a layer's kernel
+    # walks 16 slots x 1 group x 20 page slots, or 1 x 2 x 20 (at the
+    # cell's 12 layers 3,840 steps a decode run and 480 a chunk, where one
+    # head a step made 30,720 and 1,920)
+    per_layer = {"decode": 16 * 20, "prefill": 2 * 20}[program]
+    assert report[f"{program}_attn_grid_steps"] == 2 * per_layer
 
 
 @pytest.mark.parametrize("page", [8, 16])

@@ -261,6 +261,117 @@ def test_prefill_tile_matches_causal_prefix(case, caplog, monkeypatch):
             layer=1)))
 
 
+# A grid step of the kernel is one page of ``hb`` KV heads, hb read from
+# the shapes against the VMEM budget. (h_kv, g, rows, page, pool form,
+# pool dtype, K/V length a sequence, valid rows a sequence, how many heads
+# the budget is cut to hold — None: the real budget —, the hb that must
+# come of it.) A decode step's slots are ragged with an EMPTY one among
+# them; a chunk starts and ends mid-page.
+F32, BF16 = "float32", "bfloat16"
+RAGGED0 = [5, 0, 23, 16, 1]  # RAGGED with an empty slot among them
+HEADS_A_STEP = {
+    "decode-gqa-all-heads": (4, 4, 1, 8, "5d", F32, RAGGED0, None, None, 4),
+    "decode-gqa-budget-for-two": (4, 4, 1, 8, "5d", F32, RAGGED0, None, 2, 2),
+    "decode-gqa-budget-for-one": (4, 4, 1, 8, "5d", F32, RAGGED0, None, 1, 1),
+    "decode-mha-g1-4d-pool": (4, 1, 1, 8, "4d", F32, [9, 24, 0, 2], None, None, 4),
+    # four heads would fit, four does not divide six: three a step
+    "decode-six-heads-budget-for-four": (
+        6, 2, 1, 8, "5d", F32, [17, 0, 8], None, 4, 3),
+    "decode-bf16-page16-4d-pool": (
+        2, 4, 1, 16, "4d", BF16, [5, 0, 37, 32], None, None, 2),
+    "chunk-mid-page-both-ends-all-heads": (
+        2, 4, 16, 8, "5d", F32, [19], [14], None, 2),
+    "chunk-deep-page16-budget-for-two": (
+        4, 2, 16, 16, "5d", F32, [46], [9], 2, 2),
+    "chunk-padded-rows-budget-for-one": (
+        2, 4, 8, 8, "4d", F32, [16], [3], 1, 1),
+    "chunks-of-two-sequences-one-empty": (
+        2, 1, 16, 8, "5d", F32, [21, 0], [16, 0], None, 2),
+    "chunk-mha-g1-budget-for-two": (4, 1, 8, 8, "4d", F32, [13], [6], 2, 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HEADS_A_STEP))
+def test_kv_heads_a_grid_step_leave_every_row_as_one_head_a_step_did(
+        case, monkeypatch):
+    """The kernel in interpret mode, at the hb its VMEM predicate picks —
+    all KV heads, a proper divisor, one — (a) against
+    ``paged_decode_reference`` on every row that sees a key, zeros on the
+    others, and (b) BIT FOR BIT against the same call with the predicate
+    held to one head a step, the kernel as it was: a head's dots, mask,
+    carry and page order do not depend on which heads share its step. The
+    grid of the traced call is slots x h_kv / hb x page slots.
+
+    One exception to (b), and it is the interpreter's: a q tile of ONE row
+    (an MHA decode step). The heads are the batch dimension of the step's
+    dots, and XLA's CPU backend computes a one-row product of a batch of
+    one in another order than of a batch of several (1 ulp; two rows or
+    more agree exactly, ``jnp`` alone shows it), so that case is held to
+    1e-6. On the chip the kernel equalled the one-head kernel bit for bit,
+    one-row tiles too (PERF.md §6 "PR 29")."""
+    import functools
+    import importlib
+
+    from tf_operator_tpu.serve.engine import pallas_grid_steps
+
+    fa = importlib.import_module("tf_operator_tpu.ops.flash_attention")
+    h_kv, g, rows, page, form, dtype, lengths, n_valid, fits, hb = (
+        HEADS_A_STEP[case])
+    d, h, s_n = 128, h_kv * g, len(lengths)
+    _, _, kp, vp, table, lens = _paged_prefix(
+        [max(L, 1) for L in lengths], page, h_kv, d, seed=len(case),
+        scramble=True)
+    lens = jnp.asarray(np.asarray(lengths, np.int32))
+    kp, vp = kp.astype(dtype), vp.astype(dtype)
+    # the engine's rows are max_pages wide: two more slots nobody may read
+    table = jnp.concatenate(
+        [table, jnp.broadcast_to(table[:, :1], (s_n, 2))], axis=1)
+    rng = np.random.RandomState(11)
+    if rows == 1:  # the decode step's own call: q [s, h, d], no q_start
+        q = jnp.asarray(rng.randn(s_n, h, d), dtype)
+        valid = np.asarray(lengths) > 0
+        kw = {}
+    else:
+        q = jnp.asarray(rng.randn(s_n, rows, h, d), dtype)
+        valid = np.arange(rows)[None] < np.asarray(n_valid)[:, None]
+        kw = {"q_start": lens - jnp.asarray(n_valid, jnp.int32)}
+    if form == "5d":
+        kp, vp = jnp.stack([kp + 1, kp, kp * 2]), jnp.stack([vp - 1, vp, vp / 2])
+        kw["layer"] = 1
+    if fits is not None:
+        monkeypatch.setattr(
+            fa, "_TILE_VMEM_BUDGET",
+            fa._tile_vmem_bytes(fits * rows * g, d)
+            + 4 * fits * page * d * kp.dtype.itemsize)
+    assert fa._kv_heads_per_step(
+        h_kv, rows * g, d, page, kp.dtype.itemsize) == hb
+
+    def run(q):
+        return flash_attention_decode(q, kp, vp, table, lens, interpret=True,
+                                      **kw)
+
+    def grid_steps():  # a new function a trace: none is answered from a cache
+        return pallas_grid_steps(jax.make_jaxpr(lambda q: run(q))(q).jaxpr)
+
+    assert grid_steps() == s_n * (h_kv // hb) * table.shape[1]
+    got = np.asarray(run(q).astype(jnp.float32))
+    ref = np.asarray(paged_decode_reference(
+        q, kp, vp, table, lens, kw.get("layer"), kw.get("q_start")
+    ).astype(jnp.float32))
+    tol = 2e-5 if dtype == F32 else 2e-2
+    np.testing.assert_allclose(got[valid], ref[valid], atol=tol, rtol=tol)
+    np.testing.assert_array_equal(got[~valid], 0.0)
+    monkeypatch.setattr(
+        fa, "_kv_heads_per_step",
+        functools.partial(fa._kv_heads_per_step, at_most=1))
+    assert grid_steps() == s_n * h_kv * table.shape[1]
+    one_head = np.asarray(run(q).astype(jnp.float32))
+    if rows * g == 1:
+        np.testing.assert_allclose(got, one_head, atol=1e-6, rtol=0)
+    else:
+        np.testing.assert_array_equal(got, one_head)
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 @pytest.mark.parametrize("page", [16, 64])
 def test_write_rows_matches_the_scatter_it_replaces(page, program):

@@ -712,7 +712,8 @@ def flash_attention(
 # fixed-size pages of a preallocated pool and each sequence owns an
 # ordered page table. Nothing materializes a contiguous [t, d] K/V tensor
 # on TPU — the kernel walks the page table as its innermost grid
-# dimension and DMAs one page per step, with page ids resolved through
+# dimension and DMAs one page per step (of as many kv-heads as its VMEM
+# holds, ``_kv_heads_per_step``), with page ids resolved through
 # scalar-prefetch (the page table is in SMEM before the grid runs, so the
 # K/V BlockSpec index_map can compute each step's HBM source block from
 # it). The q tile of one (sequence, kv-head) pair is [rows·g, d]: the
@@ -810,8 +811,12 @@ def paged_decode_reference(q, k_pages, v_pages, page_table, seq_lens,
 
 def _paged_kernel(pt_ref, sl_ref, qs_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, page_size, g, scale):
-    """One (sequence, kv-head) pair streams its pages through VMEM past
-    its q tile [rows·g, d]. The innermost grid dim walks page-table
+    """One (sequence, group of ``hb`` kv-heads) pair streams its pages
+    through VMEM past its q tiles [hb, rows·g, d]: a grid step is one page
+    of ALL the group's heads (one contiguous slab of the pool) and runs
+    the one-head step on all of them at once — the heads are the batch
+    dimension of its two dots, and every head has rows of the carry of
+    its own. The innermost grid dim walks page-table
     SLOTS; slots past the sequence's live prefix are skipped with pl.when
     (and fetch nothing: their index map repeats the last live page).
     Inside a live page every row masks what it may not see to NEG_INF
@@ -828,9 +833,9 @@ def _paged_kernel(pt_ref, sl_ref, qs_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(pi == 0)
     def _init():
-        m_scr[:, :] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:, :] = jnp.zeros_like(l_scr)
-        acc_scr[:, :] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
     length = sl_ref[si]
     q_start = qs_ref[si]
@@ -838,48 +843,67 @@ def _paged_kernel(pt_ref, sl_ref, qs_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # [rows·g, d]
-        k = k_ref[0, 0, 0].astype(jnp.float32)  # [page_size, d]
-        v = v_ref[0, 0, 0].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32) * scale  # [hb, rows·g, d]
+        k = k_ref[0, 0].astype(jnp.float32)  # [hb, page_size, d]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [rows·g, page_size]
-        kpos = pi * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # [hb, rows·g, page_size]
+        kpos = pi * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         seen = _tile_visible(kpos, row, q_start, length, g)
         s = jnp.where(seen, s, NEG_INF)
-        m_prev = m_scr[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.where(seen, jnp.exp(s - m_new[:, None]), 0.0)
+        m_prev = m_scr[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        m_scr[:, :] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-        l_scr[:, :] = l_scr[:, :] * alpha[:, None] + jnp.sum(p, axis=1)[:, None]
-        acc_scr[:, :] = acc_scr[:, :] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
         )
 
     @pl.when(pi == npi - 1)
     def _finish():
         # a row that saw no key (seq_len == 0, a padded row) leaves l at
         # 0 — guard the divide so it emits zeros, not nan.
-        l = l_scr[:, 0]
+        l = l_scr[:, :, :1]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:, :] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 _TILE_VMEM_BUDGET = 12 << 20  # of the 16 MiB a kernel may scope by default
 
 
 def _tile_vmem_bytes(n: int, d: int) -> int:
-    """What ``_paged_call`` keeps resident for a tile of n rows, in f32:
-    q and o double-buffered, the accumulator, m and l a lane row each
-    (the K/V page blocks are small beside them)."""
+    """What ``_paged_call`` keeps resident for a q tile of n rows, in f32:
+    q and o double-buffered, the accumulator, m and l a lane row each."""
     return 4 * n * (5 * d + 2 * LSE_LANES)
+
+
+def _kv_heads_per_step(h_kv: int, n: int, d: int, page_size: int,
+                       itemsize: int, at_most: Optional[int] = None) -> int:
+    """How many KV heads ``hb`` one grid step of ``_paged_call`` takes:
+    the largest divisor of h_kv whose step stays inside
+    ``_TILE_VMEM_BUDGET`` — the hb q tiles of n rows with their carries
+    and the K and V blocks [hb, page_size, d] of the pool's dtype, double-
+    buffered. Read from the shapes in hand (8 for a decode step at the
+    -serve1 shapes, 4 for its 512-row prefill tile); 0 when not even one
+    head fits, which sends the call to the gather reference. ``at_most``
+    is the tests' handle on it (1 is the one-head step the others must
+    equal bit for bit); no caller in the package passes it."""
+    for hb in range(min(h_kv, at_most or h_kv), 0, -1):
+        step = _tile_vmem_bytes(hb * n, d) + 4 * hb * page_size * d * itemsize
+        if h_kv % hb == 0 and step <= _TILE_VMEM_BUDGET:
+            return hb
+    return 0
 
 
 def _paged_call(q, k_pool, v_pool, layer, page_table, seq_lens, q_start,
                 interpret):
-    """q [s, r, h, d] through the kernel: grid (s, h_kv, page slots)."""
+    """q [s, r, h, d] through the kernel: grid (s, h_kv / hb, page slots)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -888,19 +912,24 @@ def _paged_call(q, k_pool, v_pool, layer, page_table, seq_lens, q_start,
     p = page_table.shape[1]
     g = h // h_kv
     n = r * g
+    hb = _kv_heads_per_step(h_kv, n, d, page_size, k_pool.dtype.itemsize)
     qt = _tile_q(q, h_kv)
 
     # Scalar-prefetch args (page_table, seq_lens, q_start) reach the
     # index_maps as TRAILING refs after the grid indices — the K/V source
-    # block for grid step (si, hk, pi) is whatever page the table names,
-    # which is the whole paging trick. One K/V block is one (page,
-    # kv-head) slab [page_size, d]: the tiled minor dims Mosaic requires
-    # of a block (sublane-aligned page, whole head_dim) — the reason the
-    # pools are laid out [n_layers, n_pages, h_kv, page_size, d]. The
-    # kernel takes the WHOLE pool and ``layer`` (a Python int) sits in
-    # the index map: handing it ``pool[layer]`` makes XLA materialise
-    # that layer (a slice of the whole layer, 84 MB at the -serve1
-    # shapes) before every call. A slot past the last live page names
+    # block for grid step (si, hg, pi) is whatever page the table names,
+    # which is the whole paging trick. One K/V block is one page of hb
+    # kv-heads, [hb, page_size, d]: contiguous in the pool — the reason
+    # the pools are laid out [n_layers, n_pages, h_kv, page_size, d] —
+    # and with the tiled minor dims Mosaic requires of a block
+    # (sublane-aligned page, whole head_dim). A grid step costs its
+    # bookkeeping on the scalar core and the latency of its two DMAs
+    # whatever it carries, and most steps of a decode run are dead slots,
+    # so a step takes as many heads as VMEM holds, not one
+    # (``_kv_heads_per_step``). The kernel takes the WHOLE pool and
+    # ``layer`` (a Python int) sits in the index map: handing it
+    # ``pool[layer]`` makes XLA materialise that layer (a slice of the
+    # whole layer, 84 MB at the -serve1 shapes) before every call. A slot past the last live page names
     # that page again — a block whose index did not change is not
     # fetched — and the table is clamped HERE, once a call, not in the
     # index map, which runs at every grid step on the scalar core.
@@ -910,22 +939,23 @@ def _paged_call(q, k_pool, v_pool, layer, page_table, seq_lens, q_start,
         jnp.minimum(jnp.arange(p, dtype=jnp.int32)[None], last[:, None]),
         axis=1)
 
-    def pool_index(si, hk, pi, pt, sl, qs):
-        return (layer, pt[si, pi], hk, 0, 0)
+    def pool_index(si, hg, pi, pt, sl, qs):
+        return (layer, pt[si, pi], hg, 0, 0)
 
-    def tile_index(si, hk, pi, pt, sl, qs):
-        return (si, hk, 0, 0)
+    def tile_index(si, hg, pi, pt, sl, qs):
+        return (si, hg, 0, 0)
 
-    pool_spec = pl.BlockSpec((1, 1, 1, page_size, d), pool_index)
+    pool_spec = pl.BlockSpec((1, 1, hb, page_size, d), pool_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(s_n, h_kv, p),
-        in_specs=[pl.BlockSpec((1, 1, n, d), tile_index), pool_spec, pool_spec],
-        out_specs=pl.BlockSpec((1, 1, n, d), tile_index),
+        grid=(s_n, h_kv // hb, p),
+        in_specs=[pl.BlockSpec((1, hb, n, d), tile_index), pool_spec,
+                  pool_spec],
+        out_specs=pl.BlockSpec((1, hb, n, d), tile_index),
         scratch_shapes=[
-            pltpu.VMEM((n, LSE_LANES), jnp.float32),  # running max m
-            pltpu.VMEM((n, LSE_LANES), jnp.float32),  # running sum l
-            pltpu.VMEM((n, d), jnp.float32),          # output accumulator
+            pltpu.VMEM((hb, n, LSE_LANES), jnp.float32),  # running max m
+            pltpu.VMEM((hb, n, LSE_LANES), jnp.float32),  # running sum l
+            pltpu.VMEM((hb, n, d), jnp.float32),          # output accumulator
         ],
     )
     kernel = functools.partial(
@@ -962,9 +992,9 @@ def flash_attention_decode(
     [n_pages, h_kv, page_size, d] (the
     serve/kvcache.py pool layout — head-major so one (page, kv-head)
     slab is a tile-aligned [page_size, d] block the TPU compiler
-    accepts), or the whole pool [n_layers, n_pages, h_kv, page_size, d]
-    with ``layer`` (a Python int) naming the layer to read: the serve
-    engine's form. The kernel always takes a 5-D pool and puts the layer
+    accepts, and a page's heads are one contiguous piece), or the whole
+    pool [n_layers, n_pages, h_kv, page_size, d] with ``layer`` (a
+    Python int) naming the layer to read: the serve engine's form. The kernel always takes a 5-D pool and puts the layer
     in its BlockSpec index map, so no ``pool[layer]`` is ever cut out of
     the pool in front of it (a 4-D pool is the one-layer case, a free
     reshape); page_table [s, max_pages] int32;
@@ -975,7 +1005,9 @@ def flash_attention_decode(
     sees keys ``<= q_start + i``; a row at or past ``seq_lens`` (the
     padding of a short chunk) sees none. Returns q's shape in q's dtype.
     GQA-native: h % h_kv folds into the q tile exactly as in the full
-    kernel.
+    kernel. One grid step of the kernel is a page of as many KV heads as
+    its VMEM holds beside their q tiles (read from the shapes; a row's
+    result does not depend on it).
 
     Dispatch mirrors flash_attention: the Pallas kernel engages on TPU
     (or under ``interpret=True`` — the CPU test path) when the page size
@@ -1010,7 +1042,8 @@ def flash_attention_decode(
                    f"({jnp.dtype(k_pages.dtype).name} sublanes)")
     elif r > 1 and (r * h // h_kv) % 8:
         why_not = f"{tile} is not a multiple of 8 rows"
-    elif _tile_vmem_bytes(r * h // h_kv, q.shape[-1]) > _TILE_VMEM_BUDGET:
+    elif not _kv_heads_per_step(h_kv, r * h // h_kv, q.shape[-1], page_size,
+                                jnp.dtype(k_pages.dtype).itemsize):
         why_not = f"{tile} does not fit the kernel's VMEM"
     on_tpu = jax.default_backend() == "tpu"
     use = why_not is None and (bool(interpret) or on_tpu)

@@ -403,8 +403,9 @@ class ServeEngine:
         (``pool_copies``; 0 while the pool is written and read in place,
         in one layout), and ``<program>_attn_grid_steps``: the grid steps
         of its attention kernels, all layers (``pallas_grid_steps``; a
-        chunk walks its sequence's pages once: KV heads x page slots a
-        layer)."""
+        step is one page of as many KV heads as the kernel's VMEM holds,
+        and a chunk walks its sequence's pages once: slots x groups of
+        KV heads x page slots a layer)."""
         import jax
         import jax.numpy as jnp
 

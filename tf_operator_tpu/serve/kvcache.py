@@ -3,10 +3,11 @@
 The vLLM (SOSP '23) memory model in jax_graft form: decode K/V state
 lives in PAGES of ``page_size`` token slots, preallocated as one device
 pool per layer side — shape [n_layers, num_pages + 1, n_kv_heads,
-page_size, head_dim] (head-major inside a page: the decode kernel's
-block is one (page, kv-head) slab [page_size, head_dim], which is what
-the TPU compiler can tile). Inside a compiled program the pool keeps
-that one layout from parameter to result: ``write_rows`` stores a token's
+page_size, head_dim] (head-major inside a page: a (page, kv-head) slab
+[page_size, head_dim] is what the TPU compiler can tile, and a page's
+heads lie together, so the paged kernel's block is one page of several
+kv-heads in one contiguous piece). Inside a compiled program the pool
+keeps that one layout from parameter to result: ``write_rows`` stores a token's
 K/V rows with a scatter whose only window is ``head_dim`` (in place, no
 relayout), and the kernel takes the WHOLE pool with the layer in its
 BlockSpec index map (``ops.flash_attention_decode(..., layer=l)``), so no
