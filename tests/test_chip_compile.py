@@ -158,6 +158,75 @@ def test_flash_kernels_keep_their_names_under_remat_in_a_scan(one_chip, no_cache
                              "flash_fwd"]
 
 
+def test_flash_kernels_compile_at_latent_widths_for_v5e(one_chip, no_cache):
+    """Latent attention's shapes at the JoyAI cell's sizes: q and k 192 wide
+    (not a lane multiple: Mosaic pads it), v and o 128, 32 heads, two
+    8,192-token rows, forward and both backward kernels under their names."""
+    def spec(width):
+        return jax.ShapeDtypeStruct((2, 8192, 32, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        use, bq, bk = fa._dispatch(q, k, v, None, None, True, None)
+        assert use and (bq, bk) == (512, 1024)
+        out = fa._flash_lse(q, k, v, True, bq, bk, False)[0]
+        assert out.shape == (2, 8192, 32, 128)
+        return jnp.sum(out.astype(jnp.float32))
+
+    names = _kernel_names(jax.grad(loss, argnums=(0, 1, 2)),
+                          spec(192), spec(192), spec(128))
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum(kernel in n for n in names) == 1, (kernel, names)
+
+
+@pytest.mark.slow  # 74 s alone and 350 CPU-seconds of compiler threads: tier-1's time limit has no room for it
+def test_joyai_share_step_fits_v5e_at_the_cells_batch(topo, no_cache):
+    """The JoyAI-LLM-Flash share cell's WHOLE training step — the config
+    file's workload through ``Trainer``, the traffic mix's rows a step —
+    compiled for the described chip: 10.9 GB of state beside two 8,192-token
+    rows is what the compiler accepted (it refused three), so a change that
+    breaks the fit fails here. The flash kernels run at every one of the six
+    blocks, forward, replayed and backward, under their names."""
+    import json
+    import os
+    from unittest import mock
+
+    from tf_operator_tpu.models import transformer as tr
+    from tf_operator_tpu.parallel.mesh import build_mesh
+    from tf_operator_tpu.train.trainer import Trainer, TrainerConfig
+
+    home = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(home, "configs",
+                           "joyai-llm-flash-ep16share-train1.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(home, "traffic", "pretrain-8k-ep16share.json")) as f:
+        mix = json.load(f)
+    cfg = tr.preset_from_workload(config["workload"])
+    opt = config["optimizer"]
+    mesh = build_mesh(dict(config["mesh_axes"]), devices=list(topo.devices)[:1])
+    trainer = Trainer(
+        mesh,
+        loss_fn=lambda p, t, extra: tr.lm_loss_with_counters(
+            p, t, cfg, mesh=mesh, extra=extra),
+        init_fn=lambda k: (tr.init_transformer(k, cfg), tr.zero_moe_counters(cfg)),
+        logical_axes=tr.transformer_logical_axes(cfg),
+        config=TrainerConfig(
+            optimizer=opt["name"], learning_rate=opt["learning_rate"],
+            weight_decay=opt["weight_decay"], beta1=opt["beta1"],
+            beta2=opt["beta2"], grad_clip=opt["grad_clip"], fast_init_rng=False))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        compiled = trainer.compile_step(jax.ShapeDtypeStruct(
+            (int(mix["batch_size"]), int(mix["seq_len"])), "int32"))
+    names = _kernel_names_in(compiled.as_text())
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "gmm_"):
+        assert any(kernel in n for n in names), (kernel, sorted(set(names)))
+    # weights + two moments of 680,439,808 parameters, in and out in place
+    held = compiled.memory_analysis()
+    assert held.argument_size_in_bytes > 12 * cfg.n_params()
+    assert held.alias_size_in_bytes > 12 * cfg.n_params()
+
+
 # ---- paged decode: the kernel the serve engine cannot run without ---------
 
 

@@ -125,6 +125,48 @@ class TransformerConfig:
     # (parallel.moe._moe_single_gmm) and nothing for the absent ones.
     experts_held: int = 0
     expert_first: int = 0
+    # Attention kind: "gqa" (wq / wk / wv at one head width) or "latent"
+    # (DeepSeek-V2/V3's multi-head latent attention): q through a rank
+    # q_lora_rank bottleneck with its own RMSNorm, k and v through a shared
+    # rank kv_lora_rank one; a head's q and k are qk_nope_dim wide without
+    # position plus qk_rope_dim wide with rotary embedding (adjacent pairs,
+    # the key's rotary part ONE per token for all heads), its v v_head_dim
+    # wide; scores over sqrt(qk_nope_dim + qk_rope_dim).
+    attn_kind: str = "gqa"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # The first n_dense_lead of the n_layers have a dense SwiGLU MLP of
+    # width d_ff_dense where the others have experts: a section of its own
+    # in front of the scanned stack (params["lead"]).
+    n_dense_lead: int = 0
+    d_ff_dense: int = 0
+    # The router's scoring: "softmax" (the weights are the softmax's, over
+    # the chosen when k > 1) or "sigmoid" — s = sigmoid(logits), the top-k
+    # taken over s + b with b a per-expert BIAS that is model state and no
+    # trained leaf (router_bias: it rides TrainState.extra, and the loss
+    # returns b + router_bias_rate * sign(mean load - load_e)), the weights
+    # s of the chosen over their sum times router_scale. router_groups is
+    # the group-limited routing's group count; only 1 (no limit) runs.
+    router_score: str = "softmax"
+    router_bias: bool = False
+    router_bias_rate: float = 0.001
+    router_scale: float = 1.0
+    router_groups: int = 1
+    # Shared experts: a dense gated MLP of width n_shared_experts * d_ff
+    # beside the routed ones, computed for every token (under a share of
+    # the experts it is whole: every chip computes it for its own tokens).
+    n_shared_experts: int = 0
+    # Multi-token prediction (DeepSeek-V3): mtp_depth extra modules, each
+    # [norm(Emb(t_{i+1})) | norm(h_i)] · W_eh -> one whole expert layer ->
+    # its own final norm -> the SHARED head, scored against t_{i+2} and
+    # added to the loss with weight mtp_weight. Only depth 0 and 1 run.
+    mtp_depth: int = 0
+    mtp_weight: float = 0.3
+    # False: an output head of its own (params["head"], [vocab, d_model]).
+    tied_head: bool = True
 
     def __post_init__(self):
         if self.n_experts and not (1 <= self.moe_top_k <= self.n_experts):
@@ -154,10 +196,76 @@ class TransformerConfig:
                 raise ValueError(
                     "a share of the experts runs on moe_dispatch='gmm' only"
                 )
+        if self.attn_kind not in ("gqa", "latent"):
+            raise ValueError(f"unknown attn_kind {self.attn_kind!r}")
+        if self.attn_kind == "latent":
+            sizes = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_dim,
+                     self.qk_rope_dim, self.v_head_dim)
+            if min(sizes) <= 0 or self.qk_rope_dim % 2:
+                raise ValueError(
+                    f"latent attention needs its five sizes (got {sizes}), "
+                    "the rotary width even"
+                )
+            if self.attn_impl in ("ring", "ulysses"):
+                raise ValueError(
+                    f"latent attention runs on 'flash' or 'dense', not "
+                    f"{self.attn_impl!r}"
+                )
+            if any(w or not r for w, r in self.pattern):
+                raise ValueError(
+                    "latent attention runs global rotary layers only (no "
+                    "window, no NoPE layer)"
+                )
+            if self.n_kv_heads != self.n_heads:
+                raise ValueError("latent attention has one key a query head")
+        if not 0 <= self.n_dense_lead <= self.n_layers:
+            raise ValueError(f"n_dense_lead={self.n_dense_lead} of {self.n_layers}")
+        if self.n_dense_lead and not (self.n_experts and self.d_ff_dense):
+            raise ValueError(
+                "leading dense layers stand in front of EXPERT layers and "
+                "need their width d_ff_dense"
+            )
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router_score {self.router_score!r}")
+        if self.router_groups != 1:
+            raise ValueError(
+                f"router_groups={self.router_groups}: group-limited routing "
+                "is not implemented (one group only)"
+            )
+        if self.router_score == "sigmoid" or self.router_bias:
+            if self.moe_dispatch != "gmm" or not self.n_experts:
+                raise ValueError(
+                    "a sigmoid / bias-balanced router runs on experts "
+                    "dispatched by moe_dispatch='gmm' only"
+                )
+            if self.router_bias and self.router_score != "sigmoid":
+                raise ValueError("the router bias balances sigmoid scores")
+        if self.mtp_depth not in (0, 1):
+            raise ValueError(f"mtp_depth={self.mtp_depth}: 0 or 1 module runs")
+        if self.mtp_depth and not self.causal:
+            raise ValueError("multi-token prediction needs causal=True")
+        if self.stack_is_new and self.pp_microbatches:
+            raise ValueError(
+                "leading dense layers, a shared expert, the router bias, an "
+                "MTP module and an untied head are not stage-partitioned: "
+                "pp_microbatches must be 0"
+            )
 
     @property
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def stack_is_new(self) -> bool:
+        """Anything the pipelined stack does not partition."""
+        return bool(self.n_dense_lead or self.n_shared_experts
+                    or self.router_bias or self.mtp_depth
+                    or not self.tied_head or self.attn_kind == "latent")
+
+    @property
+    def n_stack_layers(self) -> int:
+        """Layers of the scanned stack (all of them without a dense lead)."""
+        return self.n_layers - self.n_dense_lead
 
     @property
     def pattern(self) -> tuple:
@@ -172,12 +280,26 @@ class TransformerConfig:
     def n_params(self) -> int:
         """Parameter count (for MFU accounting): what this program holds."""
         d, f, v, L = self.d_model, self.d_ff, self.vocab, self.n_layers
-        q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
+        if self.attn_kind == "latent":
+            nh, qr, kvr = self.n_heads, self.q_lora_rank, self.kv_lora_rank
+            qk = self.qk_nope_dim + self.qk_rope_dim
+            attn = (d * qr + qr + qr * nh * qk + d * (kvr + self.qk_rope_dim)
+                    + kvr + kvr * nh * (self.qk_nope_dim + self.v_head_dim)
+                    + nh * self.v_head_dim * d)
+        else:
+            q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
+            attn = d * q + 2 * d * kv + q * d
         mlp = 3 * d * f
         if self.n_experts:
-            mlp = self.n_held * mlp + d * self.n_experts  # experts + router
-        per_layer = d * q + 2 * d * kv + q * d + mlp + 2 * d  # qkv+o+mlp+norms
-        return v * d + L * per_layer + d  # embed + layers + final norm
+            # experts + router + the shared expert
+            mlp = (self.n_held + self.n_shared_experts) * mlp + d * self.n_experts
+        per_layer = attn + mlp + 2 * d  # attention + mlp + norms
+        lead = self.n_dense_lead * (attn + 3 * d * self.d_ff_dense + 2 * d)
+        # an MTP module: two norms, W_eh, one expert layer, its final norm
+        mtp = self.mtp_depth * (2 * d + 2 * d * d + per_layer + d)
+        head = 0 if self.tied_head else v * d
+        # embed + lead + layers + final norm + mtp + head
+        return v * d + lead + self.n_stack_layers * per_layer + d + mtp + head
 
     def n_active_params(self) -> int:
         """Params touched per token (= n_params for dense; top-k MoE
@@ -185,7 +307,8 @@ class TransformerConfig:
         on average) — the right N for 6ND FLOP accounting."""
         if not self.n_experts:
             return self.n_params()
-        d, f, L = self.d_model, self.d_ff, self.n_layers
+        d, f = self.d_model, self.d_ff
+        L = self.n_stack_layers + self.mtp_depth  # every expert layer
         active = self.moe_top_k * self.n_held / self.n_experts
         return self.n_params() - int(L * (self.n_held - active) * 3 * d * f)
 
@@ -276,6 +399,25 @@ PRESETS: Dict[str, TransformerConfig] = {
         layer_pattern=((0, False), (4096, True), (4096, True), (4096, True)),
         expert_act="relu", router_input="attn_norm", router_f32=True,
     ),
+    # JoyAI-LLM-Flash (jdopensource; config.json on the hub; 48B-A2.7B):
+    # latent attention (32 heads, q/k 128 + 64 rotary = 192 wide, v 128,
+    # ranks 1536 / 512), one leading dense layer of width 7168 before 39
+    # expert layers — a sigmoid top-8 router over 256 SwiGLU experts of
+    # width 768, balanced by a bias, scale 2.5, beside one shared expert —
+    # one multi-token-prediction module, an untied head. One chip trains a
+    # SHARE of it (benchmarks/configs/joyai-llm-flash-ep16share-train1.json).
+    "joyai-llm-flash": TransformerConfig(
+        vocab=129280, d_model=2048, n_layers=40, n_heads=32, n_kv_heads=32,
+        d_ff=768, max_seq=131072, rope_theta=3.2e7, norm_eps=1e-6,
+        attn_kind="latent", q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+        n_dense_lead=1, d_ff_dense=7168,
+        n_experts=256, moe_top_k=8, moe_dispatch="gmm", router_f32=True,
+        router_score="sigmoid", router_bias=True, router_bias_rate=0.001,
+        router_scale=2.5, n_shared_experts=1,
+        moe_aux_weight=0.0, moe_zloss_weight=0.0,
+        mtp_depth=1, mtp_weight=0.3, tied_head=False,
+    ),
 }
 
 
@@ -284,32 +426,43 @@ PRESETS: Dict[str, TransformerConfig] = {
 # ---------------------------------------------------------------------------
 
 
-def init_transformer(key, cfg: TransformerConfig) -> Dict[str, Any]:
-    """Initialize params (f32). Layer params are stacked on a leading
-    [n_layers] axis for the scan."""
-    d, f = cfg.d_model, cfg.d_ff
+def _init_layers(key, cfg: TransformerConfig, L: int, dense: bool) -> Dict[str, Any]:
+    """``L`` stacked layers of one shape: dense MLPs (of width d_ff_dense
+    when the model also has experts: its leading section) or expert layers.
+    The leaves today's models have draw keys 0..7 of ``key``'s split, as
+    they always did; the latent attention's and the shared expert's draw
+    from a second split (``fold_in(key, 8)``)."""
+    d = cfg.d_model
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    L = cfg.n_layers
-    k_embed, k_layers = jax.random.split(key)
-
-    def norm_init(k, *shape):
-        del k
-        return jnp.ones(shape, jnp.float32)
 
     def dense_init(k, fan_in, *shape):
         return jax.random.normal(k, shape, jnp.float32) * (fan_in**-0.5)
 
-    ks = jax.random.split(k_layers, 8)
-    layers = {
-        "attn_norm": jnp.ones((L, d), jnp.float32),
-        "wq": dense_init(ks[0], d, L, d, nh * hd),
-        "wk": dense_init(ks[1], d, L, d, nkv * hd),
-        "wv": dense_init(ks[2], d, L, d, nkv * hd),
-        "wo": dense_init(ks[3], nh * hd, L, nh * hd, d),
-        "mlp_norm": jnp.ones((L, d), jnp.float32),
-    }
-    if cfg.n_experts:
-        E = cfg.n_held  # the router scores all n_experts, the weights are the held
+    ks = jax.random.split(key, 8)
+    kx = jax.random.split(jax.random.fold_in(key, 8), 8)
+    layers = {"attn_norm": jnp.ones((L, d), jnp.float32)}
+    if cfg.attn_kind == "latent":
+        qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
+        qk, dv = cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.v_head_dim
+        layers.update({
+            "wq_a": dense_init(kx[0], d, L, d, qr),
+            "q_norm": jnp.ones((L, qr), jnp.float32),
+            "wq_b": dense_init(kx[1], qr, L, qr, nh * qk),
+            "wkv_a": dense_init(kx[2], d, L, d, kvr + cfg.qk_rope_dim),
+            "kv_norm": jnp.ones((L, kvr), jnp.float32),
+            "wkv_b": dense_init(kx[3], kvr, L, kvr, nh * (cfg.qk_nope_dim + dv)),
+            "wo": dense_init(ks[3], nh * dv, L, nh * dv, d),
+        })
+    else:
+        layers.update({
+            "wq": dense_init(ks[0], d, L, d, nh * hd),
+            "wk": dense_init(ks[1], d, L, d, nkv * hd),
+            "wv": dense_init(ks[2], d, L, d, nkv * hd),
+            "wo": dense_init(ks[3], nh * hd, L, nh * hd, d),
+        })
+    layers["mlp_norm"] = jnp.ones((L, d), jnp.float32)
+    if cfg.n_experts and not dense:
+        E, f = cfg.n_held, cfg.d_ff  # the router scores all n_experts, the weights are the held
         layers.update(
             {
                 "w_router": dense_init(ks[7], d, L, d, cfg.n_experts),
@@ -318,7 +471,15 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict[str, Any]:
                 "w_down": dense_init(ks[6], f, L, E, f, d),
             }
         )
+        if cfg.n_shared_experts:
+            fs = cfg.n_shared_experts * f
+            layers.update({
+                "ws_gate": dense_init(kx[4], d, L, d, fs),
+                "ws_up": dense_init(kx[5], d, L, d, fs),
+                "ws_down": dense_init(kx[6], fs, L, fs, d),
+            })
     else:
+        f = cfg.d_ff_dense if dense and cfg.n_experts else cfg.d_ff
         layers.update(
             {
                 "w_gate": dense_init(ks[4], d, L, d, f),
@@ -326,25 +487,66 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict[str, Any]:
                 "w_down": dense_init(ks[6], f, L, f, d),
             }
         )
+    return layers
+
+
+def init_transformer(key, cfg: TransformerConfig) -> Dict[str, Any]:
+    """Initialize params (f32). Layer params are stacked on a leading
+    [layers] axis for the scan: ``layers`` (all of them, or the expert
+    layers behind a dense lead), ``lead`` (the leading dense layers),
+    ``mtp`` (the multi-token-prediction module, its one layer stacked [1]),
+    ``head`` (an untied output head)."""
+    d = cfg.d_model
+    k_embed, k_layers = jax.random.split(key)
     params = {
         "embed": jax.random.normal(k_embed, (cfg.vocab, d), jnp.float32) * 0.02,
         "final_norm": jnp.ones((d,), jnp.float32),
-        "layers": layers,
+        "layers": _init_layers(k_layers, cfg, cfg.n_stack_layers, dense=False),
     }
+    if cfg.n_dense_lead:
+        params["lead"] = _init_layers(
+            jax.random.fold_in(key, 1), cfg, cfg.n_dense_lead, dense=True)
+    if cfg.mtp_depth:
+        k_mtp = jax.random.fold_in(key, 2)
+        params["mtp"] = {
+            "norm_e": jnp.ones((d,), jnp.float32),
+            "norm_h": jnp.ones((d,), jnp.float32),
+            "w_eh": jax.random.normal(
+                jax.random.fold_in(k_mtp, 0), (2 * d, d), jnp.float32
+            ) * (2 * d) ** -0.5,
+            "layer": _init_layers(
+                jax.random.fold_in(k_mtp, 1), cfg, 1, dense=not cfg.n_experts),
+            "final_norm": jnp.ones((d,), jnp.float32),
+        }
+    if not cfg.tied_head:
+        params["head"] = jax.random.normal(
+            jax.random.fold_in(key, 3), (cfg.vocab, d), jnp.float32) * 0.02
     return params
 
 
-def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
-    """Logical axis names per param leaf (same tree structure as params)."""
-    layers = {
-        "attn_norm": ("layers", "embed"),
-        "wq": ("layers", "embed", "heads"),
-        "wk": ("layers", "embed", "kv_heads"),
-        "wv": ("layers", "embed", "kv_heads"),
-        "wo": ("layers", "heads", "embed"),
-        "mlp_norm": ("layers", "embed"),
-    }
-    if cfg.n_experts:
+def _layer_axes(cfg: TransformerConfig, dense: bool) -> Dict[str, Any]:
+    """Logical axes of one stacked layer section (as _init_layers)."""
+    layers = {"attn_norm": ("layers", "embed")}
+    if cfg.attn_kind == "latent":
+        # the low-rank dims stay whole; the per-head dims shard as heads
+        layers.update({
+            "wq_a": ("layers", "embed", None),
+            "q_norm": ("layers", None),
+            "wq_b": ("layers", None, "heads"),
+            "wkv_a": ("layers", "embed", None),
+            "kv_norm": ("layers", None),
+            "wkv_b": ("layers", None, "heads"),
+            "wo": ("layers", "heads", "embed"),
+        })
+    else:
+        layers.update({
+            "wq": ("layers", "embed", "heads"),
+            "wk": ("layers", "embed", "kv_heads"),
+            "wv": ("layers", "embed", "kv_heads"),
+            "wo": ("layers", "heads", "embed"),
+        })
+    layers["mlp_norm"] = ("layers", "embed")
+    if cfg.n_experts and not dense:
         layers.update(
             {
                 "w_router": ("layers", "embed", "expert"),
@@ -353,6 +555,12 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                 "w_down": ("layers", "expert", "mlp", "embed"),
             }
         )
+        if cfg.n_shared_experts:
+            layers.update({
+                "ws_gate": ("layers", "embed", "mlp"),
+                "ws_up": ("layers", "embed", "mlp"),
+                "ws_down": ("layers", "mlp", "embed"),
+            })
     else:
         layers.update(
             {
@@ -361,11 +569,28 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                 "w_down": ("layers", "mlp", "embed"),
             }
         )
-    return {
+    return layers
+
+
+def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
+    """Logical axis names per param leaf (same tree structure as params)."""
+    axes = {
         "embed": ("vocab", "embed"),
         "final_norm": ("embed",),
-        "layers": layers,
+        "layers": _layer_axes(cfg, dense=False),
     }
+    if cfg.n_dense_lead:
+        axes["lead"] = _layer_axes(cfg, dense=True)
+    if cfg.mtp_depth:
+        axes["mtp"] = {
+            "norm_e": ("embed",), "norm_h": ("embed",),
+            "w_eh": (None, "embed"),
+            "layer": _layer_axes(cfg, dense=not cfg.n_experts),
+            "final_norm": ("embed",),
+        }
+    if not cfg.tied_head:
+        axes["head"] = ("vocab", "embed")
+    return axes
 
 
 # ---------------------------------------------------------------------------
@@ -390,6 +615,47 @@ def _rope(x, theta: float):
     sin = jnp.sin(angles)[None, :, None, :].astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def _rope_pairs(x, theta: float):
+    """Rotary embedding over ADJACENT pairs (x[2i], x[2i+1]) — the layout
+    of a ``rope_interleave`` checkpoint. x: [b, t, h, d]. The rotated pairs
+    leave de-interleaved (all first members, then all second): q and k go
+    through the same permutation, which no score sees."""
+    half = x.shape[-1] // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freqs[None, :]
+    cos = jnp.cos(angles)[None, :, None, :].astype(x.dtype)
+    sin = jnp.sin(angles)[None, :, None, :].astype(x.dtype)
+    pairs = x.reshape(x.shape[:-1] + (half, 2))
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def _latent_qkv(h, layer_params, cfg: TransformerConfig):
+    """Latent attention's projections: h [b, t, d] -> q, k [b, t, nh,
+    nope + rope], v [b, t, nh, v_head_dim]. q goes through the rank
+    q_lora_rank bottleneck and its RMSNorm; k's position-free part and v
+    come up from the shared rank kv_lora_rank latent (normed); the rotary
+    part of k is ONE [b, t, rope] vector a token, read beside the latent
+    from the same projection and repeated for every head."""
+    b, t, _ = h.shape
+    nh, nope, rope = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    dt = h.dtype
+    c_q = _rms_norm(h @ layer_params["wq_a"].astype(dt),
+                    layer_params["q_norm"], cfg.norm_eps)
+    q = (c_q @ layer_params["wq_b"].astype(dt)).reshape(b, t, nh, nope + rope)
+    ckv = h @ layer_params["wkv_a"].astype(dt)  # [b, t, kv_rank | rope]
+    c_kv = _rms_norm(ckv[..., :cfg.kv_lora_rank], layer_params["kv_norm"],
+                     cfg.norm_eps)
+    k_r = _rope_pairs(ckv[..., None, cfg.kv_lora_rank:], cfg.rope_theta)
+    kv = (c_kv @ layer_params["wkv_b"].astype(dt)).reshape(
+        b, t, nh, nope + cfg.v_head_dim)
+    q = jnp.concatenate(
+        [q[..., :nope], _rope_pairs(q[..., nope:], cfg.rope_theta)], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_r, (b, t, nh, rope))], axis=-1)
+    return q, k, kv[..., nope:]
 
 
 def rope_at_positions(x, positions, theta: float):
@@ -519,9 +785,10 @@ def _anchored_gamma(gamma, cfg: TransformerConfig, mesh):
 
 def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
            tp_manual_vjp=True, local_ep_axis: Optional[str] = None,
-           kind: tuple = (0, True)):
+           kind: tuple = (0, True), dense: bool = False):
     """One decoder layer. ``kind`` = (window, rotary): this layer's entry
-    of cfg.pattern, static. ``tp_axis`` (pipeline tp-within-stage, r3):
+    of cfg.pattern, static. ``dense``: a dense-MLP layer of a model that
+    has experts (its leading section), static too. ``tp_axis`` (pipeline tp-within-stage, r3):
     weights arrive as tp-LOCAL shards (wq/wk/wv/w_gate/w_up
     column-parallel, wo/w_down row-parallel — the Megatron split).
 
@@ -551,9 +818,11 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
             leave = lambda a: jax.lax.psum(a, tp_axis)  # noqa: E731
     b, t, d = x.shape
     hd = cfg.head_dim
-    wq = layer_params["wq"].astype(x.dtype)
-    wk = layer_params["wk"].astype(x.dtype)
-    wv = layer_params["wv"].astype(x.dtype)
+    latent = cfg.attn_kind == "latent"
+    if not latent:
+        wq = layer_params["wq"].astype(x.dtype)
+        wk = layer_params["wk"].astype(x.dtype)
+        wv = layer_params["wv"].astype(x.dtype)
     gamma_attn = _anchored_gamma(layer_params["attn_norm"], cfg, mesh)
     gamma_mlp = _anchored_gamma(layer_params["mlp_norm"], cfg, mesh)
 
@@ -580,18 +849,22 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
     h = anchor_tokens(_rms_norm(x, gamma_attn, cfg.norm_eps))
     if tp_axis is not None:
         h = enter(h)
-    q = (h @ wq).reshape(b, t, wq.shape[-1] // hd, hd)
-    k = (h @ wk).reshape(b, t, wk.shape[-1] // hd, hd)
-    v = (h @ wv).reshape(b, t, wv.shape[-1] // hd, hd)
     window, rotary = kind
-    if rotary:
-        q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+    if latent:
+        q, k, v = _latent_qkv(h, layer_params, cfg)
+    else:
+        q = (h @ wq).reshape(b, t, wq.shape[-1] // hd, hd)
+        k = (h @ wk).reshape(b, t, wk.shape[-1] // hd, hd)
+        v = (h @ wv).reshape(b, t, wv.shape[-1] // hd, hd)
+        if rotary:
+            q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
     gate_logits = None
-    if cfg.n_experts and cfg.router_input == "attn_norm":
+    if cfg.n_experts and not dense and cfg.router_input == "attn_norm":
         # the router sits BEFORE attention: it scores the same normalised
         # tensor attention reads
         gate_logits = _router_logits(h, layer_params, cfg)
-    attn = _attention(q, k, v, cfg, mesh, window).reshape(b, t, wq.shape[-1])
+    attn = _attention(q, k, v, cfg, mesh, window).reshape(
+        b, t, layer_params["wo"].shape[-2])
     proj = attn @ layer_params["wo"].astype(x.dtype)
     if tp_axis is not None:
         proj = leave(proj)
@@ -601,10 +874,17 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
     x = checkpoint_name(x + proj, "resid_mid")
 
     h = anchor_tokens(_rms_norm(x, gamma_mlp, cfg.norm_eps))
-    if cfg.n_experts:
+    if cfg.n_experts and not dense:
         moe_out, aux = _moe_mlp(h, layer_params, cfg, mesh,
                                 local_ep_axis=local_ep_axis,
                                 gate_logits=gate_logits)
+        if cfg.n_shared_experts:
+            # the shared expert: every token, whole on every chip of a share
+            dt = x.dtype
+            moe_out = moe_out + (
+                jax.nn.silu(h @ layer_params["ws_gate"].astype(dt))
+                * (h @ layer_params["ws_up"].astype(dt))
+            ) @ layer_params["ws_down"].astype(dt)
         return x + moe_out, aux
     if tp_axis is not None:
         h = enter(h)
@@ -632,6 +912,19 @@ def _router_logits(h, layer_params, cfg: TransformerConfig):
         return jnp.dot(flat.astype(jnp.float32), layer_params["w_router"],
                        precision=jax.lax.Precision.HIGHEST)
     return flat @ layer_params["w_router"].astype(h.dtype)
+
+
+def _router_kwargs(layer_params, cfg: TransformerConfig) -> Dict[str, Any]:
+    """moe_apply's router arguments beyond the softmax default ({} for it,
+    so today's configs make today's call): the sigmoid score, its scale,
+    and the layer's balancing bias — state the caller laid beside the
+    layer's weights as ``router_bias`` (zeros when it handed none)."""
+    if cfg.router_score == "softmax":
+        return {}
+    out = {"score": cfg.router_score, "scale": cfg.router_scale}
+    if cfg.router_bias and "router_bias" in layer_params:
+        out["bias"] = layer_params["router_bias"]
+    return out
 
 
 def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
@@ -711,6 +1004,7 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
             dispatch_impl=cfg.moe_dispatch,
             expert_act=cfg.expert_act,
             expert_first=cfg.expert_first,
+            **_router_kwargs(layer_params, cfg),
         )
     # Switch load-balance loss: E * Σ_e f_e·P_e. f_e (expert_load) comes
     # out of the discrete top-k assignment, so it carries no gradient and
@@ -730,6 +1024,8 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
         "drop_frac": stats["drop_frac"],
     }
     aux.update({k: stats[k] for k in MOE_COUNTERS if k in stats})
+    if cfg.router_bias:
+        aux["expert_count"] = stats["expert_count"]  # [E]: the bias update's input
     out = out.reshape(b, t, d)
     if local_ep_axis is None and mesh is not None and getattr(
         mesh, "devices", None
@@ -1029,8 +1325,12 @@ def transformer_hidden_pp(params, tokens, cfg: TransformerConfig, mesh):
 
 
 def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
-                       with_aux: bool = False):
+                       with_aux: bool = False, router_bias=None):
     """tokens: [b, t] int32 -> final-norm hidden states [b, t, d] (cfg.dtype).
+
+    ``router_bias`` [expert layers, E] float32: the balancing bias of a
+    bias-balanced router, one row a scanned layer (state, not a parameter;
+    None: zeros). ``aux`` then also carries ``expert_count`` [L, E].
 
     ``with_aux`` also returns the MoE router aux dict (None for dense):
     {"lb_loss", "z_loss" — mean over layers, unweighted;
@@ -1104,10 +1404,23 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
         _bwd_replicate.defvjp(_br_fwd, _br_bwd)
         x = _bwd_replicate(x)
 
+    # The leading dense layers: another SHAPE than the scanned stack's, so
+    # a section of their own in front of it, each under its own remat.
+    for j in range(cfg.n_dense_lead):
+        lead_fn = _remat_wrap(
+            partial(_layer, cfg=cfg, mesh=mesh, kind=cfg.pattern[0], dense=True),
+            cfg)
+        x, _ = lead_fn(x, jax.tree_util.tree_map(lambda a: a[j], params["lead"]))
+
     # One scan step is one PERIOD of the layer pattern, its layers
     # unrolled, each with its own static (window, rotary) and its own
     # remat boundary; a pattern of one entry is the plain per-layer scan.
     pattern = cfg.pattern
+    stack = params["layers"]
+    if cfg.router_bias and router_bias is not None:
+        # the bias rides the scan beside the layer's weights (it only picks
+        # the top-k's indices, so no gradient reaches it)
+        stack = dict(stack, router_bias=router_bias)
     layer_fns = [
         _remat_wrap(partial(_layer, cfg=cfg, mesh=mesh, kind=kind), cfg)
         for kind in pattern
@@ -1130,7 +1443,7 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
         # custom calls against 3,919 and 39 at the dense 7B step), and the
         # dense cells are held to the program they had
         x, aux_stack = jax.lax.scan(
-            partial(one_layer, layer_fns[0]), x, params["layers"])
+            partial(one_layer, layer_fns[0]), x, stack)
     else:
         P = len(pattern)
 
@@ -1148,11 +1461,11 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
         x, aux_stack = jax.lax.scan(
             period_body, x,
             jax.tree_util.tree_map(
-                lambda a: a.reshape((cfg.n_layers // P, P) + a.shape[1:]),
-                params["layers"]))
+                lambda a: a.reshape((cfg.n_stack_layers // P, P) + a.shape[1:]),
+                stack))
         if aux_stack is not None:  # [periods, P, ...] -> [L, ...]
             aux_stack = jax.tree_util.tree_map(
-                lambda a: a.reshape((cfg.n_layers,) + a.shape[2:]), aux_stack)
+                lambda a: a.reshape((cfg.n_stack_layers,) + a.shape[2:]), aux_stack)
     if carry_anchor is not None:
         # exit anchor: pins the BACKWARD scan's carry init too — the
         # transpose of this constraint re-anchors the loss head's
@@ -1174,21 +1487,57 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
     }
     # routing counters (gmm dispatch): one scalar a step, summed over layers
     aux.update({k: jnp.sum(aux_stack[k]) for k in MOE_COUNTERS if k in aux_stack})
+    if "expert_count" in aux_stack:
+        aux["expert_count"] = aux_stack["expert_count"]  # [L, E]
     return h, aux
+
+
+def mtp_hidden(params, tokens, h, cfg: TransformerConfig, mesh=None,
+               router_bias=None):
+    """The multi-token-prediction module (DeepSeek-V3's, depth 1): for the
+    main model's final-normed states h [b, t, d] of tokens t_0..t_{T-1},
+    x_i = [RMSNorm_e(Emb(t_{i+1})) | RMSNorm_h(h_i)] · W_eh, one whole layer
+    of the stack's kind over x (its own router bias ``router_bias`` [1, E]),
+    its own final norm. Returns (states [b, t, d] whose row i predicts
+    t_{i+2}, the layer's aux or None).
+
+    Every row of T positions goes through, the last two fed the row's own
+    first tokens (a roll): under causal attention they reach no other
+    position and the loss leaves them out, and T stays the length the
+    flash kernels tile."""
+    m = params["mtp"]
+    dt = cfg.dtype
+    e_next = params["embed"].astype(dt)[jnp.roll(tokens, -1, axis=1)]
+    x = jnp.concatenate(
+        [_rms_norm(e_next, m["norm_e"], cfg.norm_eps),
+         _rms_norm(h, m["norm_h"], cfg.norm_eps)], axis=-1) @ m["w_eh"].astype(dt)
+    lp = jax.tree_util.tree_map(lambda a: a[0], m["layer"])
+    if cfg.router_bias and router_bias is not None:
+        lp = dict(lp, router_bias=router_bias[0])
+    layer_fn = _remat_wrap(
+        partial(_layer, cfg=cfg, mesh=mesh, kind=cfg.pattern[0],
+                dense=not cfg.n_experts), cfg)
+    x, aux = layer_fn(x, lp)
+    return _rms_norm(x, m["final_norm"], cfg.norm_eps), aux
 
 
 def transformer_forward(params, tokens, cfg: TransformerConfig, mesh=None):
     """tokens: [b, t] int32 -> logits [b, t, vocab] (f32)."""
     x = transformer_hidden(params, tokens, cfg, mesh)
-    # tied output head: embed^T
-    return (x @ params["embed"].astype(cfg.dtype).T).astype(jnp.float32)
+    # the output head: embed^T when tied
+    return (x @ _head(params, cfg).astype(cfg.dtype).T).astype(jnp.float32)
+
+
+def _head(params, cfg: TransformerConfig):
+    """The [vocab, d] output head: the embedding when tied."""
+    return params["embed"] if cfg.tied_head else params["head"]
 
 
 MASK_TOKEN = 0
 
 
 def lm_loss_and_metrics(params, tokens, cfg: TransformerConfig, mesh=None, key=None,
-                        mask_rate=0.15):
+                        mask_rate=0.15, router_bias=None):
     """Causal: next-token cross entropy. Bidirectional (BERT-class): masked
     language modeling — ``mask_rate`` of positions are replaced with
     MASK_TOKEN and only those positions contribute to the loss (training on
@@ -1197,9 +1546,25 @@ def lm_loss_and_metrics(params, tokens, cfg: TransformerConfig, mesh=None, key=N
     Returns (total_loss, metrics). For MoE configs the total includes the
     weighted router losses and metrics carries the router telemetry:
     ce_loss, moe_lb_loss, moe_z_loss (unweighted), moe_expert_entropy
-    (mean over layers, nats — uniform routing = ln(E)), moe_drop_frac."""
+    (mean over layers, nats — uniform routing = ln(E)), moe_drop_frac.
+
+    With an MTP module the total is ``ce + mtp_weight · ce_mtp`` and
+    metrics carries both (``loss_main``, ``loss_mtp``); the routing
+    counters then sum over the module's expert layer too. With a
+    bias-balanced router, ``router_bias`` ({"layers": [L, E], "mtp":
+    [1, E]}, None: zeros) steers this step's choices and metrics carries
+    the NEXT step's under the same key — b + rate · sign(mean load −
+    load_e), loads counted over this call's tokens and all E outputs — with
+    ``moe_bias_abs_max`` and ``moe_all_load_max_over_mean``."""
+    if cfg.router_bias and router_bias is None:
+        router_bias = zero_router_bias(cfg)
+
     def _hidden(inp):
-        return transformer_hidden(params, inp, cfg, mesh, with_aux=True)
+        return transformer_hidden(
+            params, inp, cfg, mesh, with_aux=True,
+            router_bias=router_bias["layers"] if cfg.router_bias else None)
+
+    head = _head(params, cfg)
 
     def _ce_operands(flat_h, embed):
         # MoE on a multi-axis mesh (r6): pin the fused-CE block walk to
@@ -1231,55 +1596,44 @@ def lm_loss_and_metrics(params, tokens, cfg: TransformerConfig, mesh=None, key=N
             embed, NamedSharding(mesh, P(None, None)))
         return flat_h, embed
 
-    if cfg.causal:
+    def _ce(h, targets, weights=None):
+        """Mean cross-entropy of h [b, t, d] through the head against
+        targets [b, t] (weights [b, t]: a weighted mean)."""
+        b, t, d = h.shape
         if cfg.fused_xent:
             from tf_operator_tpu.ops.fused_cross_entropy import fused_cross_entropy
 
-            h, aux = _hidden(tokens)
-            h = h[:, :-1]
-            b, t, d = h.shape
-            ce = fused_cross_entropy(
-                *_ce_operands(h.reshape(b * t, d), params["embed"]),
-                tokens[:, 1:].reshape(b * t),
+            return fused_cross_entropy(
+                *_ce_operands(h.reshape(b * t, d), head), targets.reshape(b * t),
+                None if weights is None else weights.reshape(b * t),
             )
-        else:
-            h, aux = _hidden(tokens)
-            logits = (h @ params["embed"].astype(cfg.dtype).T).astype(jnp.float32)
-            targets = tokens[:, 1:]
-            logits = logits[:, :-1]
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-            ce = -jnp.mean(ll)
+        logits = (h @ head.astype(cfg.dtype).T).astype(jnp.float32)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        if weights is None:
+            return -jnp.mean(ll)
+        return -jnp.sum(ll * weights) / jnp.maximum(jnp.sum(weights), 1)
+
+    if cfg.causal:
+        h_all, aux = _hidden(tokens)
+        ce = _ce(h_all[:, :-1], tokens[:, 1:])
     else:
         if key is None:
             key = jax.random.PRNGKey(0)
         mask = jax.random.bernoulli(key, mask_rate, tokens.shape)
         inputs = jnp.where(mask, MASK_TOKEN, tokens)
-        h, aux = _hidden(inputs)
-        if cfg.fused_xent:
-            from tf_operator_tpu.ops.fused_cross_entropy import fused_cross_entropy
-
-            b, t, d = h.shape
-            ce = fused_cross_entropy(
-                *_ce_operands(h.reshape(b * t, d), params["embed"]),
-                tokens.reshape(b * t),
-                weights=mask.reshape(b * t),
-            )
-        else:
-            logits = (h @ params["embed"].astype(cfg.dtype).T).astype(jnp.float32)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            ll = jnp.take_along_axis(logp, tokens[..., None], axis=-1)[..., 0]
-            denom = jnp.maximum(jnp.sum(mask), 1)
-            ce = -jnp.sum(ll * mask) / denom
+        h_all, aux = _hidden(inputs)
+        ce = _ce(h_all, tokens, mask)
 
     metrics = {"ce_loss": ce}
     total = ce
     if aux is not None:
-        total = (
-            ce
-            + cfg.moe_aux_weight * aux["lb_loss"]
-            + cfg.moe_zloss_weight * aux["z_loss"]
-        )
+        if cfg.moe_aux_weight or cfg.moe_zloss_weight:
+            total = (
+                ce
+                + cfg.moe_aux_weight * aux["lb_loss"]
+                + cfg.moe_zloss_weight * aux["z_loss"]
+            )
         metrics.update(moe_lb_loss=aux["lb_loss"], moe_z_loss=aux["z_loss"])
         if aux.get("expert_load") is not None:
             # per-layer router telemetry (absent under pipeline parallelism
@@ -1292,7 +1646,49 @@ def lm_loss_and_metrics(params, tokens, cfg: TransformerConfig, mesh=None, key=N
                 moe_drop_frac=jnp.mean(aux["drop_frac"]),
             )
         metrics.update({f"moe_{k}": aux[k] for k in MOE_COUNTERS if k in aux})
+
+    counts = None if aux is None else aux.get("expert_count")  # [L, E] int32
+    if cfg.mtp_depth:
+        h_mtp, aux_mtp = mtp_hidden(
+            params, tokens, h_all, cfg, mesh,
+            router_bias["mtp"] if cfg.router_bias else None)
+        t = tokens.shape[1]
+        live = jnp.broadcast_to(jnp.arange(t) < t - 2, tokens.shape)
+        ce_mtp = _ce(h_mtp, jnp.roll(tokens, -2, axis=1), live)
+        total = total + cfg.mtp_weight * ce_mtp
+        metrics.update(loss_main=ce, loss_mtp=ce_mtp)
+        if aux_mtp is not None:
+            for k in MOE_COUNTERS:
+                if k in aux_mtp and f"moe_{k}" in metrics:
+                    metrics[f"moe_{k}"] = metrics[f"moe_{k}"] + aux_mtp[k]
+            if counts is not None:
+                counts = jnp.concatenate(
+                    [counts, aux_mtp["expert_count"][None]], axis=0)
+    if cfg.router_bias and counts is not None:
+        metrics.update(_next_router_bias(cfg, router_bias, counts))
     return total, metrics
+
+
+def _next_router_bias(cfg: TransformerConfig, bias, counts) -> Dict[str, Any]:
+    """The balancing bias after a step whose routers chose ``counts`` [expert
+    layers (+ the module's), E] int32: b + rate * sign(mean load - load_e),
+    compared in whole numbers (a layer's choices over E against E times an
+    expert's own), and the two scalars that describe it."""
+    per_layer = jnp.sum(counts, axis=-1, keepdims=True)
+    step = cfg.router_bias_rate * jnp.sign(
+        per_layer - counts * cfg.n_experts).astype(jnp.float32)
+    n = cfg.n_stack_layers
+    new_bias = {"layers": bias["layers"] + step[:n]}
+    if cfg.mtp_depth:
+        new_bias["mtp"] = bias["mtp"] + step[n:]
+    load = counts.astype(jnp.float32)
+    return dict(
+        router_bias=new_bias,
+        moe_bias_abs_max=jnp.max(jnp.stack(
+            [jnp.max(jnp.abs(v)) for v in new_bias.values()])),
+        moe_all_load_max_over_mean=jnp.sum(jnp.max(load, axis=-1))
+        / jnp.sum(jnp.mean(load, axis=-1)),
+    )
 
 
 def lm_loss(params, tokens, cfg: TransformerConfig, mesh=None, key=None, mask_rate=0.15):
@@ -1302,30 +1698,56 @@ def lm_loss(params, tokens, cfg: TransformerConfig, mesh=None, key=None, mask_ra
     return total
 
 
+def zero_router_bias(cfg: TransformerConfig) -> Dict[str, Any]:
+    """The balancing bias a bias-balanced router starts from: zeros, one
+    row an expert layer of the stack and of the MTP module."""
+    bias = {"layers": jnp.zeros((cfg.n_stack_layers, cfg.n_experts), jnp.float32)}
+    if cfg.mtp_depth:
+        bias["mtp"] = jnp.zeros((cfg.mtp_depth, cfg.n_experts), jnp.float32)
+    return bias
+
+
 def moe_counter_names(cfg: TransformerConfig, mesh=None) -> tuple:
-    """The ``moe_*`` routing counters a step of this config returns beside
-    its loss: gmm dispatch on the one-device expert path (no exchange
-    over an ep axis, no pipeline); () otherwise."""
+    """The device scalars a step of this config returns beside its loss:
+    the ``moe_*`` routing counters for gmm dispatch on the one-device
+    expert path (no exchange over an ep axis, no pipeline), there also
+    both partial losses of a model with an MTP module and the two numbers
+    of a router bias; () otherwise."""
     sharded = mesh is not None and any(
         mesh.shape.get(a, 1) > 1 for a in (cfg.ep_axis, cfg.pp_axis))
     if not cfg.n_experts or cfg.moe_dispatch != "gmm" or sharded:
         return ()
-    return tuple(f"moe_{k}" for k in MOE_COUNTERS)
+    names = tuple(f"moe_{k}" for k in MOE_COUNTERS)
+    if cfg.mtp_depth:
+        names += ("loss_main", "loss_mtp")
+    if cfg.router_bias:
+        names += ("moe_bias_abs_max", "moe_all_load_max_over_mean")
+    return names
 
 
-def lm_loss_with_counters(params, tokens, cfg: TransformerConfig, mesh=None):
-    """(loss, counters) in the shape ``Trainer`` takes as (loss,
-    new_extra): the routing counters of this step — device scalars, one
-    per name of moe_counter_names(cfg) — leave the step beside the loss
-    as ``TrainState.extra``, with no host sync beyond the step's own.
+def lm_loss_with_counters(params, tokens, cfg: TransformerConfig, mesh=None,
+                          extra=None):
+    """(loss, new_extra) in the shape ``Trainer`` takes: the step's device
+    scalars — one per name of moe_counter_names(cfg) — leave the step
+    beside the loss as ``TrainState.extra``, with no host sync beyond the
+    step's own. A bias-balanced router's bias is STATE on the same tree
+    (``extra["router_bias"]``): read from ``extra``, returned updated.
     Start the state from ``zero_moe_counters(cfg)``."""
-    total, metrics = lm_loss_and_metrics(params, tokens, cfg, mesh)
-    return total, {k: metrics[k].astype(jnp.float32)
-                   for k in moe_counter_names(cfg, mesh)}
+    bias = (extra or {}).get("router_bias") if cfg.router_bias else None
+    total, metrics = lm_loss_and_metrics(params, tokens, cfg, mesh,
+                                         router_bias=bias)
+    out = {k: metrics[k].astype(jnp.float32)
+           for k in moe_counter_names(cfg, mesh)}
+    if cfg.router_bias:
+        out["router_bias"] = metrics["router_bias"]
+    return total, out
 
 
 def zero_moe_counters(cfg: TransformerConfig, mesh=None) -> Dict[str, Any]:
-    return {k: jnp.zeros((), jnp.float32) for k in moe_counter_names(cfg, mesh)}
+    out = {k: jnp.zeros((), jnp.float32) for k in moe_counter_names(cfg, mesh)}
+    if cfg.router_bias:
+        out["router_bias"] = zero_router_bias(cfg)
+    return out
 
 
 def preset(name: str, **overrides) -> TransformerConfig:
@@ -1344,6 +1766,11 @@ CONFIG_OVERRIDE_FIELDS = frozenset(
         "moe_dispatch", "pp_microbatches", "pp_schedule",
         "d_head", "layer_pattern", "expert_act", "router_input", "router_f32",
         "experts_held", "expert_first",
+        "attn_kind", "q_lora_rank", "kv_lora_rank", "qk_nope_dim",
+        "qk_rope_dim", "v_head_dim", "n_dense_lead", "d_ff_dense",
+        "router_score", "router_bias", "router_bias_rate", "router_scale",
+        "router_groups", "n_shared_experts", "mtp_depth", "mtp_weight",
+        "tied_head",
     }
 )
 

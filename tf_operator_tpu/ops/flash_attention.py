@@ -135,15 +135,17 @@ def _block_live(qb, kb, block_q, block_k, causal, window):
     return live
 
 
-def _gqa_specs(g, block_q, block_k, d, q_grid_dim):
-    """BlockSpec factories shared by all three folded-GQA grids.
+def _gqa_specs(g, block_q, block_k, q_grid_dim):
+    """BlockSpec factories shared by all three folded-GQA grids, each over
+    its operand's last (lane) dim: q, k and dq/dk carry the q/k width, v,
+    o, do and dv the v width (the two differ under latent attention).
 
     Query-side tiles are (1, g, block_q, last) — the g query heads of kv
     head ``hk`` (contiguous in the h dim) stacked over one sequence
     block. ``q_grid_dim`` says which innermost grid dim walks q blocks:
     2 for the (b, h_kv, nq, nk) fwd/dq grids, 3 for the (b, h_kv, nk, nq)
     dk/dv grid; the other innermost dim walks K/V blocks. Returns
-    (q_spec_factory, kv_spec)."""
+    (q_spec_factory, kv_spec_factory)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -159,9 +161,11 @@ def _gqa_specs(g, block_q, block_k, d, q_grid_dim):
             (1, g, block_q, shape_last), q_idx, memory_space=pltpu.VMEM
         )
 
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, d), kv_idx, memory_space=pltpu.VMEM
-    )
+    def kv_spec(shape_last):
+        return pl.BlockSpec(
+            (1, 1, block_k, shape_last), kv_idx, memory_space=pltpu.VMEM
+        )
+
     return q_spec, kv_spec
 
 
@@ -177,7 +181,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     qi = pl.program_id(2)
     kb = pl.program_id(3)
     nkb = pl.num_programs(3)
-    d = q_ref.shape[-1]
+    d, dv = q_ref.shape[-1], v_ref.shape[-1]
     rows = g * block_q
 
     @pl.when(kb == 0)
@@ -217,7 +221,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     @pl.when(kb == nkb - 1)
     def _finish():
         l = l_scr[:, 0]
-        o_ref[0] = (acc_scr[:, :] / l[:, None]).reshape(g, block_q, d).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:, :] / l[:, None]).reshape(g, block_q, dv).astype(o_ref.dtype)
         lse_ref[0] = jnp.broadcast_to(
             (m_scr[:, 0] + jnp.log(l))[:, None], (rows, LSE_LANES)
         ).reshape(g, block_q, LSE_LANES)
@@ -228,7 +232,7 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, window=0):
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, h, d = q.shape
-    h_kv = k.shape[2]
+    h_kv, dv = k.shape[2], v.shape[3]  # dv: the v (and o) width, d: q/k's
     g = h // h_kv  # GQA group: g query heads fold into one q tile
     scale = d**-0.5
     # [b, t, h, d] -> [b, h, t, d]: sequence in the sublane dim, head_dim in
@@ -237,7 +241,7 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, window=0):
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
 
-    q_by_qi, kv_by_kb = _gqa_specs(g, block_q, block_k, d, q_grid_dim=2)
+    q_by_qi, kv_by_kb = _gqa_specs(g, block_q, block_k, q_grid_dim=2)
 
     kernel = functools.partial(
         _fwd_kernel, causal=causal, block_q=block_q, block_k=block_k,
@@ -246,16 +250,16 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, window=0):
     o, lse = pl.pallas_call(
         kernel,
         grid=(b, h_kv, t // block_q, t // block_k),
-        in_specs=[q_by_qi(d), kv_by_kb, kv_by_kb],
-        out_specs=[q_by_qi(d), q_by_qi(LSE_LANES)],
+        in_specs=[q_by_qi(d), kv_by_kb(d), kv_by_kb(dv)],
+        out_specs=[q_by_qi(dv), q_by_qi(LSE_LANES)],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, t, LSE_LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((g * block_q, LSE_LANES), jnp.float32),  # running max m
             pltpu.VMEM((g * block_q, LSE_LANES), jnp.float32),  # running sum l
-            pltpu.VMEM((g * block_q, d), jnp.float32),          # output accumulator
+            pltpu.VMEM((g * block_q, dv), jnp.float32),         # output accumulator
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -278,7 +282,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_s
     qi = pl.program_id(2)
     kb = pl.program_id(3)
     nkb = pl.num_programs(3)
-    d = q_ref.shape[-1]
+    d, dv = q_ref.shape[-1], v_ref.shape[-1]
     rows = g * block_q
 
     @pl.when(kb == 0)
@@ -290,7 +294,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_s
     @pl.when(live)
     def _step():
         q = q_ref[0].reshape(rows, d).astype(jnp.float32) * scale
-        do = do_ref[0].reshape(rows, d).astype(jnp.float32)
+        do = do_ref[0].reshape(rows, dv).astype(jnp.float32)
         lse = lse_ref[0].reshape(rows, LSE_LANES)[:, :1]      # value replicated on lanes
         delta = delta_ref[0].reshape(rows, LSE_LANES)[:, :1]
         k = k_ref[0, 0, :, :].astype(jnp.float32)
@@ -329,7 +333,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
     ki = pl.program_id(2)
     qb = pl.program_id(3)
     nqb = pl.num_programs(3)
-    d = q_ref.shape[-1]
+    d, dv = q_ref.shape[-1], v_ref.shape[-1]
     rows = g * block_q
 
     @pl.when(qb == 0)
@@ -346,7 +350,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         k = k_ref[0, 0, :, :].astype(jnp.float32)
         v = v_ref[0, 0, :, :].astype(jnp.float32)
         q = q_ref[0].reshape(rows, d).astype(jnp.float32) * scale
-        do = do_ref[0].reshape(rows, d).astype(jnp.float32)
+        do = do_ref[0].reshape(rows, dv).astype(jnp.float32)
         lse = lse_ref[0].reshape(rows, LSE_LANES)[:, :1]
         delta = delta_ref[0].reshape(rows, LSE_LANES)[:, :1]
         s = jax.lax.dot_general(
@@ -379,7 +383,7 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None,
 
     qt, kt, vt, o, lse_c = residuals
     b, h, t, d = qt.shape
-    h_kv = kt.shape[1]
+    h_kv, dv = kt.shape[1], vt.shape[3]
     grp = h // h_kv  # GQA group size (1 = classic MHA)
     scale = d**-0.5
     # Rebuild the kernels' lane-broadcast lse layout from the compact
@@ -398,7 +402,7 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None,
     delta = jnp.broadcast_to(delta[..., None], (b, h, t, LSE_LANES))
 
     # ---- dq: grid (b, h_kv, nq, nk); q tiles fold the group ------------
-    q_by_qi, kv_by_kb = _gqa_specs(grp, block_q, block_k, d, q_grid_dim=2)
+    q_by_qi, kv_by_kb = _gqa_specs(grp, block_q, block_k, q_grid_dim=2)
 
     dq_kernel = functools.partial(
         _bwd_dq_kernel, causal=causal, block_q=block_q, block_k=block_k,
@@ -407,7 +411,7 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None,
     dq = pl.pallas_call(
         dq_kernel,
         grid=(b, h_kv, t // block_q, t // block_k),
-        in_specs=[q_by_qi(d), kv_by_kb, kv_by_kb, q_by_qi(d),
+        in_specs=[q_by_qi(d), kv_by_kb(d), kv_by_kb(dv), q_by_qi(dv),
                   q_by_qi(LSE_LANES), q_by_qi(LSE_LANES)],
         out_specs=q_by_qi(d),
         out_shape=jax.ShapeDtypeStruct((b, h, t, d), qt.dtype),
@@ -420,7 +424,7 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None,
     # Query-side tiles fold the group ([grp·block_q, d] rows), so one K/V
     # block load serves all grp query heads and the scratch accumulates
     # the whole group per grid step (see _bwd_dkv_kernel).
-    q_by_qb, kv_by_ki = _gqa_specs(grp, block_q, block_k, d, q_grid_dim=3)
+    q_by_qb, kv_by_ki = _gqa_specs(grp, block_q, block_k, q_grid_dim=3)
 
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, causal=causal, block_q=block_q, block_k=block_k,
@@ -429,16 +433,16 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None,
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(b, h_kv, t // block_k, t // block_q),
-        in_specs=[q_by_qb(d), kv_by_ki, kv_by_ki, q_by_qb(d),
+        in_specs=[q_by_qb(d), kv_by_ki(d), kv_by_ki(dv), q_by_qb(dv),
                   q_by_qb(LSE_LANES), q_by_qb(LSE_LANES)],
-        out_specs=[kv_by_ki, kv_by_ki],
+        out_specs=[kv_by_ki(d), kv_by_ki(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((b, h_kv, t, d), kt.dtype),
-            jax.ShapeDtypeStruct((b, h_kv, t, d), vt.dtype),
+            jax.ShapeDtypeStruct((b, h_kv, t, dv), vt.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
@@ -549,7 +553,8 @@ def reference_attention_lse(q, k, v, causal: bool = False, window: int = 0):
     lse = jax.scipy.special.logsumexp(s, axis=-1)  # [b,h,q] or [b,h_kv,g,q]
     p = jnp.exp(s - lse[..., None]).astype(q.dtype)
     if hq != h_kv:
-        out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v).reshape(b, tq, hq, d)
+        out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v).reshape(
+            b, tq, hq, v.shape[-1])
         lse = lse.reshape(b, h_kv * (hq // h_kv), tq)  # head hi = hk·g + gi
     else:
         out = jnp.einsum("bhqk,bkhd->bqhd", p, v)
@@ -616,6 +621,8 @@ def _dispatch(q, k, v, block_q, block_k, interpret, force_kernel):
         )
     if k.shape[2] != v.shape[2]:
         raise ValueError(f"k/v head mismatch: {k.shape[2]} vs {v.shape[2]}")
+    if k.shape[3] != d:
+        raise ValueError(f"q/k width mismatch: {d} vs {k.shape[3]}")
     grp = q.shape[2] // k.shape[2]
     # Folded tiles and scratch scale as grp*block_q rows, so the q-block
     # target is bounded by the group: default lands on the measured
@@ -627,6 +634,9 @@ def _dispatch(q, k, v, block_q, block_k, interpret, force_kernel):
     )
     block_k = _pick_block(t, block_k or 1024)
     use = _use_kernel(t, d, block_q, block_k, bool(interpret))
+    dv = v.shape[3]
+    if dv != d:  # the v width passes the same lane gate on its own
+        use = use and _use_kernel(t, dv, block_q, block_k, bool(interpret))
     if force_kernel is not None:
         # HARD constraints still bind (exact tiling; a compiled Pallas TPU
         # kernel cannot run on CPU — off-TPU only the interpreter engages).
@@ -652,6 +662,12 @@ def flash_attention(
     window: int = 0,
 ):
     """Self-attention over [b, t, h, d] with softmax(q·kᵀ/√d)·v semantics.
+
+    v may be narrower or wider than q and k (latent attention: q/k 192
+    wide, v 128): q, k [b, t, h(_kv), d], v [b, t, h_kv, dv], the result
+    [b, t, h, dv], the scale 1/√d of the q/k width. The kernels read each
+    operand at its own width (no padded copy of v in HBM); at dv == d they
+    are the kernels they were.
 
     ``window`` > 0 (causal only) is a sliding window: query i sees key j
     iff j <= i and i - j < window. The kernels mask inside the diagonal

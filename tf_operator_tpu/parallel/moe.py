@@ -173,7 +173,8 @@ def expert_activation(name: str):
 
 
 def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
-                    block_rows: int = 256, act=jax.nn.silu, first: int = 0):
+                    block_rows: int = 256, act=jax.nn.silu, first: int = 0,
+                    score: str = "softmax", bias=None, scale: float = 1.0):
     """Padding-free single-device MoE over the Pallas grouped-matmul
     kernel (ops/grouped_matmul.gmm — the Megablocks-style path, r5), for
     ALL the experts or for ONE CHIP'S SHARE of them.
@@ -199,17 +200,33 @@ def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
     masked-matmul lowering) — the kernel exists because the XLA-level
     formulations all lose; see grouped_matmul.py.
 
+    ``score="sigmoid"`` is the bias-balanced router (DeepSeek-V3's
+    ``noaux_tc`` at one group): s = sigmoid(logits) in float32, the top-k
+    is taken over s + ``bias`` ([E] float32, model STATE: it steers the
+    choice and nothing else), the weights are s of the chosen — never
+    s + bias — over their sum, times ``scale``.
+
     Stats carry the routing counters of this call beside the router
-    observability: ``routed_here`` (choices routed to held experts),
+    observability: ``expert_count`` (choices per router output, all E:
+    what a bias update reads), ``routed_here`` (choices routed to held experts),
     ``rows_computed`` (occupied blocks × B: what the kernels multiply),
     ``held_load_max`` / ``held_load_mean`` (choices per held expert)."""
     tokens, d = x.shape
     n_experts = gate_logits.shape[-1]
     held = expert_params["w_gate"].shape[0]
-    gate_probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    top_p, top_i = jax.lax.top_k(gate_probs, k_top)  # [T, k]
-    if k_top > 1:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if score == "sigmoid":
+        gate_probs = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
+        _, top_i = jax.lax.top_k(
+            gate_probs if bias is None else gate_probs + bias, k_top)
+        top_p = jnp.take_along_axis(gate_probs, top_i, axis=-1)
+        top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20) * scale
+    elif score != "softmax":
+        raise ValueError(f"unknown router score {score!r} (softmax | sigmoid)")
+    else:
+        gate_probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
+        top_p, top_i = jax.lax.top_k(gate_probs, k_top)  # [T, k]
+        if k_top > 1:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
     tk = tokens * k_top
     B = block_rows
@@ -270,8 +287,10 @@ def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
         axis=1,
     )
     held_counts = counts.astype(jnp.float32)
+    all_counts = jnp.bincount(chosen, length=n_experts)
     stats = {
-        "expert_load": jnp.bincount(chosen, length=n_experts).astype(jnp.float32) / tk,
+        "expert_load": all_counts.astype(jnp.float32) / tk,
+        "expert_count": all_counts.astype(jnp.int32),
         "mean_gate": jnp.mean(gate_probs, axis=0),
         "drop_frac": jnp.float32(0.0),
         "routed_here": jnp.sum(held_counts),
@@ -427,7 +446,8 @@ def _dropped_value(x, dropped: str):
 
 def _moe_single(x, gate_logits, expert_params, expert_fn, capacity: int, dropped: str,
                 k_top: int = 1, dispatch_impl: str = "sort",
-                expert_act: str = "silu", expert_first: int = 0):
+                expert_act: str = "silu", expert_first: int = 0,
+                score: str = "softmax", bias=None, scale: float = 1.0):
     """All experts on one device: same routing math, no collectives — the
     fallback when the mesh has no ep axis (or no mesh at all).
 
@@ -457,6 +477,11 @@ def _moe_single(x, gate_logits, expert_params, expert_fn, capacity: int, dropped
             x, gate_logits, expert_params, k_top,
             block_rows=int(os.environ.get("TPUJOB_GMM_BLOCK_ROWS", "256")),
             act=expert_activation(expert_act), first=expert_first,
+            score=score, bias=bias, scale=scale,
+        )
+    if score != "softmax":
+        raise ValueError(
+            "a sigmoid (bias-balanced) router runs on dispatch_impl='gmm' only"
         )
     if jax.tree_util.tree_leaves(expert_params)[0].shape[0] != n_experts:
         raise ValueError(
@@ -581,6 +606,9 @@ def moe_apply(
     dispatch_impl: str = "sort",
     expert_act: str = "silu",
     expert_first: int = 0,
+    score: str = "softmax",
+    bias=None,
+    scale: float = 1.0,
 ):
     """Top-k MoE layer with experts sharded over ``axis_name``
     (``k_top=1`` — Switch; ``k_top=2`` — Mixtral-style with renormalized
@@ -626,7 +654,8 @@ def moe_apply(
     outputs — experts ``expert_first ..`` of a layer whose other experts
     live on absent chips. The router still scores all of them and the
     result is the held experts' partial sum (_moe_single_gmm); gmm
-    dispatch, no ep axis."""
+    dispatch, no ep axis. ``score`` / ``bias`` / ``scale``: the sigmoid
+    bias-balanced router (_moe_single_gmm), under the same two conditions."""
     from tf_operator_tpu.parallel.collectives import shard_map
 
     if dispatch_impl not in ("sort", "einsum", "gmm"):
@@ -639,9 +668,14 @@ def moe_apply(
         capacity = expert_capacity(capacity_factor, k_top, tokens, n_experts)
         out, stats = _moe_single(
             x, gate_logits, expert_params, expert_fn, capacity, dropped, k_top,
-            dispatch_impl, expert_act, expert_first,
+            dispatch_impl, expert_act, expert_first, score, bias, scale,
         )
         return (out, stats) if return_stats else out
+    if score != "softmax":
+        raise ValueError(
+            "a sigmoid (bias-balanced) router runs on one chip with no "
+            f"exchange; the mesh has {axis_name}={mesh.shape[axis_name]}"
+        )
     if jax.tree_util.tree_leaves(expert_params)[0].shape[0] != n_experts:
         raise ValueError(
             "a share of the experts runs on one chip with no exchange; "

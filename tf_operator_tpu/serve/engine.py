@@ -280,6 +280,10 @@ class ServeEngine:
 
         if cfg.n_experts:
             raise ValueError("serve engine: MoE presets not supported")
+        if cfg.attn_kind != "gqa" or not cfg.tied_head or cfg.mtp_depth:
+            raise ValueError(
+                "serve engine: latent attention, an untied head and a "
+                "prediction module run in training only")
         if getattr(cfg, "pp_stages", 0):
             raise ValueError("serve engine: pipeline presets not supported")
         if scfg.page_size < 1:

@@ -64,13 +64,16 @@ def main(ctx: JobContext) -> None:
     mesh = ctx.build_mesh()
 
     # gmm-dispatched experts: the step's routing counters leave it beside
-    # the loss as the state's ``extra`` — device scalars, read once below
+    # the loss as the state's ``extra`` — device scalars, read once below —
+    # and a bias-balanced router's bias rides the same tree as STATE: the
+    # step reads it, returns the next, and the checkpoint holds it (a
+    # resumed job routes as the killed one did)
     counted = bool(moe_counter_names(cfg, mesh))
 
     def loss_fn(params, tokens, extra):
-        del extra
         if counted:
-            return lm_loss_with_counters(params, tokens, cfg, mesh=mesh)
+            return lm_loss_with_counters(params, tokens, cfg, mesh=mesh,
+                                         extra=extra)
         return lm_loss(params, tokens, cfg, mesh=mesh)
 
     def init_fn(k):
@@ -158,6 +161,11 @@ def main(ctx: JobContext) -> None:
             if not os.path.exists(marker):
                 open(marker, "w").close()
                 log.warning("fault injection: requesting retry at step %d", step)
+                if ckpt.manager is not None:
+                    # a requested retry must not race its own async save: an
+                    # interpreter that shuts down under an in-flight commit
+                    # can hang, and a hung worker is never restarted
+                    ckpt.manager.wait_until_finished()
                 # routed by the harness to the user-retryable exit code
                 raise RetryableFailure(f"fault injection at step {step}")
 
@@ -192,8 +200,9 @@ def main(ctx: JobContext) -> None:
             trainer.batch_sharding,
         )
         _, m = jax.jit(
-            lambda p, tok: lm_loss_and_metrics(p, tok, cfg, mesh=mesh)
-        )(state.params, probe)
+            lambda p, tok, bias: lm_loss_and_metrics(
+                p, tok, cfg, mesh=mesh, router_bias=bias)
+        )(state.params, probe, (state.extra or {}).get("router_bias"))
         if "moe_expert_entropy" in m:
             log.info(
                 "moe router: expert_entropy=%.3f (uniform=%.3f) "
@@ -213,7 +222,8 @@ def main(ctx: JobContext) -> None:
                 float(m["moe_lb_loss"]), float(m["moe_z_loss"]),
             )
     moe_counters = (
-        {k: float(v) for k, v in state.extra.items()} if counted else None)
+        {k: float(v) for k, v in state.extra.items() if k != "router_bias"}
+        if counted else None)
     if moe_counters:
         # the last step's routing, where dashboards read live job numbers
         ctx.report_eval_metrics(steps, moe_counters)
@@ -227,8 +237,9 @@ def main(ctx: JobContext) -> None:
         loader=None if loader is None else {
             "batches": loader.batches, "wait_s": round(loader.wait_s, 4),
             "empty_pulls": loader.empty_pulls},
-        # the last step's routing counters, summed over layers (None: no
-        # gmm-dispatched experts)
+        # the last step's device scalars (None: no gmm-dispatched experts):
+        # the routing counters summed over the expert layers and, where the
+        # model has them, loss_main / loss_mtp and the router bias's two
         moe=moe_counters,
     )))
     if step_s is not None:
