@@ -227,6 +227,49 @@ def test_joyai_share_step_fits_v5e_at_the_cells_batch(topo, no_cache):
     assert held.alias_size_in_bytes > 12 * cfg.n_params()
 
 
+def test_fsdp4_step_walks_the_cross_entropy_per_chip_on_v5e(topo, no_cache):
+    """A small fsdp = 4 training step compiled for the described 2x2: the
+    TPU's partitioner shows what the CPU's does (tests/
+    test_fused_cross_entropy.py) — no collective on a [rows, vocab] tile,
+    nothing of the CE's in a loop, the head gathered once in the compute dtype and its gradient leaving as ONE f32 reduce-scatter.
+    Left to propagation the d-sharded head cost two all-reduces of the f32
+    logits tile a block (PERF.md §6, PR 31). The cell's own size is compiled
+    by hand (§6), not here."""
+    from unittest import mock
+
+    from tf_operator_tpu.models import transformer as tr
+    from tf_operator_tpu.parallel.collectives import compiled_collectives
+    from tf_operator_tpu.parallel.mesh import build_mesh
+    from tf_operator_tpu.train.trainer import Trainer, TrainerConfig
+
+    cfg = tr.preset("tiny", d_model=512, vocab=4096, n_layers=2, n_heads=4,
+                    n_kv_heads=2, d_ff=1024, max_seq=2048, attn_impl="flash")
+    mesh = build_mesh({"fsdp": 4}, devices=list(topo.devices))
+    trainer = Trainer(
+        mesh,
+        loss_fn=lambda p, t, extra: tr.lm_loss(p, t, cfg, mesh=mesh),
+        init_fn=lambda k: tr.init_transformer(k, cfg),
+        logical_axes=tr.transformer_logical_axes(cfg),
+        config=TrainerConfig(optimizer="adamw", learning_rate=1e-3))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        compiled = trainer.compile_step(jax.ShapeDtypeStruct((4, 2048), "int32"))
+    ops = compiled_collectives(compiled.as_text())
+    assert not [op for op in ops
+                if any(s.endswith(f",{cfg.vocab}]") for s in op["shapes"])]
+    head = f"[{cfg.vocab},{cfg.d_model}]"
+    ce = sorted((op["kind"], op["shapes"][0], op["runs"]) for op in ops
+                if "fused_xent" in op["op_name"] and op["bytes"] > 64)
+    assert ce == [("all-gather", "bf16" + head, 1),
+                  ("reduce-scatter", "f32" + head, 1)], ce
+    summary = trainer.step_collectives
+    # the layers' gradient reductions stay in the scan's loop (this backend's
+    # ``all-reduce-scatter`` fusions; at these widths the small leaves are
+    # plain all-reduces): none of them is near a logits tile, 16.8 MB here
+    assert summary["all-reduce"]["in_loop_max_bytes"] < 2 ** 21, summary
+    assert summary["reduce-scatter"]["count"] > 1, summary
+    assert summary["largest"] == "reduce-scatter f32" + head, summary
+
+
 # ---- paged decode: the kernel the serve engine cannot run without ---------
 
 

@@ -1,6 +1,8 @@
 """Fused blockwise cross-entropy: value/gradient parity with the naive
 materialize-the-logits path, weighting, padding, and the lm_loss toggle."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -196,3 +198,105 @@ def test_fused_trainer_step_on_mesh():
         losses.append(float(metrics["loss"]))
     assert all(np.isfinite(losses))
     assert losses[-1] < losses[0]  # it learns the (fixed) batch
+
+
+# ---- the op's own partition on a mesh of data axes (PR 31) ----------------
+
+_MESH_CFG = dict(d_model=256, vocab=4096, n_layers=2, n_heads=4, d_ff=512,
+                 max_seq=2048, dtype=jnp.float32)
+
+
+@functools.lru_cache(maxsize=None)
+def _unmeshed_loss_and_grads():
+    """{causal: (cfg, loss, grads)} of one tiny float32 model on one batch,
+    no mesh; 1,100 columns, so a chip's 1,099 (1,100 masked) rows are two
+    blocks, the second mostly padding."""
+    from tf_operator_tpu.models.transformer import init_transformer, lm_loss, preset
+
+    cfgs = {c: preset("tiny", causal=c, max_seq=2048, dtype=jnp.float32)
+            for c in (True, False)}
+    params = jax.jit(lambda k: init_transformer(k, cfgs[True]))(jax.random.PRNGKey(0))
+    tokens = np.random.default_rng(1).integers(0, cfgs[True].vocab, (4, 1100), np.int32)
+    out = {}
+    for causal, cfg in cfgs.items():  # False: the MLM mask rides as weights
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p, t: lm_loss(p, t, cfg, key=jax.random.PRNGKey(2))))(params, tokens)
+        out[causal] = (cfg, loss, grads)
+    return params, tokens, out
+
+
+@pytest.mark.parametrize(
+    "axes",
+    [{"fsdp": 4}, {"dp": 2, "fsdp": 2}, {"dp": 4}, {"fsdp": 1}, {"dp": 2, "tp": 4}],
+    ids=lambda a: "x".join(f"{k}{v}" for k, v in a.items()))
+def test_partition_follows_the_mesh(axes):
+    """On a mesh that only splits the batch the compiled Trainer step walks
+    each chip's own rows past a head gathered once: no collective carries a
+    [rows, vocab] tile, none sits in the CE's loops, the head is gathered
+    once and its gradient reduced once an axis; loss and gradients are the
+    unmeshed call's. One chip, or a mesh that shards anything else (tp), keeps
+    the one walk over all rows."""
+    from tf_operator_tpu.models.transformer import (
+        init_transformer, lm_loss, preset, transformer_logical_axes,
+    )
+    from tf_operator_tpu.ops.fused_cross_entropy import data_parallel_axes
+    from tf_operator_tpu.parallel import build_mesh
+    from tf_operator_tpu.parallel.collectives import (
+        collectives_summary, compiled_collectives,
+    )
+    from tf_operator_tpu.train import Trainer, TrainerConfig
+
+    n_dev = int(np.prod(list(axes.values())))
+    mesh = build_mesh(axes, devices=jax.devices()[:n_dev])
+    data_axes = tuple(a for a in ("dp", "fsdp") if axes.get(a, 1) > 1)
+    engages = "tp" not in axes and bool(data_axes)
+    assert data_parallel_axes(mesh) == (data_axes if engages else ())
+
+    cfg = preset("tiny", **_MESH_CFG)
+    vocab, d = cfg.vocab, cfg.d_model
+    trainer = Trainer(
+        mesh,
+        loss_fn=lambda p, tok, extra: lm_loss(p, tok, cfg, mesh=mesh),
+        init_fn=lambda k: init_transformer(k, cfg),
+        logical_axes=transformer_logical_axes(cfg),
+        config=TrainerConfig(optimizer="adamw", learning_rate=1e-3),
+    )
+    text = trainer.compile_step(jax.ShapeDtypeStruct((4, 2048), "int32")).as_text()
+    ops = compiled_collectives(text)
+    assert trainer.step_collectives == collectives_summary(ops)
+    in_map = [op for op in ops if "shard_map" in op["op_name"]]
+    if not engages:
+        assert not in_map
+        if n_dev == 1:
+            assert trainer.step_collectives == {}
+        return
+
+    tiles = [op for op in ops if any(s.endswith(f",{vocab}]") for s in op["shapes"])]
+    assert not tiles, tiles
+    in_ce_loops = [op for op in ops if "fused_xent" in op["op_name"] and op["in_loop"]]
+    assert not in_ce_loops, in_ce_loops
+    # what the CE itself put in: the head's gather (before its cast the CPU
+    # backend gathers f32), the gather's transpose over fsdp on the f32
+    # accumulator, one all-reduce of what is left over dp, two scalar psums
+    fsdp = axes.get("fsdp", 1)
+    ce = [op for op in ops if "fused_xent" in op["op_name"]]
+    assert all(op["runs"] == 1 and "shard_map" in op["op_name"] for op in ce), ce
+    big = sorted((op["kind"], op["shapes"][0]) for op in ce if op["bytes"] > 64)
+    assert big == sorted(
+        [("all-gather", f"f32[{vocab},{d}]"),
+         ("reduce-scatter", f"f32[{vocab},{d}]")] * (fsdp > 1)
+        + [("all-reduce", f"f32[{vocab},{d // fsdp}]")] * ("dp" in data_axes)), ce
+    assert len(ce) <= len(big) + 2, ce  # XLA may merge a psum with a neighbour
+
+    params, tokens, unmeshed = _unmeshed_loss_and_grads()
+    params = jax.device_put(params, trainer.param_shardings)
+    tokens = jax.device_put(tokens, trainer.batch_sharding)
+    for cfg_c, loss0, grads0 in unmeshed.values():
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p, t: lm_loss(p, t, cfg_c, mesh=mesh, key=jax.random.PRNGKey(2))
+        ))(params, tokens)
+        np.testing.assert_allclose(float(loss), float(loss0), rtol=1e-5)
+        for g, g0 in zip(jax.tree_util.tree_leaves(grads),
+                         jax.tree_util.tree_leaves(grads0)):
+            np.testing.assert_allclose(
+                g, g0, rtol=1e-5, atol=1e-5 * float(jnp.max(jnp.abs(g0))))

@@ -152,7 +152,8 @@ def test_the_module_scores_the_token_two_ahead_and_the_main_loss_the_next(monkey
     def spy(x, head, targets, weights=None, **kw):
         calls.append((targets, weights))  # tracers of the one trace below
         if weights is not None:
-            permuted.append(real(x, head, targets[::-1], weights, **kw))
+            permuted.append(real(  # every position's target, reversed
+                x, head, targets.ravel()[::-1].reshape(targets.shape), weights, **kw))
         return real(x, head, targets, weights, **kw)
 
     monkeypatch.setattr(fce, "fused_cross_entropy", spy)
@@ -168,7 +169,7 @@ def test_the_module_scores_the_token_two_ahead_and_the_main_loss_the_next(monkey
         return m["loss_main"], m["loss_mtp"], permuted[0], main_t, mtp_t, mtp_w
 
     main, mtp, mtp_permuted, main_t, mtp_t, mtp_w = map(np.asarray, losses(params))
-    assert np.array_equal(main_t, tok[:, 1:].ravel())
+    assert np.array_equal(main_t, tok[:, 1:])  # [b, t - 1]: the op flattens
     assert np.array_equal(mtp_t.reshape(2, 32)[:, :30], tok[:, 2:])
     assert np.array_equal(mtp_w.reshape(2, 32), np.tile(np.arange(32) < 30, (2, 1)))
     assert abs(float(mtp_permuted) - float(mtp)) > 1e-3

@@ -911,222 +911,3 @@ def test_unknown_moe_dispatch_raises():
     tok = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab)
     with pytest.raises(ValueError, match="unknown dispatch_impl 'ragged'"):
         lm_loss_and_metrics(params, tok, cfg)
-
-
-# ---- ep-SHARDED gmm dispatch (r6 tentpole) --------------------------------
-# dispatch_impl="gmm" no longer degrades to capacity queues under an ep
-# axis: count exchange + block-quantum a2a buffers + sentinel-skipped
-# kernel blocks (parallel.moe._moe_local_gmm). Oracle = the capacity
-# path at no-drop capacity (identical math when nothing drops).
-
-
-def test_ep_gmm_matches_capacity_oracle_on_flagship_mesh(monkeypatch):
-    """moe_apply level, the mixtral dp x fsdp x ep layout, k_top 1 and 2,
-    fwd AND grads (x, router logits, expert weights). block_rows=8 so
-    the per-(source, expert) block-quantum rounding actually engages at
-    test sizes (256 would make every expert a single partial block)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from tf_operator_tpu.parallel.moe import moe_apply
-
-    monkeypatch.setenv("TPUJOB_GMM_BLOCK_ROWS", "8")
-    T, d, f, E = 64, 16, 32, 4
-    ks = jax.random.split(jax.random.PRNGKey(0), 5)
-    x = jax.random.normal(ks[0], (T, d), jnp.float32)
-    gl = jax.random.normal(ks[1], (T, E), jnp.float32)
-    wp = {
-        "w_gate": jax.random.normal(ks[2], (E, d, f)) * 0.1,
-        "w_up": jax.random.normal(ks[3], (E, d, f)) * 0.1,
-        "w_down": jax.random.normal(ks[4], (E, f, d)) * 0.1,
-    }
-
-    def efn(w, t):
-        return (jax.nn.silu(t @ w["w_gate"]) * (t @ w["w_up"])) @ w["w_down"]
-
-    mesh = build_mesh({"dp": 2, "fsdp": 2, "ep": 2})
-    xs = jax.device_put(x, NamedSharding(mesh, P(("dp", "fsdp", "ep"))))
-    gls = jax.device_put(gl, NamedSharding(mesh, P(("dp", "fsdp", "ep"))))
-    wps = jax.tree_util.tree_map(
-        lambda a: jax.device_put(a, NamedSharding(mesh, P("ep", "fsdp"))), wp)
-
-    for k_top in (1, 2):
-        want, wstats = moe_apply(xs, gls, wps, efn, mesh, capacity_factor=8.0,
-                                 k_top=k_top, dropped="zero",
-                                 return_stats=True)
-        got, stats = moe_apply(xs, gls, wps, efn, mesh, k_top=k_top,
-                               dispatch_impl="gmm", return_stats=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
-        # router telemetry agrees with the capacity path and drops are
-        # structurally impossible
-        np.testing.assert_allclose(np.asarray(stats["expert_load"]),
-                                   np.asarray(wstats["expert_load"]),
-                                   atol=1e-6)
-        assert float(stats["drop_frac"]) == 0.0
-
-        def loss(impl):
-            def fn(x_, gl_, wp_):
-                kw = (dict(dispatch_impl="gmm") if impl == "gmm"
-                      else dict(capacity_factor=8.0, dropped="zero"))
-                return jnp.sum(moe_apply(
-                    x_, gl_, wp_, efn, mesh, k_top=k_top, **kw) ** 2)
-            return fn
-
-        g1 = jax.grad(loss("gmm"), argnums=(0, 1, 2))(xs, gls, wps)
-        g2 = jax.grad(loss("cap"), argnums=(0, 1, 2))(xs, gls, wps)
-        for a, b in zip(jax.tree_util.tree_leaves(g1),
-                        jax.tree_util.tree_leaves(g2)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=5e-5, atol=5e-5)
-
-
-def test_ep_gmm_through_transformer_moe_fsdp(monkeypatch):
-    """Config surface on the moe-fsdp dryrun mesh: moe_dispatch="gmm"
-    must match BOTH the sharded capacity oracle and the single-device
-    gmm path, CE and parameter grads."""
-    from tf_operator_tpu.models.transformer import lm_loss_and_metrics
-
-    monkeypatch.setenv("TPUJOB_GMM_BLOCK_ROWS", "8")
-    cfg = preset("tiny-moe", dtype=jnp.float32, remat=False, moe_top_k=2,
-                 moe_dispatch="gmm")
-    cfg_sort = preset("tiny-moe", dtype=jnp.float32, remat=False,
-                      moe_top_k=2, capacity_factor=8.0)
-    params = init_transformer(jax.random.PRNGKey(0), cfg)
-    tok = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab)
-    mesh = build_mesh({"dp": 2, "fsdp": 2, "ep": 2})
-
-    def ce(p, c, m):
-        return lm_loss_and_metrics(p, tok, c, mesh=m)[1]["ce_loss"]
-
-    got = float(ce(params, cfg, mesh))
-    np.testing.assert_allclose(got, float(ce(params, cfg_sort, mesh)),
-                               rtol=2e-5)
-    np.testing.assert_allclose(got, float(ce(params, cfg, None)), rtol=2e-5)
-    g1 = jax.grad(lambda p: ce(p, cfg, mesh))(params)
-    g2 = jax.grad(lambda p: ce(p, cfg_sort, mesh))(params)
-    for (pa, a), (_, b) in zip(jax.tree_util.tree_leaves_with_path(g1),
-                               jax.tree_util.tree_leaves_with_path(g2)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
-                                   atol=2e-6,
-                                   err_msg=jax.tree_util.keystr(pa))
-
-
-@pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
-def test_ep_gmm_pipeline_in_stage(schedule, monkeypatch):
-    """ep INSIDE the pipeline (the moe-pipeline dryrun mesh, pp x ep x
-    dp): the stage body routes cfg.moe_dispatch="gmm" through
-    _moe_local's gmm branch against the BOUND ep axis — both schedules,
-    CE and grads against the sharded capacity oracle."""
-    from tf_operator_tpu.models.transformer import lm_loss_and_metrics
-
-    monkeypatch.setenv("TPUJOB_GMM_BLOCK_ROWS", "8")
-    cfg = preset("tiny-moe", dtype=jnp.float32, remat=False, n_layers=4,
-                 pp_microbatches=2, moe_top_k=2, pp_schedule=schedule,
-                 moe_dispatch="gmm")
-    cfg_sort = preset("tiny-moe", dtype=jnp.float32, remat=False, n_layers=4,
-                      pp_microbatches=2, moe_top_k=2, pp_schedule=schedule,
-                      capacity_factor=8.0)
-    mesh = build_mesh({"pp": 2, "ep": 2, "dp": 2})
-    params = init_transformer(jax.random.PRNGKey(0), cfg)
-    tok = jax.random.randint(jax.random.PRNGKey(1), (16, 32), 0, cfg.vocab)
-
-    def ce(p, c):
-        return lm_loss_and_metrics(p, tok, c, mesh=mesh)[1]["ce_loss"]
-
-    np.testing.assert_allclose(float(ce(params, cfg)),
-                               float(ce(params, cfg_sort)), rtol=2e-5)
-    g1 = jax.grad(lambda p: ce(p, cfg))(params)
-    g2 = jax.grad(lambda p: ce(p, cfg_sort))(params)
-    for (pa, a), (_, b) in zip(jax.tree_util.tree_leaves_with_path(g1),
-                               jax.tree_util.tree_leaves_with_path(g2)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
-                                   atol=2e-6,
-                                   err_msg=jax.tree_util.keystr(pa))
-
-
-def test_ep_gmm_zero_token_expert_gets_zero_grad_across_shards(monkeypatch):
-    """Route every token to expert 0 (shard 0's expert) on an ep=2 mesh:
-    shard 1's experts see ZERO tokens from every source — their weight
-    grads must be exactly 0 and finite (the dw kernel zero-initializes
-    every expert tile; no garbage block needed on the remote shard)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from tf_operator_tpu.parallel.moe import moe_apply
-
-    monkeypatch.setenv("TPUJOB_GMM_BLOCK_ROWS", "8")
-    T, d, f, E = 32, 16, 32, 4
-    ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    x = jax.random.normal(ks[0], (T, d), jnp.float32)
-    gl = jnp.zeros((T, E)).at[:, 0].set(100.0)
-    wp = {
-        "w_gate": jax.random.normal(ks[1], (E, d, f)) * 0.1,
-        "w_up": jax.random.normal(ks[2], (E, d, f)) * 0.1,
-        "w_down": jax.random.normal(ks[3], (E, f, d)) * 0.1,
-    }
-
-    def efn(w, t):
-        return (jax.nn.silu(t @ w["w_gate"]) * (t @ w["w_up"])) @ w["w_down"]
-
-    mesh = build_mesh({"dp": 2, "ep": 2}, devices=jax.devices()[:4])
-    xs = jax.device_put(x, NamedSharding(mesh, P(("dp", "ep"))))
-    gls = jax.device_put(gl, NamedSharding(mesh, P(("dp", "ep"))))
-    wps = jax.tree_util.tree_map(
-        lambda a: jax.device_put(a, NamedSharding(mesh, P("ep"))), wp)
-
-    g = jax.grad(lambda w: jnp.sum(moe_apply(
-        xs, gls, w, efn, mesh, k_top=1, dispatch_impl="gmm") ** 2))(wps)
-    for name in g:
-        np.testing.assert_array_equal(np.asarray(g[name][1:]), 0.0)
-        assert np.isfinite(np.asarray(g[name])).all()
-        assert np.abs(np.asarray(g[name][0])).sum() > 0
-
-
-def test_ep_gmm_uneven_shard_loads_block_quantum_edge(monkeypatch):
-    """The block-quantum padding edge: skew the router so per-(source,
-    expert) counts are UNEVEN and not multiples of the block quantum
-    (partial last blocks + empty (source, expert) pairs on the same
-    shard), then pin against the no-drop capacity oracle."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from tf_operator_tpu.parallel.moe import moe_apply
-
-    monkeypatch.setenv("TPUJOB_GMM_BLOCK_ROWS", "8")
-    T, d, f, E = 64, 16, 32, 4
-    ks = jax.random.split(jax.random.PRNGKey(42), 5)
-    x = jax.random.normal(ks[0], (T, d), jnp.float32)
-    # strong skew: most tokens to experts 0 and 3, a trickle to 1, none
-    # to 2 from many sources
-    bias = jnp.array([3.0, -1.0, -6.0, 2.0])
-    gl = jax.random.normal(ks[1], (T, E)) + bias[None, :]
-    wp = {
-        "w_gate": jax.random.normal(ks[2], (E, d, f)) * 0.1,
-        "w_up": jax.random.normal(ks[3], (E, d, f)) * 0.1,
-        "w_down": jax.random.normal(ks[4], (E, f, d)) * 0.1,
-    }
-
-    def efn(w, t):
-        return (jax.nn.silu(t @ w["w_gate"]) * (t @ w["w_up"])) @ w["w_down"]
-
-    mesh = build_mesh({"dp": 2, "fsdp": 2, "ep": 2})
-    xs = jax.device_put(x, NamedSharding(mesh, P(("dp", "fsdp", "ep"))))
-    gls = jax.device_put(gl, NamedSharding(mesh, P(("dp", "fsdp", "ep"))))
-    wps = jax.tree_util.tree_map(
-        lambda a: jax.device_put(a, NamedSharding(mesh, P("ep", "fsdp"))), wp)
-
-    for k_top in (1, 2):
-        want = moe_apply(xs, gls, wps, efn, mesh, capacity_factor=float(E),
-                         k_top=k_top, dropped="zero")
-        got = moe_apply(xs, gls, wps, efn, mesh, k_top=k_top,
-                        dispatch_impl="gmm")
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
-        g1 = jax.grad(lambda w: jnp.sum(moe_apply(
-            xs, gls, w, efn, mesh, k_top=k_top,
-            dispatch_impl="gmm") ** 2))(wps)
-        g2 = jax.grad(lambda w: jnp.sum(moe_apply(
-            xs, gls, w, efn, mesh, capacity_factor=float(E), k_top=k_top,
-            dropped="zero") ** 2))(wps)
-        for name in g1:
-            np.testing.assert_allclose(np.asarray(g1[name]),
-                                       np.asarray(g2[name]), rtol=5e-5,
-                                       atol=5e-5, err_msg=name)

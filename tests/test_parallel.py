@@ -10,7 +10,7 @@ from jax.sharding import PartitionSpec as P
 
 from tf_operator_tpu.parallel import MeshSpec, build_mesh
 from tf_operator_tpu.parallel.sharding import DEFAULT_RULES, ShardingRules
-from tf_operator_tpu.parallel.ring_attention import reference_attention, ring_attention
+from tf_operator_tpu.parallel.ring_attention import reference_attention
 from tf_operator_tpu.parallel.pipeline import pipeline_apply
 from tf_operator_tpu.parallel.moe import moe_apply
 
@@ -66,142 +66,6 @@ def test_sharded_matmul_tp_matches_dense():
     ws = jax.device_put(w, rules.sharding(mesh, [None, "mlp"]))
     y = jax.jit(jnp.dot)(xs, ws)
     np.testing.assert_allclose(np.asarray(y), np.asarray(x @ w), rtol=1e-5, atol=1e-4)
-
-
-# ---- ring attention ------------------------------------------------------
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_ring_attention_matches_dense(causal):
-    mesh = build_mesh({"cp": 8})
-    b, t, h, d = 2, 64, 4, 16
-    key = jax.random.PRNGKey(0)
-    q, k, v = (
-        jax.random.normal(kk, (b, t, h, d), jnp.float32)
-        for kk in jax.random.split(key, 3)
-    )
-    out = ring_attention(q, k, v, mesh, axis_name="cp", causal=causal)
-    ref = reference_attention(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-5)
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_ring_attention_gqa_matches_repeat_oracle(causal):
-    """GQA-native ring (r3): k/v keep n_kv heads through the whole ring —
-    each ppermute hop moves blocks g-times smaller (the llama2-70b
-    64q/8kv shape cuts ring ICI traffic 8x). Must equal the repeat-based
-    formulation exactly, forward and grads."""
-    mesh = build_mesh({"cp": 8})
-    b, t, h, h_kv, d = 2, 64, 4, 2, 16
-    key = jax.random.PRNGKey(2)
-    ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (b, t, h, d), jnp.float32)
-    k = jax.random.normal(ks[1], (b, t, h_kv, d), jnp.float32)
-    v = jax.random.normal(ks[2], (b, t, h_kv, d), jnp.float32)
-    g = h // h_kv
-    out = ring_attention(q, k, v, mesh, axis_name="cp", causal=causal)
-    ref = reference_attention(
-        q, jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2), causal=causal
-    )
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-5)
-
-    def loss_ring(q, k, v):
-        return jnp.sum(
-            ring_attention(q, k, v, mesh, axis_name="cp", causal=causal) ** 2
-        )
-
-    def loss_ref(q, k, v):
-        return jnp.sum(
-            reference_attention(
-                q, jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2), causal=causal
-            )
-            ** 2
-        )
-
-    got = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    # the repeat sits INSIDE loss_ref, so its transpose already folds
-    # dk/dv back to [b, t, h_kv, d]
-    want = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for name, a, w in zip("qkv", got, want):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(w), rtol=5e-4, atol=5e-5,
-            err_msg=f"d{name}",
-        )
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_ring_attention_impl_parity(causal):
-    """The flash-backed body (r3 default: per-hop flash_attention_lse +
-    exact lse merge) must agree with the blockwise einsum body — forward
-    and grads, including GQA — since both are exact decompositions of the
-    same softmax."""
-    mesh = build_mesh({"cp": 8})
-    b, t, h, h_kv, d = 2, 64, 4, 2, 16
-    ks = jax.random.split(jax.random.PRNGKey(5), 3)
-    q = jax.random.normal(ks[0], (b, t, h, d), jnp.float32)
-    k = jax.random.normal(ks[1], (b, t, h_kv, d), jnp.float32)
-    v = jax.random.normal(ks[2], (b, t, h_kv, d), jnp.float32)
-
-    def loss(impl):
-        def f(q, k, v):
-            return jnp.sum(
-                ring_attention(q, k, v, mesh, axis_name="cp", causal=causal,
-                               impl=impl) ** 2)
-        return f
-
-    np.testing.assert_allclose(
-        np.asarray(ring_attention(q, k, v, mesh, axis_name="cp", causal=causal)),
-        np.asarray(ring_attention(q, k, v, mesh, axis_name="cp", causal=causal,
-                                  impl="einsum")),
-        rtol=2e-4, atol=2e-5)
-    got = jax.grad(loss("flash"), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(loss("einsum"), argnums=(0, 1, 2))(q, k, v)
-    for name, a, w in zip("qkv", got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(w),
-                                   rtol=5e-4, atol=5e-5, err_msg=f"d{name}")
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_ring_attention_flash_kernel_interpret(causal):
-    """Force the per-hop Pallas kernel (interpreter) inside the ring —
-    the TPU path's kernel logic: per-hop lse from the kernel, merged
-    across hops, gradients through the custom VJP incl. the lse
-    cotangent."""
-    mesh = build_mesh({"dp": 2, "cp": 4})
-    b, t, h, d = 1, 128, 2, 16  # t_local=32: tiles cleanly in interpret
-    ks = jax.random.split(jax.random.PRNGKey(6), 3)
-    q, k, v = (jax.random.normal(kk, (b, t, h, d), jnp.float32) for kk in ks)
-    out = ring_attention(q, k, v, mesh, axis_name="cp", causal=causal,
-                         interpret=True)
-    ref = reference_attention(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-5)
-
-    def loss(interpret):
-        def f(q, k, v):
-            return jnp.sum(
-                ring_attention(q, k, v, mesh, axis_name="cp", causal=causal,
-                               interpret=interpret) ** 2)
-        return f
-
-    got = jax.grad(loss(True), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(loss(False), argnums=(0, 1, 2))(q, k, v)
-    for name, a, w in zip("qkv", got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(w),
-                                   rtol=2e-3, atol=2e-4, err_msg=f"d{name}")
-
-
-def test_ring_attention_with_batch_sharding():
-    mesh = build_mesh({"dp": 2, "cp": 4})
-    b, t, h, d = 4, 32, 2, 8
-    key = jax.random.PRNGKey(1)
-    q, k, v = (
-        jax.random.normal(kk, (b, t, h, d), jnp.float32)
-        for kk in jax.random.split(key, 3)
-    )
-    out = ring_attention(q, k, v, mesh, axis_name="cp", causal=True, batch_axes=("dp",))
-    ref = reference_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-5)
 
 
 # ---- pipeline ------------------------------------------------------------

@@ -1564,9 +1564,14 @@ def lm_loss_and_metrics(params, tokens, cfg: TransformerConfig, mesh=None, key=N
             params, inp, cfg, mesh, with_aux=True,
             router_bias=router_bias["layers"] if cfg.router_bias else None)
 
+    from tf_operator_tpu.ops.fused_cross_entropy import (
+        data_parallel_axes,
+        fused_cross_entropy,
+    )
+
     head = _head(params, cfg)
 
-    def _ce_operands(flat_h, embed):
+    def _ce_operands(h, embed):
         # MoE on a multi-axis mesh (r6): pin the fused-CE block walk to
         # the batch-sharded layout with the EMBED all-gathered. Left to
         # propagation, the ZeRO-sharded embed (d over fsdp, a TRANSPOSED
@@ -1575,23 +1580,27 @@ def lm_loss_and_metrics(params, tokens, cfg: TransformerConfig, mesh=None, key=N
         # arrive batch-sharded — and converting between differently
         # ORDERED tile assignments is exactly what GSPMD can only do by
         # involuntary full rematerialization, once per block per layer.
-        # On the single-axis fsdp mesh propagation picks one consistent
-        # d-sharded assignment and none of this is needed (no warnings
-        # there at the seed); the anchor is scoped to ep meshes. The
-        # all-gathered embed transient is vocab·d·dtype — at mixtral
+        # The all-gathered embed transient is vocab·d·dtype — at mixtral
         # shapes ~256 MB bf16, far below the [b·t, vocab] psum the
-        # d-sharded assignment pays instead.
-        if not (cfg.n_experts and mesh is not None
+        # d-sharded assignment pays instead. That psum is what propagation
+        # picks on a mesh of data axes alone too (two all-reduces of the
+        # f32 logits tile a block, 9 % of the fsdp=4 step on the chip, PR
+        # 31), but this anchor is NOT the cure there: it shards the block
+        # walk's SCAN dimension and keeps an f32 all-reduce of the head's
+        # gradient in every block. Such a mesh takes fused_cross_entropy's
+        # own partition (its ``mesh``); the anchor stays scoped to ep.
+        if data_parallel_axes(mesh) or not (
+                cfg.n_experts and mesh is not None
                 and getattr(mesh, "devices", None) is not None
                 and cfg.ep_axis in getattr(mesh, "axis_names", ())):
-            return flat_h, embed
+            return h, embed
         data_axes = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names)
         if not data_axes:
-            return flat_h, embed
+            return h, embed
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         flat_h = jax.lax.with_sharding_constraint(
-            flat_h, NamedSharding(mesh, P(data_axes, None)))
+            h.reshape(-1, h.shape[-1]), NamedSharding(mesh, P(data_axes, None)))
         embed = jax.lax.with_sharding_constraint(
             embed, NamedSharding(mesh, P(None, None)))
         return flat_h, embed
@@ -1599,14 +1608,9 @@ def lm_loss_and_metrics(params, tokens, cfg: TransformerConfig, mesh=None, key=N
     def _ce(h, targets, weights=None):
         """Mean cross-entropy of h [b, t, d] through the head against
         targets [b, t] (weights [b, t]: a weighted mean)."""
-        b, t, d = h.shape
         if cfg.fused_xent:
-            from tf_operator_tpu.ops.fused_cross_entropy import fused_cross_entropy
-
             return fused_cross_entropy(
-                *_ce_operands(h.reshape(b * t, d), head), targets.reshape(b * t),
-                None if weights is None else weights.reshape(b * t),
-            )
+                *_ce_operands(h, head), targets, weights, mesh=mesh)
         logits = (h @ head.astype(cfg.dtype).T).astype(jnp.float32)
         logp = jax.nn.log_softmax(logits, axis=-1)
         ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]

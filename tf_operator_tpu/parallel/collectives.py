@@ -9,6 +9,9 @@ to XLA, which maps them onto ICI rings).
 from __future__ import annotations
 
 import functools
+import math
+import re
+from typing import Any, Dict, List, Tuple
 
 import jax
 
@@ -103,3 +106,151 @@ def axis_index(axis_name: str):
 
 def axis_size(axis_name: str):
     return jax.lax.psum(1, axis_name)
+
+
+# ---- what a COMPILED program's collectives are ---------------------------
+# Read from the optimised HLO text (``jax.stages.Compiled.as_text()``), the
+# way ``serve.engine.pool_copies`` reads whole-pool copies: a partition that
+# propagation chose badly shows here, at compile time, as a large collective
+# inside a ``while`` body — before a chip has run a step.
+
+COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
+                    "all-to-all", "collective-permute")
+_COLLECTIVE_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%\S+ = (?P<type>.*?) (?P<kind>" + "|".join(COLLECTIVE_KINDS)
+    + r")(?P<start>-start)?\(")
+_HLO_ARRAY = re.compile(r"\b([a-z]+\d*[a-z0-9]*)\[([\d,]*)\]")
+_CALLED = re.compile(
+    r"\b(body|calls|to_apply|branch_computations|called_computations)="
+    r"(?:%([\w.\-]+)|\{([^}]*)\})")
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
+                "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+                "f64": 8}
+
+
+def _group_size(line: str) -> int:
+    m = re.search(r"replica_groups=\[\d+,(\d+)\]", line)  # iota form [groups, size]
+    if m:
+        return int(m.group(1))
+    m = re.search(r"replica_groups=\{\{([\d,]*)\}", line)
+    return len(m.group(1).split(",")) if m else 1
+
+
+def _trip_count(while_line: str, comps: Dict[str, List[str]]) -> int:
+    """Trips of a ``while``: the compiler's ``known_trip_count`` where it
+    prints one (CPU), else the bound its condition compares the counter
+    against (TPU: ``compare(i, constant(N)), direction=LT`` — what a
+    ``lax.scan`` lowers to); 1 where neither can be read."""
+    m = re.search(r'"known_trip_count":\{"n":"(\d+)"', while_line)
+    if m:
+        return int(m.group(1))
+    cond = re.search(r"\bcondition=%([\w.\-]+)", while_line)
+    lines = comps.get(cond.group(1), ()) if cond else ()
+    bounds = [int(n) for ln in lines
+              for n in re.findall(r" s32\[\]\S* constant\((\d+)\)", ln)]
+    if len(bounds) == 1 and any("direction=LT" in ln for ln in lines):
+        return bounds[0]
+    return 1
+
+
+def compiled_collectives(hlo_text: str) -> List[Dict[str, Any]]:
+    """Every collective instruction of a compiled program, sync or the
+    ``-start`` of an async pair, in any computation (the TPU backend wraps
+    async ones in fusions): ``kind``; ``shapes``, the arrays on the
+    instruction's FULL side (an all-gather's results, a reduce-scatter's
+    operands, else either); ``bytes`` of its operands; ``runs``, how often
+    a step executes it (the product of the trips of the ``while`` bodies
+    around it, ``_trip_count``); ``in_loop``; and ``op_name``, the jax op it
+    was made for."""
+    comps: Dict[str, List[str]] = {}
+    name = entry = None
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            is_entry = line.startswith("ENTRY")
+            name = line.split()[1 if is_entry else 0].lstrip("%")
+            comps[name] = []
+            entry = name if is_entry else entry
+        elif name is not None:
+            comps[name].append(line)
+
+    # computation -> [(caller, executions of it a run of the caller)]
+    callers: Dict[str, List[Tuple[str, int, bool]]] = {}
+    for comp, lines in comps.items():
+        for line in lines:
+            for key, one, many in _CALLED.findall(line):
+                if key == "to_apply" and _COLLECTIVE_INSTR.match(line):
+                    continue  # the reduction's scalar combiner, not a call
+                trips = _trip_count(line, comps) if key == "body" else 1
+                for callee in [one] if one else re.findall(r"%([\w.\-]+)", many):
+                    callers.setdefault(callee, []).append((comp, trips, key == "body"))
+
+    seen: Dict[str, Tuple[int, bool]] = {}
+
+    def runs(comp: str) -> Tuple[int, bool]:
+        if comp == entry:
+            return 1, False
+        if comp not in seen:
+            seen[comp] = (0, False)  # a cycle cannot occur in HLO; be safe
+            total, looped = 0, False
+            for caller, trips, is_body in callers.get(comp, ()):
+                n, in_loop = runs(caller)
+                total += n * trips
+                looped |= (is_body or in_loop) and n > 0
+            seen[comp] = (total, looped)
+        return seen[comp]
+
+    out = []
+    for comp, lines in comps.items():
+        for line in lines:
+            m = _COLLECTIVE_INSTR.match(line)
+            if not m:
+                continue
+            kind, n_runs = m.group("kind"), runs(comp)
+            arrays = [(d, [int(x) for x in dims.split(",") if x])
+                      for d, dims in _HLO_ARRAY.findall(m.group("type"))]
+            if m.group("start") and kind in ("all-gather", "collective-permute"):
+                # (operands..., results..., [two u32 contexts])
+                arrays = [a for a in arrays if a[1] or a[0] != "u32"]
+                arrays = arrays[len(arrays) // 2:]
+            group = _group_size(line)
+            nbytes = sum(_DTYPE_BYTES.get(d, 4) * math.prod(s) for d, s in arrays)
+            if kind == "all-gather":
+                nbytes //= group
+            elif kind == "all-reduce" and comp.startswith("all-reduce-scatter"):
+                # the TPU backend's reduce-scatter: an all-reduce and the
+                # slice of it, in a fusion of that name
+                kind = "reduce-scatter"
+            elif kind == "reduce-scatter":
+                nbytes *= group
+                dim = re.search(r"dimensions=\{(\d+)\}", line)
+                if dim:
+                    for _, s in arrays:
+                        s[int(dim.group(1))] *= group
+            op = re.search(r'op_name="([^"]*)"', line)
+            out.append({
+                "kind": kind,
+                "shapes": [f"{d}[{','.join(map(str, s))}]" for d, s in arrays],
+                "bytes": nbytes, "runs": n_runs[0], "in_loop": n_runs[1],
+                "op_name": op.group(1) if op else "",
+            })
+    return out
+
+
+def collectives_summary(ops: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """``compiled_collectives`` by kind, as a step executes them: ``count``
+    and operand ``bytes`` (an instruction in a ``while`` body counts its
+    trips), ``in_loop_max_bytes`` (the largest operand of that kind that
+    sits in a ``while`` body; 0: none does), and the ``largest`` single
+    instruction of all."""
+    out: Dict[str, Any] = {}
+    for op in ops:
+        k = out.setdefault(
+            op["kind"], {"count": 0, "bytes": 0, "in_loop_max_bytes": 0})
+        k["count"] += op["runs"]
+        k["bytes"] += op["runs"] * op["bytes"]
+        if op["in_loop"]:
+            k["in_loop_max_bytes"] = max(k["in_loop_max_bytes"], op["bytes"])
+    if ops:
+        top = max(ops, key=lambda op: op["bytes"])
+        out["largest"] = f"{top['kind']} {' '.join(top['shapes'])}"
+    return out

@@ -16,12 +16,16 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import optax
 
+from tf_operator_tpu.parallel.collectives import (
+    collectives_summary,
+    compiled_collectives,
+)
 from tf_operator_tpu.parallel.sharding import DEFAULT_RULES, ShardingRules, replicated
 
 
@@ -141,6 +145,10 @@ class Trainer:
         self._init_jit = None
         self._step_jit = None
         self._step_compiled = None
+        # what the compiled step's collectives are (compile_step): by kind,
+        # count and operand bytes a step, the largest operand inside a
+        # ``while`` body, and the largest instruction; None until compiled
+        self.step_collectives: Optional[Dict[str, Any]] = None
         self._precompile_error = None
         self._compiled_hits = 0
         self._compiled_rejections = 0
@@ -289,7 +297,9 @@ class Trainer:
         ``jax.stages.Compiled`` — its ``as_text()`` is the program the
         device runs (what the chip smoke counts ``tpu_custom_call`` in)
         and timing this call is the step's compile time, cleanly apart
-        from its first execution. A compile failure raises."""
+        from its first execution. A compile failure raises. The program's
+        collectives are counted as it is kept (``step_collectives``: the
+        compile-time receipt of how the step was partitioned)."""
         from jax.sharding import NamedSharding
 
         tmpl = self.state_template()
@@ -310,6 +320,8 @@ class Trainer:
         self._step_compiled = self._step_jit.lower(
             tmpl.params, tmpl.opt_state, tmpl.step, tmpl.extra, batch_spec,
         ).compile()
+        self.step_collectives = collectives_summary(
+            compiled_collectives(self._step_compiled.as_text()))
         return self._step_compiled
 
     def precompile_step_async(self, batch):

@@ -232,6 +232,8 @@ def main(ctx: JobContext) -> None:
         seq_len=seq, n_layers=cfg.n_layers, attn=cfg.attn_impl,
         step_compile_s=round(compile_s, 3),
         step_tpu_custom_calls=step_kernels,
+        # how the compiled step was partitioned: its collectives by kind
+        step_collectives=trainer.step_collectives,
         step_s=step_s, losses=ckpt.loss_trace(),
         # how well the prefetch hid the input pipeline (None: no loader)
         loader=None if loader is None else {
