@@ -55,6 +55,12 @@ int record(long pid, int status) {
   return e.code;
 }
 
+// True while the registry holds a slot for pid, reaped or not.
+bool tracked(long pid) {
+  std::lock_guard<std::mutex> l(g_mu);
+  return g_procs.count(pid) != 0;
+}
+
 bool lookup(long pid, int* code) {
   std::lock_guard<std::mutex> l(g_mu);
   auto it = g_procs.find(pid);
@@ -156,8 +162,11 @@ int tpuj_wait(long pid) {
     if (r == (pid_t)pid) return record(pid, status);
     if (r < 0 && errno == EINTR) continue;
     if (r < 0 && errno == ECHILD) {
-      // Another thread won the waitpid race; its record() is imminent.
-      for (int i = 0; i < 2000; ++i) {
+      // Another thread won the waitpid race; its record() is imminent —
+      // unless that winner has already consumed the code and dropped the
+      // slot (tpuj_forget): then there is nothing left to wait for, and
+      // polling on cost a terminate that lost the race its whole 10 s.
+      for (int i = 0; i < 2000 && tracked(pid); ++i) {
         if (lookup(pid, &code)) return code;
         sleep_ms(5);
       }

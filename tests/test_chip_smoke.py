@@ -17,7 +17,7 @@ def test_chip_smoke_rehearsal_runs_every_phase_and_fails_without_a_tpu():
     proc = subprocess.run(
         [sys.executable, os.path.join(ROOT, "chip_smoke.py"),
          "--preset", "tiny"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600,
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=240,
     )
     lines = [json.loads(ln) for ln in proc.stdout.splitlines() if ln.strip()]
     phases = {ln["phase"]: ln for ln in lines if "phase" in ln}

@@ -115,6 +115,21 @@ def test_process_logs_served(stack):
     )
 
 
+def test_logs_of_a_process_not_yet_spawned_are_empty(stack, tmp_path):
+    """The log path is annotated when the process is created and the file is
+    opened when its child is spawned: a read between the two is an empty log,
+    not a 500 (``test_process_logs_served`` polled into that window under
+    load and failed on the error, CHANGES.md PRs 23-25 and 32)."""
+    from tf_operator_tpu.runtime import Process, ProcessSpec
+
+    store, client, _ = stack
+    store.create(Process(
+        metadata=ObjectMeta(name="unborn", annotations={
+            LocalProcessControl.LOG_ANNOTATION: str(tmp_path / "logs" / "unborn.log")}),
+        spec=ProcessSpec(job_name="j", replica_type="Worker")))
+    assert client.logs("default", "unborn") == ""
+
+
 def test_events_surface(stack):
     _, client, _ = stack
     client.create(make_job("eventful"))

@@ -95,7 +95,7 @@ def test_smoke_two_process_gang(rig):
     store.create(job)
     ok = wait_for(
         lambda: has_condition(job_status(store, "smoke2"), ConditionType.SUCCEEDED),
-        timeout=120,
+        timeout=60,
     )
     st = job_status(store, "smoke2")
     assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"
@@ -129,138 +129,10 @@ def test_mnist_data_parallel_training(rig):
     store.create(job)
     ok = wait_for(
         lambda: has_condition(job_status(store, "mnist-dp"), ConditionType.SUCCEEDED),
-        timeout=120,
+        timeout=60,
     )
     st = job_status(store, "mnist-dp")
     assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"
-
-
-def test_real_data_mnist_gang_reaches_accuracy(rig_api, tmp_path):
-    """VERDICT #2 done-bar: REAL data end to end. Real scanned-digit
-    images (sklearn's UCI digits — this environment has no egress to
-    download MNIST itself) are written in the exact MNIST idx wire format;
-    a 2-process gang reads disjoint shards through the DeviceLoader,
-    trains SPMD, and must reach >95% test accuracy — the same proof
-    dist_mnist.py gives the reference (test/e2e/dist-mnist). The accuracy
-    flows back through the API into TPUJobStatus.eval_metrics."""
-    import numpy as np
-
-    sklearn_datasets = pytest.importorskip(
-        "sklearn.datasets", reason="real-digits fixture needs scikit-learn"
-    )
-    load_digits = sklearn_datasets.load_digits
-
-    from tf_operator_tpu.train.data import write_idx
-
-    digits = load_digits()
-    order = np.random.default_rng(0).permutation(len(digits.target))
-    images = (digits.images * (255.0 / 16.0)).astype(np.uint8)[order]  # [1797,8,8]
-    labels = digits.target.astype(np.uint8)[order]
-    n_train = 1500
-    data_dir = tmp_path / "digits"
-    data_dir.mkdir()
-    write_idx(str(data_dir / "train-images-idx3-ubyte.gz"), images[:n_train])
-    write_idx(str(data_dir / "train-labels-idx1-ubyte.gz"), labels[:n_train])
-    write_idx(str(data_dir / "t10k-images-idx3-ubyte"), images[n_train:])
-    write_idx(str(data_dir / "t10k-labels-idx1-ubyte"), labels[n_train:])
-
-    store = rig_api
-    job = TPUJob(
-        metadata=ObjectMeta(name="mnist-real"),
-        spec=TPUJobSpec(
-            replica_specs={
-                ReplicaType.WORKER: ReplicaSpec(
-                    replicas=2,
-                    template=ProcessTemplate(
-                        entrypoint="tf_operator_tpu.workloads.mnist:main",
-                        env=dict(DATAPLANE_ENV),
-                    ),
-                )
-            },
-        ),
-    )
-    job.spec.workload = {
-        "data_dir": str(data_dir),
-        "epochs": 30,
-        "batch_size": 128,
-        "hidden": 128,
-        "lr": 0.1,
-        "target_accuracy": 0.95,  # the workload itself fails below this
-    }
-    store.create(job)
-    ok = wait_for(
-        lambda: has_condition(job_status(store, "mnist-real"), ConditionType.SUCCEEDED),
-        timeout=240,
-    )
-    st = job_status(store, "mnist-real")
-    assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"
-    # accuracy surfaced through the API into eval_metrics
-    assert st.eval_metrics.get("metrics", {}).get("accuracy", 0) > 0.95, st.eval_metrics
-
-
-def test_real_image_resnet_gang_reaches_accuracy(rig_api, tmp_path):
-    """VERDICT r2 #7 done-bar: the ResNet path trains REAL images end to
-    end — idx files -> 3-channel/32px prepare -> random-crop augmentation
-    -> DeviceLoader shards across a 2-process gang -> sharded Trainer ->
-    eval-mode (running BN stats) test accuracy, gated and reported into
-    eval_metrics. The ResNet counterpart of the dist_mnist proof
-    (test-scale `tiny` variant: same stem/BN/residual machinery at CPU-CI
-    cost; calibrated single-process accuracy 0.99)."""
-    import numpy as np
-
-    sklearn_datasets = pytest.importorskip(
-        "sklearn.datasets", reason="real-digits fixture needs scikit-learn"
-    )
-    from tf_operator_tpu.train.data import write_idx
-
-    digits = sklearn_datasets.load_digits()
-    order = np.random.default_rng(0).permutation(len(digits.target))
-    images = (digits.images * (255.0 / 16.0)).astype(np.uint8)[order]
-    labels = digits.target.astype(np.uint8)[order]
-    n_train = 1500
-    data_dir = tmp_path / "digits"
-    data_dir.mkdir()
-    write_idx(str(data_dir / "train-images-idx3-ubyte.gz"), images[:n_train])
-    write_idx(str(data_dir / "train-labels-idx1-ubyte.gz"), labels[:n_train])
-    write_idx(str(data_dir / "t10k-images-idx3-ubyte"), images[n_train:])
-    write_idx(str(data_dir / "t10k-labels-idx1-ubyte"), labels[n_train:])
-
-    store = rig_api
-    job = TPUJob(
-        metadata=ObjectMeta(name="resnet-real"),
-        spec=TPUJobSpec(
-            replica_specs={
-                ReplicaType.WORKER: ReplicaSpec(
-                    replicas=2,
-                    template=ProcessTemplate(
-                        entrypoint="tf_operator_tpu.workloads.resnet:main",
-                        env=dict(DATAPLANE_ENV),
-                    ),
-                )
-            },
-        ),
-    )
-    job.spec.workload = {
-        "data": "idx",
-        "data_dir": str(data_dir),
-        "variant": "tiny",
-        "num_classes": 10,
-        "image_size": 32,
-        "epochs": 20,
-        "batch_size": 256,
-        "lr": 0.02,
-        "augment": True,
-        "flip": False,  # digits are orientation-sensitive
-        "target_accuracy": 0.95,  # the workload itself fails below this
-    }
-    store.create(job)
-    ok = wait_for(
-        lambda: has_condition(job_status(store, "resnet-real"), ConditionType.SUCCEEDED),
-        timeout=360,
-    )
-    st = job_status(store, "resnet-real")
-    assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"
-    assert st.eval_metrics.get("metrics", {}).get("accuracy", 0) > 0.95, st.eval_metrics
 
 
 def test_lm_memmap_corpus_gang(rig, tmp_path):
@@ -332,7 +204,7 @@ def test_lm_memmap_corpus_gang(rig, tmp_path):
     store.create(job)
     ok = wait_for(
         lambda: has_condition(job_status(store, "lm-memmap"), ConditionType.SUCCEEDED),
-        timeout=240,
+        timeout=120,
     )
     st = job_status(store, "lm-memmap")
     assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"
@@ -378,7 +250,7 @@ def test_ring_attention_context_parallel_gang(rig):
     store.create(job)
     ok = wait_for(
         lambda: has_condition(job_status(store, "ring-cp"), ConditionType.SUCCEEDED),
-        timeout=240,
+        timeout=90,
     )
     st = job_status(store, "ring-cp")
     assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"
@@ -421,7 +293,7 @@ def test_hybrid_dcn_mesh_gang(rig):
     store.create(job)
     ok = wait_for(
         lambda: has_condition(job_status(store, "hybrid-dcn"), ConditionType.SUCCEEDED),
-        timeout=240,
+        timeout=90,
     )
     st = job_status(store, "hybrid-dcn")
     assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"
@@ -460,7 +332,7 @@ def test_pipeline_parallel_gang(rig):
     store.create(job)
     ok = wait_for(
         lambda: has_condition(job_status(store, "pp-gang"), ConditionType.SUCCEEDED),
-        timeout=240,
+        timeout=90,
     )
     st = job_status(store, "pp-gang")
     assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"
@@ -508,7 +380,7 @@ def test_checkpoint_resume_across_gang_restart(tmp_path):
             lambda: has_condition(
                 job_status(store, "phoenix-lm"), ConditionType.SUCCEEDED
             ),
-            timeout=240,
+            timeout=150,
         )
         st = job_status(store, "phoenix-lm")
         assert ok, (
@@ -549,7 +421,7 @@ def test_bad_entrypoint_is_permanent_failure(rig):
     store.create(job)
     ok = wait_for(
         lambda: has_condition(job_status(store, "ghost"), ConditionType.FAILED),
-        timeout=120,
+        timeout=60,
     )
     st = job_status(store, "ghost")
     assert ok, f"conditions: {[(c.type.value, c.reason) for c in st.conditions]}"
@@ -588,128 +460,10 @@ def test_lm_training_streams_through_device_loader(rig):
     store.create(job)
     ok = wait_for(
         lambda: has_condition(job_status(store, "lm-stream"), ConditionType.SUCCEEDED),
-        timeout=240,
+        timeout=90,
     )
     st = job_status(store, "lm-stream")
     assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"
-
-
-def test_evaluator_scores_checkpoints_alongside_training(rig, tmp_path):
-    """The Evaluator role doing real work (the reference defines the role
-    but no behavior): one job runs a 2-process LM training gang that
-    checkpoints, plus an Evaluator replica — outside the gang — polling
-    the same checkpoint_dir and scoring each checkpoint. Job success is
-    chief-driven (reference semantics: worker-0), so the evaluator's work
-    is asserted through its report artifact, which also catches
-    reader-staleness bugs — the evaluator here starts BEFORE any
-    checkpoint exists."""
-    store = rig
-    ckpt_dir = str(tmp_path / "ckpt")
-    report = str(tmp_path / "eval_report.json")
-    job = TPUJob(
-        metadata=ObjectMeta(name="train-eval"),
-        spec=TPUJobSpec(
-            replica_specs={
-                ReplicaType.WORKER: ReplicaSpec(
-                    replicas=2,
-                    template=ProcessTemplate(
-                        entrypoint="tf_operator_tpu.workloads.lm:main",
-                        env=dict(DATAPLANE_ENV),
-                    ),
-                ),
-                ReplicaType.EVALUATOR: ReplicaSpec(
-                    replicas=1,
-                    template=ProcessTemplate(
-                        entrypoint="tf_operator_tpu.workloads.eval:main",
-                        env=dict(DATAPLANE_ENV),
-                    ),
-                ),
-            },
-        ),
-    )
-    job.spec.workload = {
-        "preset": "tiny",
-        "steps": 6,
-        "batch_size": 4,
-        "seq_len": 32,
-        "checkpoint_dir": ckpt_dir,
-        "checkpoint_every": 2,
-        # evaluator keys (same shared workload dict). train_steps=2 so the
-        # evaluator finishes BEFORE the trainers: job success is
-        # chief-driven and cleanup kills whatever is still running, so an
-        # evaluator that needed the final checkpoint would race it.
-        "train_steps": 2,
-        "eval_batch_size": 4,
-        "eval_seq_len": 32,
-        "eval_batches": 1,
-        "poll_interval_s": 0.2,
-        "max_wait_s": 120,
-        "eval_report": report,
-    }
-    store.create(job)
-    ok = wait_for(
-        lambda: has_condition(job_status(store, "train-eval"), ConditionType.SUCCEEDED),
-        timeout=240,
-    )
-    st = job_status(store, "train-eval")
-    assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"
-
-    # Whether the evaluator got a score in before success-cleanup killed it
-    # is a timing race at this toy scale (compile time >> train time), so
-    # the report is not asserted here — evaluator liveness against a live
-    # writer is covered deterministically by
-    # tests/test_eval_workload.py::test_eval_concurrent_with_live_writer,
-    # and the operator-launched scoring path by
-    # test_eval_scoring_job_over_existing_checkpoints below.
-
-
-def test_eval_scoring_job_over_existing_checkpoints(rig, tmp_path):
-    """The scoring workload through the full operator path: a one-shot
-    eval job (worker-0 is the chief — Evaluator-ONLY jobs are rejected at
-    admission since nothing would drive job state) over a pre-existing
-    checkpoint directory; Succeeded requires the report artifact, so the
-    launched process really scored."""
-    import json
-
-    from tests.test_eval_workload import _save_checkpoints
-
-    store = rig
-    ckpt_dir = tmp_path / "ckpt"
-    _save_checkpoints(ckpt_dir, steps={2})
-    report = str(tmp_path / "report.json")
-    job = TPUJob(
-        metadata=ObjectMeta(name="eval-only"),
-        spec=TPUJobSpec(
-            replica_specs={
-                ReplicaType.WORKER: ReplicaSpec(
-                    replicas=1,
-                    template=ProcessTemplate(
-                        entrypoint="tf_operator_tpu.workloads.eval:main",
-                        env=dict(DATAPLANE_ENV),
-                    ),
-                ),
-            },
-        ),
-    )
-    job.spec.workload = {
-        "preset": "tiny",
-        "checkpoint_dir": str(ckpt_dir),
-        "eval_batch_size": 4,
-        "eval_seq_len": 32,
-        "eval_batches": 1,
-        "poll_interval_s": 0.1,
-        "max_wait_s": 60,
-        "eval_report": report,
-    }
-    store.create(job)
-    ok = wait_for(
-        lambda: has_condition(job_status(store, "eval-only"), ConditionType.SUCCEEDED),
-        timeout=240,
-    )
-    st = job_status(store, "eval-only")
-    assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"
-    with open(report) as f:
-        assert "2" in json.load(f)
 
 
 def test_moe_expert_parallel_gang(rig):
@@ -741,7 +495,7 @@ def test_moe_expert_parallel_gang(rig):
     store.create(job)
     ok = wait_for(
         lambda: has_condition(job_status(store, "moe-ep"), ConditionType.SUCCEEDED),
-        timeout=240,
+        timeout=120,
     )
     st = job_status(store, "moe-ep")
     assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"
@@ -791,7 +545,7 @@ def test_jobs_survive_chaos_kills(tmp_path):
         # parallel with benches) compile alone can eat minutes, and this
         # test measured the only load-dependent flake of the r4 suite
         assert wait_for(
-            lambda: job_status(store, "chaos-lm").restart_count >= 1, timeout=300
+            lambda: job_status(store, "chaos-lm").restart_count >= 1, timeout=120
         ), "chaos never killed anything"
         monkey.stop()
         # ...and the job still completes
@@ -799,7 +553,7 @@ def test_jobs_survive_chaos_kills(tmp_path):
             lambda: has_condition(
                 job_status(store, "chaos-lm"), ConditionType.SUCCEEDED
             ),
-            timeout=360,
+            timeout=120,
         )
         st = job_status(store, "chaos-lm")
         assert ok, (
@@ -810,97 +564,6 @@ def test_jobs_survive_chaos_kills(tmp_path):
         monkey.stop()
         ctl.stop()
         pc.shutdown()
-
-
-def test_resnet_evaluator_reports_accuracy(rig_api, tmp_path):
-    """VERDICT r3 #7b done-bar: a resnet_real_idx-class job with an
-    EVALUATOR replica reporting accuracy into eval_metrics. The trainer
-    gang checkpoints (params + BN stats); the evaluator — model="resnet",
-    outside the gang — restores both subtrees per checkpoint and scores
-    test-split accuracy through the same idx reader."""
-    import numpy as np
-
-    sklearn_datasets = pytest.importorskip(
-        "sklearn.datasets", reason="real-digits fixture needs scikit-learn"
-    )
-    from tf_operator_tpu.train.data import write_idx
-
-    digits = sklearn_datasets.load_digits()
-    order = np.random.default_rng(0).permutation(len(digits.target))
-    images = (digits.images * (255.0 / 16.0)).astype(np.uint8)[order]
-    labels = digits.target.astype(np.uint8)[order]
-    data_dir = tmp_path / "digits"
-    data_dir.mkdir()
-    write_idx(str(data_dir / "train-images-idx3-ubyte.gz"), images[:1500])
-    write_idx(str(data_dir / "train-labels-idx1-ubyte.gz"), labels[:1500])
-    write_idx(str(data_dir / "t10k-images-idx3-ubyte"), images[1500:])
-    write_idx(str(data_dir / "t10k-labels-idx1-ubyte"), labels[1500:])
-
-    store = rig_api
-    ckpt_dir = str(tmp_path / "ckpt")
-    report = str(tmp_path / "eval_report.json")
-    job = TPUJob(
-        metadata=ObjectMeta(name="resnet-eval"),
-        spec=TPUJobSpec(
-            replica_specs={
-                ReplicaType.WORKER: ReplicaSpec(
-                    replicas=1,
-                    template=ProcessTemplate(
-                        entrypoint="tf_operator_tpu.workloads.resnet:main",
-                        env=dict(DATAPLANE_ENV),
-                    ),
-                ),
-                ReplicaType.EVALUATOR: ReplicaSpec(
-                    replicas=1,
-                    template=ProcessTemplate(
-                        entrypoint="tf_operator_tpu.workloads.eval:main",
-                        env=dict(DATAPLANE_ENV),
-                    ),
-                ),
-            },
-        ),
-    )
-    job.spec.workload = {
-        "data": "idx",
-        "data_dir": str(data_dir),
-        "variant": "tiny",
-        "num_classes": 10,
-        "image_size": 32,
-        "epochs": 4,
-        "batch_size": 256,
-        "lr": 0.02,
-        "augment": True,
-        "flip": False,
-        "checkpoint_dir": ckpt_dir,
-        "checkpoint_every": 2,
-        # evaluator keys: model selects the resnet scorer; train_steps=2
-        # so the evaluator finishes BEFORE the trainer (job success is
-        # chief-driven; cleanup kills stragglers — same protocol as the
-        # LM evaluator e2e above)
-        "model": "resnet",
-        "train_steps": 2,
-        "eval_batch_size": 64,
-        "poll_interval_s": 0.2,
-        "max_wait_s": 180,
-        "eval_report": report,
-    }
-    store.create(job)
-    ok = wait_for(
-        lambda: has_condition(job_status(store, "resnet-eval"), ConditionType.SUCCEEDED),
-        timeout=360,
-    )
-    st = job_status(store, "resnet-eval")
-    assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"
-    # the trainer's own end-of-run gate also reports accuracy; the
-    # EVALUATOR's per-checkpoint scoring is asserted via its report
-    # artifact — written before job cleanup because train_steps=2 ends
-    # the evaluator while the trainer still has epochs to run, so its
-    # absence means the scoring path is broken, not a timing race
-    import json as _json
-
-    scored = _json.loads(open(report).read())
-    assert scored and all(0.0 <= v <= 1.0 for v in scored.values()), scored
-    assert "metrics" in st.eval_metrics, st.eval_metrics
 
 
 def test_moe_pipeline_ep_gang(rig):
@@ -941,7 +604,7 @@ def test_moe_pipeline_ep_gang(rig):
     store.create(job)
     ok = wait_for(
         lambda: has_condition(job_status(store, "moe-ppep"), ConditionType.SUCCEEDED),
-        timeout=420,
+        timeout=120,
     )
     st = job_status(store, "moe-ppep")
     assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"

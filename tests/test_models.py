@@ -261,7 +261,9 @@ def test_resnet50_shapes():
     params, state = init_resnet(jax.random.PRNGKey(0), cfg)
     n = sum(x.size for x in jax.tree_util.tree_leaves(params))
     assert 25e6 < n < 26e6, n  # ResNet-50 ≈ 25.5M params
-    logits, _ = resnet_forward(
-        params, state, jnp.zeros((2, 64, 64, 3)), cfg, train=True
+    # one compiled call, as the trainer's step is: run eagerly the 53 layers
+    # dispatch op by op, 14 s alone for 2 s compiled (PR 32)
+    logits, _ = jax.jit(lambda p, s, x: resnet_forward(p, s, x, cfg, train=True))(
+        params, state, jnp.zeros((2, 64, 64, 3))
     )
     assert logits.shape == (2, 1000)

@@ -1,13 +1,22 @@
 """MoE transformer, the ep-SHARDED gmm dispatch: the second half of
 tests/test_moe_transformer.py as a file of its own, so that ``--dist
 loadfile`` hands the two halves to two workers (together they were one
-worker's 1,400 s, the whole tier-1 run's length; PR 31)."""
+worker's 1,400 s, the whole tier-1 run's length; PR 31).
+
+Every case evaluates its path and its oracle through ONE compiled call each
+(``conftest.jit_out_and_grads`` / ``jit_value_and_grad``), as the trainer's
+step does: run eagerly, the ``shard_map`` body and the interpreted gmm kernel
+in it cost 18 s a forward and 41 s a gradient at these sizes, against 3.8 s
+for the jitted ``value_and_grad`` (PR 32). The jitted callables are built
+inside each test, after it has set ``TPUJOB_GMM_BLOCK_ROWS``: the block
+quantum is read at trace time."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import jit_out_and_grads, jit_value_and_grad
 from tf_operator_tpu.models.transformer import init_transformer, preset
 from tf_operator_tpu.parallel import build_mesh
 
@@ -19,7 +28,8 @@ from tf_operator_tpu.parallel import build_mesh
 # path at no-drop capacity (identical math when nothing drops).
 
 
-def test_ep_gmm_matches_capacity_oracle_on_flagship_mesh(monkeypatch):
+@pytest.mark.parametrize("k_top", [1, 2])
+def test_ep_gmm_matches_capacity_oracle_on_flagship_mesh(k_top, monkeypatch):
     """moe_apply level, the mixtral dp x fsdp x ep layout, k_top 1 and 2,
     fwd AND grads (x, router logits, expert weights). block_rows=8 so
     the per-(source, expert) block-quantum rounding actually engages at
@@ -48,35 +58,26 @@ def test_ep_gmm_matches_capacity_oracle_on_flagship_mesh(monkeypatch):
     wps = jax.tree_util.tree_map(
         lambda a: jax.device_put(a, NamedSharding(mesh, P("ep", "fsdp"))), wp)
 
-    for k_top in (1, 2):
-        want, wstats = moe_apply(xs, gls, wps, efn, mesh, capacity_factor=8.0,
-                                 k_top=k_top, dropped="zero",
-                                 return_stats=True)
-        got, stats = moe_apply(xs, gls, wps, efn, mesh, k_top=k_top,
-                               dispatch_impl="gmm", return_stats=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
-        # router telemetry agrees with the capacity path and drops are
-        # structurally impossible
-        np.testing.assert_allclose(np.asarray(stats["expert_load"]),
-                                   np.asarray(wstats["expert_load"]),
-                                   atol=1e-6)
-        assert float(stats["drop_frac"]) == 0.0
+    def run(**kw):
+        return jit_out_and_grads(
+            lambda x_, gl_, wp_: moe_apply(x_, gl_, wp_, efn, mesh, k_top=k_top,
+                                           return_stats=True, **kw),
+            xs, gls, wps, argnums=(0, 1, 2))
 
-        def loss(impl):
-            def fn(x_, gl_, wp_):
-                kw = (dict(dispatch_impl="gmm") if impl == "gmm"
-                      else dict(capacity_factor=8.0, dropped="zero"))
-                return jnp.sum(moe_apply(
-                    x_, gl_, wp_, efn, mesh, k_top=k_top, **kw) ** 2)
-            return fn
-
-        g1 = jax.grad(loss("gmm"), argnums=(0, 1, 2))(xs, gls, wps)
-        g2 = jax.grad(loss("cap"), argnums=(0, 1, 2))(xs, gls, wps)
-        for a, b in zip(jax.tree_util.tree_leaves(g1),
-                        jax.tree_util.tree_leaves(g2)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=5e-5, atol=5e-5)
+    (want, wstats), g2 = run(capacity_factor=8.0, dropped="zero")
+    (got, stats), g1 = run(dispatch_impl="gmm")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    # router telemetry agrees with the capacity path and drops are
+    # structurally impossible
+    np.testing.assert_allclose(np.asarray(stats["expert_load"]),
+                               np.asarray(wstats["expert_load"]),
+                               atol=1e-6)
+    assert float(stats["drop_frac"]) == 0.0
+    for a, b in zip(jax.tree_util.tree_leaves(g1),
+                    jax.tree_util.tree_leaves(g2)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-5, atol=5e-5)
 
 
 def test_ep_gmm_through_transformer_moe_fsdp(monkeypatch):
@@ -94,15 +95,18 @@ def test_ep_gmm_through_transformer_moe_fsdp(monkeypatch):
     tok = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab)
     mesh = build_mesh({"dp": 2, "fsdp": 2, "ep": 2})
 
-    def ce(p, c, m):
-        return lm_loss_and_metrics(p, tok, c, mesh=m)[1]["ce_loss"]
+    def ce(c, m):
+        return jit_value_and_grad(
+            lambda p: lm_loss_and_metrics(p, tok, c, mesh=m)[1]["ce_loss"],
+            params)
 
-    got = float(ce(params, cfg, mesh))
-    np.testing.assert_allclose(got, float(ce(params, cfg_sort, mesh)),
-                               rtol=2e-5)
-    np.testing.assert_allclose(got, float(ce(params, cfg, None)), rtol=2e-5)
-    g1 = jax.grad(lambda p: ce(p, cfg, mesh))(params)
-    g2 = jax.grad(lambda p: ce(p, cfg_sort, mesh))(params)
+    got, g1 = ce(cfg, mesh)
+    want, g2 = ce(cfg_sort, mesh)
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-5)
+    np.testing.assert_allclose(
+        float(got),
+        float(jax.jit(lambda p: lm_loss_and_metrics(
+            p, tok, cfg, mesh=None)[1]["ce_loss"])(params)), rtol=2e-5)
     for (pa, a), (_, b) in zip(jax.tree_util.tree_leaves_with_path(g1),
                                jax.tree_util.tree_leaves_with_path(g2)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
@@ -129,13 +133,14 @@ def test_ep_gmm_pipeline_in_stage(schedule, monkeypatch):
     params = init_transformer(jax.random.PRNGKey(0), cfg)
     tok = jax.random.randint(jax.random.PRNGKey(1), (16, 32), 0, cfg.vocab)
 
-    def ce(p, c):
-        return lm_loss_and_metrics(p, tok, c, mesh=mesh)[1]["ce_loss"]
+    def ce(c):
+        return jit_value_and_grad(
+            lambda p: lm_loss_and_metrics(p, tok, c, mesh=mesh)[1]["ce_loss"],
+            params)
 
-    np.testing.assert_allclose(float(ce(params, cfg)),
-                               float(ce(params, cfg_sort)), rtol=2e-5)
-    g1 = jax.grad(lambda p: ce(p, cfg))(params)
-    g2 = jax.grad(lambda p: ce(p, cfg_sort))(params)
+    got, g1 = ce(cfg)
+    want, g2 = ce(cfg_sort)
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-5)
     for (pa, a), (_, b) in zip(jax.tree_util.tree_leaves_with_path(g1),
                                jax.tree_util.tree_leaves_with_path(g2)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
@@ -172,15 +177,17 @@ def test_ep_gmm_zero_token_expert_gets_zero_grad_across_shards(monkeypatch):
     wps = jax.tree_util.tree_map(
         lambda a: jax.device_put(a, NamedSharding(mesh, P("ep"))), wp)
 
-    g = jax.grad(lambda w: jnp.sum(moe_apply(
-        xs, gls, w, efn, mesh, k_top=1, dispatch_impl="gmm") ** 2))(wps)
+    _, g = jit_out_and_grads(
+        lambda w: moe_apply(xs, gls, w, efn, mesh, k_top=1,
+                            dispatch_impl="gmm"), wps)
     for name in g:
         np.testing.assert_array_equal(np.asarray(g[name][1:]), 0.0)
         assert np.isfinite(np.asarray(g[name])).all()
         assert np.abs(np.asarray(g[name][0])).sum() > 0
 
 
-def test_ep_gmm_uneven_shard_loads_block_quantum_edge(monkeypatch):
+@pytest.mark.parametrize("k_top", [1, 2])
+def test_ep_gmm_uneven_shard_loads_block_quantum_edge(k_top, monkeypatch):
     """The block-quantum padding edge: skew the router so per-(source,
     expert) counts are UNEVEN and not multiples of the block quantum
     (partial last blocks + empty (source, expert) pairs on the same
@@ -212,20 +219,15 @@ def test_ep_gmm_uneven_shard_loads_block_quantum_edge(monkeypatch):
     wps = jax.tree_util.tree_map(
         lambda a: jax.device_put(a, NamedSharding(mesh, P("ep", "fsdp"))), wp)
 
-    for k_top in (1, 2):
-        want = moe_apply(xs, gls, wps, efn, mesh, capacity_factor=float(E),
-                         k_top=k_top, dropped="zero")
-        got = moe_apply(xs, gls, wps, efn, mesh, k_top=k_top,
-                        dispatch_impl="gmm")
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
-        g1 = jax.grad(lambda w: jnp.sum(moe_apply(
-            xs, gls, w, efn, mesh, k_top=k_top,
-            dispatch_impl="gmm") ** 2))(wps)
-        g2 = jax.grad(lambda w: jnp.sum(moe_apply(
-            xs, gls, w, efn, mesh, capacity_factor=float(E), k_top=k_top,
-            dropped="zero") ** 2))(wps)
-        for name in g1:
-            np.testing.assert_allclose(np.asarray(g1[name]),
-                                       np.asarray(g2[name]), rtol=5e-5,
-                                       atol=5e-5, err_msg=name)
+    def run(**kw):
+        return jit_out_and_grads(
+            lambda w: moe_apply(xs, gls, w, efn, mesh, k_top=k_top, **kw), wps)
+
+    want, g2 = run(capacity_factor=float(E), dropped="zero")
+    got, g1 = run(dispatch_impl="gmm")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    for name in g1:
+        np.testing.assert_allclose(np.asarray(g1[name]),
+                                   np.asarray(g2[name]), rtol=5e-5,
+                                   atol=5e-5, err_msg=name)

@@ -1,17 +1,25 @@
 """MoE transformer: Switch-style expert MLP as a model-family variant
 (routing math in parallel/moe.py; here its integration into the
-transformer — params, logical axes, layer body, trainer, ep sharding)."""
+transformer — params, logical axes, layer body, trainer, ep sharding).
+
+Whatever runs on a mesh, or through the interpreted gmm kernel, runs as one
+compiled call (``hidden`` / ``loss_grads`` below, ``conftest.jit_*``), the
+way the trainer's step does: eagerly a ``shard_map`` body is executed
+primitive by primitive on the 8 virtual devices, 10 - 25 x the cost (PR 32)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import jit_out_and_grads, jit_value_and_grad
 from tf_operator_tpu.models.transformer import (
     init_transformer,
     lm_loss,
+    lm_loss_and_metrics,
     preset,
     transformer_forward,
+    transformer_hidden,
     transformer_logical_axes,
 )
 from tf_operator_tpu.parallel import build_mesh
@@ -20,6 +28,26 @@ from tf_operator_tpu.train import Trainer, TrainerConfig
 
 def tokens(batch=4, seq=32, vocab=256, seed=0):
     return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq), 0, vocab)
+
+
+def hidden(params, tok, cfg, mesh, **kw):
+    """``transformer_hidden`` as one compiled call."""
+    return jax.jit(lambda p: transformer_hidden(p, tok, cfg, mesh, **kw))(params)
+
+
+def loss_grads(params, tok, cfg, mesh):
+    """``lm_loss``'s parameter gradients from one compiled call."""
+    return jit_value_and_grad(lambda p: lm_loss(p, tok, cfg, mesh=mesh), params)[1]
+
+
+def ce_and_grads(params, tok, cfg, mesh):
+    """(``ce_loss``, the step's metrics), and the CE's parameter gradients,
+    from one compiled call."""
+    def ce(p):
+        m = lm_loss_and_metrics(p, tok, cfg, mesh=mesh)[1]
+        return m["ce_loss"], m
+
+    return jit_value_and_grad(ce, params, has_aux=True)
 
 
 def test_moe_forward_shape_and_finite():
@@ -172,8 +200,6 @@ def _train_router_ablation(moe_aux_weight, moe_zloss_weight, steps=100):
     """Train tiny-moe from a router init skewed toward expert 0, fresh
     random batches each step (memorizable fixed batches mask the routing
     dynamics). Returns (expert_entropy, drop_frac) on held-out tokens."""
-    from tf_operator_tpu.models.transformer import lm_loss_and_metrics
-
     cfg = preset(
         "tiny-moe", dtype=jnp.float32,
         moe_aux_weight=moe_aux_weight, moe_zloss_weight=moe_zloss_weight,
@@ -230,8 +256,6 @@ def test_aux_losses_repair_router_imbalance_where_no_aux_collapses():
 def test_lm_loss_metrics_expose_router_stats():
     """lm_loss_and_metrics surfaces router telemetry; the scalar lm_loss
     includes the weighted aux terms (ablation: zero weights give pure CE)."""
-    from tf_operator_tpu.models.transformer import lm_loss_and_metrics
-
     cfg = preset("tiny-moe", dtype=jnp.float32)
     params = init_transformer(jax.random.PRNGKey(0), cfg)
     toks = tokens()
@@ -271,15 +295,14 @@ def test_moe_stats_agree_between_single_and_sharded_paths():
     w = {"w": jax.random.normal(jax.random.PRNGKey(2), (n_experts, d, d)) * 0.1}
     expert_fn = lambda wp, t: t @ wp["w"]  # noqa: E731
 
-    _, s_single = moe_apply(
-        x, gate_logits, w, expert_fn, None,
-        capacity_factor=float(n_experts), return_stats=True,
-    )
-    mesh = build_mesh({"ep": jax.device_count()})
-    _, s_shard = moe_apply(
-        x, gate_logits, w, expert_fn, mesh,
-        capacity_factor=float(n_experts), return_stats=True,
-    )
+    def stats(mesh):
+        return jax.jit(lambda x, gl, w: moe_apply(
+            x, gl, w, expert_fn, mesh,
+            capacity_factor=float(n_experts), return_stats=True,
+        ))(x, gate_logits, w)[1]
+
+    s_single = stats(None)
+    s_shard = stats(build_mesh({"ep": jax.device_count()}))
     np.testing.assert_allclose(
         np.asarray(s_single["expert_load"]), np.asarray(s_shard["expert_load"]),
         atol=1e-6,
@@ -312,9 +335,9 @@ def test_moe_lb_gradient_agrees_between_single_and_sharded_paths():
         )
         return n_experts * jnp.sum(stats["expert_load"] * stats["mean_gate"])
 
-    g_single = jax.grad(lb_loss)(gate_logits0, None)
     mesh = build_mesh({"ep": jax.device_count()})
-    g_shard = jax.grad(lb_loss)(gate_logits0, mesh)
+    _, g_single = jit_value_and_grad(lambda gl: lb_loss(gl, None), gate_logits0)
+    _, g_shard = jit_value_and_grad(lambda gl: lb_loss(gl, mesh), gate_logits0)
     np.testing.assert_allclose(
         np.asarray(g_single), np.asarray(g_shard), atol=1e-6
     )
@@ -330,8 +353,6 @@ def test_moe_lb_gradient_agrees_between_single_and_sharded_paths():
 def test_pipeline_transformer_matches_single_device_oracle():
     """pp=4 GPipe forward of the tiny transformer == the plain scan
     forward, exactly (same stacked-params math, f32)."""
-    from tf_operator_tpu.models.transformer import transformer_hidden
-
     cfg_pp = preset("tiny", dtype=jnp.float32, remat=False, pp_microbatches=4)
     cfg_1d = preset("tiny", dtype=jnp.float32, remat=False)
     # 4 layers so pp=4 gives one layer per stage; tiny has 2 — widen it
@@ -341,8 +362,8 @@ def test_pipeline_transformer_matches_single_device_oracle():
     params = init_transformer(jax.random.PRNGKey(0), cfg_pp)
     tok = tokens(batch=8)
     mesh = build_mesh({"pp": 4, "dp": 2})
-    got = transformer_hidden(params, tok, cfg_pp, mesh)
-    want = transformer_hidden(params, tok, cfg_1d, None)
+    got = hidden(params, tok, cfg_pp, mesh)
+    want = hidden(params, tok, cfg_1d, None)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5
     )
@@ -392,16 +413,14 @@ def test_pipeline_moe_forward_matches_single_device(schedule):
     computed per MICROBATCH under pp (each microbatch routes alone), so
     at tight capacity the dropped-token sets legitimately differ from
     full-batch routing — with headroom the math is exactly equal."""
-    from tf_operator_tpu.models.transformer import transformer_hidden
-
     cfg_pp = preset("tiny-moe", dtype=jnp.float32, pp_microbatches=4,
                     pp_schedule=schedule, capacity_factor=8.0)
     cfg_1d = preset("tiny-moe", dtype=jnp.float32, capacity_factor=8.0)
     params = init_transformer(jax.random.PRNGKey(0), cfg_pp)
     tok = tokens(batch=16)
     mesh = build_mesh({"pp": 2, "dp": 4})
-    got, aux = transformer_hidden(params, tok, cfg_pp, mesh, with_aux=True)
-    want, aux_1d = transformer_hidden(params, tok, cfg_1d, None, with_aux=True)
+    got, aux = hidden(params, tok, cfg_pp, mesh, with_aux=True)
+    want, aux_1d = hidden(params, tok, cfg_1d, None, with_aux=True)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5
     )
@@ -433,7 +452,7 @@ def test_pipeline_moe_trains_with_router_gradient(schedule):
         jax.random.randint(jax.random.PRNGKey(1), (16, 32), 0, cfg.vocab),
         trainer.batch_sharding,
     )
-    g = jax.grad(lambda p: lm_loss(p, tok, cfg, mesh=mesh))(state.params)
+    g = loss_grads(state.params, tok, cfg, mesh)
     assert float(jnp.max(jnp.abs(g["layers"]["w_router"]))) > 0.0
     losses = []
     for _ in range(4):
@@ -458,8 +477,8 @@ def test_pipeline_moe_grads_match_single_device():
     params = init_transformer(jax.random.PRNGKey(0), cfg_pp)
     tok = tokens(batch=16)
     mesh = build_mesh({"pp": 2, "dp": 4})
-    g_pp = jax.grad(lambda p: lm_loss(p, tok, cfg_pp, mesh=mesh))(params)
-    g_1d = jax.grad(lambda p: lm_loss(p, tok, cfg_1d, mesh=None))(params)
+    g_pp = loss_grads(params, tok, cfg_pp, mesh)
+    g_1d = loss_grads(params, tok, cfg_1d, None)
     for (path, a), b in zip(
         jax.tree_util.tree_flatten_with_path(g_pp)[0],
         jax.tree_util.tree_leaves(g_1d),
@@ -474,8 +493,6 @@ def test_pipeline_moe_invalid_meshes_rejected():
     """r4: ep INSIDE a pipeline stage is now supported (see the pp x ep
     oracle below) — only MoE + tp-within-stage and indivisible expert
     counts remain rejections."""
-    from tf_operator_tpu.models.transformer import transformer_hidden
-
     cfg_tp = preset("tiny-moe", dtype=jnp.float32, pp_microbatches=2,
                     n_heads=4, n_kv_heads=2)
     params = init_transformer(jax.random.PRNGKey(0), cfg_tp)
@@ -495,16 +512,14 @@ def test_pipeline_moe_invalid_meshes_rejected():
 @pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
 def test_pipeline_schedule_forward_oracle(schedule):
     """Both pipeline schedules produce the exact plain-scan forward."""
-    from tf_operator_tpu.models.transformer import transformer_hidden
-
     cfg_pp = preset("tiny", dtype=jnp.float32, remat=False, pp_microbatches=4,
                     n_layers=4, pp_schedule=schedule)
     cfg_1d = preset("tiny", dtype=jnp.float32, remat=False, n_layers=4)
     params = init_transformer(jax.random.PRNGKey(0), cfg_pp)
     tok = tokens(batch=8)
     mesh = build_mesh({"pp": 4, "dp": 2})
-    got = transformer_hidden(params, tok, cfg_pp, mesh)
-    want = transformer_hidden(params, tok, cfg_1d, None)
+    got = hidden(params, tok, cfg_pp, mesh)
+    want = hidden(params, tok, cfg_1d, None)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5
     )
@@ -514,8 +529,6 @@ def test_pipeline_tp_within_stage_matches_oracle():
     """pp x tp (VERDICT r2 #4): stage weights shard Megatron-style over tp
     (_pp_param_specs), _layer psums its row-parallel products — the
     forward must equal the single-device scan exactly."""
-    from tf_operator_tpu.models.transformer import transformer_hidden
-
     cfg_pp = preset("tiny", dtype=jnp.float32, remat=False, pp_microbatches=4,
                     n_layers=2, n_heads=4, n_kv_heads=2)
     cfg_1d = preset("tiny", dtype=jnp.float32, remat=False,
@@ -523,8 +536,8 @@ def test_pipeline_tp_within_stage_matches_oracle():
     params = init_transformer(jax.random.PRNGKey(0), cfg_pp)
     tok = tokens(batch=8)
     mesh = build_mesh({"pp": 2, "tp": 2, "dp": 2})
-    got = transformer_hidden(params, tok, cfg_pp, mesh)
-    want = transformer_hidden(params, tok, cfg_1d, None)
+    got = hidden(params, tok, cfg_pp, mesh)
+    want = hidden(params, tok, cfg_1d, None)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5
     )
@@ -557,8 +570,6 @@ def test_pipeline_tp_trains_through_trainer():
 
 
 def test_pipeline_tp_indivisible_heads_rejected():
-    from tf_operator_tpu.models.transformer import transformer_hidden
-
     cfg = preset("tiny", dtype=jnp.float32, n_layers=2, n_heads=4,
                  n_kv_heads=1, pp_microbatches=2)
     params = init_transformer(jax.random.PRNGKey(0), cfg)
@@ -585,8 +596,8 @@ def test_pipeline_tp_grads_match_single_device(schedule):
     tok = tokens(batch=8)
     mesh = build_mesh({"pp": 2, "tp": 2, "dp": 2})
 
-    g_pp = jax.grad(lambda p: lm_loss(p, tok, cfg_pp, mesh=mesh))(params)
-    g_1d = jax.grad(lambda p: lm_loss(p, tok, cfg_1d, mesh=None))(params)
+    g_pp = loss_grads(params, tok, cfg_pp, mesh)
+    g_1d = loss_grads(params, tok, cfg_1d, None)
     flat_pp = jax.tree_util.tree_flatten_with_path(g_pp)[0]
     flat_1d = jax.tree_util.tree_leaves(g_1d)
     for (path, a), b in zip(flat_pp, flat_1d):
@@ -600,16 +611,14 @@ def test_pipeline_interleaved_transformer_matches_oracle():
     """Interleaved 1F1B in the model (pp_chunks=2): 4 layers as 4 virtual
     stages on pp=2 devices (layer j on device j mod 2) — forward equals
     the plain scan exactly."""
-    from tf_operator_tpu.models.transformer import transformer_hidden
-
     cfg_pp = preset("tiny", dtype=jnp.float32, remat=False, pp_microbatches=4,
                     n_layers=4, pp_chunks=2)
     cfg_1d = preset("tiny", dtype=jnp.float32, remat=False, n_layers=4)
     params = init_transformer(jax.random.PRNGKey(0), cfg_pp)
     tok = tokens(batch=16)
     mesh = build_mesh({"pp": 2, "dp": 4})
-    got = transformer_hidden(params, tok, cfg_pp, mesh)
-    want = transformer_hidden(params, tok, cfg_1d, None)
+    got = hidden(params, tok, cfg_pp, mesh)
+    want = hidden(params, tok, cfg_1d, None)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5
     )
@@ -619,8 +628,6 @@ def test_pipeline_interleaved_tp_matches_oracle():
     """Interleaved (pp_chunks=2) composed with tp-within-stage: the
     [v, S]-reshaped Megatron param specs still shard each chunk's weights
     over tp; forward equals the single-device scan."""
-    from tf_operator_tpu.models.transformer import transformer_hidden
-
     kw = dict(dtype=jnp.float32, remat=False, n_layers=4, n_heads=4,
               n_kv_heads=2)
     cfg_pp = preset("tiny", pp_microbatches=4, pp_chunks=2, **kw)
@@ -628,8 +635,8 @@ def test_pipeline_interleaved_tp_matches_oracle():
     params = init_transformer(jax.random.PRNGKey(0), cfg_pp)
     tok = tokens(batch=8)
     mesh = build_mesh({"pp": 2, "tp": 2, "dp": 2})
-    got = transformer_hidden(params, tok, cfg_pp, mesh)
-    want = transformer_hidden(params, tok, cfg_1d, None)
+    got = hidden(params, tok, cfg_pp, mesh)
+    want = hidden(params, tok, cfg_1d, None)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5
     )
@@ -693,22 +700,18 @@ def test_moe_apply_ep_fsdp_matches_single_device_oracle():
         lambda a: jax.device_put(a, NamedSharding(mesh, P("ep", "fsdp"))), wp
     )
 
-    want = moe_apply(x, gl, wp, expert_fn, None, capacity_factor=8.0, k_top=2)
-    got = moe_apply(xs, gls, wps, expert_fn, mesh, capacity_factor=8.0, k_top=2)
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
-
-    def loss(fn_mesh, gl_):
-        def f(x_, wp_):
-            return jnp.sum(
-                moe_apply(x_, gl_, wp_, expert_fn, fn_mesh,
-                          capacity_factor=8.0, k_top=2) ** 2)
-        return f
+    def run(fn_mesh, gl_, x_, wp_):
+        return jit_out_and_grads(
+            lambda x_, wp_: moe_apply(x_, gl_, wp_, expert_fn, fn_mesh,
+                                      capacity_factor=8.0, k_top=2),
+            x_, wp_, argnums=(0, 1))
 
     # mesh path closes over the SHARDED gating logits (gls) so the
     # backward through sharded routing is what's tested
-    got_g = jax.grad(loss(mesh, gls), argnums=(0, 1))(xs, wps)
-    want_g = jax.grad(loss(None, gl), argnums=(0, 1))(x, wp)
+    got, got_g = run(mesh, gls, xs, wps)
+    want, want_g = run(None, gl, x, wp)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
     for a, b in zip(jax.tree_util.tree_leaves(got_g),
                     jax.tree_util.tree_leaves(want_g)):
         np.testing.assert_allclose(
@@ -756,10 +759,6 @@ def test_pipeline_ep_in_stage_matches_single_device(schedule):
     documented per-microbatch/per-shard aux estimators. The 1f1b arm
     additionally pins the backward's per-leaf data-axis reduction — a
     uniform psum over data axes scrambles ep-sharded expert grads."""
-    import dataclasses
-
-    from tf_operator_tpu.models.transformer import lm_loss_and_metrics
-
     cfg = preset("tiny-moe", dtype=jnp.float32, remat=False, n_layers=4,
                  pp_microbatches=2, capacity_factor=8.0, moe_top_k=2,
                  pp_schedule=schedule)
@@ -767,13 +766,9 @@ def test_pipeline_ep_in_stage_matches_single_device(schedule):
     params = init_transformer(jax.random.PRNGKey(0), cfg)
     tok = jax.random.randint(jax.random.PRNGKey(1), (16, 32), 0, cfg.vocab)
 
-    def ce(p, m):
-        return lm_loss_and_metrics(p, tok, cfg, mesh=m)[1]["ce_loss"]
-
-    np.testing.assert_allclose(
-        float(ce(params, mesh)), float(ce(params, None)), rtol=2e-5)
-    g_got = jax.grad(ce)(params, mesh)
-    g_want = jax.grad(ce)(params, None)
+    (ce_pp, m_pp), g_got = ce_and_grads(params, tok, cfg, mesh)
+    (ce_sd, m_sd), g_want = ce_and_grads(params, tok, cfg, None)
+    np.testing.assert_allclose(float(ce_pp), float(ce_sd), rtol=2e-5)
     for (pa, a), (_, b) in zip(jax.tree_util.tree_leaves_with_path(g_got),
                                jax.tree_util.tree_leaves_with_path(g_want)):
         np.testing.assert_allclose(
@@ -781,8 +776,6 @@ def test_pipeline_ep_in_stage_matches_single_device(schedule):
             err_msg=jax.tree_util.keystr(pa))
     # aux losses: finite and same order as single-device (different
     # estimator — per microbatch x ep shard)
-    m_pp = lm_loss_and_metrics(params, tok, cfg, mesh=mesh)[1]
-    m_sd = lm_loss_and_metrics(params, tok, cfg, mesh=None)[1]
     assert np.isfinite(float(m_pp["moe_lb_loss"]))
     np.testing.assert_allclose(float(m_pp["moe_lb_loss"]),
                                float(m_sd["moe_lb_loss"]), rtol=0.2)
@@ -813,7 +806,8 @@ def test_pipeline_ep_in_stage_trains():
     assert all(np.isfinite(losses)) and losses[-1] < losses[0], losses
 
 
-def test_gmm_dispatch_matches_sort_at_no_drop_capacity():
+@pytest.mark.parametrize("k_top", [1, 2])
+def test_gmm_dispatch_matches_sort_at_no_drop_capacity(k_top):
     """dispatch_impl="gmm" (r5 — padding-free grouped expert matmuls, no
     capacity) must equal the sort path when the sort path's capacity is
     large enough that nothing drops: with no drops both compute
@@ -834,27 +828,19 @@ def test_gmm_dispatch_matches_sort_at_no_drop_capacity():
     def efn(wp, t):
         return (jax.nn.silu(t @ wp["w_gate"]) * (t @ wp["w_up"])) @ wp["w_down"]
 
-    for k_top in (1, 2):
-        out_sort = moe_apply(
-            x, gl, ep, efn, None, capacity_factor=float(E), k_top=k_top,
-            dropped="zero", dispatch_impl="sort",
-        )
-        out, stats = moe_apply(
-            x, gl, ep, efn, None, k_top=k_top, dispatch_impl="gmm",
-            return_stats=True,
-        )
-        np.testing.assert_allclose(out_sort, out, atol=1e-5,
-                                   err_msg=f"k={k_top}")
-        assert float(stats["drop_frac"]) == 0.0  # never drops
+    def run(**kw):
+        return jit_out_and_grads(
+            lambda ew: moe_apply(x, gl, ew, efn, None, k_top=k_top,
+                                 return_stats=True, **kw), ep)
 
-        g = jax.grad(lambda ew: jnp.sum(moe_apply(
-            x, gl, ew, efn, None, k_top=k_top, dispatch_impl="gmm") ** 2))(ep)
-        g_sort = jax.grad(lambda ew: jnp.sum(moe_apply(
-            x, gl, ew, efn, None, capacity_factor=float(E), k_top=k_top,
-            dropped="zero", dispatch_impl="sort") ** 2))(ep)
-        for name in g:
-            np.testing.assert_allclose(g[name], g_sort[name], atol=1e-4,
-                                       err_msg=name)
+    (out_sort, _), g_sort = run(capacity_factor=float(E), dropped="zero",
+                                dispatch_impl="sort")
+    (out, stats), g = run(dispatch_impl="gmm")
+    np.testing.assert_allclose(out_sort, out, atol=1e-5)
+    assert float(stats["drop_frac"]) == 0.0  # never drops
+    for name in g:
+        np.testing.assert_allclose(g[name], g_sort[name], atol=1e-4,
+                                   err_msg=name)
 
 
 def test_gmm_zero_token_expert_gets_zero_grad():
@@ -878,8 +864,9 @@ def test_gmm_zero_token_expert_gets_zero_grad():
     def efn(wp, t):
         return (jax.nn.silu(t @ wp["w_gate"]) * (t @ wp["w_up"])) @ wp["w_down"]
 
-    g = jax.grad(lambda ew: jnp.sum(moe_apply(
-        x, gl, ew, efn, None, k_top=1, dispatch_impl="gmm") ** 2))(ep)
+    _, g = jit_out_and_grads(
+        lambda ew: moe_apply(x, gl, ew, efn, None, k_top=1,
+                             dispatch_impl="gmm"), ep)
     for name in g:
         # experts 1..3 got nothing: their grads must be exactly zero
         np.testing.assert_array_equal(np.asarray(g[name][1:]), 0.0)
@@ -899,7 +886,6 @@ def test_gmm_rejects_non_swiglu_expert_params():
 def test_unknown_moe_dispatch_raises():
     """A removed or misspelt dispatch is refused, not run as something
     else: through the layer and through the model config."""
-    from tf_operator_tpu.models.transformer import lm_loss_and_metrics
     from tf_operator_tpu.parallel.moe import moe_apply
 
     with pytest.raises(ValueError, match="unknown dispatch_impl 'ragged'"):

@@ -114,7 +114,7 @@ def test_gang_spans_hosts_and_succeeds(cluster):
     assert wait_for(span, timeout=30), f"gang never spanned both hosts: {seen_nodes}"
     ok = wait_for(
         lambda: has_condition(job_status(store, "mh-smoke"), ConditionType.SUCCEEDED),
-        timeout=120,
+        timeout=60,
     )
     st = job_status(store, "mh-smoke")
     assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"
@@ -188,7 +188,7 @@ def test_node_lost_triggers_gang_restart_onto_surviving_capacity():
             a2._watch.stop()
         ok = wait_for(
             lambda: has_condition(job_status(store, "mh-lost"), ConditionType.SUCCEEDED),
-            timeout=240,
+            timeout=60,
         )
         st = job_status(store, "mh-lost")
         assert ok, f"conditions: {[(c.type.value, c.reason, c.message) for c in st.conditions]}"

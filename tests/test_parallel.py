@@ -1,6 +1,9 @@
 """Parallel library tests on the 8-device virtual CPU mesh: mesh building,
 sharding rules, collectives, ring attention, pipeline, MoE — each verified
-against a dense single-device oracle."""
+against a dense single-device oracle. Whatever runs on a mesh runs under
+``jit``, as the trainer's step does (an eager ``shard_map`` costs 10 - 25 x
+the compiled call, PR 32); forward value and gradients come out of one call
+(``conftest.jit_out_and_grads``)."""
 
 import jax
 import jax.numpy as jnp
@@ -8,6 +11,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from conftest import jit_out_and_grads, jit_value_and_grad
 from tf_operator_tpu.parallel import MeshSpec, build_mesh
 from tf_operator_tpu.parallel.sharding import DEFAULT_RULES, ShardingRules
 from tf_operator_tpu.parallel.ring_attention import reference_attention
@@ -84,8 +88,9 @@ def test_pipeline_matches_sequential(schedule):
         w, b = params
         return jax.nn.relu(xb @ w + b)
 
-    out = pipeline_apply((ws, bs), x, stage_fn, mesh, n_microbatches=n_micro,
-                         schedule=schedule)
+    out = jax.jit(lambda params, x: pipeline_apply(
+        params, x, stage_fn, mesh, n_microbatches=n_micro,
+        schedule=schedule))((ws, bs), x)
 
     ref = x
     for i in range(n_stages):
@@ -121,8 +126,8 @@ def test_pipeline_grads_match_sequential(schedule):
             h = jnp.tanh(h @ ws[i] + bs[i])
         return jnp.sum(h ** 2)
 
-    (dws, dbs), dx = jax.grad(loss_pp, argnums=(0, 1))((ws, bs), x)
-    (rws, rbs), rx = jax.grad(loss_seq, argnums=(0, 1))((ws, bs), x)
+    _, ((dws, dbs), dx) = jit_value_and_grad(loss_pp, (ws, bs), x, argnums=(0, 1))
+    _, ((rws, rbs), rx) = jit_value_and_grad(loss_seq, (ws, bs), x, argnums=(0, 1))
     np.testing.assert_allclose(np.asarray(dws), np.asarray(rws), rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(dbs), np.asarray(rbs), rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(dx), np.asarray(rx), rtol=1e-4, atol=1e-5)
@@ -144,8 +149,9 @@ def test_pipeline_interleaved_matches_sequential(n_chunks):
         w, b = params
         return jnp.tanh(xb @ w + b)
 
-    out = pipeline_apply((ws, bs), x, stage_fn, mesh, n_microbatches=n_micro,
-                         schedule="1f1b", n_chunks=n_chunks)
+    out = jax.jit(lambda params, x: pipeline_apply(
+        params, x, stage_fn, mesh, n_microbatches=n_micro, schedule="1f1b",
+        n_chunks=n_chunks))((ws, bs), x)
     ref = x
     for j in range(J):
         ref = jnp.tanh(ref @ ws[j] + bs[j])
@@ -181,8 +187,8 @@ def test_pipeline_interleaved_grads_match_sequential():
             h = jnp.tanh(h @ ws[j] + bs[j])
         return jnp.sum(h ** 2)
 
-    (dws, dbs), dx = jax.grad(loss_pp, argnums=(0, 1))((ws, bs), x)
-    (rws, rbs), rx = jax.grad(loss_seq, argnums=(0, 1))((ws, bs), x)
+    _, ((dws, dbs), dx) = jit_value_and_grad(loss_pp, (ws, bs), x, argnums=(0, 1))
+    _, ((rws, rbs), rx) = jit_value_and_grad(loss_seq, (ws, bs), x, argnums=(0, 1))
     np.testing.assert_allclose(np.asarray(dws), np.asarray(rws), rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(dbs), np.asarray(rbs), rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(dx), np.asarray(rx), rtol=1e-4, atol=1e-5)
@@ -202,13 +208,13 @@ def test_pipeline_interleaved_aux_channel():
         y = jnp.tanh(xb @ w)
         return y, jnp.sum(y ** 2)[None]
 
-    def run(ws, x):
-        out, aux = pipeline_apply(
+    def aux_total(ws, x):
+        _, aux = pipeline_apply(
             ws, x, stage_fn, mesh, n_microbatches=n_micro, schedule="1f1b",
             n_chunks=n_chunks, aux_size=1)
-        return out, aux
+        return aux[0]
 
-    out, aux = run(ws, x)
+    aux0, g = jit_value_and_grad(aux_total, ws, x)
     # oracle: sequential trace, aux summed over stages and microbatches
     # (pipeline_apply means over data shards; each shard sums its slice,
     # so the global total is the full-batch sum divided by n_data — undo
@@ -218,8 +224,7 @@ def test_pipeline_interleaved_aux_channel():
         h = jnp.tanh(h @ ws[j])
         total = total + jnp.sum(h ** 2)
     n_data = 4
-    np.testing.assert_allclose(float(aux[0]), float(total) / n_data, rtol=1e-4)
-    g = jax.grad(lambda w: run(w, x)[1][0])(ws)
+    np.testing.assert_allclose(float(aux0), float(total) / n_data, rtol=1e-4)
     assert float(jnp.abs(g).max()) > 0.0
 
 
@@ -300,7 +305,9 @@ def test_moe_matches_dense_routing():
         return toks @ params
 
     # generous capacity: nothing dropped -> must match dense routing exactly
-    out = moe_apply(x, gate_logits, w, expert_fn, mesh, capacity_factor=float(n_experts))
+    out = jax.jit(lambda x, gl, w: moe_apply(
+        x, gl, w, expert_fn, mesh, capacity_factor=float(n_experts)))(
+            x, gate_logits, w)
 
     probs = jax.nn.softmax(gate_logits, axis=-1)
     idx = jnp.argmax(probs, axis=-1)
@@ -320,7 +327,8 @@ def test_moe_capacity_drop_passthrough():
     def expert_fn(params, toks):
         return toks @ params
 
-    out = moe_apply(x, gate_logits, w, expert_fn, mesh, capacity_factor=0.01)
+    out = jax.jit(lambda x, gl, w: moe_apply(
+        x, gl, w, expert_fn, mesh, capacity_factor=0.01))(x, gate_logits, w)
     # capacity floors at 1 per expert; per shard 2 tokens, 1 kept (output 0 * gate),
     # 1 dropped (passes through unchanged)
     out = np.asarray(out)
@@ -435,10 +443,10 @@ def test_moe_capacity_drop_zero_mode():
     gate_logits = jnp.zeros((tokens, n_experts)).at[:, 0].set(100.0)
     w = jnp.zeros((n_experts, d, d))
 
-    out = moe_apply(
-        x, gate_logits, w, lambda p, t: t @ p, mesh,
+    out = jax.jit(lambda x, gl, w: moe_apply(
+        x, gl, w, lambda p, t: t @ p, mesh,
         capacity_factor=0.01, dropped="zero",
-    )
+    ))(x, gate_logits, w)
     np.testing.assert_allclose(np.asarray(out), 0.0, atol=1e-6)
 
 
@@ -453,10 +461,10 @@ def test_moe_dp_x_ep_mesh_shards_tokens_over_both():
     gate_logits = jax.random.normal(jax.random.PRNGKey(5), (tokens, n_experts))
     w = jax.random.normal(jax.random.PRNGKey(6), (n_experts, d, d)) / np.sqrt(d)
 
-    out = moe_apply(
-        x, gate_logits, w, lambda p, t: t @ p, mesh,
+    out = jax.jit(lambda x, gl, w: moe_apply(
+        x, gl, w, lambda p, t: t @ p, mesh,
         capacity_factor=float(n_experts),
-    )
+    ))(x, gate_logits, w)
     probs = jax.nn.softmax(gate_logits, axis=-1)
     idx = jnp.argmax(probs, axis=-1)
     gate = jnp.take_along_axis(probs, idx[:, None], axis=-1)[:, 0]
@@ -473,10 +481,10 @@ def test_moe_top2_matches_dense_routing():
     gate_logits = jax.random.normal(jax.random.PRNGKey(5), (tokens, n_experts))
     w = jax.random.normal(jax.random.PRNGKey(6), (n_experts, d, d)) / np.sqrt(d)
 
-    out = moe_apply(
-        x, gate_logits, w, lambda p, t: t @ p, mesh,
+    out = jax.jit(lambda x, gl, w: moe_apply(
+        x, gl, w, lambda p, t: t @ p, mesh,
         capacity_factor=float(n_experts), k_top=2,
-    )
+    ))(x, gate_logits, w)
     probs = jax.nn.softmax(gate_logits, axis=-1)
     top_p, top_i = jax.lax.top_k(probs, 2)
     top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
@@ -510,10 +518,10 @@ def test_moe_top2_partial_drop_renormalizes_survivors():
     scales = jnp.array([2.0, -1.0, 3.0, 0.5])
     w = jnp.einsum("e,ij->eij", scales, jnp.eye(d))  # expert e = scale_e * I
 
-    out = moe_apply(
-        x, gate_logits, w, lambda p, t: t @ p, mesh,
+    out = jax.jit(lambda x, gl, w: moe_apply(
+        x, gl, w, lambda p, t: t @ p, mesh,
         capacity_factor=1e-9, k_top=2,  # capacity floors at 1 per expert
-    )
+    ))(x, gate_logits, w)
     out = np.asarray(out)
     xn = np.asarray(x)
     for shard in (0, 4):
@@ -535,7 +543,8 @@ def test_config_rejects_bad_top_k():
 # ---- ulysses (all-to-all sequence parallelism) ---------------------------
 
 
-def test_ulysses_matches_dense_oracle():
+@pytest.mark.parametrize("causal", [False, True])
+def test_ulysses_matches_dense_oracle(causal):
     """Seq->heads all-to-all, full-seq attention per head shard, back:
     must equal dense attention exactly (same math, re-sharded)."""
     from tf_operator_tpu.parallel.ulysses import ulysses_attention
@@ -546,14 +555,13 @@ def test_ulysses_matches_dense_oracle():
     b, t, h, d = 2, 32, 8, 16
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q, k, v = (jax.random.normal(kk, (b, t, h, d), jnp.float32) for kk in ks)
-    for causal in (False, True):
-        got = ulysses_attention(
-            q, k, v, mesh, causal=causal, batch_axes=("dp",)
-        )
-        want = reference_attention(q, k, v, causal=causal)
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5
-        )
+    got = jax.jit(lambda q, k, v: ulysses_attention(
+        q, k, v, mesh, causal=causal, batch_axes=("dp",)))(q, k, v)
+    want = jax.jit(lambda q, k, v: reference_attention(
+        q, k, v, causal=causal))(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5
+    )
 
 
 def test_ulysses_rejects_indivisible_heads():
@@ -579,8 +587,8 @@ def test_ulysses_transformer_trains():
     params = init_transformer(jax.random.PRNGKey(0), cfg)
     tok = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab)
     np.testing.assert_allclose(
-        float(lm_loss(params, tok, cfg, mesh=mesh)),
-        float(lm_loss(params, tok, cfg_dense, mesh=None)),
+        float(jax.jit(lambda p: lm_loss(p, tok, cfg, mesh=mesh))(params)),
+        float(jax.jit(lambda p: lm_loss(p, tok, cfg_dense, mesh=None))(params)),
         rtol=1e-4,
     )
     trainer = Trainer(
@@ -622,21 +630,14 @@ def test_ulysses_gqa_matches_repeat_oracle(h_kv):
             q, jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2), causal=True
         )
 
-    got = ulysses_attention(q, k, v, mesh, causal=True, batch_axes=("dp",))
+    got, got_g = jit_out_and_grads(
+        lambda q, k, v: ulysses_attention(q, k, v, mesh, causal=True,
+                                          batch_axes=("dp",)),
+        q, k, v, argnums=(0, 1, 2))
+    want, want_g = jit_out_and_grads(oracle, q, k, v, argnums=(0, 1, 2))
     np.testing.assert_allclose(
-        np.asarray(got), np.asarray(oracle(q, k, v)), rtol=2e-4, atol=2e-5
+        np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5
     )
-
-    def loss_u(q, k, v):
-        return jnp.sum(
-            ulysses_attention(q, k, v, mesh, causal=True, batch_axes=("dp",)) ** 2
-        )
-
-    def loss_o(q, k, v):
-        return jnp.sum(oracle(q, k, v) ** 2)
-
-    got_g = jax.grad(loss_u, argnums=(0, 1, 2))(q, k, v)
-    want_g = jax.grad(loss_o, argnums=(0, 1, 2))(q, k, v)
     for name, a, w in zip("qkv", got_g, want_g):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(w), rtol=5e-4, atol=5e-5,
@@ -644,9 +645,10 @@ def test_ulysses_gqa_matches_repeat_oracle(h_kv):
         )
 
 
+@pytest.mark.parametrize("cf", [2.0, 0.5], ids=["ample", "drops"])
 @pytest.mark.parametrize("k_top", [1, 2])
 @pytest.mark.parametrize("dropped", ["passthrough", "zero"])
-def test_moe_dispatch_impl_parity(k_top, dropped):
+def test_moe_dispatch_impl_parity(k_top, dropped, cf):
     """Sort-based dispatch (r3 default: argsort/scatter/gather, O(T·d))
     vs the one-hot einsum oracle (O(T²·d)): identical queue semantics
     means identical outputs, gradients, and stats — INCLUDING which
@@ -658,32 +660,26 @@ def test_moe_dispatch_impl_parity(k_top, dropped):
     gates = jax.random.normal(ks[1], (tokens, n_experts))
     wexp = jax.random.normal(ks[2], (n_experts, d, d)) / np.sqrt(d)
 
-    def run(impl, cf):
-        return moe_apply(x, gates, wexp, lambda w, t: jnp.tanh(t @ w), mesh,
-                         capacity_factor=cf, k_top=k_top, dropped=dropped,
-                         dispatch_impl=impl, return_stats=True)
+    def run(impl):
+        return jit_out_and_grads(
+            lambda x, gates, wexp: moe_apply(
+                x, gates, wexp, lambda w, t: jnp.tanh(t @ w), mesh,
+                capacity_factor=cf, k_top=k_top, dropped=dropped,
+                dispatch_impl=impl, return_stats=True),
+            x, gates, wexp, argnums=(0, 1, 2))
 
-    for cf in (2.0, 0.5):  # ample capacity AND forced drops
-        got, gstats = run("sort", cf)
-        want, wstats = run("einsum", cf)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
-        for key in gstats:
-            np.testing.assert_allclose(np.asarray(gstats[key]),
-                                       np.asarray(wstats[key]),
-                                       rtol=1e-6, atol=1e-6, err_msg=key)
-
-    def loss(impl):
-        def f(x, gates, wexp):
-            return jnp.sum(
-                moe_apply(x, gates, wexp, lambda w, t: jnp.tanh(t @ w), mesh,
-                          capacity_factor=0.5, k_top=k_top, dropped=dropped,
-                          dispatch_impl=impl) ** 2)
-        return f
-
-    got = jax.grad(loss("sort"), argnums=(0, 1, 2))(x, gates, wexp)
-    want = jax.grad(loss("einsum"), argnums=(0, 1, 2))(x, gates, wexp)
-    for name, a, w in zip(["x", "gates", "wexp"], got, want):
+    (got, gstats), got_g = run("sort")
+    (want, wstats), want_g = run("einsum")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    for key in gstats:
+        np.testing.assert_allclose(np.asarray(gstats[key]),
+                                   np.asarray(wstats[key]),
+                                   rtol=1e-6, atol=1e-6, err_msg=key)
+    # Finite: compiled, the einsum path's renormalisation gave a token with
+    # both choices dropped a NaN router gradient, and NaN equals NaN below.
+    for name, a, w in zip(["x", "gates", "wexp"], got_g, want_g):
+        assert np.isfinite(np.asarray(w)).all(), f"d{name}"
         np.testing.assert_allclose(np.asarray(a), np.asarray(w),
                                    rtol=5e-4, atol=5e-5, err_msg=f"d{name}")
 
@@ -754,13 +750,10 @@ def test_ulysses_gqa_indivisible_kv_no_repeat_tensor():
         return ulysses_attention(q, k, v, mesh, causal=True,
                                  batch_axes=("dp",))
 
-    np.testing.assert_allclose(
-        np.asarray(run(q, k, v)), np.asarray(oracle(q, k, v)),
-        rtol=2e-4, atol=2e-5)
-    got_g = jax.grad(lambda *a: jnp.sum(run(*a) ** 2), argnums=(0, 1, 2))(
-        q, k, v)
-    want_g = jax.grad(lambda *a: jnp.sum(oracle(*a) ** 2), argnums=(0, 1, 2))(
-        q, k, v)
+    got, got_g = jit_out_and_grads(run, q, k, v, argnums=(0, 1, 2))
+    want, want_g = jit_out_and_grads(oracle, q, k, v, argnums=(0, 1, 2))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
     for name, a, w in zip("qkv", got_g, want_g):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(w), rtol=5e-4, atol=5e-5,

@@ -4,7 +4,9 @@ phase/exit codes into the store. Lifecycle tests run against BOTH real
 backends — behavioral parity between them is itself the contract."""
 
 import os
+import subprocess
 import sys
+import time
 
 import pytest
 
@@ -195,8 +197,6 @@ def test_native_normalizes_signal_exit_codes():
 def test_native_group_kill_reaps_grandchildren():
     """Deleting a process must take down children IT forked (the C++
     supervisor signals the whole setsid process group)."""
-    import subprocess
-
     store = Store()
     marker = "tpujob-native-grandchild-marker"
     # Child forks a grandchild (identifiable via argv marker) then sleeps.
@@ -223,8 +223,6 @@ def test_native_group_kill_reaps_grandchildren():
 def test_native_group_reaped_when_leader_dies_on_its_own():
     """Pod semantics: the leader exiting by itself (crash, chaos kill) must
     still take its forked children down — not only explicit deletes."""
-    import subprocess
-
     store = Store()
     marker = "tpujob-native-selfdeath-marker"
     # Child forks a long-lived grandchild then EXITS on its own.
@@ -324,3 +322,23 @@ def test_clean_exit_ignores_oom_counter_noise():
         is ProcessPhase.SUCCEEDED
     )
     assert store.get("Process", "default", "clean").status.oom_killed is False
+
+
+def test_a_hung_child_costs_its_case_its_own_limit(case_limit):
+    """The tests' own bound (``conftest.case_limit``): a case that waits on a
+    child that never ends is failed at ITS limit — 3 s for this one — with
+    every thread's stack in the message, while the run's clock is minutes
+    away. The alarm interrupts the un-timed ``wait``."""
+    assert case_limit == 3
+    child = subprocess.Popen([sys.executable, "-c", "import time; time.sleep(600)"])
+    t0 = time.monotonic()
+    try:
+        with pytest.raises(pytest.fail.Exception, match="ran into its limit of 3 s") as hit:
+            child.wait()
+        assert time.monotonic() - t0 < 3.5
+        # where it stood: this test's frame, waiting on the child
+        assert "test_a_hung_child_costs_its_case_its_own_limit" in str(hit.value)
+        assert "subprocess.py" in str(hit.value)
+    finally:
+        child.kill()
+        child.wait()

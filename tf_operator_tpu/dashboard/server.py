@@ -417,6 +417,10 @@ class _Handler(BaseHTTPRequestHandler):
                     size = f.tell()
                     f.seek(max(0, size - 1024 * 1024))
                     data = f.read()
+            except FileNotFoundError:
+                # annotated when the process is created, opened when its child
+                # is spawned: between the two nothing has been written yet
+                data = b""
             except OSError as exc:
                 return self._error(500, str(exc))
             self.send_response(200)

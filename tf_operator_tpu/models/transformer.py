@@ -973,7 +973,7 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
         # cfg.moe_dispatch with moe_apply's ladder semantics: gmm runs
         # padding-free in-stage (r6); einsum degrades to sort (the inbox
         # layout is identical, sort is the cheap form).
-        import os
+        from tf_operator_tpu.ops.grouped_matmul import gmm_block_rows
 
         local_impl = "gmm" if cfg.moe_dispatch == "gmm" else "sort"
         capacity = expert_capacity(
@@ -984,7 +984,7 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
             axis_name=local_ep_axis, capacity=capacity, dropped="zero",
             k_top=cfg.moe_top_k, stat_axes=(local_ep_axis,),
             dispatch_impl=local_impl,
-            block_rows=int(os.environ.get("TPUJOB_GMM_BLOCK_ROWS", "256")),
+            block_rows=gmm_block_rows(),
             expert_act=cfg.expert_act,
         )
     else:

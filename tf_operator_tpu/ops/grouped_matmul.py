@@ -58,9 +58,18 @@ _moe_local_gmm).
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
+
+
+def gmm_block_rows() -> int:
+    """The block quantum B of every gmm dispatch: rows a grid step, and the
+    unit the MoE buffers round to. ``TPUJOB_GMM_BLOCK_ROWS`` (default 256,
+    the measured-fastest tile; tests set 8 so that partial blocks occur at
+    test sizes), read when the caller TRACES, not when its program runs."""
+    return int(os.environ.get("TPUJOB_GMM_BLOCK_ROWS", "256"))
 
 
 def _pick_cols(n: int, target: int) -> int:

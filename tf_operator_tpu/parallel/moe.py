@@ -37,6 +37,17 @@ def expert_capacity(capacity_factor: float, k_top: int, local_tokens: int,
     return max(1, int(capacity_factor * k_top * local_tokens / n_experts))
 
 
+def _renormalize_survivors(w, kept, surviving):
+    """``w * kept / surviving`` where any choice survived, else ``w``. The
+    divisor is made 1 where nothing survived, not clamped to a tiny value:
+    the unselected branch's gradient divides by the divisor SQUARED, 1e-40
+    is a float32 denormal, and the compiled CPU step flushes it to zero —
+    0 / 0, a NaN router gradient for every token with all its choices
+    dropped (PR 32: seen the first time the einsum path ran under ``jit``)."""
+    alive = surviving > 0
+    return jnp.where(alive, w * kept / jnp.where(alive, surviving, 1.0), w)
+
+
 def _route(x, gate_logits, capacity: int, k_top: int = 1, dropped: str = "passthrough"):
     """Top-k routing bookkeeping shared by the sharded and single-device
     paths. Each token goes to its ``k_top`` highest-gated experts; with
@@ -72,7 +83,7 @@ def _route(x, gate_logits, capacity: int, k_top: int = 1, dropped: str = "passth
     kept = assign * (pos < capacity)  # [T, E]
     if k_top > 1 and dropped == "passthrough":
         surviving = jnp.sum(w * kept, axis=-1, keepdims=True)
-        w = jnp.where(surviving > 0, w * kept / jnp.maximum(surviving, 1e-20), w)
+        w = _renormalize_survivors(w, kept, surviving)
     pos_onehot = jax.nn.one_hot(pos.astype(jnp.int32), capacity, dtype=jnp.float32)
     dispatch = kept[:, :, None] * pos_onehot  # [T, E, C] 0/1
     dispatch_w = dispatch * w[:, :, None]  # combine side carries gate weights
@@ -128,7 +139,7 @@ def _route_sparse(x, gate_logits, capacity: int, k_top: int = 1,
     w = top_p
     if k_top > 1 and dropped == "passthrough":
         surviving = jnp.sum(w * kept, axis=-1, keepdims=True)
-        w = jnp.where(surviving > 0, w * kept / jnp.maximum(surviving, 1e-20), w)
+        w = _renormalize_survivors(w, kept, surviving)
     keep_any = jnp.any(kept, axis=-1)
 
     # inbox by scatter-add: each kept (token, choice) owns a unique slot;
@@ -460,7 +471,7 @@ def _moe_single(x, gate_logits, expert_params, expert_fn, capacity: int, dropped
     tokens, d = x.shape
     n_experts = gate_logits.shape[-1]
     if dispatch_impl == "gmm":
-        import os
+        from tf_operator_tpu.ops.grouped_matmul import gmm_block_rows
 
         # the gmm path runs the experts as grouped ragged matmuls over
         # the SwiGLU parameter triple directly — a custom expert_fn
@@ -475,7 +486,7 @@ def _moe_single(x, gate_logits, expert_params, expert_fn, capacity: int, dropped
             )
         return _moe_single_gmm(
             x, gate_logits, expert_params, k_top,
-            block_rows=int(os.environ.get("TPUJOB_GMM_BLOCK_ROWS", "256")),
+            block_rows=gmm_block_rows(),
             act=expert_activation(expert_act), first=expert_first,
             score=score, bias=bias, scale=scale,
         )
@@ -706,13 +717,13 @@ def moe_apply(
     token_spec = P((*data_axes, axis_name))
     param_specs = jax.tree_util.tree_map(lambda _: P(axis_name), expert_params)
     stat_specs = {"expert_load": P(), "mean_gate": P(), "drop_frac": P()}
-    import os
+    from tf_operator_tpu.ops.grouped_matmul import gmm_block_rows
 
     fn = shard_map(
         partial(_moe_local, expert_fn=expert_fn, axis_name=axis_name, capacity=capacity,
                 dropped=dropped, k_top=k_top, stat_axes=(*data_axes, axis_name),
                 dispatch_impl=dispatch_impl,
-                block_rows=int(os.environ.get("TPUJOB_GMM_BLOCK_ROWS", "256")),
+                block_rows=gmm_block_rows(),
                 expert_act=expert_act),
         mesh=mesh,
         in_specs=(token_spec, token_spec, param_specs),

@@ -177,6 +177,7 @@ def plan(preset_name: str, mesh_axes: dict, batch: int, seq: int,
     # regardless of the save set (FLASH_SAVE_NAMES note) and are NOT part
     # of a name policy's saved bytes.
     from tf_operator_tpu.models.transformer import remat_save_names
+    from tf_operator_tpu.ops.grouped_matmul import gmm_block_rows
 
     _name_width = {
         "flash_q": d // tp, "flash_k": kv // tp, "flash_v": kv // tp,
@@ -230,7 +231,7 @@ def plan(preset_name: str, mesh_axes: dict, batch: int, seq: int,
         # sidecars are noise. T_moe = this chip's tokens / ep (tokens
         # shard over (data axes × ep) inside moe_apply).
         ep = mesh_axes["ep"]
-        bq = int(os.environ.get("TPUJOB_GMM_BLOCK_ROWS", "256"))
+        bq = gmm_block_rows()
         t_moe = max(1, local_tokens // ep)
         k_top = int(getattr(cfg, "moe_top_k", 1))
         e_local = max(1, cfg.n_experts // ep)
