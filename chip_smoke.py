@@ -6,8 +6,9 @@ twice through the entry points a user calls — ``tools.deploy up`` →
 ``tpujob submit`` → ``tpujob wait`` → worker log:
 
 1. *train*: ``examples/gqa_2048_northstar.json`` (``workloads.lm``, preset
-   gqa-2048 at full width and depth, t=2048, flash attention, save_mid
-   remat, adamw) for a few steps on one fixed batch, no checkpoint.
+   gqa-2048 at full width and depth, t=2048, flash attention,
+   ``save:resid_mid`` remat, adamw) for a few steps on one fixed batch, no
+   checkpoint.
 2. *serve*: ``tpujob submit --workload serve`` at the same preset's widths
    and depth: a handful of requests, all arriving at t=0, through the
    paged continuous-batching engine, and one of them held against plain
@@ -53,13 +54,17 @@ REPORT_MARK = "run report: "
 # b=6 is the batch this preset was tuned at, and it still fits one 16 GB
 # v5e under the installed jax 0.9.0 (PR 21 chip run: peak_bytes_in_use
 # 9.59e9), although memory_analysis() of the same program adds up to more.
+# It is the largest that fits, so the job states the smallest ``*_mid``
+# set: under ``save_mid`` (flash_o + flash_lse since PR 33) the compiler
+# rematerialises MLP matmuls of its own to fit and the step reads 0.660 s
+# for 0.584 (PR 33 chip run; PERF.md §6).
 TRAIN_BATCH = 6
 
 SIZES = {
     # preset -> (train workload overrides, serve workload overrides)
     "gqa-2048": (
         {"preset": "gqa-2048", "steps": 7, "batch_size": TRAIN_BATCH,
-         "seq_len": 2048, "attn": "flash", "remat": "save_mid"},
+         "seq_len": 2048, "attn": "flash", "remat": "save:resid_mid"},
         # full depth (12 layers), f32 weights and pools as the engine keeps
         # them; max_seq bounds the page table (prompt <= 512, +32 new)
         {"preset": "gqa-2048", "max_seq": 640, "requests": 8,

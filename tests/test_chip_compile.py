@@ -66,15 +66,55 @@ def no_cache():
 
 def _kernel_names_in(text: str) -> list:
     """The HLO instruction name of every Mosaic kernel in a compiled
-    program's text, instance suffix cut (``%flash_fwd.3 = ...
-    custom-call(...)`` gives ``flash_fwd``). The profiler's trace prints an
-    op as its instruction text without metadata, so this name is all a
-    reader of a trace has to tell kernels apart."""
-    import re
+    program's text, instance suffix cut, sorted (``compiled_kernels``, what
+    ``Trainer.step_kernels`` keeps, one entry an instruction)."""
+    from tf_operator_tpu.parallel.collectives import compiled_kernels
 
-    return [m.group(1) for m in re.finditer(
-        r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom-call\([^\n]*"
-        r'custom_call_target="tpu_custom_call"', text)]
+    return [name for name, n in compiled_kernels(text).items()
+            for _ in range(n)]
+
+
+def test_compiled_kernels_cut_instance_suffix_and_transform_wrappers():
+    """By the instruction's own name only: a kernel's name also appears as
+    an operand of the ops that read its result, and other custom calls
+    have other targets."""
+    from tf_operator_tpu.parallel.collectives import compiled_kernels
+
+    call = 'custom_call_target="tpu_custom_call", operand_layout_constraints={}'
+    text = "\n".join([
+        f"  %flash_fwd.3 = (bf16[2,8]{{1,0}}, f32[2]{{0}}) custom-call(%a), {call}",
+        f"  %jvp_flash_fwd_ = bf16[2]{{0}} custom-call(%a, %flash_fwd.3), {call}",
+        f"  ROOT %transpose_jvp_flash_bwd_dq__.1 = bf16[2]{{0}} custom-call(%b), {call}",
+        f"  %gmm_dw_scaled.7 = f32[4]{{0}} custom-call(%c), {call}",
+        '  %s = f32[] custom-call(%flash_fwd.3), custom_call_target="Sharding"',
+    ])
+    assert compiled_kernels(text) == {
+        "flash_bwd_dq": 1, "flash_fwd": 2, "gmm_dw_scaled": 1}
+    assert compiled_kernels("ENTRY %main { ROOT %x = f32[] add(%a, %b) }") == {}
+    # a clone the compiler rebuilds for want of memory is one more run of
+    # that kernel, under the kernel's name
+    for clone in ("flash_fwd.3.remat", "flash_fwd.3.remat2", "flash_fwd.remat.1"):
+        again = f"{text}\n  %{clone} = bf16[2]{{0}} custom-call(%a), {call}"
+        assert compiled_kernels(again)["flash_fwd"] == 3, clone
+
+
+@pytest.mark.parametrize("line, counted", [
+    ("  %fusion.382.remat2 = bf16[6,2048,8192]{2,1,0} fusion(%a), kind=kLoop", 1),
+    ("  %fusion.1678.remat = bf16[135168,2048]{1,0} fusion(%a), kind=kLoop", 1),
+    ("  ROOT %copy.218.remat.1.remat2 = bf16[6,2048,16,128]{3,2,1,0} copy(%a)", 1),
+    # a read of a rebuilt tuple is no work
+    ("  %gte.remat.5 = bf16[6,2048]{1,0} get-tuple-element(%fusion.377.remat3), index=1", 0),
+    # jax's own remat2 region, and a reader of a clone: not clones
+    ("  %remat2.161 = bf16[6,2048,2048]{2,1,0} fusion(%a), kind=kOutput", 0),
+    ("  %fusion.400 = bf16[6,2048]{1,0} fusion(%fusion.382.remat2), kind=kLoop", 0),
+])
+def test_compiled_remats_count_the_compilers_own_clones(line, counted):
+    """``compiled_remats`` (``Trainer.step_remats``): the instructions XLA's
+    scheduler cloned to fit the chip, by their DEFINITIONS' names."""
+    from tf_operator_tpu.parallel.collectives import compiled_remats
+
+    text = f"ENTRY %main {{\n  %a = f32[] parameter(0)\n{line}\n}}"
+    assert compiled_remats(text) == counted
 
 
 def _kernel_names(fn, *args) -> list:
@@ -129,12 +169,20 @@ def test_flash_attention_compiles_for_v5e(one_chip, no_cache, shape, bwd):
         assert sum(kernel in n for n in names) == 1, (kernel, names)
 
 
-def test_flash_kernels_keep_their_names_under_remat_in_a_scan(one_chip, no_cache):
+@pytest.mark.parametrize("policy, fwd_runs", [
+    ("nothing_saveable", 2), ("flash_o+flash_lse", 1)])
+def test_flash_kernels_keep_their_names_under_remat_in_a_scan(
+        one_chip, no_cache, policy, fwd_runs):
     """As the train step holds them: a rematerialised layer inside
-    ``lax.scan``. The forward runs twice (once replayed), both under
-    ``flash_fwd``; before the kernels had names these read ``closed_call``,
-    ``rematted_computation`` and ``checkpoint``."""
+    ``lax.scan``. With nothing saved the forward runs twice (once
+    replayed), both under ``flash_fwd``; before the kernels had names these
+    read ``closed_call``, ``rematted_computation`` and ``checkpoint``. A
+    policy that names the forward's two outputs keeps them for the backward
+    kernels, and the replay is gone (what every ``*_mid`` tier does)."""
     b, t, h, h_kv, d = 2, 1024, 8, 2, 128
+    policies = jax.checkpoint_policies
+    policy = (policies.nothing_saveable if policy == "nothing_saveable" else
+              policies.save_only_these_names("flash_o", "flash_lse"))
 
     def spec(heads):
         return jax.ShapeDtypeStruct((b, t, heads, d), jnp.bfloat16,
@@ -146,16 +194,15 @@ def test_flash_kernels_keep_their_names_under_remat_in_a_scan(one_chip, no_cache
         return fa._flash_lse(q, k, v, True, bq, bk, False)[0]
 
     def loss(q, k, v):
-        f = jax.checkpoint(
-            layer, policy=jax.checkpoint_policies.nothing_saveable)
+        f = jax.checkpoint(layer, policy=policy)
         out, _ = jax.lax.scan(lambda c, _: (c + f(c, k, v), None), q, None,
                               length=2)
         return jnp.sum(out.astype(jnp.float32))
 
     names = _kernel_names(jax.grad(loss, argnums=(0, 1, 2)),
                           spec(h), spec(h_kv), spec(h_kv))
-    assert sorted(names) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd",
-                             "flash_fwd"]
+    assert names == (["flash_bwd_dkv", "flash_bwd_dq"]
+                     + ["flash_fwd"] * fwd_runs)
 
 
 def test_flash_kernels_compile_at_latent_widths_for_v5e(one_chip, no_cache):
@@ -186,7 +233,11 @@ def test_joyai_share_step_fits_v5e_at_the_cells_batch(topo, no_cache):
     compiled for the described chip: 10.9 GB of state beside two 8,192-token
     rows is what the compiler accepted (it refused three), so a change that
     breaks the fit fails here. The flash kernels run at every one of the six
-    blocks, forward, replayed and backward, under their names."""
+    blocks under their names: three instructions each (the dense lead, the
+    scanned body, the prediction module) — ``save_mid`` keeps the forward's
+    ``flash_o`` / ``flash_lse``, so no ``flash_fwd`` is replayed (six before
+    PR 33), and with them the temporaries are 10.21 GB beside 8.17 GB of
+    weights and moments."""
     import json
     import os
     from unittest import mock
@@ -221,10 +272,38 @@ def test_joyai_share_step_fits_v5e_at_the_cells_batch(topo, no_cache):
     names = _kernel_names_in(compiled.as_text())
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "gmm_"):
         assert any(kernel in n for n in names), (kernel, sorted(set(names)))
+    assert {k: trainer.step_kernels[k] for k in
+            ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")} == {
+        "flash_fwd": 3, "flash_bwd_dq": 3, "flash_bwd_dkv": 3}
     # weights + two moments of 680,439,808 parameters, in and out in place
     held = compiled.memory_analysis()
     assert held.argument_size_in_bytes > 12 * cfg.n_params()
     assert held.alias_size_in_bytes > 12 * cfg.n_params()
+
+
+def _compiled_lm_trainer(cfg, mesh_axes, devices, batch_shape):
+    """A ``Trainer`` over the described devices and its dense LM step
+    compiled for them (the process's own backend is the CPU, so the kernels'
+    dispatch is steered to "tpu" around the compile)."""
+    from unittest import mock
+
+    from tf_operator_tpu.models import transformer as tr
+    from tf_operator_tpu.parallel.mesh import build_mesh
+    from tf_operator_tpu.train.trainer import Trainer, TrainerConfig
+
+    mesh = build_mesh(mesh_axes, devices=devices)
+    trainer = Trainer(
+        mesh,
+        loss_fn=lambda p, t, extra: tr.lm_loss(p, t, cfg, mesh=mesh),
+        init_fn=lambda k: tr.init_transformer(k, cfg),
+        logical_axes=tr.transformer_logical_axes(cfg),
+        config=TrainerConfig(optimizer="adamw", learning_rate=1e-3))
+    assert trainer.step_kernels is None and trainer.step_collectives is None
+    assert trainer.step_remats is None
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        compiled = trainer.compile_step(
+            jax.ShapeDtypeStruct(batch_shape, "int32"))
+    return trainer, compiled
 
 
 def test_fsdp4_step_walks_the_cross_entropy_per_chip_on_v5e(topo, no_cache):
@@ -235,24 +314,13 @@ def test_fsdp4_step_walks_the_cross_entropy_per_chip_on_v5e(topo, no_cache):
     Left to propagation the d-sharded head cost two all-reduces of the f32
     logits tile a block (PERF.md §6, PR 31). The cell's own size is compiled
     by hand (§6), not here."""
-    from unittest import mock
-
     from tf_operator_tpu.models import transformer as tr
     from tf_operator_tpu.parallel.collectives import compiled_collectives
-    from tf_operator_tpu.parallel.mesh import build_mesh
-    from tf_operator_tpu.train.trainer import Trainer, TrainerConfig
 
     cfg = tr.preset("tiny", d_model=512, vocab=4096, n_layers=2, n_heads=4,
                     n_kv_heads=2, d_ff=1024, max_seq=2048, attn_impl="flash")
-    mesh = build_mesh({"fsdp": 4}, devices=list(topo.devices))
-    trainer = Trainer(
-        mesh,
-        loss_fn=lambda p, t, extra: tr.lm_loss(p, t, cfg, mesh=mesh),
-        init_fn=lambda k: tr.init_transformer(k, cfg),
-        logical_axes=tr.transformer_logical_axes(cfg),
-        config=TrainerConfig(optimizer="adamw", learning_rate=1e-3))
-    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        compiled = trainer.compile_step(jax.ShapeDtypeStruct((4, 2048), "int32"))
+    trainer, compiled = _compiled_lm_trainer(
+        cfg, {"fsdp": 4}, list(topo.devices), (4, 2048))
     ops = compiled_collectives(compiled.as_text())
     assert not [op for op in ops
                 if any(s.endswith(f",{cfg.vocab}]") for s in op["shapes"])]
@@ -268,6 +336,33 @@ def test_fsdp4_step_walks_the_cross_entropy_per_chip_on_v5e(topo, no_cache):
     assert summary["all-reduce"]["in_loop_max_bytes"] < 2 ** 21, summary
     assert summary["reduce-scatter"]["count"] > 1, summary
     assert summary["largest"] == "reduce-scatter f32" + head, summary
+
+
+# what each remat mode leaves of the flash forward in a compiled step: the
+# tiers that keep its (o, lse) hold the kernel once, every other mode that
+# rematerialises replays it — the programs they were before the two names
+STEP_FLASH_FWD = {
+    "save_mid": 1, "save_mlp_mid": 1, "save:flash_o,flash_lse": 1,
+    "save:resid_mid": 2, "save_qkv": 2, "full": 2, "dots": 2, "none": 1,
+}
+
+
+@pytest.mark.parametrize("remat", sorted(STEP_FLASH_FWD))
+def test_step_kernels_say_what_a_remat_tier_replays_on_v5e(topo, no_cache, remat):
+    """``Trainer.step_kernels`` of a small one-chip step compiled for the
+    described chip: the Pallas kernels by name, as instructions (the layer
+    scan's body holds each once whatever its trips)."""
+    from tf_operator_tpu.models import transformer as tr
+
+    cfg = tr.preset("tiny", d_model=256, vocab=512, n_layers=2, n_heads=2,
+                    n_kv_heads=1, d_ff=512, max_seq=512, attn_impl="flash",
+                    remat=remat)
+    trainer, _ = _compiled_lm_trainer(
+        cfg, {"fsdp": 1}, list(topo.devices)[:1], (2, 512))
+    assert trainer.step_kernels == {
+        "flash_bwd_dkv": 1, "flash_bwd_dq": 1,
+        "flash_fwd": STEP_FLASH_FWD[remat]}
+    assert trainer.step_remats == 0  # a step this small fits under any tier
 
 
 # ---- paged decode: the kernel the serve engine cannot run without ---------

@@ -264,6 +264,8 @@ def test_partition_follows_the_mesh(axes):
     text = trainer.compile_step(jax.ShapeDtypeStruct((4, 2048), "int32")).as_text()
     ops = compiled_collectives(text)
     assert trainer.step_collectives == collectives_summary(ops)
+    assert trainer.step_kernels == {}  # a CPU program holds no Mosaic kernel
+    assert trainer.step_remats == 0  # nor a clone made for want of memory
     in_map = [op for op in ops if "shard_map" in op["op_name"]]
     if not engages:
         assert not in_map

@@ -400,3 +400,31 @@ def test_lm_workload_reports_the_routing_counters(caplog):
     (line,) = [r.getMessage() for r in caplog.records
                if r.getMessage().startswith("run report: ")]
     assert json.loads(line[len("run report: "):])["moe"] is None and not dense.reports
+
+
+@pytest.mark.parametrize("remat, clones, warned", [
+    ("save_mid", 2, True), ("save_mid", 0, False), ("full", 2, False)])
+def test_lm_workload_reports_the_compilers_own_remats(
+        caplog, monkeypatch, remat, clones, warned):
+    """``step_remats`` (the compiled step's ``.remat`` clones) is in the
+    ``run report`` beside ``step_kernels``, and a names policy that
+    compiles with any is warned about: the step runs, slower than a set
+    that fits (here the count is planted: the CPU's compiler makes none)."""
+    import json
+    import logging
+
+    from tf_operator_tpu.train import trainer
+    from tf_operator_tpu.workloads import lm as workload
+
+    monkeypatch.setattr(trainer, "compiled_remats", lambda text: clones)
+    ctx = _LmStubContext({"preset": "tiny", "remat": remat, "steps": 2,
+                          "batch_size": 2, "seq_len": 16})
+    with caplog.at_level(logging.INFO, logger="tpujob.lm"):
+        workload.main(ctx)
+    (line,) = [r.getMessage() for r in caplog.records
+               if r.getMessage().startswith("run report: ")]
+    report = json.loads(line[len("run report: "):])
+    assert report["step_remats"] == clones and report["step_kernels"] == {}
+    warnings = [r.getMessage() for r in caplog.records
+                if r.levelno == logging.WARNING and "rematerialises" in r.getMessage()]
+    assert bool(warnings) == warned, warnings

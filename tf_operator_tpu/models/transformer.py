@@ -45,7 +45,10 @@ class TransformerConfig:
     # True/"full": save only layer inputs, recompute everything (min HBM,
     # +2ND FLOPs). "dots": selective checkpointing — save matmul outputs,
     # recompute just the elementwise chain (near-6ND at moderate HBM).
-    # False/"none": no remat (max HBM).
+    # False/"none": no remat (max HBM). A _REMAT_SAVE_SETS alias
+    # ("save_mid", ...) or "save:name1,name2": save the named activations
+    # only (KNOWN_SAVE_NAMES; the *_mid tiers keep flash_o/flash_lse, so
+    # the flash forward is not replayed).
     remat: Any = True
     # "dense" | "flash" (Pallas kernel) | "ring" (cp ppermute ring) |
     # "ulysses" (cp all-to-all head/seq re-shard; needs heads % cp == 0)
@@ -757,7 +760,11 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh, window: int = 0):
     # dense and flash configs are pinned to the same math by its tests)
     from tf_operator_tpu.ops.flash_attention import reference_attention
 
-    return reference_attention(q, k, v, causal=cfg.causal, window=window)
+    # under the flash entries' name for the attention output, so that a
+    # ``*_mid`` remat tier cuts the recompute chain here on this path too
+    return checkpoint_name(
+        reference_attention(q, k, v, causal=cfg.causal, window=window),
+        "flash_o")
 
 
 def _anchored_gamma(gamma, cfg: TransformerConfig, mesh):
@@ -870,7 +877,8 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
         proj = leave(proj)
     # Selective-remat tag: saving the post-attention residual stream lets
     # the MLP recompute chain start HERE instead of replaying qkv →
-    # attention → wo to rebuild it (see _remat_wrap).
+    # attention → wo to rebuild it ("save:resid_mid"; the *_mid tiers keep
+    # the attention output one product upstream instead, _REMAT_SAVE_SETS).
     x = checkpoint_name(x + proj, "resid_mid")
 
     h = anchor_tokens(_rms_norm(x, gamma_mlp, cfg.norm_eps))
@@ -1057,38 +1065,50 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
 # replays qkv+attn+wo+gate+up in the backward) and "dots" (save every
 # matmul output, OOMs at north-star shapes). Ordered by per-layer HBM cost
 # at gqa-2048 b=6 t=2048 (bf16): flash_q 50.3 MB + flash_k/v 12.6 each;
-# resid_mid 50.3; mlp_up/mlp_gate 201 each. The recompute each tier
-# retires (in btd² matmul units of the 23 the full-remat backward replays
-# — the down projection is never replayed, its output is dead in the
-# backward): qkv 3, +wo 2, +up 8, +gate 8. The attention forward replay
-# (~2 units) is the structural floor of every tier: the flash custom-vjp
-# rebuilds its (o, lse) residuals in the backward regardless (see
-# ops/flash_attention.py FLASH_SAVE_NAMES — the boundary is opaque to
-# name policies on the output side).
+# flash_o 50.3 (b·t·h·dv) + flash_lse 0.8 (b·t·h f32); resid_mid 50.3
+# (b·t·d); mlp_up/mlp_gate 201 each. The recompute each name retires (in
+# btd² matmul units of the 23 the full-remat backward replays — the down
+# projection is never replayed, its output is dead in the backward):
+# qkv 3, the attention forward ~2 (flash_o + flash_lse; on the chip the
+# slowest 2: the flash kernels run at a quarter to a third of their
+# roofline), wo 2 (resid_mid), up 8, gate 8.
+#
+# r5 measured the attention replay as "the structural floor of every
+# tier"; what hid the flash custom-vjp's (o, lse) from name policies was
+# its ``optimize_remat`` registration, not the boundary (ops/
+# flash_attention.py FLASH_SAVE_NAMES). Since PR 33 both are nameable and
+# every ``*_mid`` tier keeps THEM at the point where it kept ``resid_mid``:
+# the recompute chain is still cut at the attention block — the backward
+# replays norm → qkv (+ rotary) for the kernels' operands and the one
+# ``o @ wo`` product for the residual stream behind it, and no flash_fwd.
+# In place of, not beside: where h·dv = d the two weigh what resid_mid
+# weighed, and a step that sat at its memory limit (mistral-7b 2 × 4096 on
+# one v5e) answered all three names with the compiler's OWN
+# rematerialisation of an MLP matmul — 15.7 ms a step for the 10.3 the
+# kernel replay cost (PERF.md §6, PR 33). A job with HBM to spare states
+# the larger set through the syntax that exists,
+# ``remat="save:resid_mid,flash_o,flash_lse"`` (no replayed kernel AND no
+# replayed wo), and ``"save:resid_mid"`` is the set these aliases had.
+_MID = ("flash_o", "flash_lse")
 _REMAT_SAVE_SETS: Dict[str, tuple] = {
-    # the r5 north-star winner: +50 MB/layer at gqa-2048 b=6 retires the
-    # wo replay AND severs the recompute chain at the residual stream —
-    # measured 57.3% exact / 50.9% 6ND vs full remat's 55.9/49.6 (the
-    # only policy that beats full remat at the max-fit batch; BASELINE.md
-    # selective-remat table)
-    "save_mid": ("resid_mid",),
+    # the r5 north-star tier (BASELINE.md selective-remat table: another
+    # installation, with resid_mid where flash_o/flash_lse stand now)
+    "save_mid": _MID,
     "save_qkv": ("flash_q", "flash_k", "flash_v"),
-    "save_qkv_mid": ("flash_q", "flash_k", "flash_v", "resid_mid"),
-    "save_qkv_mid_up": (
-        "flash_q", "flash_k", "flash_v", "resid_mid", "mlp_up",
-    ),
+    "save_qkv_mid": ("flash_q", "flash_k", "flash_v") + _MID,
+    "save_qkv_mid_up": ("flash_q", "flash_k", "flash_v") + _MID + ("mlp_up",),
     "save_qkv_mid_mlp": (
-        "flash_q", "flash_k", "flash_v", "resid_mid", "mlp_up", "mlp_gate",
-    ),
-    "save_mlp_mid": ("resid_mid", "mlp_gate", "mlp_up"),
+        "flash_q", "flash_k", "flash_v") + _MID + ("mlp_up", "mlp_gate"),
+    "save_mlp_mid": _MID + ("mlp_gate", "mlp_up"),
 }
 
 
-# Every checkpoint_name tag the model actually emits (flash q/k/v from
-# ops/flash_attention.FLASH_SAVE_NAMES + the layer-body tags above) —
-# the validation domain for user "save:" policies.
+# Every checkpoint_name tag the model actually emits (the flash inputs and
+# outputs from ops/flash_attention.FLASH_SAVE_NAMES + the layer-body tags
+# above) — the validation domain for user "save:" policies.
 KNOWN_SAVE_NAMES = frozenset(
-    {"flash_q", "flash_k", "flash_v", "resid_mid", "mlp_gate", "mlp_up"}
+    {"flash_q", "flash_k", "flash_v", "flash_o", "flash_lse",
+     "resid_mid", "mlp_gate", "mlp_up"}
 )
 
 

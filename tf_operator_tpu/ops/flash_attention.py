@@ -457,22 +457,32 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None,
 # ---------------------------------------------------------------------------
 
 
-# Selective rematerialization contract (r5): the custom-VJP boundary is
-# opaque to jax.checkpoint policies — checkpoint_name tags INSIDE the fwd
-# rule are invisible to save_only_these_names (measured:
-# print_saved_residuals shows only the arguments, and compiled FLOPs are
-# identical with and without internal tags, optimize_remat or not). So
-# the residuals are restructured to be exactly the MODEL-LAYOUT inputs
-# and public outputs, and the q/k/v INPUTS are tagged in the public
-# entries, outside the call, where the policy can see them. A policy
-# saving flash_q/k/v then retires the qkv projection recompute (the
-# residual q/k/v are literally the saved tagged values); the flash
-# forward itself still re-runs once in the backward to rebuild (o, lse)
-# — the structural floor of this boundary, ~2 of the 31 per-layer fwd
-# matmul units at gqa-2048 shapes. The bwd pays three cheap re-transposes
-# to kernel layout (<1% of step time; the fwd no longer stores its
-# transposed copies, which is a small memory WIN in the no-remat case).
-FLASH_SAVE_NAMES = ("flash_q", "flash_k", "flash_v")
+# Selective rematerialization contract: every residual of the custom-VJP
+# is a model-layout value with a checkpoint_name a policy can save — the
+# q/k/v INPUTS (tagged in the public entries, outside the call) and the
+# two OUTPUTS (o and the compact [b, t, h] f32 lse, tagged INSIDE the fwd
+# rule). A policy saving flash_q/k/v retires the qkv projection recompute
+# (the residual q/k/v are literally the saved tagged values); one saving
+# flash_o/flash_lse retires the replay of the flash forward itself, ~2 of
+# the 31 per-layer fwd matmul units at gqa-2048 shapes: the backward
+# kernels read the o and lse the forward wrote instead of an identical
+# second copy. Per-layer HBM cost: flash_o b·t·h·dv in the activation
+# dtype (50.3 MB at gqa-2048 b=6, the size of flash_q or resid_mid),
+# flash_lse b·t·h·4 B (0.8 MB). The model's ``*_mid`` remat tiers name
+# both (models/transformer.py _REMAT_SAVE_SETS).
+#
+# What r5 measured as "the boundary is opaque on the output side"
+# (print_saved_residuals showing only the arguments, compiled FLOPs
+# identical with and without internal tags) was ``optimize_remat=True``:
+# that wraps the fwd rule in an opaque remat-optimised call whose inner
+# names no policy sees. The vjp is registered WITHOUT it — the fwd's two
+# outputs come from one kernel, so there was nothing for that
+# optimisation to drop — and the inner tags are then visible (jax 0.9.0).
+# Tags outside the call do nothing for the outputs either way: the
+# residual is the fwd rule's own value, not the caller's copy of it.
+# The bwd pays three cheap re-transposes to kernel layout (<1% of step
+# time; the fwd does not store its transposed copies).
+FLASH_SAVE_NAMES = ("flash_q", "flash_k", "flash_v", "flash_o", "flash_lse")
 
 
 def _tag_inputs(q, k, v):
@@ -482,6 +492,18 @@ def _tag_inputs(q, k, v):
         checkpoint_name(q, "flash_q"),
         checkpoint_name(k, "flash_k"),
         checkpoint_name(v, "flash_v"),
+    )
+
+
+def _tag_outputs(out, lse_pub):
+    """The (o, lse) a names policy may keep for the backward: the
+    model-layout output and the compact [b, t, h] f32 row-logsumexp (not
+    the kernel's 128-lane copy). Identities unless a policy names them."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    return (
+        checkpoint_name(out, "flash_o"),
+        checkpoint_name(lse_pub, "flash_lse"),
     )
 
 
@@ -505,10 +527,11 @@ def _flash_lse(q, k, v, causal, block_q, block_k, interpret, window=0):
 
 def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret, window=0):
     out, res = _fwd(q, k, v, causal, block_q, block_k, interpret, window)
-    lse_pub = _lse_public(res[4])
+    out, lse_pub = _tag_outputs(out, _lse_public(res[4]))
     # model-layout residuals: q/k/v are the (possibly checkpoint_name-
-    # tagged) INPUTS — under a names policy they are saved values, so the
-    # backward reconstruction does not replay the qkv projections.
+    # tagged) INPUTS and out/lse the tagged outputs — under a names policy
+    # they are saved values, so the backward reconstruction replays
+    # neither the qkv projections nor this kernel.
     return (out, lse_pub), (q, k, v, out, lse_pub)
 
 
@@ -519,7 +542,7 @@ def _flash_lse_bwd(causal, block_q, block_k, interpret, window, residuals, cts):
                 window=window)
 
 
-_flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd, optimize_remat=True)
+_flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
 def reference_attention_lse(q, k, v, causal: bool = False, window: int = 0):
@@ -587,7 +610,10 @@ def flash_attention_lse(
     _check_window(causal, window)
     q, k, v = _tag_inputs(q, k, v)
     if not use:
-        return reference_attention_lse(q, k, v, causal=causal, window=window)
+        # the dense fallback's (o, lse) under the kernel path's names, so
+        # that a name means the same on both paths
+        return _tag_outputs(
+            *reference_attention_lse(q, k, v, causal=causal, window=window))
     return _flash_lse(q, k, v, causal, block_q, block_k, bool(interpret),
                       int(window))
 
@@ -712,7 +738,8 @@ def flash_attention(
                 f"t={q.shape[1]} d={q.shape[3]} blocks=({block_q},{block_k}) "
                 "does not tile, or is under the hd=64 crossover",
             )
-        return reference_attention(q, k, v, causal=causal, window=window)
+        return _tag_outputs(
+            *reference_attention_lse(q, k, v, causal=causal, window=window))[0]
     # One custom-vjp entry serves both public surfaces (the lse output is
     # a residual either way, so dropping it here costs nothing).
     return _flash_lse(q, k, v, causal, block_q, block_k, bool(interpret),

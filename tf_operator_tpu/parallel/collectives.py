@@ -108,11 +108,13 @@ def axis_size(axis_name: str):
     return jax.lax.psum(1, axis_name)
 
 
-# ---- what a COMPILED program's collectives are ---------------------------
+# ---- what a COMPILED program's collectives and kernels are ---------------
 # Read from the optimised HLO text (``jax.stages.Compiled.as_text()``), the
 # way ``serve.engine.pool_copies`` reads whole-pool copies: a partition that
 # propagation chose badly shows here, at compile time, as a large collective
-# inside a ``while`` body — before a chip has run a step.
+# inside a ``while`` body — before a chip has run a step; so does a remat
+# policy that replays a kernel it was meant to retire, or that saves more
+# than the chip holds (the compiler then rebuilds values on its own).
 
 COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
                     "all-to-all", "collective-permute")
@@ -126,6 +128,54 @@ _CALLED = re.compile(
 _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
                 "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
                 "f64": 8}
+
+
+_PALLAS_CALL = re.compile(
+    r"%([\w\-]+?)(?:\.(?:\d+|remat\d*))* = [^\n]*custom-call\([^\n]*"
+    r'custom_call_target="tpu_custom_call"')
+
+
+_TRANSFORM_PREFIX = re.compile(r"^(?:(?:jvp|transpose|vmap|checkpoint|remat)_)+")
+
+
+def compiled_kernels(hlo_text: str) -> Dict[str, int]:
+    """The Pallas (Mosaic) kernels of a compiled program by name: how many
+    ``tpu_custom_call`` INSTRUCTIONS carry each ``pallas_call`` name
+    (``%flash_fwd.3 = ... custom-call(...)`` counts under ``flash_fwd``;
+    one in a ``while`` body is one instruction, whatever its trips). The
+    instance suffix and the transform wrappers jax adds outside a scan are
+    cut (``transpose_jvp_flash_bwd_dq__.1`` is a ``flash_bwd_dq``), and so
+    is the compiler's mark on a clone it rebuilds for want of memory
+    (``%flash_fwd.3.remat2`` is one more ``flash_fwd``: a replay, the very
+    thing the count is kept to show). The profiler's trace prints an op as
+    its instruction without metadata, so this name is also all a reader of
+    a trace has to tell kernels apart.
+    Empty off the TPU: the interpreter and the jnp references lower to
+    plain HLO."""
+    out: Dict[str, int] = {}
+    for name in _PALLAS_CALL.findall(hlo_text):
+        name = _TRANSFORM_PREFIX.sub("", name).rstrip("_")
+        out[name] = out.get(name, 0) + 1
+    return dict(sorted(out.items()))
+
+
+_COMPILER_REMAT = re.compile(
+    r"^\s*(?:ROOT )?%[\w\-.]*\.remat\d*(?:\.\d+)* = "
+    r"(?![^\n]*? get-tuple-element\()", re.M)
+
+
+def compiled_remats(hlo_text: str) -> int:
+    """How many instructions of a compiled program are the COMPILER's own
+    rematerialisations: clones XLA's scheduler made (``%fusion.382.remat2``,
+    ``%copy.218.remat``) because the program did not fit the chip with
+    every value held to its last use — it then compiles and runs, slower,
+    instead of failing (PERF.md §6, PR 33: three names a layer where there
+    was room for two cost the dense step an MLP matmul a layer). The reads
+    of a rebuilt tuple (``%gte.remat``) are no work and are not counted;
+    jax's own ``remat2`` regions carry the word without the dot. 0 is a
+    step whose remat policy fits; more says the policy saves more than the
+    chip holds at this batch."""
+    return len(_COMPILER_REMAT.findall(hlo_text))
 
 
 def _group_size(line: str) -> int:

@@ -25,6 +25,8 @@ import optax
 from tf_operator_tpu.parallel.collectives import (
     collectives_summary,
     compiled_collectives,
+    compiled_kernels,
+    compiled_remats,
 )
 from tf_operator_tpu.parallel.sharding import DEFAULT_RULES, ShardingRules, replicated
 
@@ -149,6 +151,13 @@ class Trainer:
         # count and operand bytes a step, the largest operand inside a
         # ``while`` body, and the largest instruction; None until compiled
         self.step_collectives: Optional[Dict[str, Any]] = None
+        # and its Pallas kernels: custom-call instructions by kernel name
+        # (a remat tier that keeps flash_o/flash_lse holds ``flash_fwd``
+        # once a layer kind, one that replays it twice); None until compiled
+        self.step_kernels: Optional[Dict[str, int]] = None
+        # and the instructions the COMPILER rebuilt for want of memory
+        # (``.remat`` clones): 0 where the remat policy's saved set fits
+        self.step_remats: Optional[int] = None
         self._precompile_error = None
         self._compiled_hits = 0
         self._compiled_rejections = 0
@@ -299,7 +308,10 @@ class Trainer:
         and timing this call is the step's compile time, cleanly apart
         from its first execution. A compile failure raises. The program's
         collectives are counted as it is kept (``step_collectives``: the
-        compile-time receipt of how the step was partitioned)."""
+        compile-time receipt of how the step was partitioned), and so are
+        its Pallas kernels by name (``step_kernels``: the receipt of what
+        the remat policy replays) and the compiler's own rematerialisations
+        (``step_remats``: what the policy saves beyond what the chip holds)."""
         from jax.sharding import NamedSharding
 
         tmpl = self.state_template()
@@ -320,8 +332,10 @@ class Trainer:
         self._step_compiled = self._step_jit.lower(
             tmpl.params, tmpl.opt_state, tmpl.step, tmpl.extra, batch_spec,
         ).compile()
-        self.step_collectives = collectives_summary(
-            compiled_collectives(self._step_compiled.as_text()))
+        text = self._step_compiled.as_text()
+        self.step_collectives = collectives_summary(compiled_collectives(text))
+        self.step_kernels = compiled_kernels(text)
+        self.step_remats = compiled_remats(text)
         return self._step_compiled
 
     def precompile_step_async(self, batch):

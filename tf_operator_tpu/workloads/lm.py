@@ -44,6 +44,7 @@ def main(ctx: JobContext) -> None:
         lm_loss_with_counters,
         moe_counter_names,
         preset_from_workload,
+        remat_save_names,
         transformer_logical_axes,
         zero_moe_counters,
     )
@@ -174,10 +175,17 @@ def main(ctx: JobContext) -> None:
     # compiler refuses fails HERE with its own error, and the compiled
     # text says which kernels the device will really run.
     t_compile = time.perf_counter()
-    step_kernels = trainer.compile_step(
-        jax.ShapeDtypeStruct((batch, seq), "int32")
-    ).as_text().count("tpu_custom_call")
+    trainer.compile_step(jax.ShapeDtypeStruct((batch, seq), "int32"))
     compile_s = time.perf_counter() - t_compile
+    if trainer.step_remats and remat_save_names(cfg.remat):
+        # the step fits only because XLA rebuilds values it could not hold:
+        # it runs, and a names policy that saves less may run faster
+        # (PERF.md §6, PR 33: gqa-2048 at b = 6, 13 % between two sets)
+        log.warning(
+            "remat=%r at batch %d does not fit with every saved value held: "
+            "the compiler rematerialises %d instructions on its own; compare "
+            "a smaller save set (\"save:...\") or batch",
+            cfg.remat, batch, trainer.step_remats)
 
     try:
         with profile_ctx(wl.get("profile_dir")):
@@ -231,7 +239,13 @@ def main(ctx: JobContext) -> None:
         workload="lm", preset=wl.get("preset", "tiny"), batch_size=batch,
         seq_len=seq, n_layers=cfg.n_layers, attn=cfg.attn_impl,
         step_compile_s=round(compile_s, 3),
-        step_tpu_custom_calls=step_kernels,
+        # the compiled step's Pallas kernels by name (instructions: what a
+        # remat tier replays shows as a second ``flash_fwd``), and their sum
+        step_kernels=trainer.step_kernels,
+        step_tpu_custom_calls=sum(trainer.step_kernels.values()),
+        # the compiler's own ``.remat`` clones: > 0 under a names policy is
+        # a saved set that does not fit (warned above)
+        step_remats=trainer.step_remats,
         # how the compiled step was partitioned: its collectives by kind
         step_collectives=trainer.step_collectives,
         step_s=step_s, losses=ckpt.loss_trace(),
