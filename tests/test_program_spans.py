@@ -367,10 +367,12 @@ class _LmStubContext(_StubContext):
         return build_mesh({"dp": 1}, devices=jax.devices()[:1])
 
 
-def test_lm_workload_reports_the_routing_counters(caplog):
+def test_lm_workload_reports_the_routing_counters(caplog, tmp_path):
     """gmm-dispatched experts: the four ``moe_*`` counters of the last step
     are in the ``run report`` and on ``eval_metrics``; they left the step
-    as ``TrainState.extra``, not through a sync of their own."""
+    as ``TrainState.extra``, not through a sync of their own. Beside them
+    ``step_sections``: the compiled step's instructions by section, and a
+    capture (``profile_dir``) holds them as its ``train.program`` span."""
     import json
     import logging
 
@@ -383,7 +385,13 @@ def test_lm_workload_reports_the_routing_counters(caplog):
         workload.main(ctx)
     (line,) = [r.getMessage() for r in caplog.records
                if r.getMessage().startswith("run report: ")]
-    moe = json.loads(line[len("run report: "):])["moe"]
+    report = json.loads(line[len("run report: "):])
+    counts = report["step_sections"]["instructions"]
+    assert {"embed", "stack", "attn_proj", "attn_core", "router", "moe_dispatch",
+            "moe_experts", "mlp", "head_ce", "optimizer", "none"} == set(counts)
+    assert all(n > 0 for n in counts.values())
+    assert 0 < report["step_sections"]["parse_s"] < 0.5
+    moe = report["moe"]
     assert set(moe) == {"moe_routed_here", "moe_rows_computed",
                         "moe_held_load_max", "moe_held_load_mean"}
     # 2 layers x 64 tokens x top-2, every expert held: nothing routed away
@@ -393,13 +401,18 @@ def test_lm_workload_reports_the_routing_counters(caplog):
     assert ctx.reports[-1] == (3, moe)
     # a dense run has no such entry and reports nothing
     dense = _LmStubContext({"preset": "tiny", "steps": 2, "batch_size": 2,
-                            "seq_len": 16})
+                            "seq_len": 16, "profile_dir": str(tmp_path)})
     caplog.clear()
     with caplog.at_level(logging.INFO, logger="tpujob.lm"):
         workload.main(dense)
     (line,) = [r.getMessage() for r in caplog.records
                if r.getMessage().startswith("run report: ")]
-    assert json.loads(line[len("run report: "):])["moe"] is None and not dense.reports
+    report = json.loads(line[len("run report: "):])
+    assert report["moe"] is None and not dense.reports
+    assert "moe_dispatch" not in report["step_sections"]["instructions"]
+    (spans,) = _host_spans(tmp_path, "train.program").values()
+    ((_, _, _, attrs),) = spans  # once a capture
+    assert attrs["program"] == "jit__step_body" and "optimizer.optimizer" in attrs
 
 
 @pytest.mark.parametrize("remat, clones, warned", [

@@ -826,12 +826,17 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
     b, t, d = x.shape
     hd = cfg.head_dim
     latent = cfg.attn_kind == "latent"
-    if not latent:
-        wq = layer_params["wq"].astype(x.dtype)
-        wk = layer_params["wk"].astype(x.dtype)
-        wv = layer_params["wv"].astype(x.dtype)
-    gamma_attn = _anchored_gamma(layer_params["attn_norm"], cfg, mesh)
-    gamma_mlp = _anchored_gamma(layer_params["mlp_norm"], cfg, mesh)
+    # SECTION scopes (``sec_*``, PERF.md §3): metadata on the instructions
+    # made here, nothing else — ``compiled_sections`` reads them back from
+    # the compiled step. The statements keep the order they had.
+    with jax.named_scope("sec_attn_proj"):
+        if not latent:
+            wq = layer_params["wq"].astype(x.dtype)
+            wk = layer_params["wk"].astype(x.dtype)
+            wv = layer_params["wv"].astype(x.dtype)
+        gamma_attn = _anchored_gamma(layer_params["attn_norm"], cfg, mesh)
+    with jax.named_scope("sec_mlp"):
+        gamma_mlp = _anchored_gamma(layer_params["mlp_norm"], cfg, mesh)
 
     def anchor_tokens(a):
         # companion to _anchored_gamma (same scope): keeps the normed
@@ -853,59 +858,66 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
             a, NamedSharding(mesh, P(data_axes, *(None,) * (a.ndim - 1)))
         )
 
-    h = anchor_tokens(_rms_norm(x, gamma_attn, cfg.norm_eps))
-    if tp_axis is not None:
-        h = enter(h)
-    window, rotary = kind
-    if latent:
-        q, k, v = _latent_qkv(h, layer_params, cfg)
-    else:
-        q = (h @ wq).reshape(b, t, wq.shape[-1] // hd, hd)
-        k = (h @ wk).reshape(b, t, wk.shape[-1] // hd, hd)
-        v = (h @ wv).reshape(b, t, wv.shape[-1] // hd, hd)
-        if rotary:
-            q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+    with jax.named_scope("sec_attn_proj"):
+        h = anchor_tokens(_rms_norm(x, gamma_attn, cfg.norm_eps))
+        if tp_axis is not None:
+            h = enter(h)
+        window, rotary = kind
+        if latent:
+            q, k, v = _latent_qkv(h, layer_params, cfg)
+        else:
+            q = (h @ wq).reshape(b, t, wq.shape[-1] // hd, hd)
+            k = (h @ wk).reshape(b, t, wk.shape[-1] // hd, hd)
+            v = (h @ wv).reshape(b, t, wv.shape[-1] // hd, hd)
+            if rotary:
+                q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
     gate_logits = None
     if cfg.n_experts and not dense and cfg.router_input == "attn_norm":
         # the router sits BEFORE attention: it scores the same normalised
         # tensor attention reads
-        gate_logits = _router_logits(h, layer_params, cfg)
-    attn = _attention(q, k, v, cfg, mesh, window).reshape(
-        b, t, layer_params["wo"].shape[-2])
-    proj = attn @ layer_params["wo"].astype(x.dtype)
-    if tp_axis is not None:
-        proj = leave(proj)
-    # Selective-remat tag: saving the post-attention residual stream lets
-    # the MLP recompute chain start HERE instead of replaying qkv →
-    # attention → wo to rebuild it ("save:resid_mid"; the *_mid tiers keep
-    # the attention output one product upstream instead, _REMAT_SAVE_SETS).
-    x = checkpoint_name(x + proj, "resid_mid")
+        with jax.named_scope("sec_router"):
+            gate_logits = _router_logits(h, layer_params, cfg)
+    with jax.named_scope("sec_attn_core"):
+        attn = _attention(q, k, v, cfg, mesh, window)
+    with jax.named_scope("sec_attn_proj"):
+        attn = attn.reshape(b, t, layer_params["wo"].shape[-2])
+        proj = attn @ layer_params["wo"].astype(x.dtype)
+        if tp_axis is not None:
+            proj = leave(proj)
+        # Selective-remat tag: saving the post-attention residual stream lets
+        # the MLP recompute chain start HERE instead of replaying qkv →
+        # attention → wo to rebuild it ("save:resid_mid"; the *_mid tiers keep
+        # the attention output one product upstream instead, _REMAT_SAVE_SETS).
+        x = checkpoint_name(x + proj, "resid_mid")
 
-    h = anchor_tokens(_rms_norm(x, gamma_mlp, cfg.norm_eps))
+    with jax.named_scope("sec_mlp"):
+        h = anchor_tokens(_rms_norm(x, gamma_mlp, cfg.norm_eps))
     if cfg.n_experts and not dense:
         moe_out, aux = _moe_mlp(h, layer_params, cfg, mesh,
                                 local_ep_axis=local_ep_axis,
                                 gate_logits=gate_logits)
-        if cfg.n_shared_experts:
-            # the shared expert: every token, whole on every chip of a share
-            dt = x.dtype
-            moe_out = moe_out + (
-                jax.nn.silu(h @ layer_params["ws_gate"].astype(dt))
-                * (h @ layer_params["ws_up"].astype(dt))
-            ) @ layer_params["ws_down"].astype(dt)
-        return x + moe_out, aux
-    if tp_axis is not None:
-        h = enter(h)
-    # PRE-activation tags: the silu backward needs the pre-activation
-    # value (silu'(z) is a function of z, not of silu(z)), so saving z
-    # rather than silu(z) is what actually retires the gate/up matmul
-    # recompute — the elementwise silu/mul replay from z is free.
-    z_gate = checkpoint_name(h @ layer_params["w_gate"].astype(x.dtype), "mlp_gate")
-    up = checkpoint_name(h @ layer_params["w_up"].astype(x.dtype), "mlp_up")
-    down = (jax.nn.silu(z_gate) * up) @ layer_params["w_down"].astype(x.dtype)
-    if tp_axis is not None:
-        down = leave(down)
-    return x + down, None
+        with jax.named_scope("sec_mlp"):
+            if cfg.n_shared_experts:
+                # the shared expert: every token, whole on every chip of a share
+                dt = x.dtype
+                moe_out = moe_out + (
+                    jax.nn.silu(h @ layer_params["ws_gate"].astype(dt))
+                    * (h @ layer_params["ws_up"].astype(dt))
+                ) @ layer_params["ws_down"].astype(dt)
+            return x + moe_out, aux
+    with jax.named_scope("sec_mlp"):
+        if tp_axis is not None:
+            h = enter(h)
+        # PRE-activation tags: the silu backward needs the pre-activation
+        # value (silu'(z) is a function of z, not of silu(z)), so saving z
+        # rather than silu(z) is what actually retires the gate/up matmul
+        # recompute — the elementwise silu/mul replay from z is free.
+        z_gate = checkpoint_name(h @ layer_params["w_gate"].astype(x.dtype), "mlp_gate")
+        up = checkpoint_name(h @ layer_params["w_up"].astype(x.dtype), "mlp_up")
+        down = (jax.nn.silu(z_gate) * up) @ layer_params["w_down"].astype(x.dtype)
+        if tp_axis is not None:
+            down = leave(down)
+        return x + down, None
 
 
 # the routing counters of one step (parallel.moe._moe_single_gmm's stats),
@@ -962,7 +974,8 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
     b, t, d = h.shape
     flat = h.reshape(b * t, d)
     if gate_logits is None:  # cfg.router_input == "mlp_norm"
-        gate_logits = _router_logits(h, layer_params, cfg)
+        with jax.named_scope("sec_router"):
+            gate_logits = _router_logits(h, layer_params, cfg)
     act = expert_activation(cfg.expert_act)
 
     def expert_fn(wp, toks):
@@ -1018,13 +1031,14 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
     # out of the discrete top-k assignment, so it carries no gradient and
     # acts as a per-expert coefficient on the differentiable mean gate
     # probability — overloaded experts get their router prob pushed down.
-    lb_loss = cfg.n_experts * jnp.sum(
-        stats["expert_load"] * stats["mean_gate"]
-    )
-    # ST-MoE router z-loss: keeps router logits near the softmax's
-    # well-conditioned range.
-    z = jax.scipy.special.logsumexp(gate_logits.astype(jnp.float32), axis=-1)
-    z_loss = jnp.mean(jnp.square(z))
+    with jax.named_scope("sec_router"):
+        lb_loss = cfg.n_experts * jnp.sum(
+            stats["expert_load"] * stats["mean_gate"]
+        )
+        # ST-MoE router z-loss: keeps router logits near the softmax's
+        # well-conditioned range.
+        z = jax.scipy.special.logsumexp(gate_logits.astype(jnp.float32), axis=-1)
+        z_loss = jnp.mean(jnp.square(z))
     aux = {
         "lb_loss": lb_loss,
         "z_loss": z_loss,
@@ -1273,7 +1287,8 @@ def transformer_hidden_pp(params, tokens, cfg: TransformerConfig, mesh):
             if val % tp:
                 raise ValueError(f"{nm}={val} not divisible by tp={tp}")
         tp_axis = "tp"
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    with jax.named_scope("sec_embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
     layer_fn = _remat_wrap(
         partial(_layer, cfg=cfg, mesh=None, tp_axis=tp_axis,
                 tp_manual_vjp=(cfg.pp_schedule == "1f1b"),
@@ -1340,8 +1355,10 @@ def transformer_hidden_pp(params, tokens, cfg: TransformerConfig, mesh):
             "expert_load": None,  # per-layer telemetry not carried via pp
             "drop_frac": None,
         }
-        return _rms_norm(h, params["final_norm"], cfg.norm_eps), aux
-    return _rms_norm(res, params["final_norm"], cfg.norm_eps), None
+        with jax.named_scope("sec_head_ce"):
+            return _rms_norm(h, params["final_norm"], cfg.norm_eps), aux
+    with jax.named_scope("sec_head_ce"):
+        return _rms_norm(res, params["final_norm"], cfg.norm_eps), None
 
 
 def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
@@ -1389,14 +1406,15 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             carry_anchor = NamedSharding(mesh, P(data_axes, None, None))
-    et = params["embed"].astype(cfg.dtype)
-    if carry_anchor is not None:
-        from jax.sharding import NamedSharding, PartitionSpec as P
+    with jax.named_scope("sec_embed"):
+        et = params["embed"].astype(cfg.dtype)
+        if carry_anchor is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
 
-        et = jax.lax.with_sharding_constraint(
-            et, NamedSharding(mesh, P(None, None))
-        )
-    x = et[tokens]
+            et = jax.lax.with_sharding_constraint(
+                et, NamedSharding(mesh, P(None, None))
+            )
+        x = et[tokens]
     if carry_anchor is not None:
         # The token-embedding-gradient scatter-add (this gather's
         # transpose) accumulates into the table's layout; handing it the
@@ -1422,7 +1440,8 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
             return (jax.lax.with_sharding_constraint(g, rep),)
 
         _bwd_replicate.defvjp(_br_fwd, _br_bwd)
-        x = _bwd_replicate(x)
+        with jax.named_scope("sec_embed"):
+            x = _bwd_replicate(x)
 
     # The leading dense layers: another SHAPE than the scanned stack's, so
     # a section of their own in front of it, each under its own remat.
@@ -1430,7 +1449,8 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
         lead_fn = _remat_wrap(
             partial(_layer, cfg=cfg, mesh=mesh, kind=cfg.pattern[0], dense=True),
             cfg)
-        x, _ = lead_fn(x, jax.tree_util.tree_map(lambda a: a[j], params["lead"]))
+        with jax.named_scope("sec_stack"):
+            x, _ = lead_fn(x, jax.tree_util.tree_map(lambda a: a[j], params["lead"]))
 
     # One scan step is one PERIOD of the layer pattern, its layers
     # unrolled, each with its own static (window, rotary) and its own
@@ -1462,8 +1482,9 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
         # to ANOTHER program for the described v5e (4,086 HLO lines and 45
         # custom calls against 3,919 and 39 at the dense 7B step), and the
         # dense cells are held to the program they had
-        x, aux_stack = jax.lax.scan(
-            partial(one_layer, layer_fns[0]), x, stack)
+        with jax.named_scope("sec_stack"):
+            x, aux_stack = jax.lax.scan(
+                partial(one_layer, layer_fns[0]), x, stack)
     else:
         P = len(pattern)
 
@@ -1478,11 +1499,12 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
                 return x, None
             return x, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *auxes)
 
-        x, aux_stack = jax.lax.scan(
-            period_body, x,
-            jax.tree_util.tree_map(
-                lambda a: a.reshape((cfg.n_stack_layers // P, P) + a.shape[1:]),
-                stack))
+        with jax.named_scope("sec_stack"):
+            x, aux_stack = jax.lax.scan(
+                period_body, x,
+                jax.tree_util.tree_map(
+                    lambda a: a.reshape((cfg.n_stack_layers // P, P) + a.shape[1:]),
+                    stack))
         if aux_stack is not None:  # [periods, P, ...] -> [L, ...]
             aux_stack = jax.tree_util.tree_map(
                 lambda a: a.reshape((cfg.n_stack_layers,) + a.shape[2:]), aux_stack)
@@ -1493,20 +1515,22 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
         # so the fused-CE block walk and the backward loop agree on the
         # batch layout instead of full-rematerializing per layer
         x = jax.lax.with_sharding_constraint(x, carry_anchor)
-    h = _rms_norm(x, _anchored_gamma(params["final_norm"], cfg, mesh),
-                  cfg.norm_eps)
+    with jax.named_scope("sec_head_ce"):
+        h = _rms_norm(x, _anchored_gamma(params["final_norm"], cfg, mesh),
+                      cfg.norm_eps)
     if not with_aux:
         return h
     if aux_stack is None:
         return h, None
-    aux = {
-        "lb_loss": jnp.mean(aux_stack["lb_loss"]),
-        "z_loss": jnp.mean(aux_stack["z_loss"]),
-        "expert_load": aux_stack["expert_load"],  # [L, E]
-        "drop_frac": aux_stack["drop_frac"],  # [L]
-    }
-    # routing counters (gmm dispatch): one scalar a step, summed over layers
-    aux.update({k: jnp.sum(aux_stack[k]) for k in MOE_COUNTERS if k in aux_stack})
+    with jax.named_scope("sec_router"):
+        aux = {
+            "lb_loss": jnp.mean(aux_stack["lb_loss"]),
+            "z_loss": jnp.mean(aux_stack["z_loss"]),
+            "expert_load": aux_stack["expert_load"],  # [L, E]
+            "drop_frac": aux_stack["drop_frac"],  # [L]
+        }
+        # routing counters (gmm dispatch): one scalar a step, summed over layers
+        aux.update({k: jnp.sum(aux_stack[k]) for k in MOE_COUNTERS if k in aux_stack})
     if "expert_count" in aux_stack:
         aux["expert_count"] = aux_stack["expert_count"]  # [L, E]
     return h, aux
@@ -1527,18 +1551,21 @@ def mtp_hidden(params, tokens, h, cfg: TransformerConfig, mesh=None,
     flash kernels tile."""
     m = params["mtp"]
     dt = cfg.dtype
-    e_next = params["embed"].astype(dt)[jnp.roll(tokens, -1, axis=1)]
-    x = jnp.concatenate(
-        [_rms_norm(e_next, m["norm_e"], cfg.norm_eps),
-         _rms_norm(h, m["norm_h"], cfg.norm_eps)], axis=-1) @ m["w_eh"].astype(dt)
-    lp = jax.tree_util.tree_map(lambda a: a[0], m["layer"])
-    if cfg.router_bias and router_bias is not None:
-        lp = dict(lp, router_bias=router_bias[0])
-    layer_fn = _remat_wrap(
-        partial(_layer, cfg=cfg, mesh=mesh, kind=cfg.pattern[0],
-                dense=not cfg.n_experts), cfg)
-    x, aux = layer_fn(x, lp)
-    return _rms_norm(x, m["final_norm"], cfg.norm_eps), aux
+    with jax.named_scope("sec_embed"):  # the module's input: lookup, two norms, W_eh
+        e_next = params["embed"].astype(dt)[jnp.roll(tokens, -1, axis=1)]
+        x = jnp.concatenate(
+            [_rms_norm(e_next, m["norm_e"], cfg.norm_eps),
+             _rms_norm(h, m["norm_h"], cfg.norm_eps)], axis=-1) @ m["w_eh"].astype(dt)
+    with jax.named_scope("sec_stack"):
+        lp = jax.tree_util.tree_map(lambda a: a[0], m["layer"])
+        if cfg.router_bias and router_bias is not None:
+            lp = dict(lp, router_bias=router_bias[0])
+        layer_fn = _remat_wrap(
+            partial(_layer, cfg=cfg, mesh=mesh, kind=cfg.pattern[0],
+                    dense=not cfg.n_experts), cfg)
+        x, aux = layer_fn(x, lp)
+    with jax.named_scope("sec_head_ce"):
+        return _rms_norm(x, m["final_norm"], cfg.norm_eps), aux
 
 
 def transformer_forward(params, tokens, cfg: TransformerConfig, mesh=None):
@@ -1640,14 +1667,16 @@ def lm_loss_and_metrics(params, tokens, cfg: TransformerConfig, mesh=None, key=N
 
     if cfg.causal:
         h_all, aux = _hidden(tokens)
-        ce = _ce(h_all[:, :-1], tokens[:, 1:])
+        with jax.named_scope("sec_head_ce"):
+            ce = _ce(h_all[:, :-1], tokens[:, 1:])
     else:
         if key is None:
             key = jax.random.PRNGKey(0)
         mask = jax.random.bernoulli(key, mask_rate, tokens.shape)
         inputs = jnp.where(mask, MASK_TOKEN, tokens)
         h_all, aux = _hidden(inputs)
-        ce = _ce(h_all, tokens, mask)
+        with jax.named_scope("sec_head_ce"):
+            ce = _ce(h_all, tokens, mask)
 
     metrics = {"ce_loss": ce}
     total = ce
@@ -1677,8 +1706,9 @@ def lm_loss_and_metrics(params, tokens, cfg: TransformerConfig, mesh=None, key=N
             params, tokens, h_all, cfg, mesh,
             router_bias["mtp"] if cfg.router_bias else None)
         t = tokens.shape[1]
-        live = jnp.broadcast_to(jnp.arange(t) < t - 2, tokens.shape)
-        ce_mtp = _ce(h_mtp, jnp.roll(tokens, -2, axis=1), live)
+        with jax.named_scope("sec_head_ce"):
+            live = jnp.broadcast_to(jnp.arange(t) < t - 2, tokens.shape)
+            ce_mtp = _ce(h_mtp, jnp.roll(tokens, -2, axis=1), live)
         total = total + cfg.mtp_weight * ce_mtp
         metrics.update(loss_main=ce, loss_mtp=ce_mtp)
         if aux_mtp is not None:
@@ -1689,7 +1719,8 @@ def lm_loss_and_metrics(params, tokens, cfg: TransformerConfig, mesh=None, key=N
                 counts = jnp.concatenate(
                     [counts, aux_mtp["expert_count"][None]], axis=0)
     if cfg.router_bias and counts is not None:
-        metrics.update(_next_router_bias(cfg, router_bias, counts))
+        with jax.named_scope("sec_router"):
+            metrics.update(_next_router_bias(cfg, router_bias, counts))
     return total, metrics
 
 

@@ -11,7 +11,7 @@ from __future__ import annotations
 import functools
 import math
 import re
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
@@ -130,6 +130,22 @@ _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
                 "f64": 8}
 
 
+def _computations(hlo_text: str) -> Tuple[Dict[str, List[str]], str]:
+    """({computation name: its instruction lines}, the ENTRY's name) of an
+    HLO module's text."""
+    comps: Dict[str, List[str]] = {}
+    name = entry = None
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            is_entry = line.startswith("ENTRY")
+            name = line.split()[1 if is_entry else 0].lstrip("%")
+            comps[name] = []
+            entry = name if is_entry else entry
+        elif name is not None:
+            comps[name].append(line)
+    return comps, entry
+
+
 _PALLAS_CALL = re.compile(
     r"%([\w\-]+?)(?:\.(?:\d+|remat\d*))* = [^\n]*custom-call\([^\n]*"
     r'custom_call_target="tpu_custom_call"')
@@ -148,8 +164,9 @@ def compiled_kernels(hlo_text: str) -> Dict[str, int]:
     is the compiler's mark on a clone it rebuilds for want of memory
     (``%flash_fwd.3.remat2`` is one more ``flash_fwd``: a replay, the very
     thing the count is kept to show). The profiler's trace prints an op as
-    its instruction without metadata, so this name is also all a reader of
-    a trace has to tell kernels apart.
+    its instruction WITHOUT metadata; what part of the model any other
+    instruction belongs to is ``compiled_sections``' to say, from the
+    ``sec_*`` scopes on the metadata this text still has.
     Empty off the TPU: the interpreter and the jnp references lower to
     plain HLO."""
     out: Dict[str, int] = {}
@@ -176,6 +193,157 @@ def compiled_remats(hlo_text: str) -> int:
     step whose remat policy fits; more says the policy saves more than the
     chip holds at this batch."""
     return len(_COMPILER_REMAT.findall(hlo_text))
+
+
+SECTION_PHASES = ("fwd", "bwd", "replay", "optimizer", "other")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(?P<name>[\w.\-]+) = .*? (?P<opcode>[a-z][a-z0-9\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_SECTION = re.compile(r"\bsec_([a-z0-9_]+)")
+_NAME_REMAT = re.compile(r"\.remat\d*(?:\.\d+)*$")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+# computations that run as part of ONE instruction of another (a fusion's
+# body, a reduction's combiner): the trace shows the instruction, not them
+_INLINED = re.compile(r" (?:fusion|reduce|reduce-window|scatter|select-and-scatter|"
+                      r"sort|map|all-reduce|reduce-scatter)(?:-start)?\(.*?"
+                      r"\b(?:calls|to_apply)=%([\w.\-]+)")
+# opcodes that are no work of their own on the device's op line
+_NO_WORK = frozenset((
+    "parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+    "while", "conditional", "call", "after-all", "opt-barrier",
+    "partition-id", "replica-id"))
+
+
+def _op_path(line: str, comps: Dict[str, List[str]]) -> str:
+    """An instruction's ``op_name``; for a fusion the compiler left bare
+    (the CPU backend's all are) its root's, else that of the named
+    instruction nearest its root; "" where there is none."""
+    op = _OP_NAME.search(line)
+    called = None if op else re.search(r"\bcalls=%([\w.\-]+)", line)
+    for inner in reversed(comps.get(called.group(1), ())) if called else ():
+        op = _OP_NAME.search(inner)
+        if op:
+            break
+    return op.group(1) if op else ""
+
+
+def compiled_sections(hlo_text: str) -> Dict[str, List[str]]:
+    """What part of the model and of the step every instruction of a compiled
+    program belongs to, as ``{"<section>.<phase>": [instruction names]}`` —
+    the names a profiler trace prints, each in exactly one key. Every
+    instruction the device runs as an op of its own is filed (fusions,
+    custom calls, dots, convolutions, copies, collectives, the few plain
+    ops XLA leaves unfused; in the entry and every ``while`` body or
+    branch — not inside a fusion or a reduction's combiner).
+
+    Both halves come from the ``op_name`` jax wrote on the instruction.
+    Section: the innermost ``sec_*`` ``jax.named_scope`` on it
+    (``…/closed_call/sec_mlp/dot_general`` and ``transpose(jvp(sec_embed))/…``
+    alike), without the prefix. Phase: ``replay`` where the path holds
+    jax's ``rematted_computation`` or the instruction's name the compiler's
+    own ``.remat`` mark (``compiled_remats``); else ``bwd`` under
+    ``transpose(``; else ``fwd`` under ``jvp(``; else ``optimizer`` in
+    section ``optimizer``; else ``other`` (what was hoisted out of the
+    differentiated function: masks, casts of constants). A fusion that
+    spans two sections is filed where XLA's own metadata puts it — as a
+    rule the matmul it was built around.
+
+    An instruction with no scope on it — as a rule one the compiler made
+    itself, without metadata: the ``copy-start`` / ``copy-done`` and
+    ``slice-start`` / ``slice-done`` pairs that prefetch an operand into
+    fast memory, a layout ``copy`` — works for whatever reads its result,
+    and is filed with the nearest reader that has a section: through
+    bitcasts and the like, through a tuple to the ``get-tuple-element`` of
+    ITS index, and through a ``while``'s operand to what reads that element
+    of the carry inside the body (a weight cast hoisted out of the layer
+    scan is the first matmul's that reads it), at most eight hops. ``none``
+    is what is left: nothing reads it, or no reader has a scope either."""
+    comps, _ = _computations(hlo_text)
+    inlined = set(_INLINED.findall(hlo_text))
+    filed: Dict[str, Tuple[str, str]] = {}   # instruction -> (section, phase)
+    readers: Dict[str, List[str]] = {}       # instruction -> who reads it
+    tuples: Dict[str, List[str]] = {}        # a tuple -> its operands, in order
+    elements: Dict[Tuple[str, int], List[str]] = {}  # (its source, index) -> get-tuple-elements
+    parameter: Dict[str, str] = {}           # computation -> its parameter (a body has one)
+    body_of: Dict[str, str] = {}             # a while -> its body
+    opaque = set()                           # results that are not their operands'
+    for comp, lines in comps.items():
+        if comp in inlined:
+            continue
+        for line in lines:
+            m = _INSTR.match(line)
+            if not m:
+                continue
+            name, opcode = m.group("name"), m.group("opcode")
+            at = m.end()  # just past the opcode's "("
+            operands = _OPERAND.findall(line[at:line.index(")", at)])
+            for operand in operands:
+                readers.setdefault(operand, []).append(name)
+            if opcode == "tuple":
+                tuples[name] = operands
+            elif opcode == "get-tuple-element":
+                index = int(re.search(r"\bindex=(\d+)", line).group(1))
+                elements.setdefault((operands[0], index), []).append(name)
+            elif opcode == "parameter":
+                parameter[comp] = name
+            elif opcode == "while":
+                body_of[name] = re.search(r"\bbody=%([\w.\-]+)", line).group(1)
+            elif opcode in ("conditional", "call"):
+                opaque.add(name)
+            if opcode in _NO_WORK:
+                continue
+            path = _op_path(line, comps)
+            found = _SECTION.findall(path)
+            if "rematted_computation" in path or _NAME_REMAT.search(name):
+                phase = "replay"
+            elif "transpose(" in path:
+                phase = "bwd"
+            elif "jvp(" in path:
+                phase = "fwd"
+            else:
+                phase = "optimizer" if found and found[-1] == "optimizer" else "other"
+            filed[name] = (found[-1] if found else "none", phase)
+
+    def seen_through(name: str, reader: str) -> List[str]:
+        """Where ``name`` goes on through ``reader``, which has no section."""
+        if reader in body_of or reader in opaque:
+            return []
+        if reader not in tuples:
+            return [reader]
+        out = []  # by index: into a loop's body, else to the tuple's own elements
+        for index, operand in enumerate(tuples[reader]):
+            for user in readers.get(reader, ()) if operand == name else ():
+                source = parameter.get(body_of[user], "") if user in body_of else user
+                out += elements.get((source, index), [])
+        return out
+
+    def reader_with_a_section(name: str, hops: int = 8) -> Optional[Tuple[str, str]]:
+        for reader in readers.get(name, ()) if hops else ():
+            got = filed.get(reader)
+            if got is not None and got[0] != "none":
+                return got
+            for onward in seen_through(name, reader):
+                got = reader_with_a_section(onward, hops - 1)
+                if got is not None:
+                    return got
+        return None
+
+    out: Dict[str, List[str]] = {}
+    for name, (section, phase) in filed.items():
+        if section == "none":
+            got = reader_with_a_section(name)
+            if got is not None:  # its own ``.remat`` mark still says replay
+                section, phase = got[0], "replay" if phase == "replay" else got[1]
+        out.setdefault(f"{section}.{phase}", []).append(name)
+    return dict(sorted(out.items()))
+
+
+def sections_summary(sections: Dict[str, List[str]]) -> Dict[str, int]:
+    """``compiled_sections`` as instruction counts by section."""
+    out: Dict[str, int] = {}
+    for key, names in sections.items():
+        section = key.rsplit(".", 1)[0]
+        out[section] = out.get(section, 0) + len(names)
+    return out
 
 
 def _group_size(line: str) -> int:
@@ -212,16 +380,7 @@ def compiled_collectives(hlo_text: str) -> List[Dict[str, Any]]:
     a step executes it (the product of the trips of the ``while`` bodies
     around it, ``_trip_count``); ``in_loop``; and ``op_name``, the jax op it
     was made for."""
-    comps: Dict[str, List[str]] = {}
-    name = entry = None
-    for line in hlo_text.splitlines():
-        if line.endswith("{") and not line.startswith(" "):
-            is_entry = line.startswith("ENTRY")
-            name = line.split()[1 if is_entry else 0].lstrip("%")
-            comps[name] = []
-            entry = name if is_entry else entry
-        elif name is not None:
-            comps[name].append(line)
+    comps, entry = _computations(hlo_text)
 
     # computation -> [(caller, executions of it a run of the caller)]
     callers: Dict[str, List[Tuple[str, int, bool]]] = {}

@@ -66,11 +66,12 @@ def _route(x, gate_logits, capacity: int, k_top: int = 1, dropped: str = "passth
     observability: expert_load [E] (fraction of token-choices assigned to
     each expert), mean_gate [E] (mean router probability), drop_frac
     (fraction of token-choices that overflowed capacity))."""
-    gate_probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
     n_experts = gate_logits.shape[-1]
-    top_p, top_i = jax.lax.top_k(gate_probs, k_top)  # [T, k]
-    if k_top > 1:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    with jax.named_scope("sec_router"):
+        gate_probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
+        top_p, top_i = jax.lax.top_k(gate_probs, k_top)  # [T, k]
+        if k_top > 1:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
     # assign[t, e] = 1 if e is one of t's choices; w[t, e] = its gate weight
     choice_onehot = jax.nn.one_hot(top_i, n_experts, dtype=jnp.float32)  # [T,k,E]
@@ -91,11 +92,12 @@ def _route(x, gate_logits, capacity: int, k_top: int = 1, dropped: str = "passth
     # Expert inboxes from local tokens: [E, C, d]
     inbox = jnp.einsum("tec,td->ecd", dispatch, x.astype(jnp.float32))
     n_choices = jnp.float32(x.shape[0] * k_top)
-    stats = {
-        "expert_load": jnp.sum(assign, axis=0) / n_choices,  # [E]
-        "mean_gate": jnp.mean(gate_probs, axis=0),  # [E]
-        "drop_frac": 1.0 - jnp.sum(kept) / n_choices,
-    }
+    with jax.named_scope("sec_router"):
+        stats = {
+            "expert_load": jnp.sum(assign, axis=0) / n_choices,  # [E]
+            "mean_gate": jnp.mean(gate_probs, axis=0),  # [E]
+            "drop_frac": 1.0 - jnp.sum(kept) / n_choices,
+        }
     return dispatch_w, keep_any, inbox, stats
 
 
@@ -113,12 +115,13 @@ def _route_sparse(x, gate_logits, capacity: int, k_top: int = 1,
     dump row for capacity-dropped choices), w [T,k] f32 combine weights,
     keep_any [T], inbox [E,C,d] f32, stats) — inbox layout identical to
     _route's, so the ep all_to_all path is impl-agnostic."""
-    gate_probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
     tokens, d = x.shape
     n_experts = gate_logits.shape[-1]
-    top_p, top_i = jax.lax.top_k(gate_probs, k_top)  # [T, k]
-    if k_top > 1:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    with jax.named_scope("sec_router"):
+        gate_probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
+        top_p, top_i = jax.lax.top_k(gate_probs, k_top)  # [T, k]
+        if k_top > 1:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
     flat_e = top_i.reshape(-1).astype(jnp.int32)  # [T*k], t-major: the
     # stable sort below then orders each expert's queue by token index —
@@ -152,11 +155,12 @@ def _route_sparse(x, gate_logits, capacity: int, k_top: int = 1,
     inbox = inbox[:-1].reshape(n_experts, capacity, d)
 
     n_choices = jnp.float32(tokens * k_top)
-    stats = {
-        "expert_load": counts.astype(jnp.float32) / n_choices,
-        "mean_gate": jnp.mean(gate_probs, axis=0),
-        "drop_frac": 1.0 - jnp.sum(kept) / n_choices,
-    }
+    with jax.named_scope("sec_router"):
+        stats = {
+            "expert_load": counts.astype(jnp.float32) / n_choices,
+            "mean_gate": jnp.mean(gate_probs, axis=0),
+            "drop_frac": 1.0 - jnp.sum(kept) / n_choices,
+        }
     return slot, w, keep_any, inbox, stats
 
 
@@ -225,90 +229,97 @@ def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
     tokens, d = x.shape
     n_experts = gate_logits.shape[-1]
     held = expert_params["w_gate"].shape[0]
-    if score == "sigmoid":
-        gate_probs = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
-        _, top_i = jax.lax.top_k(
-            gate_probs if bias is None else gate_probs + bias, k_top)
-        top_p = jnp.take_along_axis(gate_probs, top_i, axis=-1)
-        top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20) * scale
-    elif score != "softmax":
-        raise ValueError(f"unknown router score {score!r} (softmax | sigmoid)")
-    else:
-        gate_probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-        top_p, top_i = jax.lax.top_k(gate_probs, k_top)  # [T, k]
-        if k_top > 1:
-            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    with jax.named_scope("sec_router"):
+        if score == "sigmoid":
+            gate_probs = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
+            _, top_i = jax.lax.top_k(
+                gate_probs if bias is None else gate_probs + bias, k_top)
+            top_p = jnp.take_along_axis(gate_probs, top_i, axis=-1)
+            top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20) * scale
+        elif score != "softmax":
+            raise ValueError(f"unknown router score {score!r} (softmax | sigmoid)")
+        else:
+            gate_probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
+            top_p, top_i = jax.lax.top_k(gate_probs, k_top)  # [T, k]
+            if k_top > 1:
+                top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
     tk = tokens * k_top
     B = block_rows
     nb = -(-tk // B) + held  # static lossless bound incl. per-expert pad
-    chosen = top_i.reshape(-1).astype(jnp.int32)  # [T*k], t-major
-    local = chosen - first
-    here = (local >= 0) & (local < held)
-    flat_e = jnp.where(here, local, held)  # not held: a spare bucket, sorted last
-    order = jnp.argsort(flat_e, stable=True)
-    bucket = jnp.bincount(flat_e, length=held + 1).astype(jnp.int32)
-    counts = bucket[:held]
-    bucket_start = jnp.cumsum(bucket) - bucket
-    offsets = bucket_start[:held]  # unpadded sorted offsets
-    rank_sorted = jnp.arange(tk, dtype=jnp.int32) - bucket_start[flat_e[order]]
-    ranks = jnp.zeros((tk,), jnp.int32).at[order].set(rank_sorted)
+    with jax.named_scope("sec_moe_dispatch"):
+        chosen = top_i.reshape(-1).astype(jnp.int32)  # [T*k], t-major
+        local = chosen - first
+        here = (local >= 0) & (local < held)
+        flat_e = jnp.where(here, local, held)  # not held: a spare bucket, sorted last
+        order = jnp.argsort(flat_e, stable=True)
+        bucket = jnp.bincount(flat_e, length=held + 1).astype(jnp.int32)
+        counts = bucket[:held]
+        bucket_start = jnp.cumsum(bucket) - bucket
+        offsets = bucket_start[:held]  # unpadded sorted offsets
+        rank_sorted = jnp.arange(tk, dtype=jnp.int32) - bucket_start[flat_e[order]]
+        ranks = jnp.zeros((tk,), jnp.int32).at[order].set(rank_sorted)
 
-    # an expert with no routed row owns no block: the dw kernel zeroes
-    # every (expert, col-tile) at its walk's first step, so its gradient
-    # is an exact zero all the same (test_gmm_zero_token_expert_gets_zero_grad)
-    blocks_per_e = -(-counts // B)
-    bounds = jnp.cumsum(blocks_per_e)  # [held]
-    pad_start = (bounds - blocks_per_e) * B
-    owner = jnp.searchsorted(
-        bounds, jnp.arange(nb, dtype=jnp.int32), side="right"
-    ).astype(jnp.int32)
-    block_expert = jnp.where(owner < held, owner, -1)
-    # padded slot s -> source token (sentinel and round-up slots read row
-    # 0 with gate weight 0; their outputs are never gathered back and
-    # their cotangents are zero)
-    s = jnp.arange(nb * B, dtype=jnp.int32)
-    owner_s = block_expert[s // B]
-    e_s = jnp.maximum(owner_s, 0)
-    rank_s = s - pad_start[e_s]
-    valid = (owner_s >= 0) & (rank_s < counts[e_s])
-    src_choice = order[jnp.clip(offsets[e_s] + rank_s, 0, tk - 1)]
-    x_pad = x[jnp.where(valid, src_choice // k_top, 0)]  # [nb*B, d]
+        # an expert with no routed row owns no block: the dw kernel zeroes
+        # every (expert, col-tile) at its walk's first step, so its gradient
+        # is an exact zero all the same (test_gmm_zero_token_expert_gets_zero_grad)
+        blocks_per_e = -(-counts // B)
+        bounds = jnp.cumsum(blocks_per_e)  # [held]
+        pad_start = (bounds - blocks_per_e) * B
+        owner = jnp.searchsorted(
+            bounds, jnp.arange(nb, dtype=jnp.int32), side="right"
+        ).astype(jnp.int32)
+        block_expert = jnp.where(owner < held, owner, -1)
+        # padded slot s -> source token (sentinel and round-up slots read row
+        # 0 with gate weight 0; their outputs are never gathered back and
+        # their cotangents are zero)
+        s = jnp.arange(nb * B, dtype=jnp.int32)
+        owner_s = block_expert[s // B]
+        e_s = jnp.maximum(owner_s, 0)
+        rank_s = s - pad_start[e_s]
+        valid = (owner_s >= 0) & (rank_s < counts[e_s])
+        src_choice = order[jnp.clip(offsets[e_s] + rank_s, 0, tk - 1)]
+        x_pad = x[jnp.where(valid, src_choice // k_top, 0)]  # [nb*B, d]
 
     from tf_operator_tpu.ops.grouped_matmul import gmm
 
     interpret = jax.default_backend() != "tpu"
     run = partial(gmm, block_rows=B, interpret=interpret)
-    zg = run(x_pad, expert_params["w_gate"].astype(x.dtype), block_expert)
-    zu = run(x_pad, expert_params["w_up"].astype(x.dtype), block_expert)
+    with jax.named_scope("sec_moe_experts"):
+        zg = run(x_pad, expert_params["w_gate"].astype(x.dtype), block_expert)
+        zu = run(x_pad, expert_params["w_up"].astype(x.dtype), block_expert)
     # fused combine epilogue (r6): each padded slot's gate weight rides
     # the down-projection kernel as a row scale, so the combine below is
     # a pure gather+sum — the separate f32 [T,k,d] weighted-reduction
     # einsum (and its HBM pass) is gone. Garbage slots scale by 0.
-    s_pad = jnp.where(valid, top_p.reshape(-1)[src_choice], 0.0)
-    h = run(act(zg) * zu,
-            expert_params["w_down"].astype(x.dtype), block_expert,
-            row_scale=s_pad)
+    with jax.named_scope("sec_moe_dispatch"):
+        s_pad = jnp.where(valid, top_p.reshape(-1)[src_choice], 0.0)
+    with jax.named_scope("sec_moe_experts"):
+        h = run(act(zg) * zu,
+                expert_params["w_down"].astype(x.dtype), block_expert,
+                row_scale=s_pad)
 
     # every held choice's padded slot; a choice held elsewhere adds nothing
-    dst = jnp.where(here, pad_start[jnp.clip(local, 0, held - 1)] + ranks, 0)
-    gathered = h[dst.reshape(tokens, k_top)]  # [T, k, d] — pre-weighted
-    out = jnp.sum(
-        jnp.where(here.reshape(tokens, k_top, 1), gathered, 0).astype(jnp.float32),
-        axis=1,
-    )
-    held_counts = counts.astype(jnp.float32)
-    all_counts = jnp.bincount(chosen, length=n_experts)
-    stats = {
-        "expert_load": all_counts.astype(jnp.float32) / tk,
-        "expert_count": all_counts.astype(jnp.int32),
-        "mean_gate": jnp.mean(gate_probs, axis=0),
-        "drop_frac": jnp.float32(0.0),
-        "routed_here": jnp.sum(held_counts),
-        "rows_computed": (bounds[-1] * B).astype(jnp.float32),
-        "held_load_max": jnp.max(held_counts),
-        "held_load_mean": jnp.mean(held_counts),
-    }
+    with jax.named_scope("sec_moe_dispatch"):
+        dst = jnp.where(here, pad_start[jnp.clip(local, 0, held - 1)] + ranks, 0)
+        gathered = h[dst.reshape(tokens, k_top)]  # [T, k, d] — pre-weighted
+        out = jnp.sum(
+            jnp.where(here.reshape(tokens, k_top, 1), gathered, 0).astype(jnp.float32),
+            axis=1,
+        )
+    with jax.named_scope("sec_router"):
+        held_counts = counts.astype(jnp.float32)
+        all_counts = jnp.bincount(chosen, length=n_experts)
+        stats = {
+            "expert_load": all_counts.astype(jnp.float32) / tk,
+            "expert_count": all_counts.astype(jnp.int32),
+            "mean_gate": jnp.mean(gate_probs, axis=0),
+            "drop_frac": jnp.float32(0.0),
+            "routed_here": jnp.sum(held_counts),
+            "rows_computed": (bounds[-1] * B).astype(jnp.float32),
+            "held_load_max": jnp.max(held_counts),
+            "held_load_mean": jnp.mean(held_counts),
+        }
     return out.astype(x.dtype), stats
 
 
@@ -362,10 +373,11 @@ def _moe_local_gmm(x, gate_logits, expert_params, axis_name: str,
     B = block_rows
     tk = tokens * k_top
 
-    gate_probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    top_p, top_i = jax.lax.top_k(gate_probs, k_top)  # [T, k]
-    if k_top > 1:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    with jax.named_scope("sec_router"):
+        gate_probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
+        top_p, top_i = jax.lax.top_k(gate_probs, k_top)  # [T, k]
+        if k_top > 1:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
     flat_e = top_i.reshape(-1).astype(jnp.int32)  # [T*k], t-major
     order = jnp.argsort(flat_e, stable=True)
@@ -424,22 +436,24 @@ def _moe_local_gmm(x, gate_logits, expert_params, axis_name: str,
     interpret = jax.default_backend() != "tpu"
     run = partial(gmm, block_rows=B, interpret=interpret)
     x_flat = x_rcv.reshape(n_shards * s_cap, d)
-    zg = run(x_flat, expert_params["w_gate"].astype(x.dtype), block_expert)
-    zu = run(x_flat, expert_params["w_up"].astype(x.dtype), block_expert)
-    h = run(act(zg) * zu,
-            expert_params["w_down"].astype(x.dtype), block_expert,
-            row_scale=s_rcv.reshape(-1))
+    with jax.named_scope("sec_moe_experts"):
+        zg = run(x_flat, expert_params["w_gate"].astype(x.dtype), block_expert)
+        zu = run(x_flat, expert_params["w_up"].astype(x.dtype), block_expert)
+        h = run(act(zg) * zu,
+                expert_params["w_down"].astype(x.dtype), block_expert,
+                row_scale=s_rcv.reshape(-1))
 
     # --- return results to source shards, combine -----------------------
     h_ret = a2a(h.reshape(n_shards, s_cap, -1)).reshape(n_shards * s_cap, -1)
     gathered = h_ret[send_slot.reshape(tokens, k_top)]  # [T, k, d] pre-weighted
     out = jnp.sum(gathered.astype(jnp.float32), axis=1)
 
-    stats = {
-        "expert_load": counts.astype(jnp.float32) / tk,
-        "mean_gate": jnp.mean(gate_probs, axis=0),
-        "drop_frac": jnp.float32(0.0),
-    }
+    with jax.named_scope("sec_router"):
+        stats = {
+            "expert_load": counts.astype(jnp.float32) / tk,
+            "mean_gate": jnp.mean(gate_probs, axis=0),
+            "drop_frac": jnp.float32(0.0),
+        }
     return out.astype(x.dtype), stats
 
 
@@ -499,12 +513,13 @@ def _moe_single(x, gate_logits, expert_params, expert_fn, capacity: int, dropped
             "a share of the experts (fewer expert weights than router "
             "outputs) runs on dispatch_impl='gmm' only"
         )
-    if dispatch_impl == "sort":
-        slot, w, keep_any, inbox, stats = _route_sparse(
-            x, gate_logits, capacity, k_top, dropped)
-    else:
-        dispatch_w, keep_any, inbox, stats = _route(
-            x, gate_logits, capacity, k_top, dropped)
+    with jax.named_scope("sec_moe_dispatch"):
+        if dispatch_impl == "sort":
+            slot, w, keep_any, inbox, stats = _route_sparse(
+                x, gate_logits, capacity, k_top, dropped)
+        else:
+            dispatch_w, keep_any, inbox, stats = _route(
+                x, gate_logits, capacity, k_top, dropped)
 
     # vmap over the stacked expert dim — ONE batched-matmul program for
     # all experts. r4: the previous fori_loop ran E sequential [C,d]
@@ -513,14 +528,16 @@ def _moe_single(x, gate_logits, expert_params, expert_fn, capacity: int, dropped
     # FLOPs measured 15.1 ms looped vs 8.1 ms batched (an earlier
     # installation's reading), and the batched form runs at 87% of the
     # chip's chained matmul rate.
-    outbox = jax.vmap(
-        lambda w_e, t: expert_fn(w_e, t.astype(x.dtype))
-    )(expert_params, inbox).astype(jnp.float32)
-    if dispatch_impl == "sort":
-        combined = _combine_sparse(outbox, slot, w)
-    else:
-        combined = jnp.einsum("tec,ecd->td", dispatch_w, outbox)
-    out = jnp.where(keep_any[:, None], combined, _dropped_value(x, dropped))
+    with jax.named_scope("sec_moe_experts"):
+        outbox = jax.vmap(
+            lambda w_e, t: expert_fn(w_e, t.astype(x.dtype))
+        )(expert_params, inbox).astype(jnp.float32)
+    with jax.named_scope("sec_moe_dispatch"):
+        if dispatch_impl == "sort":
+            combined = _combine_sparse(outbox, slot, w)
+        else:
+            combined = jnp.einsum("tec,ecd->td", dispatch_w, outbox)
+        out = jnp.where(keep_any[:, None], combined, _dropped_value(x, dropped))
     return out.astype(x.dtype), stats
 
 
@@ -550,28 +567,32 @@ def _moe_local(x, gate_logits, expert_params, expert_fn, axis_name: str, capacit
                 f"expert_fn; got param keys {sorted(expert_params)} — use "
                 "dispatch_impl='sort' for custom expert bodies"
             )
-        out, stats = _moe_local_gmm(
-            x, gate_logits, expert_params, axis_name, k_top, block_rows,
-            act=expert_activation(expert_act),
-        )
+        # the layout and the exchanges; the router's part and the kernels
+        # name their own sections inside
+        with jax.named_scope("sec_moe_dispatch"):
+            out, stats = _moe_local_gmm(
+                x, gate_logits, expert_params, axis_name, k_top, block_rows,
+                act=expert_activation(expert_act),
+            )
         for ax in stat_axes or (axis_name,):
             stats = jax.tree_util.tree_map(
                 lambda s: jax.lax.pmean(s, ax), stats)
         return out, stats
-    if dispatch_impl == "sort":
-        slot, w, keep_any, inbox, stats = _route_sparse(
-            x, gate_logits, capacity, k_top, dropped)
-    else:
-        dispatch_w, keep_any, inbox, stats = _route(
-            x, gate_logits, capacity, k_top, dropped)
+    with jax.named_scope("sec_moe_dispatch"):
+        if dispatch_impl == "sort":
+            slot, w, keep_any, inbox, stats = _route_sparse(
+                x, gate_logits, capacity, k_top, dropped)
+        else:
+            dispatch_w, keep_any, inbox, stats = _route(
+                x, gate_logits, capacity, k_top, dropped)
 
-    # all_to_all: regroup so each shard holds inboxes for ITS experts from
-    # every shard: [E, C, d] -> [E_local * n_shards, C, d] where the leading
-    # dim interleaves (source_shard, local_expert).
-    inbox = inbox.reshape(n_shards, experts_per_shard, capacity, d)
-    inbox = jax.lax.all_to_all(inbox, axis_name, split_axis=0, concat_axis=0, tiled=False)
-    # Now: [n_shards(source), E_local, C, d] on each device.
-    inbox = inbox.reshape(n_shards, experts_per_shard, capacity, d)
+        # all_to_all: regroup so each shard holds inboxes for ITS experts from
+        # every shard: [E, C, d] -> [E_local * n_shards, C, d] where the leading
+        # dim interleaves (source_shard, local_expert).
+        inbox = inbox.reshape(n_shards, experts_per_shard, capacity, d)
+        inbox = jax.lax.all_to_all(inbox, axis_name, split_axis=0, concat_axis=0, tiled=False)
+        # Now: [n_shards(source), E_local, C, d] on each device.
+        inbox = inbox.reshape(n_shards, experts_per_shard, capacity, d)
 
     # Run each local expert over its gathered tokens — vmapped over the
     # expert dim into one batched-matmul program (r4, same rationale as
@@ -581,20 +602,22 @@ def _moe_local(x, gate_logits, expert_params, expert_fn, axis_name: str, capacit
         out = expert_fn(params_e, toks.reshape(n_shards * capacity, d).astype(x.dtype))
         return out.astype(jnp.float32).reshape(n_shards, capacity, d)
 
-    outbox = jax.vmap(one_expert, in_axes=(0, 1), out_axes=1)(
-        expert_params, inbox
-    )
+    with jax.named_scope("sec_moe_experts"):
+        outbox = jax.vmap(one_expert, in_axes=(0, 1), out_axes=1)(
+            expert_params, inbox
+        )
 
-    # Return results to source shards.
-    outbox = jax.lax.all_to_all(outbox, axis_name, split_axis=0, concat_axis=0, tiled=False)
-    outbox = outbox.reshape(n_experts, capacity, d)
+    with jax.named_scope("sec_moe_dispatch"):
+        # Return results to source shards.
+        outbox = jax.lax.all_to_all(outbox, axis_name, split_axis=0, concat_axis=0, tiled=False)
+        outbox = outbox.reshape(n_experts, capacity, d)
 
-    # Combine: weight by gate prob; dropped tokens per the dropped mode.
-    if dispatch_impl == "sort":
-        combined = _combine_sparse(outbox, slot, w)
-    else:
-        combined = jnp.einsum("tec,ecd->td", dispatch_w, outbox)
-    out = jnp.where(keep_any[:, None], combined, _dropped_value(x, dropped))
+        # Combine: weight by gate prob; dropped tokens per the dropped mode.
+        if dispatch_impl == "sort":
+            combined = _combine_sparse(outbox, slot, w)
+        else:
+            combined = jnp.einsum("tec,ecd->td", dispatch_w, outbox)
+        out = jnp.where(keep_any[:, None], combined, _dropped_value(x, dropped))
     # Aggregate router stats across token shards (every shard routed its
     # own slice; the job-level view is the mean over all of them).
     for ax in stat_axes or (axis_name,):

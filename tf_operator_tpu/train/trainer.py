@@ -14,9 +14,10 @@ inherits the param shardings; batches shard over ("dp","fsdp").
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,7 @@ from tf_operator_tpu.parallel.collectives import (
     compiled_collectives,
     compiled_kernels,
     compiled_remats,
+    compiled_sections,
 )
 from tf_operator_tpu.parallel.sharding import DEFAULT_RULES, ShardingRules, replicated
 
@@ -158,6 +160,14 @@ class Trainer:
         # and the instructions the COMPILER rebuilt for want of memory
         # (``.remat`` clones): 0 where the remat policy's saved set fits
         self.step_remats: Optional[int] = None
+        # and what part of the model and of the step each instruction is
+        # (``compiled_sections``: {"<section>.<phase>": [instruction names]}
+        # from the ``sec_*`` scopes), with the seconds the parse took; a
+        # profiler session gets it once as the ``train.program`` span
+        self.step_sections: Optional[Dict[str, List[str]]] = None
+        self.step_sections_parse_s: Optional[float] = None
+        self._step_program = ""  # the compiled step's module name
+        self._program_in_trace = False
         self._precompile_error = None
         self._compiled_hits = 0
         self._compiled_rejections = 0
@@ -310,8 +320,11 @@ class Trainer:
         collectives are counted as it is kept (``step_collectives``: the
         compile-time receipt of how the step was partitioned), and so are
         its Pallas kernels by name (``step_kernels``: the receipt of what
-        the remat policy replays) and the compiler's own rematerialisations
-        (``step_remats``: what the policy saves beyond what the chip holds)."""
+        the remat policy replays), the compiler's own rematerialisations
+        (``step_remats``: what the policy saves beyond what the chip holds)
+        and every instruction's section and phase (``step_sections``: what
+        a trace of this program needs to name its ops; ``step()`` writes it
+        into each profiler session)."""
         from jax.sharding import NamedSharding
 
         tmpl = self.state_template()
@@ -336,6 +349,12 @@ class Trainer:
         self.step_collectives = collectives_summary(compiled_collectives(text))
         self.step_kernels = compiled_kernels(text)
         self.step_remats = compiled_remats(text)
+        t0 = time.perf_counter()
+        self.step_sections = compiled_sections(text)
+        self.step_sections_parse_s = time.perf_counter() - t0
+        # "HloModule jit__step_body, ...": the name ``XLA Modules`` prints
+        self._step_program = text.split(None, 2)[1].rstrip(",")
+        self._program_in_trace = False
         return self._step_compiled
 
     def precompile_step_async(self, batch):
@@ -365,8 +384,23 @@ class Trainer:
         """One optimizer step (returns at enqueue). Under a profiler
         session the dispatch is a ``train.step`` span whose ``call`` is
         this trainer's host-side count of calls — never ``state.step``,
-        which lives on the device and would synchronise to read."""
+        which lives on the device and would synchronise to read. The first
+        call of each session also writes the zero-length ``train.program``
+        span: the compiled step's module name and, one attribute a
+        ``<section>.<phase>``, its instructions' names (``step_sections``),
+        so that the trace says by itself what part of the model each of its
+        ops is. With no session open a call pays the one ``is_enabled()``
+        read and writes nothing."""
         self._step_calls += 1
+        if not jax.profiler.TraceAnnotation.is_enabled():
+            self._program_in_trace = False
+        elif not self._program_in_trace and self.step_sections is not None:
+            self._program_in_trace = True
+            # names hold no ',', '=' or '#' (TraceMe's own separators)
+            with jax.profiler.TraceAnnotation(
+                    "train.program", program=self._step_program,
+                    **{k: " ".join(v) for k, v in self.step_sections.items()}):
+                pass
         with jax.profiler.TraceAnnotation("train.step", call=self._step_calls):
             return self._dispatch_step(state, batch)
 
@@ -428,8 +462,9 @@ class Trainer:
                 return out, extra
 
             (loss, new_extra), grads = jax.value_and_grad(wrapped, has_aux=True)(params)
-        updates, opt_state = self.tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("sec_optimizer"):  # clip, AdamW, the update
+            updates, opt_state = self.tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, step + 1, new_extra, loss
 
     def _accum_grads(self, params, extra, batch):

@@ -55,6 +55,7 @@ def main(ctx: JobContext) -> None:
         transformer_train_flops,
         transformer_train_flops_exact,
     )
+    from tf_operator_tpu.parallel.collectives import sections_summary
     from tf_operator_tpu.train.trainer import Trainer, TrainerConfig
 
     wl = ctx.workload
@@ -248,6 +249,11 @@ def main(ctx: JobContext) -> None:
         step_remats=trainer.step_remats,
         # how the compiled step was partitioned: its collectives by kind
         step_collectives=trainer.step_collectives,
+        # what a trace of it needs to name its ops: instructions by section
+        # (``none``: no ``sec_*`` scope reached them) and the parse's seconds
+        step_sections={
+            "instructions": sections_summary(trainer.step_sections),
+            "parse_s": round(trainer.step_sections_parse_s, 4)},
         step_s=step_s, losses=ckpt.loss_trace(),
         # how well the prefetch hid the input pipeline (None: no loader)
         loader=None if loader is None else {
