@@ -226,32 +226,53 @@ def test_flash_kernels_compile_at_latent_widths_for_v5e(one_chip, no_cache):
         assert sum(kernel in n for n in names) == 1, (kernel, names)
 
 
-@pytest.mark.slow  # 74 s alone and 350 CPU-seconds of compiler threads: tier-1's time limit has no room for it
-def test_joyai_share_step_fits_v5e_at_the_cells_batch(topo, no_cache):
-    """The JoyAI-LLM-Flash share cell's WHOLE training step — the config
-    file's workload through ``Trainer``, the traffic mix's rows a step —
-    compiled for the described chip: 10.9 GB of state beside two 8,192-token
-    rows is what the compiler accepted (it refused three), so a change that
-    breaks the fit fails here. The flash kernels run at every one of the six
-    blocks under their names: three instructions each (the dense lead, the
-    scanned body, the prediction module) — ``save_mid`` keeps the forward's
-    ``flash_o`` / ``flash_lse``, so no ``flash_fwd`` is replayed (six before
-    PR 33), and with them the temporaries are 10.21 GB beside 8.17 GB of
-    weights and moments."""
+# the two share cells' whole steps: config file, traffic mix, what the parent
+# of PR 36 compiled to (temporaries in bytes, the compiler's own `.remat`
+# clones) and the row counts of its lossless bound, nb·B and T·k
+SHARE_STEPS = {
+    "joyai": dict(
+        config="joyai-llm-flash-ep16share-train1.json",
+        mix="pretrain-8k-ep16share.json", flash=3,
+        parent_temp=10_210_817_024, parent_remats=3,
+        bound_rows=("135168,2048", "131072,2048")),
+    "smallthinker": dict(
+        config="smallthinker-21ba3b-ep4share-train1.json",
+        mix="pretrain-8k-ep4share.json", flash=4,
+        parent_temp=9_385_231_872, parent_remats=0,
+        bound_rows=("200704,2560", "196608,2560")),
+}
+
+
+@pytest.mark.slow  # 100 - 150 s each alone and 350+ CPU-seconds of compiler threads: tier-1's time limit has no room for them
+@pytest.mark.parametrize("cell", sorted(SHARE_STEPS))
+def test_share_cell_step_fits_v5e_and_holds_nothing_bound_sized(topo, no_cache, cell):
+    """A share cell's WHOLE training step — the config file's workload
+    through ``Trainer``, the traffic mix's rows a step — compiled for the
+    described chip. JoyAI: 10.9 GB of state beside two 8,192-token rows is
+    what the compiler accepted (it refused three), so a change that breaks
+    the fit fails here. The flash kernels run under their names once a layer
+    kind — ``save_mid`` keeps the forward's ``flash_o`` / ``flash_lse``, so no
+    ``flash_fwd`` is replayed (PR 33). The expert layer walks segments
+    (``parallel.moe._expert_walk``, PR 36): every ``gmm_*`` kernel is there by
+    name, NO instruction has the lossless bound's row count in its shape, the
+    compiler rebuilds nothing of its own accord where the parent's JoyAI
+    step held three ``.remat`` clones, and the temporaries are not above the
+    parent's."""
     import json
     import os
+    import re
     from unittest import mock
 
     from tf_operator_tpu.models import transformer as tr
     from tf_operator_tpu.parallel.mesh import build_mesh
     from tf_operator_tpu.train.trainer import Trainer, TrainerConfig
 
+    spec = SHARE_STEPS[cell]
     home = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmarks")
-    with open(os.path.join(home, "configs",
-                           "joyai-llm-flash-ep16share-train1.json")) as f:
+    with open(os.path.join(home, "configs", spec["config"])) as f:
         config = json.load(f)
-    with open(os.path.join(home, "traffic", "pretrain-8k-ep16share.json")) as f:
+    with open(os.path.join(home, "traffic", spec["mix"])) as f:
         mix = json.load(f)
     cfg = tr.preset_from_workload(config["workload"])
     opt = config["optimizer"]
@@ -269,16 +290,21 @@ def test_joyai_share_step_fits_v5e_at_the_cells_batch(topo, no_cache):
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         compiled = trainer.compile_step(jax.ShapeDtypeStruct(
             (int(mix["batch_size"]), int(mix["seq_len"])), "int32"))
-    names = _kernel_names_in(compiled.as_text())
-    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "gmm_"):
-        assert any(kernel in n for n in names), (kernel, sorted(set(names)))
-    assert {k: trainer.step_kernels[k] for k in
-            ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")} == {
-        "flash_fwd": 3, "flash_bwd_dq": 3, "flash_bwd_dkv": 3}
-    # weights + two moments of 680,439,808 parameters, in and out in place
+    text = compiled.as_text()
+    kernels = trainer.step_kernels
+    assert {k: kernels[k] for k in
+            ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")} == dict.fromkeys(
+        ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"), spec["flash"])
+    assert {"gmm_fwd", "gmm_fwd_scaled", "gmm_dx", "gmm_dw",
+            "gmm_dw_scaled"} <= set(kernels), kernels
+    for rows in spec["bound_rows"]:
+        assert not re.findall(r"\[" + rows + r"\]", text), rows
+    assert trainer.step_remats <= spec["parent_remats"]
+    # weights + two moments of every parameter, in and out in place
     held = compiled.memory_analysis()
     assert held.argument_size_in_bytes > 12 * cfg.n_params()
     assert held.alias_size_in_bytes > 12 * cfg.n_params()
+    assert held.temp_size_in_bytes <= spec["parent_temp"], held.temp_size_in_bytes
 
 
 def _compiled_lm_trainer(cfg, mesh_axes, devices, batch_shape):

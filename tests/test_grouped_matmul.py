@@ -76,6 +76,35 @@ def test_grads_match_dense_reference(scaled):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5, err_msg=name)
 
 
+@pytest.mark.parametrize("scaled", [False, True])
+def test_gmm_grads_keeps_dw_in_float32_and_sums_over_segments(scaled):
+    """``gmm_grads`` on bfloat16 operands: dw is the accumulator's float32
+    (the vjp's is that, rounded), and two SEGMENTS of a buffer summed in
+    float32 give the whole buffer's dw — what the expert walk relies on."""
+    from tf_operator_tpu.ops.grouped_matmul import gmm_grads
+
+    x, w, s = _mk()
+    x, w = x.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
+    dy = jax.random.normal(jax.random.PRNGKey(7), (64, 32)).astype(jnp.bfloat16)
+    be = jnp.array([0, 0, 1, -1, 2, 2, 0, 3], jnp.int32)
+    scale = s if scaled else None
+    w_t = jnp.swapaxes(w, 1, 2)
+    whole = gmm_grads(x, w_t, be, dy, row_scale=scale, block_rows=B, interpret=True)
+    assert whole[1].dtype == jnp.float32 and whole[0].dtype == jnp.bfloat16
+    args = (x, w) + ((s,) if scaled else ())
+    _, vjp = jax.vjp(lambda *a: gmm(a[0], a[1], be, row_scale=a[2] if scaled else None,
+                                    block_rows=B, interpret=True), *args)
+    cots = vjp(dy)
+    np.testing.assert_array_equal(np.asarray(cots[1]), np.asarray(whole[1].astype(w.dtype)))
+    np.testing.assert_array_equal(np.asarray(cots[0]), np.asarray(whole[0]))
+    halves = [gmm_grads(x[h], w_t, be[b], dy[h], block_rows=B, interpret=True,
+                        row_scale=None if scale is None else scale[h])
+              for h, b in ((slice(0, 32), slice(0, 4)), (slice(32, 64), slice(4, 8)))]
+    np.testing.assert_allclose(halves[0][1] + halves[1][1], whole[1], rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(
+        np.concatenate([np.asarray(h[0]) for h in halves]), np.asarray(whole[0]))
+
+
 def test_unvisited_expert_dw_is_exact_zero():
     """The regridded dw kernel zeroes every (expert, col-tile) output at
     walk step 0, so an expert no block maps to gets dw == 0 — not

@@ -21,7 +21,8 @@ CELL = "joyai-flash-train-ep16share"
 CONFIG = "joyai-llm-flash-ep16share-train1"
 NEW_METRICS = ("mfu_latent_routed", "flash_latent_roofline",
                "gmm_device_share.ep16share", "moe_block_padding_share.ep16share",
-               "moe_held_load_max_over_mean.ep16share")
+               "moe_held_load_max_over_mean.ep16share",
+               "moe_rows_walked_share.ep16share")
 # the published shape at toy widths: a dense lead + 1 expert layer, 4 heads
 # of 16 | 8 | 16 through ranks 32 / 16, top-2 of 8 router outputs, 2 held
 TINY = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=4,
@@ -169,6 +170,8 @@ def test_counters_bias_and_readers_of_a_real_run(ran, root):
         assert c["moe_held_load_mean"] * 2 == pytest.approx(c["moe_routed_here"])
         assert c["moe_routed_here"] <= layers * 2 * 32 * 2
         assert c["moe_all_load_max_over_mean"] >= 1.0
+        # at toy size the segment (1 + 2 blocks) is the whole bound: one trip
+        assert c["moe_rows_walked"] == c["moe_rows_bound"] == layers * 3 * 256
         # an entry moves by the rate a step, set-up's three warm-up steps included
         assert 0 < c["moe_bias_abs_max"] <= 0.001 * (len(counters) + 3) + 1e-6
         assert c["loss_main"] > 0 and c["loss_mtp"] > 0
@@ -186,6 +189,7 @@ def test_counters_bias_and_readers_of_a_real_run(ran, root):
     values = {m: read(m) for m in NEW_METRICS}
     assert 0 < values["moe_block_padding_share.ep16share"] < 100
     assert values["moe_held_load_max_over_mean.ep16share"] >= 1.0
+    assert values["moe_rows_walked_share.ep16share"] == 100.0
     for m in ("mfu_latent_routed", "flash_latent_roofline",
               "gmm_device_share.ep16share"):
         assert values[m] is None
@@ -214,7 +218,8 @@ def test_trace_readers_and_flop_counts_at_the_published_sizes(root):
     per_token = flops_joyai.train_flops_per_step(sizes, 2, 8192, routed) / (2 * 8192)
     assert 3.3e9 < per_token < 3.5e9
     counters = [{"moe_routed_here": routed, "moe_rows_computed": routed * 1.3,
-                 "moe_held_load_max": 600.0, "moe_held_load_mean": 512.0}] * 4
+                 "moe_held_load_max": 600.0, "moe_held_load_mean": 512.0,
+                 "moe_rows_walked": 5 * 12288.0, "moe_rows_bound": 5 * 135168.0}] * 4
     record = SimpleNamespace(
         samples={"model_sizes": sizes, "counters": counters, "elapsed_s": 8.0,
                  "traced": {"steps": 4, "counters": counters}},
@@ -226,6 +231,7 @@ def test_trace_readers_and_flop_counts_at_the_published_sizes(root):
               for m in NEW_METRICS}
     assert all(v is not None for v in values.values()), values
     assert values["gmm_device_share.ep16share"] == pytest.approx(10.0)
+    assert values["moe_rows_walked_share.ep16share"] == pytest.approx(100 * 12288 / 135168)
     f, b = flops_joyai.flash_latent_cost(sizes, 2, 8192)
     assert f / 197e12 > b / 819e9  # bound by flops at these shapes
     assert values["flash_latent_roofline"] == pytest.approx(100 * 4 * f / 197e12 / 0.4)

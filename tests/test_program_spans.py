@@ -393,10 +393,13 @@ def test_lm_workload_reports_the_routing_counters(caplog, tmp_path):
     assert 0 < report["step_sections"]["parse_s"] < 0.5
     moe = report["moe"]
     assert set(moe) == {"moe_routed_here", "moe_rows_computed",
-                        "moe_held_load_max", "moe_held_load_mean"}
+                        "moe_held_load_max", "moe_held_load_mean",
+                        "moe_rows_walked", "moe_rows_bound"}
     # 2 layers x 64 tokens x top-2, every expert held: nothing routed away
     assert moe["moe_routed_here"] == 2 * 64 * 2
     assert moe["moe_rows_computed"] % 256 == 0 and moe["moe_rows_computed"] >= 256
+    # every expert held: the segment is the whole lossless bound, in both layers
+    assert moe["moe_rows_walked"] == moe["moe_rows_bound"] == 2 * (1 + 4) * 256
     assert moe["moe_held_load_mean"] == 2 * 64 * 2 / 4
     assert ctx.reports[-1] == (3, moe)
     # a dense run has no such entry and reports nothing

@@ -193,6 +193,146 @@ def test_share_loses_no_choice_when_every_token_picks_the_held_experts():
     np.testing.assert_allclose(out, ref.expert_mix(h, gates[:, :6], part), atol=5e-5)
 
 
+# ---- (ii b) the segment walk ---------------------------------------------------
+
+WALK_T, WALK_E, WALK_K, WALK_B = 100, 8, 2, 8
+# load -> (first, held, what is added to the held experts' router scores)
+WALK_LOADS = {
+    "even": (2, 2, (0.0, 0.0)),
+    "every_choice_held": (2, 2, (100.0, 100.0)),   # all trips run: the lossless case
+    "no_choice_held": (2, 2, (-1e9, -1e9)),         # 0 trips
+    "one_held_expert_takes_all": (2, 2, (100.0, -1e9)),
+    "whole_layer": (0, WALK_E, (0.0,) * WALK_E),    # held == E: one segment, no loop
+}
+
+
+def _walk_gates(r, router):
+    """[T, E] gate weights, zero where an expert was not chosen: the oracle's
+    own router (k rounds of argmax; softmax over the chosen, or sigmoid
+    scores chosen by score + bias, over their sum, times the scale)."""
+    score, bias, scale = router
+    s = jax.nn.sigmoid(r) if score == "sigmoid" else r
+    left, chosen = s + (0.0 if bias is None else bias), jnp.zeros(r.shape, bool)
+    for _ in range(WALK_K):
+        pick = jax.nn.one_hot(jnp.argmax(left, axis=-1), r.shape[-1], dtype=bool)
+        chosen, left = chosen | pick, jnp.where(pick, -jnp.inf, left)
+    if score == "sigmoid":
+        return jnp.where(chosen, s, 0.0) / jnp.sum(
+            jnp.where(chosen, s, 0.0), axis=-1, keepdims=True) * scale, chosen
+    return jax.nn.softmax(jnp.where(chosen, r, -jnp.inf), axis=-1), chosen
+
+
+def _walk_oracle_layer(h, w_router, part, steer, first, router):
+    """Every held expert densely over every token, weighted by its gate."""
+    gates, chosen = _walk_gates(h @ w_router + steer, router)
+    out = jnp.zeros_like(h)
+    for e in range(part["w_gate"].shape[0]):
+        z = jax.nn.relu(h @ part["w_gate"][e]) * (h @ part["w_up"][e])
+        out = out + gates[:, first + e, None] * (z @ part["w_down"][e])
+    return out, chosen
+
+
+def _walk_layer(h, w_router, part, steer, first, router):
+    from tf_operator_tpu.parallel.moe import moe_apply
+
+    score, bias, scale = router
+    return moe_apply(h, h @ w_router + steer, part, None, None, k_top=WALK_K,
+                     dropped="zero", return_stats=True, dispatch_impl="gmm",
+                     expert_act="relu", expert_first=first, score=score,
+                     bias=bias, scale=scale)
+
+
+def _as_the_trainer_runs_it(layer):
+    """Two residual layers under ``lax.scan``, each under ``jax.checkpoint``
+    with the ``save_mid`` tier's policy."""
+    from tf_operator_tpu.models.transformer import remat_save_names
+
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *remat_save_names("save_mid"))
+
+    def run(h, w_router, part, *rest):
+        def body(h, _):
+            out, aux = layer(h, w_router, part, *rest)
+            return h + 0.1 * out, aux
+
+        h, aux = jax.lax.scan(jax.checkpoint(body, policy=policy), h, None, length=2)
+        return h, jax.tree_util.tree_map(lambda a: a[0], aux)
+    return run
+
+
+WALK_CASES = [(load, "softmax", wrap) for load in WALK_LOADS
+              for wrap in ("alone", "scan_remat")] + [
+    ("even", "sigmoid", "alone"), ("even", "sigmoid", "scan_remat")]
+
+
+@pytest.mark.parametrize("load,score,wrap", WALK_CASES)
+def test_the_walk_equals_the_dense_oracle_in_output_and_every_gradient(
+        load, score, wrap, monkeypatch):
+    """The expert layer as a walk over segments (``_expert_walk``; one
+    segment under plain autodiff where the share is the whole layer): output
+    and the gradients of x, the router weights (through the gate weights)
+    and the three expert stacks against every held expert computed densely,
+    at five loads, alone and as the trainer runs it; and the two counters
+    that say how far it walked."""
+    from conftest import jit_value_and_grad
+
+    monkeypatch.setenv("TPUJOB_GMM_BLOCK_ROWS", str(WALK_B))
+    first, held, add = WALK_LOADS[load]
+    h, _, lw = _layer_inputs(t=WALK_T, n=WALK_E, seed=3)
+    w_router = jax.random.normal(jax.random.PRNGKey(4), (64, WALK_E)) * 0.3
+    part = {k: v[first:first + held] for k, v in lw.items()}
+    steer = jnp.zeros((WALK_E,)).at[first:first + held].set(jnp.asarray(add))
+    router = (score, jnp.linspace(-0.2, 0.2, WALK_E), 2.5) if score == "sigmoid" \
+        else ("softmax", None, 1.0)
+    weigh = jax.random.normal(jax.random.PRNGKey(5), h.shape)
+
+    def loss_of(layer):
+        fn = _as_the_trainer_runs_it(layer) if wrap == "scan_remat" else layer
+
+        def loss(h, w_router, part):
+            out, aux = fn(h, w_router, part, steer, first, router)
+            return jnp.sum(out * weigh), (out, aux)
+        return loss
+
+    walk = loss_of(_walk_layer)
+    (_, (out, stats)), grads = jit_value_and_grad(
+        walk, h, w_router, part, argnums=(0, 1, 2), has_aux=True)
+    (_, (want, chosen)), want_grads = jit_value_and_grad(
+        loss_of(_walk_oracle_layer), h, w_router, part, argnums=(0, 1, 2),
+        has_aux=True)
+    np.testing.assert_allclose(out, want, atol=3e-5, rtol=1e-5)
+    for got, ref_g in zip(jax.tree_util.tree_leaves(grads),
+                          jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(got, ref_g, atol=2e-4, rtol=1e-4)
+
+    # how far it walked (the first layer's routing, in both wraps)
+    counts = np.asarray(chosen).sum(0)[first:first + held]
+    occupied = int(np.sum(-(-counts // WALK_B)))
+    tk = WALK_T * WALK_K
+    nb = -(-tk // WALK_B) + held
+    seg_blocks = -(-(tk * held) // (WALK_E * WALK_B)) + held
+    assert float(stats["routed_here"]) == counts.sum()
+    assert float(stats["rows_computed"]) == occupied * WALK_B
+    assert float(stats["rows_bound"]) == nb * WALK_B
+    assert float(stats["rows_walked"]) == -(-occupied // seg_blocks) * seg_blocks * WALK_B
+    text = str(jax.make_jaxpr(lambda *a: _walk_layer(*a, steer, first, router))(
+        h, w_router, part))
+    if load == "whole_layer":
+        assert seg_blocks == nb and "while" not in text
+    else:
+        assert "while" in text and -(-nb // seg_blocks) == 3
+    if load == "every_choice_held":  # no choice drops: every trip the bound allows
+        assert occupied == 26 and float(stats["rows_walked"]) == 3 * seg_blocks * WALK_B
+    if load == "no_choice_held":  # 0 trips: zeros out, exact-zero gradients
+        assert float(stats["rows_walked"]) == 0.0
+        g_h, g_router, g_part = grads
+        for k, g in g_part.items():
+            assert not np.any(np.asarray(g)), k
+        if wrap == "alone":  # under the scan the residual stream passes through
+            assert not np.any(np.asarray(out)) and not np.any(np.asarray(g_h))
+            assert not np.any(np.asarray(g_router))
+
+
 # ---- (iii) windowed flash attention ------------------------------------------
 
 

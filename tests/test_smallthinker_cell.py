@@ -20,7 +20,7 @@ CELL = "smallthinker21b-train-ep4share"
 CONFIG = "smallthinker-21ba3b-ep4share-train1"
 NEW_METRICS = ("mfu_routed", "flash_window_roofline", "gmm_roofline",
                "gmm_device_share", "moe_block_padding_share",
-               "moe_held_load_max_over_mean")
+               "moe_held_load_max_over_mean", "moe_rows_walked_share")
 # the published shape at toy widths: 4 layers = one period, head width its
 # own number (4 x 32 != 64), top-6 of 8 router outputs, 2 experts held
 TINY = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
@@ -152,6 +152,10 @@ def test_counters_and_readers_of_a_real_run(ran, root):
         assert c["moe_held_load_max"] >= c["moe_held_load_mean"] > 0
         assert c["moe_held_load_mean"] * 2 == pytest.approx(c["moe_routed_here"])
         assert c["moe_routed_here"] <= layers * 2 * 48 * 6
+        # segments of 1 + 2 blocks under a bound of 3 + 2 a layer: one or two trips
+        assert c["moe_rows_bound"] == layers * 5 * 256
+        assert c["moe_rows_computed"] <= c["moe_rows_walked"] <= layers * 2 * 3 * 256
+        assert c["moe_rows_walked"] % (3 * 256) == 0
     # readers: program counters give numbers on any device; device-trace
     # readers give None without a trace, mfu_routed None without peaks
     cell = run.load_cell(root, CELL)
@@ -167,6 +171,7 @@ def test_counters_and_readers_of_a_real_run(ran, root):
     values = {m: read(m) for m in NEW_METRICS}
     assert 0 < values["moe_block_padding_share"] < 100
     assert values["moe_held_load_max_over_mean"] >= 1.0
+    assert 60.0 <= values["moe_rows_walked_share"] <= 120.0
     for m in ("mfu_routed", "flash_window_roofline", "gmm_roofline",
               "gmm_device_share"):
         assert values[m] is None
@@ -211,7 +216,8 @@ def test_trace_readers_match_kernels_by_instruction_name(root):
                  head_dim=128, d_ff=768, n_experts=64, top_k=6, held=16,
                  pattern=pattern)
     counters = [{"moe_routed_here": 98304.0, "moe_rows_computed": 114688.0,
-                 "moe_held_load_max": 6500.0, "moe_held_load_mean": 6144.0}] * 4
+                 "moe_held_load_max": 6500.0, "moe_held_load_mean": 6144.0,
+                 "moe_rows_walked": 6 * 53248.0, "moe_rows_bound": 4 * 200704.0}] * 4
     record = SimpleNamespace(
         samples={"model_sizes": sizes, "counters": counters, "elapsed_s": 2.0,
                  "traced": {"steps": 4, "counters": counters}},
@@ -223,6 +229,14 @@ def test_trace_readers_match_kernels_by_instruction_name(root):
     assert all(v is not None for v in values.values()), values
     assert values["gmm_device_share"] == pytest.approx(20.0)
     assert values["moe_block_padding_share"] == pytest.approx(100 * (1 - 98304 / 114688))
+    assert values["moe_rows_walked_share"] == pytest.approx(100 * 6 * 53248 / (4 * 200704))
+    # a program from before the walk has no such counter: the line leaves it out
+    record.samples["counters"] = [
+        {k: v for k, v in c.items() if not k.startswith("moe_rows_w")} for c in counters]
+    assert run._load_py(run.reader_path(home, "moe_rows_walked_share"),
+                        "t_walked").read(record) is None
+    assert run.reader_path(home, "moe_rows_walked_share.ep16share") == run.reader_path(
+        home, "moe_rows_walked_share")
     f, _ = flops_smallthinker.flash_window_cost(sizes, 2, 8192)
     assert values["flash_window_roofline"] == pytest.approx(100 * 4 * f / 197e12 / 0.4)
     for m in ("mfu_routed", "flash_window_roofline", "gmm_roofline"):

@@ -40,8 +40,11 @@ WORKLOADS = {
 EVERY = {"optimizer.optimizer", "embed.fwd", "embed.bwd", "head_ce.fwd",
          "head_ce.bwd", "attn_proj.fwd", "attn_proj.bwd", "attn_core.fwd",
          "attn_core.bwd", "mlp.fwd", "mlp.bwd", "stack.fwd", "stack.bwd"}
+# no "moe_experts.replay": the expert walk's residuals are its inputs, its
+# backward computes gate and up again itself (``bwd``), and the forward walk
+# that jax replays under ``save_mid`` is dead code (PR 36)
 MOE = {"router.fwd", "moe_dispatch.fwd", "moe_dispatch.bwd", "moe_dispatch.replay",
-       "moe_experts.fwd", "moe_experts.bwd", "moe_experts.replay"}
+       "moe_experts.fwd", "moe_experts.bwd"}
 EXPECTED = {
     "dense-full": EVERY | {"mlp.replay", "attn_proj.replay", "attn_core.replay"},
     "dense-none": EVERY,
@@ -73,7 +76,8 @@ def _trainer(name):
         logical_axes=tf.transformer_logical_axes(cfg),
         config=TrainerConfig(optimizer="adamw", learning_rate=1e-3))
     assert trainer.step_sections is None and trainer.step_sections_parse_s is None
-    trainer.compile_step(jax.ShapeDtypeStruct((2, 64), "int32"))
+    trainer.text = trainer.compile_step(
+        jax.ShapeDtypeStruct((2, 64), "int32")).as_text()
     return trainer
 
 
@@ -119,6 +123,42 @@ def test_no_instruction_is_in_two_keys_and_few_are_unnamed(compiled):
     counts = sections_summary(trainer.step_sections)
     assert sum(counts.values()) == len(names)
     assert counts.get("none", 0) / len(names) < NONE_SHARE_LIMIT, counts
+
+
+def test_the_expert_walks_backward_is_filed_as_backward_and_named(compiled):
+    """The expert walk brings its own backward (``parallel.moe._expert_walk``:
+    a loop inside the layer's): its ops — the segment's gathers and
+    scatter-adds, the kernels and what lies between them — file under
+    ``moe_dispatch.bwd`` / ``moe_experts.bwd``, nothing of either walk is
+    left without a section, and no expert op is replayed."""
+    import re
+
+    from tf_operator_tpu.parallel.collectives import _computations
+
+    name, trainer = compiled
+    if name != "gmm-moe":  # latent-mtp's toy share is one segment: no loop
+        pytest.skip("no expert walk")
+    key_of = {n: k for k, v in trainer.step_sections.items() for n in v}
+    comps, _ = _computations(trainer.text)
+    walk = re.compile(r'op_name="[^"]*/while/body/sec_moe_(dispatch|experts)/')
+    bodies = {c: lines for c, lines in comps.items()
+              if any(walk.search(line) for line in lines)}
+    assert bodies
+    keys, backward = set(), set()
+    for lines in bodies.values():
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line)
+            if not m or m.group(1) not in key_of:
+                continue
+            key = key_of[m.group(1)]
+            keys.add(key)
+            w = walk.search(line)
+            if w and "transpose(" in line:
+                backward.add((w.group(1), key))
+    assert not [k for k in keys if k.startswith("none.")], sorted(keys)
+    assert backward == {("dispatch", "moe_dispatch.bwd"),
+                        ("experts", "moe_experts.bwd")}, backward
+    assert "moe_experts.replay" not in trainer.step_sections
 
 
 def test_the_scopes_change_metadata_only(compiled, monkeypatch):
@@ -420,5 +460,5 @@ def test_the_nine_entries_are_declared_and_read_by_files_of_their_own():
         moe = name == "step_moe_dispatch_ms"
         assert sorted(m["workloads"]) == sorted(
             [c for c in train if "share" in c] if moe else train)
-    assert [m["name"] for m in bench["per_layer"]][-9:] == list(READERS) + [
+    assert [m["name"] for m in bench["per_layer"]][-11:-2] == list(READERS) + [
         "step_unattributed_share"]

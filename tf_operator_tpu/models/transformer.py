@@ -920,9 +920,9 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
         return x + down, None
 
 
-# the routing counters of one step (parallel.moe._moe_single_gmm's stats),
-# summed over layers on their way out of the model as ``moe_<name>``
-MOE_COUNTERS = ("routed_here", "rows_computed", "held_load_max", "held_load_mean")
+# one step's routing counters (_moe_single_gmm's stats), summed over layers as ``moe_<name>``
+MOE_COUNTERS = ("routed_here", "rows_computed", "held_load_max", "held_load_mean",
+                "rows_walked", "rows_bound")
 
 
 def _router_logits(h, layer_params, cfg: TransformerConfig):

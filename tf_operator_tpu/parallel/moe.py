@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -187,6 +187,180 @@ def expert_activation(name: str):
     raise ValueError(f"unknown expert activation {name!r} (silu | relu)")
 
 
+def _segment_blocks(tk: int, held: int, n_experts: int, block_rows: int) -> int:
+    """Row-blocks a segment of the expert walk: the rows this share of the
+    experts gets under EVEN routing plus a round-up block an expert — from
+    the shapes a call observes, no setting. held == n_experts gives the
+    lossless bound itself (one segment)."""
+    return -(-(tk * held) // (n_experts * block_rows)) + held
+
+
+class _Walk(NamedTuple):
+    """What is static about one expert walk (hashable: the op's
+    non-differentiated argument)."""
+    k_top: int
+    block_rows: int
+    seg_blocks: int
+    act: Callable
+    interpret: bool
+
+    @property
+    def kernel(self) -> dict:
+        return dict(block_rows=self.block_rows, interpret=self.interpret)
+
+
+class _Routing(NamedTuple):
+    """The tk-sized bookkeeping of one call, held choices sorted first:
+    ``order`` [T·k] (sorted position -> flattened choice), per held expert
+    its ``counts``, its ``offsets`` among the sorted choices, the running
+    sum of its blocks ``bounds`` and its first row ``pad_start`` in the
+    padded buffer."""
+    order: jax.Array
+    counts: jax.Array
+    offsets: jax.Array
+    bounds: jax.Array
+    pad_start: jax.Array
+
+
+def _segment_inputs(j, x, top_p, idx: _Routing, walk: _Walk):
+    """Segment ``j`` of the padded buffer (``walk.seg_blocks`` row-blocks
+    from block ``j·seg_blocks``) as (block_expert, valid, src_choice, tok,
+    x_seg, s_pad): its block→expert map and, per slot, whether a routed
+    choice sits there, that choice's index into the flattened [T·k] choices,
+    its token, the token's row and its gate weight. Blocks behind the last
+    occupied one (and behind the lossless bound) are SENTINELS (-1);
+    sentinel and round-up slots read token 0 with gate weight 0. The
+    experts' tables are read once a BLOCK; a slot adds its row within the
+    block. Dispatch is a row GATHER of the segment's slots, and each slot's
+    gate weight rides the down-projection kernel as a row scale (the fused
+    combine epilogue, r6): garbage slots scale by 0."""
+    held, tk = idx.counts.shape[0], idx.order.shape[0]
+    B, S = walk.block_rows, walk.seg_blocks
+    block = j * S + jnp.arange(S, dtype=jnp.int32)
+    owner = jnp.sum(block[:, None] >= idx.bounds, axis=1).astype(jnp.int32)
+    block_expert = jnp.where(owner < held, owner, -1)
+    e_b = jnp.maximum(block_expert, 0)
+    # [S, B]: a slot's rank among its expert's sorted choices
+    rank = (block * B - idx.pad_start[e_b])[:, None] + jnp.arange(B, dtype=jnp.int32)
+    valid = (rank < jnp.where(block_expert >= 0, idx.counts[e_b], 0)[:, None]).reshape(-1)
+    src_choice = idx.order[
+        jnp.clip(idx.offsets[e_b][:, None] + rank, 0, tk - 1).reshape(-1)]
+    tok = jnp.where(valid, src_choice // walk.k_top, 0)
+    x_seg = x[tok]  # [seg_blocks·B, d]
+    s_pad = jnp.where(valid, top_p.reshape(-1)[src_choice], 0.0)
+    return block_expert, valid, src_choice, tok, x_seg, s_pad
+
+
+def _segment_forward(j, out, x, top_p, weights, idx, walk):
+    """``out`` [T, d] float32 plus segment j's experts' outputs: the combine
+    is a scatter-add from the slots (``h`` is pre-weighted), so the sum over
+    a token's choices runs in float32."""
+    from tf_operator_tpu.ops.grouped_matmul import gmm
+
+    run = partial(gmm, **walk.kernel)
+    w_gate, w_up, w_down = weights
+    with jax.named_scope("sec_moe_dispatch"):
+        block_expert, valid, _, tok, x_seg, s_pad = _segment_inputs(
+            j, x, top_p, idx, walk)
+    with jax.named_scope("sec_moe_experts"):
+        zg = run(x_seg, w_gate, block_expert)
+        zu = run(x_seg, w_up, block_expert)
+        h = run(walk.act(zg) * zu, w_down, block_expert, row_scale=s_pad)
+    with jax.named_scope("sec_moe_dispatch"):
+        return out.at[tok].add(jnp.where(valid[:, None], h, 0).astype(jnp.float32))
+
+
+def _walk_trips(idx, walk):
+    """Segments that hold an occupied block: the sort puts held choices
+    first, so everything behind ``bounds[-1]`` blocks is sentinel."""
+    return -(-idx.bounds[-1] // walk.seg_blocks)
+
+
+def _cast_weights(weights, dtype):
+    with jax.named_scope("sec_moe_experts"):
+        return tuple(w.astype(dtype) for w in weights)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _expert_walk(walk, x, top_p, weights, idx):
+    """Σ over the held experts' routed choices of gate weight × expert(x),
+    [T, d] in ``x.dtype``, as a WALK over fixed-size segments of the padded
+    buffer whose trip count is read on the device (_walk_trips): the cost
+    follows the rows routed here, and the last possible trip ends at the
+    lossless bound. Reverse mode cannot see through a dynamic trip count,
+    so the op brings its own backward — the same walk."""
+    tokens, d = x.shape
+    weights = _cast_weights(weights, x.dtype)
+    with jax.named_scope("sec_moe_dispatch"):  # the loop's own plumbing too
+        out = jax.lax.fori_loop(
+            0, _walk_trips(idx, walk),
+            lambda j, out: _segment_forward(j, out, x, top_p, weights, idx, walk),
+            jnp.zeros((tokens, d), jnp.float32))
+        return out.astype(x.dtype)
+
+
+def _expert_walk_fwd(walk, x, top_p, weights, idx):
+    # residuals are the op's INPUTS: nothing segment- or bound-sized is kept,
+    # and under a remat policy the replayed forward walk is dead code
+    return _expert_walk(walk, x, top_p, weights, idx), (x, top_p, weights, idx)
+
+
+def _expert_walk_bwd(walk, res, g):
+    """Per segment: gather its rows and its rows of ``g`` again, gate and up
+    again (what ``save_mid`` replays anyway), the kernels' cotangents
+    (grouped_matmul.gmm_grads), then ``dx`` and ``d top_p`` scatter-added
+    and the three weight gradients summed on float32 carries — rounded
+    once, after the last segment."""
+    from tf_operator_tpu.ops.grouped_matmul import gmm, gmm_grads
+
+    x, top_p, weights, idx = res
+    kernel = walk.kernel
+    w_gate, w_up, w_down = _cast_weights(weights, x.dtype)
+    with jax.named_scope("sec_moe_experts"):  # transposed once, not a segment
+        t_gate, t_up, t_down = (
+            jnp.swapaxes(w, 1, 2) for w in (w_gate, w_up, w_down))
+
+    def segment(j, carry):
+        dx, d_top_p, dw_gate, dw_up, dw_down = carry
+        with jax.named_scope("sec_moe_dispatch"):
+            block_expert, valid, src_choice, tok, x_seg, s_pad = _segment_inputs(
+                j, x, top_p, idx, walk)
+            # a sentinel or round-up slot reads row 0 of ``g``: its gate weight
+            # is 0, so every cotangent it feeds but ``ds`` is an exact zero
+            g_h = g[tok]
+        with jax.named_scope("sec_moe_experts"):
+            zg = gmm(x_seg, w_gate, block_expert, **kernel)
+            zu = gmm(x_seg, w_up, block_expert, **kernel)
+            a, act_vjp = jax.vjp(lambda zg, zu: walk.act(zg) * zu, zg, zu)
+            da, dw_down_j, ds = gmm_grads(
+                a, t_down, block_expert, g_h, row_scale=s_pad, **kernel)
+            dzg, dzu = act_vjp(da)
+            dx_g, dw_gate_j = gmm_grads(x_seg, t_gate, block_expert, dzg, **kernel)
+            dx_u, dw_up_j = gmm_grads(x_seg, t_up, block_expert, dzu, **kernel)
+            dw_gate, dw_up, dw_down = (
+                dw_gate + dw_gate_j, dw_up + dw_up_j, dw_down + dw_down_j)
+        with jax.named_scope("sec_moe_dispatch"):
+            dx_seg = dx_g.astype(jnp.float32) + dx_u.astype(jnp.float32)
+            dx = dx.at[tok].add(jnp.where(valid[:, None], dx_seg, 0))
+            d_top_p = d_top_p.at[src_choice].add(jnp.where(valid, ds, 0))
+        return dx, d_top_p, dw_gate, dw_up, dw_down
+
+    with jax.named_scope("sec_moe_dispatch"):
+        carry = (jnp.zeros(x.shape, jnp.float32),
+                 jnp.zeros((top_p.size,), jnp.float32),
+                 *(jnp.zeros(w.shape, jnp.float32) for w in weights))
+        dx, d_top_p, *dw = jax.lax.fori_loop(
+            0, _walk_trips(idx, walk), segment, carry)
+        dx = dx.astype(x.dtype)
+        d_top_p = d_top_p.reshape(top_p.shape).astype(top_p.dtype)
+    with jax.named_scope("sec_moe_experts"):
+        dw = tuple(g_w.astype(w.dtype) for g_w, w in zip(dw, weights))
+    return dx, d_top_p, dw, None
+
+
+_expert_walk.defvjp(_expert_walk_fwd, _expert_walk_bwd)
+
+
 def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
                     block_rows: int = 256, act=jax.nn.silu, first: int = 0,
                     score: str = "softmax", bias=None, scale: float = 1.0):
@@ -204,14 +378,21 @@ def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
     absent chips (the destination-0 segment of _moe_local_gmm without
     its exchanges).
 
-    Held choices are sorted by expert and each expert's rows padded only
-    to the ROW-BLOCK quantum; a scalar-prefetched block→expert map steers
-    every block's weight-tile load. The buffer has the lossless bound
-    ceil(T·k/B) + held blocks — every choice could land here — and what
-    the routing leaves unoccupied is SENTINEL blocks (-1: zeros written,
-    no MXU work), so no choice of a held expert ever drops, at any load.
-    Dispatch is a row GATHER (no scatter-add inbox). ragged_dot was
-    measured at ~19 TFLOP/s on the same shapes (full-height
+    Held choices are sorted by expert (first; the rest behind them) and
+    each expert's rows padded only to the ROW-BLOCK quantum; a
+    scalar-prefetched block→expert map steers every block's weight-tile
+    load. The padded buffer has the lossless bound nb = ceil(T·k/B) + held
+    blocks — every choice could land here, so no choice of a held expert
+    ever drops, at any load — but it is never built: the experts run as a
+    WALK over segments of ``seg_blocks`` = ceil(T·k·held / (E·B)) + held
+    blocks (the rows this share gets under even routing plus a round-up
+    block an expert), ceil(occupied blocks / seg_blocks) of them, counted
+    on the device (_expert_walk). A segment is a row GATHER of its slots,
+    the three grouped matmuls, and a float32 scatter-add of its weighted
+    rows onto the [T, d] result; only a segment's tail can be sentinel
+    blocks (-1: zeros written, no MXU work). held == E makes the segment
+    the whole buffer: one segment under plain autodiff, no loop. ragged_dot
+    was measured at ~19 TFLOP/s on the same shapes (full-height
     masked-matmul lowering) — the kernel exists because the XLA-level
     formulations all lose; see grouped_matmul.py.
 
@@ -225,7 +406,10 @@ def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
     observability: ``expert_count`` (choices per router output, all E:
     what a bias update reads), ``routed_here`` (choices routed to held experts),
     ``rows_computed`` (occupied blocks × B: what the kernels multiply),
-    ``held_load_max`` / ``held_load_mean`` (choices per held expert)."""
+    ``rows_walked`` (segments walked × seg_blocks × B: what the gathers and
+    scatter-adds move) beside ``rows_bound`` (nb × B: what they moved before
+    the walk), ``held_load_max`` / ``held_load_mean`` (choices per held
+    expert)."""
     tokens, d = x.shape
     n_experts = gate_logits.shape[-1]
     held = expert_params["w_gate"].shape[0]
@@ -247,18 +431,16 @@ def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
     tk = tokens * k_top
     B = block_rows
     nb = -(-tk // B) + held  # static lossless bound incl. per-expert pad
+    walk = _Walk(k_top, B, _segment_blocks(tk, held, n_experts, B), act,
+                 jax.default_backend() != "tpu")
     with jax.named_scope("sec_moe_dispatch"):
         chosen = top_i.reshape(-1).astype(jnp.int32)  # [T*k], t-major
         local = chosen - first
         here = (local >= 0) & (local < held)
         flat_e = jnp.where(here, local, held)  # not held: a spare bucket, sorted last
         order = jnp.argsort(flat_e, stable=True)
-        bucket = jnp.bincount(flat_e, length=held + 1).astype(jnp.int32)
-        counts = bucket[:held]
-        bucket_start = jnp.cumsum(bucket) - bucket
-        offsets = bucket_start[:held]  # unpadded sorted offsets
-        rank_sorted = jnp.arange(tk, dtype=jnp.int32) - bucket_start[flat_e[order]]
-        ranks = jnp.zeros((tk,), jnp.int32).at[order].set(rank_sorted)
+        counts = jnp.bincount(flat_e, length=held + 1).astype(jnp.int32)[:held]
+        offsets = jnp.cumsum(counts) - counts  # unpadded sorted offsets
 
         # an expert with no routed row owns no block: the dw kernel zeroes
         # every (expert, col-tile) at its walk's first step, so its gradient
@@ -266,47 +448,19 @@ def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
         blocks_per_e = -(-counts // B)
         bounds = jnp.cumsum(blocks_per_e)  # [held]
         pad_start = (bounds - blocks_per_e) * B
-        owner = jnp.searchsorted(
-            bounds, jnp.arange(nb, dtype=jnp.int32), side="right"
-        ).astype(jnp.int32)
-        block_expert = jnp.where(owner < held, owner, -1)
-        # padded slot s -> source token (sentinel and round-up slots read row
-        # 0 with gate weight 0; their outputs are never gathered back and
-        # their cotangents are zero)
-        s = jnp.arange(nb * B, dtype=jnp.int32)
-        owner_s = block_expert[s // B]
-        e_s = jnp.maximum(owner_s, 0)
-        rank_s = s - pad_start[e_s]
-        valid = (owner_s >= 0) & (rank_s < counts[e_s])
-        src_choice = order[jnp.clip(offsets[e_s] + rank_s, 0, tk - 1)]
-        x_pad = x[jnp.where(valid, src_choice // k_top, 0)]  # [nb*B, d]
+        idx = _Routing(order, counts, offsets, bounds, pad_start)
 
-    from tf_operator_tpu.ops.grouped_matmul import gmm
-
-    interpret = jax.default_backend() != "tpu"
-    run = partial(gmm, block_rows=B, interpret=interpret)
-    with jax.named_scope("sec_moe_experts"):
-        zg = run(x_pad, expert_params["w_gate"].astype(x.dtype), block_expert)
-        zu = run(x_pad, expert_params["w_up"].astype(x.dtype), block_expert)
-    # fused combine epilogue (r6): each padded slot's gate weight rides
-    # the down-projection kernel as a row scale, so the combine below is
-    # a pure gather+sum — the separate f32 [T,k,d] weighted-reduction
-    # einsum (and its HBM pass) is gone. Garbage slots scale by 0.
-    with jax.named_scope("sec_moe_dispatch"):
-        s_pad = jnp.where(valid, top_p.reshape(-1)[src_choice], 0.0)
-    with jax.named_scope("sec_moe_experts"):
-        h = run(act(zg) * zu,
-                expert_params["w_down"].astype(x.dtype), block_expert,
-                row_scale=s_pad)
-
-    # every held choice's padded slot; a choice held elsewhere adds nothing
-    with jax.named_scope("sec_moe_dispatch"):
-        dst = jnp.where(here, pad_start[jnp.clip(local, 0, held - 1)] + ranks, 0)
-        gathered = h[dst.reshape(tokens, k_top)]  # [T, k, d] — pre-weighted
-        out = jnp.sum(
-            jnp.where(here.reshape(tokens, k_top, 1), gathered, 0).astype(jnp.float32),
-            axis=1,
-        )
+    weights = tuple(expert_params[k] for k in ("w_gate", "w_up", "w_down"))
+    if walk.seg_blocks == nb:  # held == E: the segment is the whole buffer
+        with jax.named_scope("sec_moe_dispatch"):
+            out = jnp.zeros((tokens, d), jnp.float32)
+        out = _segment_forward(
+            0, out, x, top_p, _cast_weights(weights, x.dtype), idx, walk
+        ).astype(x.dtype)
+        trips = jnp.int32(1)
+    else:
+        out = _expert_walk(walk, x, top_p, weights, idx)
+        trips = _walk_trips(idx, walk)
     with jax.named_scope("sec_router"):
         held_counts = counts.astype(jnp.float32)
         all_counts = jnp.bincount(chosen, length=n_experts)
@@ -317,10 +471,12 @@ def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
             "drop_frac": jnp.float32(0.0),
             "routed_here": jnp.sum(held_counts),
             "rows_computed": (bounds[-1] * B).astype(jnp.float32),
+            "rows_walked": (trips * (walk.seg_blocks * B)).astype(jnp.float32),
+            "rows_bound": jnp.float32(nb * B),
             "held_load_max": jnp.max(held_counts),
             "held_load_mean": jnp.mean(held_counts),
         }
-    return out.astype(x.dtype), stats
+    return out, stats
 
 
 def _moe_local_gmm(x, gate_logits, expert_params, axis_name: str,
@@ -401,7 +557,7 @@ def _moe_local_gmm(x, gate_logits, expert_params, axis_name: str,
     # symmetric, so the send layout IS the combine layout)
 
     # fill the send buffer by row GATHER (the cheap direction on TPU —
-    # same rationale as _moe_single_gmm's x_pad)
+    # same rationale as _moe_single_gmm's x_seg)
     r = jnp.arange(n_shards * s_cap, dtype=jnp.int32)
     seg, u = r // s_cap, r % s_cap
     le_r = jnp.sum(u[:, None] >= bounds_rows[seg], axis=1).astype(jnp.int32)
