@@ -516,6 +516,123 @@ def test_engine_compile_counts_no_pool_copies_at_tiny(page):
     assert report["prefill_attn_grid_steps"] == 0
 
 
+# ---- the hybrid engine: two kinds of sequence state, both where they lie ----
+
+HYBRID1 = dict(page_size=64, pool_pages=1024, max_slots=16, prefill_chunk=256)
+
+
+@pytest.fixture(scope="module")
+def hybrid_engine(one_chip):
+    """``ServeEngine`` for preset ``olmo-hybrid-7b`` at the benchmark's
+    -serve1 shapes (published widths, the whole vocabulary, pool ``[full
+    layers, 1025, 30, 64, 128]``, state store ``[linear layers, 17, 30, 96,
+    192]``, 64 page slots a sequence, 256-token chunks) with ONE period (3
+    linear layers + 1 full) of the cell's two, from abstract parameters,
+    compiled for the described chip by the engine's own ``compile()``."""
+    from unittest import mock
+
+    from tf_operator_tpu.models.transformer import init_transformer, preset
+    from tf_operator_tpu.serve.engine import ServeConfig, ServeEngine
+
+    cfg = preset("olmo-hybrid-7b", n_layers=4, max_seq=4096)
+    shapes = jax.eval_shape(
+        lambda: init_transformer(jax.random.PRNGKey(0), cfg))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes)
+    engine = ServeEngine(cfg, params, ServeConfig(**HYBRID1))
+    with _cache_off(), mock.patch.object(jax, "default_backend",
+                                         lambda: "tpu"):
+        report = engine.compile()
+    return engine, report
+
+
+@pytest.mark.parametrize("program,kernel", [("decode", "gdn_step"),
+                                            ("prefill", "gdn_chunk_fwd")])
+def test_hybrid_engine_programs_keep_both_stores_in_place_on_v5e(
+        hybrid_engine, program, kernel):
+    """A linear layer's kernel a layer beside the paged kernel of the full
+    one, and neither the page pool nor the recurrent-state store is copied,
+    sliced by layer or relaid between parameter and result: the decode step
+    updates the store through the kernel's aliased operand, a chunk reads
+    and writes its one slot."""
+    from tf_operator_tpu.serve.engine import pool_copies
+
+    engine, report = hybrid_engine
+    assert engine._pool_shape() == (1, 1025, 30, 64, 128)
+    assert engine.store.state_shape == (3, 17, 30, 96, 192)
+    assert engine.store.conv_shape == (3, 17, 3, 11520)
+    text = getattr(engine, f"_{program}").as_text()
+    assert report[f"{program}_kernels"] == {kernel: 3, "paged_attention": 1}
+    assert report[f"{program}_tpu_custom_calls"] == 4
+    assert report[f"{program}_pool_copies"] == 0
+    assert report[f"{program}_state_copies"] == 0
+    assert pool_copies(text, engine.store.state_shape) == 0
+    assert "input_output_alias" in text
+    # grid steps: the full layer walks 64 page slots for 16 slots x 1 group of
+    # 30 KV heads (a one-row tile) or 1 x 3 groups of 10 (a 256-row tile); a
+    # linear layer steps 16 slots x 2 groups of 15 heads, or 30 heads x 4
+    # chunks of 64 positions
+    per_layer = {"decode": (16 * 64, 16 * 2), "prefill": (3 * 64, 30 * 4)}[program]
+    assert report[f"{program}_attn_grid_steps"] == per_layer[0] + 3 * per_layer[1]
+
+
+def test_kv_heads_per_step_at_thirty_heads():
+    """30 KV heads (divisors 1, 2, 3, 5, 6, 10, 15, 30; no power of two above
+    2): every head of a one-row decode tile in one step, 10 a step for a
+    256-row chunk tile (15 would overrun the 12 MiB budget), and a tile no
+    divisor serves goes to the reference."""
+    assert fa._kv_heads_per_step(30, 1, 128, 64, 4) == 30
+    assert fa._kv_heads_per_step(30, 256, 128, 64, 4) == 10
+    assert fa._tile_vmem_bytes(15 * 256, 128) + 4 * 15 * 64 * 128 * 4 > fa._TILE_VMEM_BUDGET
+    assert fa._kv_heads_per_step(30, 256, 128, 64, 4, at_most=4) == 3
+    assert fa._kv_heads_per_step(30, 1 << 14, 128, 64, 4) == 0
+
+
+@pytest.mark.parametrize("rows", [1, 256], ids=["decode", "chunk256"])
+def test_paged_kernel_compiles_at_thirty_kv_heads_for_v5e(one_chip, no_cache, rows):
+    """One key a query head (g = 1): a decode tile is ONE row a head."""
+    h, d, page, n_pages, p = 30, 128, 64, 1025, 64
+    s_n = 16 if rows == 1 else 1
+
+    def a(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = a((2, n_pages, h, page, d), jnp.float32)
+    names = _kernel_names(
+        lambda q, k, v, pt, sl, qs: fa._paged_call(q, k, v, 1, pt, sl, qs, False),
+        a((s_n, rows, h, d), jnp.float32), pool, pool, a((s_n, p), jnp.int32),
+        a((s_n,), jnp.int32), a((s_n,), jnp.int32),
+    )
+    assert names == ["paged_attention"]
+
+
+@pytest.mark.parametrize("form", ["chunk", "step"])
+def test_gated_delta_kernels_compile_for_v5e(one_chip, no_cache, form):
+    """The two linear-attention kernels alone at the published head sizes:
+    30 heads, keys 96 and values 192 wide (neither a multiple of 128)."""
+    from unittest import mock
+
+    gd = importlib.import_module("tf_operator_tpu.ops.gated_delta")
+    H, dk, dv = 30, 96, 192
+
+    def a(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        if form == "chunk":
+            names = _kernel_names(
+                gd.gated_delta_chunk, a((256, H, dk)), a((256, H, dk)),
+                a((256, H, dv)), a((256, H)), a((256, H)), a((H, dk, dv)))
+        else:
+            names = _kernel_names(
+                lambda q, k, v, al, b, st, sl: gd.gated_delta_step(
+                    q, k, v, al, b, st, layer=4, slots=sl),
+                a((16, H, dk)), a((16, H, dk)), a((16, H, dv)), a((16, H)),
+                a((16, H)), a((6, 17, H, dk, dv)), a((16,), jnp.int32))
+    assert names == ["gdn_chunk_fwd" if form == "chunk" else "gdn_step"]
+
+
 # ---- grouped matmul: fwd, dx, dw, with and without the fused row scale ----
 
 GMM_WIDTHS = {

@@ -241,7 +241,9 @@ def tiny_root(tmp_path_factory):
 
 
 def test_this_pr_added_fifteen_entries():
-    assert len(_new_metrics()) == 15
+    # + the five `.longdoc` entries of PR 37: the same readers on the
+    # Olmo-Hybrid cell, whose engine writes the same spans
+    assert len(_new_metrics()) == 15 + 5
 
 
 @pytest.mark.parametrize("metric,cell", _new_metrics())

@@ -29,6 +29,9 @@ import jax
 import jax.numpy as jnp
 
 
+LINEAR = "linear"  # a layer_pattern entry: the layer's mixer is the Gated DeltaNet
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     vocab: int = 32000
@@ -111,6 +114,8 @@ class TransformerConfig:
     # positional encoding at all in that layer (NoPE). () is one entry
     # (0, True): today's stack. The stack scans over periods with the
     # period's layers unrolled in the body, so both are static per layer.
+    # An entry may instead be the layer KIND "linear": a Gated-DeltaNet
+    # mixer (the lin_* sizes below) in the attention's place.
     layer_pattern: tuple = ()
     # MoE variants. expert_act: the gate activation of the expert MLP
     # ("silu" SwiGLU | "relu" ReGLU). router_input: the tensor the router
@@ -170,6 +175,21 @@ class TransformerConfig:
     mtp_weight: float = 0.3
     # False: an output head of its own (params["head"], [vocab, d_model]).
     tied_head: bool = True
+    # The "linear" layers' mixer (Gated DeltaNet, ops/gated_delta.py):
+    # lin_heads heads with keys lin_dk and values lin_dv wide, a causal
+    # depthwise convolution of lin_conv taps over [q | k | v] before the
+    # recurrence, the write strength in [0, 2] (lin_neg_eigval) or [0, 1].
+    lin_heads: int = 0
+    lin_dk: int = 0
+    lin_dv: int = 0
+    lin_conv: int = 4
+    lin_neg_eigval: bool = True
+    # "pre": x + F(norm(x)), the Llama order. "post": x + norm(F(x)), the
+    # OLMo-2 order — the same two gains a layer, on the sublayers' OUTPUTS.
+    norm_order: str = "pre"
+    # RMSNorm over the whole q and the whole k projection (gains q_norm
+    # [heads·head_dim], k_norm [kv_heads·head_dim]) before the heads split.
+    qk_norm: bool = False
 
     def __post_init__(self):
         if self.n_experts and not (1 <= self.moe_top_k <= self.n_experts):
@@ -183,8 +203,33 @@ class TransformerConfig:
                 f"n_layers={self.n_layers} is not a whole number of periods "
                 f"of {len(self.pattern)} layers"
             )
-        if any(w and not self.causal for w, _ in self.pattern):
+        if any(w and not self.causal for w, _ in self.attn_kinds):
             raise ValueError("a window layer needs causal=True")
+        if self.norm_order not in ("pre", "post"):
+            raise ValueError(f"unknown norm_order {self.norm_order!r}")
+        if (self.norm_order == "post" or self.qk_norm) and (
+                self.n_experts or self.attn_kind != "gqa"):
+            raise ValueError(
+                "norm_order='post' and qk_norm run on dense gqa layers only "
+                "(no experts, no latent attention)")
+        if self.has_linear:
+            if min(self.lin_heads, self.lin_dk, self.lin_dv) <= 0 or self.lin_conv < 2:
+                raise ValueError(
+                    f"a 'linear' layer needs lin_heads, lin_dk, lin_dv > 0 and "
+                    f"lin_conv >= 2 (got {self.lin_heads}, {self.lin_dk}, "
+                    f"{self.lin_dv}, {self.lin_conv})")
+            for what, bad in (
+                    ("pipeline stages (pp_microbatches)", self.pp_microbatches),
+                    (f"attn_impl={self.attn_impl!r}",
+                     self.attn_impl in ("ring", "ulysses")),
+                    ("experts (n_experts)", self.n_experts),
+                    ("latent attention", self.attn_kind != "gqa"),
+                    ("a prediction module (mtp_depth)", self.mtp_depth),
+                    ("a bidirectional model (causal=False)", not self.causal)):
+                if bad:
+                    raise ValueError(
+                        f"a 'linear' layer does not run with {what}: its state "
+                        "is carried along the whole sequence on one device")
         if self.expert_act not in ("silu", "relu"):
             raise ValueError(f"unknown expert_act {self.expert_act!r}")
         if self.router_input not in ("mlp_norm", "attn_norm"):
@@ -214,7 +259,7 @@ class TransformerConfig:
                     f"latent attention runs on 'flash' or 'dense', not "
                     f"{self.attn_impl!r}"
                 )
-            if any(w or not r for w, r in self.pattern):
+            if any(w or not r for w, r in self.attn_kinds):
                 raise ValueError(
                     "latent attention runs global rotary layers only (no "
                     "window, no NoPE layer)"
@@ -250,8 +295,8 @@ class TransformerConfig:
         if self.stack_is_new and self.pp_microbatches:
             raise ValueError(
                 "leading dense layers, a shared expert, the router bias, an "
-                "MTP module and an untied head are not stage-partitioned: "
-                "pp_microbatches must be 0"
+                "MTP module, an untied head, q/k norms and linear layers are "
+                "not stage-partitioned: pp_microbatches must be 0"
             )
 
     @property
@@ -263,7 +308,8 @@ class TransformerConfig:
         """Anything the pipelined stack does not partition."""
         return bool(self.n_dense_lead or self.n_shared_experts
                     or self.router_bias or self.mtp_depth
-                    or not self.tied_head or self.attn_kind == "latent")
+                    or not self.tied_head or self.attn_kind == "latent"
+                    or self.qk_norm or self.has_linear)
 
     @property
     def n_stack_layers(self) -> int:
@@ -272,8 +318,38 @@ class TransformerConfig:
 
     @property
     def pattern(self) -> tuple:
-        """((window, rotary), ...): one entry a layer of the period."""
+        """One entry a layer of the period: (window, rotary), or LINEAR."""
         return tuple(self.layer_pattern) or ((0, True),)
+
+    @property
+    def attn_kinds(self) -> tuple:
+        """The period's attention layers' (window, rotary) entries."""
+        return tuple(k for k in self.pattern if k != LINEAR)
+
+    @property
+    def has_linear(self) -> bool:
+        return LINEAR in self.pattern
+
+    def _in_period(self, linear: bool, upto: Optional[int] = None) -> int:
+        """Layers of one kind among the period's first ``upto`` (all)."""
+        return sum((k == LINEAR) == linear for k in self.pattern[:upto])
+
+    def n_of_kind(self, linear: bool) -> int:
+        """How many of the stack's layers are linear (or are not)."""
+        return self._in_period(linear) * (self.n_stack_layers // len(self.pattern))
+
+    def kind_index(self, layer: int) -> int:
+        """Layer ``layer``'s place among the stack's layers of ITS kind: the
+        index of its mixer's leaves (stacked by kind) and of its sequence
+        state in the serve engine (pages or recurrent state)."""
+        period, j = divmod(layer, len(self.pattern))
+        linear = self.pattern[j] == LINEAR
+        return period * self._in_period(linear) + self._in_period(linear, j)
+
+    @property
+    def lin_conv_channels(self) -> int:
+        """Channels the linear mixer's convolution runs over: [q | k | v]."""
+        return self.lin_heads * (2 * self.lin_dk + self.lin_dv)
 
     @property
     def n_held(self) -> int:
@@ -291,7 +367,7 @@ class TransformerConfig:
                     + nh * self.v_head_dim * d)
         else:
             q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
-            attn = d * q + 2 * d * kv + q * d
+            attn = d * q + 2 * d * kv + q * d + (q + kv if self.qk_norm else 0)
         mlp = 3 * d * f
         if self.n_experts:
             # experts + router + the shared expert
@@ -301,8 +377,15 @@ class TransformerConfig:
         # an MTP module: two norms, W_eh, one expert layer, its final norm
         mtp = self.mtp_depth * (2 * d + 2 * d * d + per_layer + d)
         head = 0 if self.tied_head else v * d
+        # a linear layer's mixer in the attention's place: the projections
+        # (q, k, v, z, the two gates), the convolution, A_log and dt_bias,
+        # the output norm's gain, the output projection
+        H, hv = self.lin_heads, self.lin_heads * self.lin_dv
+        lin = (d * (self.lin_conv_channels + hv + 2 * H)
+               + self.lin_conv * self.lin_conv_channels + 2 * H + self.lin_dv + hv * d)
+        swap = self.n_of_kind(True) * (lin - attn)
         # embed + lead + layers + final norm + mtp + head
-        return v * d + lead + self.n_stack_layers * per_layer + d + mtp + head
+        return v * d + lead + self.n_stack_layers * per_layer + swap + d + mtp + head
 
     def n_active_params(self) -> int:
         """Params touched per token (= n_params for dense; top-k MoE
@@ -421,6 +504,22 @@ PRESETS: Dict[str, TransformerConfig] = {
         moe_aux_weight=0.0, moe_zloss_weight=0.0,
         mtp_depth=1, mtp_weight=0.3, tied_head=False,
     ),
+    # Olmo-Hybrid-7B (allenai; config.json on the hub, model_type
+    # olmo_hybrid): period [Gated-DeltaNet linear x3, full attention] x 8;
+    # the linear mixer has 30 heads with keys 96 and values 192 wide behind
+    # a 4-tap convolution, write strengths in [0, 2]; the full layers 30
+    # heads = 30 KV heads of 128 with NO rotary embedding; SwiGLU 11008; an
+    # untied head. By the OLMo-2/3 family's convention (the config has no
+    # key for it) the norms stand on each sublayer's output and q and k are
+    # normalised whole. One chip SERVES two periods of it
+    # (benchmarks/configs/olmo-hybrid-7b-serve1.json).
+    "olmo-hybrid-7b": TransformerConfig(
+        vocab=100352, d_model=3840, n_layers=32, n_heads=30, n_kv_heads=30,
+        d_ff=11008, max_seq=65536, norm_eps=1e-6,
+        layer_pattern=(LINEAR, LINEAR, LINEAR, (0, False)),
+        lin_heads=30, lin_dk=96, lin_dv=192, lin_conv=4, lin_neg_eigval=True,
+        norm_order="post", qk_norm=True, tied_head=False,
+    ),
 }
 
 
@@ -444,6 +543,12 @@ def _init_layers(key, cfg: TransformerConfig, L: int, dense: bool) -> Dict[str, 
     ks = jax.random.split(key, 8)
     kx = jax.random.split(jax.random.fold_in(key, 8), 8)
     layers = {"attn_norm": jnp.ones((L, d), jnp.float32)}
+    # a mixer's leaves are stacked by KIND: the attention's over the La
+    # layers that attend, the linear mixer's (lin_*) over the linear ones
+    n_lin = cfg.n_of_kind(True)
+    La = L - n_lin
+    if n_lin:
+        layers.update(_init_linear_mixer(jax.random.fold_in(key, 9), cfg, n_lin))
     if cfg.attn_kind == "latent":
         qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
         qk, dv = cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.v_head_dim
@@ -456,13 +561,16 @@ def _init_layers(key, cfg: TransformerConfig, L: int, dense: bool) -> Dict[str, 
             "wkv_b": dense_init(kx[3], kvr, L, kvr, nh * (cfg.qk_nope_dim + dv)),
             "wo": dense_init(ks[3], nh * dv, L, nh * dv, d),
         })
-    else:
+    elif La:
         layers.update({
-            "wq": dense_init(ks[0], d, L, d, nh * hd),
-            "wk": dense_init(ks[1], d, L, d, nkv * hd),
-            "wv": dense_init(ks[2], d, L, d, nkv * hd),
-            "wo": dense_init(ks[3], nh * hd, L, nh * hd, d),
+            "wq": dense_init(ks[0], d, La, d, nh * hd),
+            "wk": dense_init(ks[1], d, La, d, nkv * hd),
+            "wv": dense_init(ks[2], d, La, d, nkv * hd),
+            "wo": dense_init(ks[3], nh * hd, La, nh * hd, d),
         })
+        if cfg.qk_norm:
+            layers["q_norm"] = jnp.ones((La, nh * hd), jnp.float32)
+            layers["k_norm"] = jnp.ones((La, nkv * hd), jnp.float32)
     layers["mlp_norm"] = jnp.ones((L, d), jnp.float32)
     if cfg.n_experts and not dense:
         E, f = cfg.n_held, cfg.d_ff  # the router scores all n_experts, the weights are the held
@@ -491,6 +599,33 @@ def _init_layers(key, cfg: TransformerConfig, L: int, dense: bool) -> Dict[str, 
             }
         )
     return layers
+
+
+def _init_linear_mixer(key, cfg: TransformerConfig, L: int) -> Dict[str, Any]:
+    """``L`` stacked Gated-DeltaNet mixers. Projections fan-in scaled like
+    every other matrix; the decay's two per-head scalars as the published
+    layer initialises them (A uniform in [1, 16), the step dt log-uniform in
+    [1e-3, 1e-1) and stored through the inverse softplus), so that a state
+    remembers tens to hundreds of tokens, as a trained one does."""
+    d, H = cfg.d_model, cfg.lin_heads
+    ch, hv, K = cfg.lin_conv_channels, cfg.lin_heads * cfg.lin_dv, cfg.lin_conv
+    ks = jax.random.split(key, 8)
+
+    def normal(k, fan_in, *shape):
+        return jax.random.normal(k, shape, jnp.float32) * (fan_in**-0.5)
+
+    dt = jnp.exp(jax.random.uniform(
+        ks[6], (L, H), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+    return {
+        "lin_wqkv": normal(ks[0], d, L, d, ch),
+        "lin_wz": normal(ks[1], d, L, d, hv),
+        "lin_wba": normal(ks[2], d, L, d, 2 * H),
+        "lin_conv": normal(ks[3], K, L, K, ch),
+        "lin_wo": normal(ks[4], hv, L, hv, d),
+        "lin_A_log": jnp.log(jax.random.uniform(ks[5], (L, H), jnp.float32, 1.0, 16.0)),
+        "lin_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "lin_norm": jnp.ones((L, cfg.lin_dv), jnp.float32),
+    }
 
 
 def init_transformer(key, cfg: TransformerConfig) -> Dict[str, Any]:
@@ -547,6 +682,19 @@ def _layer_axes(cfg: TransformerConfig, dense: bool) -> Dict[str, Any]:
             "wk": ("layers", "embed", "kv_heads"),
             "wv": ("layers", "embed", "kv_heads"),
             "wo": ("layers", "heads", "embed"),
+        })
+        if cfg.qk_norm:
+            layers.update({"q_norm": ("layers", "heads"),
+                           "k_norm": ("layers", "kv_heads")})
+    if cfg.has_linear:
+        layers.update({
+            "lin_wqkv": ("layers", "embed", "heads"),
+            "lin_wz": ("layers", "embed", "heads"),
+            "lin_wba": ("layers", "embed", None),
+            "lin_conv": ("layers", None, "heads"),
+            "lin_wo": ("layers", "heads", "embed"),
+            "lin_A_log": ("layers", None), "lin_dt_bias": ("layers", None),
+            "lin_norm": ("layers", None),
         })
     layers["mlp_norm"] = ("layers", "embed")
     if cfg.n_experts and not dense:
@@ -676,6 +824,79 @@ def rope_at_positions(x, positions, theta: float):
     sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+# The linear (Gated DeltaNet) mixer in pieces the serve engine shares: it
+# runs the same projections and gates around its OWN convolution tail and
+# recurrent state (serve/engine.py), where the whole-sequence forward below
+# starts both from zero.
+
+
+def lin_project(h, lp, cfg: TransformerConfig):
+    """h [..., d] -> (the convolution's input [..., channels] = [q | k | v]
+    before the taps, the output gate z, the write-strength logit b and the
+    decay logit a, both [..., H])."""
+    dt = h.dtype
+    ba = h @ lp["lin_wba"].astype(dt)
+    H = cfg.lin_heads
+    return (h @ lp["lin_wqkv"].astype(dt), h @ lp["lin_wz"].astype(dt),
+            ba[..., :H], ba[..., H:])
+
+
+def lin_conv_taps(ext, w, n: int):
+    """The causal depthwise convolution + SiLU over ``n`` positions: ``ext``
+    [..., n + K − 1, channels] is the input with the K − 1 positions before
+    it in front (zeros at a sequence's start), w [K, channels] the taps, tap
+    j on the input j − (K − 1) positions back."""
+    K = w.shape[0]
+    acc = sum(ext[..., j:j + n, :] * w[j].astype(ext.dtype) for j in range(K))
+    return jax.nn.silu(acc)
+
+
+def lin_gates(u, b, a, lp, cfg: TransformerConfig):
+    """The recurrence's operands from the convolved channels u [..., ch] and
+    the two logits [..., H]: q (l2-normalised, scaled d_k^-1/2), k
+    (l2-normalised) [..., H, d_k], v [..., H, d_v], alpha_log = −exp(A_log) ·
+    softplus(a + dt_bias) and beta = sigmoid(b), doubled where the model
+    lets the transition's eigenvalues turn negative — all float32."""
+    H, dk, dv = cfg.lin_heads, cfg.lin_dk, cfg.lin_dv
+    u = u.astype(jnp.float32)
+    lead = u.shape[:-1]
+
+    def l2(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+    q = l2(u[..., : H * dk].reshape(lead + (H, dk))) * dk**-0.5
+    k = l2(u[..., H * dk: 2 * H * dk].reshape(lead + (H, dk)))
+    v = u[..., 2 * H * dk:].reshape(lead + (H, dv))
+    alpha_log = -jnp.exp(lp["lin_A_log"]) * jax.nn.softplus(
+        a.astype(jnp.float32) + lp["lin_dt_bias"])
+    beta = jax.nn.sigmoid(b.astype(jnp.float32)) * (2.0 if cfg.lin_neg_eigval else 1.0)
+    return q, k, v, alpha_log, beta
+
+
+def lin_output(o, z, lp, cfg: TransformerConfig, dtype):
+    """(RMSNorm over d_v of the recurrence's output, one gain for all
+    heads) ⊙ SiLU(z), through the output projection: [..., H, d_v] -> [..., d]."""
+    o = _rms_norm(o, lp["lin_norm"], cfg.norm_eps).astype(dtype)
+    y = o * jax.nn.silu(z.reshape(o.shape))
+    return y.reshape(y.shape[:-2] + (-1,)) @ lp["lin_wo"].astype(dtype)
+
+
+def _linear_mixer(h, lp, cfg: TransformerConfig):
+    """Whole sequences [b, t, d] through the linear mixer, from a zero
+    convolution tail and a zero state: the chunked scan, a row at a time."""
+    from tf_operator_tpu.ops.gated_delta import gated_delta_chunk
+
+    t = h.shape[1]
+    pre, z, b, a = lin_project(h, lp, cfg)
+    ext = jnp.pad(pre, ((0, 0), (cfg.lin_conv - 1, 0), (0, 0)))
+    q, k, v, alpha_log, beta = lin_gates(
+        lin_conv_taps(ext, lp["lin_conv"], t), b, a, lp, cfg)
+    state0 = jnp.zeros((cfg.lin_heads, cfg.lin_dk, cfg.lin_dv), jnp.float32)
+    o, _ = jax.vmap(
+        lambda *row: gated_delta_chunk(*row, state0))(q, k, v, alpha_log, beta)
+    return lin_output(o, z, lp, cfg, h.dtype)
 
 
 def _attention(q, k, v, cfg: TransformerConfig, mesh, window: int = 0):
@@ -826,11 +1047,13 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
     b, t, d = x.shape
     hd = cfg.head_dim
     latent = cfg.attn_kind == "latent"
+    linear = kind == LINEAR
+    post = cfg.norm_order == "post"
     # SECTION scopes (``sec_*``, PERF.md §3): metadata on the instructions
     # made here, nothing else — ``compiled_sections`` reads them back from
     # the compiled step. The statements keep the order they had.
     with jax.named_scope("sec_attn_proj"):
-        if not latent:
+        if not latent and not linear:
             wq = layer_params["wq"].astype(x.dtype)
             wk = layer_params["wk"].astype(x.dtype)
             wv = layer_params["wv"].astype(x.dtype)
@@ -859,15 +1082,22 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
         )
 
     with jax.named_scope("sec_attn_proj"):
-        h = anchor_tokens(_rms_norm(x, gamma_attn, cfg.norm_eps))
+        # norm_order "post": the sublayer reads the stream as it is and its
+        # OUTPUT is normalised (the same gain) on its way back into it
+        h = x if post else anchor_tokens(_rms_norm(x, gamma_attn, cfg.norm_eps))
         if tp_axis is not None:
             h = enter(h)
-        window, rotary = kind
+        if not linear:
+            window, rotary = kind
         if latent:
             q, k, v = _latent_qkv(h, layer_params, cfg)
-        else:
-            q = (h @ wq).reshape(b, t, wq.shape[-1] // hd, hd)
-            k = (h @ wk).reshape(b, t, wk.shape[-1] // hd, hd)
+        elif not linear:
+            q, k = h @ wq, h @ wk
+            if cfg.qk_norm:
+                q = _rms_norm(q, layer_params["q_norm"], cfg.norm_eps)
+                k = _rms_norm(k, layer_params["k_norm"], cfg.norm_eps)
+            q = q.reshape(b, t, wq.shape[-1] // hd, hd)
+            k = k.reshape(b, t, wk.shape[-1] // hd, hd)
             v = (h @ wv).reshape(b, t, wv.shape[-1] // hd, hd)
             if rotary:
                 q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
@@ -878,12 +1108,18 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
         with jax.named_scope("sec_router"):
             gate_logits = _router_logits(h, layer_params, cfg)
     with jax.named_scope("sec_attn_core"):
-        attn = _attention(q, k, v, cfg, mesh, window)
+        if linear:
+            proj = _linear_mixer(h, layer_params, cfg)
+        else:
+            attn = _attention(q, k, v, cfg, mesh, window)
     with jax.named_scope("sec_attn_proj"):
-        attn = attn.reshape(b, t, layer_params["wo"].shape[-2])
-        proj = attn @ layer_params["wo"].astype(x.dtype)
+        if not linear:
+            attn = attn.reshape(b, t, layer_params["wo"].shape[-2])
+            proj = attn @ layer_params["wo"].astype(x.dtype)
         if tp_axis is not None:
             proj = leave(proj)
+        if post:
+            proj = _rms_norm(proj, gamma_attn, cfg.norm_eps)
         # Selective-remat tag: saving the post-attention residual stream lets
         # the MLP recompute chain start HERE instead of replaying qkv →
         # attention → wo to rebuild it ("save:resid_mid"; the *_mid tiers keep
@@ -891,7 +1127,7 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
         x = checkpoint_name(x + proj, "resid_mid")
 
     with jax.named_scope("sec_mlp"):
-        h = anchor_tokens(_rms_norm(x, gamma_mlp, cfg.norm_eps))
+        h = x if post else anchor_tokens(_rms_norm(x, gamma_mlp, cfg.norm_eps))
     if cfg.n_experts and not dense:
         moe_out, aux = _moe_mlp(h, layer_params, cfg, mesh,
                                 local_ep_axis=local_ep_axis,
@@ -917,8 +1153,15 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
         down = (jax.nn.silu(z_gate) * up) @ layer_params["w_down"].astype(x.dtype)
         if tp_axis is not None:
             down = leave(down)
+        if post:
+            down = _rms_norm(down, gamma_mlp, cfg.norm_eps)
         return x + down, None
 
+
+# mixer leaves stacked by layer KIND where a model has linear layers
+_LIN_LEAVES = frozenset({"lin_wqkv", "lin_wz", "lin_wba", "lin_conv", "lin_wo",
+                         "lin_A_log", "lin_dt_bias", "lin_norm"})
+_ATTN_LEAVES = frozenset({"wq", "wk", "wv", "wo", "q_norm", "k_norm"})
 
 # one step's routing counters (_moe_single_gmm's stats), summed over layers as ``moe_<name>``
 MOE_COUNTERS = ("routed_here", "rows_computed", "held_load_max", "held_load_mean",
@@ -1488,12 +1731,22 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
     else:
         P = len(pattern)
 
+        def layer_of(period_params, j):
+            """Layer j of a period: its leaves at j — a mixer's at the
+            layer's place among the period's layers of its kind, where the
+            mixers are stacked by kind."""
+            if not cfg.has_linear:
+                return jax.tree_util.tree_map(lambda a: a[j], period_params)
+            i = cfg.kind_index(j)
+            mine = _LIN_LEAVES if pattern[j] == LINEAR else _ATTN_LEAVES
+            return {name: a[i if name in mine else j]
+                    for name, a in period_params.items()
+                    if name in mine or name not in _LIN_LEAVES | _ATTN_LEAVES}
+
         def period_body(x, period_params):
             auxes = []
             for j, layer_fn in enumerate(layer_fns):
-                x, aux = one_layer(
-                    layer_fn, x,
-                    jax.tree_util.tree_map(lambda a: a[j], period_params))
+                x, aux = one_layer(layer_fn, x, layer_of(period_params, j))
                 auxes.append(aux)
             if auxes[0] is None:
                 return x, None
@@ -1502,8 +1755,10 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
         with jax.named_scope("sec_stack"):
             x, aux_stack = jax.lax.scan(
                 period_body, x,
-                jax.tree_util.tree_map(
-                    lambda a: a.reshape((cfg.n_stack_layers // P, P) + a.shape[1:]),
+                jax.tree_util.tree_map(  # [periods, the period's layers (of the leaf's kind), ...]
+                    lambda a: a.reshape(
+                        (cfg.n_stack_layers // P, a.shape[0] * P // cfg.n_stack_layers)
+                        + a.shape[1:]),
                     stack))
         if aux_stack is not None:  # [periods, P, ...] -> [L, ...]
             aux_stack = jax.tree_util.tree_map(
@@ -1826,6 +2081,8 @@ CONFIG_OVERRIDE_FIELDS = frozenset(
         "router_score", "router_bias", "router_bias_rate", "router_scale",
         "router_groups", "n_shared_experts", "mtp_depth", "mtp_weight",
         "tied_head",
+        "lin_heads", "lin_dk", "lin_dv", "lin_conv", "lin_neg_eigval",
+        "norm_order", "qk_norm",
     }
 )
 
@@ -1836,7 +2093,8 @@ def preset_from_workload(workload: Dict[str, Any]) -> TransformerConfig:
     overrides = {k: workload[k] for k in CONFIG_OVERRIDE_FIELDS if k in workload}
     if "layer_pattern" in overrides:  # JSON lists -> the hashable tuple form
         overrides["layer_pattern"] = tuple(
-            (int(w), bool(r)) for w, r in overrides["layer_pattern"])
+            LINEAR if e == LINEAR else (int(e[0]), bool(e[1]))
+            for e in overrides["layer_pattern"])
     if workload.get("attn") in ("ring", "ulysses", "flash", "dense"):
         overrides["attn_impl"] = workload["attn"]
     return preset(workload.get("preset", "tiny"), **overrides)

@@ -28,8 +28,29 @@ Two compiled functions, both fixed-shape:
   same kernel with a tile of one row a slot. The last chunk's final
   logits yield the request's first generated token (the TTFT boundary).
 
-Both take the two KV pools donated and hand them back, and between
-parameter and result the pool is never copied, sliced or relaid: the
+What the engine serves: a dense decoder of models/transformer.py — GQA
+layers with or without rotary embedding (a NoPE layer), pre- or post-norm
+(``norm_order``), q/k norms, a tied or an untied head — and LINEAR layers
+(Gated DeltaNet, ``layer_pattern`` entries ``"linear"``) among them. So it
+holds TWO KINDS of sequence state (serve/kvcache.py): pages of K and V for
+the layers that attend, and for the linear layers a fixed-size recurrent
+state and convolution tail a batch SLOT (``StateStore``; slot ``max_slots``
+is the trash slot). A layer's place among the layers of its kind
+(``cfg.kind_index``) is its index into its kind's store and into its
+mixer's stacked weights. In the decode step a linear layer runs
+``ops.gated_delta_step`` on every slot's state in place (an inactive slot's
+reads and writes are steered to the trash slot, as its page writes go to
+the trash page); in a prefill chunk it runs the chunked scan
+(``ops.gated_delta_chunk``) from the sequence's state in its slot and
+leaves the state after the chunk's last VALID row there (padding rows
+write and decay nothing). Nothing resets a slot between requests: a
+sequence's FIRST chunk (``start == 0``) starts from zeros inside the
+program. It refuses, by name, what does not run: experts, latent
+attention, a prediction module, a window layer.
+
+Both take the pair of KV pools and the pair of state arrays (``()`` for a
+model without linear layers) donated and hand them back, and between parameter and result the pool is never copied, sliced or
+relaid: the
 write is a scatter whose only window dimension is head_dim (the pool's
 minor-most, so XLA updates the head-major pool where it lies) and the
 kernel takes the whole 5-D pool with the layer in its BlockSpec index
@@ -37,7 +58,8 @@ map. The earlier ``kp.at[l, pid, :, row].set(k)`` + ``kp[l]`` forced a
 row-major-pages layout on the pool for the scatter's (head, head_dim)
 window: four whole-pool relayout copies a call and a slice of a whole
 layer in front of every kernel. ``compile()`` counts what is left of
-that (``<program>_pool_copies``, 0).
+that (``<program>_pool_copies``, 0; ``<program>_state_copies`` for the
+recurrent state).
 
 Greedy argmax sampling, f32 compute throughout: serving determinism is
 what the correctness oracle (tests/test_serve.py) and the seeded bench
@@ -46,7 +68,10 @@ artifact pin against.
 ``run`` is instrumented with ``jax.profiler.TraceAnnotation`` spans
 (``serve.admit``, ``serve.step`` and its children ``serve.prefill``,
 ``serve.prefill_fetch``, ``serve.decode_prep``, ``serve.decode``,
-``serve.decode_fetch``; ``serve.idle``; the closing ``serve.counters``).
+``serve.decode_fetch``; ``serve.idle``; the closing ``serve.counters``);
+inside both programs the mixers carry ``jax.named_scope``s
+(``serve.lin_mixer`` / ``serve.full_attn``: metadata on the compiled
+instructions, nothing at run time).
 They cost well under a microsecond each while no profiler session is
 open and land in the xplane of ``profile_ctx`` / ``tpujob profile`` on
 the device trace's own clock; docs/design.md ("On-demand profiling")
@@ -67,8 +92,11 @@ from tf_operator_tpu.serve.kvcache import (
     PagePool,
     PoolExhausted,
     SequencePages,
+    StateStore,
     pages_needed,
+    read_slot,
     write_rows,
+    write_slot,
 )
 
 
@@ -116,6 +144,10 @@ class EngineCounters:
     decode_steps: int = 0      # calls of the decode program
     decode_slot_tokens: int = 0  # tokens they produced (active slots, summed)
     idle_sleeps: int = 0       # sleeps of an empty engine waiting for an arrival
+    # a model with linear layers (0 without): its slots' recurrent state
+    state_resets: int = 0      # first chunks: a slot's state started from zeros
+    prefill_state_carries: int = 0  # later chunks: started from the slot's state
+    lin_slot_steps: int = 0    # recurrent steps: active slots x linear layers
 
 
 @dataclass
@@ -280,10 +312,14 @@ class ServeEngine:
 
         if cfg.n_experts:
             raise ValueError("serve engine: MoE presets not supported")
-        if cfg.attn_kind != "gqa" or not cfg.tied_head or cfg.mtp_depth:
+        if cfg.attn_kind != "gqa" or cfg.mtp_depth:
             raise ValueError(
-                "serve engine: latent attention, an untied head and a "
-                "prediction module run in training only")
+                "serve engine: latent attention and a prediction module "
+                "run in training only")
+        if any(window for window, _ in cfg.attn_kinds):
+            raise ValueError(
+                "serve engine: the paged kernel has no sliding window; a "
+                "window layer runs in training only")
         if getattr(cfg, "pp_stages", 0):
             raise ValueError("serve engine: pipeline presets not supported")
         if scfg.page_size < 1:
@@ -306,6 +342,8 @@ class ServeEngine:
 
         self.params = jax.tree_util.tree_map(f32, params)
         self.max_pages_per_seq = pages_needed(cfg.max_seq, scfg.page_size)
+        # the linear layers' state, a slot a batch slot; None without any
+        self.store = StateStore.for_model(cfg, scfg.max_slots)
         self._jit_build()
 
     # -- compiled step functions -----------------------------------------
@@ -315,46 +353,89 @@ class ServeEngine:
         import jax.numpy as jnp
 
         from tf_operator_tpu.models.transformer import (
+            _LIN_LEAVES,
+            LINEAR,
+            _head,
             _rms_norm,
+            lin_conv_taps,
+            lin_gates,
+            lin_output,
+            lin_project,
             rope_at_positions,
         )
         from tf_operator_tpu.ops.flash_attention import flash_attention_decode
+        from tf_operator_tpu.ops.gated_delta import (
+            gated_delta_chunk,
+            gated_delta_step,
+        )
 
         cfg = self.cfg
         ps = self.scfg.page_size
         trash = self.scfg.pool_pages  # PagePool.trash_page
         hd = cfg.head_dim
-        L = cfg.n_layers
+        eps = cfg.norm_eps
+        post = cfg.norm_order == "post"
+        taps = cfg.lin_conv
+        trash_slot = self.store.trash_slot if self.store else None
+        kinds = [cfg.pattern[l % len(cfg.pattern)] for l in range(cfg.n_layers)]
 
-        def _body(params, kp, vp, x, pos, attend, write_pid, write_row):
+        def _body(params, kp, vp, state, x, pos, attend, write_pid, write_row,
+                  linear):
             """Shared per-layer body: x [n, d] at absolute positions pos
-            [n]; writes each row's k/v to (write_pid[i], write_row[i])
-            then ``attend(q [n, h, hd], kp, vp, l)`` reads them back
-            through the caller's page table(s). The pools [L, page, h_kv, row, hd] are carried WHOLE through
-            every layer — written by ``write_rows``, read by the kernel
-            at ``layer=l`` — and never indexed by layer here: ``kp[l]``
-            is a copy of a layer. Returns (kp, vp, final hidden [n, d])."""
+            [n]. A layer that ATTENDS writes each row's k/v to
+            (write_pid[i], write_row[i]) then ``attend(q [n, h, hd], kp, vp,
+            i)`` reads them back through the caller's page table(s). The
+            pools [attending layers, page, h_kv, row, hd] are carried WHOLE
+            through every layer — written by ``write_rows``, read by the
+            kernel at ``layer=i`` — and never indexed by layer here:
+            ``kp[i]`` is a copy of a layer. A LINEAR layer hands its input
+            to the caller's ``linear(h, weights, i, state)``, which runs the
+            mixer around the program's own view of the state store and
+            returns (output [n, d], state). ``i`` is the layer's place among
+            the layers of its kind: its index into its mixer's stacked
+            weights and into its kind's store. Returns (kp, vp, state,
+            final hidden [n, d])."""
             n = x.shape[0]
             lp = params["layers"]
-            for l in range(L):
-                h = _rms_norm(x, lp["attn_norm"][l], cfg.norm_eps)
-                q = (h @ lp["wq"][l]).reshape(n, -1, hd)
-                k = (h @ lp["wk"][l]).reshape(n, -1, hd)
-                v = (h @ lp["wv"][l]).reshape(n, -1, hd)
-                q = rope_at_positions(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-                k = rope_at_positions(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-                kp, vp = write_rows(kp, vp, l, k, v, write_pid, write_row)
-                attn = attend(q, kp, vp, l)
-                x = x + attn.reshape(n, -1) @ lp["wo"][l]
-                h2 = _rms_norm(x, lp["mlp_norm"][l], cfg.norm_eps)
-                x = x + (
+            for l, kind in enumerate(kinds):
+                i = cfg.kind_index(l)
+                # norm_order "post" (OLMo-2): the gain stands on the
+                # sublayer's OUTPUT, x + norm(F(x))
+                h = x if post else _rms_norm(x, lp["attn_norm"][l], eps)
+                if kind == LINEAR:
+                    with jax.named_scope("serve.lin_mixer"):
+                        y, state = linear(
+                            h, {name: lp[name][i] for name in _LIN_LEAVES}, i, state)
+                else:
+                    with jax.named_scope("serve.full_attn"):
+                        q, k = h @ lp["wq"][i], h @ lp["wk"][i]
+                        if cfg.qk_norm:
+                            q = _rms_norm(q, lp["q_norm"][i], eps)
+                            k = _rms_norm(k, lp["k_norm"][i], eps)
+                        q = q.reshape(n, -1, hd)
+                        k = k.reshape(n, -1, hd)
+                        v = (h @ lp["wv"][i]).reshape(n, -1, hd)
+                        if kind[1]:  # rotary; a NoPE layer attends by content alone
+                            q = rope_at_positions(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
+                            k = rope_at_positions(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
+                        kp, vp = write_rows(kp, vp, i, k, v, write_pid, write_row)
+                        attn = attend(q, kp, vp, i)
+                        y = attn.reshape(n, -1) @ lp["wo"][i]
+                x = x + (_rms_norm(y, lp["attn_norm"][l], eps) if post else y)
+                h2 = x if post else _rms_norm(x, lp["mlp_norm"][l], eps)
+                y = (
                     jax.nn.silu(h2 @ lp["w_gate"][l]) * (h2 @ lp["w_up"][l])
                 ) @ lp["w_down"][l]
-            return kp, vp, x
+                x = x + (_rms_norm(y, lp["mlp_norm"][l], eps) if post else y)
+            return kp, vp, state, x
 
-        def decode_step(params, kp, vp, table, seq_lens, tokens, active):
+        def decode_step(params, pools, state, table, seq_lens, tokens, active):
             """One token for every slot. tokens[i] sits at position
-            seq_lens[i]; returns next greedy token per slot."""
+            seq_lens[i]; returns next greedy token per slot. ``pools`` is
+            the (K, V) pair of page pools, ``state`` the linear layers'
+            (recurrent state, convolution tail) pair, or () for a model
+            without; both come back updated."""
+            kp, vp = pools
             s = tokens.shape[0]
             x = params["embed"][tokens]
             pos = seq_lens
@@ -362,18 +443,39 @@ class ServeEngine:
             pid = jnp.where(active, pid, trash)
             lens = jnp.where(active, pos + 1, 0)
 
-            def attend(q, kp, vp, l):  # one row a slot
-                return flash_attention_decode(q, kp, vp, table, lens, layer=l)
+            def attend(q, kp, vp, i):  # one row a slot
+                return flash_attention_decode(q, kp, vp, table, lens, layer=i)
 
-            kp, vp, x = _body(params, kp, vp, x, pos, attend, pid, pos % ps)
-            logits = _rms_norm(x, params["final_norm"], cfg.norm_eps) @ params["embed"].T
-            return kp, vp, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            def linear(h, w, i, state):
+                """One token a slot: the convolution over the slot's tail
+                and this input, the recurrent step on the slot's state in
+                place. An inactive slot reads and writes the trash slot."""
+                st, cv = state
+                slot = jnp.where(active, jnp.arange(s), trash_slot)
+                pre, z, b, a = lin_project(h, w, cfg)
+                ext = jnp.concatenate([cv[i, slot], pre[:, None]], axis=1)
+                u = lin_conv_taps(ext, w["lin_conv"], 1)[:, 0]
+                cv = cv.at[i, slot].set(ext[:, 1:])
+                q, k, v, alpha_log, beta = lin_gates(u, b, a, w, cfg)
+                o, st = gated_delta_step(q, k, v, alpha_log, beta, st,
+                                         valid=active, layer=i, slots=slot)
+                return lin_output(o, z, w, cfg, h.dtype), (st, cv)
 
-        def prefill_chunk(params, kp, vp, table_row, start, tokens_c, n_valid):
+            kp, vp, state, x = _body(
+                params, kp, vp, state, x, pos, attend, pid, pos % ps, linear)
+            logits = _rms_norm(x, params["final_norm"], eps) @ _head(params, cfg).T
+            return (kp, vp), state, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        def prefill_chunk(params, pools, state, table_row, start, tokens_c,
+                          n_valid, slot=None):
             """One chunk of one sequence's prompt: its C positions are one
             query tile over the sequence's page-table row, causal per row;
             rows past ``n_valid`` lie past the sequence's length, see
-            nothing and write to the trash page."""
+            nothing and write to the trash page. The linear layers carry
+            the sequence's state in batch slot ``slot`` from chunk to
+            chunk; the first chunk (``start == 0``) starts from zeros. A
+            model without linear layers is called without ``slot``."""
+            kp, vp = pools
             c = tokens_c.shape[0]
             idx = jnp.arange(c)
             pos = start + idx
@@ -381,15 +483,36 @@ class ServeEngine:
             x = params["embed"][tokens_c]
             pid = jnp.where(valid, table_row[pos // ps], trash)
 
-            def attend(q, kp, vp, l):  # ONE sequence, c rows
+            def attend(q, kp, vp, i):  # ONE sequence, c rows
                 return flash_attention_decode(
                     q[None], kp, vp, table_row[None], (start + n_valid)[None],
-                    layer=l, q_start=start[None])[0]
+                    layer=i, q_start=start[None])[0]
 
-            kp, vp, x = _body(params, kp, vp, x, pos, attend, pid, pos % ps)
-            last = _rms_norm(x[n_valid - 1], params["final_norm"], cfg.norm_eps)
-            logits = last @ params["embed"].T
-            return kp, vp, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            def linear(h, w, i, state):
+                """The chunk through the chunked scan from the slot's state
+                (a padding row writes and decays nothing), the convolution
+                over the slot's tail + the chunk; the slot keeps the state
+                after the last valid row and that row's last inputs."""
+                st, cv = state
+                fresh = start == 0
+                pre, z, b, a = lin_project(h, w, cfg)
+                ext = jnp.concatenate([read_slot(cv, i, slot, fresh), pre])
+                u = lin_conv_taps(ext, w["lin_conv"], c)
+                # ext row r is position start + r - (taps - 1)
+                cv = write_slot(cv, i, slot, jax.lax.dynamic_slice_in_dim(
+                    ext, n_valid, taps - 1))
+                q, k, v, alpha_log, beta = lin_gates(u, b, a, w, cfg)
+                o, s1 = gated_delta_chunk(
+                    q, k, v, alpha_log, beta, read_slot(st, i, slot, fresh),
+                    valid=valid)
+                st = write_slot(st, i, slot, s1)
+                return lin_output(o, z, w, cfg, h.dtype), (st, cv)
+
+            kp, vp, state, x = _body(
+                params, kp, vp, state, x, pos, attend, pid, pos % ps, linear)
+            last = _rms_norm(x[n_valid - 1], params["final_norm"], eps)
+            logits = last @ _head(params, cfg).T
+            return (kp, vp), state, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         self._decode = jax.jit(decode_step, donate_argnums=(1, 2))
         self._prefill = jax.jit(prefill_chunk, donate_argnums=(1, 2))
@@ -409,27 +532,41 @@ class ServeEngine:
         of its attention kernels, all layers (``pallas_grid_steps``; a
         step is one page of as many KV heads as the kernel's VMEM holds,
         and a chunk walks its sequence's pages once: slots x groups of
-        KV heads x page slots a layer)."""
+        KV heads x page slots a layer; the linear layers' kernels count
+        here too: a step of ``gdn_step`` is one slot's heads, of
+        ``gdn_chunk_fwd`` one head's 64 positions). ``<program>_kernels``
+        names the Pallas kernels (instructions by kernel name), and for a
+        model with linear layers ``<program>_state_copies`` counts for the
+        recurrent-state array what ``_pool_copies`` counts for the pool (0
+        while a slot's state is read and written where it lies) and
+        ``<program>_conv_tail_copies`` the same for the convolution tails
+        (a few MB: the TPU compiler may stage them through faster memory
+        around the decode step's gather and scatter)."""
         import jax
         import jax.numpy as jnp
+
+        from tf_operator_tpu.parallel.collectives import compiled_kernels
 
         scfg = self.scfg
 
         def arr(shape, dtype):
             return jax.ShapeDtypeStruct(shape, dtype)
 
-        kp, vp = (arr(self._pool_shape(), jnp.float32),) * 2
+        pools = (arr(self._pool_shape(), jnp.float32),) * 2
+        state = () if self.store is None else (
+            arr(self.store.state_shape, jnp.float32),
+            arr(self.store.conv_shape, jnp.float32))
         s_n, p = scfg.max_slots, self.max_pages_per_seq
         i32 = jnp.int32
         programs = {
             "decode": (self._decode, (
-                self.params, kp, vp, arr((s_n, p), i32), arr((s_n,), i32),
+                self.params, pools, state, arr((s_n, p), i32), arr((s_n,), i32),
                 arr((s_n,), i32), arr((s_n,), jnp.bool_),
             )),
             "prefill": (self._prefill, (
-                self.params, kp, vp, arr((p,), i32), arr((), i32),
+                self.params, pools, state, arr((p,), i32), arr((), i32),
                 arr((scfg.prefill_chunk,), i32), arr((), i32),
-            )),
+            ) + ((arr((), i32),) if state else ())),
         }
         out: Dict[str, Any] = {}
         for name, (fn, args) in programs.items():
@@ -441,13 +578,18 @@ class ServeEngine:
             text = compiled.as_text()
             out[f"{name}_tpu_custom_calls"] = text.count("tpu_custom_call")
             out[f"{name}_pool_copies"] = pool_copies(text, self._pool_shape())
+            out[f"{name}_kernels"] = dict(compiled_kernels(text))
+            if self.store is not None:
+                out[f"{name}_state_copies"] = pool_copies(text, self.store.state_shape)
+                out[f"{name}_conv_tail_copies"] = pool_copies(
+                    text, self.store.conv_shape)
             setattr(self, f"_{name}", compiled)
         return out
 
     def _pool_shape(self):
         cfg, scfg = self.cfg, self.scfg
-        return (
-            cfg.n_layers, scfg.pool_pages + 1, cfg.n_kv_heads,
+        return (  # the layers that attend: a linear layer keeps no pages
+            cfg.n_of_kind(False), scfg.pool_pages + 1, cfg.n_kv_heads,
             scfg.page_size, cfg.head_dim,
         )
 
@@ -492,7 +634,10 @@ class ServeEngine:
                 )
         pool = PagePool(scfg.pool_pages)
         free_start = pool.free_count
-        kp, vp = self._fresh_pools()
+        pools = self._fresh_pools()
+        # nothing resets it between requests: a slot's first chunk does
+        state = () if self.store is None else self.store.fresh()
+        n_lin = 0 if self.store is None else self.store.n_layers
         s_n = scfg.max_slots
         table = np.full((s_n, self.max_pages_per_seq), pool.trash_page - 1,
                         np.int32)
@@ -544,7 +689,7 @@ class ServeEngine:
 
         def _prefill_chunks() -> None:
             """One chunk per still-prefilling slot."""
-            nonlocal kp, vp, generated
+            nonlocal pools, state, generated
             c = scfg.prefill_chunk
             for i, sl in enumerate(slots):
                 if sl is None or sl.prefill_pos >= len(sl.req.prompt):
@@ -560,11 +705,16 @@ class ServeEngine:
                           last=int(last), kv_pages=kv_pages):
                     buf = np.zeros(c, np.int32)
                     buf[:n_valid] = chunk
-                    kp, vp, tok = self._prefill(
-                        self.params, kp, vp, jnp.asarray(table[i]),
+                    pools, state, tok = self._prefill(
+                        self.params, pools, state, jnp.asarray(table[i]),
                         jnp.int32(sl.prefill_pos), jnp.asarray(buf),
-                        jnp.int32(n_valid),
+                        jnp.int32(n_valid), *((jnp.int32(i),) if n_lin else ()),
                     )
+                if n_lin:
+                    if sl.prefill_pos == 0:
+                        counters.state_resets += 1
+                    else:
+                        counters.prefill_state_carries += 1
                 counters.prefill_chunks += 1
                 counters.prefill_tokens += n_valid
                 counters.prefill_padded += c - n_valid
@@ -588,7 +738,7 @@ class ServeEngine:
 
         def _decode_step() -> None:
             """One batched step over the decoding slots."""
-            nonlocal kp, vp, generated
+            nonlocal pools, state, generated
             dec = [
                 (i, sl) for i, sl in enumerate(slots)
                 if sl is not None
@@ -608,9 +758,10 @@ class ServeEngine:
                 args = (jnp.asarray(table), jnp.asarray(lens),
                         jnp.asarray(toks), jnp.asarray(active))
             with span("serve.decode", active=len(dec), slots=s_n):
-                kp, vp, nxt = self._decode(self.params, kp, vp, *args)
+                pools, state, nxt = self._decode(self.params, pools, state, *args)
             counters.decode_steps += 1
             counters.decode_slot_tokens += len(dec)
+            counters.lin_slot_steps += n_lin * len(dec)
             with span("serve.decode_fetch"):
                 nxt = np.asarray(nxt)
             t_tok = clock() - t0

@@ -1,8 +1,22 @@
-"""Paged KV cache: fixed-size pages in a preallocated device pool.
+"""The serve engine's sequence state: a paged KV cache for the layers that
+attend, and a slot-indexed recurrent-state store for the linear layers.
+
+TWO KINDS of per-sequence state live side by side. A layer that ATTENDS
+keeps a key and a value per token: pages of a preallocated pool, below. A
+LINEAR (Gated DeltaNet) layer keeps a fixed-size state per sequence however
+long the sequence is — a [heads, d_k, d_v] float32 matrix and the last
+``taps − 1`` inputs of its convolution — in ``StateStore``, indexed by the
+engine's batch SLOT, one more slot than the engine has (the trash slot).
+Pages are allocated at admission and freed at completion; a slot's state
+needs no allocator (the slot is the allocation) and is reset inside the
+first prefill chunk of whoever takes the slot next. A model's layers index
+each store by their place among the layers of their kind
+(``TransformerConfig.kind_index``): the pool spans the attending layers
+only, the state store the linear ones.
 
 The vLLM (SOSP '23) memory model in jax_graft form: decode K/V state
 lives in PAGES of ``page_size`` token slots, preallocated as one device
-pool per layer side — shape [n_layers, num_pages + 1, n_kv_heads,
+pool per layer side — shape [attending layers, num_pages + 1, n_kv_heads,
 page_size, head_dim] (head-major inside a page: a (page, kv-head) slab
 [page_size, head_dim] is what the TPU compiler can tile, and a page's
 heads lie together, so the paged kernel's block is one page of several
@@ -37,7 +51,7 @@ which the engine tests and the serve worker assert.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import List
+from typing import List, Optional
 
 
 class PoolExhausted(Exception):
@@ -50,6 +64,87 @@ def pages_needed(tokens: int, page_size: int) -> int:
     return max(1, -(-int(tokens) // int(page_size)))
 
 
+@dataclass(frozen=True)
+class StateStore:
+    """Geometry of the recurrent-state store: for each of ``n_layers``
+    linear layers and each of ``slots`` batch slots (+ 1: the trash slot)
+    the recurrence's state ``[heads, d_k, d_v]`` and the convolution's tail
+    ``[conv_rows, conv_channels]`` (the last taps − 1 inputs), float32. The
+    two device arrays are the engine's (donated to its programs and handed
+    back, like the pools); a decode step updates the state in place through
+    ``ops.gated_delta_step(..., layer=, slots=)``, a prefill chunk reads and
+    writes its one slot with the functions below."""
+
+    n_layers: int
+    slots: int
+    heads: int
+    d_k: int
+    d_v: int
+    conv_rows: int
+    conv_channels: int
+
+    @classmethod
+    def for_model(cls, cfg, slots: int) -> Optional["StateStore"]:
+        """The store a model's linear layers need; None for a model without."""
+        if not cfg.has_linear:
+            return None
+        return cls(cfg.n_of_kind(True), slots, cfg.lin_heads, cfg.lin_dk,
+                   cfg.lin_dv, cfg.lin_conv - 1, cfg.lin_conv_channels)
+
+    @property
+    def trash_slot(self) -> int:
+        """Where an inactive slot's writes are steered: one past the slots."""
+        return self.slots
+
+    @property
+    def state_shape(self) -> tuple:
+        return (self.n_layers, self.slots + 1, self.heads, self.d_k, self.d_v)
+
+    @property
+    def conv_shape(self) -> tuple:
+        return (self.n_layers, self.slots + 1, self.conv_rows, self.conv_channels)
+
+    @property
+    def slot_bytes(self) -> int:
+        """Bytes ONE sequence's state takes over all the linear layers."""
+        return 4 * self.n_layers * (
+            self.heads * self.d_k * self.d_v + self.conv_rows * self.conv_channels)
+
+    @property
+    def bytes(self) -> int:
+        return (self.slots + 1) * self.slot_bytes
+
+    def fresh(self) -> tuple:
+        """(state, conv tail), zeros."""
+        import jax.numpy as jnp
+
+        return (jnp.zeros(self.state_shape, jnp.float32),
+                jnp.zeros(self.conv_shape, jnp.float32))
+
+
+def read_slot(store, layer: int, slot, fresh):
+    """One slot's entry of one of the two state arrays, ``[layer, slot]``
+    (slot a traced scalar) — zeros where ``fresh``: a sequence's first chunk
+    starts from nothing, whatever the slot's last owner left there."""
+    import jax
+    import jax.numpy as jnp
+
+    # one slice of the whole store: ``store[layer]`` first would be a copy
+    # of a layer's every slot
+    got = jax.lax.dynamic_slice(
+        store, (layer, slot) + (0,) * (store.ndim - 2), (1, 1) + store.shape[2:])
+    return jnp.where(fresh, 0.0, got[0, 0])
+
+
+def write_slot(store, layer: int, slot, value):
+    """``store[layer, slot] = value``, where the store lies (a
+    dynamic-update-slice of one slot's entry)."""
+    import jax
+
+    at = (layer, slot) + (0,) * value.ndim
+    return jax.lax.dynamic_update_slice(store, value[None, None], at)
+
+
 def pool_bytes(
     n_layers: int,
     num_pages: int,
@@ -57,13 +152,16 @@ def pool_bytes(
     n_kv_heads: int,
     head_dim: int,
     dtype_bytes: int = 4,
+    state: Optional[StateStore] = None,
 ) -> int:
-    """Device bytes of the K+V pools (including the trash page) — the
-    number tools/memplan.py budgets for a serve job."""
+    """Device bytes of the sequence state of both kinds: the K+V pools over
+    the ``n_layers`` ATTENDING layers (including the trash page) and, for a
+    model with linear layers, its ``StateStore`` — the number
+    tools/memplan.py budgets for a serve job."""
     per_side = (
         n_layers * (num_pages + 1) * page_size * n_kv_heads * head_dim
     )
-    return 2 * per_side * dtype_bytes
+    return 2 * per_side * dtype_bytes + (state.bytes if state else 0)
 
 
 def write_rows(kp, vp, layer: int, k, v, pid, row):

@@ -281,7 +281,7 @@ def serve_plan(preset_name: str, workload: dict | None = None,
         init_transformer,
         preset_from_workload,
     )
-    from tf_operator_tpu.serve.kvcache import pages_needed, pool_bytes
+    from tf_operator_tpu.serve.kvcache import StateStore, pages_needed, pool_bytes
 
     wl = dict(workload or {})
     wl.setdefault("preset", preset_name)
@@ -298,9 +298,10 @@ def serve_plan(preset_name: str, workload: dict | None = None,
     params_b = sum(
         math.prod(leaf.shape) * 4 for leaf in jax.tree_util.tree_leaves(shapes)
     )
-    kv_b = pool_bytes(
-        cfg.n_layers, kv_pool_pages, kv_page_size,
+    kv_b = pool_bytes(  # pages over the attending layers + the linear layers' state
+        cfg.n_of_kind(False), kv_pool_pages, kv_page_size,
         cfg.n_kv_heads, cfg.head_dim, dtype_bytes=4,
+        state=StateStore.for_model(cfg, max_slots),
     )
     # working set per step: the wider of a decode batch (max_slots rows)
     # and a prefill chunk, through one layer's intermediates plus the
