@@ -577,6 +577,29 @@ def test_hybrid_engine_programs_keep_both_stores_in_place_on_v5e(
     assert report[f"{program}_attn_grid_steps"] == per_layer[0] + 3 * per_layer[1]
 
 
+@pytest.mark.parametrize("fixture", ["serve1_engine", "hybrid_engine"])
+def test_engine_programs_hand_the_token_array_on_v5e(request, fixture):
+    """The loop runs a program ahead (PR 38): the token every slot decodes
+    from is an operand of BOTH programs and the last of their three results —
+    ``int32[max_slots]``, never donated, because the host reads the very array
+    the next run takes — and a chunk names its slot whatever the model; the
+    pools (and the state) are still the donated operands, still in place."""
+    engine, report = request.getfixturevalue(fixture)
+    s_n = engine.scfg.max_slots
+    small = {"decode": [(s_n, engine.max_pages_per_seq), (s_n,), (s_n,), (s_n,)],
+             "prefill": [(engine.max_pages_per_seq,), (),
+                         (engine.scfg.prefill_chunk,), (), (s_n,), ()]}
+    for program, shapes in small.items():
+        compiled = getattr(engine, f"_{program}")
+        args, _ = compiled.args_info
+        assert [(a.shape, a.donated) for a in args[3:]] == [(s, False) for s in shapes]
+        assert all(a.donated for a in jax.tree_util.tree_leaves(args[1:3]))
+        tok = compiled.out_info[2]
+        assert (tok.shape, str(tok.dtype)) == ((s_n,), "int32")
+        assert report[f"{program}_pool_copies"] == 0
+        assert report.get(f"{program}_state_copies", 0) == 0
+
+
 def test_kv_heads_per_step_at_thirty_heads():
     """30 KV heads (divisors 1, 2, 3, 5, 6, 10, 15, 30; no power of two above
     2): every head of a one-row decode tile in one step, 10 a step for a

@@ -41,7 +41,7 @@ def _requests():
     return reqs + [Request(rid=6, prompt=[9] * 8, max_new=2, arrival=1e9)]
 
 
-def _run(engine, monkeypatch, on_event=None):
+def _run(engine, monkeypatch, on_event=None, requests=None):
     """With a clock that jumps past the last arrival when the idle engine
     sleeps: the run does not depend on the host's speed."""
     from types import SimpleNamespace
@@ -59,7 +59,7 @@ def _run(engine, monkeypatch, on_event=None):
 
     monkeypatch.setattr(engine_module, "time", SimpleNamespace(
         sleep=sleep, perf_counter=time.perf_counter))
-    return engine.run(_requests(), clock=clock, on_event=on_event)
+    return engine.run(requests or _requests(), clock=clock, on_event=on_event)
 
 
 def _host_spans(trace_dir, prefix):
@@ -141,10 +141,19 @@ def test_counters_in_the_trace_equal_the_run_results(traced):
     assert c.prefill_chunks and c.prefill_padded and c.decode_steps
     assert c.decode_slot_tokens + c.admitted == res.generated_tokens
     assert len(by["serve.idle"]) == c.idle_sleeps == 1
+    # every program run but the first of each busy stretch went to the device
+    # before the host had read the run before it
+    assert written["runs_enqueued_ahead"] == c.runs_enqueued_ahead \
+        == c.prefill_chunks + c.decode_steps - 2
     assert [a["step"] for a in by["serve.step"]] == list(range(1, res.steps + 1))
     for a in by["serve.step"]:
-        assert 0 < a["occupied"] <= 3 and a["kv_tokens"] <= a["kv_reserved"]
+        assert a["occupied"] <= 3 and a["kv_tokens"] <= a["kv_reserved"]
         assert a["kv_reserved"] <= 8 * res.pool_peak_in_use
+    # behind each of the two busy stretches ONE step that enqueues nothing and
+    # collects what is in flight, before the engine sleeps or returns; the
+    # late request's chunk and only decode run are one step between them
+    assert [a["step"] for a in by["serve.step"] if not a["occupied"]] \
+        == [res.steps - 2, res.steps]
     # four slots, a pool for three of these requests: the queue's head waited
     # for pages while a slot was free, never for a slot
     assert c.blocked_on_pool > 0 and c.blocked_on_slots == 0
@@ -188,9 +197,10 @@ def test_a_run_cut_from_on_event_leaves_no_span_open(tiny_engine, tmp_path,
             if payload["step"] == 3:
                 raise Cut
 
+    requests = _requests()
     with jax.profiler.trace(str(tmp_path)):
         with pytest.raises(Cut):
-            _run(tiny_engine, monkeypatch, on_event)
+            _run(tiny_engine, monkeypatch, on_event, requests)
         with jax.profiler.TraceAnnotation("serve.after"):
             pass
     (spans,) = _host_spans(tmp_path, "serve.").values()
@@ -205,6 +215,16 @@ def test_a_run_cut_from_on_event_leaves_no_span_open(tiny_engine, tmp_path,
     (written,) = [attrs for n, _, _, attrs in spans if n == "serve.counters"]
     assert {k: written[k] for k in asdict(seen["counters"])} == asdict(seen["counters"])
     assert seen["step"] == 3 and written["decode_steps"] > 0
+    # the cut leaves a step in flight: its tokens never reach the host, and a
+    # request is finished only when its last token has — none reads finished
+    # with a short list, and what the host holds is stamped token for token
+    assert written["decode_slot_tokens"] + written["admitted"] \
+        > sum(len(r.tokens) for r in requests) > 0
+    for r in requests:
+        assert len(r.tokens) == len(r.token_times) <= r.max_new
+        assert (r.finished >= 0) == (len(r.tokens) == r.max_new)
+    assert any(r.finished >= 0 for r in requests) \
+        and any(r.tokens and r.finished < 0 for r in requests)
 
 
 # ---- train/data.py and train/trainer.py ------------------------------------

@@ -276,6 +276,139 @@ def test_prefill_kv_pages_is_its_arithmetic(tiny_engine, chunk, page):
     assert c.prefill_kv_pages >= sum(-(-n // page) for n in CHUNK_PROMPTS)
 
 
+# The loop enqueues step n+1 on counts before it reads step n's tokens: the
+# cases the split into enqueue and collect can get wrong, each against plain
+# un-paged, un-chunked greedy decoding. (prompt length, max_new) a request,
+# all due at once; page 8, chunk 8.
+LOOKAHEAD_CASES = {
+    # one slot: the next tenant's first chunk is enqueued while the old
+    # tenant's last token is still unfetched
+    "slot_readmitted_in_the_next_step": dict(
+        slots=1, pool=48, reqs=[(5, 3), (11, 4), (8, 2), (3, 5)]),
+    # finished at the prefill's fetch: no decode run ever carries the slot
+    "max_new_1": dict(slots=2, pool=48, reqs=[(5, 1), (19, 1), (8, 3), (16, 1)]),
+    "prompts_of_exactly_k_chunks": dict(
+        slots=3, pool=48, reqs=[(8, 4), (16, 4), (24, 4), (32, 2)]),
+    # three slots, pages for one request: admission waits for the pages that
+    # a COUNTED finish releases, never for a slot
+    "pool_for_one_request": dict(slots=3, pool=3, reqs=[(12, 4), (9, 7), (16, 2)]),
+    "hybrid": dict(slots=2, pool=48, hybrid=True,
+                   reqs=[(7, 4), (17, 1), (16, 6), (33, 3)]),
+}
+
+
+@pytest.mark.serve
+@pytest.mark.parametrize("case", sorted(LOOKAHEAD_CASES))
+def test_lookahead_serves_the_unchunked_oracles_tokens(tiny_engine, case):
+    import jax
+    import numpy as np
+
+    from tf_operator_tpu.serve.engine import (
+        Request,
+        ServeConfig,
+        ServeEngine,
+        greedy_reference_gaps,
+    )
+
+    spec = LOOKAHEAD_CASES[case]
+    cfg, params, tol = tiny_engine.cfg, tiny_engine.params, 0.0
+    if spec.get("hybrid"):  # linear layers beside the attending ones
+        from test_olmo_hybrid import LOGIT_TOL as tol, TINY
+
+        from tf_operator_tpu.models import transformer as tr
+
+        cfg = tr.preset("olmo-hybrid-7b", **TINY)
+        params = jax.jit(lambda k: tr.init_transformer(k, cfg))(jax.random.PRNGKey(5))
+    engine = ServeEngine(cfg, params, ServeConfig(
+        page_size=8, pool_pages=spec["pool"], max_slots=spec["slots"],
+        prefill_chunk=8))
+    rng = np.random.RandomState(17)
+    reqs = [Request(rid=i, prompt=[int(t) for t in rng.randint(1, 256, n)],
+                    max_new=m) for i, (n, m) in enumerate(spec["reqs"])]
+    res = engine.run(reqs, clock=_fake_clock())
+    assert res.completed == len(reqs)
+    assert res.free_pages_start == res.free_pages_end  # pages all returned
+    assert res.generated_tokens == sum(m for _, m in spec["reqs"])
+    for req in reqs:
+        assert req.finished >= 0 and len(req.tokens) == req.max_new
+        assert req.arrival <= req.admitted <= req.first_token <= req.finished
+        assert req.token_times == sorted(req.token_times)
+        assert (req.token_times[0], req.token_times[-1]) == (req.first_token, req.finished)
+        n_exact, max_gap = greedy_reference_gaps(cfg, engine.params, req.prompt, req.tokens)
+        assert max_gap <= tol and (tol or n_exact == len(req.tokens)), req.rid
+    # a busy engine never waits for a token before it enqueues the next run:
+    # every run but the first went out ahead, the re-admissions included
+    c = res.counters
+    assert c.runs_enqueued_ahead == c.prefill_chunks + c.decode_steps - 1
+    assert c.idle_sleeps == 0
+    if case == "pool_for_one_request":
+        assert c.blocked_on_pool > 0 and c.blocked_on_slots == 0
+        assert res.pool_peak_in_use <= 3
+    if case == "slot_readmitted_in_the_next_step":
+        assert c.blocked_on_slots > 0 and c.blocked_on_pool == 0
+
+
+class _ReadNoted:
+    """A program's token array that notes when the host first reads it."""
+
+    def __init__(self, arr, log, n):
+        self.arr, self.log, self.n = arr, log, n
+
+    def __array__(self, dtype=None, copy=None):
+        import numpy as np
+
+        self.log.append(("read", self.n))
+        return np.asarray(self.arr, dtype)
+
+
+def _recorded(engine, log):
+    """Both programs of ``engine`` wrapped: every call is noted with its
+    ordinal, and the token array it returns notes its host read."""
+    def wrap(program):
+        def call(*args):
+            n = sum(kind == "call" for kind, _ in log)
+            log.append(("call", n))
+            pools, state, tok = program(
+                *(a.arr if isinstance(a, _ReadNoted) else a for a in args))
+            return pools, state, _ReadNoted(tok, log, n)
+        return call
+
+    engine._prefill, engine._decode = wrap(engine._prefill), wrap(engine._decode)
+
+
+@pytest.mark.serve
+def test_a_run_is_enqueued_before_the_run_before_it_is_read(tiny_engine):
+    """ONE request of P chunks and N tokens alone: P + N - 1 program runs
+    (the N - 1 decode runs behind the P chunks), every one but the first
+    enqueued while the run before it is unread — ``runs_enqueued_ahead`` =
+    P + N - 2 — and never two: the loop is one step deep. Non-last chunks
+    hand the host nothing to read."""
+    from tf_operator_tpu.serve.engine import Request
+
+    chunks, tokens = 3, 6
+    engine = _chunk_engine(tiny_engine, 8)
+    log = []
+    _recorded(engine, log)
+    req = Request(rid=0, prompt=[7] * (8 * chunks - 3), max_new=tokens)
+    res = engine.run([req], clock=_fake_clock())
+    assert len(req.tokens) == tokens and res.completed == 1
+    runs = chunks + tokens - 1
+    assert [n for kind, n in log if kind == "call"] == list(range(runs))
+    # read: the last chunk's array (the first token), then every decode run's
+    assert [n for kind, n in log if kind == "read"] == list(range(chunks - 1, runs))
+    at = {entry: i for i, entry in enumerate(log)}
+    for n in range(chunks - 1, runs - 1):
+        assert at["call", n + 1] < at["read", n]
+    # the last chunk and the first decode run are ONE step (two runs ahead of
+    # the first read); from there calls and reads alternate
+    for n in range(chunks, runs - 2):
+        assert at["read", n] < at["call", n + 2]
+    c = res.counters
+    assert (c.prefill_chunks, c.decode_steps) == (chunks, tokens - 1)
+    assert c.runs_enqueued_ahead == chunks + tokens - 2
+    assert res.steps == chunks + tokens - 1  # + the step that only collects
+
+
 @pytest.mark.serve
 def test_engine_rejects_impossible_requests(tiny_engine):
     from tf_operator_tpu.serve.engine import Request

@@ -9,6 +9,13 @@ sequences immediately (pages back to the free list the same step — the
 next admission reuses them copy-free). There is no drain-the-batch
 barrier anywhere.
 
+The loop keeps ONE step ahead of the device (``run`` says how): the
+token every slot decodes from is an ``int32[max_slots]`` array that
+stays on the device — each program run takes the array the run before
+returned and returns the next (the decode step writes its active slots,
+a chunk its own slot), undonated — and the host reads the same arrays
+one step late.
+
 Two compiled functions, both fixed-shape:
 
 - the DECODE step: every slot advances one token. Each layer computes
@@ -26,7 +33,8 @@ Two compiled functions, both fixed-shape:
   the sequence's pages are walked once for the whole chunk, and prefill
   has no attention implementation of its own — a decode step is the
   same kernel with a tile of one row a slot. The last chunk's final
-  logits yield the request's first generated token (the TTFT boundary).
+  logits yield the request's first generated token (the TTFT boundary),
+  written into the slot's entry of the token array.
 
 What the engine serves: a dense decoder of models/transformer.py — GQA
 layers with or without rotary embedding (a NoPE layer), pre- or post-norm
@@ -68,7 +76,8 @@ artifact pin against.
 ``run`` is instrumented with ``jax.profiler.TraceAnnotation`` spans
 (``serve.admit``, ``serve.step`` and its children ``serve.prefill``,
 ``serve.prefill_fetch``, ``serve.decode_prep``, ``serve.decode``,
-``serve.decode_fetch``; ``serve.idle``; the closing ``serve.counters``);
+``serve.decode_fetch`` — the two fetches of a step are of the step
+BEFORE it; ``serve.idle``; the closing ``serve.counters``);
 inside both programs the mixers carry ``jax.named_scope``s
 (``serve.lin_mixer`` / ``serve.full_attn``: metadata on the compiled
 instructions, nothing at run time).
@@ -84,7 +93,7 @@ import re
 import time
 from collections import deque
 from dataclasses import asdict, dataclass, field
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -144,6 +153,10 @@ class EngineCounters:
     decode_steps: int = 0      # calls of the decode program
     decode_slot_tokens: int = 0  # tokens they produced (active slots, summed)
     idle_sleeps: int = 0       # sleeps of an empty engine waiting for an arrival
+    # program runs (chunks and decode runs) enqueued while the host had not
+    # yet read the result of the run before: the device had its next program
+    # before the host looked at this one's tokens
+    runs_enqueued_ahead: int = 0
     # a model with linear layers (0 without): its slots' recurrent state
     state_resets: int = 0      # first chunks: a slot's state started from zeros
     prefill_state_carries: int = 0  # later chunks: started from the slot's state
@@ -295,15 +308,29 @@ def pallas_grid_steps(jaxpr) -> int:
 
 
 class _Slot:
-    __slots__ = ("req", "pages", "seq_len", "prefill_pos", "cur_tok", "generated")
+    """A batch slot's tenant as COUNTS of what has been enqueued for it:
+    the scheduler never waits for a token's value."""
+
+    __slots__ = ("req", "pages", "seq_len", "prefill_pos", "generated")
 
     def __init__(self, req: Request, pages: SequencePages):
         self.req = req
         self.pages = pages
-        self.seq_len = 0        # K/V positions written
-        self.prefill_pos = 0    # prompt tokens consumed
-        self.cur_tok = -1       # pending input token once decoding
-        self.generated = 0
+        self.seq_len = 0        # K/V positions whose write is enqueued
+        self.prefill_pos = 0    # prompt tokens whose chunk is enqueued
+        self.generated = 0      # output tokens whose run is enqueued
+
+
+class _Unread(NamedTuple):
+    """A program run's token array that the host has yet to read."""
+
+    fetch: str              # the span its fetch is timed under
+    attrs: Dict[str, Any]   # ... and that span's attributes
+    run: int                # the run's ordinal among the engine run's runs
+    tokens: Any             # int32[max_slots], on the device
+    # whose token it holds and where — the request, not the slot's tenant:
+    # the slot may have its next one by the time the array is read
+    owners: List[Tuple[Request, int]]
 
 
 class ServeEngine:
@@ -430,11 +457,14 @@ class ServeEngine:
             return kp, vp, state, x
 
         def decode_step(params, pools, state, table, seq_lens, tokens, active):
-            """One token for every slot. tokens[i] sits at position
-            seq_lens[i]; returns next greedy token per slot. ``pools`` is
-            the (K, V) pair of page pools, ``state`` the linear layers'
-            (recurrent state, convolution tail) pair, or () for a model
-            without; both come back updated."""
+            """One token for every ACTIVE slot. tokens[i] sits at position
+            seq_lens[i]; returns the token each slot decodes from NEXT: an
+            active slot's greedy choice, any other slot's entry as it came
+            in — the array is the next run's ``tokens`` as it stands, and
+            the host reads the same array later. ``pools`` is the (K, V)
+            pair of page pools, ``state`` the linear layers' (recurrent
+            state, convolution tail) pair, or () for a model without; both
+            come back updated."""
             kp, vp = pools
             s = tokens.shape[0]
             x = params["embed"][tokens]
@@ -464,17 +494,23 @@ class ServeEngine:
             kp, vp, state, x = _body(
                 params, kp, vp, state, x, pos, attend, pid, pos % ps, linear)
             logits = _rms_norm(x, params["final_norm"], eps) @ _head(params, cfg).T
-            return (kp, vp), state, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (kp, vp), state, jnp.where(active, nxt, tokens)
 
         def prefill_chunk(params, pools, state, table_row, start, tokens_c,
-                          n_valid, slot=None):
-            """One chunk of one sequence's prompt: its C positions are one
-            query tile over the sequence's page-table row, causal per row;
-            rows past ``n_valid`` lie past the sequence's length, see
-            nothing and write to the trash page. The linear layers carry
-            the sequence's state in batch slot ``slot`` from chunk to
-            chunk; the first chunk (``start == 0``) starts from zeros. A
-            model without linear layers is called without ``slot``."""
+                          n_valid, tokens, slot):
+            """One chunk of the prompt of the sequence in batch slot
+            ``slot``: its C positions are one query tile over the
+            sequence's page-table row, causal per row; rows past
+            ``n_valid`` lie past the sequence's length, see nothing and
+            write to the trash page. The linear layers carry the
+            sequence's state in the slot from chunk to chunk; the first
+            chunk (``start == 0``) starts from zeros. Returns ``tokens``
+            (the decode step's operand, one entry a slot) with the slot's
+            entry set to the greedy token after the chunk's last valid
+            row: the sequence's first generated token when the chunk is
+            its prompt's last, and read by nothing before that chunk has
+            written it."""
             kp, vp = pools
             c = tokens_c.shape[0]
             idx = jnp.arange(c)
@@ -512,7 +548,8 @@ class ServeEngine:
                 params, kp, vp, state, x, pos, attend, pid, pos % ps, linear)
             last = _rms_norm(x[n_valid - 1], params["final_norm"], eps)
             logits = last @ _head(params, cfg).T
-            return (kp, vp), state, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (kp, vp), state, tokens.at[slot].set(tok)
 
         self._decode = jax.jit(decode_step, donate_argnums=(1, 2))
         self._prefill = jax.jit(prefill_chunk, donate_argnums=(1, 2))
@@ -566,7 +603,8 @@ class ServeEngine:
             "prefill": (self._prefill, (
                 self.params, pools, state, arr((p,), i32), arr((), i32),
                 arr((scfg.prefill_chunk,), i32), arr((), i32),
-            ) + ((arr((), i32),) if state else ())),
+                arr((s_n,), i32), arr((), i32),
+            )),
         }
         out: Dict[str, Any] = {}
         for name, (fn, args) in programs.items():
@@ -612,7 +650,23 @@ class ServeEngine:
         "admitted"/"first_token"/"finished" (payload: the Request) and
         "step" (payload: dict with step/active/waiting/completed/
         generated/free_pages and the live ``EngineCounters``) — the
-        workload's span + live-count seam."""
+        workload's span + live-count seam.
+
+        The loop runs ONE engine step ahead of the device. A request has
+        no stop token, so who decodes at which position, who finishes and
+        whose pages come back are arithmetic on counts the host holds;
+        only the next run's embedding lookup needs a token's VALUE, and
+        the token array stays on the device, each run handing the next
+        its operand. So a loop iteration ENQUEUES step n+1 (a chunk per
+        prefilling slot, the decode run) and only then COLLECTS step n:
+        blocks on its token arrays in device order, stamps and appends
+        the tokens, and marks a request finished when its last token is
+        on the host. A slot and its pages are released when the
+        sequence's last token has been enqueued — a later program cannot
+        overtake the one that writes it. With nothing in flight (the
+        first step, the first after the engine was empty) this is the
+        synchronous loop; an engine that runs empty collects what is in
+        flight, in a step that enqueues nothing, before it sleeps."""
         import jax.numpy as jnp
         from jax.profiler import TraceAnnotation as span
 
@@ -639,6 +693,8 @@ class ServeEngine:
         state = () if self.store is None else self.store.fresh()
         n_lin = 0 if self.store is None else self.store.n_layers
         s_n = scfg.max_slots
+        # the token each slot decodes from next; every run returns the next's
+        toks = jnp.zeros(s_n, jnp.int32)
         table = np.full((s_n, self.max_pages_per_seq), pool.trash_page - 1,
                         np.int32)
         slots: List[Optional[_Slot]] = [None] * s_n
@@ -649,8 +705,10 @@ class ServeEngine:
         counters = EngineCounters()
         t0 = clock()
         step = 0
-        completed = 0
-        generated = 0
+        completed = 0   # requests whose last token is on the host
+        generated = 0   # tokens on the host
+        fetched = 0     # program runs the host has seen the device reach
+        flight: List[_Unread] = []  # the step in flight, in device order
 
         def _try_admit(now: float) -> None:
             while waiting:
@@ -675,21 +733,31 @@ class ServeEngine:
                 counters.admitted += 1
                 emit("admitted", req)
 
-        def _finish(i: int, now: float) -> None:
-            """Mark slot i's request complete and release the slot and
-            its pages IMMEDIATELY (reusable this very step)."""
-            nonlocal completed
-            sl = slots[i]
-            sl.req.finished = now
-            completed += 1
-            emit("finished", sl.req)
-            sl.pages.release(pool)
-            table[i, :] = pool.trash_page - 1
-            slots[i] = None
+        def _count_run() -> int:
+            """One more program run goes to the device; returns its
+            ordinal. It runs AHEAD when the host has not yet read the
+            result of the run before it."""
+            runs = counters.prefill_chunks + counters.decode_steps
+            if fetched < runs:
+                counters.runs_enqueued_ahead += 1
+            return runs + 1
 
-        def _prefill_chunks() -> None:
+        def _release_if_done(i: int) -> None:
+            """The run that writes slot i's last token is enqueued: the
+            slot and its pages are free from the next admission on."""
+            sl = slots[i]
+            if sl.generated >= sl.req.max_new:
+                sl.pages.release(pool)
+                table[i, :] = pool.trash_page - 1
+                slots[i] = None
+
+        # Host arrays go to the programs as numpy (no transfer program of
+        # their own), and what the loop goes on to write is copied first:
+        # the CPU backend may alias a numpy operand instead of copying it.
+
+        def _prefill_chunks(ahead: List[_Unread]) -> None:
             """One chunk per still-prefilling slot."""
-            nonlocal pools, state, generated
+            nonlocal pools, state, toks
             c = scfg.prefill_chunk
             for i, sl in enumerate(slots):
                 if sl is None or sl.prefill_pos >= len(sl.req.prompt):
@@ -705,10 +773,11 @@ class ServeEngine:
                           last=int(last), kv_pages=kv_pages):
                     buf = np.zeros(c, np.int32)
                     buf[:n_valid] = chunk
-                    pools, state, tok = self._prefill(
-                        self.params, pools, state, jnp.asarray(table[i]),
-                        jnp.int32(sl.prefill_pos), jnp.asarray(buf),
-                        jnp.int32(n_valid), *((jnp.int32(i),) if n_lin else ()),
+                    run_no = _count_run()
+                    pools, state, toks = self._prefill(
+                        self.params, pools, state, table[i].copy(),
+                        np.int32(sl.prefill_pos), buf, np.int32(n_valid),
+                        toks, np.int32(i),
                     )
                 if n_lin:
                     if sl.prefill_pos == 0:
@@ -723,22 +792,14 @@ class ServeEngine:
                 sl.seq_len = sl.prefill_pos
                 if last:
                     # last chunk's logits ARE the first generated token
-                    t_tok = clock() - t0
-                    with span("serve.prefill_fetch", rid=sl.req.rid):
-                        first = int(tok)
-                    sl.req.tokens.append(first)
-                    sl.req.token_times.append(t_tok)
-                    sl.req.first_token = t_tok
                     sl.generated = 1
-                    sl.cur_tok = first
-                    generated += 1
-                    emit("first_token", sl.req)
-                    if sl.generated >= sl.req.max_new:
-                        _finish(i, t_tok)
+                    ahead.append(_Unread("serve.prefill_fetch", {"rid": sl.req.rid},
+                                         run_no, toks, [(sl.req, i)]))
+                    _release_if_done(i)
 
-        def _decode_step() -> None:
+        def _decode_step(ahead: List[_Unread]) -> None:
             """One batched step over the decoding slots."""
-            nonlocal pools, state, generated
+            nonlocal pools, state, toks
             dec = [
                 (i, sl) for i, sl in enumerate(slots)
                 if sl is not None
@@ -749,31 +810,45 @@ class ServeEngine:
                 return
             with span("serve.decode_prep", active=len(dec)):
                 active = np.zeros(s_n, bool)
-                toks = np.zeros(s_n, np.int32)
                 lens = np.zeros(s_n, np.int32)
                 for i, sl in dec:
                     active[i] = True
-                    toks[i] = sl.cur_tok
                     lens[i] = sl.seq_len
-                args = (jnp.asarray(table), jnp.asarray(lens),
-                        jnp.asarray(toks), jnp.asarray(active))
+                args = (table.copy(), lens, toks, active)
             with span("serve.decode", active=len(dec), slots=s_n):
-                pools, state, nxt = self._decode(self.params, pools, state, *args)
+                run_no = _count_run()
+                pools, state, toks = self._decode(self.params, pools, state, *args)
             counters.decode_steps += 1
             counters.decode_slot_tokens += len(dec)
             counters.lin_slot_steps += n_lin * len(dec)
-            with span("serve.decode_fetch"):
-                nxt = np.asarray(nxt)
-            t_tok = clock() - t0
+            ahead.append(_Unread("serve.decode_fetch", {}, run_no, toks,
+                                 [(sl.req, i) for i, sl in dec]))
             for i, sl in dec:
                 sl.seq_len += 1
                 sl.generated += 1
-                sl.cur_tok = int(nxt[i])
-                sl.req.tokens.append(sl.cur_tok)
-                sl.req.token_times.append(t_tok)
-                generated += 1
-                if sl.generated >= sl.req.max_new:
-                    _finish(i, t_tok)
+                _release_if_done(i)
+
+        def _collect(unread: List[_Unread]) -> None:
+            """Block on a step's token arrays in device order; a token is
+            stamped with the clock at its fetch's return, a request is
+            finished when its last token is on the host."""
+            nonlocal fetched, generated, completed
+            for u in unread:
+                with span(u.fetch, **u.attrs):
+                    host = np.asarray(u.tokens)
+                now = clock() - t0
+                fetched = u.run
+                for req, i in u.owners:
+                    req.tokens.append(int(host[i]))
+                    req.token_times.append(now)
+                    generated += 1
+                    if len(req.tokens) == 1:
+                        req.first_token = now
+                        emit("first_token", req)
+                    if len(req.tokens) >= req.max_new:
+                        req.finished = now
+                        completed += 1
+                        emit("finished", req)
 
         try:
             while completed < len(requests):
@@ -783,7 +858,7 @@ class ServeEngine:
                         waiting.append(pending.popleft())
                     _try_admit(now)
                 busy = [sl for sl in slots if sl is not None]
-                if not busy:
+                if not busy and not flight:
                     if pending:
                         # idle until the next arrival — a serving engine,
                         # not a busy loop.
@@ -794,9 +869,10 @@ class ServeEngine:
                             )
                     continue
                 # attributes as the step STARTS, after this boundary's
-                # admissions; the span's self time (duration less its
-                # children) is the bookkeeping: token appends, _finish,
-                # the on_event callbacks.
+                # admissions (all 0 in the step that only collects, behind
+                # an engine run empty); the fetches inside are of the step
+                # BEFORE; the span's self time (duration less its children)
+                # is the bookkeeping: token appends, the on_event callbacks.
                 with span(
                     "serve.step", step=step + 1, occupied=len(busy),
                     waiting=len(waiting), free_pages=pool.free_count,
@@ -804,12 +880,16 @@ class ServeEngine:
                     kv_reserved=scfg.page_size
                     * sum(len(sl.pages.pages) for sl in busy),
                 ):
-                    _prefill_chunks()
-                    _decode_step()
+                    ahead: List[_Unread] = []
+                    _prefill_chunks(ahead)
+                    _decode_step(ahead)
+                    _collect(flight)
+                    flight = ahead
                     step += 1
                     emit("step", {
                         "step": step,
-                        "active": sum(1 for sl in slots if sl is not None),
+                        # admitted, last token not yet on the host
+                        "active": counters.admitted - completed,
                         "waiting": len(waiting) + len(pending),
                         "completed": completed,
                         "generated": generated,
