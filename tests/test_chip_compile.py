@@ -84,12 +84,12 @@ def test_compiled_kernels_cut_instance_suffix_and_transform_wrappers():
     text = "\n".join([
         f"  %flash_fwd.3 = (bf16[2,8]{{1,0}}, f32[2]{{0}}) custom-call(%a), {call}",
         f"  %jvp_flash_fwd_ = bf16[2]{{0}} custom-call(%a, %flash_fwd.3), {call}",
-        f"  ROOT %transpose_jvp_flash_bwd_dq__.1 = bf16[2]{{0}} custom-call(%b), {call}",
+        f"  ROOT %transpose_jvp_flash_bwd_dqkv__.1 = bf16[2]{{0}} custom-call(%b), {call}",
         f"  %gmm_dw_scaled.7 = f32[4]{{0}} custom-call(%c), {call}",
         '  %s = f32[] custom-call(%flash_fwd.3), custom_call_target="Sharding"',
     ])
     assert compiled_kernels(text) == {
-        "flash_bwd_dq": 1, "flash_fwd": 2, "gmm_dw_scaled": 1}
+        "flash_bwd_dqkv": 1, "flash_fwd": 2, "gmm_dw_scaled": 1}
     assert compiled_kernels("ENTRY %main { ROOT %x = f32[] add(%a, %b) }") == {}
     # a clone the compiler rebuilds for want of memory is one more run of
     # that kernel, under the kernel's name
@@ -162,11 +162,10 @@ def test_flash_attention_compiles_for_v5e(one_chip, no_cache, shape, bwd):
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if bwd else fwd
     names = _kernel_names(fn, spec(h), spec(h_kv), spec(h_kv))
-    assert len(names) >= (3 if bwd else 1)  # fwd; + dq and dk/dv kernels
     # each kernel under its own name, whatever jvp/transpose/remat wrapper
-    # the call sits in (``transpose_jvp_flash_bwd_dq__`` still carries it)
-    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")[: 3 if bwd else 1]:
-        assert sum(kernel in n for n in names) == 1, (kernel, names)
+    # the call sits in (``transpose_jvp_flash_bwd_dqkv__`` still carries
+    # it): the forward, and ONE backward kernel for dq, dk and dv
+    assert names == ["flash_bwd_dqkv", "flash_fwd"][0 if bwd else 1:]
 
 
 @pytest.mark.parametrize("policy, fwd_runs", [
@@ -178,7 +177,7 @@ def test_flash_kernels_keep_their_names_under_remat_in_a_scan(
     replayed), both under ``flash_fwd``; before the kernels had names these
     read ``closed_call``, ``rematted_computation`` and ``checkpoint``. A
     policy that names the forward's two outputs keeps them for the backward
-    kernels, and the replay is gone (what every ``*_mid`` tier does)."""
+    kernel, and the replay is gone (what every ``*_mid`` tier does)."""
     b, t, h, h_kv, d = 2, 1024, 8, 2, 128
     policies = jax.checkpoint_policies
     policy = (policies.nothing_saveable if policy == "nothing_saveable" else
@@ -201,14 +200,13 @@ def test_flash_kernels_keep_their_names_under_remat_in_a_scan(
 
     names = _kernel_names(jax.grad(loss, argnums=(0, 1, 2)),
                           spec(h), spec(h_kv), spec(h_kv))
-    assert names == (["flash_bwd_dkv", "flash_bwd_dq"]
-                     + ["flash_fwd"] * fwd_runs)
+    assert names == ["flash_bwd_dqkv"] + ["flash_fwd"] * fwd_runs
 
 
 def test_flash_kernels_compile_at_latent_widths_for_v5e(one_chip, no_cache):
     """Latent attention's shapes at the JoyAI cell's sizes: q and k 192 wide
     (not a lane multiple: Mosaic pads it), v and o 128, 32 heads, two
-    8,192-token rows, forward and both backward kernels under their names."""
+    8,192-token rows, the forward and the backward kernel under their names."""
     def spec(width):
         return jax.ShapeDtypeStruct((2, 8192, 32, width), jnp.bfloat16,
                                     sharding=one_chip)
@@ -222,8 +220,7 @@ def test_flash_kernels_compile_at_latent_widths_for_v5e(one_chip, no_cache):
 
     names = _kernel_names(jax.grad(loss, argnums=(0, 1, 2)),
                           spec(192), spec(192), spec(128))
-    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
-        assert sum(kernel in n for n in names) == 1, (kernel, names)
+    assert names == ["flash_bwd_dqkv", "flash_fwd"]
 
 
 # the two share cells' whole steps: config file, traffic mix, what the parent
@@ -292,9 +289,8 @@ def test_share_cell_step_fits_v5e_and_holds_nothing_bound_sized(topo, no_cache, 
             (int(mix["batch_size"]), int(mix["seq_len"])), "int32"))
     text = compiled.as_text()
     kernels = trainer.step_kernels
-    assert {k: kernels[k] for k in
-            ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")} == dict.fromkeys(
-        ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"), spec["flash"])
+    assert {k: n for k, n in kernels.items() if k.startswith("flash_")} == {
+        "flash_fwd": spec["flash"], "flash_bwd_dqkv": spec["flash"]}
     assert {"gmm_fwd", "gmm_fwd_scaled", "gmm_dx", "gmm_dw",
             "gmm_dw_scaled"} <= set(kernels), kernels
     for rows in spec["bound_rows"]:
@@ -386,8 +382,7 @@ def test_step_kernels_say_what_a_remat_tier_replays_on_v5e(topo, no_cache, remat
     trainer, _ = _compiled_lm_trainer(
         cfg, {"fsdp": 1}, list(topo.devices)[:1], (2, 512))
     assert trainer.step_kernels == {
-        "flash_bwd_dkv": 1, "flash_bwd_dq": 1,
-        "flash_fwd": STEP_FLASH_FWD[remat]}
+        "flash_bwd_dqkv": 1, "flash_fwd": STEP_FLASH_FWD[remat]}
     assert trainer.step_remats == 0  # a step this small fits under any tier
 
 

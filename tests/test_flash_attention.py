@@ -189,7 +189,7 @@ def test_lse_entry_matches_reference(causal, h, h_kv):
 @pytest.mark.parametrize("causal", [False, True])
 def test_lse_entry_grads_through_lse(causal):
     """Gradients THROUGH the lse output: the lse cotangent folds into the
-    backward kernels' delta term (ds = p·(dp − (delta − g))) — the
+    backward kernel's delta term (ds = p·(dp − (delta − g))) — the
     contract ring attention's merge relies on. Tolerances are f32-rounding
     scale: both paths sit ~1e-2 relative from the f64 truth on the
     squared-sum scalar (measured; the kernel is marginally CLOSER), so
@@ -269,7 +269,7 @@ def test_gqa_g3_kernel_matches_oracle():
 @pytest.mark.parametrize("causal", [False, True])
 def test_gqa_kernel_grads_match_oracle(causal):
     """dk/dv must accumulate ALL query heads of a group (the fused
-    (group, q-block) grid dim in _bwd_dkv_kernel) — a missed member
+    (group, q-block) grid dim in _bwd_kernel) — a missed member
     under-counts dk/dv by its contribution."""
     q, k, v = _gqa_qkv(jax.random.PRNGKey(5), b=1, t=64, h=4, h_kv=2, d=32)
 

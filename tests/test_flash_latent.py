@@ -1,4 +1,4 @@
-"""The three flash kernels with a v width of its own (latent attention: q/k
+"""The flash kernels (forward and fused backward) with a v width of its own (latent attention: q/k
 192 wide, v 128) in interpret mode against the jnp oracle — values and
 gradients, causal, one key a query head and grouped — and equality with
 the equal-width kernels: v zero-padded to the q/k width goes through the
@@ -72,3 +72,40 @@ def test_dispatch_refuses_mismatched_q_k_widths_and_gates_each_width():
     out = fa.flash_attention(q, k, v, causal=True)
     assert out.shape == (2, 64, 2, 40)
     assert bool(jnp.all(out == fa.reference_attention(q, k, v, causal=True)))
+
+
+# ---- the fused backward at unequal widths (PR 43) --------------------------
+# dq's HBM tiles are whole lanes wide (192 → 256 on the chip; 48 → 128 here),
+# dk is q/k wide and dv v wide, from one pair's p and ds.
+
+LATENT_BWD_CASES = {
+    "causal": dict(),
+    "full": dict(causal=False),
+    "g4-window-2.5-blocks": dict(h=8, window=160),
+    "walks-of-1": dict(t=64),
+    "walks-of-2": dict(t=128),
+    "lse-cotangent-window-one-block": dict(dlse=True, window=64),
+    "v-wider-than-q": dict(widths=(32, 48)),
+}
+
+
+def _latent_case(name):
+    from tests.test_flash_backward import fused_bwd_case
+
+    differs = dict(LATENT_BWD_CASES[name])
+    d, dv = differs.pop("widths", (48, 32))
+    return fused_bwd_case(name, differs, d=d, dv=dv)
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_BWD_CASES))
+def test_fused_backward_at_unequal_widths_matches_autodiff(name):
+    from tests.test_flash_backward import check_fused_bwd_against_autodiff
+
+    check_fused_bwd_against_autodiff(_latent_case(name))
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_BWD_CASES))
+def test_fused_backward_at_unequal_widths_equals_the_two_kernels(name):
+    from tests.test_flash_backward import check_fused_bwd_against_two_kernels
+
+    check_fused_bwd_against_two_kernels(_latent_case(name))

@@ -205,7 +205,7 @@ def test_trace_readers_and_flop_counts_at_the_published_sizes(root):
     from tests.test_smallthinker_cell import _fake_trace
 
     trace = _fake_trace({
-        "flash_fwd.2": 0.2, "flash_bwd_dq.1": 0.1, "flash_bwd_dkv.1": 0.1,
+        "flash_fwd.2": 0.2, "flash_bwd_dqkv.1": 0.2,  # found by the reader's ``flash_bwd_dq``
         "gmm_fwd.3": 0.05, "gmm_dw.2": 0.05, "fusion.77": 0.4}, busy_s=1.0)
     sizes = dict(vocab=16160, d_model=2048, n_layers=5, n_dense=1, n_heads=32,
                  q_rank=1536, kv_rank=512, nope=128, rope=64, v_dim=128, d_ff=768,

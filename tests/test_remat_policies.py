@@ -178,7 +178,7 @@ def _pallas_calls(fn, *args) -> dict:
 
     text = str(jax.make_jaxpr(fn)(*args))
     return {name: len(re.findall(rf"name={name}\b", text))
-            for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+            for name in ("flash_fwd", "flash_bwd_dqkv")}
 
 
 @pytest.mark.parametrize("remat, fwd_runs", [
@@ -187,11 +187,11 @@ def _pallas_calls(fn, *args) -> dict:
 def test_mid_tiers_keep_the_flash_outputs_and_replay_no_forward(remat, fwd_runs):
     """The gradient of a scanned, rematerialised flash layer holds
     ``flash_fwd`` once under every tier that names ``flash_o`` /
-    ``flash_lse`` (the backward kernels read what the forward wrote) and
+    ``flash_lse`` (the backward kernel reads what the forward wrote) and
     twice under the modes that do not (the program they always were)."""
     loss, W = _scanned_flash_layers(remat)
     assert _pallas_calls(jax.grad(loss), W) == {
-        "flash_fwd": fwd_runs, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+        "flash_fwd": fwd_runs, "flash_bwd_dqkv": 1}
 
 
 @pytest.mark.parametrize("remat", ["save_mid", "save:flash_o,flash_lse"])
@@ -220,7 +220,7 @@ def test_flash_output_names_are_policy_visible(force_kernel):
     named = saved("save:flash_o,flash_lse")
     assert "f32[1,64,2,16]" in named, named
     # flash_attention() drops the lse: the kernel path keeps it as the
-    # backward kernels' residual, the dense path has no reader for it
+    # backward kernel's residual, the dense path has no reader for it
     assert ("f32[1,64,2] named 'flash_lse'" in named) == force_kernel, named
     old_set = saved("save:resid_mid")
     assert "flash_lse" not in old_set and "f32[1,64,2,16]" not in old_set, old_set

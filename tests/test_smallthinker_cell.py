@@ -204,11 +204,17 @@ def test_trace_readers_match_kernels_by_instruction_name(root):
     from benchmarks import flops_smallthinker, kernel_seconds, run
 
     trace = _fake_trace({
-        "flash_fwd.2": 0.2, "flash_bwd_dq.1": 0.1, "flash_bwd_dkv.1": 0.1,
+        "flash_fwd.2": 0.2, "flash_bwd_dqkv.1": 0.2,
         "gmm_fwd.3": 0.05, "gmm_fwd_scaled.1": 0.02, "transpose_jvp_gmm_dx__.4": 0.04,
         "gmm_dw.2": 0.06, "gmm_dw_scaled.1": 0.03, "fusion.77": 0.4}, busy_s=1.0)
     assert kernel_seconds.seconds(trace, "gmm_") == pytest.approx(0.2)
     assert kernel_seconds.seconds(trace, "flash_fwd", "flash_bwd") == pytest.approx(0.4)
+    # the ONE backward kernel since PR 43 answers to the readers' own key
+    # (``flash_window_roofline.KERNELS``), once, and nothing to the old second
+    assert kernel_seconds.seconds(trace, "flash_bwd_dq", "flash_bwd_dkv") == pytest.approx(0.2)
+    assert [n.split()[0] for n in kernel_seconds.names(trace, "flash_bwd_dq")] == [
+        "flash_bwd_dqkv.1"]
+    assert kernel_seconds.names(trace, "flash_bwd_dkv") == []
     assert "fusion.77" not in " ".join(kernel_seconds.names(trace, "gmm_"))
 
     pattern = ((0, False), (4096, True), (4096, True), (4096, True))

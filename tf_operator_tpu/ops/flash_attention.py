@@ -8,15 +8,21 @@ resident, so HBM traffic is O(t·d) and the MXU stays fed. The backward
 pass recomputes P from the saved logsumexp instead of storing it (the
 standard flash recipe), trading FLOPs for HBM exactly as TPUs want.
 
-Kernel structure: the contraction dimension is a GRID dimension, not a
-VMEM-resident loop — grid (b, h_kv, nq, nk) for forward/dq and
-(b, h_kv, nk, nq) for dk/dv, with the running (m, l, acc) state in VMEM
-scratch that persists across the innermost grid dimension (TPU grids
-iterate the last dimension sequentially, which is what makes carried
-scratch sound). VMEM holds only one block of each operand at a time, so
-sequence length is bounded by HBM, not by the ~16 MB VMEM budget. Causal
-grids skip above-diagonal blocks with `pl.when` (zero compute, still one
-grid step).
+Kernel structure: TWO kernels, ``flash_fwd`` and ``flash_bwd_dqkv``. The
+contraction dimension is a GRID dimension, not a VMEM-resident loop —
+grid (b, h_kv, nq, nk) for the forward and (b, h_kv, nk, nq) for the
+backward, with the running (m, l, acc) / (dk, dv) state in VMEM scratch
+that persists across the innermost grid dimension (TPU grids iterate the
+last dimension sequentially, which is what makes carried scratch sound).
+VMEM holds only one block of each operand at a time, so sequence length
+is bounded by HBM, not by the ~16 MB VMEM budget. Causal grids skip
+above-diagonal blocks with `pl.when` (zero compute, still one grid
+step). The backward is ONE kernel over (k block, q block) pairs: a live
+pair builds s, the mask, p, dp and ds once and feeds all three products
+(five dots; a dq kernel beside a dk/dv kernel ran seven and the f32
+vector work twice). dk/dv are the inner walk's scratch; dq, whose q
+block comes round again an inner walk later, accumulates in f32 HBM
+tiles the kernel reads and writes back itself (``_bwd_kernel``).
 
 GQA is folded into the q tile: the grid's head dimension iterates K/V
 heads, and each step's q tile is [g·block_q, d] — the g query heads of
@@ -135,17 +141,35 @@ def _block_live(qb, kb, block_q, block_k, causal, window):
     return live
 
 
-def _gqa_specs(g, block_q, block_k, q_grid_dim):
-    """BlockSpec factories shared by all three folded-GQA grids, each over
+def _nearest_live_q(ki, qb, block_q, block_k, nq, window):
+    """The q block nearest ``qb`` whose pair with k block ``ki`` is live
+    under causal masking — ``_block_live`` solved for the q block: the
+    first holds the k block's first key, the last (with a window) the
+    last row that still sees its last key. A dead pair runs nothing, so
+    it should fetch nothing either: a backward grid step whose pair is
+    dead names THIS block for its q-side tiles — a block whose index did
+    not change is not fetched, and the dead steps in front of a k block's
+    first live pair prefetch it."""
+    first = (ki * block_k) // block_q
+    last = nq - 1
+    if window:
+        last = jnp.minimum(last, (ki * block_k + block_k + window - 2) // block_q)
+    return jnp.clip(qb, first, last)
+
+
+def _gqa_specs(g, block_q, block_k, q_grid_dim, q_block=lambda ki, qb: qb):
+    """BlockSpec factories shared by both folded-GQA grids, each over
     its operand's last (lane) dim: q, k and dq/dk carry the q/k width, v,
     o, do and dv the v width (the two differ under latent attention).
 
     Query-side tiles are (1, g, block_q, last) — the g query heads of kv
     head ``hk`` (contiguous in the h dim) stacked over one sequence
     block. ``q_grid_dim`` says which innermost grid dim walks q blocks:
-    2 for the (b, h_kv, nq, nk) fwd/dq grids, 3 for the (b, h_kv, nk, nq)
-    dk/dv grid; the other innermost dim walks K/V blocks. Returns
-    (q_spec_factory, kv_spec_factory)."""
+    2 for the forward's (b, h_kv, nq, nk) grid, 3 for the backward's
+    (b, h_kv, nk, nq); the other innermost dim walks K/V blocks.
+    ``q_block(ki, qb)`` (backward grid only) is the q block a step's
+    q-side tiles hold: its own, unless ``_bwd`` spares dead pairs the
+    fetch. Returns (q_spec_factory, kv_spec_factory)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -153,7 +177,7 @@ def _gqa_specs(g, block_q, block_k, q_grid_dim):
         q_idx = lambda bi, hk, qi, kb: (bi, hk, qi, 0)
         kv_idx = lambda bi, hk, qi, kb: (bi, hk, kb, 0)
     else:
-        q_idx = lambda bi, hk, ki, qb: (bi, hk, qb, 0)
+        q_idx = lambda bi, hk, ki, qb: (bi, hk, q_block(ki, qb), 0)
         kv_idx = lambda bi, hk, ki, qb: (bi, hk, ki, 0)
 
     def q_spec(shape_last):
@@ -271,87 +295,94 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, window=0):
 
 
 # ---------------------------------------------------------------------------
-# backward kernels — same streaming-grid structure
+# backward kernel — grid (b, h_kv, nk, nq): dk/dv in scratch, dq in HBM
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
-                   *, causal, block_q, block_k, scale, g, window=0):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_hbm, dk_ref, dv_ref, dk_scr, dv_scr, dq_buf, sem, state,
+                *, causal, block_q, block_k, scale, g, window=0):
+    """dq, dk and dv of one (q block, k block) pair from ONE s, mask, p,
+    dp and ds: five dots. The q tile stacks the g query heads of the group
+    ([g·block_q, d]), so the row contractions pᵀ·do and dsᵀ·q sum every
+    group member in one matmul.
+
+    The k block is the OUTER walk: dk/dv accumulate in [block_k, ·] f32
+    scratch across the innermost q-block dim and are written once at its
+    end. dq's accumulator belongs to the q block, which comes round again
+    a whole inner walk later, so it lives in HBM as f32 tiles
+    ``dq_hbm[b, h_kv, nq, g·block_q, d in whole lanes]`` (space ANY) that the
+    kernel reads, adds to and writes back itself: the read is started
+    before the pair's first dot and waited for after its fourth, the
+    write-back is waited for one live pair later, just before the next
+    one starts — at most one read and one write are in flight. A tile's
+    read must not meet its own write-back, and which tile the live pair
+    BEFORE wrote is a matter of the mask, not of the grid's walk: dead
+    steps run nothing, so the last live pair of one k row and the first of
+    the next are neighbours in time (causal with block_q >= block_k, a
+    window of a q block or less, an inner walk of one block: all name the
+    same q block twice running). So ``state`` remembers the q block of the
+    write in flight, and a pair that is about to read that very tile waits
+    for the write first; every other pair pays nothing. A q block's FIRST
+    live pair (k block 0, or the first inside the window) writes without
+    reading, so the buffer needs no zeros; dead pairs move nothing.
+    ``state`` (SMEM) = [a write is in flight, the buffer slot the next
+    pair takes, the q block of the write in flight]."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    qi = pl.program_id(2)
-    kb = pl.program_id(3)
-    nkb = pl.num_programs(3)
-    d, dv = q_ref.shape[-1], v_ref.shape[-1]
-    rows = g * block_q
-
-    @pl.when(kb == 0)
-    def _init():
-        dq_scr[:, :] = jnp.zeros_like(dq_scr)
-
-    live = _block_live(qi, kb, block_q, block_k, causal, window)
-
-    @pl.when(live)
-    def _step():
-        q = q_ref[0].reshape(rows, d).astype(jnp.float32) * scale
-        do = do_ref[0].reshape(rows, dv).astype(jnp.float32)
-        lse = lse_ref[0].reshape(rows, LSE_LANES)[:, :1]      # value replicated on lanes
-        delta = delta_ref[0].reshape(rows, LSE_LANES)[:, :1]
-        k = k_ref[0, 0, :, :].astype(jnp.float32)
-        v = v_ref[0, 0, :, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        if causal:
-            s = _causal_mask(s, qi, kb, block_q, block_k, window)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta)
-        dq_scr[:, :] = dq_scr[:, :] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    @pl.when(kb == nkb - 1)
-    def _finish():
-        dq_ref[0] = (dq_scr[:, :] * scale).reshape(g, block_q, d).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                    dk_scr, dv_scr, *, causal, block_q, block_k, scale, g,
-                    window=0):
-    """dk/dv for one k/v head. The q tile stacks the g query heads of the
-    group ([g·block_q, d]), so the row contraction in p·ᵀdo and ds·ᵀq sums
-    over every group member in one matmul — the [block_k, d] scratch
-    accumulates across the innermost q-block grid dim and writes once at
-    the end (the output block (bi, hk, ki) is revisited only on
-    consecutive grid steps, which is what makes carried scratch and one
-    final write sound on TPU)."""
-    from jax.experimental import pallas as pl
-
-    ki = pl.program_id(2)
-    qb = pl.program_id(3)
+    bi, hk = pl.program_id(0), pl.program_id(1)
+    ki, qb = pl.program_id(2), pl.program_id(3)
     nqb = pl.num_programs(3)
     d, dv = q_ref.shape[-1], v_ref.shape[-1]
     rows = g * block_q
+    ids = (bi, hk, ki, qb)
+
+    @pl.when(sum(ids) == 0)
+    def _start():
+        state[0] = 0
+        state[1] = 0
+        state[2] = -1
 
     @pl.when(qb == 0)
     def _init():
         dk_scr[:, :] = jnp.zeros_like(dk_scr)
         dv_scr[:, :] = jnp.zeros_like(dv_scr)
 
+    def wait_write():
+        @pl.when(state[0] == 1)
+        def _():
+            pltpu.make_async_copy(dq_buf.at[0], dq_hbm.at[0, 0, 0], sem.at[1]).wait()
+            state[0] = 0
+
     # Causal: q-blocks strictly before this k-block see none of it; with
     # a window, neither do q-blocks wholly past it.
     live = _block_live(qb, ki, block_q, block_k, causal, window)
+    first = ki == 0
+    if window:
+        first |= ~_block_live(qb, ki - 1, block_q, block_k, causal, window)
 
     @pl.when(live)
     def _step():
+        slot = state[1]
+        tile, buf = dq_hbm.at[bi, hk, qb], dq_buf.at[slot]
+        read = pltpu.make_async_copy(tile, buf, sem.at[0])
+
+        @pl.when(~first)
+        def _():
+            # the live pair before wrote this very tile: it was this
+            # (b, h_kv)'s, whose first live pair reads nothing
+            @pl.when(state[2] == qb)
+            def _():
+                wait_write()
+
+            read.start()
+
         k = k_ref[0, 0, :, :].astype(jnp.float32)
         v = v_ref[0, 0, :, :].astype(jnp.float32)
         q = q_ref[0].reshape(rows, d).astype(jnp.float32) * scale
         do = do_ref[0].reshape(rows, dv).astype(jnp.float32)
-        lse = lse_ref[0].reshape(rows, LSE_LANES)[:, :1]
+        lse = lse_ref[0].reshape(rows, LSE_LANES)[:, :1]      # value replicated on lanes
         delta = delta_ref[0].reshape(rows, LSE_LANES)[:, :1]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -361,7 +392,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         p = jnp.exp(s - lse)  # [g·bq, bk]
         dv_scr[:, :] = dv_scr[:, :] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bk, d] — row contraction sums the whole group
+        )  # [bk, dv] — row contraction sums the whole group
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [g·bq, bk]
@@ -369,11 +400,33 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         dk_scr[:, :] = dk_scr[:, :] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )  # [bk, d]
+        dq = jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [g·bq, d]
+
+        @pl.when(first)
+        def _():
+            buf[:, :d] = dq
+
+        @pl.when(~first)
+        def _():
+            read.wait()
+            buf[:, :d] = buf[:, :d] + dq
+
+        wait_write()  # of the pair before, from the other slot
+        pltpu.make_async_copy(buf, tile, sem.at[1]).start()
+        state[0] = 1
+        state[1] = 1 - slot
+        state[2] = qb
 
     @pl.when(qb == nqb - 1)
     def _finish():
         dk_ref[0, 0, :, :] = dk_scr[:, :].astype(dk_ref.dtype)  # q pre-scaled
         dv_ref[0, 0, :, :] = dv_scr[:, :].astype(dv_ref.dtype)
+
+    @pl.when(sum(ids) == sum(pl.num_programs(i) - 1 for i in range(4)))
+    def _drain():
+        wait_write()
 
 
 def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None,
@@ -385,9 +438,11 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None,
     b, h, t, d = qt.shape
     h_kv, dv = kt.shape[1], vt.shape[3]
     grp = h // h_kv  # GQA group size (1 = classic MHA)
+    nq, rows = t // block_q, grp * block_q
+    d_tile = -(-d // LSE_LANES) * LSE_LANES  # a dq tile is whole lanes wide
     scale = d**-0.5
-    # Rebuild the kernels' lane-broadcast lse layout from the compact
-    # [b, h, t] residual (transient — lives only through the bwd kernels).
+    # Rebuild the kernel's lane-broadcast lse layout from the compact
+    # [b, h, t] residual (transient — lives only through the bwd kernel).
     lse = jnp.broadcast_to(lse_c[..., None], (b, h, t, LSE_LANES))
     do = g.transpose(0, 2, 1, 3)
     # delta_i = rowsum(do_i * o_i) — the softmax-jacobian correction term —
@@ -397,59 +452,53 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None,
         # lse cotangent (the flash_attention_lse entry): ∂lse_i/∂s_ij = p_ij,
         # so the s gradient gains p_ij·g_i — algebraically ds = p·(dp −
         # (delta − g)), i.e. the whole lse-gradient path folds into the
-        # delta term and the kernels run UNCHANGED. dlse arrives [b, t, h].
+        # delta term and the kernel runs UNCHANGED. dlse arrives [b, t, h].
         delta = delta - dlse.astype(jnp.float32).transpose(0, 2, 1)
     delta = jnp.broadcast_to(delta[..., None], (b, h, t, LSE_LANES))
 
-    # ---- dq: grid (b, h_kv, nq, nk); q tiles fold the group ------------
-    q_by_qi, kv_by_kb = _gqa_specs(grp, block_q, block_k, q_grid_dim=2)
-
-    dq_kernel = functools.partial(
-        _bwd_dq_kernel, causal=causal, block_q=block_q, block_k=block_k,
-        scale=scale, g=grp, window=window,
-    )
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(b, h_kv, t // block_q, t // block_k),
-        in_specs=[q_by_qi(d), kv_by_kb(d), kv_by_kb(dv), q_by_qi(dv),
-                  q_by_qi(LSE_LANES), q_by_qi(LSE_LANES)],
-        out_specs=q_by_qi(d),
-        out_shape=jax.ShapeDtypeStruct((b, h, t, d), qt.dtype),
-        scratch_shapes=[pltpu.VMEM((grp * block_q, d), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(qt, kt, vt, do, lse, delta)
-
-    # ---- dk/dv: grid (b, h_kv, nk, nq) ---------------------------------
     # Query-side tiles fold the group ([grp·block_q, d] rows), so one K/V
     # block load serves all grp query heads and the scratch accumulates
-    # the whole group per grid step (see _bwd_dkv_kernel).
-    q_by_qb, kv_by_ki = _gqa_specs(grp, block_q, block_k, q_grid_dim=3)
+    # the whole group per grid step (see _bwd_kernel). Under causal
+    # masking a dead pair's step names the nearest live q block's tiles.
+    q_block = functools.partial(
+        _nearest_live_q, block_q=block_q, block_k=block_k, nq=nq,
+        window=window) if causal else (lambda ki, qb: qb)
+    q_by_qb, kv_by_ki = _gqa_specs(grp, block_q, block_k, q_grid_dim=3,
+                                   q_block=q_block)
 
-    dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, causal=causal, block_q=block_q, block_k=block_k,
+    kernel = functools.partial(
+        _bwd_kernel, causal=causal, block_q=block_q, block_k=block_k,
         scale=scale, g=grp, window=window,
     )
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(b, h_kv, t // block_k, t // block_q),
+    dq, dk, dv_ = pl.pallas_call(
+        kernel,
+        grid=(b, h_kv, t // block_k, nq),
         in_specs=[q_by_qb(d), kv_by_ki(d), kv_by_ki(dv), q_by_qb(dv),
                   q_by_qb(LSE_LANES), q_by_qb(LSE_LANES)],
-        out_specs=[kv_by_ki(d), kv_by_ki(dv)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY), kv_by_ki(d),
+                   kv_by_ki(dv)],
         out_shape=[
+            jax.ShapeDtypeStruct((b, h_kv, nq, rows, d_tile), jnp.float32),
             jax.ShapeDtypeStruct((b, h_kv, t, d), kt.dtype),
             jax.ShapeDtypeStruct((b, h_kv, t, dv), vt.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, dv), jnp.float32),
+            pltpu.VMEM((2, rows, d_tile), jnp.float32),  # dq: read / write-back
+            pltpu.SemaphoreType.DMA((2,)),          # dq read, dq write-back
+            pltpu.SMEM((3,), jnp.int32),
         ],
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bwd_dqkv",
     )(qt, kt, vt, do, lse, delta)
 
-    to_model = lambda x: x.transpose(0, 2, 1, 3)
-    return to_model(dq), to_model(dk), to_model(dv)
+    # dq's tiles [b, h_kv, nq, grp·block_q, d] → the model's [b, t, h, d]:
+    # scaled and rounded once, as the scratch's last write was.
+    dq = (dq[..., :d] * scale).astype(qt.dtype).reshape(
+        b, h_kv, nq, grp, block_q, d)
+    dq = dq.transpose(0, 2, 4, 1, 3, 5).reshape(b, t, h, d)
+    return dq, dk.transpose(0, 2, 1, 3), dv_.transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------
@@ -465,7 +514,7 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g, dlse=None,
 # (the residual q/k/v are literally the saved tagged values); one saving
 # flash_o/flash_lse retires the replay of the flash forward itself, ~2 of
 # the 31 per-layer fwd matmul units at gqa-2048 shapes: the backward
-# kernels read the o and lse the forward wrote instead of an identical
+# kernel reads the o and lse the forward wrote instead of an identical
 # second copy. Per-layer HBM cost: flash_o b·t·h·dv in the activation
 # dtype (50.3 MB at gqa-2048 b=6, the size of flash_q or resid_mid),
 # flash_lse b·t·h·4 B (0.8 MB). The model's ``*_mid`` remat tiers name
@@ -600,7 +649,7 @@ def flash_attention_lse(
     for blockwise/distributed attention (ring attention's per-hop local
     compute): normalized partial outputs merge exactly across key blocks
     via their lse. Gradients are exact THROUGH lse — the lse cotangent
-    folds into the backward kernels' delta term (see _bwd), so callers
+    folds into the backward kernel's delta term (see _bwd), so callers
     may use lse in differentiable math. Same dispatch gate and fallback
     as flash_attention — including the explicit-block clamp/rounding
     documented there. Fully-masked rows report the finite NEG_INF

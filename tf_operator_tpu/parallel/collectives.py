@@ -160,7 +160,7 @@ def compiled_kernels(hlo_text: str) -> Dict[str, int]:
     (``%flash_fwd.3 = ... custom-call(...)`` counts under ``flash_fwd``;
     one in a ``while`` body is one instruction, whatever its trips). The
     instance suffix and the transform wrappers jax adds outside a scan are
-    cut (``transpose_jvp_flash_bwd_dq__.1`` is a ``flash_bwd_dq``), and so
+    cut (``transpose_jvp_flash_bwd_dqkv__.1`` is a ``flash_bwd_dqkv``), and so
     is the compiler's mark on a clone it rebuilds for want of memory
     (``%flash_fwd.3.remat2`` is one more ``flash_fwd``: a replay, the very
     thing the count is kept to show). The profiler's trace prints an op as
