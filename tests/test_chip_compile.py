@@ -475,19 +475,23 @@ def test_engine_programs_keep_the_pool_in_place_on_v5e(serve1_engine, program):
     # ...which is what the engine's own counter says, here and in compile()
     assert pool_copies(text, pool) == 0
     assert report[f"{program}_pool_copies"] == 0
-    # 3. the pools still alias parameter -> result; one kernel a layer
+    # 3. the pools still alias parameter -> result; one kernel a layer in
+    # the decode step, two in the run that carries a chunk (the chunk's
+    # tile, then the decode rows': there is no chunk-only program)
     assert "input_output_alias" in text
     kernels = _kernel_names_in(text)
-    assert kernels.count("paged_attention") == 2, kernels
-    assert report[f"{program}_tpu_custom_calls"] >= 2
+    calls = {"decode": 2, "prefill": 4}[program]
+    assert kernels.count("paged_attention") == calls, kernels
+    assert report[f"{program}_tpu_custom_calls"] >= calls
     # 4. a grid step is one page of as many KV heads as VMEM holds beside
     # their q tiles (``_kv_heads_per_step``): all 8 for a decode step's
     # 4-row tiles, 4 for a chunk's 512-row tiles (its 128 positions are
     # ONE tile a head over their sequence's pages). So a layer's kernel
     # walks 16 slots x 1 group x 20 page slots, or 1 x 2 x 20 (at the
     # cell's 12 layers 3,840 steps a decode run and 480 a chunk, where one
-    # head a step made 30,720 and 1,920)
-    per_layer = {"decode": 16 * 20, "prefill": 2 * 20}[program]
+    # head a step made 30,720 and 1,920); the run that carries a chunk
+    # walks both, whether or not a decode row is active
+    per_layer = {"decode": 16 * 20, "prefill": 2 * 20 + 16 * 20}[program]
     assert report[f"{program}_attn_grid_steps"] == 2 * per_layer
 
 
@@ -542,15 +546,17 @@ def hybrid_engine(one_chip):
     return engine, report
 
 
-@pytest.mark.parametrize("program,kernel", [("decode", "gdn_step"),
-                                            ("prefill", "gdn_chunk_fwd")])
+@pytest.mark.parametrize("program,kernels", [
+    ("decode", {"gdn_step": 3, "paged_attention": 1}),
+    ("prefill", {"gdn_chunk_fwd": 3, "gdn_step": 3, "paged_attention": 2})])
 def test_hybrid_engine_programs_keep_both_stores_in_place_on_v5e(
-        hybrid_engine, program, kernel):
+        hybrid_engine, program, kernels):
     """A linear layer's kernel a layer beside the paged kernel of the full
-    one, and neither the page pool nor the recurrent-state store is copied,
-    sliced by layer or relaid between parameter and result: the decode step
-    updates the store through the kernel's aliased operand, a chunk reads
-    and writes its one slot."""
+    one — both kinds' in the run that carries a chunk, whose rows are the
+    chunk's AND the decode step's — and neither the page pool nor the
+    recurrent-state store is copied, sliced by layer or relaid between
+    parameter and result: the decode rows update the store through the
+    kernel's aliased operand, a chunk reads and writes its one slot."""
     from tf_operator_tpu.serve.engine import pool_copies
 
     engine, report = hybrid_engine
@@ -558,8 +564,8 @@ def test_hybrid_engine_programs_keep_both_stores_in_place_on_v5e(
     assert engine.store.state_shape == (3, 17, 30, 96, 192)
     assert engine.store.conv_shape == (3, 17, 3, 11520)
     text = getattr(engine, f"_{program}").as_text()
-    assert report[f"{program}_kernels"] == {kernel: 3, "paged_attention": 1}
-    assert report[f"{program}_tpu_custom_calls"] == 4
+    assert report[f"{program}_kernels"] == kernels
+    assert report[f"{program}_tpu_custom_calls"] == sum(kernels.values())
     assert report[f"{program}_pool_copies"] == 0
     assert report[f"{program}_state_copies"] == 0
     assert pool_copies(text, engine.store.state_shape) == 0
@@ -567,9 +573,10 @@ def test_hybrid_engine_programs_keep_both_stores_in_place_on_v5e(
     # grid steps: the full layer walks 64 page slots for 16 slots x 1 group of
     # 30 KV heads (a one-row tile) or 1 x 3 groups of 10 (a 256-row tile); a
     # linear layer steps 16 slots x 2 groups of 15 heads, or 30 heads x 4
-    # chunks of 64 positions
-    per_layer = {"decode": (16 * 64, 16 * 2), "prefill": (3 * 64, 30 * 4)}[program]
-    assert report[f"{program}_attn_grid_steps"] == per_layer[0] + 3 * per_layer[1]
+    # chunks of 64 positions; the run that carries a chunk steps both
+    decode, chunk = 16 * 64 + 3 * 16 * 2, 3 * 64 + 3 * 30 * 4
+    assert report[f"{program}_attn_grid_steps"] == {
+        "decode": decode, "prefill": chunk + decode}[program]
 
 
 @pytest.mark.parametrize("fixture", ["serve1_engine", "hybrid_engine"])
@@ -578,12 +585,17 @@ def test_engine_programs_hand_the_token_array_on_v5e(request, fixture):
     from is an operand of BOTH programs and the last of their three results —
     ``int32[max_slots]``, never donated, because the host reads the very array
     the next run takes — and a chunk names its slot whatever the model; the
-    pools (and the state) are still the donated operands, still in place."""
+    pools (and the state) are still the donated operands, still in place. The
+    run that carries a chunk takes the chunk's operands, then the decode
+    step's own four. ``compile()`` builds these two programs and no third."""
     engine, report = request.getfixturevalue(fixture)
     s_n = engine.scfg.max_slots
-    small = {"decode": [(s_n, engine.max_pages_per_seq), (s_n,), (s_n,), (s_n,)],
+    decode = [(s_n, engine.max_pages_per_seq), (s_n,), (s_n,), (s_n,)]
+    small = {"decode": decode,
              "prefill": [(engine.max_pages_per_seq,), (),
-                         (engine.scfg.prefill_chunk,), (), (s_n,), ()]}
+                         (engine.scfg.prefill_chunk,), (), ()] + decode}
+    assert {k[: -len("_compile_s")] for k in report if k.endswith("_compile_s")} \
+        == set(small)
     for program, shapes in small.items():
         compiled = getattr(engine, f"_{program}")
         args, _ = compiled.args_info

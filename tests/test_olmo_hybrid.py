@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 from benchmarks import reference_olmo_hybrid as ref
+from test_serve import MIXED_CASES, check_mixed_case
 from tf_operator_tpu.models import transformer as tr
 from tf_operator_tpu.serve.engine import Request, ServeConfig, ServeEngine
 
@@ -224,6 +225,16 @@ def test_two_sequences_decode_while_a_third_prefills_each_as_if_alone(tiny):
         assert _gap(tiny[2], together[i]) < LOGIT_TOL
 
 
+@pytest.mark.parametrize("case", sorted(MIXED_CASES))
+def test_a_mixed_run_leaves_each_sequence_its_tokens_and_its_state(tiny, case):
+    """The dense engine's mixed-run cases (tests/test_serve.py) with linear
+    layers among the attending ones: a run that carries a chunk runs the
+    chunked scan on the chunk's slot and the recurrent step on the decode
+    slots — the tokens, the recurrent state and the convolution tail each
+    sequence is left with are those of the sequence served alone."""
+    check_mixed_case(_engine(tiny), case)
+
+
 @pytest.mark.parametrize("chunk", [64, 128, 256])
 def test_the_chunk_size_is_a_schedule_not_a_result(tiny, chunk):
     """One 150-token prompt served in prefill chunks of 64, 128 and 256 (3,
@@ -269,6 +280,10 @@ def test_hybrid_engine_counts_both_kinds_of_state(tiny):
     # (tests/test_chip_compile.py); here: the counters exist for this model
     report = engine.compile()
     assert report["decode_state_copies"] == 0 and "prefill_state_copies" in report
+    # two programs for this model too: the decode step, the run that carries
+    # a chunk (no chunk-only program, no third)
+    assert {k.rsplit("_compile_s", 1)[0] for k in report if k.endswith("_compile_s")} \
+        == {"decode", "prefill"}
     with pytest.raises(ValueError, match="no sliding window"):
         ServeEngine(tr.replace(cfg, layer_pattern=("linear", (16, True))),
                     tiny[1], ServeConfig())

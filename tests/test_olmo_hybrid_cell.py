@@ -26,7 +26,8 @@ NEW_METRICS = ("mfu_hybrid_serve", "gdn_prefill_roofline", "gdn_decode_roofline"
 # the accepted span readers of a saturated serve cell, read on this cell too
 SHARED_READERS = ("engine_host_step_ms", "device_prefill_share", "idle_host_bound_share",
                   "decode_slot_occupancy", "kv_reserved_unused_share",
-                  "engine_runs_ahead_share")  # the last since PR 38
+                  "engine_runs_ahead_share",  # since PR 38
+                  "chunk_carries_decode_share")  # since PR 45
 # the published shape at toy widths: two periods of 3 linear + 1 full layer
 TINY = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
             num_key_value_heads=4, num_hidden_layers=8, vocab_size=256,

@@ -151,9 +151,16 @@ def test_counters_in_the_trace_equal_the_run_results(traced):
         assert a["kv_reserved"] <= 8 * res.pool_peak_in_use
     # behind each of the two busy stretches ONE step that enqueues nothing and
     # collects what is in flight, before the engine sleeps or returns; the
-    # late request's chunk and only decode run are one step between them
+    # late request's chunk and its only decode run are a step each between
+    # them (a sequence decodes from the step AFTER its last chunk's)
     assert [a["step"] for a in by["serve.step"] if not a["occupied"]] \
-        == [res.steps - 2, res.steps]
+        == [res.steps - 3, res.steps]
+    # a chunk beside decoding slots carried their rows: one run, counted as
+    # the chunk and the decode step it is
+    assert 0 < c.chunks_carrying_decode <= min(c.prefill_chunks, c.decode_steps)
+    inside = [(a, b) for n, a, b, _ in spans if n == "serve.prefill"]
+    assert sum(any(pa <= a and b <= pb for pa, pb in inside)
+               for n, a, b, _ in spans if n == "serve.decode") == c.chunks_carrying_decode
     # four slots, a pool for three of these requests: the queue's head waited
     # for pages while a slot was free, never for a slot
     assert c.blocked_on_pool > 0 and c.blocked_on_slots == 0
@@ -194,7 +201,7 @@ def test_a_run_cut_from_on_event_leaves_no_span_open(tiny_engine, tmp_path,
     def on_event(kind, payload):
         if kind == "step":
             seen.update(step=payload["step"], counters=payload["counters"])
-            if payload["step"] == 3:
+            if payload["step"] == 4:
                 raise Cut
 
     requests = _requests()
@@ -206,15 +213,15 @@ def test_a_run_cut_from_on_event_leaves_no_span_open(tiny_engine, tmp_path,
     (spans,) = _host_spans(tmp_path, "serve.").values()
     after = next(a for n, a, _, _ in spans if n == "serve.after")
     # every span closed before the exception left run(): each ends before
-    # the marker written right after, and the third step holds its children
+    # the marker written right after, and the fourth step holds its children
     for n, a, b, _ in spans:
         assert n == "serve.after" or b <= after, n
-    assert sum(n == "serve.step" for n, *_ in spans) == 3
+    assert sum(n == "serve.step" for n, *_ in spans) == 4
     from dataclasses import asdict
 
     (written,) = [attrs for n, _, _, attrs in spans if n == "serve.counters"]
     assert {k: written[k] for k in asdict(seen["counters"])} == asdict(seen["counters"])
-    assert seen["step"] == 3 and written["decode_steps"] > 0
+    assert seen["step"] == 4 and written["decode_steps"] > 0
     # the cut leaves a step in flight: its tokens never reach the host, and a
     # request is finished only when its last token has — none reads finished
     # with a short list, and what the host holds is stamped token for token

@@ -348,6 +348,179 @@ def test_lookahead_serves_the_unchunked_oracles_tokens(tiny_engine, case):
         assert c.blocked_on_slots > 0 and c.blocked_on_pool == 0
 
 
+# A run that carries a chunk also carries a decode row for every slot that
+# decodes as the run goes out (one pass over the weights for both). What that
+# can get wrong, each case against every request served ALONE: (prompt length
+# in chunks c and rows, max_new, arrival on a clock that ticks once a reading).
+MIXED_CASES = {
+    # chunks of later arrivals ride with the decode rows of earlier ones
+    "staggered_arrivals": [((2, 3), 12, 0), ((3, 1), 6, 6), ((0, 5), 5, 14)],
+    # a last chunk of 3 valid rows beside decoding slots: padding rows and
+    # decode rows both follow the chunk's valid rows in the program
+    "short_last_chunk": [((1, 0), 10, 0), ((1, 3), 4, 4), ((2, 3), 3, 4)],
+    # two slots prefill in one step while a third decodes: BOTH chunks' runs
+    # carry its row (two tokens a step), and the slot whose prompt ends first
+    # decodes beside the other's later chunks
+    "two_slots_prefill_in_one_step": [((0, 4), 16, 0), ((2, 1), 3, 5), ((3, 0), 3, 5)],
+    # nobody decodes beside these chunks (every decode row inactive in all
+    # four runs); one request ends at its prefill, the other decodes alone
+    "a_chunk_with_nobody_decoding": [((1, 2), 1, 0), ((2, 0), 3, 0)],
+}
+
+
+class _Spy:
+    """Both programs of an engine wrapped for the length of a ``with``: every
+    call is noted with what it carried — ("prefill", slot, start, n_valid,
+    the chunk's tokens, active decode rows) or ("decode", active rows) — a
+    step's end as ("step",), and the state the last call returned is kept."""
+
+    def __init__(self, engine):
+        self.engine, self.log, self.state = engine, [], None
+
+    def __enter__(self):
+        import numpy as np
+
+        self.saved = self.engine._prefill, self.engine._decode
+
+        def prefill(*args):
+            row, start, tokens_c, n_valid, slot, table, lens, toks, active = args[3:]
+            self.log.append(("prefill", int(slot), int(start), int(n_valid),
+                             np.array(tokens_c), int(np.sum(active))))
+            out = self.saved[0](*args)
+            self.state = out[1]
+            return out
+
+        def decode(*args):
+            self.log.append(("decode", int(np.sum(args[-1]))))
+            out = self.saved[1](*args)
+            self.state = out[1]
+            return out
+
+        self.engine._prefill, self.engine._decode = prefill, decode
+        return self
+
+    def __exit__(self, *exc):
+        self.engine._prefill, self.engine._decode = self.saved
+
+    def on_event(self, kind, payload):
+        if kind == "step":
+            self.log.append(("step",))
+
+    def steps(self):
+        """The program calls of each engine step, in order."""
+        out, cur = [], []
+        for entry in self.log:
+            if entry[0] == "step":
+                out.append(cur)
+                cur = []
+            else:
+                cur.append(entry)
+        return out
+
+    def slot_of(self, req):
+        """The batch slot ``req`` prefilled in: its first chunk's."""
+        import numpy as np
+
+        (slot,) = {e[1] for e in self.log if e[0] == "prefill" and e[2] == 0
+                   and np.array_equal(e[4][: e[3]], req.prompt[: e[3]])}
+        return slot
+
+
+def check_mixed_case(engine, case):
+    """Serve ``MIXED_CASES[case]`` through ``engine`` together, then each
+    request alone: the same tokens, token for token; for a model with linear
+    layers the same recurrent state and convolution tail in the sequence's
+    slot; the counters are their arithmetic; the case's situation occurred."""
+    import numpy as np
+
+    from tf_operator_tpu.serve.engine import Request
+
+    c = engine.scfg.prefill_chunk
+    rng = np.random.RandomState(23)
+
+    def make():
+        return [Request(rid=i, prompt=[int(t) for t in rng.randint(1, 256, k * c + r)],
+                        max_new=m, arrival=float(at))
+                for i, ((k, r), m, at) in enumerate(MIXED_CASES[case])]
+
+    state0 = rng.get_state()
+    together = make()
+    ticks = iter(range(10**6))
+    with _Spy(engine) as spy:
+        res = engine.run(together, clock=lambda: float(next(ticks)),
+                         on_event=spy.on_event)
+    assert res.completed == len(together)
+    assert res.free_pages_start == res.free_pages_end  # zero page leaks
+    ctr = res.counters
+    calls = [e for e in spy.log if e[0] != "step"]
+    chunks = [e for e in calls if e[0] == "prefill"]
+    carrying = [e for e in chunks if e[5]]
+    assert ctr.prefill_chunks == len(chunks)
+    assert ctr.chunks_carrying_decode == len(carrying) <= ctr.prefill_chunks
+    assert ctr.decode_steps == len(calls) - len(chunks) + len(carrying)
+    assert ctr.runs_enqueued_ahead <= ctr.prefill_chunks + ctr.decode_steps
+    # a request's first token is its last chunk's, every other a decode row's
+    assert ctr.decode_slot_tokens == sum(r.max_new - 1 for r in together) \
+        == sum(e[-1] for e in calls)
+    n_lin = 0 if engine.store is None else engine.store.n_layers
+    assert ctr.lin_slot_steps == n_lin * ctr.decode_slot_tokens
+    steps = spy.steps()
+    for step in steps:  # a chunk a prefilling slot; the decode step only
+        kinds = [e[0] for e in step]  # in a step without one
+        assert kinds in ([], ["decode"]) or set(kinds) == {"prefill"}, step
+    if case == "staggered_arrivals":
+        assert len(carrying) >= 3 and ctr.decode_steps > len(carrying)
+    if case == "short_last_chunk":
+        assert any(e[3] == 3 for e in carrying)
+    if case == "two_slots_prefill_in_one_step":
+        assert any(len(step) == 2 and step[0][5] == 1 and step[1][5] == 1
+                   for step in steps)
+        # the 2c + 1 prompt's last chunk is the FIRST run of its step; the
+        # second run of that step already carries its first decode row
+        assert any(len(step) == 2 and step[0][3] == 1 and step[1][5] == 2
+                   for step in steps)
+    if case == "a_chunk_with_nobody_decoding":
+        assert [[e[0] for e in step] for step in steps[:3]] \
+            == [["prefill"] * 2, ["prefill"] * 2, ["decode"]] and not carrying
+
+    rng.set_state(state0)
+    last_tenant = {spy.slot_of(r): r.rid for r in together}
+    for req, alone in zip(together, make()):
+        alone.arrival = 0.0
+        with _Spy(engine) as spy_alone:
+            engine.run([alone])
+        assert req.tokens == alone.tokens and len(req.tokens) == req.max_new, req.rid
+        slot = spy.slot_of(req)
+        if engine.store is None or last_tenant[slot] != req.rid:
+            continue
+        # float32 sums in another order at most: rows of one product
+        for got, want in zip(spy.state, spy_alone.state):
+            np.testing.assert_allclose(np.asarray(got[:, slot]), np.asarray(want[:, 0]),
+                                       rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.serve
+@pytest.mark.parametrize("case", sorted(MIXED_CASES))
+def test_a_mixed_run_serves_each_request_as_if_alone(tiny_engine, case):
+    check_mixed_case(_chunk_engine(tiny_engine, 8), case)
+
+
+@pytest.mark.serve
+def test_compile_builds_the_decode_step_and_the_run_that_carries_a_chunk(tiny_engine):
+    """Two programs, as before the chunk's run took the decode rows: there is
+    no chunk-only program and no third. (The hybrid model's pair, with the
+    state store in place: tests/test_olmo_hybrid.py, tests/test_chip_compile.py.)"""
+    engine = _chunk_engine(tiny_engine, 8)
+    report = engine.compile()
+    assert {k.rsplit("_compile_s", 1)[0] for k in report if k.endswith("_compile_s")} \
+        == {"decode", "prefill"}
+    assert sorted(k.split("_", 1)[1] for k in report if k.startswith("decode_")) \
+        == sorted(k.split("_", 1)[1] for k in report if k.startswith("prefill_"))
+    assert report["decode_pool_copies"] == 0 == report["prefill_pool_copies"]
+    compiled = {k for k, v in vars(engine).items() if hasattr(v, "as_text")}
+    assert compiled == {"_decode", "_prefill"}
+
+
 class _ReadNoted:
     """A program's token array that notes when the host first reads it."""
 
@@ -381,8 +554,9 @@ def test_a_run_is_enqueued_before_the_run_before_it_is_read(tiny_engine):
     """ONE request of P chunks and N tokens alone: P + N - 1 program runs
     (the N - 1 decode runs behind the P chunks), every one but the first
     enqueued while the run before it is unread — ``runs_enqueued_ahead`` =
-    P + N - 2 — and never two: the loop is one step deep. Non-last chunks
-    hand the host nothing to read."""
+    P + N - 2 — and never two: the loop is one step deep, and a step of one
+    sequence is ONE run (its first decode run is the step AFTER its last
+    chunk's). Non-last chunks hand the host nothing to read."""
     from tf_operator_tpu.serve.engine import Request
 
     chunks, tokens = 3, 6
@@ -399,14 +573,14 @@ def test_a_run_is_enqueued_before_the_run_before_it_is_read(tiny_engine):
     at = {entry: i for i, entry in enumerate(log)}
     for n in range(chunks - 1, runs - 1):
         assert at["call", n + 1] < at["read", n]
-    # the last chunk and the first decode run are ONE step (two runs ahead of
-    # the first read); from there calls and reads alternate
-    for n in range(chunks, runs - 2):
+    # from the last chunk on, calls and reads alternate
+    for n in range(chunks - 1, runs - 2):
         assert at["read", n] < at["call", n + 2]
     c = res.counters
     assert (c.prefill_chunks, c.decode_steps) == (chunks, tokens - 1)
+    assert c.chunks_carrying_decode == 0  # alone: nobody decodes beside a chunk
     assert c.runs_enqueued_ahead == chunks + tokens - 2
-    assert res.steps == chunks + tokens - 1  # + the step that only collects
+    assert res.steps == chunks + tokens  # a run a step + the step that only collects
 
 
 @pytest.mark.serve

@@ -243,30 +243,37 @@ def tiny_root(tmp_path_factory):
 def test_this_pr_added_fifteen_entries():
     # + the five `.longdoc` entries of PR 37: the same readers on the
     # Olmo-Hybrid cell, whose engine writes the same spans; + PR 38's
-    # `engine_runs_ahead_share`, one entry a serve cell
-    assert len(_new_metrics()) == 15 + 5 + 3
+    # `engine_runs_ahead_share`, one entry a serve cell; + PR 45's
+    # `chunk_carries_decode_share`, the same three
+    assert len(_new_metrics()) == 15 + 5 + 3 + 3
 
 
-def test_runs_ahead_share_reads_the_counter_or_nothing(served):
-    """Three entries, one reader: 100 x runs enqueued ahead / program runs from
-    the trace's ``serve.counters``; None where the counter is absent (the
-    parent of PR 38) and for an untraced run."""
+@pytest.mark.parametrize("metric,counter,n,share", [
+    # 100 x runs enqueued ahead / (chunks + decode steps) — PR 38
+    ("engine_runs_ahead_share", "runs_enqueued_ahead", 2, 100.0 * 2 / 3),
+    # 100 x chunks whose run carried a decode row / chunks — PR 45
+    ("chunk_carries_decode_share", "chunks_carrying_decode", 1, 100.0),
+])
+def test_a_counter_share_reads_the_counter_or_nothing(served, metric, counter, n, share):
+    """Three entries, one reader: a share of the program runs that the trace's
+    ``serve.counters`` count; None where the counter is absent (the parent of
+    the PR that brought it), where there is nothing to take a share of, and
+    for an untraced run."""
     home = os.path.join(REPO, "benchmarks")
-    paths = {run.reader_path(home, "engine_runs_ahead_share" + cell)
-             for cell in ("", ".sat", ".longdoc")}
-    assert paths == {os.path.join(home, "metrics", "engine_runs_ahead_share.py")}
-    reader = run._load_py(paths.pop(), "runs_ahead_reader_under_test")
+    paths = {run.reader_path(home, metric + cell) for cell in ("", ".sat", ".longdoc")}
+    assert paths == {os.path.join(home, "metrics", metric + ".py")}
+    reader = run._load_py(paths.pop(), metric + "_reader_under_test")
     assert reader.read(NS(trace=None)) is None
-    assert "runs_enqueued_ahead" not in served.counters()
+    assert counter not in served.counters()
     assert reader.read(NS(trace=NS(), spans=served)) is None
     planes = serving_planes()
     planes[1].lines[0].events[-1] = ev(
         "serve.counters", 950, 0, admitted=1, prefill_chunks=1, decode_steps=2,
-        runs_enqueued_ahead=2, idle_sleeps=1)
-    ahead = NS(trace=NS(), spans=sr.reduce_planes(planes))
-    assert reader.read(ahead) == pytest.approx(100.0 * 2 / 3)
+        idle_sleeps=1, **{counter: n})
+    counted = NS(trace=NS(), spans=sr.reduce_planes(planes))
+    assert reader.read(counted) == pytest.approx(share)
     planes[1].lines[0].events[-1] = ev(
-        "serve.counters", 950, 0, prefill_chunks=0, decode_steps=0, runs_enqueued_ahead=0)
+        "serve.counters", 950, 0, prefill_chunks=0, decode_steps=0, **{counter: 0})
     assert reader.read(NS(trace=NS(), spans=sr.reduce_planes(planes))) is None
 
 

@@ -3,22 +3,28 @@ scheduling + vLLM SOSP '23 paged KV) over the models/transformer.py LM.
 
 The unit of scheduling is ONE engine step, not one request: at every
 step boundary the engine admits newly-arrived requests into free batch
-slots, pushes one prefill chunk for each still-prefilling slot, runs one
-batched decode step for every decoding slot, and evicts finished
-sequences immediately (pages back to the free list the same step — the
+slots, then either pushes one prefill chunk for each still-prefilling
+slot — each chunk carrying, in the same program run, the next token of
+every slot that is decoding as the run goes out — or, with nobody
+prefilling, runs one batched decode step; finished sequences are
+evicted immediately (pages back to the free list the same step — the
 next admission reuses them copy-free). There is no drain-the-batch
-barrier anywhere.
+barrier anywhere. A program run streams every weight once whatever rows
+it carries, so a busy step (a chunk AND decoding slots) pays for the
+weights once, not twice.
 
 The loop keeps ONE step ahead of the device (``run`` says how): the
 token every slot decodes from is an ``int32[max_slots]`` array that
 stays on the device — each program run takes the array the run before
-returned and returns the next (the decode step writes its active slots,
+returned and returns the next (the decode rows write their active slots,
 a chunk its own slot), undonated — and the host reads the same arrays
 one step late.
 
-Two compiled functions, both fixed-shape:
+Two compiled functions, both fixed-shape; which one a run is follows
+from one thing the loop sees, whether a slot is prefilling:
 
-- the DECODE step: every slot advances one token. Each layer computes
+- the DECODE step (``decode_step``), for a step in which nobody
+  prefills: every slot advances one token. Each layer computes
   single-position q/k/v, rotates at the token's absolute position
   (rope_at_positions), stores k/v into the slot's current page row
   (kvcache.write_rows), and attends through the page table
@@ -26,15 +32,25 @@ Two compiled functions, both fixed-shape:
   off-TPU). Inactive slots steer their writes to the pool's trash page
   and mask attention with seq_len 0.
 
-- the PREFILL chunk: ``prefill_chunk`` prompt tokens of ONE sequence.
-  k/v of the chunk's C positions are written first, then the SAME paged
-  attention runs with the C positions as ONE query tile of that sequence
-  (``q_start`` = the chunk's first position, a causal limit per row):
-  the sequence's pages are walked once for the whole chunk, and prefill
-  has no attention implementation of its own — a decode step is the
-  same kernel with a tile of one row a slot. The last chunk's final
-  logits yield the request's first generated token (the TTFT boundary),
-  written into the slot's entry of the token array.
+- the run that CARRIES A CHUNK (``prefill_chunk``): ``prefill_chunk``
+  prompt tokens of ONE sequence followed by the decode step's row for
+  every slot, C + ``max_slots`` rows through the shared body ONCE, so
+  every product with a weight, the MLP and the norms see both kinds of
+  row, and the head runs once (over the decode rows and the chunk's last
+  valid row). Inside a layer the two kinds part only where they must:
+  k/v of ALL rows are written first, then the SAME paged attention runs
+  twice — the chunk's C positions as ONE query tile of its sequence
+  (``q_start`` = the chunk's first position, a causal limit per row: the
+  sequence's pages are walked once for the whole chunk, and prefill has
+  no attention implementation of its own), and the slots' one-row tiles
+  over the page table as in the decode step. A slot prefills or decodes,
+  never both, so the two kinds write disjoint pages and disjoint state
+  slots. The last chunk's final logits yield the request's first
+  generated token (the TTFT boundary), written into the slot's entry of
+  the token array; that sequence's first decode row rides the NEXT
+  run (the next chunk's, or the next step's). A chunk with no decode row
+  to carry (nobody decodes) is the same program with every decode row
+  inactive: there is no chunk-only program.
 
 What the engine serves: a dense decoder of models/transformer.py — GQA
 layers with or without rotary embedding (a NoPE layer), pre- or post-norm
@@ -45,10 +61,10 @@ the layers that attend, and for the linear layers a fixed-size recurrent
 state and convolution tail a batch SLOT (``StateStore``; slot ``max_slots``
 is the trash slot). A layer's place among the layers of its kind
 (``cfg.kind_index``) is its index into its kind's store and into its
-mixer's stacked weights. In the decode step a linear layer runs
-``ops.gated_delta_step`` on every slot's state in place (an inactive slot's
-reads and writes are steered to the trash slot, as its page writes go to
-the trash page); in a prefill chunk it runs the chunked scan
+mixer's stacked weights. On the decode rows (of either program) a linear
+layer runs ``ops.gated_delta_step`` on every slot's state in place (an
+inactive slot's reads and writes are steered to the trash slot, as its page
+writes go to the trash page); on a chunk's rows it runs the chunked scan
 (``ops.gated_delta_chunk``) from the sequence's state in its slot and
 leaves the state after the chunk's last VALID row there (padding rows
 write and decay nothing). Nothing resets a slot between requests: a
@@ -76,8 +92,10 @@ artifact pin against.
 ``run`` is instrumented with ``jax.profiler.TraceAnnotation`` spans
 (``serve.admit``, ``serve.step`` and its children ``serve.prefill``,
 ``serve.prefill_fetch``, ``serve.decode_prep``, ``serve.decode``,
-``serve.decode_fetch`` — the two fetches of a step are of the step
-BEFORE it; ``serve.idle``; the closing ``serve.counters``);
+``serve.decode_fetch`` — ``serve.decode`` is around every program call
+that advances decode rows, INSIDE ``serve.prefill`` when the run carries
+a chunk; the fetches of a step are of the step BEFORE it; ``serve.idle``;
+the closing ``serve.counters``);
 inside both programs the mixers carry ``jax.named_scope``s
 (``serve.lin_mixer`` / ``serve.full_attn``: metadata on the compiled
 instructions, nothing at run time).
@@ -92,6 +110,7 @@ from __future__ import annotations
 import re
 import time
 from collections import deque
+from contextlib import nullcontext
 from dataclasses import asdict, dataclass, field
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
@@ -146,16 +165,23 @@ class EngineCounters:
     # step boundaries at which the head of the queue could not be admitted
     blocked_on_pool: int = 0   # ... for want of KV pages
     blocked_on_slots: int = 0  # ... for want of a batch slot
-    prefill_chunks: int = 0    # calls of the prefill program
+    prefill_chunks: int = 0    # program runs that carried a chunk
+    # ... of which those that carried a decoding slot's token too (one
+    # weight pass for both: a busy step is such a run, not two runs)
+    chunks_carrying_decode: int = 0
     prefill_tokens: int = 0    # prompt tokens they carried
     prefill_padded: int = 0    # chunk positions they padded
     prefill_kv_pages: int = 0  # K/V pages their attention walked, once a chunk
-    decode_steps: int = 0      # calls of the decode program
+    # program runs that advanced decoding slots: calls of the decode program
+    # + ``chunks_carrying_decode`` (such a run is a chunk AND a decode step,
+    # and counts as both)
+    decode_steps: int = 0
     decode_slot_tokens: int = 0  # tokens they produced (active slots, summed)
     idle_sleeps: int = 0       # sleeps of an empty engine waiting for an arrival
-    # program runs (chunks and decode runs) enqueued while the host had not
-    # yet read the result of the run before: the device had its next program
-    # before the host looked at this one's tokens
+    # chunks and decode steps enqueued while the host had not yet read the
+    # result of the run before: the device had its next program before the
+    # host looked at this one's tokens. In the unit of ``prefill_chunks +
+    # decode_steps``: a chunk that carries decode rows counts twice.
     runs_enqueued_ahead: int = 0
     # a model with linear layers (0 without): its slots' recurrent state
     state_resets: int = 0      # first chunks: a slot's state started from zeros
@@ -405,6 +431,10 @@ class ServeEngine:
         taps = cfg.lin_conv
         trash_slot = self.store.trash_slot if self.store else None
         kinds = [cfg.pattern[l % len(cfg.pattern)] for l in range(cfg.n_layers)]
+        # in ONE order in every process: a set's order follows the process's
+        # string hashing, the order of the slices is part of the program's
+        # text, and the text is the persistent compile cache's key
+        lin_leaves = sorted(_LIN_LEAVES)
 
         def _body(params, kp, vp, state, x, pos, attend, write_pid, write_row,
                   linear):
@@ -432,7 +462,7 @@ class ServeEngine:
                 if kind == LINEAR:
                     with jax.named_scope("serve.lin_mixer"):
                         y, state = linear(
-                            h, {name: lp[name][i] for name in _LIN_LEAVES}, i, state)
+                            h, {name: lp[name][i] for name in lin_leaves}, i, state)
                 else:
                     with jax.named_scope("serve.full_attn"):
                         q, k = h @ lp["wq"][i], h @ lp["wk"][i]
@@ -456,6 +486,29 @@ class ServeEngine:
                 x = x + (_rms_norm(y, lp["mlp_norm"][l], eps) if post else y)
             return kp, vp, state, x
 
+        def slot_rows(table, seq_lens, active):
+            """One decode row a slot, at position seq_lens[i]: (the page its
+            k/v go to, the K/V length its attention sees) — the trash page
+            and 0 for a slot that is not active."""
+            pid = table[jnp.arange(seq_lens.shape[0]), seq_lens // ps]
+            return jnp.where(active, pid, trash), jnp.where(active, seq_lens + 1, 0)
+
+        def slot_mixer(pre, b, a, w, i, state, active):
+            """One token a slot through a linear layer's sequence mixing,
+            from ``lin_project``'s outputs for the decode rows: the
+            convolution over the slot's tail and this input, the recurrent
+            step on the slot's state in place. An inactive slot reads and
+            writes the trash slot. Returns (o [s, H, d_v], state)."""
+            st, cv = state
+            slot = jnp.where(active, jnp.arange(active.shape[0]), trash_slot)
+            ext = jnp.concatenate([cv[i, slot], pre[:, None]], axis=1)
+            u = lin_conv_taps(ext, w["lin_conv"], 1)[:, 0]
+            cv = cv.at[i, slot].set(ext[:, 1:])
+            q, k, v, alpha_log, beta = lin_gates(u, b, a, w, cfg)
+            o, st = gated_delta_step(q, k, v, alpha_log, beta, st,
+                                     valid=active, layer=i, slots=slot)
+            return o, (st, cv)
+
         def decode_step(params, pools, state, table, seq_lens, tokens, active):
             """One token for every ACTIVE slot. tokens[i] sits at position
             seq_lens[i]; returns the token each slot decodes from NEXT: an
@@ -466,97 +519,106 @@ class ServeEngine:
             state, convolution tail) pair, or () for a model without; both
             come back updated."""
             kp, vp = pools
-            s = tokens.shape[0]
             x = params["embed"][tokens]
-            pos = seq_lens
-            pid = table[jnp.arange(s), pos // ps]
-            pid = jnp.where(active, pid, trash)
-            lens = jnp.where(active, pos + 1, 0)
+            pid, lens = slot_rows(table, seq_lens, active)
 
             def attend(q, kp, vp, i):  # one row a slot
                 return flash_attention_decode(q, kp, vp, table, lens, layer=i)
 
             def linear(h, w, i, state):
-                """One token a slot: the convolution over the slot's tail
-                and this input, the recurrent step on the slot's state in
-                place. An inactive slot reads and writes the trash slot."""
-                st, cv = state
-                slot = jnp.where(active, jnp.arange(s), trash_slot)
                 pre, z, b, a = lin_project(h, w, cfg)
-                ext = jnp.concatenate([cv[i, slot], pre[:, None]], axis=1)
-                u = lin_conv_taps(ext, w["lin_conv"], 1)[:, 0]
-                cv = cv.at[i, slot].set(ext[:, 1:])
-                q, k, v, alpha_log, beta = lin_gates(u, b, a, w, cfg)
-                o, st = gated_delta_step(q, k, v, alpha_log, beta, st,
-                                         valid=active, layer=i, slots=slot)
-                return lin_output(o, z, w, cfg, h.dtype), (st, cv)
+                o, state = slot_mixer(pre, b, a, w, i, state, active)
+                return lin_output(o, z, w, cfg, h.dtype), state
 
             kp, vp, state, x = _body(
-                params, kp, vp, state, x, pos, attend, pid, pos % ps, linear)
+                params, kp, vp, state, x, seq_lens, attend, pid, seq_lens % ps,
+                linear)
             logits = _rms_norm(x, params["final_norm"], eps) @ _head(params, cfg).T
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (kp, vp), state, jnp.where(active, nxt, tokens)
 
         def prefill_chunk(params, pools, state, table_row, start, tokens_c,
-                          n_valid, tokens, slot):
-            """One chunk of the prompt of the sequence in batch slot
-            ``slot``: its C positions are one query tile over the
-            sequence's page-table row, causal per row; rows past
-            ``n_valid`` lie past the sequence's length, see nothing and
-            write to the trash page. The linear layers carry the
-            sequence's state in the slot from chunk to chunk; the first
-            chunk (``start == 0``) starts from zeros. Returns ``tokens``
-            (the decode step's operand, one entry a slot) with the slot's
-            entry set to the greedy token after the chunk's last valid
-            row: the sequence's first generated token when the chunk is
-            its prompt's last, and read by nothing before that chunk has
-            written it."""
+                          n_valid, slot, table, seq_lens, tokens, active):
+            """A run that CARRIES A CHUNK: one chunk of the prompt of the
+            sequence in batch slot ``slot`` AND ``decode_step``'s token for
+            every active slot, in ONE pass over the weights. The rows of
+            ``_body`` are the chunk's C positions followed by the slots'
+            decode rows; what differs by kind of row runs on its slice of
+            them. The chunk's rows are one query tile over the sequence's
+            page-table row, causal per row; rows past ``n_valid`` lie past
+            the sequence's length, see nothing and write to the trash page.
+            The linear layers carry the sequence's state in the slot from
+            chunk to chunk; the first chunk (``start == 0``) starts from
+            zeros. The decode rows are ``decode_step``'s (``table``,
+            ``seq_lens``, ``tokens``, ``active`` as there; the chunk's own
+            slot is never active — a slot prefills or decodes — so the two
+            kinds write disjoint pages and disjoint state slots), all
+            inactive when the run has none to carry. The head runs once,
+            over the decode rows and the chunk's last valid row. Returns
+            the token each slot decodes from next, as ``decode_step`` does,
+            with ``slot``'s entry set to the greedy token after the chunk's
+            last valid row: the sequence's first generated token when the
+            chunk is its prompt's last, and read by nothing before that
+            chunk has written it."""
             kp, vp = pools
             c = tokens_c.shape[0]
             idx = jnp.arange(c)
-            pos = start + idx
+            pos_c = start + idx
             valid = idx < n_valid
-            x = params["embed"][tokens_c]
-            pid = jnp.where(valid, table_row[pos // ps], trash)
+            pid_d, lens = slot_rows(table, seq_lens, active)
+            x = params["embed"][jnp.concatenate([tokens_c, tokens])]
+            pos = jnp.concatenate([pos_c, seq_lens])
+            pid = jnp.concatenate(
+                [jnp.where(valid, table_row[pos_c // ps], trash), pid_d])
 
-            def attend(q, kp, vp, i):  # ONE sequence, c rows
-                return flash_attention_decode(
-                    q[None], kp, vp, table_row[None], (start + n_valid)[None],
-                    layer=i, q_start=start[None])[0]
+            def attend(q, kp, vp, i):  # ONE sequence's c rows, then one row a slot
+                return jnp.concatenate([
+                    flash_attention_decode(
+                        q[:c][None], kp, vp, table_row[None],
+                        (start + n_valid)[None], layer=i, q_start=start[None])[0],
+                    flash_attention_decode(q[c:], kp, vp, table, lens, layer=i)])
 
             def linear(h, w, i, state):
-                """The chunk through the chunked scan from the slot's state
-                (a padding row writes and decays nothing), the convolution
-                over the slot's tail + the chunk; the slot keeps the state
-                after the last valid row and that row's last inputs."""
+                """The projections over all the rows; then the chunk through
+                the chunked scan from its slot's state (a padding row writes
+                and decays nothing) behind the convolution over the slot's
+                tail + the chunk — the slot keeps the state after the last
+                valid row and that row's last inputs — and the decode rows as
+                in ``decode_step``."""
                 st, cv = state
                 fresh = start == 0
                 pre, z, b, a = lin_project(h, w, cfg)
-                ext = jnp.concatenate([read_slot(cv, i, slot, fresh), pre])
+                ext = jnp.concatenate([read_slot(cv, i, slot, fresh), pre[:c]])
                 u = lin_conv_taps(ext, w["lin_conv"], c)
                 # ext row r is position start + r - (taps - 1)
                 cv = write_slot(cv, i, slot, jax.lax.dynamic_slice_in_dim(
                     ext, n_valid, taps - 1))
-                q, k, v, alpha_log, beta = lin_gates(u, b, a, w, cfg)
-                o, s1 = gated_delta_chunk(
+                q, k, v, alpha_log, beta = lin_gates(u, b[:c], a[:c], w, cfg)
+                o_c, s1 = gated_delta_chunk(
                     q, k, v, alpha_log, beta, read_slot(st, i, slot, fresh),
                     valid=valid)
                 st = write_slot(st, i, slot, s1)
-                return lin_output(o, z, w, cfg, h.dtype), (st, cv)
+                o_d, state = slot_mixer(pre[c:], b[c:], a[c:], w, i, (st, cv), active)
+                return lin_output(jnp.concatenate([o_c, o_d]), z, w, cfg,
+                                  h.dtype), state
 
             kp, vp, state, x = _body(
                 params, kp, vp, state, x, pos, attend, pid, pos % ps, linear)
-            last = _rms_norm(x[n_valid - 1], params["final_norm"], eps)
-            logits = last @ _head(params, cfg).T
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (kp, vp), state, tokens.at[slot].set(tok)
+            rows = jnp.concatenate([x[c:], x[n_valid - 1][None]])
+            logits = _rms_norm(rows, params["final_norm"], eps) @ _head(params, cfg).T
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (kp, vp), state, jnp.where(
+                active, nxt[:-1], tokens).at[slot].set(nxt[-1])
 
         self._decode = jax.jit(decode_step, donate_argnums=(1, 2))
         self._prefill = jax.jit(prefill_chunk, donate_argnums=(1, 2))
 
     def compile(self) -> Dict[str, Any]:
-        """AOT-compile both step programs at the engine's fixed shapes
-        and serve with the compiled executables from here on: a server
+        """AOT-compile the engine's TWO programs at its fixed shapes —
+        ``decode``, the decode step, and ``prefill``, the run that carries
+        a chunk AND the decode rows (there is no chunk-only program: a
+        chunk with nobody decoding rides it with every decode row
+        inactive) — and serve with the compiled executables from here on: a server
         warms up before it takes traffic, so no request's TTFT carries a
         compile, and a program the device's compiler refuses fails here,
         by name. Returns each program's compile seconds, how many
@@ -571,7 +633,10 @@ class ServeEngine:
         and a chunk walks its sequence's pages once: slots x groups of
         KV heads x page slots a layer; the linear layers' kernels count
         here too: a step of ``gdn_step`` is one slot's heads, of
-        ``gdn_chunk_fwd`` one head's 64 positions). ``<program>_kernels``
+        ``gdn_chunk_fwd`` one head's 64 positions; ``prefill`` holds the
+        decode rows' kernels and grid steps beside the chunk's own: the
+        paged kernel twice an attending layer, ``gdn_chunk_fwd`` and
+        ``gdn_step`` a linear one). ``<program>_kernels``
         names the Pallas kernels (instructions by kernel name), and for a
         model with linear layers ``<program>_state_copies`` counts for the
         recurrent-state array what ``_pool_copies`` counts for the pool (0
@@ -602,8 +667,9 @@ class ServeEngine:
             )),
             "prefill": (self._prefill, (
                 self.params, pools, state, arr((p,), i32), arr((), i32),
-                arr((scfg.prefill_chunk,), i32), arr((), i32),
-                arr((s_n,), i32), arr((), i32),
+                arr((scfg.prefill_chunk,), i32), arr((), i32), arr((), i32),
+                arr((s_n, p), i32), arr((s_n,), i32), arr((s_n,), i32),
+                arr((s_n,), jnp.bool_),
             )),
         }
         out: Dict[str, Any] = {}
@@ -658,10 +724,14 @@ class ServeEngine:
         only the next run's embedding lookup needs a token's VALUE, and
         the token array stays on the device, each run handing the next
         its operand. So a loop iteration ENQUEUES step n+1 (a chunk per
-        prefilling slot, the decode run) and only then COLLECTS step n:
+        prefilling slot, each carrying the decode rows of every slot
+        that decodes as that run goes out; with nobody prefilling, the
+        decode run) and only then COLLECTS step n:
         blocks on its token arrays in device order, stamps and appends
         the tokens, and marks a request finished when its last token is
-        on the host. A slot and its pages are released when the
+        on the host. A sequence decodes from the run AFTER its last
+        chunk's: its first token is that run's result. A
+        slot and its pages are released when the
         sequence's last token has been enqueued — a later program cannot
         overtake the one that writes it. With nothing in flight (the
         first step, the first after the engine was empty) this is the
@@ -733,14 +803,15 @@ class ServeEngine:
                 counters.admitted += 1
                 emit("admitted", req)
 
-        def _count_run() -> int:
+        def _count_run(carries: bool = False) -> int:
             """One more program run goes to the device; returns its
-            ordinal. It runs AHEAD when the host has not yet read the
-            result of the run before it."""
+            ordinal among chunks and decode steps — a chunk that
+            ``carries`` decode rows is one of each. It runs AHEAD when the
+            host has not yet read the result of the run before it."""
             runs = counters.prefill_chunks + counters.decode_steps
             if fetched < runs:
-                counters.runs_enqueued_ahead += 1
-            return runs + 1
+                counters.runs_enqueued_ahead += 1 + carries
+            return runs + 1 + carries
 
         def _release_if_done(i: int) -> None:
             """The run that writes slot i's last token is enqueued: the
@@ -755,30 +826,74 @@ class ServeEngine:
         # their own), and what the loop goes on to write is copied first:
         # the CPU backend may alias a numpy operand instead of copying it.
 
-        def _prefill_chunks(ahead: List[_Unread]) -> None:
-            """One chunk per still-prefilling slot."""
+        def _decode_rows(dec: List[Tuple[int, _Slot]]):
+            """(table, seq_lens, active): the decode operands for ``dec``
+            (nobody: every row inactive)."""
+            with span("serve.decode_prep", active=len(dec)):
+                active = np.zeros(s_n, bool)
+                lens = np.zeros(s_n, np.int32)
+                for i, sl in dec:
+                    active[i] = True
+                    lens[i] = sl.seq_len
+                return table.copy(), lens, active
+
+        def _decoded(dec: List[Tuple[int, _Slot]]) -> None:
+            """A run that advances ``dec`` one token each is enqueued."""
+            counters.decode_steps += 1
+            counters.decode_slot_tokens += len(dec)
+            counters.lin_slot_steps += n_lin * len(dec)
+            for i, sl in dec:
+                sl.seq_len += 1
+                sl.generated += 1
+                _release_if_done(i)
+
+        def _decoding() -> List[Tuple[int, _Slot]]:
+            """The slots whose next run is a decode row, as counted now."""
+            return [
+                (i, sl) for i, sl in enumerate(slots)
+                if sl is not None
+                and sl.prefill_pos >= len(sl.req.prompt)
+                and sl.generated < sl.req.max_new
+            ]
+
+        def _enqueue_step(ahead: List[_Unread]) -> None:
+            """The step's program runs: one chunk per still-prefilling
+            slot, EACH with a decode row for every slot that decodes as
+            the run goes out (one pass over the weights for both — a
+            decode row costs a chunk's run next to nothing, so a step of
+            k chunks advances the decoding slots k tokens); with nobody
+            prefilling, the decode step."""
             nonlocal pools, state, toks
             c = scfg.prefill_chunk
-            for i, sl in enumerate(slots):
-                if sl is None or sl.prefill_pos >= len(sl.req.prompt):
-                    continue
+            prefilling = [
+                (i, sl) for i, sl in enumerate(slots)
+                if sl is not None and sl.prefill_pos < len(sl.req.prompt)
+            ]
+            for i, sl in prefilling:
+                # a sequence whose last chunk was an earlier run of this
+                # step is among them: the token it decodes from is that
+                # run's result, in the array this run takes
+                carried = _decoding()
                 prompt = sl.req.prompt
                 chunk = prompt[sl.prefill_pos : sl.prefill_pos + c]
                 n_valid = len(chunk)
                 last = sl.prefill_pos + n_valid >= len(prompt)
                 kv_pages = pages_needed(sl.prefill_pos + n_valid,
                                         scfg.page_size)
+                tbl, lens, active = _decode_rows(carried)
                 with span("serve.prefill", rid=sl.req.rid, slot=i,
                           start=sl.prefill_pos, n_valid=n_valid, chunk=c,
                           last=int(last), kv_pages=kv_pages):
                     buf = np.zeros(c, np.int32)
                     buf[:n_valid] = chunk
-                    run_no = _count_run()
-                    pools, state, toks = self._prefill(
-                        self.params, pools, state, table[i].copy(),
-                        np.int32(sl.prefill_pos), buf, np.int32(n_valid),
-                        toks, np.int32(i),
-                    )
+                    with (span("serve.decode", active=len(carried), slots=s_n)
+                          if carried else nullcontext()):
+                        run_no = _count_run(bool(carried))
+                        pools, state, toks = self._prefill(
+                            self.params, pools, state, table[i].copy(),
+                            np.int32(sl.prefill_pos), buf, np.int32(n_valid),
+                            np.int32(i), tbl, lens, toks, active,
+                        )
                 if n_lin:
                     if sl.prefill_pos == 0:
                         counters.state_resets += 1
@@ -790,43 +905,29 @@ class ServeEngine:
                 counters.prefill_kv_pages += kv_pages
                 sl.prefill_pos += n_valid
                 sl.seq_len = sl.prefill_pos
+                owners = [(d.req, j) for j, d in carried]
+                fetch, attrs = "serve.decode_fetch", {}
+                if carried:
+                    counters.chunks_carrying_decode += 1
+                    _decoded(carried)
                 if last:
                     # last chunk's logits ARE the first generated token
                     sl.generated = 1
-                    ahead.append(_Unread("serve.prefill_fetch", {"rid": sl.req.rid},
-                                         run_no, toks, [(sl.req, i)]))
+                    owners.append((sl.req, i))
+                    fetch, attrs = "serve.prefill_fetch", {"rid": sl.req.rid}
                     _release_if_done(i)
-
-        def _decode_step(ahead: List[_Unread]) -> None:
-            """One batched step over the decoding slots."""
-            nonlocal pools, state, toks
-            dec = [
-                (i, sl) for i, sl in enumerate(slots)
-                if sl is not None
-                and sl.prefill_pos >= len(sl.req.prompt)
-                and sl.generated < sl.req.max_new
-            ]
-            if not dec:
-                return
-            with span("serve.decode_prep", active=len(dec)):
-                active = np.zeros(s_n, bool)
-                lens = np.zeros(s_n, np.int32)
-                for i, sl in dec:
-                    active[i] = True
-                    lens[i] = sl.seq_len
-                args = (table.copy(), lens, toks, active)
-            with span("serve.decode", active=len(dec), slots=s_n):
-                run_no = _count_run()
-                pools, state, toks = self._decode(self.params, pools, state, *args)
-            counters.decode_steps += 1
-            counters.decode_slot_tokens += len(dec)
-            counters.lin_slot_steps += n_lin * len(dec)
-            ahead.append(_Unread("serve.decode_fetch", {}, run_no, toks,
-                                 [(sl.req, i) for i, sl in dec]))
-            for i, sl in dec:
-                sl.seq_len += 1
-                sl.generated += 1
-                _release_if_done(i)
+                if owners:
+                    ahead.append(_Unread(fetch, attrs, run_no, toks, owners))
+            dec = [] if prefilling else _decoding()
+            if dec:  # nobody prefills: the decode program
+                tbl, lens, active = _decode_rows(dec)
+                with span("serve.decode", active=len(dec), slots=s_n):
+                    run_no = _count_run()
+                    pools, state, toks = self._decode(
+                        self.params, pools, state, tbl, lens, toks, active)
+                ahead.append(_Unread("serve.decode_fetch", {}, run_no, toks,
+                                     [(sl.req, i) for i, sl in dec]))
+                _decoded(dec)
 
         def _collect(unread: List[_Unread]) -> None:
             """Block on a step's token arrays in device order; a token is
@@ -881,8 +982,7 @@ class ServeEngine:
                     * sum(len(sl.pages.pages) for sl in busy),
                 ):
                     ahead: List[_Unread] = []
-                    _prefill_chunks(ahead)
-                    _decode_step(ahead)
+                    _enqueue_step(ahead)
                     _collect(flight)
                     flight = ahead
                     step += 1

@@ -303,14 +303,15 @@ def serve_plan(preset_name: str, workload: dict | None = None,
         cfg.n_kv_heads, cfg.head_dim, dtype_bytes=4,
         state=StateStore.for_model(cfg, max_slots),
     )
-    # working set per step: the wider of a decode batch (max_slots rows)
-    # and a prefill chunk, through one layer's intermediates plus the
-    # f32 logits row for sampling
-    rows = max(max_slots, prefill_chunk)
+    # working set per step: the widest program run — a prefill chunk
+    # with the decode batch's rows behind it (serve/engine.py) — through
+    # one layer's intermediates, plus the f32 logits rows for sampling
+    # (the decode rows and the chunk's last)
+    rows = max_slots + prefill_chunk
     d, f = cfg.d_model, cfg.d_ff
     kv_width = cfg.n_kv_heads * cfg.head_dim
     working_b = rows * (6 * d + 2 * kv_width + 4 * f) * 4
-    working_b += max_slots * cfg.vocab * 4
+    working_b += (max_slots + 1) * cfg.vocab * 4
 
     total = params_b + kv_b + working_b
     out = {
