@@ -48,7 +48,9 @@ def test_token_bucket_burst_then_throttle():
 
 
 def test_add_rate_limited_delivers_later():
-    q = RateLimitingQueue(base_delay=0.02)
+    # 0.25 s against the first get's 5 ms: under six loaded workers a 20-ms
+    # delay could run out before that get was reached (one failure, PR 43)
+    q = RateLimitingQueue(base_delay=0.25)
     q.add_rate_limited("a")
     assert q.get(timeout=0.005) is None  # not yet
     assert q.get(timeout=1) == "a"

@@ -21,12 +21,15 @@ bidirectional encoder (MLM head = the same tied vocab projection).
 
 from __future__ import annotations
 
-from dataclasses import dataclass, replace
-from functools import partial
-from typing import Any, Dict, Optional
+import math
+from dataclasses import dataclass, fields, replace
+from functools import cache, partial
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+
+from tf_operator_tpu.parallel.mesh import AXIS_CONTEXT, AXIS_EXPERT, AXIS_PIPELINE
 
 
 LINEAR = "linear"  # a layer_pattern entry: the layer's mixer is the Gated DeltaNet
@@ -56,7 +59,6 @@ class TransformerConfig:
     # "dense" | "flash" (Pallas kernel) | "ring" (cp ppermute ring) |
     # "ulysses" (cp all-to-all head/seq re-shard; needs heads % cp == 0)
     attn_impl: str = "dense"
-    cp_axis: str = "cp"
     # Blockwise fused loss (ops/fused_cross_entropy): logits never hit HBM
     # as a [b,t,vocab] f32 array. Same math as the unfused path.
     fused_xent: bool = True
@@ -68,7 +70,6 @@ class TransformerConfig:
     n_experts: int = 0
     moe_top_k: int = 1
     capacity_factor: float = 2.0
-    ep_axis: str = "ep"
     # Expert dispatch: "sort" (capacity queues + scatter/gather, the ep
     # all_to_all layout), "einsum" (one-hot oracle), or "gmm" (r5/r6 —
     # the Pallas grouped-matmul kernel: block-granular padding only, no
@@ -102,7 +103,6 @@ class TransformerConfig:
     # (pp-1)/(M+pp-1) to (pp-1)/(M*v+pp-1). Requires pp_schedule="1f1b"
     # and pp_microbatches % pp == 0.
     pp_microbatches: int = 0
-    pp_axis: str = "pp"
     pp_schedule: str = "1f1b"
     pp_chunks: int = 1
     # Head width when it is its own number (0: d_model // n_heads). With
@@ -357,35 +357,10 @@ class TransformerConfig:
         return self.experts_held or self.n_experts
 
     def n_params(self) -> int:
-        """Parameter count (for MFU accounting): what this program holds."""
-        d, f, v, L = self.d_model, self.d_ff, self.vocab, self.n_layers
-        if self.attn_kind == "latent":
-            nh, qr, kvr = self.n_heads, self.q_lora_rank, self.kv_lora_rank
-            qk = self.qk_nope_dim + self.qk_rope_dim
-            attn = (d * qr + qr + qr * nh * qk + d * (kvr + self.qk_rope_dim)
-                    + kvr + kvr * nh * (self.qk_nope_dim + self.v_head_dim)
-                    + nh * self.v_head_dim * d)
-        else:
-            q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
-            attn = d * q + 2 * d * kv + q * d + (q + kv if self.qk_norm else 0)
-        mlp = 3 * d * f
-        if self.n_experts:
-            # experts + router + the shared expert
-            mlp = (self.n_held + self.n_shared_experts) * mlp + d * self.n_experts
-        per_layer = attn + mlp + 2 * d  # attention + mlp + norms
-        lead = self.n_dense_lead * (attn + 3 * d * self.d_ff_dense + 2 * d)
-        # an MTP module: two norms, W_eh, one expert layer, its final norm
-        mtp = self.mtp_depth * (2 * d + 2 * d * d + per_layer + d)
-        head = 0 if self.tied_head else v * d
-        # a linear layer's mixer in the attention's place: the projections
-        # (q, k, v, z, the two gates), the convolution, A_log and dt_bias,
-        # the output norm's gain, the output projection
-        H, hv = self.lin_heads, self.lin_heads * self.lin_dv
-        lin = (d * (self.lin_conv_channels + hv + 2 * H)
-               + self.lin_conv * self.lin_conv_channels + 2 * H + self.lin_dv + hv * d)
-        swap = self.n_of_kind(True) * (lin - attn)
-        # embed + lead + layers + final norm + mtp + head
-        return v * d + lead + self.n_stack_layers * per_layer + swap + d + mtp + head
+        """Parameter count (for MFU accounting): what this program holds —
+        the leaves of ``model_leaves``, summed."""
+        return sum(math.prod(leaf.shape)
+                   for leaf in jax.tree_util.tree_leaves(model_leaves(self)))
 
     def n_active_params(self) -> int:
         """Params touched per token (= n_params for dense; top-k MoE
@@ -426,14 +401,12 @@ PRESETS: Dict[str, TransformerConfig] = {
         vocab=30522, d_model=768, n_layers=12, n_heads=12, n_kv_heads=12, d_ff=3072,
         max_seq=512, causal=False,
     ),
-    # North-star-shape single-chip config (r4): the largest GQA model
-    # whose adamw state fits one 16 GB chip, at the d>=2048 shapes the
-    # 50%-MFU target presumes — measured 56% exact MFU / 49.7% 6ND vs
-    # gpt-small's 38% at d=768 (BASELINE.md; the gap is model-level
-    # per-op overhead at small d, not a matmul-rate wall — the chip's
-    # chained-matmul rate is ~flat across these shapes under the r4
-    # corrected protocol). ~795M params — sized against the MEASURED
-    # adamw residency of ~18 bytes/param at grad_accum=1 (p+m+v+grads f32
+    # North-star-shape single-chip config: the largest GQA model whose
+    # adamw state fits one 16 GB chip, at the d>=2048 shapes the 50%-MFU
+    # target presumes (at gpt-small's d=768 the per-op overhead of the
+    # model, not the matmul rate, holds the step back). ~795M params —
+    # sized against the MEASURED adamw residency of ~18 bytes/param at
+    # grad_accum=1 (p+m+v+grads f32
     # + the bf16 compute cast; accum>1 adds a second f32 grad buffer and
     # pushed the L=14 variant to 19.9G on a 15.75G chip). The
     # [b·t,2048]x[2048,8192] MLP matmuls dominate the FLOPs.
@@ -457,7 +430,7 @@ PRESETS: Dict[str, TransformerConfig] = {
         vocab=32000, d_model=8192, n_layers=80, n_heads=64, n_kv_heads=8, d_ff=28672,
         max_seq=4096,
     ),
-    # Flagship-scale sparse config (r4, VERDICT r3 #5): Mixtral-8x7B
+    # Flagship-scale sparse config: Mixtral-8x7B
     # shapes — 8 experts top-2, GQA 32q/8kv, ~46.5B total / ~12.7B
     # active params. Its legal mesh is dp x fsdp x ep: experts shard
     # over ep on their expert dim AND over fsdp on their embed dim
@@ -528,220 +501,197 @@ PRESETS: Dict[str, TransformerConfig] = {
 # ---------------------------------------------------------------------------
 
 
-def _init_layers(key, cfg: TransformerConfig, L: int, dense: bool) -> Dict[str, Any]:
-    """``L`` stacked layers of one shape: dense MLPs (of width d_ff_dense
-    when the model also has experts: its leading section) or expert layers.
-    The leaves today's models have draw keys 0..7 of ``key``'s split, as
-    they always did; the latent attention's and the shared expert's draw
-    from a second split (``fold_in(key, 8)``)."""
+ATTN = "attn"  # with LINEAR: the two layer kinds a mixer's leaves are stacked by
+
+
+@dataclass(frozen=True)
+class Leaf:
+    """One weight of the model, said ONCE: ``init_transformer``,
+    ``transformer_logical_axes``, ``TransformerConfig.n_params``, the
+    pipeline's stage specs and the by-kind slicing of a period's layers all
+    read these rows. Adding a weight is adding a row."""
+
+    name: str
+    shape: tuple  # a stacked leaf's first dimension: the layers that hold it
+    axes: tuple  # a logical axis (parallel.sharding) or None a dimension
+    # the steps from the model's key to this leaf's, each ("split", n, i) —
+    # split(key, n)[i] — or ("fold", i) — fold_in(key, i); () draws nothing
+    key: tuple = ()
+    draw: Callable = lambda key, shape: jnp.ones(shape, jnp.float32)
+    # the layer KIND that stacks it: ATTN (the layers that attend), LINEAR,
+    # or None (every layer)
+    kind: Optional[str] = None
+
+
+def _normal(scale: float) -> Callable:
+    return lambda key, shape: jax.random.normal(key, shape, jnp.float32) * scale
+
+
+# The decay's two per-head scalars as the published Gated-DeltaNet layer
+# initialises them (A uniform in [1, 16), the step dt log-uniform in [1e-3,
+# 1e-1) and stored through the inverse softplus), so that a state remembers
+# tens to hundreds of tokens, as a trained one does.
+
+
+def _a_log(key, shape):
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+
+
+def _dt_bias(key, shape):
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def layer_leaves(cfg: TransformerConfig, n_layers: int, dense: bool) -> List[Leaf]:
+    """The leaves of ``n_layers`` stacked layers of one shape: dense MLPs (of
+    width d_ff_dense when the model also has experts: its leading section)
+    or expert layers. A mixer's leaves are stacked by KIND: the attention's
+    over the layers that attend, the linear mixer's (lin_*) over the linear
+    ones. Matrices are normal draws scaled fan-in^-1/2. The leaves the first
+    models had draw keys 0..7 of the section key's split, as they always
+    did; the latent attention's and the shared expert's draw from a second
+    split (``fold_in(key, 8)``), the linear mixer's from a third (9)."""
     d = cfg.d_model
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-
-    def dense_init(k, fan_in, *shape):
-        return jax.random.normal(k, shape, jnp.float32) * (fan_in**-0.5)
-
-    ks = jax.random.split(key, 8)
-    kx = jax.random.split(jax.random.fold_in(key, 8), 8)
-    layers = {"attn_norm": jnp.ones((L, d), jnp.float32)}
-    # a mixer's leaves are stacked by KIND: the attention's over the La
-    # layers that attend, the linear mixer's (lin_*) over the linear ones
     n_lin = cfg.n_of_kind(True)
-    La = L - n_lin
+    stacked = {None: n_layers, ATTN: n_layers - n_lin, LINEAR: n_lin}
+    first, second, third = (), (("fold", 8),), (("fold", 9),)
+
+    def gain(name, width, axis, kind=None):
+        return Leaf(name, (stacked[kind], width), ("layers", axis), kind=kind)
+
+    def drawn(name, split, i, draw, dims, axes, kind=None):
+        return Leaf(name, (stacked[kind],) + dims, ("layers",) + axes,
+                    split + (("split", 8, i),), draw, kind)
+
+    def matrix(name, split, i, fan_in, dims, axes, kind=None):
+        return drawn(name, split, i, _normal(fan_in**-0.5), dims, axes, kind)
+
+    def gated_mlp(prefix, split, f, experts=(), axis=()):
+        """Gate, up and down of one width: keys 4, 5, 6 of their split."""
+        return [
+            matrix(prefix + "_gate", split, 4, d, experts + (d, f), axis + ("embed", "mlp")),
+            matrix(prefix + "_up", split, 5, d, experts + (d, f), axis + ("embed", "mlp")),
+            matrix(prefix + "_down", split, 6, f, experts + (f, d), axis + ("mlp", "embed")),
+        ]
+
+    leaves = [gain("attn_norm", d, "embed")]
     if n_lin:
-        layers.update(_init_linear_mixer(jax.random.fold_in(key, 9), cfg, n_lin))
+        H, K = cfg.lin_heads, cfg.lin_conv
+        ch, hv = cfg.lin_conv_channels, cfg.lin_heads * cfg.lin_dv
+        leaves += [
+            matrix("lin_wqkv", third, 0, d, (d, ch), ("embed", "heads"), LINEAR),
+            matrix("lin_wz", third, 1, d, (d, hv), ("embed", "heads"), LINEAR),
+            matrix("lin_wba", third, 2, d, (d, 2 * H), ("embed", None), LINEAR),
+            matrix("lin_conv", third, 3, K, (K, ch), (None, "heads"), LINEAR),
+            matrix("lin_wo", third, 4, hv, (hv, d), ("heads", "embed"), LINEAR),
+            drawn("lin_A_log", third, 5, _a_log, (H,), (None,), LINEAR),
+            drawn("lin_dt_bias", third, 6, _dt_bias, (H,), (None,), LINEAR),
+            gain("lin_norm", cfg.lin_dv, None, LINEAR),
+        ]
     if cfg.attn_kind == "latent":
-        qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
-        qk, dv = cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.v_head_dim
-        layers.update({
-            "wq_a": dense_init(kx[0], d, L, d, qr),
-            "q_norm": jnp.ones((L, qr), jnp.float32),
-            "wq_b": dense_init(kx[1], qr, L, qr, nh * qk),
-            "wkv_a": dense_init(kx[2], d, L, d, kvr + cfg.qk_rope_dim),
-            "kv_norm": jnp.ones((L, kvr), jnp.float32),
-            "wkv_b": dense_init(kx[3], kvr, L, kvr, nh * (cfg.qk_nope_dim + dv)),
-            "wo": dense_init(ks[3], nh * dv, L, nh * dv, d),
-        })
-    elif La:
-        layers.update({
-            "wq": dense_init(ks[0], d, La, d, nh * hd),
-            "wk": dense_init(ks[1], d, La, d, nkv * hd),
-            "wv": dense_init(ks[2], d, La, d, nkv * hd),
-            "wo": dense_init(ks[3], nh * hd, La, nh * hd, d),
-        })
+        # the low-rank dims stay whole; the per-head dims shard as heads
+        qr, kvr, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_dim
+        qk, kv, v = cfg.qk_nope_dim + rope, cfg.qk_nope_dim + cfg.v_head_dim, cfg.v_head_dim
+        leaves += [
+            matrix("wq_a", second, 0, d, (d, qr), ("embed", None), ATTN),
+            gain("q_norm", qr, None, ATTN),
+            matrix("wq_b", second, 1, qr, (qr, nh * qk), (None, "heads"), ATTN),
+            matrix("wkv_a", second, 2, d, (d, kvr + rope), ("embed", None), ATTN),
+            gain("kv_norm", kvr, None, ATTN),
+            matrix("wkv_b", second, 3, kvr, (kvr, nh * kv), (None, "heads"), ATTN),
+            matrix("wo", first, 3, nh * v, (nh * v, d), ("heads", "embed"), ATTN),
+        ]
+    elif stacked[ATTN]:
+        leaves += [
+            matrix("wq", first, 0, d, (d, nh * hd), ("embed", "heads"), ATTN),
+            matrix("wk", first, 1, d, (d, nkv * hd), ("embed", "kv_heads"), ATTN),
+            matrix("wv", first, 2, d, (d, nkv * hd), ("embed", "kv_heads"), ATTN),
+            matrix("wo", first, 3, nh * hd, (nh * hd, d), ("heads", "embed"), ATTN),
+        ]
         if cfg.qk_norm:
-            layers["q_norm"] = jnp.ones((La, nh * hd), jnp.float32)
-            layers["k_norm"] = jnp.ones((La, nkv * hd), jnp.float32)
-    layers["mlp_norm"] = jnp.ones((L, d), jnp.float32)
+            leaves += [gain("q_norm", nh * hd, "heads", ATTN),
+                       gain("k_norm", nkv * hd, "kv_heads", ATTN)]
+    leaves.append(gain("mlp_norm", d, "embed"))
     if cfg.n_experts and not dense:
-        E, f = cfg.n_held, cfg.d_ff  # the router scores all n_experts, the weights are the held
-        layers.update(
-            {
-                "w_router": dense_init(ks[7], d, L, d, cfg.n_experts),
-                "w_gate": dense_init(ks[4], d, L, E, d, f),
-                "w_up": dense_init(ks[5], d, L, E, d, f),
-                "w_down": dense_init(ks[6], f, L, E, f, d),
-            }
-        )
+        # the router scores all n_experts, the weights are the held
+        leaves.append(matrix("w_router", first, 7, d, (d, cfg.n_experts), ("embed", "expert")))
+        leaves += gated_mlp("w", first, cfg.d_ff, (cfg.n_held,), ("expert",))
         if cfg.n_shared_experts:
-            fs = cfg.n_shared_experts * f
-            layers.update({
-                "ws_gate": dense_init(kx[4], d, L, d, fs),
-                "ws_up": dense_init(kx[5], d, L, d, fs),
-                "ws_down": dense_init(kx[6], fs, L, fs, d),
-            })
+            leaves += gated_mlp("ws", second, cfg.n_shared_experts * cfg.d_ff)
     else:
-        f = cfg.d_ff_dense if dense and cfg.n_experts else cfg.d_ff
-        layers.update(
-            {
-                "w_gate": dense_init(ks[4], d, L, d, f),
-                "w_up": dense_init(ks[5], d, L, d, f),
-                "w_down": dense_init(ks[6], f, L, f, d),
-            }
-        )
-    return layers
+        leaves += gated_mlp("w", first, cfg.d_ff_dense if dense and cfg.n_experts else cfg.d_ff)
+    return leaves
 
 
-def _init_linear_mixer(key, cfg: TransformerConfig, L: int) -> Dict[str, Any]:
-    """``L`` stacked Gated-DeltaNet mixers. Projections fan-in scaled like
-    every other matrix; the decay's two per-head scalars as the published
-    layer initialises them (A uniform in [1, 16), the step dt log-uniform in
-    [1e-3, 1e-1) and stored through the inverse softplus), so that a state
-    remembers tens to hundreds of tokens, as a trained one does."""
-    d, H = cfg.d_model, cfg.lin_heads
-    ch, hv, K = cfg.lin_conv_channels, cfg.lin_heads * cfg.lin_dv, cfg.lin_conv
-    ks = jax.random.split(key, 8)
+def model_leaves(cfg: TransformerConfig) -> Dict[str, Any]:
+    """The parameter tree with a ``Leaf`` at every leaf: the top level's own
+    rows and one ``layer_leaves`` section each for ``layers`` (all of them,
+    or the expert layers behind a dense lead), ``lead`` (the leading dense
+    layers), ``mtp.layer`` (the multi-token-prediction module's one layer,
+    stacked [1]); ``head`` is an untied output head."""
+    d = cfg.d_model
 
-    def normal(k, fan_in, *shape):
-        return jax.random.normal(k, shape, jnp.float32) * (fan_in**-0.5)
+    def section(n_layers, dense, *key):
+        return {leaf.name: replace(leaf, key=key + leaf.key if leaf.key else ())
+                for leaf in layer_leaves(cfg, n_layers, dense)}
 
-    dt = jnp.exp(jax.random.uniform(
-        ks[6], (L, H), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
-    return {
-        "lin_wqkv": normal(ks[0], d, L, d, ch),
-        "lin_wz": normal(ks[1], d, L, d, hv),
-        "lin_wba": normal(ks[2], d, L, d, 2 * H),
-        "lin_conv": normal(ks[3], K, L, K, ch),
-        "lin_wo": normal(ks[4], hv, L, hv, d),
-        "lin_A_log": jnp.log(jax.random.uniform(ks[5], (L, H), jnp.float32, 1.0, 16.0)),
-        "lin_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-        "lin_norm": jnp.ones((L, cfg.lin_dv), jnp.float32),
+    def table(name, *key):
+        return Leaf(name, (cfg.vocab, d), ("vocab", "embed"), key, _normal(0.02))
+
+    tree = {
+        "embed": table("embed", ("split", 2, 0)),
+        "final_norm": Leaf("final_norm", (d,), ("embed",)),
+        "layers": section(cfg.n_stack_layers, False, ("split", 2, 1)),
     }
+    if cfg.n_dense_lead:
+        tree["lead"] = section(cfg.n_dense_lead, True, ("fold", 1))
+    if cfg.mtp_depth:
+        tree["mtp"] = {
+            "norm_e": Leaf("norm_e", (d,), ("embed",)),
+            "norm_h": Leaf("norm_h", (d,), ("embed",)),
+            "w_eh": Leaf("w_eh", (2 * d, d), (None, "embed"),
+                         (("fold", 2), ("fold", 0)), _normal((2 * d) ** -0.5)),
+            "layer": section(1, not cfg.n_experts, ("fold", 2), ("fold", 1)),
+            "final_norm": Leaf("final_norm", (d,), ("embed",)),
+        }
+    if not cfg.tied_head:
+        tree["head"] = table("head", ("fold", 3))
+    return tree
+
+
+def stacked_by(cfg: TransformerConfig) -> Dict[str, Optional[str]]:
+    """Each leaf of the scanned stack -> the layer kind that stacks it."""
+    return {leaf.name: leaf.kind
+            for leaf in layer_leaves(cfg, cfg.n_stack_layers, False)}
 
 
 def init_transformer(key, cfg: TransformerConfig) -> Dict[str, Any]:
-    """Initialize params (f32). Layer params are stacked on a leading
-    [layers] axis for the scan: ``layers`` (all of them, or the expert
-    layers behind a dense lead), ``lead`` (the leading dense layers),
-    ``mtp`` (the multi-token-prediction module, its one layer stacked [1]),
-    ``head`` (an untied output head)."""
-    d = cfg.d_model
-    k_embed, k_layers = jax.random.split(key)
-    params = {
-        "embed": jax.random.normal(k_embed, (cfg.vocab, d), jnp.float32) * 0.02,
-        "final_norm": jnp.ones((d,), jnp.float32),
-        "layers": _init_layers(k_layers, cfg, cfg.n_stack_layers, dense=False),
-    }
-    if cfg.n_dense_lead:
-        params["lead"] = _init_layers(
-            jax.random.fold_in(key, 1), cfg, cfg.n_dense_lead, dense=True)
-    if cfg.mtp_depth:
-        k_mtp = jax.random.fold_in(key, 2)
-        params["mtp"] = {
-            "norm_e": jnp.ones((d,), jnp.float32),
-            "norm_h": jnp.ones((d,), jnp.float32),
-            "w_eh": jax.random.normal(
-                jax.random.fold_in(k_mtp, 0), (2 * d, d), jnp.float32
-            ) * (2 * d) ** -0.5,
-            "layer": _init_layers(
-                jax.random.fold_in(k_mtp, 1), cfg, 1, dense=not cfg.n_experts),
-            "final_norm": jnp.ones((d,), jnp.float32),
-        }
-    if not cfg.tied_head:
-        params["head"] = jax.random.normal(
-            jax.random.fold_in(key, 3), (cfg.vocab, d), jnp.float32) * 0.02
-    return params
+    """Initialize params (f32): every leaf of ``model_leaves`` drawn from
+    its own key. Layer params are stacked on a leading [layers] axis for
+    the scan."""
 
+    @cache
+    def split(steps, n):  # made once for all its indices: the init program stays small
+        return jax.random.split(key_at(steps), n)
 
-def _layer_axes(cfg: TransformerConfig, dense: bool) -> Dict[str, Any]:
-    """Logical axes of one stacked layer section (as _init_layers)."""
-    layers = {"attn_norm": ("layers", "embed")}
-    if cfg.attn_kind == "latent":
-        # the low-rank dims stay whole; the per-head dims shard as heads
-        layers.update({
-            "wq_a": ("layers", "embed", None),
-            "q_norm": ("layers", None),
-            "wq_b": ("layers", None, "heads"),
-            "wkv_a": ("layers", "embed", None),
-            "kv_norm": ("layers", None),
-            "wkv_b": ("layers", None, "heads"),
-            "wo": ("layers", "heads", "embed"),
-        })
-    else:
-        layers.update({
-            "wq": ("layers", "embed", "heads"),
-            "wk": ("layers", "embed", "kv_heads"),
-            "wv": ("layers", "embed", "kv_heads"),
-            "wo": ("layers", "heads", "embed"),
-        })
-        if cfg.qk_norm:
-            layers.update({"q_norm": ("layers", "heads"),
-                           "k_norm": ("layers", "kv_heads")})
-    if cfg.has_linear:
-        layers.update({
-            "lin_wqkv": ("layers", "embed", "heads"),
-            "lin_wz": ("layers", "embed", "heads"),
-            "lin_wba": ("layers", "embed", None),
-            "lin_conv": ("layers", None, "heads"),
-            "lin_wo": ("layers", "heads", "embed"),
-            "lin_A_log": ("layers", None), "lin_dt_bias": ("layers", None),
-            "lin_norm": ("layers", None),
-        })
-    layers["mlp_norm"] = ("layers", "embed")
-    if cfg.n_experts and not dense:
-        layers.update(
-            {
-                "w_router": ("layers", "embed", "expert"),
-                "w_gate": ("layers", "expert", "embed", "mlp"),
-                "w_up": ("layers", "expert", "embed", "mlp"),
-                "w_down": ("layers", "expert", "mlp", "embed"),
-            }
-        )
-        if cfg.n_shared_experts:
-            layers.update({
-                "ws_gate": ("layers", "embed", "mlp"),
-                "ws_up": ("layers", "embed", "mlp"),
-                "ws_down": ("layers", "mlp", "embed"),
-            })
-    else:
-        layers.update(
-            {
-                "w_gate": ("layers", "embed", "mlp"),
-                "w_up": ("layers", "embed", "mlp"),
-                "w_down": ("layers", "mlp", "embed"),
-            }
-        )
-    return layers
+    @cache
+    def key_at(steps):
+        if not steps:
+            return key
+        op, *arg = steps[-1]
+        if op == "fold":
+            return jax.random.fold_in(key_at(steps[:-1]), arg[0])
+        return split(steps[:-1], arg[0])[arg[1]]
+
+    return jax.tree_util.tree_map(
+        lambda leaf: leaf.draw(key_at(leaf.key), leaf.shape), model_leaves(cfg))
 
 
 def transformer_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     """Logical axis names per param leaf (same tree structure as params)."""
-    axes = {
-        "embed": ("vocab", "embed"),
-        "final_norm": ("embed",),
-        "layers": _layer_axes(cfg, dense=False),
-    }
-    if cfg.n_dense_lead:
-        axes["lead"] = _layer_axes(cfg, dense=True)
-    if cfg.mtp_depth:
-        axes["mtp"] = {
-            "norm_e": ("embed",), "norm_h": ("embed",),
-            "w_eh": (None, "embed"),
-            "layer": _layer_axes(cfg, dense=not cfg.n_experts),
-            "final_norm": ("embed",),
-        }
-    if not cfg.tied_head:
-        axes["head"] = ("vocab", "embed")
-    return axes
+    return jax.tree_util.tree_map(lambda leaf: leaf.axes, model_leaves(cfg))
 
 
 # ---------------------------------------------------------------------------
@@ -921,14 +871,14 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh, window: int = 0):
             f"attn_impl={cfg.attn_impl!r} has no sliding window; window "
             "layers run on 'flash' or 'dense'"
         )
-    if cfg.attn_impl == "ring" and mesh is not None and cfg.cp_axis in mesh.axis_names:
+    if cfg.attn_impl == "ring" and mesh is not None and AXIS_CONTEXT in mesh.axis_names:
         from tf_operator_tpu.parallel.ring_attention import ring_attention
 
         batch_axes = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names) or None
         return ring_attention(
-            q, k, v, mesh, axis_name=cfg.cp_axis, causal=cfg.causal, batch_axes=batch_axes
+            q, k, v, mesh, axis_name=AXIS_CONTEXT, causal=cfg.causal, batch_axes=batch_axes
         )
-    if cfg.attn_impl == "ulysses" and mesh is not None and cfg.cp_axis in mesh.axis_names:
+    if cfg.attn_impl == "ulysses" and mesh is not None and AXIS_CONTEXT in mesh.axis_names:
         # All-to-all SP (DeepSpeed-Ulysses): re-shard seq->heads once, run
         # ordinary full-sequence attention per head shard (the flash kernel
         # applies untouched on TPU; dense fallback elsewhere), re-shard back.
@@ -937,7 +887,7 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh, window: int = 0):
 
         batch_axes = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names) or None
         return ulysses_attention(
-            q, k, v, mesh, axis_name=cfg.cp_axis, causal=cfg.causal,
+            q, k, v, mesh, axis_name=AXIS_CONTEXT, causal=cfg.causal,
             batch_axes=batch_axes,
             attn_fn=lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=cfg.causal),
         )
@@ -1002,7 +952,7 @@ def _anchored_gamma(gamma, cfg: TransformerConfig, mesh):
     body — manual axes can't take auto sharding constraints anyway)."""
     if not (cfg.n_experts and mesh is not None
             and getattr(mesh, "devices", None) is not None
-            and cfg.ep_axis in getattr(mesh, "axis_names", ())):
+            and AXIS_EXPERT in getattr(mesh, "axis_names", ())):
         return gamma
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -1012,7 +962,7 @@ def _anchored_gamma(gamma, cfg: TransformerConfig, mesh):
 
 
 def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
-           tp_manual_vjp=True, local_ep_axis: Optional[str] = None,
+           tp_manual_vjp=True, bound_ep: Optional[str] = None,
            kind: tuple = (0, True), dense: bool = False):
     """One decoder layer. ``kind`` = (window, rotary): this layer's entry
     of cfg.pattern, static. ``dense``: a dense-MLP layer of a model that
@@ -1070,7 +1020,7 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
         # boundary
         if not (cfg.n_experts and mesh is not None
                 and getattr(mesh, "devices", None) is not None
-                and cfg.ep_axis in getattr(mesh, "axis_names", ())):
+                and AXIS_EXPERT in getattr(mesh, "axis_names", ())):
             return a
         data_axes = tuple(ax for ax in ("dp", "fsdp") if ax in mesh.axis_names)
         if not data_axes:
@@ -1130,7 +1080,7 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
         h = x if post else anchor_tokens(_rms_norm(x, gamma_mlp, cfg.norm_eps))
     if cfg.n_experts and not dense:
         moe_out, aux = _moe_mlp(h, layer_params, cfg, mesh,
-                                local_ep_axis=local_ep_axis,
+                                bound_ep=bound_ep,
                                 gate_logits=gate_logits)
         with jax.named_scope("sec_mlp"):
             if cfg.n_shared_experts:
@@ -1157,11 +1107,6 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
             down = _rms_norm(down, gamma_mlp, cfg.norm_eps)
         return x + down, None
 
-
-# mixer leaves stacked by layer KIND where a model has linear layers
-_LIN_LEAVES = frozenset({"lin_wqkv", "lin_wz", "lin_wba", "lin_conv", "lin_wo",
-                         "lin_A_log", "lin_dt_bias", "lin_norm"})
-_ATTN_LEAVES = frozenset({"wq", "wk", "wv", "wo", "q_norm", "k_norm"})
 
 # one step's routing counters (_moe_single_gmm's stats), summed over layers as ``moe_<name>``
 MOE_COUNTERS = ("routed_here", "rows_computed", "held_load_max", "held_load_mean",
@@ -1191,12 +1136,12 @@ def _router_kwargs(layer_params, cfg: TransformerConfig) -> Dict[str, Any]:
 
 
 def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
-             local_ep_axis: Optional[str] = None, gate_logits=None):
+             bound_ep: Optional[str] = None, gate_logits=None):
     """Top-k expert MLP (k = cfg.moe_top_k: 1 Switch / 2 Mixtral-style):
     router -> all-to-all dispatch over the ep axis (parallel.moe) ->
     per-expert SwiGLU -> gate-weighted combine.
 
-    ``local_ep_axis`` (r4, ep-inside-pipeline): the caller already runs
+    ``bound_ep`` (r4, ep-inside-pipeline): the caller already runs
     inside a shard_map that maps the ep axis (pipeline_apply binds every
     mesh axis), so moe_apply's own shard_map would nest — instead the
     per-device body (parallel.moe._moe_local) runs directly against the
@@ -1231,7 +1176,7 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
         "w_up": layer_params["w_up"],
         "w_down": layer_params["w_down"],
     }
-    if local_ep_axis is not None:
+    if bound_ep is not None:
         # same capacity rule as moe_apply's sharded branch: flat is
         # already the per-shard token slice. dispatch follows
         # cfg.moe_dispatch with moe_apply's ladder semantics: gmm runs
@@ -1245,8 +1190,8 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
         )
         out, stats = _moe_local(
             flat, gate_logits, expert_params, expert_fn,
-            axis_name=local_ep_axis, capacity=capacity, dropped="zero",
-            k_top=cfg.moe_top_k, stat_axes=(local_ep_axis,),
+            axis_name=bound_ep, capacity=capacity, dropped="zero",
+            k_top=cfg.moe_top_k, stat_axes=(bound_ep,),
             dispatch_impl=local_impl,
             block_rows=gmm_block_rows(),
             expert_act=cfg.expert_act,
@@ -1258,7 +1203,7 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
             expert_params,
             expert_fn,
             mesh,
-            axis_name=cfg.ep_axis,
+            axis_name=AXIS_EXPERT,
             capacity_factor=cfg.capacity_factor,
             # the result feeds a residual add: a capacity-dropped token's
             # MLP must contribute 0, not its own input again
@@ -1292,9 +1237,9 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
     if cfg.router_bias:
         aux["expert_count"] = stats["expert_count"]  # [E]: the bias update's input
     out = out.reshape(b, t, d)
-    if local_ep_axis is None and mesh is not None and getattr(
+    if bound_ep is None and mesh is not None and getattr(
         mesh, "devices", None
-    ) is not None and cfg.ep_axis in getattr(mesh, "axis_names", ()):
+    ) is not None and AXIS_EXPERT in getattr(mesh, "axis_names", ()):
         # Re-anchor the layer output to the model's canonical activation
         # layout (batch over the data axes, ep REPLICATED). moe_apply's
         # shard_map constrains its flat tokens to P((dp, fsdp, ep)) —
@@ -1317,7 +1262,7 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
     return out, aux
 
 
-# Selective-remat policy ladder (r5, VERDICT r4 #1): named-activation sets
+# Selective-remat policy ladder: named-activation sets
 # between the two extremes full remat (save layer inputs only, fits, but
 # replays qkv+attn+wo+gate+up in the backward) and "dots" (save every
 # matmul output, OOMs at north-star shapes). Ordered by per-layer HBM cost
@@ -1348,9 +1293,7 @@ def _moe_mlp(h, layer_params, cfg: TransformerConfig, mesh,
 # replayed wo), and ``"save:resid_mid"`` is the set these aliases had.
 _MID = ("flash_o", "flash_lse")
 _REMAT_SAVE_SETS: Dict[str, tuple] = {
-    # the r5 north-star tier (BASELINE.md selective-remat table: another
-    # installation, with resid_mid where flash_o/flash_lse stand now)
-    "save_mid": _MID,
+    "save_mid": _MID,  # the tier the train cells run (benchmarks/configs/*)
     "save_qkv": ("flash_q", "flash_k", "flash_v"),
     "save_qkv_mid": ("flash_q", "flash_k", "flash_v") + _MID,
     "save_qkv_mid_up": ("flash_q", "flash_k", "flash_v") + _MID + ("mlp_up",),
@@ -1422,42 +1365,27 @@ def _use_pipeline(cfg: TransformerConfig, mesh) -> bool:
     return bool(
         cfg.pp_microbatches
         and mesh is not None
-        and cfg.pp_axis in getattr(mesh, "axis_names", ())
-        and mesh.shape[cfg.pp_axis] > 1
+        and AXIS_PIPELINE in getattr(mesh, "axis_names", ())
+        and mesh.shape[AXIS_PIPELINE] > 1
     )
 
 
-def _pp_param_specs(cfg: TransformerConfig, tp_axis: Optional[str]):
-    """PartitionSpecs for the stage-major [S, per_stage, ...] layer params:
-    stage dim over pp; with tp, the Megatron split — wq/wk/wv/w_gate/w_up
-    column-parallel (last dim over tp), wo/w_down row-parallel (first
-    weight dim over tp), norms replicated."""
+def _pp_param_specs(cfg: TransformerConfig, tp_axis: Optional[str],
+                    bound_ep: Optional[str]):
+    """PartitionSpecs for the stage-major [S, per_stage, ...] layer params,
+    from the leaves' logical axes: the stage dim over pp; with tp, the
+    Megatron split — the dimension that carries heads / kv_heads / mlp over
+    tp (wq/wk/wv/w_gate/w_up column-parallel, wo/w_down row-parallel), norms
+    replicated; under ep-in-stage the experts' own dimension over ep, so each
+    device holds its stage's layers x its E/ep experts — and the router
+    whole: every device scores every expert."""
     from jax.sharding import PartitionSpec as P
 
-    pp = cfg.pp_axis
-    col = P(pp, None, None, tp_axis)
-    row = P(pp, None, tp_axis, None)
+    over = {"heads": tp_axis, "kv_heads": tp_axis, "mlp": tp_axis, "expert": bound_ep}
     return {
-        "attn_norm": P(pp, None, None),
-        "wq": col, "wk": col, "wv": col, "wo": row,
-        "mlp_norm": P(pp, None, None),
-        "w_gate": col, "w_up": col, "w_down": row,
-    }
-
-
-def _pp_param_specs_moe(cfg: TransformerConfig):
-    """PartitionSpecs for MoE stage params under ep-in-stage (r4): stage
-    dim over pp everywhere; the expert leaves additionally shard their
-    expert dim (index 2 of [S, per_stage, E, ...]) over ep, so each
-    device holds its stage's layers x its E/ep experts."""
-    from jax.sharding import PartitionSpec as P
-
-    pp, ep = cfg.pp_axis, cfg.ep_axis
-    exp = P(pp, None, ep)
-    return {
-        "attn_norm": P(pp), "wq": P(pp), "wk": P(pp), "wv": P(pp),
-        "wo": P(pp), "mlp_norm": P(pp), "w_router": P(pp),
-        "w_gate": exp, "w_up": exp, "w_down": exp,
+        leaf.name: P(AXIS_PIPELINE, None, *(
+            None if leaf.name == "w_router" else over.get(a) for a in leaf.axes[1:]))
+        for leaf in layer_leaves(cfg, cfg.n_layers, False)
     }
 
 
@@ -1476,8 +1404,8 @@ def transformer_hidden_pp(params, tokens, cfg: TransformerConfig, mesh):
 
     MoE + pipeline: experts REPLICATE within each stage by default (the
     moe_apply no-ep routing path — identical math to the ep-sharded
-    dispatch); with an ep axis in the mesh (r4 — the VERDICT r3 #5
-    stretch), experts SHARD over ep inside each stage: pipeline_apply's
+    dispatch); with an ep axis in the mesh, experts SHARD over ep inside
+    each stage: pipeline_apply's
     one shard_map binds every mesh axis, so the stage body runs
     parallel.moe._moe_local directly against the bound "ep" name (no
     nesting) — tokens shard over (dp, fsdp, ep) as additional pipeline
@@ -1507,15 +1435,16 @@ def transformer_hidden_pp(params, tokens, cfg: TransformerConfig, mesh):
         )
     ep_in_stage = bool(
         cfg.n_experts
-        and cfg.ep_axis in mesh.axis_names
-        and mesh.shape[cfg.ep_axis] > 1
+        and AXIS_EXPERT in mesh.axis_names
+        and mesh.shape[AXIS_EXPERT] > 1
     )
-    if ep_in_stage and cfg.n_experts % mesh.shape[cfg.ep_axis]:
+    if ep_in_stage and cfg.n_experts % mesh.shape[AXIS_EXPERT]:
         raise ValueError(
             f"{cfg.n_experts} experts not divisible by "
-            f"{cfg.ep_axis}={mesh.shape[cfg.ep_axis]}"
+            f"{AXIS_EXPERT}={mesh.shape[AXIS_EXPERT]}"
         )
-    n_stages = mesh.shape[cfg.pp_axis]
+    bound_ep = AXIS_EXPERT if ep_in_stage else None
+    n_stages = mesh.shape[AXIS_PIPELINE]
     n_virtual = n_stages * cfg.pp_chunks
     if cfg.n_layers % n_virtual:
         raise ValueError(
@@ -1535,7 +1464,7 @@ def transformer_hidden_pp(params, tokens, cfg: TransformerConfig, mesh):
     layer_fn = _remat_wrap(
         partial(_layer, cfg=cfg, mesh=None, tp_axis=tp_axis,
                 tp_manual_vjp=(cfg.pp_schedule == "1f1b"),
-                local_ep_axis=(cfg.ep_axis if ep_in_stage else None)),
+                bound_ep=bound_ep),
         cfg,
     )
     moe = bool(cfg.n_experts)
@@ -1568,21 +1497,15 @@ def transformer_hidden_pp(params, tokens, cfg: TransformerConfig, mesh):
         lambda a: a.reshape((n_virtual, per_stage) + a.shape[1:]),
         params["layers"],
     )
-    if tp_axis:
-        param_specs = _pp_param_specs(cfg, tp_axis)
-    elif ep_in_stage:
-        param_specs = _pp_param_specs_moe(cfg)
-    else:
-        param_specs = None
     res = pipeline_apply(
-        stage_params, x, stage_fn, mesh, cfg.pp_microbatches, cfg.pp_axis,
+        stage_params, x, stage_fn, mesh, cfg.pp_microbatches, AXIS_PIPELINE,
         schedule=cfg.pp_schedule,
         # with ep-in-stage the ep axis is a pipeline DATA axis too: each
         # (dp, ep) coordinate pipelines its own token slice, and the MoE
         # layers all-to-all those slices to the expert owners over ep
-        batch_axes=(("dp", "fsdp", cfg.ep_axis) if ep_in_stage
+        batch_axes=(("dp", "fsdp", AXIS_EXPERT) if ep_in_stage
                     else ("dp", "fsdp")),
-        param_specs=param_specs,
+        param_specs=_pp_param_specs(cfg, tp_axis, bound_ep),
         aux_size=2 if moe else 0,
         n_chunks=cfg.pp_chunks,
     )
@@ -1645,7 +1568,7 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
         # the competing token spec; elsewhere propagation is already
         # consistent and anchors would just constrain it for nothing
         if (cfg.n_experts and data_axes
-                and cfg.ep_axis in mesh.axis_names):
+                and AXIS_EXPERT in mesh.axis_names):
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             carry_anchor = NamedSharding(mesh, P(data_axes, None, None))
@@ -1730,6 +1653,7 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
                 partial(one_layer, layer_fns[0]), x, stack)
     else:
         P = len(pattern)
+        kind_of = stacked_by(cfg)
 
         def layer_of(period_params, j):
             """Layer j of a period: its leaves at j — a mixer's at the
@@ -1738,10 +1662,10 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
             if not cfg.has_linear:
                 return jax.tree_util.tree_map(lambda a: a[j], period_params)
             i = cfg.kind_index(j)
-            mine = _LIN_LEAVES if pattern[j] == LINEAR else _ATTN_LEAVES
-            return {name: a[i if name in mine else j]
+            mine = LINEAR if pattern[j] == LINEAR else ATTN
+            return {name: a[i if kind_of[name] == mine else j]
                     for name, a in period_params.items()
-                    if name in mine or name not in _LIN_LEAVES | _ATTN_LEAVES}
+                    if kind_of[name] in (None, mine)}
 
         def period_body(x, period_params):
             auxes = []
@@ -1894,7 +1818,7 @@ def lm_loss_and_metrics(params, tokens, cfg: TransformerConfig, mesh=None, key=N
         if data_parallel_axes(mesh) or not (
                 cfg.n_experts and mesh is not None
                 and getattr(mesh, "devices", None) is not None
-                and cfg.ep_axis in getattr(mesh, "axis_names", ())):
+                and AXIS_EXPERT in getattr(mesh, "axis_names", ())):
             return h, embed
         data_axes = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names)
         if not data_axes:
@@ -2024,7 +1948,7 @@ def moe_counter_names(cfg: TransformerConfig, mesh=None) -> tuple:
     both partial losses of a model with an MTP module and the two numbers
     of a router bias; () otherwise."""
     sharded = mesh is not None and any(
-        mesh.shape.get(a, 1) > 1 for a in (cfg.ep_axis, cfg.pp_axis))
+        mesh.shape.get(a, 1) > 1 for a in (AXIS_EXPERT, AXIS_PIPELINE))
     if not cfg.n_experts or cfg.moe_dispatch != "gmm" or sharded:
         return ()
     names = tuple(f"moe_{k}" for k in MOE_COUNTERS)
@@ -2064,27 +1988,13 @@ def preset(name: str, **overrides) -> TransformerConfig:
     return replace(PRESETS[name], **overrides)
 
 
-# Workload-dict keys accepted as TransformerConfig overrides. ONE set for
-# every role reading the shared spec.workload (trainer lm.py, evaluator
-# eval.py) — duplicated sets would let the roles build different configs
-# from the same dict and fail at checkpoint restore.
-CONFIG_OVERRIDE_FIELDS = frozenset(
-    {
-        "vocab", "d_model", "n_layers", "n_heads", "n_kv_heads", "d_ff",
-        "max_seq", "causal", "remat", "fused_xent", "n_experts",
-        "moe_top_k", "capacity_factor", "moe_aux_weight", "moe_zloss_weight",
-        "moe_dispatch", "pp_microbatches", "pp_schedule",
-        "d_head", "layer_pattern", "expert_act", "router_input", "router_f32",
-        "experts_held", "expert_first",
-        "attn_kind", "q_lora_rank", "kv_lora_rank", "qk_nope_dim",
-        "qk_rope_dim", "v_head_dim", "n_dense_lead", "d_ff_dense",
-        "router_score", "router_bias", "router_bias_rate", "router_scale",
-        "router_groups", "n_shared_experts", "mtp_depth", "mtp_weight",
-        "tied_head",
-        "lin_heads", "lin_dk", "lin_dv", "lin_conv", "lin_neg_eigval",
-        "norm_order", "qk_norm",
-    }
-)
+# Workload-dict keys accepted as TransformerConfig overrides: every field
+# but the few a job does not set by its own name (``attn_impl`` is the key
+# ``attn``). ONE set for every role reading the shared spec.workload
+# (trainer lm.py, evaluator eval.py) — duplicated sets would let the roles
+# build different configs from the same dict and fail at checkpoint restore.
+CONFIG_OVERRIDE_FIELDS = frozenset(f.name for f in fields(TransformerConfig)) - {
+    "rope_theta", "norm_eps", "dtype", "attn_impl", "pp_chunks"}
 
 
 def preset_from_workload(workload: Dict[str, Any]) -> TransformerConfig:

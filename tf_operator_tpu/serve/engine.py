@@ -406,7 +406,6 @@ class ServeEngine:
         import jax.numpy as jnp
 
         from tf_operator_tpu.models.transformer import (
-            _LIN_LEAVES,
             LINEAR,
             _head,
             _rms_norm,
@@ -415,6 +414,7 @@ class ServeEngine:
             lin_output,
             lin_project,
             rope_at_positions,
+            stacked_by,
         )
         from tf_operator_tpu.ops.flash_attention import flash_attention_decode
         from tf_operator_tpu.ops.gated_delta import (
@@ -431,10 +431,10 @@ class ServeEngine:
         taps = cfg.lin_conv
         trash_slot = self.store.trash_slot if self.store else None
         kinds = [cfg.pattern[l % len(cfg.pattern)] for l in range(cfg.n_layers)]
-        # in ONE order in every process: a set's order follows the process's
-        # string hashing, the order of the slices is part of the program's
-        # text, and the text is the persistent compile cache's key
-        lin_leaves = sorted(_LIN_LEAVES)
+        # the linear mixer's leaves in SORTED order, as these programs have
+        # always sliced them: the order of the slices is part of the
+        # program's text, and the text is the persistent compile cache's key
+        lin_leaves = sorted(n for n, kind in stacked_by(cfg).items() if kind == LINEAR)
 
         def _body(params, kp, vp, state, x, pos, attend, write_pid, write_row,
                   linear):
