@@ -663,6 +663,90 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip, no_cache, form):
     assert names == ["gdn_chunk_fwd" if form == "chunk" else "gdn_step"]
 
 
+# ---- the Jamba engine: Mamba-1 layers beside multi-query attention ----------
+
+JAMBA1 = dict(page_size=64, pool_pages=2560, max_slots=64, prefill_chunk=256)
+
+
+@pytest.mark.parametrize("form", ["chunk", "step"])
+def test_selective_scan_kernels_compile_for_v5e(one_chip, no_cache, form):
+    """The two Mamba-1 kernels alone at the published sizes: 5,120 channels,
+    a state of 16 a channel, a 256-token chunk, 64 slots of a 26-layer store."""
+    from unittest import mock
+
+    ss = importlib.import_module("tf_operator_tpu.ops.selective_scan")
+    I, N = 5120, 16
+
+    def a(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        if form == "chunk":
+            names = _kernel_names(
+                ss.selective_scan_chunk, a((256, I)), a((256, I)), a((256, N)),
+                a((256, N)), a((I, N)), a((I,)), a((N, I)))
+        else:
+            names = _kernel_names(
+                lambda u, d, B, C, A, D, st, sl: ss.selective_scan_step(
+                    u, d, B, C, A, D, st, layer=4, slots=sl),
+                a((64, I)), a((64, I)), a((64, N)), a((64, N)), a((I, N)), a((I,)),
+                a((26, 65, 1, N, I)), a((64,), jnp.int32))
+    assert names == ["ssm_chunk_fwd" if form == "chunk" else "ssm_step"]
+
+
+@pytest.fixture(scope="module")
+def jamba_engine(one_chip):
+    """``ServeEngine`` for preset ``ai21-jamba2-3b`` at the benchmark's
+    -serve1 shapes (published widths, the whole vocabulary, pool ``[2 -> 1,
+    2561, 1, 64, 128]``, state store ``[13, 65, 1, 16, 5120]``, 40 page slots
+    a sequence, 256-token chunks, 64 slots) with ONE 14-layer period of the
+    cell's two, from abstract parameters, compiled for the described chip by
+    the engine's own ``compile()``."""
+    from unittest import mock
+
+    from tf_operator_tpu.models.transformer import init_transformer, preset
+    from tf_operator_tpu.serve.engine import ServeConfig, ServeEngine
+
+    cfg = preset("ai21-jamba2-3b", n_layers=14, max_seq=2560)
+    shapes = jax.eval_shape(
+        lambda: init_transformer(jax.random.PRNGKey(0), cfg))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes)
+    engine = ServeEngine(cfg, params, ServeConfig(**JAMBA1))
+    with _cache_off(), mock.patch.object(jax, "default_backend",
+                                         lambda: "tpu"):
+        report = engine.compile()
+    return engine, report
+
+
+@pytest.mark.parametrize("program,kernels", [
+    ("decode", {"ssm_step": 13, "paged_attention": 1}),
+    ("prefill", {"ssm_chunk_fwd": 13, "ssm_step": 13, "paged_attention": 2})])
+def test_jamba_engine_programs_run_both_kernels_and_keep_both_stores_in_place_on_v5e(
+        jamba_engine, program, kernels):
+    """Both recurrent kernels by name a Mamba layer, the attention layer
+    THROUGH the paged kernel — at 20 query heads over one key/value head a
+    256-token chunk is 5,120 rows and goes as two tiles of 128 — and neither
+    the page pool nor the state store copied, sliced by layer or relaid."""
+    engine, report = jamba_engine
+    assert engine._pool_shape() == (1, 2561, 1, 64, 128)
+    assert engine.store.state_shape == (13, 65, 1, 16, 5120)
+    assert engine.store.conv_shape == (13, 65, 3, 5120)
+    assert report[f"{program}_kernels"] == kernels
+    assert report[f"{program}_tpu_custom_calls"] == sum(kernels.values())
+    assert report[f"{program}_paged_reference_calls"] == 0
+    assert report[f"{program}_pool_copies"] == 0
+    assert report[f"{program}_state_copies"] == 0
+    assert "input_output_alias" in getattr(engine, f"_{program}").as_text()
+    # grid steps: the attention layer walks 40 page slots for 64 slots (a
+    # 20-row tile each) or for 2 tiles of 128 positions; a Mamba layer steps
+    # 64 slots, or 10 channel blocks of 512 over one block of 256 positions
+    decode, chunk = 64 * 40 + 13 * 64, 2 * 40 + 13 * 10
+    assert report[f"{program}_attn_grid_steps"] == {
+        "decode": decode, "prefill": chunk + decode}[program]
+
+
 # ---- grouped matmul: fwd, dx, dw, with and without the fused row scale ----
 
 GMM_WIDTHS = {

@@ -380,6 +380,7 @@ def test_config_surface_is_the_fields_less_five():
         "router_groups", "n_shared_experts", "mtp_depth", "mtp_weight",
         "tied_head",
         "lin_heads", "lin_dk", "lin_dv", "lin_conv", "lin_neg_eigval",
+        "mamba_d_state", "mamba_d_conv", "mamba_expand", "mamba_dt_rank",  # PR 47
         "norm_order", "qk_norm",
     }
     for gone in ("cp_axis", "ep_axis", "pp_axis"):

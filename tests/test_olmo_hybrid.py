@@ -52,7 +52,7 @@ def test_preset_counts_the_published_parameters():
     assert cfg.n_params() == (24 * (lin + mlp + 2 * 3840) + 8 * (full + mlp + 2 * 3840)
                               + 2 * 100352 * 3840 + 3840)
     assert [cfg.kind_index(l) for l in range(8)] == [0, 1, 2, 0, 3, 4, 5, 1]
-    assert cfg.n_of_kind(True) == 24 and cfg.n_of_kind(False) == 8
+    assert cfg.n_of_kind(tr.LINEAR) == 24 and cfg.n_of_kind(tr.ATTN) == 8
     assert preset_roundtrip(cfg) == cfg
 
 

@@ -37,7 +37,7 @@ TINY_WORKLOAD = dict(
     preset="olmo-hybrid-7b", vocab=256, d_model=64, n_layers=8, n_heads=4,
     n_kv_heads=4, d_ff=128, lin_heads=4, lin_dk=8, lin_dv=16, max_seq=192,
     kv_page_size=8, kv_pool_pages=96, max_slots=4, prefill_chunk=32)
-# served to completion: a loaded CPU finishes what it will inside 2 seconds
+# served to completion: a loaded CPU has its first tokens inside the 4-second window
 TINY_MIX = dict(rate_per_s=12.0, trace_seconds=1, stop_at_close=False,
                 prompt_len={"median": 64, "sigma": 0.5, "min": 16, "max": 128},
                 output_len={"median": 8, "sigma": 0.6, "min": 4, "max": 16})
@@ -107,7 +107,7 @@ def ran(root):
 
     run._load_py = spy
     try:
-        result = run.run_cell(CELL, SEED, 2.0, False, root=root,
+        result = run.run_cell(CELL, SEED, 4.0, False, root=root,
                               device_check=cpu_devices)
     finally:
         run._load_py = load

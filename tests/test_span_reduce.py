@@ -244,8 +244,9 @@ def test_this_pr_added_fifteen_entries():
     # + the five `.longdoc` entries of PR 37: the same readers on the
     # Olmo-Hybrid cell, whose engine writes the same spans; + PR 38's
     # `engine_runs_ahead_share`, one entry a serve cell; + PR 45's
-    # `chunk_carries_decode_share`, the same three
-    assert len(_new_metrics()) == 15 + 5 + 3 + 3
+    # `chunk_carries_decode_share`, the same three; + the seven of PR 47's
+    # eight `.reason` entries whose reader reads spans (the Jamba cell)
+    assert len(_new_metrics()) == 15 + 5 + 3 + 3 + 7
 
 
 @pytest.mark.parametrize("metric,counter,n,share", [

@@ -32,7 +32,20 @@ import jax.numpy as jnp
 from tf_operator_tpu.parallel.mesh import AXIS_CONTEXT, AXIS_EXPERT, AXIS_PIPELINE
 
 
-LINEAR = "linear"  # a layer_pattern entry: the layer's mixer is the Gated DeltaNet
+# The KINDS a layer's mixer is one of, by name. Two are ``layer_pattern``
+# entries as they stand — LINEAR (Gated DeltaNet) and MAMBA (Mamba-1's
+# selective scan): the RECURRENT kinds, whose sequence state has one size
+# however long the sequence — and a layer that attends (a (window, rotary)
+# entry) is of kind ATTN. A mixer's leaves are stacked by kind.
+LINEAR = "linear"
+MAMBA = "mamba"
+ATTN = "attn"
+RECURRENT = (LINEAR, MAMBA)
+
+
+def kind_of(entry) -> str:
+    """A ``layer_pattern`` entry's kind."""
+    return entry if entry in RECURRENT else ATTN
 
 
 @dataclass(frozen=True)
@@ -114,8 +127,10 @@ class TransformerConfig:
     # positional encoding at all in that layer (NoPE). () is one entry
     # (0, True): today's stack. The stack scans over periods with the
     # period's layers unrolled in the body, so both are static per layer.
-    # An entry may instead be the layer KIND "linear": a Gated-DeltaNet
-    # mixer (the lin_* sizes below) in the attention's place.
+    # An entry may instead be a recurrent layer KIND: "linear", a
+    # Gated-DeltaNet mixer (the lin_* sizes below), or "mamba", a Mamba-1
+    # mixer (the mamba_* sizes), in the attention's place. One model holds
+    # one recurrent kind.
     layer_pattern: tuple = ()
     # MoE variants. expert_act: the gate activation of the expert MLP
     # ("silu" SwiGLU | "relu" ReGLU). router_input: the tensor the router
@@ -184,6 +199,15 @@ class TransformerConfig:
     lin_dv: int = 0
     lin_conv: int = 4
     lin_neg_eigval: bool = True
+    # The "mamba" layers' mixer (Mamba-1, ops/selective_scan.py): an inner
+    # width of mamba_expand * d_model channels behind a causal depthwise
+    # convolution of mamba_d_conv taps (with a bias), a state of
+    # mamba_d_state numbers a channel, the step size through a rank
+    # mamba_dt_rank bottleneck; Jamba's RMSNorms on dt, B and C.
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
     # "pre": x + F(norm(x)), the Llama order. "post": x + norm(F(x)), the
     # OLMo-2 order — the same two gains a layer, on the sublayers' OUTPUTS.
     norm_order: str = "pre"
@@ -212,13 +236,23 @@ class TransformerConfig:
             raise ValueError(
                 "norm_order='post' and qk_norm run on dense gqa layers only "
                 "(no experts, no latent attention)")
-        if self.has_linear:
-            if min(self.lin_heads, self.lin_dk, self.lin_dv) <= 0 or self.lin_conv < 2:
-                raise ValueError(
-                    f"a 'linear' layer needs lin_heads, lin_dk, lin_dv > 0 and "
-                    f"lin_conv >= 2 (got {self.lin_heads}, {self.lin_dk}, "
-                    f"{self.lin_dv}, {self.lin_conv})")
+        if LINEAR in self.pattern and (
+                min(self.lin_heads, self.lin_dk, self.lin_dv) <= 0 or self.lin_conv < 2):
+            raise ValueError(
+                f"a 'linear' layer needs lin_heads, lin_dk, lin_dv > 0 and "
+                f"lin_conv >= 2 (got {self.lin_heads}, {self.lin_dk}, "
+                f"{self.lin_dv}, {self.lin_conv})")
+        if MAMBA in self.pattern and (
+                min(self.mamba_d_state, self.mamba_dt_rank, self.mamba_expand) <= 0
+                or self.mamba_d_conv < 2):
+            raise ValueError(
+                f"a 'mamba' layer needs mamba_d_state, mamba_dt_rank, "
+                f"mamba_expand > 0 and mamba_d_conv >= 2 (got {self.mamba_d_state}, "
+                f"{self.mamba_dt_rank}, {self.mamba_expand}, {self.mamba_d_conv})")
+        for kind in (k for k in RECURRENT if k in self.pattern):
             for what, bad in (
+                    ("a 'linear' layer in the same model",
+                     kind == MAMBA and LINEAR in self.pattern),
                     ("pipeline stages (pp_microbatches)", self.pp_microbatches),
                     (f"attn_impl={self.attn_impl!r}",
                      self.attn_impl in ("ring", "ulysses")),
@@ -228,7 +262,7 @@ class TransformerConfig:
                     ("a bidirectional model (causal=False)", not self.causal)):
                 if bad:
                     raise ValueError(
-                        f"a 'linear' layer does not run with {what}: its state "
+                        f"a {kind!r} layer does not run with {what}: its state "
                         "is carried along the whole sequence on one device")
         if self.expert_act not in ("silu", "relu"):
             raise ValueError(f"unknown expert_act {self.expert_act!r}")
@@ -295,7 +329,7 @@ class TransformerConfig:
         if self.stack_is_new and self.pp_microbatches:
             raise ValueError(
                 "leading dense layers, a shared expert, the router bias, an "
-                "MTP module, an untied head, q/k norms and linear layers are "
+                "MTP module, an untied head, q/k norms and recurrent layers are "
                 "not stage-partitioned: pp_microbatches must be 0"
             )
 
@@ -309,7 +343,7 @@ class TransformerConfig:
         return bool(self.n_dense_lead or self.n_shared_experts
                     or self.router_bias or self.mtp_depth
                     or not self.tied_head or self.attn_kind == "latent"
-                    or self.qk_norm or self.has_linear)
+                    or self.qk_norm or self.recurrent_kind)
 
     @property
     def n_stack_layers(self) -> int:
@@ -318,38 +352,49 @@ class TransformerConfig:
 
     @property
     def pattern(self) -> tuple:
-        """One entry a layer of the period: (window, rotary), or LINEAR."""
+        """One entry a layer of the period: (window, rotary), or a
+        recurrent kind's name."""
         return tuple(self.layer_pattern) or ((0, True),)
 
     @property
     def attn_kinds(self) -> tuple:
         """The period's attention layers' (window, rotary) entries."""
-        return tuple(k for k in self.pattern if k != LINEAR)
+        return tuple(k for k in self.pattern if kind_of(k) == ATTN)
 
     @property
-    def has_linear(self) -> bool:
-        return LINEAR in self.pattern
+    def recurrent_kind(self) -> Optional[str]:
+        """The recurrent kind the model's pattern holds (one at most), or None."""
+        return next((k for k in RECURRENT if k in self.pattern), None)
 
-    def _in_period(self, linear: bool, upto: Optional[int] = None) -> int:
+    def _in_period(self, kind: str, upto: Optional[int] = None) -> int:
         """Layers of one kind among the period's first ``upto`` (all)."""
-        return sum((k == LINEAR) == linear for k in self.pattern[:upto])
+        return sum(kind_of(k) == kind for k in self.pattern[:upto])
 
-    def n_of_kind(self, linear: bool) -> int:
-        """How many of the stack's layers are linear (or are not)."""
-        return self._in_period(linear) * (self.n_stack_layers // len(self.pattern))
+    def n_of_kind(self, kind) -> int:
+        """How many of the stack's layers are of ``kind``, a kind's name.
+        True and False still read as LINEAR and ATTN for the accepted hybrid
+        runner alone (``benchmarks/runners/serve_olmo_hybrid.py``, not this
+        PR's to edit): the mapping goes with the next ``benchmark`` PR."""
+        kind = {True: LINEAR, False: ATTN}.get(kind, kind)
+        return self._in_period(kind) * (self.n_stack_layers // len(self.pattern))
 
     def kind_index(self, layer: int) -> int:
         """Layer ``layer``'s place among the stack's layers of ITS kind: the
         index of its mixer's leaves (stacked by kind) and of its sequence
         state in the serve engine (pages or recurrent state)."""
         period, j = divmod(layer, len(self.pattern))
-        linear = self.pattern[j] == LINEAR
-        return period * self._in_period(linear) + self._in_period(linear, j)
+        kind = kind_of(self.pattern[j])
+        return period * self._in_period(kind) + self._in_period(kind, j)
 
     @property
     def lin_conv_channels(self) -> int:
         """Channels the linear mixer's convolution runs over: [q | k | v]."""
         return self.lin_heads * (2 * self.lin_dk + self.lin_dv)
+
+    @property
+    def mamba_inner(self) -> int:
+        """Channels of the Mamba mixer: its inner width."""
+        return self.mamba_expand * self.d_model
 
     @property
     def n_held(self) -> int:
@@ -493,15 +538,25 @@ PRESETS: Dict[str, TransformerConfig] = {
         lin_heads=30, lin_dk=96, lin_dv=192, lin_conv=4, lin_neg_eigval=True,
         norm_order="post", qk_norm=True, tied_head=False,
     ),
+    # AI21-Jamba2-3B (ai21labs; config.json on the hub, model_type jamba):
+    # 28 layers, layer i attends iff i % 14 == 7, every other layer is a
+    # Mamba-1 mixer (inner width 5120, state 16, 4 taps with a bias, dt rank
+    # 160, RMSNorms on dt, B and C); attention is 20 query heads over ONE
+    # key/value head of 128 with NO rotary embedding; every feed-forward the
+    # SiLU-gated MLP (num_experts 1); pre-norm, a tied head. One chip SERVES
+    # all of it (benchmarks/configs/ai21-jamba2-3b-serve1.json).
+    "ai21-jamba2-3b": TransformerConfig(
+        vocab=65536, d_model=2560, n_layers=28, n_heads=20, n_kv_heads=1,
+        d_ff=8192, max_seq=262144, norm_eps=1e-6,
+        layer_pattern=(MAMBA,) * 7 + ((0, False),) + (MAMBA,) * 6,
+        mamba_d_state=16, mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=160,
+    ),
 }
 
 
 # ---------------------------------------------------------------------------
 # Params
 # ---------------------------------------------------------------------------
-
-
-ATTN = "attn"  # with LINEAR: the two layer kinds a mixer's leaves are stacked by
 
 
 @dataclass(frozen=True)
@@ -519,7 +574,7 @@ class Leaf:
     key: tuple = ()
     draw: Callable = lambda key, shape: jnp.ones(shape, jnp.float32)
     # the layer KIND that stacks it: ATTN (the layers that attend), LINEAR,
-    # or None (every layer)
+    # MAMBA, or None (every layer)
     kind: Optional[str] = None
 
 
@@ -543,20 +598,33 @@ def _dt_bias(key, shape):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
+def _zeros(key, shape):
+    return jnp.zeros(shape, jnp.float32)
+
+
+def _state_a_log(key, shape):
+    """Mamba-1's A as the published layer constructs it: 1 .. N a channel."""
+    return jnp.broadcast_to(
+        jnp.log(jnp.arange(1, shape[-1] + 1, dtype=jnp.float32)), shape)
+
+
 def layer_leaves(cfg: TransformerConfig, n_layers: int, dense: bool) -> List[Leaf]:
     """The leaves of ``n_layers`` stacked layers of one shape: dense MLPs (of
     width d_ff_dense when the model also has experts: its leading section)
     or expert layers. A mixer's leaves are stacked by KIND: the attention's
     over the layers that attend, the linear mixer's (lin_*) over the linear
-    ones. Matrices are normal draws scaled fan-in^-1/2. The leaves the first
-    models had draw keys 0..7 of the section key's split, as they always
-    did; the latent attention's and the shared expert's draw from a second
-    split (``fold_in(key, 8)``), the linear mixer's from a third (9)."""
+    ones, the Mamba mixer's (mamba_*) over the Mamba ones. Matrices are
+    normal draws scaled fan-in^-1/2. The leaves the first models had draw
+    keys 0..7 of the section key's split, as they always did; the latent
+    attention's and the shared expert's draw from a second split
+    (``fold_in(key, 8)``), the linear mixer's from a third (9), the Mamba
+    mixer's from a fourth (10)."""
     d = cfg.d_model
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    n_lin = cfg.n_of_kind(True)
-    stacked = {None: n_layers, ATTN: n_layers - n_lin, LINEAR: n_lin}
-    first, second, third = (), (("fold", 8),), (("fold", 9),)
+    n_lin, n_mamba = cfg.n_of_kind(LINEAR), cfg.n_of_kind(MAMBA)
+    stacked = {None: n_layers, ATTN: n_layers - n_lin - n_mamba, LINEAR: n_lin,
+               MAMBA: n_mamba}
+    first, second, third, fourth = (), (("fold", 8),), (("fold", 9),), (("fold", 10),)
 
     def gain(name, width, axis, kind=None):
         return Leaf(name, (stacked[kind], width), ("layers", axis), kind=kind)
@@ -589,6 +657,25 @@ def layer_leaves(cfg: TransformerConfig, n_layers: int, dense: bool) -> List[Lea
             drawn("lin_A_log", third, 5, _a_log, (H,), (None,), LINEAR),
             drawn("lin_dt_bias", third, 6, _dt_bias, (H,), (None,), LINEAR),
             gain("lin_norm", cfg.lin_dv, None, LINEAR),
+        ]
+    if n_mamba:
+        inner, N, R, K = (cfg.mamba_inner, cfg.mamba_d_state, cfg.mamba_dt_rank,
+                          cfg.mamba_d_conv)
+        leaves += [
+            matrix("mamba_in", fourth, 0, d, (d, 2 * inner), ("embed", "heads"), MAMBA),
+            matrix("mamba_conv", fourth, 1, K, (K, inner), (None, "heads"), MAMBA),
+            Leaf("mamba_conv_bias", (n_mamba, inner), ("layers", "heads"),
+                 draw=_zeros, kind=MAMBA),
+            matrix("mamba_x", fourth, 2, inner, (inner, R + 2 * N), ("heads", None), MAMBA),
+            matrix("mamba_dt", fourth, 3, R, (R, inner), (None, "heads"), MAMBA),
+            drawn("mamba_dt_bias", fourth, 4, _dt_bias, (inner,), ("heads",), MAMBA),
+            Leaf("mamba_A_log", (n_mamba, inner, N), ("layers", "heads", None),
+                 draw=_state_a_log, kind=MAMBA),
+            gain("mamba_D", inner, "heads", MAMBA),
+            matrix("mamba_out", fourth, 5, inner, (inner, d), ("heads", "embed"), MAMBA),
+            gain("mamba_dt_norm", R, None, MAMBA),
+            gain("mamba_b_norm", N, None, MAMBA),
+            gain("mamba_c_norm", N, None, MAMBA),
         ]
     if cfg.attn_kind == "latent":
         # the low-rank dims stay whole; the per-head dims shard as heads
@@ -793,13 +880,16 @@ def lin_project(h, lp, cfg: TransformerConfig):
             ba[..., :H], ba[..., H:])
 
 
-def lin_conv_taps(ext, w, n: int):
+def lin_conv_taps(ext, w, n: int, bias=None):
     """The causal depthwise convolution + SiLU over ``n`` positions: ``ext``
     [..., n + K − 1, channels] is the input with the K − 1 positions before
     it in front (zeros at a sequence's start), w [K, channels] the taps, tap
-    j on the input j − (K − 1) positions back."""
+    j on the input j − (K − 1) positions back; ``bias`` [channels] (the Mamba
+    mixer's) is added before the SiLU."""
     K = w.shape[0]
     acc = sum(ext[..., j:j + n, :] * w[j].astype(ext.dtype) for j in range(K))
+    if bias is not None:
+        acc = acc + bias.astype(ext.dtype)
     return jax.nn.silu(acc)
 
 
@@ -847,6 +937,54 @@ def _linear_mixer(h, lp, cfg: TransformerConfig):
     o, _ = jax.vmap(
         lambda *row: gated_delta_chunk(*row, state0))(q, k, v, alpha_log, beta)
     return lin_output(o, z, lp, cfg, h.dtype)
+
+
+# The Mamba-1 mixer in the same pieces (the convolution is ``lin_conv_taps``
+# with the mixer's bias): the serve engine runs them around its own
+# convolution tail and state, the whole-sequence forward from zeros.
+
+
+def mamba_project(h, lp, cfg: TransformerConfig):
+    """h [..., d] -> (the convolution's input x [..., inner] before the taps,
+    the output gate z [..., inner])."""
+    xz = h @ lp["mamba_in"].astype(h.dtype)
+    return xz[..., : cfg.mamba_inner], xz[..., cfg.mamba_inner:]
+
+
+def mamba_gates(u, lp, cfg: TransformerConfig):
+    """The recurrence's operands from the convolved channels u [..., inner]:
+    the step size delta = softplus(RMSNorm(dt) · W_dt + b_dt) [..., inner], B
+    and C [..., N] (each through its RMSNorm: Jamba's three inner norms), and
+    A = −exp(A_log) [inner, N] — all float32."""
+    R, N = cfg.mamba_dt_rank, cfg.mamba_d_state
+    dbc = (u @ lp["mamba_x"].astype(u.dtype)).astype(jnp.float32)
+    dt = _rms_norm(dbc[..., :R], lp["mamba_dt_norm"], cfg.norm_eps)
+    B = _rms_norm(dbc[..., R:R + N], lp["mamba_b_norm"], cfg.norm_eps)
+    C = _rms_norm(dbc[..., R + N:], lp["mamba_c_norm"], cfg.norm_eps)
+    delta = jax.nn.softplus(dt @ lp["mamba_dt"] + lp["mamba_dt_bias"])
+    return delta, B, C, -jnp.exp(lp["mamba_A_log"])
+
+
+def mamba_output(y, z, lp, dtype):
+    """(the recurrence's output ⊙ SiLU(z)) through the output projection:
+    [..., inner] -> [..., d]."""
+    return (y.astype(dtype) * jax.nn.silu(z)) @ lp["mamba_out"].astype(dtype)
+
+
+def _mamba_mixer(h, lp, cfg: TransformerConfig):
+    """Whole sequences [b, t, d] through the Mamba mixer, from a zero
+    convolution tail and a zero state: the scan, a row at a time."""
+    from tf_operator_tpu.ops.selective_scan import selective_scan_chunk
+
+    t = h.shape[1]
+    x, z = mamba_project(h, lp, cfg)
+    ext = jnp.pad(x, ((0, 0), (cfg.mamba_d_conv - 1, 0), (0, 0)))
+    u = lin_conv_taps(ext, lp["mamba_conv"], t, lp["mamba_conv_bias"])
+    delta, B, C, A = mamba_gates(u, lp, cfg)
+    state0 = jnp.zeros((cfg.mamba_d_state, cfg.mamba_inner), jnp.float32)
+    y, _ = jax.vmap(lambda *row: selective_scan_chunk(
+        *row, A, lp["mamba_D"], state0))(u, delta, B, C)
+    return mamba_output(y, z, lp, h.dtype)
 
 
 def _attention(q, k, v, cfg: TransformerConfig, mesh, window: int = 0):
@@ -997,7 +1135,7 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
     b, t, d = x.shape
     hd = cfg.head_dim
     latent = cfg.attn_kind == "latent"
-    linear = kind == LINEAR
+    linear = kind in RECURRENT  # no q/k/v of the attention's: the mixer projects
     post = cfg.norm_order == "post"
     # SECTION scopes (``sec_*``, PERF.md §3): metadata on the instructions
     # made here, nothing else — ``compiled_sections`` reads them back from
@@ -1059,7 +1197,8 @@ def _layer(x, layer_params, cfg: TransformerConfig, mesh, tp_axis=None,
             gate_logits = _router_logits(h, layer_params, cfg)
     with jax.named_scope("sec_attn_core"):
         if linear:
-            proj = _linear_mixer(h, layer_params, cfg)
+            mixer = _linear_mixer if kind == LINEAR else _mamba_mixer
+            proj = mixer(h, layer_params, cfg)
         else:
             attn = _attention(q, k, v, cfg, mesh, window)
     with jax.named_scope("sec_attn_proj"):
@@ -1653,19 +1792,19 @@ def transformer_hidden(params, tokens, cfg: TransformerConfig, mesh=None,
                 partial(one_layer, layer_fns[0]), x, stack)
     else:
         P = len(pattern)
-        kind_of = stacked_by(cfg)
+        stacks = stacked_by(cfg)
 
         def layer_of(period_params, j):
             """Layer j of a period: its leaves at j — a mixer's at the
             layer's place among the period's layers of its kind, where the
             mixers are stacked by kind."""
-            if not cfg.has_linear:
+            if not cfg.recurrent_kind:
                 return jax.tree_util.tree_map(lambda a: a[j], period_params)
             i = cfg.kind_index(j)
-            mine = LINEAR if pattern[j] == LINEAR else ATTN
-            return {name: a[i if kind_of[name] == mine else j]
+            mine = kind_of(pattern[j])
+            return {name: a[i if stacks[name] == mine else j]
                     for name, a in period_params.items()
-                    if kind_of[name] in (None, mine)}
+                    if stacks[name] in (None, mine)}
 
         def period_body(x, period_params):
             auxes = []
@@ -2003,7 +2142,7 @@ def preset_from_workload(workload: Dict[str, Any]) -> TransformerConfig:
     overrides = {k: workload[k] for k in CONFIG_OVERRIDE_FIELDS if k in workload}
     if "layer_pattern" in overrides:  # JSON lists -> the hashable tuple form
         overrides["layer_pattern"] = tuple(
-            LINEAR if e == LINEAR else (int(e[0]), bool(e[1]))
+            e if e in RECURRENT else (int(e[0]), bool(e[1]))
             for e in overrides["layer_pattern"])
     if workload.get("attn") in ("ring", "ulysses", "flash", "dense"):
         overrides["attn_impl"] = workload["attn"]

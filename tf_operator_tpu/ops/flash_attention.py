@@ -993,6 +993,37 @@ def _kv_heads_per_step(h_kv: int, n: int, d: int, page_size: int,
     return 0
 
 
+def _rows_per_tile(r: int, g: int, h_kv: int, d: int, page_size: int,
+                   itemsize: int) -> int:
+    """Query positions ONE q tile of ``_paged_call`` takes: all ``r`` where
+    the tile fits (the shapes every cell before the multi-query one has:
+    their calls are what they were), else the largest divisor of r whose
+    tile — rows·g a multiple of 8 — does: a chunk at 20 query heads a KV
+    head is 5,120 rows at 256 positions, 18 MB of tile against the 12 MiB
+    budget, and goes as two tiles of 128. 0 when no divisor fits."""
+    for rows in range(r, 0, -1):
+        if r % rows == 0 and (rows == r or (rows * g) % 8 == 0) and \
+                _kv_heads_per_step(h_kv, rows * g, d, page_size, itemsize):
+            return rows
+    return 0
+
+
+def _row_tiles(q, page_table, seq_lens, q_start, rows: int):
+    """q [s, r, h, d] cut into r / rows tiles of ``rows`` consecutive
+    positions, each a sequence of its own for the kernel's grid: tile j of
+    sequence i starts at ``q_start[i] + j·rows``, walks the same page-table
+    row, and sees the sequence only as far as its own last row (``seq_lens``
+    cut there: a tile walks no page that all its rows mask; a tile wholly
+    past the sequence's length walks none)."""
+    s_n, r, h, d = q.shape
+    nb = r // rows
+    starts = q_start[:, None] + rows * jnp.arange(nb, dtype=q_start.dtype)[None]
+    lens = jnp.minimum(seq_lens[:, None], starts + rows)
+    lens = jnp.where(lens > starts, lens, 0)
+    return (q.reshape(s_n * nb, rows, h, d), jnp.repeat(page_table, nb, axis=0),
+            lens.reshape(-1), starts.reshape(-1))
+
+
 def _paged_call(q, k_pool, v_pool, layer, page_table, seq_lens, q_start,
                 interpret):
     """q [s, r, h, d] through the kernel: grid (s, h_kv / hb, page slots)."""
@@ -1104,8 +1135,9 @@ def flash_attention_decode(
     Dispatch mirrors flash_attention: the Pallas kernel engages on TPU
     (or under ``interpret=True`` — the CPU test path) when the page size
     is sublane-aligned for the pool dtype (8 rows of f32, 16 of bf16)
-    and a tile of several positions is too (r·g a multiple of 8, and
-    small enough to stay in VMEM with its carry);
+    and a tile of several positions is too (r·g a multiple of 8; a tile too
+    large to stay in VMEM with its carry is cut into tiles of fewer
+    positions that run in turn over the same pages, ``_rows_per_tile``);
     otherwise the pure-JAX gather reference (same math, same f32
     softmax, same NEG_INF masking) — the off-TPU path, so the serve
     engine runs everywhere. A TPU run that takes the reference says so
@@ -1134,9 +1166,11 @@ def flash_attention_decode(
                    f"({jnp.dtype(k_pages.dtype).name} sublanes)")
     elif r > 1 and (r * h // h_kv) % 8:
         why_not = f"{tile} is not a multiple of 8 rows"
-    elif not _kv_heads_per_step(h_kv, r * h // h_kv, q.shape[-1], page_size,
-                                jnp.dtype(k_pages.dtype).itemsize):
-        why_not = f"{tile} does not fit the kernel's VMEM"
+    else:
+        rows = _rows_per_tile(r, h // h_kv, h_kv, q.shape[-1], page_size,
+                              jnp.dtype(k_pages.dtype).itemsize)
+        if not rows:
+            why_not = f"{tile} does not fit the kernel's VMEM, cut or whole"
     on_tpu = jax.default_backend() == "tpu"
     use = why_not is None and (bool(interpret) or on_tpu)
     if force_kernel is not None:
@@ -1151,8 +1185,10 @@ def flash_attention_decode(
         k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     if q_start is None:
         q_start = seq_lens - r
-    o = _paged_call(
-        q.reshape(q.shape[0], r, h, q.shape[-1]), k_pages, v_pages, layer,
-        page_table, seq_lens, q_start, bool(interpret),
-    )
+    tiles = q.reshape(q.shape[0], r, h, q.shape[-1])
+    if rows < r:
+        tiles, page_table, seq_lens, q_start = _row_tiles(
+            tiles, page_table, seq_lens, q_start, rows)
+    o = _paged_call(tiles, k_pages, v_pages, layer, page_table, seq_lens,
+                    q_start, bool(interpret))
     return o.reshape(q.shape)

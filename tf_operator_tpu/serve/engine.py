@@ -54,18 +54,21 @@ from one thing the loop sees, whether a slot is prefilling:
 
 What the engine serves: a dense decoder of models/transformer.py — GQA
 layers with or without rotary embedding (a NoPE layer), pre- or post-norm
-(``norm_order``), q/k norms, a tied or an untied head — and LINEAR layers
-(Gated DeltaNet, ``layer_pattern`` entries ``"linear"``) among them. So it
+(``norm_order``), q/k norms, a tied or an untied head — and layers of ONE
+RECURRENT KIND among them (``layer_pattern`` entries ``"linear"``, Gated
+DeltaNet, or ``"mamba"``, Mamba-1's selective scan). So it
 holds TWO KINDS of sequence state (serve/kvcache.py): pages of K and V for
-the layers that attend, and for the linear layers a fixed-size recurrent
+the layers that attend, and for the recurrent layers a fixed-size recurrent
 state and convolution tail a batch SLOT (``StateStore``; slot ``max_slots``
 is the trash slot). A layer's place among the layers of its kind
 (``cfg.kind_index``) is its index into its kind's store and into its
-mixer's stacked weights. On the decode rows (of either program) a linear
-layer runs ``ops.gated_delta_step`` on every slot's state in place (an
+mixer's stacked weights. On the decode rows (of either program) a recurrent
+layer runs its kind's step (``ops.gated_delta_step`` /
+``ops.selective_scan_step``) on every slot's state in place (an
 inactive slot's reads and writes are steered to the trash slot, as its page
-writes go to the trash page); on a chunk's rows it runs the chunked scan
-(``ops.gated_delta_chunk``) from the sequence's state in its slot and
+writes go to the trash page); on a chunk's rows it runs the kind's scan
+over many tokens (``ops.gated_delta_chunk`` / ``ops.selective_scan_chunk``)
+from the sequence's state in its slot and
 leaves the state after the chunk's last VALID row there (padding rows
 write and decay nothing). Nothing resets a slot between requests: a
 sequence's FIRST chunk (``start == 0``) starts from zeros inside the
@@ -73,7 +76,7 @@ program. It refuses, by name, what does not run: experts, latent
 attention, a prediction module, a window layer.
 
 Both take the pair of KV pools and the pair of state arrays (``()`` for a
-model without linear layers) donated and hand them back, and between parameter and result the pool is never copied, sliced or
+model without recurrent layers) donated and hand them back, and between parameter and result the pool is never copied, sliced or
 relaid: the
 write is a scatter whose only window dimension is head_dim (the pool's
 minor-most, so XLA updates the head-major pool where it lies) and the
@@ -97,7 +100,7 @@ that advances decode rows, INSIDE ``serve.prefill`` when the run carries
 a chunk; the fetches of a step are of the step BEFORE it; ``serve.idle``;
 the closing ``serve.counters``);
 inside both programs the mixers carry ``jax.named_scope``s
-(``serve.lin_mixer`` / ``serve.full_attn``: metadata on the compiled
+(``serve.lin_mixer`` / ``serve.mamba_mixer`` / ``serve.full_attn``: metadata on the compiled
 instructions, nothing at run time).
 They cost well under a microsecond each while no profiler session is
 open and land in the xplane of ``profile_ctx`` / ``tpujob profile`` on
@@ -183,10 +186,11 @@ class EngineCounters:
     # host looked at this one's tokens. In the unit of ``prefill_chunks +
     # decode_steps``: a chunk that carries decode rows counts twice.
     runs_enqueued_ahead: int = 0
-    # a model with linear layers (0 without): its slots' recurrent state
+    # a model with recurrent layers, "linear" or "mamba" (0 without): its
+    # slots' recurrent state
     state_resets: int = 0      # first chunks: a slot's state started from zeros
     prefill_state_carries: int = 0  # later chunks: started from the slot's state
-    lin_slot_steps: int = 0    # recurrent steps: active slots x linear layers
+    lin_slot_steps: int = 0    # recurrent steps: active slots x recurrent layers
 
 
 @dataclass
@@ -200,6 +204,10 @@ class RunResult:
     counters: EngineCounters = field(default_factory=EngineCounters)
     pool_peak_in_use: int = 0
     pool_alloc_failures: int = 0
+    # the recurrent layers' (state, convolution tail) arrays as the last
+    # program left them, () for a model without: a slot holds what its last
+    # sequence left until the next one's first chunk
+    state: Tuple[Any, ...] = ()
 
     @property
     def completed(self) -> int:
@@ -364,7 +372,9 @@ class ServeEngine:
         import jax
 
         if cfg.n_experts:
-            raise ValueError("serve engine: MoE presets not supported")
+            raise ValueError(
+                "serve engine: MoE presets not supported (attending, 'linear' "
+                "and 'mamba' layers are served with the dense MLP only)")
         if cfg.attn_kind != "gqa" or cfg.mtp_depth:
             raise ValueError(
                 "serve engine: latent attention and a prediction module "
@@ -372,7 +382,8 @@ class ServeEngine:
         if any(window for window, _ in cfg.attn_kinds):
             raise ValueError(
                 "serve engine: the paged kernel has no sliding window; a "
-                "window layer runs in training only")
+                "window layer runs in training only, beside 'linear' or "
+                "'mamba' layers too")
         if getattr(cfg, "pp_stages", 0):
             raise ValueError("serve engine: pipeline presets not supported")
         if scfg.page_size < 1:
@@ -395,7 +406,7 @@ class ServeEngine:
 
         self.params = jax.tree_util.tree_map(f32, params)
         self.max_pages_per_seq = pages_needed(cfg.max_seq, scfg.page_size)
-        # the linear layers' state, a slot a batch slot; None without any
+        # the recurrent layers' state, a slot a batch slot; None without any
         self.store = StateStore.for_model(cfg, scfg.max_slots)
         self._jit_build()
 
@@ -407,12 +418,17 @@ class ServeEngine:
 
         from tf_operator_tpu.models.transformer import (
             LINEAR,
+            MAMBA,
+            RECURRENT,
             _head,
             _rms_norm,
             lin_conv_taps,
             lin_gates,
             lin_output,
             lin_project,
+            mamba_gates,
+            mamba_output,
+            mamba_project,
             rope_at_positions,
             stacked_by,
         )
@@ -421,6 +437,10 @@ class ServeEngine:
             gated_delta_chunk,
             gated_delta_step,
         )
+        from tf_operator_tpu.ops.selective_scan import (
+            selective_scan_chunk,
+            selective_scan_step,
+        )
 
         cfg = self.cfg
         ps = self.scfg.page_size
@@ -428,16 +448,17 @@ class ServeEngine:
         hd = cfg.head_dim
         eps = cfg.norm_eps
         post = cfg.norm_order == "post"
-        taps = cfg.lin_conv
+        taps = cfg.mamba_d_conv if cfg.recurrent_kind == MAMBA else cfg.lin_conv
+        scopes = {LINEAR: "serve.lin_mixer", MAMBA: "serve.mamba_mixer"}
         trash_slot = self.store.trash_slot if self.store else None
         kinds = [cfg.pattern[l % len(cfg.pattern)] for l in range(cfg.n_layers)]
-        # the linear mixer's leaves in SORTED order, as these programs have
+        # the recurrent mixer's leaves in SORTED order, as these programs have
         # always sliced them: the order of the slices is part of the
         # program's text, and the text is the persistent compile cache's key
-        lin_leaves = sorted(n for n, kind in stacked_by(cfg).items() if kind == LINEAR)
+        mixer_leaves = sorted(n for n, kind in stacked_by(cfg).items() if kind in RECURRENT)
 
         def _body(params, kp, vp, state, x, pos, attend, write_pid, write_row,
-                  linear):
+                  recurrent):
             """Shared per-layer body: x [n, d] at absolute positions pos
             [n]. A layer that ATTENDS writes each row's k/v to
             (write_pid[i], write_row[i]) then ``attend(q [n, h, hd], kp, vp,
@@ -445,8 +466,8 @@ class ServeEngine:
             pools [attending layers, page, h_kv, row, hd] are carried WHOLE
             through every layer — written by ``write_rows``, read by the
             kernel at ``layer=i`` — and never indexed by layer here:
-            ``kp[i]`` is a copy of a layer. A LINEAR layer hands its input
-            to the caller's ``linear(h, weights, i, state)``, which runs the
+            ``kp[i]`` is a copy of a layer. A RECURRENT layer hands its input
+            to the caller's ``recurrent(h, weights, i, state)``, which runs the
             mixer around the program's own view of the state store and
             returns (output [n, d], state). ``i`` is the layer's place among
             the layers of its kind: its index into its mixer's stacked
@@ -459,10 +480,10 @@ class ServeEngine:
                 # norm_order "post" (OLMo-2): the gain stands on the
                 # sublayer's OUTPUT, x + norm(F(x))
                 h = x if post else _rms_norm(x, lp["attn_norm"][l], eps)
-                if kind == LINEAR:
-                    with jax.named_scope("serve.lin_mixer"):
-                        y, state = linear(
-                            h, {name: lp[name][i] for name in lin_leaves}, i, state)
+                if kind in RECURRENT:
+                    with jax.named_scope(scopes[kind]):
+                        y, state = recurrent(
+                            h, {name: lp[name][i] for name in mixer_leaves}, i, state)
                 else:
                     with jax.named_scope("serve.full_attn"):
                         q, k = h @ lp["wq"][i], h @ lp["wk"][i]
@@ -509,13 +530,22 @@ class ServeEngine:
                                      valid=active, layer=i, slots=slot)
             return o, (st, cv)
 
+        def mamba_slot_taps(x, w, i, cv, active):
+            """One token a slot through a Mamba layer's convolution, over the
+            slot's tail and this input (an inactive slot reads and writes the
+            trash slot). Returns (u [s, inner], the slots, cv)."""
+            slot = jnp.where(active, jnp.arange(active.shape[0]), trash_slot)
+            ext = jnp.concatenate([cv[i, slot], x[:, None]], axis=1)
+            u = lin_conv_taps(ext, w["mamba_conv"], 1, w["mamba_conv_bias"])[:, 0]
+            return u, slot, cv.at[i, slot].set(ext[:, 1:])
+
         def decode_step(params, pools, state, table, seq_lens, tokens, active):
             """One token for every ACTIVE slot. tokens[i] sits at position
             seq_lens[i]; returns the token each slot decodes from NEXT: an
             active slot's greedy choice, any other slot's entry as it came
             in — the array is the next run's ``tokens`` as it stands, and
             the host reads the same array later. ``pools`` is the (K, V)
-            pair of page pools, ``state`` the linear layers' (recurrent
+            pair of page pools, ``state`` the recurrent layers' (recurrent
             state, convolution tail) pair, or () for a model without; both
             come back updated."""
             kp, vp = pools
@@ -530,9 +560,20 @@ class ServeEngine:
                 o, state = slot_mixer(pre, b, a, w, i, state, active)
                 return lin_output(o, z, w, cfg, h.dtype), state
 
+            def mamba(h, w, i, state):
+                """The convolution over each slot's tail, the gates, the
+                recurrent step on each slot's state in place."""
+                st, cv = state
+                x, z = mamba_project(h, w, cfg)
+                u, slot, cv = mamba_slot_taps(x, w, i, cv, active)
+                delta, B, C, A = mamba_gates(u, w, cfg)
+                y, st = selective_scan_step(u, delta, B, C, A, w["mamba_D"], st,
+                                            valid=active, layer=i, slots=slot)
+                return mamba_output(y, z, w, h.dtype), (st, cv)
+
             kp, vp, state, x = _body(
                 params, kp, vp, state, x, seq_lens, attend, pid, seq_lens % ps,
-                linear)
+                mamba if cfg.recurrent_kind == MAMBA else linear)
             logits = _rms_norm(x, params["final_norm"], eps) @ _head(params, cfg).T
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (kp, vp), state, jnp.where(active, nxt, tokens)
@@ -547,7 +588,7 @@ class ServeEngine:
             them. The chunk's rows are one query tile over the sequence's
             page-table row, causal per row; rows past ``n_valid`` lie past
             the sequence's length, see nothing and write to the trash page.
-            The linear layers carry the sequence's state in the slot from
+            The recurrent layers carry the sequence's state in the slot from
             chunk to chunk; the first chunk (``start == 0``) starts from
             zeros. The decode rows are ``decode_step``'s (``table``,
             ``seq_lens``, ``tokens``, ``active`` as there; the chunk's own
@@ -602,8 +643,36 @@ class ServeEngine:
                 return lin_output(jnp.concatenate([o_c, o_d]), z, w, cfg,
                                   h.dtype), state
 
+            def mamba(h, w, i, state):
+                """As ``linear``, for a Mamba layer: the projection over all
+                the rows; the convolution over the slot's tail + the chunk
+                and over each decoding slot's tail; the gates (two products
+                with a weight) over all the rows at once; the chunk through
+                the scan from its slot's state, the decode rows through the
+                step."""
+                st, cv = state
+                fresh = start == 0
+                x, z = mamba_project(h, w, cfg)
+                ext = jnp.concatenate([read_slot(cv, i, slot, fresh), x[:c]])
+                u_c = lin_conv_taps(ext, w["mamba_conv"], c, w["mamba_conv_bias"])
+                cv = write_slot(cv, i, slot, jax.lax.dynamic_slice_in_dim(
+                    ext, n_valid, taps - 1))
+                u_d, slots_d, cv = mamba_slot_taps(x[c:], w, i, cv, active)
+                u = jnp.concatenate([u_c, u_d])
+                delta, B, C, A = mamba_gates(u, w, cfg)
+                y_c, s1 = selective_scan_chunk(
+                    u_c, delta[:c], B[:c], C[:c], A, w["mamba_D"],
+                    read_slot(st, i, slot, fresh)[0], valid=valid)
+                st = write_slot(st, i, slot, s1[None])
+                y_d, st = selective_scan_step(
+                    u_d, delta[c:], B[c:], C[c:], A, w["mamba_D"], st,
+                    valid=active, layer=i, slots=slots_d)
+                return mamba_output(jnp.concatenate([y_c, y_d]), z, w,
+                                    h.dtype), (st, cv)
+
             kp, vp, state, x = _body(
-                params, kp, vp, state, x, pos, attend, pid, pos % ps, linear)
+                params, kp, vp, state, x, pos, attend, pid, pos % ps,
+                mamba if cfg.recurrent_kind == MAMBA else linear)
             rows = jnp.concatenate([x[c:], x[n_valid - 1][None]])
             logits = _rms_norm(rows, params["final_norm"], eps) @ _head(params, cfg).T
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -631,14 +700,22 @@ class ServeEngine:
         of its attention kernels, all layers (``pallas_grid_steps``; a
         step is one page of as many KV heads as the kernel's VMEM holds,
         and a chunk walks its sequence's pages once: slots x groups of
-        KV heads x page slots a layer; the linear layers' kernels count
+        KV heads x page slots a layer; the recurrent layers' kernels count
         here too: a step of ``gdn_step`` is one slot's heads, of
-        ``gdn_chunk_fwd`` one head's 64 positions; ``prefill`` holds the
+        ``gdn_chunk_fwd`` one head's 64 positions, of ``ssm_step`` one
+        slot's channels, of ``ssm_chunk_fwd`` 512 channels' 256 positions;
+        ``prefill`` holds the
         decode rows' kernels and grid steps beside the chunk's own: the
         paged kernel twice an attending layer, ``gdn_chunk_fwd`` and
-        ``gdn_step`` a linear one). ``<program>_kernels``
+        ``gdn_step`` a linear one, ``ssm_chunk_fwd`` and ``ssm_step`` a
+        Mamba one). ``<program>_paged_reference_calls`` counts the paged
+        attention calls of the COMPILED program that are not the kernel (one
+        call an attending layer, two in ``prefill``, less its
+        ``paged_attention`` instructions): they run the gather reference —
+        every one off the TPU, on it a call whose q tile no cut fits (0:
+        every attending layer runs the kernel). ``<program>_kernels``
         names the Pallas kernels (instructions by kernel name), and for a
-        model with linear layers ``<program>_state_copies`` counts for the
+        model with recurrent layers ``<program>_state_copies`` counts for the
         recurrent-state array what ``_pool_copies`` counts for the pool (0
         while a slot's state is read and written where it lies) and
         ``<program>_conv_tail_copies`` the same for the convolution tails
@@ -647,6 +724,7 @@ class ServeEngine:
         import jax
         import jax.numpy as jnp
 
+        from tf_operator_tpu.models.transformer import ATTN
         from tf_operator_tpu.parallel.collectives import compiled_kernels
 
         scfg = self.scfg
@@ -682,7 +760,12 @@ class ServeEngine:
             text = compiled.as_text()
             out[f"{name}_tpu_custom_calls"] = text.count("tpu_custom_call")
             out[f"{name}_pool_copies"] = pool_copies(text, self._pool_shape())
-            out[f"{name}_kernels"] = dict(compiled_kernels(text))
+            kernels = out[f"{name}_kernels"] = dict(compiled_kernels(text))
+            # an attending layer calls paged attention once over the decode
+            # rows and, in ``prefill``, once over the chunk
+            out[f"{name}_paged_reference_calls"] = (
+                self.cfg.n_of_kind(ATTN) * (1 + (name == "prefill"))
+                - kernels.get("paged_attention", 0))
             if self.store is not None:
                 out[f"{name}_state_copies"] = pool_copies(text, self.store.state_shape)
                 out[f"{name}_conv_tail_copies"] = pool_copies(
@@ -691,9 +774,11 @@ class ServeEngine:
         return out
 
     def _pool_shape(self):
+        from tf_operator_tpu.models.transformer import ATTN
+
         cfg, scfg = self.cfg, self.scfg
-        return (  # the layers that attend: a linear layer keeps no pages
-            cfg.n_of_kind(False), scfg.pool_pages + 1, cfg.n_kv_heads,
+        return (  # the layers that attend: a recurrent layer keeps no pages
+            cfg.n_of_kind(ATTN), scfg.pool_pages + 1, cfg.n_kv_heads,
             scfg.page_size, cfg.head_dim,
         )
 
@@ -1010,5 +1095,5 @@ class ServeEngine:
             generated_tokens=generated, free_pages_start=free_start,
             free_pages_end=pool.free_count, counters=counters,
             pool_peak_in_use=pool.peak_in_use,
-            pool_alloc_failures=pool.alloc_failures,
+            pool_alloc_failures=pool.alloc_failures, state=state,
         )

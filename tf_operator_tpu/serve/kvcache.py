@@ -1,18 +1,21 @@
 """The serve engine's sequence state: a paged KV cache for the layers that
-attend, and a slot-indexed recurrent-state store for the linear layers.
+attend, and a slot-indexed recurrent-state store for the recurrent layers.
 
 TWO KINDS of per-sequence state live side by side. A layer that ATTENDS
 keeps a key and a value per token: pages of a preallocated pool, below. A
-LINEAR (Gated DeltaNet) layer keeps a fixed-size state per sequence however
-long the sequence is — a [heads, d_k, d_v] float32 matrix and the last
-``taps − 1`` inputs of its convolution — in ``StateStore``, indexed by the
+layer of a RECURRENT kind (Gated DeltaNet, ``"linear"``; Mamba-1,
+``"mamba"``) keeps a fixed-size state per sequence however long the
+sequence is — a [heads, d_k, d_v] float32 array (a matrix a head for the
+delta rule; for Mamba one "head" of [d_state, channels], the channels on the
+lanes) and the last ``taps − 1`` inputs of its convolution — in
+``StateStore``, indexed by the
 engine's batch SLOT, one more slot than the engine has (the trash slot).
 Pages are allocated at admission and freed at completion; a slot's state
 needs no allocator (the slot is the allocation) and is reset inside the
 first prefill chunk of whoever takes the slot next. A model's layers index
 each store by their place among the layers of their kind
 (``TransformerConfig.kind_index``): the pool spans the attending layers
-only, the state store the linear ones.
+only, the state store the recurrent ones.
 
 The vLLM (SOSP '23) memory model in jax_graft form: decode K/V state
 lives in PAGES of ``page_size`` token slots, preallocated as one device
@@ -67,13 +70,14 @@ def pages_needed(tokens: int, page_size: int) -> int:
 @dataclass(frozen=True)
 class StateStore:
     """Geometry of the recurrent-state store: for each of ``n_layers``
-    linear layers and each of ``slots`` batch slots (+ 1: the trash slot)
+    recurrent layers and each of ``slots`` batch slots (+ 1: the trash slot)
     the recurrence's state ``[heads, d_k, d_v]`` and the convolution's tail
     ``[conv_rows, conv_channels]`` (the last taps − 1 inputs), float32. The
     two device arrays are the engine's (donated to its programs and handed
     back, like the pools); a decode step updates the state in place through
-    ``ops.gated_delta_step(..., layer=, slots=)``, a prefill chunk reads and
-    writes its one slot with the functions below."""
+    the kind's step (``ops.gated_delta_step`` / ``ops.selective_scan_step``
+    with ``layer=, slots=``), a prefill chunk reads and writes its one slot
+    with the functions below."""
 
     n_layers: int
     slots: int
@@ -85,10 +89,15 @@ class StateStore:
 
     @classmethod
     def for_model(cls, cfg, slots: int) -> Optional["StateStore"]:
-        """The store a model's linear layers need; None for a model without."""
-        if not cfg.has_linear:
+        """The store a model's recurrent layers need; None for a model
+        without. A Mamba layer is one head of [d_state, channels]."""
+        kind = cfg.recurrent_kind
+        if kind is None:
             return None
-        return cls(cfg.n_of_kind(True), slots, cfg.lin_heads, cfg.lin_dk,
+        if kind == "mamba":
+            return cls(cfg.n_of_kind(kind), slots, 1, cfg.mamba_d_state,
+                       cfg.mamba_inner, cfg.mamba_d_conv - 1, cfg.mamba_inner)
+        return cls(cfg.n_of_kind(kind), slots, cfg.lin_heads, cfg.lin_dk,
                    cfg.lin_dv, cfg.lin_conv - 1, cfg.lin_conv_channels)
 
     @property
@@ -106,7 +115,7 @@ class StateStore:
 
     @property
     def slot_bytes(self) -> int:
-        """Bytes ONE sequence's state takes over all the linear layers."""
+        """Bytes ONE sequence's state takes over all the recurrent layers."""
         return 4 * self.n_layers * (
             self.heads * self.d_k * self.d_v + self.conv_rows * self.conv_channels)
 
@@ -156,7 +165,7 @@ def pool_bytes(
 ) -> int:
     """Device bytes of the sequence state of both kinds: the K+V pools over
     the ``n_layers`` ATTENDING layers (including the trash page) and, for a
-    model with linear layers, its ``StateStore`` — the number
+    model with recurrent layers, its ``StateStore`` — the number
     tools/memplan.py budgets for a serve job."""
     per_side = (
         n_layers * (num_pages + 1) * page_size * n_kv_heads * head_dim

@@ -278,6 +278,7 @@ def serve_plan(preset_name: str, workload: dict | None = None,
     import jax
 
     from tf_operator_tpu.models.transformer import (
+        ATTN,
         init_transformer,
         preset_from_workload,
     )
@@ -298,8 +299,8 @@ def serve_plan(preset_name: str, workload: dict | None = None,
     params_b = sum(
         math.prod(leaf.shape) * 4 for leaf in jax.tree_util.tree_leaves(shapes)
     )
-    kv_b = pool_bytes(  # pages over the attending layers + the linear layers' state
-        cfg.n_of_kind(False), kv_pool_pages, kv_page_size,
+    kv_b = pool_bytes(  # pages over the attending layers + the recurrent layers' state
+        cfg.n_of_kind(ATTN), kv_pool_pages, kv_page_size,
         cfg.n_kv_heads, cfg.head_dim, dtype_bytes=4,
         state=StateStore.for_model(cfg, max_slots),
     )
