@@ -607,6 +607,116 @@ def test_engine_programs_hand_the_token_array_on_v5e(request, fixture):
         assert report.get(f"{program}_state_copies", 0) == 0
 
 
+# ---- every product reads its weight where it lies ---------------------------
+
+# a weight's slice written out in bfloat16, transposed, then relaid in front of
+# the product that reads it: what both programs held for wq, wk and wv (and the
+# linear mixer's lin_wz) before PR 48 — and the same product reading the
+# stacked parameter itself, as it does since
+PLANTED_COPIED = """
+%fused_slice (param_0: f32[2,64,32]) -> bf16[32,64] {
+  %param_0 = f32[2,64,32]{2,1,0:T(8,128)} parameter(0)
+  %slice.1 = bf16[1,64,32]{2,1,0:T(8,128)(2,1)} slice(%param_0), slice={[1:2], [0:64], [0:32]}
+  ROOT %bitcast.1 = bf16[32,64]{0,1:T(8,128)(2,1)S(1)} bitcast(%slice.1)
+}
+
+%fused_product (param_0.1: bf16[32,64], param_1: f32[16,64]) -> f32[16,4,8] {
+  %param_0.1 = bf16[32,64]{1,0:T(8,128)(2,1)} parameter(0)
+  %param_1 = f32[16,64]{1,0:T(8,128)} parameter(1)
+  ROOT %convolution.1 = f32[16,4,8]{2,1,0:T(8,128)} convolution(%param_1, %param_0.1), dim_labels=bf_io->bf
+}
+
+ENTRY %main (wq: f32[2,64,32], h: f32[16,64]) -> f32[16,4,8] {
+  %wq = f32[2,64,32]{2,1,0:T(8,128)} parameter(0)
+  %h = f32[16,64]{1,0:T(8,128)} parameter(1)
+  %slice_bitcast_fusion = bf16[32,64]{0,1:T(8,128)(2,1)S(1)} fusion(%wq), kind=kLoop, calls=%fused_slice
+  %copy.1 = bf16[32,64]{1,0:T(8,128)(2,1)S(1)} copy(%slice_bitcast_fusion)
+  ROOT %fusion.1 = f32[16,4,8]{2,1,0:T(8,128)} fusion(%copy.1, %h), kind=kOutput, calls=%fused_product
+}
+"""
+PLANTED_IN_PLACE = """
+%fused_slice (param_0: f32[2,64,32]) -> bf16[64,32] {
+  %param_0 = f32[2,64,32]{2,1,0:T(8,128)} parameter(0)
+  %slice.1 = bf16[1,64,32]{2,1,0:T(8,128)(2,1)} slice(%param_0), slice={[1:2], [0:64], [0:32]}
+  ROOT %bitcast.1 = bf16[64,32]{1,0:T(8,128)(2,1)} bitcast(%slice.1)
+}
+
+%fused_product (param_0.1: f32[2,64,32], param_1: f32[16,64]) -> f32[16,32] {
+  %param_0.1 = f32[2,64,32]{2,1,0:T(8,128)} parameter(0)
+  %param_1 = f32[16,64]{1,0:T(8,128)} parameter(1)
+  %fusion.2 = bf16[64,32]{1,0:T(8,128)(2,1)} fusion(%param_0.1), kind=kLoop, calls=%fused_slice
+  ROOT %convolution.1 = f32[16,32]{1,0:T(8,128)} convolution(%param_1, %fusion.2), dim_labels=bf_io->bf
+}
+
+ENTRY %main (wq: f32[2,64,32], h: f32[16,64]) -> f32[16,32] {
+  %wq = f32[2,64,32]{2,1,0:T(8,128)} parameter(0)
+  %h = f32[16,64]{1,0:T(8,128)} parameter(1)
+  %copy-start = (f32[1,64,32]{2,1,0:T(8,128)S(1)}, f32[1,64,32]{2,1,0:T(8,128)}, u32[]{:S(2)}) copy-start(%wq), cross_program_prefetch_index=0
+  %copy-done = f32[1,64,32]{2,1,0:T(8,128)S(1)} copy-done(%copy-start)
+  ROOT %fusion.1 = f32[16,32]{1,0:T(8,128)} fusion(%wq, %h), kind=kOutput, calls=%fused_product
+}
+"""
+
+
+@pytest.mark.parametrize("text,want", [(PLANTED_COPIED, 2), (PLANTED_IN_PLACE, 0)],
+                         ids=["copied", "in_place"])
+def test_weight_copies_counts_a_weight_written_out_before_its_product(text, want):
+    """``compile()``'s ``<program>_weight_copies`` on planted text, no compiler:
+    the conversion fusion and the relayout ``copy`` count, one each; a slice
+    fused INTO its product, the product itself and the compiler's prefetch of
+    the parameter into faster memory do not, nor does any result of other
+    dimensions (the activations, a weight that is not in the list)."""
+    from tf_operator_tpu.serve.engine import weight_copies
+
+    assert weight_copies(text, [(64, 32)]) == want
+    assert weight_copies(text, [(64, 64), (16, 64)]) == 0
+
+
+def _multiplied_in_place(text: str, leaf: str) -> bool:
+    """Whether the stacked parameter ``params["layers"][leaf]`` ITSELF — or a
+    view of it (a ``bitcast``: the one layer of a stack of one), or the
+    compiler's own prefetch of it or of a layer's slice into faster memory,
+    float32 in the parameter's layout still — is an operand of an executed
+    fusion whose computation holds a convolution."""
+    import re
+
+    names = set(re.findall(rf"(%params__layers____{leaf}__[.\d]*) = ", text))
+    views = re.findall(
+        r"(%[\w.\-]+) = .*? (?:bitcast|(?:copy|slice)-(?:start|done))\((%[\w.\-]+)[,)]",
+        text)
+    for _ in range(3):  # parameter -> view -> <op>-start -> <op>-done
+        names |= {name for name, operand in views if operand in names}
+    for m in re.finditer(r" fusion\(([^)]*)\).*?calls=%([\w.\-]+)", text):
+        if names & set(m.group(1).split(", ")):
+            body = text.split(f"\n%{m.group(2)} (", 1)[1].split("\n}", 1)[0]
+            if " convolution(" in body:
+                return True
+    return False
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("fixture,leaves", [
+    ("serve1_engine", ("wq", "wk", "wv")),
+    ("hybrid_engine", ("lin_wz", "wv")),
+    ("jamba_engine", ("wq", "wk", "wv")),
+])
+def test_engine_products_read_their_weight_in_place_on_v5e(
+        request, fixture, leaves, program):
+    """A product whose result is split by heads at once (q, k, v; the linear
+    mixer's gate z) reads its weight as ``wo`` and the MLP always have: the
+    stacked f32 parameter is an operand of the fusion that multiplies it.
+    Before PR 48 the compiler folded the head reshape into the product and
+    fed it a transposed bfloat16 copy of the weight's slice, relaid by a
+    ``copy``: 72 weight-sized results a program at the -serve1 cell's 12
+    layers, 16 at the hybrid cell's 8, 1.2 GB written and 1.8 GB read a run
+    that nothing needed."""
+    engine, report = request.getfixturevalue(fixture)
+    assert report[f"{program}_weight_copies"] == 0
+    text = getattr(engine, f"_{program}").as_text()
+    for leaf in leaves + ("wo", "w_up"):
+        assert _multiplied_in_place(text, leaf), leaf
+
+
 def test_kv_heads_per_step_at_thirty_heads():
     """30 KV heads (divisors 1, 2, 3, 5, 6, 10, 15, 30; no power of two above
     2): every head of a one-row decode tile in one step, 10 a step for a

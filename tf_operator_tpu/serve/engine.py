@@ -88,6 +88,13 @@ layer in front of every kernel. ``compile()`` counts what is left of
 that (``<program>_pool_copies``, 0; ``<program>_state_copies`` for the
 recurrent state).
 
+The weights are read where they lie too: every product of both programs has
+its weight's slice of the stacked float32 parameter as an operand of the
+fusion that multiplies it, whatever is done to the product's result
+afterwards (``whole`` in ``_jit_build`` says how and why); ``compile()``
+counts the copies of a weight a program makes (``<program>_weight_copies``,
+0).
+
 Greedy argmax sampling, f32 compute throughout: serving determinism is
 what the correctness oracle (tests/test_serve.py) and the seeded bench
 artifact pin against.
@@ -267,30 +274,24 @@ def greedy_reference_gaps(cfg, params, prompt: List[int], tokens: List[int]):
     return int(jnp.sum(gaps == 0.0)), float(jnp.max(gaps))
 
 
-# one HLO instruction: ``%name = f32[2,321,8,64,128]{layout} opcode(...``
-_HLO_ARRAY_INSTR = re.compile(
-    r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\((.*)$"
-)
+# one HLO instruction: ``%name = f32[2,321,8,64,128]{layout} opcode(...``, or
+# with a tuple of such arrays for its result (a multi-output fusion)
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%\S+ = (.*?) ([\w\-]+)\((.*)$")
+_HLO_ARRAY = re.compile(r"\w+\[([\d,]*)\]")
 _HLO_FREE = frozenset({
     # name or view a buffer, or update it where it lies
     "parameter", "get-tuple-element", "bitcast", "while", "conditional",
-    "call", "scatter", "dynamic-update-slice",
+    "call", "scatter", "dynamic-update-slice", "tuple",
 })
 
 
-def pool_copies(hlo_text: str, pool_shape) -> int:
-    """How many instructions of a compiled program MATERIALISE a whole KV
-    pool side or one whole layer of it: executed instructions (fusion
-    bodies are read only to classify their fusion) whose result has the
-    pool's shape ``[L, P+1, h_kv, page, hd]`` or a layer's (``[1, P+1,
-    …]`` / ``[P+1, …]``) and that are neither a name or view of a buffer
-    nor an update in place (a scatter or dynamic-update-slice, bare or as
-    a fusion's root; a custom call that aliases its operand). What is
-    left is a ``copy``, ``slice``, ``copy-done`` or relayout fusion: data
-    movement of 84 MB to 1 GB at the -serve1 shapes that changes no
-    value. 0 when the pool is written and read in one layout, in place."""
-    dims = [str(int(d)) for d in pool_shape]
-    shapes = {",".join(dims), ",".join(["1"] + dims[1:]), ",".join(dims[1:])}
+def _executed(hlo_text: str):
+    """The instructions a compiled program EXECUTES, less those that only
+    name or view a buffer or update it where it lies (``_HLO_FREE``), as
+    (the result arrays' dims — one, or each of a multi-output fusion's
+    tuple —, opcode, the opcodes INSIDE the computation a fusion calls, the
+    rest of the line): fusion bodies are read only to classify their
+    fusion, and an asynchronous op's result is its ``-done``'s."""
     bodies: Dict[str, List[str]] = {}
     name = None
     for line in hlo_text.splitlines():
@@ -301,28 +302,88 @@ def pool_copies(hlo_text: str, pool_shape) -> int:
             bodies[name].append(line)
     fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", hlo_text))
 
-    def in_place(rest: str) -> bool:
+    def opcodes(rest: str) -> set:
         called = re.search(r"calls=%([\w.\-]+)", rest)
-        return bool(called) and any(
-            " scatter(" in ln or " dynamic-update-slice(" in ln
-            for ln in bodies.get(called.group(1), ())
-        )
+        return {m.group(2) for m in map(
+            _HLO_INSTR.match, bodies.get(called.group(1), ()) if called else ()) if m}
 
-    count = 0
+    instrs = []
     for comp, lines in bodies.items():
         if comp in fused:
             continue
         for line in lines:
-            m = _HLO_ARRAY_INSTR.match(line)
-            if not m or m.group(1) not in shapes or m.group(2) in _HLO_FREE:
-                continue
-            op, rest = m.group(2), m.group(3)
-            if op == "fusion" and in_place(rest):
-                continue
-            if op == "custom-call" and "output_to_operand_aliasing" in rest:
-                continue
-            count += 1
+            m = _HLO_INSTR.match(line)
+            if m and m.group(2) not in _HLO_FREE and not m.group(2).endswith("-start"):
+                results, op, rest = m.groups()
+                instrs.append((_HLO_ARRAY.findall(results), op,
+                               opcodes(rest) if op == "fusion" else set(), rest))
+    return instrs
+
+
+def pool_copies(hlo_text: str, pool_shape) -> int:
+    """How many instructions of a compiled program MATERIALISE a whole KV
+    pool side or one whole layer of it: executed instructions whose result
+    has the pool's shape ``[L, P+1, h_kv, page, hd]`` or a layer's (``[1,
+    P+1, …]`` / ``[P+1, …]``) and that are neither a name or view of a
+    buffer nor an update in place (a scatter or dynamic-update-slice, bare
+    or as a fusion's root; a custom call that aliases its operand). What is
+    left is a ``copy``, ``slice``, ``copy-done`` or relayout fusion: data
+    movement of 84 MB to 1 GB at the -serve1 shapes that changes no value.
+    0 when the pool is written and read in one layout, in place."""
+    dims = [str(int(d)) for d in pool_shape]
+    shapes = {",".join(dims), ",".join(["1"] + dims[1:]), ",".join(dims[1:])}
+    count = 0
+    for results, op, inside, rest in _executed(hlo_text):
+        if len(results) != 1 or results[0] not in shapes:
+            continue
+        if inside & {"scatter", "dynamic-update-slice"}:
+            continue
+        if op == "custom-call" and "output_to_operand_aliasing" in rest:
+            continue
+        count += 1
     return count
+
+
+def weight_copies(hlo_text: str, weight_shapes) -> int:
+    """How many results of a compiled program are a COPY of one layer's
+    slice of a stacked matmul weight: results of executed instructions
+    (each array of a multi-output fusion's tuple is one) whose dimensions
+    are a weight's ``[in, out]`` in either order (1s aside), made by
+    something that multiplies nothing — a ``copy``, a ``transpose``, a
+    ``slice``, a fusion that holds no ``convolution`` / ``dot``. A product
+    reads its weight as an operand of its own fusion, from the stacked
+    parameter, so such a result is the weight written out again (converted,
+    transposed, relaid: 17 to 67 MB a time at the -serve1 widths) before
+    anything multiplies it. 0 when every product reads its weight where it
+    lies. ``weight_shapes`` are the slices' ``(in, out)``; matched by
+    dimensions, not by element count: a run's activations can have a small
+    weight's count (320 rows x 2,560 = 160 x 5,120 at the Jamba shapes). Not
+    counted: the compiler's own PREFETCH of a parameter's slice into faster
+    memory (an asynchronous ``copy-done`` / ``slice-done``: the same type
+    and layout, moved once, and the product reads it there)."""
+    shapes = {tuple(sorted(int(d) for d in s)) for s in weight_shapes}
+    count = 0
+    for results, op, inside, _ in _executed(hlo_text):
+        if op.endswith("-done") or (inside | {op}) & {"convolution", "dot"}:
+            continue
+        count += sum(
+            tuple(sorted(int(d) for d in dims.split(",") if d not in ("", "1")))
+            in shapes for dims in results)
+    return count
+
+
+def multiplied_shapes(jaxpr) -> set:
+    """The shapes of every operand of every product (``dot_general``) a
+    traced program holds, nested jaxprs included."""
+    from jax.core import jaxprs_in_params
+
+    shapes = set()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            shapes.update(tuple(v.aval.shape) for v in eqn.invars)
+        for sub in jaxprs_in_params(eqn.params):
+            shapes |= multiplied_shapes(sub)
+    return shapes
 
 
 def pallas_grid_steps(jaxpr) -> int:
@@ -457,6 +518,23 @@ class ServeEngine:
         # program's text, and the text is the persistent compile cache's key
         mixer_leaves = sorted(n for n, kind in stacked_by(cfg).items() if kind in RECURRENT)
 
+        def whole(*products):
+            """The products ``rows @ weight`` [n, out] whose result is about to
+            be split by heads, held WHOLE until they are computed. THE RULE: a
+            product's weight is an operand of the fusion that multiplies it,
+            read once from the stacked f32 parameter where it lies. Left to
+            itself the TPU compiler folds the head reshape into the product,
+            the folded product wants its weight in a layout the stacked
+            parameter does not have, and the weight's slice is first written
+            out as a transposed bfloat16 copy and then relaid — the weight
+            moved three times a run where once would do. Behind the barrier
+            the 2-D product is the one ``wo`` and the MLP have always had;
+            the reshape that follows is a view of ``[n, out]`` rows. No value
+            changes: the same DEFAULT-precision product of the same float32
+            operands. ``compile()`` counts what a program still materialises
+            (``<program>_weight_copies``, 0)."""
+            return jax.lax.optimization_barrier(products)
+
         def _body(params, kp, vp, state, x, pos, attend, write_pid, write_row,
                   recurrent):
             """Shared per-layer body: x [n, d] at absolute positions pos
@@ -486,13 +564,14 @@ class ServeEngine:
                             h, {name: lp[name][i] for name in mixer_leaves}, i, state)
                 else:
                     with jax.named_scope("serve.full_attn"):
-                        q, k = h @ lp["wq"][i], h @ lp["wk"][i]
+                        q, k, v = whole(
+                            h @ lp["wq"][i], h @ lp["wk"][i], h @ lp["wv"][i])
                         if cfg.qk_norm:
                             q = _rms_norm(q, lp["q_norm"][i], eps)
                             k = _rms_norm(k, lp["k_norm"][i], eps)
                         q = q.reshape(n, -1, hd)
                         k = k.reshape(n, -1, hd)
-                        v = (h @ lp["wv"][i]).reshape(n, -1, hd)
+                        v = v.reshape(n, -1, hd)
                         if kind[1]:  # rotary; a NoPE layer attends by content alone
                             q = rope_at_positions(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
                             k = rope_at_positions(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
@@ -557,6 +636,7 @@ class ServeEngine:
 
             def linear(h, w, i, state):
                 pre, z, b, a = lin_project(h, w, cfg)
+                z, = whole(z)  # ``lin_output`` splits it by heads
                 o, state = slot_mixer(pre, b, a, w, i, state, active)
                 return lin_output(o, z, w, cfg, h.dtype), state
 
@@ -629,6 +709,7 @@ class ServeEngine:
                 st, cv = state
                 fresh = start == 0
                 pre, z, b, a = lin_project(h, w, cfg)
+                z, = whole(z)  # ``lin_output`` splits it by heads
                 ext = jnp.concatenate([read_slot(cv, i, slot, fresh), pre[:c]])
                 u = lin_conv_taps(ext, w["lin_conv"], c)
                 # ext row r is position start + r - (taps - 1)
@@ -696,7 +777,11 @@ class ServeEngine:
         ``<program>_pool_copies``: how many of its instructions
         materialise a whole pool side or a whole layer of one
         (``pool_copies``; 0 while the pool is written and read in place,
-        in one layout), and ``<program>_attn_grid_steps``: the grid steps
+        in one layout), ``<program>_weight_copies``: how many of its results
+        are one layer's slice of a stacked matmul weight written out again by
+        something that multiplies nothing (``weight_copies``; 0 while every
+        product reads its weight as its own fusion's operand, from the
+        stacked parameter), and ``<program>_attn_grid_steps``: the grid steps
         of its attention kernels, all layers (``pallas_grid_steps``; a
         step is one page of as many KV heads as the kernel's VMEM holds,
         and a chunk walks its sequence's pages once: slots x groups of
@@ -760,6 +845,14 @@ class ServeEngine:
             text = compiled.as_text()
             out[f"{name}_tpu_custom_calls"] = text.count("tpu_custom_call")
             out[f"{name}_pool_copies"] = pool_copies(text, self._pool_shape())
+            # a stacked matmul weight: a leaf [layers, in, out] whose slice is
+            # an operand of one of the program's products (the convolutions'
+            # taps and a decay's logarithm are stacked the same way and
+            # multiply nothing)
+            out[f"{name}_weight_copies"] = weight_copies(text, {
+                leaf.shape[1:] for leaf in self.params["layers"].values()
+                if len(leaf.shape) == 3
+            } & multiplied_shapes(traced.jaxpr.jaxpr))
             kernels = out[f"{name}_kernels"] = dict(compiled_kernels(text))
             # an attending layer calls paged attention once over the decode
             # rows and, in ``prefill``, once over the chunk
