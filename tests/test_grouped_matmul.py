@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tf_operator_tpu.ops.grouped_matmul import gmm
+from tf_operator_tpu.ops.grouped_matmul import (_combine_pairs, _token_tile,
+                                                 combine_rows, gmm)
 
 B = 8  # small block quantum so tests exercise multi-block experts cheaply
 
@@ -180,3 +181,108 @@ def test_plan_cols_counts_every_resident_tile(name, args, want):
         assert need <= _VMEM_SCOPED_DEFAULT
     else:
         assert _VMEM_SCOPED_DEFAULT < need < limit <= _VMEM_ASK_MAX
+
+
+# ---- combine_rows: a segment's rows added onto their tokens ----------------
+
+
+def _segment(seed, tokens, k_top, experts, held, n_blocks=None, never=()):
+    """(tok, valid) of one segment in gmm's layout: every token's ``k_top``
+    distinct choices of ``experts`` (none of ``never``), the held experts'
+    choices sorted stably by expert, each expert padded to the block quantum
+    B (invalid slots read token 0, as the walk's do) and the tail filled
+    with sentinel blocks up to ``n_blocks``."""
+    rng = np.random.default_rng(seed)
+    open_to = [e for e in range(experts) if e not in never]
+    chosen = np.stack([rng.choice(open_to, k_top, replace=False)
+                       for _ in range(tokens)]).reshape(-1)
+    order = np.argsort(chosen, kind="stable")
+    tok, valid = [], []
+    for e in range(held):
+        mine = order[chosen[order] == e] // k_top
+        pad = -len(mine) % B
+        tok += [*mine, *[0] * pad]
+        valid += [*[True] * len(mine), *[False] * pad]
+    tail = (n_blocks or 0) * B - len(tok)
+    assert tail >= 0 or n_blocks is None
+    tok += [0] * max(tail, 0)
+    valid += [False] * max(tail, 0)
+    return jnp.asarray(tok, jnp.int32), jnp.asarray(valid)
+
+
+COMBINE_CASES = {
+    # tokens, d, k_top, experts, held + what the case is there for; the token
+    # tile is the op's own choice, the widest of 512 ... 8 that divides the
+    # tokens: 64 is one tile, 80 five of 16, 40 five of 8, 1024 two of 512
+    "bf16-d128": dict(shape=(64, 128, 2, 8, 4), dtype=jnp.bfloat16),
+    "f32-d512": dict(shape=(64, 512, 2, 8, 4), dtype=jnp.float32),
+    "bf16-d512-five-tiles-of-16": dict(shape=(80, 512, 3, 8, 8),
+                                       dtype=jnp.bfloat16, tile=16),
+    # every token is named by every held expert: each group meets each tile,
+    # which is the most pairs a routing can list
+    "a-token-in-every-experts-blocks": dict(
+        shape=(40, 128, 4, 4, 4), dtype=jnp.bfloat16, tile=8),
+    "tail-of-sentinel-blocks": dict(shape=(64, 128, 2, 8, 4),
+                                    dtype=jnp.bfloat16, n_blocks=24),
+    "an-expert-with-no-row": dict(shape=(80, 128, 2, 8, 4),
+                                  dtype=jnp.float32, never=(1,), tile=16),
+    "valid-all-false": dict(shape=(80, 128, 2, 8, 4), dtype=jnp.bfloat16,
+                            none_valid=True, tile=16),
+    "the-backwards-two-operands": dict(shape=(64, 128, 2, 8, 4),
+                                       dtype=jnp.bfloat16, operands=2),
+    "f32-and-bf16-operands-24-tokens": dict(
+        shape=(24, 128, 2, 4, 2), dtype=jnp.float32, operands=2, tile=8),
+    # the cells' own tile, twice: ~130 chunks of 8 rows against 2 tiles
+    "two-tiles-of-512": dict(shape=(1024, 128, 2, 8, 4), dtype=jnp.bfloat16,
+                             tile=512),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COMBINE_CASES))
+def test_combine_rows_is_the_scatter_add_and_its_vjp(case):
+    """``combine_rows`` (the ``moe_combine`` kernel's body, through the
+    interpreter) against ``acc.at[tok].add(where(valid, rows, 0))``: the
+    result, and the cotangents of ``acc`` and of every row operand against
+    ``jax.grad`` of the scatter form. The selection is exact and a token's
+    rows are added in the slots' order, which is the order XLA's CPU scatter
+    applies them in, so the two agree to the last bit of float32 but for
+    the association of the operands' sum."""
+    spec = COMBINE_CASES[case]
+    tokens, d, k_top, experts, held = spec["shape"]
+    tok, valid = _segment(3, tokens, k_top, experts, held,
+                          spec.get("n_blocks"), spec.get("never", ()))
+    if spec.get("none_valid"):
+        valid = jnp.zeros_like(valid)
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    acc = jax.random.normal(keys[0], (tokens, d), jnp.float32)
+    dtypes = [spec["dtype"], jnp.bfloat16][:spec.get("operands", 1)]
+    rows = tuple(jax.random.normal(k, (tok.shape[0], d), jnp.float32).astype(dt)
+                 for k, dt in zip(keys[1:], dtypes))
+    weigh = jax.random.normal(keys[3], (tokens, d), jnp.float32)
+    run = dict(groups=held, block_rows=B, interpret=True)
+
+    def kernel(acc, rows):
+        return combine_rows(acc, rows if len(rows) > 1 else rows[0], tok,
+                            valid, **run)
+
+    def scatter(acc, rows):
+        add = sum(r.astype(jnp.float32) for r in rows)
+        return acc.at[tok].add(jnp.where(valid[:, None], add, 0))
+
+    tile, _ = _token_tile(tokens, d, B * d * sum(
+        jnp.dtype(dt).itemsize for dt in dtypes))
+    assert tile == spec.get("tile", tokens)
+    _, _, n_pairs = _combine_pairs(
+        jnp.where(valid, tok, -1).reshape(-1, 1, B), tokens, tile, held)
+    assert int(n_pairs[0]) <= held * (tokens // tile) + tok.shape[0] // B
+    if spec.get("none_valid"):
+        assert int(n_pairs[0]) == 0
+    np.testing.assert_allclose(kernel(acc, rows), scatter(acc, rows),
+                               rtol=1e-6, atol=1e-6)
+    got = jax.grad(lambda a, r: jnp.sum(kernel(a, r) * weigh), (0, 1))(acc, rows)
+    want = jax.grad(lambda a, r: jnp.sum(scatter(a, r) * weigh), (0, 1))(acc, rows)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))

@@ -141,14 +141,20 @@ def test_the_expert_walks_backward_is_filed_as_backward_and_named(compiled):
     key_of = {n: k for k, v in trainer.step_sections.items() for n in v}
     comps, _ = _computations(trainer.text)
     walk = re.compile(r'op_name="[^"]*/while/body/sec_moe_(dispatch|experts)/')
+    # not the entry: a constant hoisted out of a walk keeps the walk's path
     bodies = {c: lines for c, lines in comps.items()
-              if any(walk.search(line) for line in lines)}
+              if not c.startswith("main")
+              and any(walk.search(line) for line in lines)}
     assert bodies
     keys, backward = set(), set()
     for lines in bodies.values():
         for line in lines:
             m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line)
             if not m or m.group(1) not in key_of:
+                continue
+            # the CPU's copy insertion: the [T, d] carry on its way into the
+            # interpreted ``moe_combine``'s own loop, with no metadata at all
+            if " copy(" in line and "metadata=" not in line:
                 continue
             key = key_of[m.group(1)]
             keys.add(key)

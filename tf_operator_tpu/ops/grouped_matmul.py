@@ -479,3 +479,203 @@ def _gmm_scaled_bwd_rule(block_rows, block_cols, interpret, res, dy):
 
 
 _gmm_scaled.defvjp(_gmm_scaled_fwd_rule, _gmm_scaled_bwd_rule)
+
+
+# ---- the combine: a segment's rows added onto their tokens, in place -------
+
+
+def _token_tile(tokens: int, d: int, rows_bytes: int):
+    """(token tile, vmem_limit_bytes or None) for ``combine_rows``: the
+    largest tile of at most 512 tokens that divides ``tokens`` (all of them
+    where none does) and whose resident set fits what a kernel may ask for.
+    Resident: the f32 [tile, d] carry tile in and out, each double-buffered,
+    the product the dot leaves before it is added (twice: one being summed
+    while the next is made), and the double-buffered row chunks
+    (``rows_bytes``)."""
+    for tile in [t for t in (512, 256, 128, 64, 32, 16, 8)
+                 if tokens % t == 0] or [tokens]:
+        need = 6 * tile * d * 4 + 2 * rows_bytes
+        if need * 5 // 4 <= _VMEM_ASK_MAX:
+            break
+    else:
+        raise ValueError(
+            f"combine_rows: d={d} keeps {need / 2**20:.1f} MiB resident in "
+            f"VMEM at a tile of {tile} tokens; the kernel has no column "
+            f"tiling, so it is good up to ~{_VMEM_ASK_MAX // 2**20} MiB")
+    if need <= _VMEM_SCOPED_DEFAULT * 7 // 8:
+        return tile, None
+    return tile, need * 5 // 4
+
+
+def _combine_pairs(tok_lanes, tokens: int, tile: int, groups: int):
+    """The (token tile, row chunk) pairs of one combine, tile-major, as
+    (pair_tile [P], pair_chunk [P], n_pairs [1]) for the scalar prefetch,
+    from ``tok_lanes`` [n_chunks, 1, chunk]: a row's token, -1 where invalid.
+    A chunk lies inside ONE group's ascending rows, so the tiles it meets
+    are the run from its first valid token's to its last valid token's, and
+    consecutive chunks of a group share at most one tile: a group lists at
+    most ``n_tiles - 1`` pairs beyond its chunks, which makes
+    P = groups · n_tiles + n_chunks a bound no routing exceeds. Entries
+    behind the last pair repeat it — the same blocks, so nothing is
+    fetched for a step that adds nothing."""
+    n_tiles, n_chunks = tokens // tile, tok_lanes.shape[0]
+    # a chunk with no valid row: first = n_tiles, last = -1, meets nothing
+    first = jnp.min(jnp.where(tok_lanes < 0, tokens, tok_lanes), axis=(1, 2)) // tile
+    last = jnp.max(tok_lanes, axis=(1, 2)) // tile
+    t = jnp.arange(n_tiles, dtype=jnp.int32)[:, None]
+    meets = (first[None, :] <= t) & (t <= last[None, :])  # [n_tiles, n_chunks]
+    upto = jnp.cumsum(meets.reshape(-1).astype(jnp.int32))
+    n_pairs = upto[-1]
+    n_max = groups * n_tiles + n_chunks
+    nth = jnp.minimum(jnp.arange(1, n_max + 1, dtype=jnp.int32),
+                      jnp.maximum(n_pairs, 1))
+    at = jnp.minimum(
+        jnp.searchsorted(upto, nth, side="left", method="compare_all"),
+        n_tiles * n_chunks - 1).astype(jnp.int32)
+    return at // n_chunks, at % n_chunks, n_pairs.reshape(1)
+
+
+def _bf16_parts(x):
+    """``x`` as bfloat16 arrays whose float32 sum is ``x``: itself, or a
+    wider dtype's three 8-bit slices of the 24-bit significand, so that a
+    0/1 matrix selects rows of any dtype EXACTLY through the MXU."""
+    if x.dtype == jnp.bfloat16:
+        return (x,)
+    parts, rest = [], x.astype(jnp.float32)
+    for _ in range(3):
+        parts.append(rest.astype(jnp.bfloat16))
+        rest = rest - parts[-1].astype(jnp.float32)
+    return tuple(parts)
+
+
+def _combine_kernel(tile_ref, chunk_ref, n_ref, tok_ref, acc_ref, *refs):
+    from jax.experimental import pallas as pl
+
+    *rows_refs, o_ref = refs
+    p = pl.program_id(0)
+    tile = tile_ref[p]
+
+    @pl.when(jnp.logical_or(p == 0, tile != tile_ref[jnp.maximum(p - 1, 0)]))
+    def _enter():
+        # a tile's pairs are one run of the grid: the carry's tile comes in
+        # once, here, and leaves once, when the run ends
+        o_ref[...] = acc_ref[...]
+
+    @pl.when(p < n_ref[0])
+    def _add():
+        rows_in_tile, chunk = o_ref.shape[0], tok_ref.shape[-1]
+        # [tile, chunk] 0/1: row r of the chunk goes to token tok[r]; an
+        # invalid row's token is -1 and a token outside this tile matches
+        # no line of the iota, so both are selected by nothing
+        onto = jax.lax.broadcasted_iota(jnp.int32, (rows_in_tile, chunk), 0) == (
+            tok_ref[0] - tile * rows_in_tile)
+        onto = jnp.where(onto, 1.0, 0.0).astype(jnp.bfloat16)
+        add = None
+        for rows_ref in rows_refs:
+            for part in _bf16_parts(rows_ref[...]):
+                picked = jax.lax.dot_general(
+                    onto, part, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                add = picked if add is None else add + picked
+        o_ref[...] += add
+
+
+def _combine_call(acc, rows, tok, valid, groups, block_rows, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    tokens, d = acc.shape
+    n_rows = tok.shape[0]
+    if acc.dtype != jnp.float32:
+        raise ValueError(f"combine_rows sums in float32; acc is {acc.dtype}")
+    if n_rows % block_rows or any(r.shape != (n_rows, d) for r in rows):
+        raise ValueError(
+            f"combine_rows: rows {[r.shape for r in rows]} for {n_rows} "
+            f"tokens' indices in blocks of {block_rows}, onto {acc.shape}")
+    # the MXU contracts 128 deep: a longer chunk selects no more rows a pass
+    chunk = 128 if block_rows % 128 == 0 else block_rows
+    tile, vmem_limit = _token_tile(
+        tokens, d, sum(chunk * d * r.dtype.itemsize for r in rows))
+    tok_lanes = jnp.where(valid, tok, -1).astype(jnp.int32).reshape(
+        n_rows // chunk, 1, chunk)
+    pair_tile, pair_chunk, n_pairs = _combine_pairs(
+        tok_lanes, tokens, tile, groups)
+
+    def rows_map(p, tiles, chunks, n):
+        return (chunks[p], 0)
+
+    def acc_map(p, tiles, chunks, n):
+        return (tiles[p], 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(pair_tile.shape[0],),
+        in_specs=[
+            pl.BlockSpec((1, 1, chunk), lambda p, tiles, chunks, n: (chunks[p], 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((tile, d), acc_map, memory_space=pltpu.VMEM),
+            *(pl.BlockSpec((chunk, d), rows_map, memory_space=pltpu.VMEM)
+              for _ in rows),
+        ],
+        out_specs=pl.BlockSpec((tile, d), acc_map, memory_space=pltpu.VMEM),
+    )
+    return pl.pallas_call(
+        _combine_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
+        input_output_aliases={4: 0},  # acc, behind the three scalars and tok
+        compiler_params=_compiler_params(vmem_limit),
+        interpret=interpret,
+        name="moe_combine",
+    )(pair_tile, pair_chunk, n_pairs, tok_lanes, acc, *rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _combine(acc, rows, tok, valid, groups, block_rows, interpret):
+    return _combine_call(acc, rows, tok, valid, groups, block_rows, interpret)
+
+
+def _combine_fwd_rule(acc, rows, tok, valid, groups, block_rows, interpret):
+    out = _combine_call(acc, rows, tok, valid, groups, block_rows, interpret)
+    # of the rows only their dtypes are kept: what their cotangents come in
+    return out, (tok, valid, tuple(jnp.zeros((), r.dtype) for r in rows))
+
+
+def _combine_bwd_rule(groups, block_rows, interpret, res, g):
+    tok, valid, like = res
+    g_rows = jnp.where(valid[:, None], g[tok], 0)
+    return g, tuple(g_rows.astype(r.dtype) for r in like), None, None
+
+
+_combine.defvjp(_combine_fwd_rule, _combine_bwd_rule)
+
+
+def combine_rows(acc, rows, tok, valid, *, groups: int, block_rows: int = 256,
+                 interpret: bool = False):
+    """``acc`` [T, d] float32 with ``rows[i]`` added at ``acc[tok[i]]`` for
+    every ``valid[i]`` — the MoE combine (and its mirror, ``dx``) as ONE
+    kernel, ``moe_combine``, in place of an XLA scatter-add.
+
+    ``rows``: [R, d] in any float dtype, or a tuple of such arrays whose
+    SUM is what is added (the backward's two input cotangents: summed in
+    float32 inside, never written out as one); ``tok`` [R] int32, ``valid``
+    [R] bool. The caller's order is what the kernel uses in place of a sort:
+    every ``block_rows`` rows belong to ONE of at most ``groups`` groups (an
+    expert), groups lie one behind the other, and the valid tokens of a
+    group ascend (gmm's layout: a stable sort of t-major choices). An
+    invalid row adds nothing, whatever its ``tok`` (its value must be
+    finite: it meets a 0 of the selection, not a mask).
+
+    The grid walks (token tile, row chunk) pairs, tile-major
+    (_combine_pairs): a tile of ``acc`` stays in VMEM while every chunk of
+    128 rows that holds one of its tokens is fetched by a plain block DMA
+    and added through a 0/1 [tile, chunk] product on the MXU — exact
+    selection of bfloat16 rows (_bf16_parts for wider ones), float32
+    accumulation, in the pairs' order, which the routing alone decides: no
+    atomics, nothing that differs run to run. ``acc`` is read and written
+    once, in place (aliased to the result). Differentiable in ``acc`` (the
+    cotangent itself) and ``rows`` (its rows gathered at ``tok``, zero where
+    invalid). The token tile is the widest that fits (_token_tile)."""
+    many = isinstance(rows, (tuple, list))
+    return _combine(acc, tuple(rows) if many else (rows,), tok, valid,
+                    int(groups), int(block_rows), bool(interpret))

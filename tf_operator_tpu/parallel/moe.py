@@ -253,9 +253,10 @@ def _segment_inputs(j, x, top_p, idx: _Routing, walk: _Walk):
 
 def _segment_forward(j, out, x, top_p, weights, idx, walk):
     """``out`` [T, d] float32 plus segment j's experts' outputs: the combine
-    is a scatter-add from the slots (``h`` is pre-weighted), so the sum over
-    a token's choices runs in float32."""
-    from tf_operator_tpu.ops.grouped_matmul import gmm
+    is the ``moe_combine`` kernel (grouped_matmul.combine_rows) adding the
+    slots' rows onto their tokens in place (``h`` is pre-weighted), so the
+    sum over a token's choices runs in float32, in the order of the slots."""
+    from tf_operator_tpu.ops.grouped_matmul import combine_rows, gmm
 
     run = partial(gmm, **walk.kernel)
     w_gate, w_up, w_down = weights
@@ -267,7 +268,8 @@ def _segment_forward(j, out, x, top_p, weights, idx, walk):
         zu = run(x_seg, w_up, block_expert)
         h = run(walk.act(zg) * zu, w_down, block_expert, row_scale=s_pad)
     with jax.named_scope("sec_moe_dispatch"):
-        return out.at[tok].add(jnp.where(valid[:, None], h, 0).astype(jnp.float32))
+        return combine_rows(out, h, tok, valid, groups=idx.counts.shape[0],
+                            **walk.kernel)
 
 
 def _walk_trips(idx, walk):
@@ -308,10 +310,12 @@ def _expert_walk_fwd(walk, x, top_p, weights, idx):
 def _expert_walk_bwd(walk, res, g):
     """Per segment: gather its rows and its rows of ``g`` again, gate and up
     again (what ``save_mid`` replays anyway), the kernels' cotangents
-    (grouped_matmul.gmm_grads), then ``dx`` and ``d top_p`` scatter-added
-    and the three weight gradients summed on float32 carries — rounded
-    once, after the last segment."""
-    from tf_operator_tpu.ops.grouped_matmul import gmm, gmm_grads
+    (grouped_matmul.gmm_grads), then ``dx`` combined as the forward's
+    ``out`` is (its two input cotangents go to ``moe_combine`` as they are:
+    their float32 sum is never written out), ``d top_p`` scatter-added, and
+    the three weight gradients summed on float32 carries — rounded once,
+    after the last segment."""
+    from tf_operator_tpu.ops.grouped_matmul import combine_rows, gmm, gmm_grads
 
     x, top_p, weights, idx = res
     kernel = walk.kernel
@@ -339,9 +343,14 @@ def _expert_walk_bwd(walk, res, g):
             dx_u, dw_up_j = gmm_grads(x_seg, t_up, block_expert, dzu, **kernel)
             dw_gate, dw_up, dw_down = (
                 dw_gate + dw_gate_j, dw_up + dw_up_j, dw_down + dw_down_j)
+            # the combine waits for the weight-gradient kernels: by then the
+            # two gathered segments (x_seg, g_h) are dead and the body holds
+            # two segment-sized buffers fewer while the kernel runs
+            dx_g, dx_u, dw_gate, dw_up, dw_down = jax.lax.optimization_barrier(
+                (dx_g, dx_u, dw_gate, dw_up, dw_down))
         with jax.named_scope("sec_moe_dispatch"):
-            dx_seg = dx_g.astype(jnp.float32) + dx_u.astype(jnp.float32)
-            dx = dx.at[tok].add(jnp.where(valid[:, None], dx_seg, 0))
+            dx = combine_rows(dx, (dx_g, dx_u), tok, valid,
+                              groups=idx.counts.shape[0], **kernel)
             d_top_p = d_top_p.at[src_choice].add(jnp.where(valid, ds, 0))
         return dx, d_top_p, dw_gate, dw_up, dw_down
 
@@ -388,13 +397,14 @@ def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
     blocks (the rows this share gets under even routing plus a round-up
     block an expert), ceil(occupied blocks / seg_blocks) of them, counted
     on the device (_expert_walk). A segment is a row GATHER of its slots,
-    the three grouped matmuls, and a float32 scatter-add of its weighted
-    rows onto the [T, d] result; only a segment's tail can be sentinel
-    blocks (-1: zeros written, no MXU work). held == E makes the segment
-    the whole buffer: one segment under plain autodiff, no loop. ragged_dot
-    was measured at ~19 TFLOP/s on the same shapes (full-height
-    masked-matmul lowering) — the kernel exists because the XLA-level
-    formulations all lose; see grouped_matmul.py.
+    the three grouped matmuls, and the ``moe_combine`` kernel adding its
+    weighted rows onto the float32 [T, d] result in place, in the order the
+    sort made (grouped_matmul.combine_rows: no scatter, no second sort);
+    only a segment's tail can be sentinel blocks (-1: zeros written, no MXU
+    work). held == E makes the segment the whole buffer: one segment under
+    plain autodiff, no loop. ragged_dot was measured at ~19 TFLOP/s on the
+    same shapes (full-height masked-matmul lowering) — the kernel exists
+    because the XLA-level formulations all lose; see grouped_matmul.py.
 
     ``score="sigmoid"`` is the bias-balanced router (DeepSeek-V3's
     ``noaux_tc`` at one group): s = sigmoid(logits) in float32, the top-k
@@ -407,7 +417,7 @@ def _moe_single_gmm(x, gate_logits, expert_params, k_top: int = 1,
     what a bias update reads), ``routed_here`` (choices routed to held experts),
     ``rows_computed`` (occupied blocks × B: what the kernels multiply),
     ``rows_walked`` (segments walked × seg_blocks × B: what the gathers and
-    scatter-adds move) beside ``rows_bound`` (nb × B: what they moved before
+    the combines move) beside ``rows_bound`` (nb × B: what they moved before
     the walk), ``held_load_max`` / ``held_load_mean`` (choices per held
     expert)."""
     tokens, d = x.shape
